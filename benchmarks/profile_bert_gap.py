@@ -26,7 +26,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmarks import _harness  # noqa: F401,E402 — TERM-clean + cache
+from benchmarks import _harness  # noqa: F401,E402 — compile cache
 
 import numpy as np
 

@@ -10,7 +10,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmarks import _harness  # noqa: F401 — clean-exit TERM handler (TPU claim hygiene)
+from benchmarks import _harness  # noqa: F401 — compile cache
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -8,7 +8,6 @@ Public entry points mirror the reference:
   - add_config_arguments() (reference: deepspeed/__init__.py:216)
 """
 
-from . import compat  # noqa: F401  (installs jax API shims; must be first)
 from .version import __version__
 from .config import DeepSpeedConfig, DeepSpeedConfigError
 from .parallel import (MeshContext, get_mesh_context, initialize_mesh,

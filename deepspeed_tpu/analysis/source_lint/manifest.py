@@ -68,9 +68,6 @@ DEGRADATION_WAIVERS = {
     ("deepspeed_tpu/analysis/hlo_audit.py", "audit_target_hlo"):
         "the compile failure becomes an audit Finding that escalates "
         "under require_spmd_match — louder than the registry",
-    ("deepspeed_tpu/compat.py", "_install_name_replication_rule"):
-        "jax-version layout probe: newer jax needs no patch, nothing "
-        "degrades",
     ("deepspeed_tpu/config.py", "PreemptionConfig.from_dict"):
         "jax import probe at config-parse time; the guarded multihost "
         "path RAISES DeepSpeedConfigError, it never falls back",

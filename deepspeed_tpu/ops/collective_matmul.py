@@ -79,10 +79,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:  # TPU-specific pallas bits are unavailable on some CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from .. import constants as C
 from ..runtime.comm.low_bandwidth import (DEFAULT_BLOCK, blockwise_dequantize,
@@ -591,16 +588,13 @@ def _ag_matmul_tpu(x, q, s, axis_name, bits, out_dtype,
     double-buffered VMEM slots while the MXU multiplies the tile that
     arrived last step — the T3 schedule realized in-kernel.
 
-    UNVALIDATED on real chips (this host has none): written against the
-    Pallas TPU RDMA contract (neighbor barrier before the first remote
-    write, per-slot DMA semaphores, send-wait before slot reuse) and
-    folded into ROADMAP item 1's measured sweep.  Interpret-mode callers
-    take :func:`_ag_matmul_interp`, which pins the identical numerics
-    with the transport mesh-simulated."""
-    if pltpu is None:
-        raise RuntimeError(
-            "fused_allgather_matmul: pallas TPU support unavailable — "
-            "pass interpret=True (mesh-simulated transport) on CPU")
+    DOES NOT LOWER on jax 0.9.0 ("LOGICAL device_id_type does not
+    support device_id as a tuple or dict" — docs/
+    fused_collective_matmul.md); never run on a chip.  Written against
+    the Pallas TPU RDMA contract (neighbor barrier before the first
+    remote write, per-slot DMA semaphores, send-wait before slot reuse).
+    Interpret-mode callers take :func:`_ag_matmul_interp`, which pins
+    the identical numerics with the transport mesh-simulated."""
     world = int(lax.axis_size(axis_name))
     kc = q.shape[0]
     n = _tile_n(q, kc, bits)
@@ -700,14 +694,11 @@ def _matmul_rs_tpu(lhs, rhs, error, axis_name,
     one); the receiver dequantizes the source table and reduces in
     shard-index order — the modular accumulation contract.
 
-    UNVALIDATED on real chips (this host has none) — folded into
-    ROADMAP item 1's measured sweep; interpret-mode callers take the
-    per-tile path in :func:`fused_matmul_reduce_scatter`, which pins
-    the identical numerics with the transport mesh-simulated."""
-    if pltpu is None:
-        raise RuntimeError(
-            "fused_matmul_reduce_scatter: pallas TPU support "
-            "unavailable — pass interpret=True on CPU")
+    DOES NOT LOWER on jax 0.9.0 ("Must wait semaphores of the following
+    types ... Got dma_sem" — docs/fused_collective_matmul.md); never
+    run on a chip.  Interpret-mode callers take the per-tile path in
+    :func:`fused_matmul_reduce_scatter`, which pins the identical
+    numerics with the transport mesh-simulated."""
     from ..runtime.comm.low_bandwidth import largest_divisor_at_most
     world = int(lax.axis_size(axis_name))
     k, n = lhs.shape[1], rhs.shape[1]

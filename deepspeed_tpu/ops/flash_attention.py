@@ -25,11 +25,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU-specific pallas bits are unavailable on some CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from .dispatch import (BATCH_AXES, HEAD_AXES, manual_kernel_region,
+                       pallas_available, pallas_interpret)
 
 # Finite mask value: keeps running-max finite for fully-masked rows (an -inf
 # row max would turn exp(s - m) into NaN).
@@ -162,8 +161,28 @@ def _keep_scale(rate: float, bits: int) -> float:
     return float(2 ** bits) / _quantized_threshold(rate, bits)
 
 
+def _fmix32(x):
+    """murmur3's 32-bit finalizer."""
+    x = (x ^ (x >> np.uint32(16))) * np.uint32(0x85EBCA6B)
+    x = (x ^ (x >> np.uint32(13))) * np.uint32(0xC2B2AE35)
+    return x ^ (x >> np.uint32(16))
+
+
+def _interpret_random_bits(v1, v2, shape):
+    """Interpret-mode stand-in for prng_seed(v1, v2) + prng_random_bits:
+    the TPU PRNG has no CPU lowering (the Pallas TPU interpreter returns
+    zeros for it), so the CPU lane hashes the two seed values and the
+    position instead.  Another stream than the chip's, but regenerable
+    from the same tile coordinates, which is all the kernels rely on."""
+    key = _fmix32(_fmix32(v2.astype(jnp.uint32)) ^ v1.astype(jnp.uint32))
+    pos = (jax.lax.broadcasted_iota(jnp.uint32, shape, 0)
+           * np.uint32(shape[1])
+           + jax.lax.broadcasted_iota(jnp.uint32, shape, 1))
+    return _fmix32(pos ^ key)
+
+
 def _dropout_keep(seed_ref, b, h, qi, ki, rate, block_q, block_k,
-                  num_k_blocks, bits=32):
+                  num_k_blocks, bits=32, interpret=False):
     """Regenerable per-tile keep mask: the PRNG is reseeded from the step
     seed and the tile's ABSOLUTE coordinates, so the forward kernel and
     both backward kernels (whose grids order (qi, ki) differently)
@@ -189,9 +208,14 @@ def _dropout_keep(seed_ref, b, h, qi, ki, rate, block_q, block_k,
     arithmetic stays in plain int32: scalar casts/bitcasts are
     Mosaic-illegal ('tpu.bitcast' needs vector operands — measured on
     v5e, round 4)."""
-    pltpu.prng_seed(seed_ref[0] + b * pl.num_programs(1) + h,
-                    qi * num_k_blocks + ki
-                    + seed_ref[0] * np.int32(-1640531527))
+    v1 = seed_ref[0] + b * pl.num_programs(1) + h
+    v2 = qi * num_k_blocks + ki + seed_ref[0] * np.int32(-1640531527)
+    if interpret:
+        def random_bits(shape):
+            return _interpret_random_bits(v1, v2, shape)
+    else:
+        pltpu.prng_seed(v1, v2)
+        random_bits = pltpu.prng_random_bits
     if bits == 8:
         # one 32-bit word per FOUR mask positions: byte j of word w maps
         # to column j*block_k/4 + w (column-GROUP layout — no Mosaic
@@ -200,14 +224,13 @@ def _dropout_keep(seed_ref, b, h, qi, ki, rate, block_q, block_k,
         # bits where block_k is known (_effective_dropout_bits), so the
         # divisibility precondition holds here by construction.
         assert block_k % 4 == 0, "8-bit dropout requires block_k % 4 == 0"
-        w = pltpu.prng_random_bits((block_q, block_k // 4))
-        w = w.astype(jnp.uint32)
+        w = random_bits((block_q, block_k // 4)).astype(jnp.uint32)
         t8 = _quantized_threshold(rate, 8)
         m = jnp.concatenate(
             [(w >> np.uint32(8 * j)) & np.uint32(0xFF) for j in range(4)],
             axis=1)
         return m < np.uint32(t8)
-    rbits = pltpu.prng_random_bits((block_q, block_k))
+    rbits = random_bits((block_q, block_k))
     threshold = np.uint32(_quantized_threshold(rate, 32))
     return rbits.astype(jnp.uint32) < threshold
 
@@ -304,7 +327,8 @@ def _fa_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                *rest,
                causal: bool, sm_scale: float, block_q: int, block_k: int,
                num_k_blocks: int, dropout_rate: float,
-               dropout_pbits: int = 32, save_mask: bool = False):
+               dropout_pbits: int = 32, save_mask: bool = False,
+               interpret: bool = False):
     if save_mask:
         mask_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -359,7 +383,7 @@ def _fa_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             # commutes with the final /l)
             keep = _dropout_keep(seed_ref, b, h, qi, ki, dropout_rate,
                                  block_q, block_k, num_k_blocks,
-                                 bits=dropout_pbits)
+                                 bits=dropout_pbits, interpret=interpret)
             inv = _keep_scale(dropout_rate, dropout_pbits)
             p = jnp.where(keep, p * inv, 0.0)
             if save_mask:
@@ -476,10 +500,6 @@ def flash_attention_pallas(q, k, v, causal: bool = False,
     the bit-packed keep mask [B, H, S_q/32, S_k] uint32 — ALWAYS in the
     internal bhsd-derived index space regardless of layout — for
     flash_attention_bwd_pallas(dropout_mask=...)."""
-    if pltpu is None:
-        raise RuntimeError(
-            "pallas TPU support unavailable in this jax install — use "
-            "mha_reference / the public flash_attention dispatcher instead")
     batch, heads, q_len, d = _dims(q, layout)
     k_len = _dims(k, layout)[2]
     if layout == "bshd":
@@ -496,15 +516,6 @@ def flash_attention_pallas(q, k, v, causal: bool = False,
             f"seq lengths ({q_len},{k_len}) only tile into 1-wide blocks "
             f"— use the flash_attention dispatcher (XLA fallback)")
     nq, nk = q_len // block_q, k_len // block_k
-    if dropout_rate > 0.0 and interpret:
-        raise ValueError(
-            f"in-kernel dropout (dropout_rate={dropout_rate}) needs the "
-            "TPU PRNG — pltpu.prng_seed has no CPU lowering, so "
-            "interpret mode cannot generate the mask.  Fix: call with "
-            "dropout_rate=0 (parity tests compare the dropout-free "
-            "kernel), or take the XLA path — flash_attention("
-            "impl='xla') / mha_reference — whose jax.random dropout "
-            "runs on any backend")
     seed = _seed_arg(dropout_seed)
 
     if save_dropout_mask:
@@ -525,7 +536,7 @@ def flash_attention_pallas(q, k, v, causal: bool = False,
         block_q=block_q, block_k=block_k, num_k_blocks=nk,
         dropout_rate=float(dropout_rate),
         dropout_pbits=_effective_dropout_bits(block_k),
-        save_mask=save_dropout_mask)
+        save_mask=save_dropout_mask, interpret=interpret)
 
     scratch = [
         pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
@@ -568,6 +579,7 @@ def flash_attention_pallas(q, k, v, causal: bool = False,
             scratch_shapes=scratch),
         out_shape=out_shape,
         interpret=interpret,
+        name="flash_fwd",
         **params,
     )(seed, q, k, v)
     out, lse = res[0], res[1]
@@ -584,7 +596,8 @@ def flash_attention_pallas(q, k, v, causal: bool = False,
 def _fa_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                         delta_ref, *rest, causal, sm_scale, block_q,
                         block_k, num_q_blocks, num_k_blocks, dropout_rate,
-                        dropout_pbits=32, reuse_mask: bool = False):
+                        dropout_pbits=32, reuse_mask: bool = False,
+                        interpret: bool = False):
     if reuse_mask:
         mask_ref, dk_ref, dv_ref, dk_scr, dv_scr = rest
     else:
@@ -634,7 +647,8 @@ def _fa_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             else:
                 keep = _dropout_keep(seed_ref, b, h, qi, ki, dropout_rate,
                                      block_q, block_k, num_k_blocks,
-                                     bits=dropout_pbits)
+                                     bits=dropout_pbits,
+                                     interpret=interpret)
             inv = _keep_scale(dropout_rate, dropout_pbits)
             p_drop = jnp.where(keep, p * inv, 0.0)
             dp = jnp.where(keep, dp * inv, 0.0)
@@ -658,7 +672,8 @@ def _fa_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _fa_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                       delta_ref, *rest, causal, sm_scale, block_q,
                       block_k, num_k_blocks, dropout_rate,
-                      dropout_pbits=32, reuse_mask: bool = False):
+                      dropout_pbits=32, reuse_mask: bool = False,
+                      interpret: bool = False):
     if reuse_mask:
         mask_ref, dq_ref, dq_scr = rest
     else:
@@ -701,7 +716,8 @@ def _fa_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             else:
                 keep = _dropout_keep(seed_ref, b, h, qi, ki, dropout_rate,
                                      block_q, block_k, num_k_blocks,
-                                     bits=dropout_pbits)
+                                     bits=dropout_pbits,
+                                     interpret=interpret)
             inv = _keep_scale(dropout_rate, dropout_pbits)
             dp = jnp.where(keep, dp * inv, 0.0)
         ds = p * (dp - delta) * sm_scale
@@ -752,18 +768,6 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
             f"seq lengths ({q_len},{k_len}) only tile into 1-wide blocks "
             f"— use the flash_attention dispatcher (XLA fallback)")
     nq, nk = q_len // block_q, k_len // block_k
-    if dropout_rate > 0.0 and interpret and dropout_mask is None:
-        # reuse-mode (dropout_mask given) backward never touches the PRNG
-        # — the unpack is plain vector ops, so interpret mode is legal
-        # there (and is how the CPU lane tests the reuse numerics)
-        raise ValueError(
-            f"in-kernel dropout (dropout_rate={dropout_rate}) needs the "
-            "TPU PRNG — pltpu.prng_seed has no CPU lowering, so the "
-            "interpret-mode backward cannot regenerate the mask.  Fix: "
-            "call with dropout_rate=0, pass the forward's saved "
-            "dropout_mask (save_dropout_mask / set_dropout_mask_reuse("
-            "True) — the bit-unpack needs no PRNG), or take the XLA "
-            "path (flash_attention(impl='xla') / mha_reference)")
     seed = _seed_arg(dropout_seed)
 
     # delta_i = rowsum(dO_i * O_i)  (cheap elementwise; leave to XLA).
@@ -817,7 +821,8 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
         _fa_bwd_dkdv_kernel, causal=causal, sm_scale=float(sm_scale),
         block_q=block_q, block_k=block_k, num_q_blocks=nq, num_k_blocks=nk,
         dropout_rate=float(dropout_rate),
-        dropout_pbits=_effective_dropout_bits(block_k), reuse_mask=reuse)
+        dropout_pbits=_effective_dropout_bits(block_k), reuse_mask=reuse,
+        interpret=interpret)
     dkdv_in_specs = [
         _tile_spec(block_q, d, "j"),
         _tile_spec(block_k, d, "i"),
@@ -851,6 +856,7 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkdv",
         **params,
     )(seed, q, k, v, do, lse, delta, *mask_in)
 
@@ -861,7 +867,8 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
         _fa_bwd_dq_kernel, causal=causal, sm_scale=float(sm_scale),
         block_q=block_q, block_k=block_k, num_k_blocks=nk,
         dropout_rate=float(dropout_rate),
-        dropout_pbits=_effective_dropout_bits(block_k), reuse_mask=reuse)
+        dropout_pbits=_effective_dropout_bits(block_k), reuse_mask=reuse,
+        interpret=interpret)
     dq_in_specs = [
         _tile_spec(block_q, d, "i"),
         _tile_spec(block_k, d, "j"),
@@ -883,6 +890,7 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
         **params,
     )(seed, q, k, v, do, lse, delta, *mask_in)
 
@@ -901,8 +909,8 @@ def _flash(q, k, v, seed, causal, sm_scale, block_q, block_k,
                       layout, dropout_rate)[0]
 
 
-# Auto-dispatch crossover (v5e, 2026-07-31, benchmarks/session_r4/
-# bert_ab.log): at S=128 the XLA attention beats the Pallas flash kernel
+# Auto-dispatch crossover (v5e, 2026-07-31, docs/ROUND4_NOTES.md bert_ab
+# 2x2): at S=128 the XLA attention beats the Pallas flash kernel
 # by ~25% on the full BERT-large step (90.3 vs 115.5 ms dropout-on) —
 # short sequences leave the streaming kernel overhead-bound while XLA
 # fuses the whole [S, S] attention in registers/VMEM.  At S=1024 the
@@ -912,8 +920,7 @@ AUTO_MIN_SEQ = 512
 
 
 def _use_pallas(q_len, k_len, d, block_q, block_k):
-    from .dispatch import pallas_available
-    if not pallas_available():
+    if not (pallas_available() or pallas_interpret()):
         return False
     usable, _, _ = _resolve_blocks(q_len, k_len, block_q, block_k)
     return usable
@@ -957,12 +964,13 @@ def _flash_fwd(q, k, v, seed, causal, sm_scale, block_q, block_k,
                 q, k, v, causal=causal, sm_scale=sm_scale,
                 block_q=bq, block_k=bk, return_lse=True, layout=layout,
                 dropout_rate=dropout_rate, dropout_seed=seed,
-                save_dropout_mask=True)
+                save_dropout_mask=True, interpret=pallas_interpret())
             return out, (q, k, v, seed, out, lse, mask)
         out, lse = flash_attention_pallas(
             q, k, v, causal=causal, sm_scale=sm_scale,
             block_q=bq, block_k=bk, return_lse=True, layout=layout,
-            dropout_rate=dropout_rate, dropout_seed=seed)
+            dropout_rate=dropout_rate, dropout_seed=seed,
+            interpret=pallas_interpret())
         return out, (q, k, v, seed, out, lse, None)
     out = _ref_in_layout(q, k, v, causal, sm_scale, layout, dropout_rate,
                          seed[0])
@@ -979,7 +987,8 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, layout, dropout_rate,
             q, k, v, out, lse, g, causal=causal, sm_scale=sm_scale,
             block_q=bq, block_k=bk, layout=layout,
             dropout_rate=dropout_rate, dropout_seed=seed,
-            dropout_mask=mask, dropout_mask_block_q=bq)
+            dropout_mask=mask, dropout_mask_block_q=bq,
+            interpret=pallas_interpret())
         return dq, dk, dv, None
     _, vjp = jax.vjp(
         lambda q_, k_, v_: _ref_in_layout(q_, k_, v_, causal, sm_scale,
@@ -989,6 +998,36 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, layout, dropout_rate,
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+# Odd multiplier (murmur3's c1 as an int32 bit pattern) that spreads the
+# shard index over the seed space: neighbouring shards' seeds land
+# billions apart, far outside the (batch, head) offsets the kernel adds.
+_SHARD_SEED_STRIDE = np.int32(-862048943)
+
+
+def _flash_over_mesh(q, k, v, seed, causal, sm_scale, block_q, block_k,
+                     layout, dropout_rate):
+    """_flash, placed for the device mesh.  Where the Pallas kernel will
+    run, the call sits in a region manual over every mesh axis
+    (dispatch.manual_kernel_region) with the batch split over the data
+    axes and the heads over the model axis, so each chip runs the kernel
+    on the rows it already holds and no q/k/v gather precedes it.  The
+    XLA reference needs no region: GSPMD partitions it."""
+    q_len, k_len = _dims(q, layout)[2], _dims(k, layout)[2]
+    if not _use_pallas(q_len, k_len, q.shape[3], block_q, block_k):
+        return _flash(q, k, v, seed, causal, sm_scale, block_q, block_k,
+                      layout, dropout_rate)
+    dims = {0: BATCH_AXES, (1 if layout == "bhsd" else 2): HEAD_AXES}
+
+    def local(shard_index, q, k, v, seed):
+        # every shard numbers its rows and heads from 0, so on the step
+        # seed alone all shards would draw the same dropout masks
+        seed = seed + shard_index * _SHARD_SEED_STRIDE
+        return _flash(q, k, v, seed, causal, sm_scale, block_q, block_k,
+                      layout, dropout_rate)
+
+    return manual_kernel_region(local, (q, k, v, seed),
+                                (dims, dims, dims, None), dims)
 
 
 # Default block sizes, tuned on v5e (benchmarks/profile_flash_blocks.py,
@@ -1042,8 +1081,8 @@ def flash_attention(q, k, v, causal: bool = False,
                 f"impl='pallas': no aligned tiling for seq lengths "
                 f"({q.shape[2]},{k.shape[2]}) or Pallas unavailable on this "
                 "backend — use impl='auto' for the XLA fallback")
-        return _flash(q, k, v, seed, causal, sm_scale, block_q, block_k,
-                      "bhsd", dropout_rate)
+        return _flash_over_mesh(q, k, v, seed, causal, sm_scale, block_q,
+                                block_k, "bhsd", dropout_rate)
     if bias is not None:
         return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale,
                              bias=bias, dropout_rate=dropout_rate,
@@ -1052,8 +1091,8 @@ def flash_attention(q, k, v, causal: bool = False,
         return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale,
                              dropout_rate=dropout_rate,
                              dropout_seed=seed[0])
-    return _flash(q, k, v, seed, causal, sm_scale, block_q, block_k,
-                  "bhsd", dropout_rate)
+    return _flash_over_mesh(q, k, v, seed, causal, sm_scale, block_q,
+                            block_k, "bhsd", dropout_rate)
 
 
 def flash_attention_bsh(q, k, v, causal: bool = False,
@@ -1094,5 +1133,5 @@ def flash_attention_bsh(q, k, v, causal: bool = False,
                                      causal=causal, sm_scale=sm_scale,
                                      bias=bias, dropout_rate=dropout_rate,
                                      dropout_seed=seed[0]))
-    return _flash(q, k, v, seed, causal, sm_scale, block_q, block_k,
-                  "bshd", dropout_rate)
+    return _flash_over_mesh(q, k, v, seed, causal, sm_scale, block_q,
+                            block_k, "bshd", dropout_rate)

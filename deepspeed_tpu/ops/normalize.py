@@ -15,11 +15,6 @@ import numpy as np
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
 
 def layer_norm_reference(x, gamma, beta, eps: float = 1e-5):
     """LN over the last dim with fp32 statistics (normalize_kernels.cu

@@ -104,15 +104,18 @@ class OpBuilder:
         if os.path.exists(path):
             return path
         os.makedirs(BUILD_DIR, exist_ok=True)
+        # a temp name of this process's own: concurrent builders (xdist
+        # workers on a fresh checkout) must not replace each other's file
+        tmp = f"{path}.{os.getpid()}.tmp"
         cmd = ([self.compiler()] + self.cxx_flags() + self.sources() +
-               self.ldflags() + ["-o", path + ".tmp"])
+               self.ldflags() + ["-o", tmp])
         logger.info(f"building native op {self.NAME}: {' '.join(cmd)}")
         try:
             subprocess.run(cmd, capture_output=True, check=True, text=True)
         except subprocess.CalledProcessError as e:
             raise RuntimeError(
                 f"native build of {self.NAME} failed:\n{e.stderr}") from e
-        os.replace(path + ".tmp", path)  # atomic vs concurrent builders
+        os.replace(tmp, path)  # atomic vs concurrent builders
         return path
 
     def load(self) -> ctypes.CDLL:

@@ -24,10 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pragma: no cover
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 class QuantizedWeight(NamedTuple):
@@ -94,8 +91,6 @@ def fused_dequant_matmul(x, w: QuantizedWeight, block_m: int = 256,
     Blocks are fitted to the shapes (callers go through
     matmul_maybe_int8, which falls back to the XLA path when no aligned
     tiling exists)."""
-    if pltpu is None:
-        raise RuntimeError("pallas TPU support unavailable")
     m, k = x.shape
     k2, n = w.qweight.shape
     assert k == k2, (x.shape, w.qweight.shape)

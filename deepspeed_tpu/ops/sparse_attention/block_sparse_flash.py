@@ -32,10 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pragma: no cover
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from ..flash_attention import (DEFAULT_MASK_VALUE, _STATS_LANES, _LANES,
                                causal_keep_mask)
@@ -229,8 +226,6 @@ def block_sparse_flash_fwd(q, k, v, idx, valid, block: int, causal: bool,
                            interpret: bool = False,
                            return_lse: bool = False):
     """q,k,v [B, H, S, D]; idx/valid [H, nb, max_deg] (layout_gather)."""
-    if pltpu is None:
-        raise RuntimeError("pallas TPU support unavailable")
     batch, heads, s, d = q.shape
     if s % block:
         raise ValueError(f"seq len {s} not divisible by block {block}")

@@ -25,7 +25,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import deepspeed_tpu as ds
@@ -359,7 +359,7 @@ def test_traced_collectives_reconcile_at_ratio_one():
     def f(x):
         def body(c, _):
             r = shard_map(region, mesh=mesh, in_specs=P("data"),
-                          out_specs=P("data"), check_rep=False)(c)
+                          out_specs=P("data"), check_vma=False)(c)
             return r, None
         out, _ = jax.lax.scan(body, x, None, length=5)
         return out

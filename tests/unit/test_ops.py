@@ -38,30 +38,39 @@ def test_flash_attention_pallas_matches_reference(causal):
                                rtol=1e-5, atol=1e-5)
 
 
-def test_interpret_mode_dropout_error_is_actionable():
-    """ISSUE 13 satellite: the interpret-mode dropout refusal must name
-    the knob and the workarounds (rate 0 / impl='xla' / the saved
-    dropout_mask for the backward), not just state the PRNG limitation."""
+def test_interpret_mode_dropout_keeps_the_kernel_contract():
+    """The TPU PRNG has no CPU lowering, so interpret mode hashes the
+    same two seed values instead (_interpret_random_bits): the keep rate
+    matches, the mask is a function of (seed, tile), and the backward
+    kernels regenerate the forward's mask — everything the chip's stream
+    is relied on for, on another stream (tests/tpu checks the chip's, at
+    both PRNG widths)."""
     from deepspeed_tpu.ops.flash_attention import flash_attention_bwd_pallas
-    q, k, v = _qkv()
-    with pytest.raises(ValueError) as ei:
-        flash_attention_pallas(q, k, v, block_q=64, block_k=64,
-                               interpret=True, dropout_rate=0.1,
-                               dropout_seed=0)
-    msg = str(ei.value)
-    assert "dropout_rate=0" in msg and "impl='xla'" in msg
-    assert "pltpu.prng_seed" in msg  # still explains WHY
+    q, k, _v = _qkv(s=128)
+    v = jnp.ones_like(q)
+
+    def run(seed, rate=0.25):
+        return flash_attention_pallas(q, k, v, block_q=64, block_k=64,
+                                      interpret=True, dropout_rate=rate,
+                                      dropout_seed=seed)
+
+    # v = 1: each output is the kept, rescaled probability mass of its row
+    out = np.asarray(run(11))
+    assert abs(out.mean() - 1.0) < 0.02 and out.std() > 0.01
+    np.testing.assert_array_equal(out, np.asarray(run(11)))
+    assert not np.allclose(out, np.asarray(run(12)))
 
     out, lse = flash_attention_pallas(q, k, v, block_q=64, block_k=64,
-                                      interpret=True, return_lse=True)
+                                      interpret=True, return_lse=True,
+                                      dropout_rate=0.25, dropout_seed=11)
     do = jnp.ones_like(q)
-    with pytest.raises(ValueError) as ei:
-        flash_attention_bwd_pallas(q, k, v, out, lse, do, block_q=64,
-                                   block_k=64, interpret=True,
-                                   dropout_rate=0.1, dropout_seed=0)
-    msg = str(ei.value)
-    assert "dropout_rate=0" in msg and "dropout_mask" in msg
-    assert "set_dropout_mask_reuse" in msg
+    _, _, dv = flash_attention_bwd_pallas(
+        q, k, v, out, lse, do, block_q=64, block_k=64, interpret=True,
+        dropout_rate=0.25, dropout_seed=11)
+    # dV = dropped(P)^T @ dO and out = dropped(P) @ v: with dO = v = 1 the
+    # two sums agree only if both sides drew the same mask
+    np.testing.assert_allclose(float(jnp.sum(dv[..., 0])),
+                               float(jnp.sum(out[..., 0])), rtol=1e-5)
 
 
 @pytest.mark.parametrize("causal", [False, True])

@@ -20,7 +20,7 @@ import sys
 from pathlib import Path
 
 REPO = Path(__file__).resolve().parents[2]
-LINT_PATHS = ("deepspeed_tpu", "tests", "bench.py")
+LINT_PATHS = ("deepspeed_tpu", "tests", "bench.py", "chip_smoke.py")
 # mirrors [tool.ruff.lint.per-file-ignores]: __init__ re-export surfaces
 F401_EXEMPT = "__init__.py"
 
