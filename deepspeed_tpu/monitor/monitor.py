@@ -29,7 +29,7 @@ from .fleet import FleetAggregator, format_fleet_line
 from .health import FleetHealth, format_health_line
 from .heartbeat import HEARTBEAT_DIR, HeartbeatWriter
 from .reconcile import Bands, format_line, reconcile_window
-from .trace import TID_STEP, TraceEventBuffer
+from .trace import TraceEventBuffer
 from .writers import (CsvWriter, JsonlWriter, MetricsWriter,
                       TensorBoardWriter, WriterThread)
 
@@ -561,16 +561,6 @@ class TrainingMonitor:
         self.stream.end_step(step, loss=loss, tokens=tokens,
                              counters=counters, swap=swap,
                              grad_norm=grad_norm)
-
-    def add_phase(self, name: str, t_start: float,
-                  step: Optional[int] = None,
-                  t_end: Optional[float] = None) -> None:
-        """Record one dispatch-phase span ending now (or at t_end)."""
-        if self.trace is not None:
-            self.trace.add_span(name, t_start,
-                                t_end if t_end is not None
-                                else time.perf_counter(),
-                                tid=TID_STEP, step=step)
 
     # ------------------------------------------------------------------ #
     def _sink(self, records: List[dict]) -> None:

@@ -1,8 +1,14 @@
-"""Chrome/Perfetto trace-event exporter.
+"""The program's spans, and the Chrome/Perfetto trace-event exporter.
 
-Turns the monitor's host-side timeline — step phases (forward/grad,
-accumulate, apply dispatch windows; the fused path's single whole-step
-dispatch), swap-tier I/O (``InflightGroupRead``/``InflightTensorWrite``
+``span`` is the one way the program marks where its host time goes: a
+``jax.profiler.TraceAnnotation`` named ``ds.<name>``, so the span lies on
+the profiler's clock beside the device's operations whenever a profiler
+session is open (and is inert when none is), and, when the monitor's
+Chrome export is on, the same name and window on its step lane.
+
+The exporter turns the monitor's host-side timeline — those spans (the
+engine's forward / backward / step calls and, inside them, the dispatch
+windows of the compiled programs), swap-tier I/O (``InflightGroupRead``/``InflightTensorWrite``
 issue→done windows with their exposed-wait tails), and flush boundaries
 — into trace-event JSON that chrome://tracing and https://ui.perfetto.dev
 open directly.
@@ -25,7 +31,12 @@ import os
 import time
 from typing import Any, Dict, List, Optional
 
+import jax
+
 from .record import SCHEMA_VERSION
+
+# Prefix of every span the program writes into the profiler's trace.
+SPAN_PREFIX = "ds."
 
 # lane -> tid (thread_name metadata emitted on first use)
 TID_STEP = 1
@@ -37,6 +48,44 @@ TID_MOE = 5
 _LANE_NAMES = {TID_STEP: "step phases", TID_SWAP_IN: "swap in (NVMe read)",
                TID_SWAP_OUT: "swap out (NVMe write)", TID_MARKS: "monitor",
                TID_MOE: "moe routing"}
+
+
+class span:
+    """``with span("forward.dispatch", monitor, step=3, program="jit_f"):``
+
+    Opens ``jax.profiler.TraceAnnotation("ds." + name, **ids)``: on the
+    host plane of a profiler trace the event carries ``ids`` as its
+    stats, and spans nest by time on the thread that opened them.  When
+    ``monitor`` (a ``TrainingMonitor`` or None) has ``trace_active``, the
+    same name and window also go to its ``TraceEventBuffer``.  Always on
+    the code path; there is no switch."""
+
+    __slots__ = ("_name", "_ids", "_buffer", "_annotation", "_t0")
+
+    def __init__(self, name: str, monitor=None, **ids):
+        self._name = SPAN_PREFIX + name
+        self._ids = ids
+        self._buffer = (monitor.trace if monitor is not None
+                        and monitor.trace_active else None)
+
+    def __enter__(self):
+        self._annotation = jax.profiler.TraceAnnotation(self._name,
+                                                        **self._ids)
+        self._annotation.__enter__()
+        if self._buffer is not None:
+            self._t0 = time.perf_counter()
+            # an outer span opens first and closes last: the export's
+            # origin is the first opening, not the first closing
+            self._buffer.open_at(self._t0)
+        return self
+
+    def __exit__(self, *exc):
+        if self._buffer is not None:
+            ids = dict(self._ids)
+            self._buffer.add_span(self._name, self._t0, time.perf_counter(),
+                                  tid=TID_STEP, step=ids.pop("step", None),
+                                  args=ids)
+        return self._annotation.__exit__(*exc)
 
 
 class TraceEventBuffer:
@@ -78,9 +127,13 @@ class TraceEventBuffer:
         self._steps_seen.add(step)
         return True
 
-    def _ts(self, t: float) -> float:
+    def open_at(self, t: float) -> None:
+        """The trace's origin, unless an earlier time already is."""
         if self._t0 is None:
             self._t0 = t
+
+    def _ts(self, t: float) -> float:
+        self.open_at(t)
         return (t - self._t0) * 1e6  # seconds -> microseconds
 
     def _name_lane(self, tid: int) -> None:

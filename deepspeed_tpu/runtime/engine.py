@@ -26,7 +26,6 @@ gradient-accumulation boundaries.
 """
 
 import os
-import time
 import warnings
 from typing import Any, Callable, Dict, Optional
 
@@ -53,8 +52,10 @@ def _install_donation_warning_filter():
         _donation_filter_installed = True
 
 from ..config import DeepSpeedConfig
+from ..monitor.trace import span
 from ..parallel import mesh as mesh_mod
 from ..parallel.mesh import MeshContext
+from ..profiling import scope_map
 from ..utils.logging import log_dist, logger
 from ..utils.timer import SynchronizedWallClockTimer, ThroughputTimer
 from . import checkpoint as ckpt_mod
@@ -74,6 +75,24 @@ STEP_GLOBAL_TIMER = "step"
 # window-level timer for the fused whole-step path: the gas window is ONE
 # dispatch, so forward/backward micro timers cannot exist there
 FUSED_STEP_TIMER = "fused_train_batch"
+
+
+def _program_name(fn):
+    """What a device trace's ``XLA Modules`` line calls the jitted
+    ``fn``, before the fingerprint in parentheses."""
+    return "jit_" + getattr(fn, "__name__", type(fn).__name__)
+
+
+def _abstract(x):
+    """The shape a jitted function is lowered from in place of ``x``: no
+    device buffer is kept."""
+    if isinstance(x, jax.Array):
+        # an uncommitted array (the eager split's key) goes where the
+        # program's other arguments are: no sharding of its own
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=x.sharding if x.committed else None,
+            weak_type=getattr(x, "weak_type", False))
+    return x
 
 
 def _tree_cast(tree, dtype):
@@ -345,6 +364,10 @@ class DeepSpeedEngine:
             self._init_onebit_tier()
 
         # ---- compiled programs --------------------------------------- #
+        # step programs launched so far -> the shapes of their first call
+        # (step_programs(); profiling/scope_map.py reads them)
+        self._launched = {}
+        scope_map.register(self)
         self._build_functions()
 
         # ---- fused whole-step program (off by default) --------------- #
@@ -1119,7 +1142,6 @@ class DeepSpeedEngine:
             self._fused_step_fn = fb["fn"]
             self._fused_step_raw = fb["raw"]
             self._fused_donate_argnums = fb["donate_argnums"]
-            self._fused_dispatch_label = fb["label"]
         log_dist(
             f"onebit tier: entering compressed phase at applied step "
             f"{ob['freeze_step']} (planned retrace: {planned}) — dense "
@@ -1140,7 +1162,6 @@ class DeepSpeedEngine:
                 self._fused_step_fn = fa["fn"]
                 self._fused_step_raw = fa["raw"]
                 self._fused_donate_argnums = fa["donate_argnums"]
-                self._fused_dispatch_label = fa["label"]
         log_dist("onebit tier: back to warmup phase (checkpoint load)",
                  ranks=[0])
 
@@ -1348,7 +1369,6 @@ class DeepSpeedEngine:
                 "fn": self._fused_step_fn,
                 "raw": self._fused_step_raw,
                 "donate_argnums": self._fused_donate_argnums,
-                "label": self._fused_dispatch_label,
             }
             progs["fused"] = build_fused_step(self, onebit={
                 "loss_and_grads": loss_and_grads,
@@ -1409,15 +1429,48 @@ class DeepSpeedEngine:
         return sub
 
     # ------------------------------------------------------------------ #
+    # tracing: the engine's spans and the programs it launched
+    # ------------------------------------------------------------------ #
+    def _span(self, name, **ids):
+        """``ds.<name>`` in the profiler's trace and, when the monitor's
+        Chrome export is on, on its step lane (monitor/trace.py)."""
+        return span(name, self.monitor, **ids)
+
+    def _micro_index(self):
+        """Which micro-batch of the accumulation window is under way."""
+        return self.micro_steps % self.gradient_accumulation_steps()
+
+    def _launch(self, fn, *args, **kwargs):
+        """Call the step program ``fn``.  The shapes of its first call
+        are kept, so that step_programs() can give its compiled text."""
+        if fn not in self._launched:
+            self._launched[fn] = jax.tree.map(_abstract, (args, kwargs))
+        return fn(*args, **kwargs)
+
+    def step_programs(self):
+        """[(name, text)] of the step programs this engine has launched:
+        ``name`` as the ``XLA Modules`` line of a device trace shows it
+        (``jit_loss_and_grads``, ``jit_accumulate``, ``jit_apply_step``,
+        ``jit_fused_step``, before the fingerprint in parentheses) and
+        ``text()`` the optimized HLO, ``compiled.as_text()``, lowered
+        from the shapes of the first call.  Nothing is lowered until
+        ``text`` is called; the compile cache serves it where one is on.
+        profiling/scope_map.py turns the text into (scope, phase) per
+        instruction."""
+        def text_of(fn, shapes):
+            return lambda: fn.lower(*shapes[0], **shapes[1]
+                                    ).compile().as_text()
+        return [(_program_name(fn), text_of(fn, shapes))
+                for fn, shapes in self._launched.items()]
+
+    # ------------------------------------------------------------------ #
     # forward / backward / step (reference: engine.py:1224,1303,1462)
     # ------------------------------------------------------------------ #
-    def forward(self, *args, **kwargs):
-        """Run the fused loss+grad program; returns the (unscaled) loss.
-
-        The gradient work rides along with forward (one compiled program)
-        instead of a separate autograd pass — backward() then only
-        accumulates.  This keeps the DeepSpeed call protocol while staying
-        single-dispatch on TPU."""
+    def _prepare_forward(self, args, kwargs):
+        """What forward() does to a micro-batch on the host before it
+        is placed: timers, curriculum truncation, progressive layer
+        drop, chaos, the retrace and monitor observations.  Returns
+        (args, kwargs)."""
         if (self._onebit is not None and self._onebit_phase == "warmup"
                 and self._cached_grads is None and self._grad_acc is None):
             # only at gas-window starts: a phase switch mid-window would
@@ -1461,61 +1514,69 @@ class DeepSpeedEngine:
         self._observe_retrace((args, kwargs))
         if self.monitor is not None:
             self._monitor_note_batch((args, kwargs))
-        batch = self._shard_batch((args, kwargs))
-        args, kwargs = batch
-        rng = self._next_rng()
-        fp_cfg = self.config.flops_profiler_config
-        profile_now = (fp_cfg.enabled and self._is_train_mode and
-                       self.global_steps == fp_cfg.profile_step and
-                       not getattr(self, "_flops_profiled", False))
-        if profile_now:
-            # reference: FlopsProfiler armed from forward at profile_step
-            # (engine.py:1231); here one jaxpr walk of the fused loss+grad
-            # program counts the whole step exactly.
-            from ..profiling import FlopsProfiler
-            prof = FlopsProfiler(config=fp_cfg)
-            prof.set_params(self.params)
-            prof.start_profile()
-            prof.profile_fn(self._grad_fn, self.params, self.scaler_state,
-                            rng, *args, **kwargs)
-        if (self.eigenvalue is not None and self.quantizer is not None
-                and self._is_train_mode):
-            # curvature probes re-run the loss on the latest TRAIN batch;
-            # no quantizer = no consumer, don't pin the batch
-            self._last_batch = (args, kwargs)
-        trace_on = self.monitor is not None and self.monitor.trace_active
-        if trace_on:
-            _tp0 = time.perf_counter()
-        grad_fn = self._grad_fn
-        if self._onebit is not None and self._onebit_phase == "compressed":
-            # compressed phase: local (unreduced) stacked grads — the
-            # dense allreduce left the program at the freeze boundary
-            grad_fn = self._onebit_programs["grad_fn"]
-        if self._moe_stats_enabled:
-            loss, grads, moe_stats = grad_fn(
-                self.params, self.scaler_state, rng, *args, **kwargs)
-            self._moe_note_stats(moe_stats)
-        else:
-            loss, grads = grad_fn(self.params, self.scaler_state,
-                                  rng, *args, **kwargs)
-        if trace_on:
-            # host DISPATCH window of the grad program (XLA executes
-            # asynchronously behind it) — the async-host-loop timeline
-            self.monitor.add_phase("grad_dispatch", _tp0,
-                                   step=self.global_steps + 1)
-        if profile_now:
-            jax.block_until_ready(loss)
-            prof.stop_profile()
-            prof.print_model_profile(profile_step=fp_cfg.profile_step,
-                                     detailed=fp_cfg.detailed,
-                                     output_file=fp_cfg.output_file)
-            self._flops_profiled = True
-            self.flops_profiler = prof
-        self._cached_grads = grads
-        self._last_loss = loss
-        if self.wall_clock_breakdown():
-            self.timers(FORWARD_MICRO_TIMER).stop()
-        return loss
+        return args, kwargs
+
+    def forward(self, *args, **kwargs):
+        """Run the fused loss+grad program; returns the (unscaled) loss.
+
+        The gradient work rides along with forward (one compiled program)
+        instead of a separate autograd pass — backward() then only
+        accumulates.  This keeps the DeepSpeed call protocol while staying
+        single-dispatch on TPU."""
+        with self._span("forward", step=self.global_steps + 1,
+                        micro=self._micro_index()):
+            with self._span("forward.prepare"):
+                args, kwargs = self._prepare_forward(args, kwargs)
+            with self._span("forward.shard_batch"):
+                args, kwargs = self._shard_batch((args, kwargs))
+            with self._span("forward.rng"):
+                rng = self._next_rng()
+            fp_cfg = self.config.flops_profiler_config
+            profile_now = (fp_cfg.enabled and self._is_train_mode and
+                           self.global_steps == fp_cfg.profile_step and
+                           not getattr(self, "_flops_profiled", False))
+            if profile_now:
+                # reference: FlopsProfiler armed from forward at profile_step
+                # (engine.py:1231); here one jaxpr walk of the fused loss+grad
+                # program counts the whole step exactly.
+                from ..profiling import FlopsProfiler
+                prof = FlopsProfiler(config=fp_cfg)
+                prof.set_params(self.params)
+                prof.start_profile()
+                prof.profile_fn(self._grad_fn, self.params, self.scaler_state,
+                                rng, *args, **kwargs)
+            if (self.eigenvalue is not None and self.quantizer is not None
+                    and self._is_train_mode):
+                # curvature probes re-run the loss on the latest TRAIN batch;
+                # no quantizer = no consumer, don't pin the batch
+                self._last_batch = (args, kwargs)
+            grad_fn = self._grad_fn
+            if self._onebit is not None and self._onebit_phase == "compressed":
+                # compressed phase: local (unreduced) stacked grads — the
+                # dense allreduce left the program at the freeze boundary
+                grad_fn = self._onebit_programs["grad_fn"]
+            with self._span("forward.dispatch",
+                            program=_program_name(grad_fn)):
+                out = self._launch(grad_fn, self.params, self.scaler_state,
+                                   rng, *args, **kwargs)
+            if self._moe_stats_enabled:
+                loss, grads, moe_stats = out
+                self._moe_note_stats(moe_stats)
+            else:
+                loss, grads = out
+            if profile_now:
+                jax.block_until_ready(loss)
+                prof.stop_profile()
+                prof.print_model_profile(profile_step=fp_cfg.profile_step,
+                                         detailed=fp_cfg.detailed,
+                                         output_file=fp_cfg.output_file)
+                self._flops_profiled = True
+                self.flops_profiler = prof
+            self._cached_grads = grads
+            self._last_loss = loss
+            if self.wall_clock_breakdown():
+                self.timers(FORWARD_MICRO_TIMER).stop()
+            return loss
 
     __call__ = forward
 
@@ -1526,28 +1587,27 @@ class DeepSpeedEngine:
         program (XLA collective), so this is purely the GAS accumulation."""
         assert self._cached_grads is not None, \
             "backward() called before forward()"
-        if self.wall_clock_breakdown():
-            self.timers(BACKWARD_MICRO_TIMER).start()
-        trace_on = self.monitor is not None and self.monitor.trace_active
-        if trace_on:
-            _tp0 = time.perf_counter()
-        if self._grad_acc is None:
-            self._grad_acc = self._cached_grads
-        else:
-            acc_fn = self._acc_fn
-            if self._onebit is not None and \
-                    self._onebit_phase == "compressed":
-                # stacked [W, ...] leaves need the stacked out-sharding
-                acc_fn = self._onebit_programs["acc_fn"]
-            self._grad_acc = acc_fn(self._grad_acc, self._cached_grads)
-        if trace_on:
-            self.monitor.add_phase("accumulate_dispatch", _tp0,
-                                   step=self.global_steps + 1)
-        self._cached_grads = None
-        self.micro_steps += 1
-        if self.wall_clock_breakdown():
-            self.timers(BACKWARD_MICRO_TIMER).stop()
-        return loss if loss is not None else self._last_loss
+        with self._span("backward", step=self.global_steps + 1,
+                        micro=self._micro_index()):
+            if self.wall_clock_breakdown():
+                self.timers(BACKWARD_MICRO_TIMER).start()
+            if self._grad_acc is None:
+                self._grad_acc = self._cached_grads
+            else:
+                acc_fn = self._acc_fn
+                if self._onebit is not None and \
+                        self._onebit_phase == "compressed":
+                    # stacked [W, ...] leaves need the stacked out-sharding
+                    acc_fn = self._onebit_programs["acc_fn"]
+                with self._span("backward.dispatch",
+                                program=_program_name(acc_fn)):
+                    self._grad_acc = self._launch(acc_fn, self._grad_acc,
+                                                  self._cached_grads)
+            self._cached_grads = None
+            self.micro_steps += 1
+            if self.wall_clock_breakdown():
+                self.timers(BACKWARD_MICRO_TIMER).stop()
+            return loss if loss is not None else self._last_loss
 
     def step(self, lr_kwargs=None):
         """Apply the optimizer at gradient-accumulation boundaries
@@ -1555,6 +1615,10 @@ class DeepSpeedEngine:
         if not self.is_gradient_accumulation_boundary():
             return
         assert self._grad_acc is not None, "step() called before backward()"
+        with self._span("step", step=self.global_steps + 1):
+            self._take_step(lr_kwargs)
+
+    def _take_step(self, lr_kwargs):
         if self.wall_clock_breakdown():
             self.timers(STEP_MICRO_TIMER).start()
 
@@ -1577,34 +1641,43 @@ class DeepSpeedEngine:
                 return
             sentinel_skip = verdict == "skip"
 
-        trace_on = self.monitor is not None and self.monitor.trace_active
-        if trace_on:
-            _tp0 = time.perf_counter()
         if self._offload_enabled:
             # host-side optimizer: a sentinel skip simply never runs it
-            overflow = False if sentinel_skip else self._offload_step()
+            with self._span("step.dispatch", program="offload_step"):
+                overflow = False if sentinel_skip else self._offload_step()
         elif self._onebit is not None and self._onebit_phase == "compressed":
             # compressed-phase apply: momentum sync on the packed wire;
             # the wire-error state threads through as a donated arg, and
             # the sentinel verdict rides the same healthy flag as the
             # dense path (always passed — one program, both postures)
-            (self.params, self.opt_state, self.scaler_state, overflow,
-             self._onebit_wire_error) = self._onebit_programs["apply_fn"](
-                self.params, self.opt_state, self.scaler_state,
-                self._grad_acc, self._onebit_wire_error,
-                jnp.asarray(not sentinel_skip))
-        elif self.sentinel is not None:
-            (self.params, self.opt_state, self.scaler_state,
-             overflow) = self._apply_fn(self.params, self.opt_state,
-                                        self.scaler_state, self._grad_acc,
-                                        jnp.asarray(not sentinel_skip))
+            apply_fn = self._onebit_programs["apply_fn"]
+            with self._span("step.dispatch",
+                            program=_program_name(apply_fn)):
+                (self.params, self.opt_state, self.scaler_state, overflow,
+                 self._onebit_wire_error) = self._launch(
+                    apply_fn, self.params, self.opt_state,
+                    self.scaler_state, self._grad_acc,
+                    self._onebit_wire_error, jnp.asarray(not sentinel_skip))
         else:
-            (self.params, self.opt_state, self.scaler_state,
-             overflow) = self._apply_fn(self.params, self.opt_state,
-                                        self.scaler_state, self._grad_acc)
-        if trace_on:
-            self.monitor.add_phase("apply_dispatch", _tp0,
-                                   step=self.global_steps + 1)
+            # the sentinel's verdict rides the apply's healthy flag
+            verdict = (() if self.sentinel is None
+                       else (jnp.asarray(not sentinel_skip),))
+            with self._span("step.dispatch",
+                            program=_program_name(self._apply_fn)):
+                (self.params, self.opt_state, self.scaler_state,
+                 overflow) = self._launch(
+                    self._apply_fn, self.params, self.opt_state,
+                    self.scaler_state, self._grad_acc, *verdict)
+        with self._span("step.bookkeeping"):
+            self._after_apply(overflow, sentinel_skip, lr_kwargs)
+        if self.wall_clock_breakdown():
+            self.timers(STEP_MICRO_TIMER).stop()
+        self._maybe_handle_preemption()
+
+    def _after_apply(self, overflow, sentinel_skip, lr_kwargs):
+        """The host's share of an optimizer step once the apply program
+        is dispatched: counters, scheduler, quantizer, monitor record,
+        boundary logging."""
         self._grad_acc = None
         self._last_overflow = overflow
         self.global_steps += 1
@@ -1669,9 +1742,6 @@ class DeepSpeedEngine:
                                   grad_norm=getattr(
                                       self, "_last_grad_norm_host", None))
         self._boundary_logging()
-        if self.wall_clock_breakdown():
-            self.timers(STEP_MICRO_TIMER).stop()
-        self._maybe_handle_preemption()
 
     def _boundary_logging(self):
         """Coalesced host reads: the loss fetch (`float(self._last_loss)`),
@@ -2272,7 +2342,8 @@ class DeepSpeedEngine:
                 raise ValueError("train_batch needs data_iter or training_data")
             data_iter = iter(self.training_dataloader)
         if self._fused_step_fn is not None and self._is_train_mode:
-            return self._fused_train_batch(data_iter)
+            with self._span("train_batch", step=self.global_steps + 1):
+                return self._fused_train_batch(data_iter)
         losses = []
         for _ in range(self.gradient_accumulation_steps()):
             batch = next(data_iter)
@@ -2311,23 +2382,24 @@ class DeepSpeedEngine:
         self._observe_retrace(stacked)
         args = self._shard_stacked_batch(stacked)
         rng = self._next_rng()
-        trace_on = self.monitor is not None and self.monitor.trace_active
-        if trace_on:
-            _tp0 = time.perf_counter()
-        if self._onebit is not None and self._onebit_phase == "compressed":
-            # compressed-phase fused program threads the wire-error state
-            # through as a donated carry (fused_step.py onebit build)
-            (self.params, self.opt_state, self.scaler_state,
-             self._fused_sent_state, self._onebit_wire_error, loss,
-             overflow, sent_flags) = self._fused_step_fn(
-                self.params, self.opt_state, self.scaler_state,
-                self._fused_sent_state, self._onebit_wire_error, rng,
-                args, {})
-            fused_out = None
-        else:
-            fused_out = self._fused_step_fn(
-                self.params, self.opt_state, self.scaler_state,
-                self._fused_sent_state, rng, args, {})
+        with self._span("train_batch.dispatch", step=self.global_steps + 1,
+                        program=_program_name(self._fused_step_fn)):
+            if self._onebit is not None and \
+                    self._onebit_phase == "compressed":
+                # compressed-phase fused program threads the wire-error
+                # state through as a donated carry (fused_step.py onebit
+                # build)
+                (self.params, self.opt_state, self.scaler_state,
+                 self._fused_sent_state, self._onebit_wire_error, loss,
+                 overflow, sent_flags) = self._launch(
+                    self._fused_step_fn, self.params, self.opt_state,
+                    self.scaler_state, self._fused_sent_state,
+                    self._onebit_wire_error, rng, args, {})
+                fused_out = None
+            else:
+                fused_out = self._launch(
+                    self._fused_step_fn, self.params, self.opt_state,
+                    self.scaler_state, self._fused_sent_state, rng, args, {})
         if fused_out is None:
             pass
         elif self._moe_stats_enabled:
@@ -2340,10 +2412,6 @@ class DeepSpeedEngine:
             (self.params, self.opt_state, self.scaler_state,
              self._fused_sent_state, loss, overflow,
              sent_flags) = fused_out
-        if trace_on:
-            self.monitor.add_phase(
-                getattr(self, "_fused_dispatch_label", "fused_dispatch"),
-                _tp0, step=self.global_steps + 1)
         self._last_loss = loss
         self._last_overflow = overflow
         self.micro_steps += gas

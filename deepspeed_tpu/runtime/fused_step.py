@@ -285,7 +285,6 @@ def build_fused_step(engine, onebit=None):
                           donate_argnums=donate),
             "raw": fused_step_onebit,
             "donate_argnums": donate,
-            "label": f"fused_step(gas={gas},onebit)",
         }
     # The un-jitted body, the donation facts, and the scan structure are
     # recorded on the engine for the Program Auditor (analysis/
@@ -294,11 +293,9 @@ def build_fused_step(engine, onebit=None):
     engine._fused_step_raw = fused_step
     engine._fused_donate_argnums = (0, 1)
     engine._fused_scan_info = {"gas_scan_length": gas}
-    # telemetry provenance (monitor/record.py dispatches_per_step; the
-    # trace exporter labels the whole-window span with this): the fused
-    # path is ONE dispatch where the modular loop issues 2*gas
+    # telemetry provenance (monitor/record.py dispatches_per_step): the
+    # fused path is ONE dispatch where the modular loop issues 2*gas
     engine._dispatches_per_step = 1
-    engine._fused_dispatch_label = f"fused_step(gas={gas})"
     out_shardings = (engine.param_shardings, engine.opt_shardings,
                      replicated, sent_shardings, replicated, replicated,
                      (replicated, replicated))

@@ -152,9 +152,10 @@ def test_trace_export_validates_and_has_step_phases(tmp_path):
     assert validate_trace_events(payload) == []
     events = payload["traceEvents"]
     names = {e["name"] for e in events}
-    # modular path: grad/accumulate/apply dispatch spans per step
-    assert "grad_dispatch" in names
-    assert "apply_dispatch" in names
+    # modular path: the engine's own spans (monitor/trace.py span), the
+    # grad and apply dispatch windows among them
+    assert "ds.forward.dispatch" in names
+    assert "ds.step.dispatch" in names
     x_events = [e for e in events if e["ph"] == "X"]
     assert all(e["dur"] >= 0 for e in x_events)
     steps = {e.get("args", {}).get("step") for e in x_events}
