@@ -46,7 +46,7 @@ train step wraps this layer scan in an OUTER ``lax.scan`` over the
 microbatch axis.  No special casing is needed here, but the composition
 leans on an invariant of this file: gathered layer groups are NEVER saved
 as residuals.  In ``carried`` mode that is structural — the hand-written
-VJP's residuals are the group-boundary activation carries plus the
+VJP's residuals are each layer's input activation carry plus the
 sharded inputs, and the backward re-gathers (``_build_carried_stream``);
 in ``unrolled``/``off`` modes the ``zero3_gathered`` checkpoint-name
 policy (see ``gather_group``) does the same job through the remat
@@ -275,6 +275,11 @@ def _index_tree(tree, i):
         lambda leaf: lax.dynamic_index_in_dim(leaf, i, keepdims=False), tree)
 
 
+def _layer_of(full, extras, j):
+    """Layer ``j``'s leaves and extras out of one ``[g, ...]`` group."""
+    return [leaf[j] for leaf in full], jax.tree.map(lambda e: e[j], extras)
+
+
 def _body_closes_over_tracers(body) -> bool:
     """True when the user body (or a callable it closes over, two levels
     deep) captures live JAX tracers.  NO streaming mode differentiates
@@ -314,7 +319,7 @@ def _body_closes_over_tracers(body) -> bool:
     return check(body, 0)
 
 
-def _build_carried_stream(steps: int, gather_group, run_group,
+def _build_carried_stream(steps: int, g: int, gather_group, run_layer,
                           scatter_grads):
     """Carried double-buffer executor with a hand-scheduled VJP.
 
@@ -347,20 +352,27 @@ def _build_carried_stream(steps: int, gather_group, run_group,
     double buffer, so the backward's wire hides under the backward's
     compute exactly like the forward's.
 
-    The residuals saved are the per-group INPUT activation carries (the
-    forward scan's ys) plus the sharded inputs; each group's internal
-    activations are rematerialized inside its backward step (``jax.vjp``
-    re-runs ``run_group`` from the saved carry).  That is one extra
-    forward pass of the layer stack per step — the deliberate trade for
-    taking BOTH directions' gathers off the critical path while keeping
-    peak gathered memory at ``2 x layers_per_step x params_per_layer``
-    (models running remat anyway, e.g. ``activation_checkpointing``,
-    were already paying it).
+    The residuals saved are the INPUT activation carry of every LAYER —
+    the forward scan's ``ys``, ``[S-1, g, ...]`` per carry leaf, and the
+    epilogue group's ``[g, ...]``: ``num_layers`` carries of one
+    batch-shard activation each — plus the sharded inputs.  That is what
+    a plain
+    ``lax.scan`` over ``jax.checkpoint(body)`` saves, so the streamed and
+    the non-streamed engine paths hold the same thing.  The backward
+    takes each layer's VJP from its own saved carry, so each layer's
+    internals are recomputed ONCE: two layer forwards a step for every
+    group size, whether or not ``body`` is itself checkpointed (a
+    checkpointed body's ``jax.vjp`` has no live primal output and the
+    rematerialization is its one recomputation; a plain body's
+    ``jax.vjp`` runs it once and keeps one layer's internals at a
+    time).  Peak gathered memory stays at ``2 x layers_per_step x
+    params_per_layer``.
 
     ``steps`` must be >= 2 (the plan guarantees it in carried mode).
-    ``gather_group(shards) -> full``, ``run_group(act, full, extras) ->
-    act`` and ``scatter_grads(g_full) -> g_shards`` (the exact transpose
-    of ``gather_group``'s wire, qwZ/qgZ aware) come from the enclosing
+    ``gather_group(shards) -> full``, ``run_layer(act, layer_leaves,
+    layer_extras) -> act`` for ONE of a group's ``g`` layers and
+    ``scatter_grads(g_full) -> g_shards`` (the exact transpose of
+    ``gather_group``'s wire, qwZ/qgZ aware) come from the enclosing
     :meth:`Zero3StreamContext.scan` trace.
     """
 
@@ -372,6 +384,39 @@ def _build_carried_stream(steps: int, gather_group, run_group,
     # a list of leaves is a pytree: _index_tree slices shard groups too
     _group_shards = _index_tree
 
+    def _set_row(stacked, leaves, j):
+        """Layer ``j``'s leaves into ``[g, ...]`` stacks, written as the
+        backward yields them (last layer first)."""
+        if stacked is None:
+            stacked = [jnp.zeros((g,) + leaf.shape, leaf.dtype)
+                       for leaf in leaves]
+        return [buf.at[j].set(leaf) for buf, leaf in zip(stacked, leaves)]
+
+    def _finish_rows(g_c, stacked):
+        """This layer's rows are written before the next layer's backward
+        (which needs ``g_c``) starts.  Left free, XLA fuses each
+        weight-gradient matmul into its row update and sinks the chain
+        to the group's end, every layer's recomputed activations live
+        until then (+7 GB of temporaries for GPT-2 XL at g=16).  A
+        barrier gives all it holds one varying-axes type, so under
+        ``check_vma`` it holds the stacks of ``g_c``'s type only."""
+        vma = jax.typeof(jax.tree.leaves(g_c)[0]).vma
+        held = [jax.typeof(buf).vma == vma for buf in stacked]
+        g_c, out = lax.optimization_barrier(
+            (g_c, [buf for buf, h in zip(stacked, held) if h]))
+        out = iter(out)
+        return g_c, [next(out) if h else buf
+                     for buf, h in zip(stacked, held)]
+
+    def _run_group(c, full, extras_i):
+        """A gathered group's ``g`` layers.  Returns the output carry and
+        the layers' INPUT carries, stacked ``[g, ...]``."""
+        ins = []
+        for j in range(g):
+            ins.append(c)
+            c = run_layer(c, *_layer_of(full, extras_i, j))
+        return c, jax.tree.map(lambda *leaves: jnp.stack(leaves), *ins)
+
     def _forward(c0, params_g, extras_g):
         first = gather_group(_group_shards(params_g, 0))
 
@@ -381,26 +426,28 @@ def _build_carried_stream(steps: int, gather_group, run_group,
             # consumed next iteration (carried), so its wire has the
             # whole group's MXU work as slack
             nxt = gather_group(_group_shards(params_g, i + 1))
-            c_out = run_group(c, cur, _index_tree(extras_g, i))
-            return (c_out, nxt), c
+            c, ins = _run_group(c, cur, _index_tree(extras_g, i))
+            return (c, nxt), ins
 
-        (c_pen, last), c_ins = lax.scan(
+        (c, last), c_ins = lax.scan(
             fbody, (c0, first), jnp.arange(steps - 1))
-        c_fin = run_group(c_pen, last, _index_tree(extras_g, steps - 1))
-        return c_fin, (c_pen, c_ins)
+        c_fin, c_ins_last = _run_group(
+            c, last, _index_tree(extras_g, steps - 1))
+        return c_fin, (c_ins, c_ins_last)
 
     @jax.custom_vjp
     def carried(c0, params_g, extras_g):
         return _forward(c0, params_g, extras_g)[0]
 
     def carried_fwd(c0, params_g, extras_g):
-        c_fin, (c_pen, c_ins) = _forward(c0, params_g, extras_g)
-        # residuals: group-boundary activation carries (c_ins[0] IS c0)
-        # + the SHARDED inputs — never a gathered buffer
-        return c_fin, (c_pen, c_ins, params_g, extras_g)
+        c_fin, saved = _forward(c0, params_g, extras_g)
+        # residuals: every layer's input carry (the scan's ys for groups
+        # 0 .. S-2, the epilogue group's own stack; c_ins[0, 0] IS c0) +
+        # the SHARDED inputs — never a gathered buffer
+        return c_fin, (saved, params_g, extras_g)
 
     def carried_bwd(res, g_out):
-        c_pen, c_ins, params_g, extras_g = res
+        (c_ins, c_ins_last), params_g, extras_g = res
         ex_leaves = jax.tree.leaves(extras_g)
         ex_tree = jax.tree.structure(extras_g)
         is_float = [jnp.issubdtype(leaf.dtype, jnp.inexact)
@@ -410,36 +457,49 @@ def _build_carried_stream(steps: int, gather_group, run_group,
             return [leaf for leaf, f in zip(jax.tree.leaves(g_ex), is_float)
                     if f]
 
-        def group_vjp(c_in, full, ex_i, g_c):
-            _, vjp_fn = jax.vjp(run_group, c_in, full, ex_i)
-            return vjp_fn(g_c)
+        def group_vjp(i, carry_in, full, g_c):
+            """Group ``i``'s layers in reverse, each from its own saved
+            carry ``carry_in(j)``; per-layer cotangents restacked to the
+            ``[g, ...]`` leaves ``scatter_grads`` (and the extras) take."""
+            extras_i = _index_tree(extras_g, i)
+            g_full = g_ex = None
+            for j in reversed(range(g)):
+                _, vjp_fn = jax.vjp(run_layer, carry_in(j),
+                                    *_layer_of(full, extras_i, j))
+                g_c, g_layer, g_ex_j = vjp_fn(g_c)
+                g_full = _set_row(g_full, g_layer, j)
+                g_ex = _set_row(g_ex, float_only(g_ex_j), j)
+                g_c, g_full = _finish_rows(g_c, g_full)
+            return g_c, g_full, g_ex
+
+        def saved_carry(stack, *group):
+            """``j -> `` layer ``j``'s carry out of a stack of them, one
+            slice each (no ``[g, ...]`` group copy)."""
+            return lambda j: jax.tree.map(lambda buf: buf[group + (j,)],
+                                          stack)
 
         # group S-1: backward re-fetch, with S-2's re-gather issued
         # BEFORE the transposed compute (the backward's own prologue
         # double buffer)
         full_last = gather_group(_group_shards(params_g, steps - 1))
         full_prev = gather_group(_group_shards(params_g, steps - 2))
-        g_c, g_full, g_ex = group_vjp(
-            c_pen, full_last, _index_tree(extras_g, steps - 1), g_out)
+        g_c, g_full, g_ex_last = group_vjp(
+            steps - 1, saved_carry(c_ins_last), full_last, g_out)
         g_sh_last = scatter_grads(g_full)
-        g_ex_last = float_only(g_ex)
 
         def bbody(carry, i):
             g_c, cur = carry
             nxt = gather_group(_group_shards(params_g, i - 1))
-            g_c, g_full, g_ex = group_vjp(
-                _index_tree(c_ins, i), cur, _index_tree(extras_g, i), g_c)
-            return (g_c, nxt), (scatter_grads(g_full), float_only(g_ex))
+            g_c, g_full, g_ex = group_vjp(i, saved_carry(c_ins, i), cur, g_c)
+            return (g_c, nxt), (scatter_grads(g_full), g_ex)
 
         (g_c, cur0), (g_sh_mid, g_ex_mid) = lax.scan(
             bbody, (g_c, full_prev), jnp.arange(1, steps - 1),
             reverse=True)
 
         # group 0: consumes the last carried re-gather
-        g_c0, g_full, g_ex = group_vjp(
-            _index_tree(c_ins, 0), cur0, _index_tree(extras_g, 0), g_c)
+        g_c0, g_full, g_ex0 = group_vjp(0, saved_carry(c_ins, 0), cur0, g_c)
         g_sh0 = scatter_grads(g_full)
-        g_ex0 = float_only(g_ex)
 
         g_params = [jnp.concatenate([a[None], mid, b[None]], axis=0)
                     for a, mid, b in zip(g_sh0, g_sh_mid, g_sh_last)]
@@ -705,11 +765,22 @@ class Zero3StreamContext:
                 lb = (f", low_bandwidth: qwz={self.lbc.qwz_bits}b "
                       f"qgz={self.lbc.qgz_bits}b hpz={hpz}"
                       f"{' fcm' if self.fcm else ''}")
+            saved = ""
+            if plan.mode == "carried":
+                # the carried VJP's residuals: every layer's input carry
+                zero_world = int(np.prod(
+                    [self.axis_sizes[a] for a in self.manual]))
+                carry_bytes = sum(
+                    int(np.prod(leaf.shape)) * jnp.dtype(leaf.dtype).itemsize
+                    for leaf in jax.tree.leaves(init_carry)) // zero_world
+                saved = (f", backward saves {plan.num_layers} layer-input "
+                         f"carries ({plan.num_layers * carry_bytes:,} B "
+                         f"per shard)")
             log_dist(
                 f"ZeRO-3 streaming: {plan.num_layers} layers in groups of "
                 f"{plan.layers_per_step}, prefetch={plan.prefetch} "
                 f"(mode={plan.mode}), live<= {plan.live_parameters:,} "
-                f"params (max_live={self.max_live_parameters:,}){lb}",
+                f"params (max_live={self.max_live_parameters:,}){saved}{lb}",
                 ranks=[0])
             if plan.forfeited:
                 log_dist(
@@ -808,13 +879,11 @@ class Zero3StreamContext:
                 full.append(checkpoint_name(leaf, "zero3_gathered"))
             return full
 
-        def run_group(carry, full_group, extras_group):
-            """Unrolled pass over the g layers inside one gathered group."""
-            for j in range(g):
-                layer = p_tree.unflatten(
-                    [leaf[j] for leaf in full_group])
-                extras_j = jax.tree.map(lambda e: e[j], extras_group)
-                carry, _ = body(carry, (layer,) + tuple(extras_j))
+        def run_layer(carry, layer_leaves, layer_extras):
+            """One layer of a gathered group: leaves and extras without
+            the group dimension."""
+            carry, _ = body(carry, (p_tree.unflatten(layer_leaves),) +
+                            tuple(layer_extras))
             return carry
 
         if plan.mode == "carried":
@@ -867,8 +936,8 @@ class Zero3StreamContext:
                     out.append(gk)
                 return out
 
-            carried = _build_carried_stream(steps, gather_group,
-                                            run_group, scatter_grads)
+            carried = _build_carried_stream(steps, g, gather_group,
+                                            run_layer, scatter_grads)
 
             def region_fn(carry, params_grouped, extras_grouped):
                 return carried(carry, params_grouped, extras_grouped)
@@ -876,7 +945,9 @@ class Zero3StreamContext:
             def step(c, xs):
                 shards, extras_g = xs
                 full = gather_group(shards)
-                return run_group(c, full, extras_g), None
+                for j in range(g):  # unrolled over the gathered group
+                    c = run_layer(c, *_layer_of(full, extras_g, j))
+                return c, None
 
             # Save every intermediate EXCEPT the gathered params:
             # activations are stored as usual (no recompute tax), only the

@@ -137,7 +137,7 @@ def test_fused_matches_modular_fp16_overflow_skip():
                   "stage3_prefetch_bucket_size": 0}, id="at_use"),
     # carried double-buffer prefetch nested INSIDE the fused gas scan
     # (scan-in-scan-in-scan): the hand-written VJP's residuals are the
-    # group-boundary carries, so the outer scan never stacks gathered
+    # layers' input carries, so the outer scan never stacks gathered
     # groups across microbatches (ISSUE 7)
     pytest.param({"stage3_max_live_parameters": 100_000,
                   "stage3_prefetch_bucket_size": 100_000,

@@ -313,32 +313,50 @@ def test_zero3_bf16_streams_on_cpu():
     assert losses[-1] < losses[0]
 
 
-def _train_tiny(zero_cfg, bf16=False, num_layers=5, steps=2,
-                mesh_axes=None, seed_ids=1):
-    """Fast trainer for the prefetch-mode parity matrix: tiny model, two
-    steps, losses + final params.  Modes are compared against each other
-    (same gather/quantization structure), so tolerances stay tight."""
+def _tiny_ids(seed=1):
+    return np.asarray(jax.random.randint(jax.random.PRNGKey(seed), (8, 16),
+                                         0, 64), np.int32)
+
+
+def _tiny_engine(zero_cfg, bf16=False, num_layers=5, mesh_axes=None,
+                 pld=False, dropout=0.0, checkpointing=False,
+                 optimizer="Adam"):
+    """Tiny GPT-2 under an engine on a fresh mesh.  Returns (engine,
+    model); the caller resets the mesh context."""
     ds.reset_mesh_context()
     mesh = ds.initialize_mesh(**(mesh_axes or {"data": -1}))
     cfg = GPT2Config(vocab_size=64, n_positions=16, hidden_size=32,
                      num_layers=num_layers, num_heads=4, bf16=bf16,
-                     embd_dropout=0.0, attn_dropout=0.0, hidden_dropout=0.0)
+                     embd_dropout=dropout, attn_dropout=dropout,
+                     hidden_dropout=dropout,
+                     activation_checkpointing=checkpointing)
     model = GPT2Model(cfg)
     conf = {
         "train_micro_batch_size_per_gpu": 8 // mesh.data_parallel_world_size
         if mesh.data_parallel_world_size <= 8 else 1,
-        "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
+        "optimizer": {"type": optimizer, "params": {"lr": 1e-3}},
         "zero_optimization": zero_cfg,
         "steps_per_print": 10 ** 9,
     }
     if bf16:
         conf["bf16"] = {"enabled": True}
+    if pld:
+        # theta(t) = 0.5 + 0.5 exp(-gamma t): 1 at step 0, 0.5 from step 1
+        conf["progressive_layer_drop"] = {"enabled": True, "theta": 0.5,
+                                          "gamma": 5.0}
     engine, _, _, _ = ds.initialize(
         model=model, config=conf,
         model_parameters=model.init_params(jax.random.PRNGKey(0)),
         mesh=mesh, rng=jax.random.PRNGKey(7))
-    ids = np.asarray(jax.random.randint(jax.random.PRNGKey(seed_ids),
-                                        (8, 16), 0, 64), np.int32)
+    return engine, model
+
+
+def _train_tiny(zero_cfg, steps=2, seed_ids=1, **engine_kw):
+    """Fast trainer for the prefetch-mode parity matrix: tiny model, two
+    steps, losses + final params.  Modes are compared against each other
+    (same gather/quantization structure), so tolerances stay tight."""
+    engine, _ = _tiny_engine(zero_cfg, **engine_kw)
+    ids = _tiny_ids(seed_ids)
     losses = []
     for _ in range(steps):
         loss = engine.forward(ids)
@@ -358,6 +376,15 @@ def _mode_cfg(mode, extra=None):
            "stage3_prefetch_mode": mode}
     cfg.update(extra or {})
     return cfg
+
+
+def _group_cfg(mode, layers_per_step):
+    """``_mode_cfg`` whose plan comes out at ``layers_per_step`` (12,832
+    parameters a layer; carried halves the live budget for its double
+    buffer)."""
+    live = layers_per_step * 12832 * (2 if mode == "carried" else 1)
+    return _mode_cfg(mode, {"stage3_max_live_parameters": live,
+                            "stage3_prefetch_bucket_size": live})
 
 
 @pytest.mark.parametrize("mode", ["carried", "unrolled"])
@@ -521,3 +548,131 @@ def test_stream_context_rejects_misaligned_hpz():
                            low_bandwidth=ZeroLowBandwidthConfig(
                                hpz_group_size=3))
     ds.reset_mesh_context()
+
+
+# -- the carried VJP saves one carry a LAYER and recomputes each layer once -- #
+
+def _weighted_prim_count(jaxpr, name, weight=1):
+    """Occurrences of primitive ``name`` in ``jaxpr`` and every jaxpr
+    riding in its equations' params, each weighted by the ``length`` of
+    the scans around it — how often the primitive RUNS in one call."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            total += weight
+        inner_w = weight * (eqn.params["length"]
+                            if eqn.primitive.name == "scan" else 1)
+        for v in eqn.params.values():
+            for sub in jax.tree.leaves(
+                    v, is_leaf=lambda x: hasattr(x, "jaxpr") or
+                    hasattr(x, "eqns")):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    total += _weighted_prim_count(inner, name, inner_w)
+    return total
+
+
+@pytest.mark.parametrize("num_layers,g,checkpointing", [
+    (8, 4, True), (8, 2, True), (8, 1, True), (8, 4, False)])
+def test_carried_stream_runs_each_layer_forward_twice(num_layers, g,
+                                                      checkpointing):
+    """A step runs every layer forward TWICE under the carried stream —
+    once in the forward scan, once for its own backward — for every
+    group size, with or without ``activation_checkpointing``.  With
+    group-boundary residuals the backward's ``jax.vjp`` of a whole group
+    ran the group forward a THIRD time to rebuild the checkpointed
+    layers' input carries: 3L - S forwards (22 and 20 in the first two
+    cases).  Counted as the ``tanh`` of gelu (one per layer forward) on
+    the DCE'd grad jaxpr, weighted by scan lengths; the compiled CPU
+    program cannot show it (the CPU backend drops ``jax.checkpoint``'s
+    optimisation barriers and merges the recomputations, the TPU keeps
+    them)."""
+    from jax.interpreters import partial_eval as pe
+
+    engine, model = _tiny_engine(_group_cfg("carried", g),
+                                 num_layers=num_layers,
+                                 checkpointing=checkpointing)
+    ids = _tiny_ids()
+    closed = jax.make_jaxpr(jax.grad(
+        lambda p: model.loss(p, jax.random.PRNGKey(3), ids)))(engine.params)
+    plan = engine._zero3_stream.last_plan
+    assert plan.mode == "carried" and plan.layers_per_step == g
+    jaxpr, _ = pe.dce_jaxpr(closed.jaxpr, [True] * len(closed.jaxpr.outvars))
+    assert _weighted_prim_count(jaxpr, "tanh") == 2 * num_layers
+    ds.reset_mesh_context()
+
+
+def test_carried_residuals_are_per_layer_carries(monkeypatch):
+    """The carried VJP saves every layer's input carry — ``[S-1, g, ...]``
+    per carry leaf from the forward scan and ``[g, ...]`` from the
+    epilogue group, ``S x g`` carries in all — beside the SHARDED inputs,
+    and never a gathered (``zero3_gathered``) buffer, which would stack
+    the unsharded model."""
+    from deepspeed_tpu.runtime.zero import stage3_streaming as s3
+
+    seen = {}
+    build = s3._build_carried_stream
+
+    def recording_build(steps, g, gather_group, *rest):
+        def recording_gather(shards):
+            full = gather_group(shards)
+            seen["gathered"] = [leaf.shape for leaf in full]
+            return full
+
+        carried = build(steps, g, recording_gather, *rest)
+        fwd = carried.fwd
+
+        def recording_fwd(*args):
+            out, res = fwd(*args)
+            seen["steps_g"] = (steps, g)
+            seen["carry"] = jax.tree.leaves(args[0])[0].shape
+            seen["res"] = [leaf.shape for leaf in jax.tree.leaves(res)]
+            return out, res
+
+        carried.fwd = recording_fwd
+        return carried
+
+    monkeypatch.setattr(s3, "_build_carried_stream", recording_build)
+    engine, model = _tiny_engine(_group_cfg("carried", 2), num_layers=8,
+                                 checkpointing=True)
+    ids = _tiny_ids()
+    jax.make_jaxpr(jax.grad(
+        lambda p: model.loss(p, jax.random.PRNGKey(3), ids)))(engine.params)
+    assert seen["steps_g"] == (4, 2)
+    # the saved carries: leading [S-1, g] and [g], then one batch-shard
+    # activation: S x g = num_layers of them
+    assert seen["res"][:2] == [(3, 2) + seen["carry"], (2,) + seen["carry"]]
+    assert not any(shape[-3:] == seen["carry"] for shape in seen["res"][2:])
+    # nothing of a gathered group's shape ([g, full dims]) is saved; the
+    # sharded inputs keep their [S, g, local dims] form.  Leaves too small
+    # to shard gather to their own shape and say nothing either way.
+    full = set(seen["gathered"])
+    sharded = {shape[1:] for shape in seen["res"][2:]}
+    assert full - sharded, (full, sharded)
+    assert not any(shape in full - sharded for shape in seen["res"])
+    ds.reset_mesh_context()
+
+
+@pytest.mark.parametrize("pld", [False, True], ids=["dropout", "dropout+pld"])
+def test_carried_parity_with_checkpointing_and_dropout(pld):
+    """Carried against ``off`` with ``activation_checkpointing`` on, g > 1
+    and the three dropouts at 0.1: the per-layer backward must hand every
+    layer's recomputation the rng (and the PLD keep-probability and key)
+    its forward drew, or the masks differ and so do the trajectories.
+    The PLD case also restacks the float extras' cotangents.  Tolerances
+    are ``test_carried_mode_parity_fp32``'s; the optimizer is SGD, whose
+    step is linear in the gradient, so they bound the GRADIENTS: the two
+    programs' differ by an ulp (6e-8 at most, on 0.14), a wrong mask by
+    the size of a leaf's gradient (a perturbed key in the backward moves
+    parameters by 1e-5, a hundred times ``atol``).  Under Adam the same
+    ulp on a gradient that is zero in exact arithmetic (the key bias's)
+    becomes 2e-6 of parameter, between ANY two fusings of one program."""
+    kw = dict(num_layers=8, checkpointing=True, dropout=0.1, pld=pld,
+              optimizer="sgd")
+    l_off, p_off, plan = _train_tiny(_group_cfg("off", 4), **kw)
+    assert plan.mode == "off"
+    l_car, p_car, plan = _train_tiny(_group_cfg("carried", 4), **kw)
+    assert plan.mode == "carried" and plan.layers_per_step == 4
+    np.testing.assert_allclose(l_car, l_off, rtol=1e-6)
+    for a, b in zip(jax.tree.leaves(p_car), jax.tree.leaves(p_off)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
