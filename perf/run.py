@@ -235,12 +235,12 @@ def run_cell(workload, seed, seconds, trace, root=ROOT, platform="tpu",
     # 2. the timed engine's own losses
     values = [float(v) for v in jax.device_get(losses)]
     failed = sum(not math.isfinite(v) for v in values[WARMUP_STEPS:])
-    check = cell["loss_check"]
-    at = values[check["step"] - 1] if len(values) >= check["step"] else None
-    in_band = at is not None and check["band"][0] <= at <= check["band"][1]
-    say(f"loss: first {values[0]:.4f}, step {check['step']} "
-        f"{'not reached' if at is None else format(at, '.4f')} (band "
-        f"{check['band']}), last {values[-1]:.4f} after {len(values)} steps")
+    vocab = family.vocab_rows(config)
+    verdict = loss_verdict(values, cell["loss_check"],
+                           generator.entropy(traffic, vocab),
+                           generator.batch_sd(traffic, vocab,
+                                              rows * job["seq"]))
+    say("losses " + " ".join(f"{v:.4f}" for v in values))
     say(f"window: {attempted} steps in {t1 - t0:.3f} s, {len(step_s)} step "
         f"times, min {1e3 * min(step_s):.2f} median "
         f"{host['step_ms_p50']:.2f} max {1e3 * max(step_s):.2f} ms; "
@@ -260,8 +260,19 @@ def run_cell(workload, seed, seconds, trace, root=ROOT, platform="tpu",
     device["count"] = chips
     device["memory_peak_bytes"] = max((m for m in memory if m), default=0)
 
-    result = {"correct": bool(parity["ok"] and failed == 0 and in_band
-                              and compiles.count == 0),
+    parts = {"parity": bool(parity["ok"]), "finite": failed == 0,
+             "loss check": verdict["ok"], "compiles": compiles.count == 0}
+    a, b = cell["loss_check"]["steps"]
+    say("correct: " + ", ".join(
+        f"{name} {'held' if held else 'FAILED'}"
+        for name, held in parts.items())
+        + f"; loss check: median of steps {a} to {b} "
+        + ("not reached" if verdict["settled"] is None
+           else format(verdict["settled"], ".4f"))
+        + f" against limit {verdict['limit']:.4f}, lowest of {len(values)} "
+        f"losses {verdict['lowest']:.4f} against floor "
+        f"{verdict['floor']:.4f}")
+    result = {"correct": all(parts.values()),
               "attempted": attempted, "failed": failed}
     if trace:
         say("program memory " + json.dumps(
@@ -296,6 +307,40 @@ def run_cell(workload, seed, seconds, trace, root=ROOT, platform="tpu",
     result["metrics"] = metrics
     result["device"] = device
     return result
+
+
+FLOOR_SDS = 6
+
+
+def loss_verdict(values, check, entropy, sd):
+    """The loss check on ``values``, every step's loss from the first
+    warm-up step on, under a cell's ``check`` = {"steps": [a, b],
+    "rise": r}.  ``entropy`` is the traffic's, nats a token, and ``sd``
+    the scatter of one reported loss about its expectation; both come
+    from the traffic's generator, neither from the program.  Two
+    conditions:
+
+    floor    no loss is under ``entropy - FLOOR_SDS * sd``.  Derived, not
+             fitted: fresh independent ids cannot be predicted better,
+             so a loss below it saw its targets or is no mean
+             cross-entropy.
+    settled  the median of steps a to b (1-based, warm-up counted) is at
+             most ``entropy + rise``: training has come down to the
+             plateau.  A median, so that a spike or two in the run of
+             steps refuse nobody; fixed indices, so that a seed's
+             statistic is one number however fast the step is.  A run
+             that does not reach step b is not judged sound.
+    """
+    a, b = check["steps"]
+    floor = entropy - FLOOR_SDS * sd
+    limit = entropy + check["rise"]
+    lowest = min(values)
+    settled = statistics.median(values[a - 1:b]) if len(values) >= b else None
+    return {"settled": settled, "limit": limit, "lowest": lowest,
+            "floor": floor,
+            "ok": bool(all(map(math.isfinite, values))
+                       and settled is not None and settled <= limit
+                       and lowest >= floor)}
 
 
 def main(argv=None):

@@ -92,6 +92,7 @@ def test_every_configuration_has_its_file_and_the_reverse(bench):
 
 
 def test_every_cell_has_its_file_and_the_reverse(bench):
+    from perf import run
     named = {w["name"]: w for w in bench["workloads"]}
     on_disk = {p.stem for p in (PERF / "workloads").glob("*.json")}
     assert set(named) == on_disk
@@ -104,8 +105,11 @@ def test_every_cell_has_its_file_and_the_reverse(bench):
         traffic = json.loads(
             (PERF / "traffic" / f"{w['traffic']}.json").read_text())
         assert (PERF / "traffic" / (traffic["generator"] + ".py")).is_file()
-        lo, hi = body["loss_check"]["band"]
-        assert 0 < lo < hi
+        check = body["loss_check"]
+        assert set(check) == {"steps", "rise"}
+        first, last = check["steps"]
+        assert run.WARMUP_STEPS < first and first + 4 <= last
+        assert 0 < check["rise"]
     used = {w["traffic"] for w in bench["workloads"]}
     assert used == {p.stem for p in (PERF / "traffic").glob("*.json")}
 
@@ -181,7 +185,7 @@ def test_a_new_cell_config_and_metric_are_new_files_only(
         "assumed": {"vocab_rows_padded": 256}}))
     (root / "perf/traffic/toy.b2.s64.json").write_text(json.dumps({
         "generator": "zipf_tokens", "batch_per_chip": 2, "seq": 64,
-        "exponent": 1.0, "pool_steps": 8}))
+        "exponent": 1.0, "pool_steps": 512}))
     (root / "perf/workloads/toy.cell.json").write_text(json.dumps({
         "config": "toy", "traffic": "toy.b2.s64", "chips": 1, "why": "test",
         "job": {"gradient_accumulation_steps": 1,
@@ -191,7 +195,7 @@ def test_a_new_cell_config_and_metric_are_new_files_only(
                     "bf16": {"enabled": True},
                     "zero_optimization": {"stage": 2}},
                 "parity": {"layers": 2, "rows_per_chip": 2}},
-        "loss_check": {"step": 3, "band": [1.0, 7.0]},
+        "loss_check": {"steps": [3, 7], "rise": 1.5},
         "per_layer": ["toy_steps", "compiles_in_window"]}))
     (root / "perf/layer_metrics/toy_steps.py").write_text(
         'LAYER, UNIT, MOVES, SOURCE = "entry", "count", "tokens_per_s", '
