@@ -14,6 +14,19 @@ logsumexp; two Pallas kernels recompute P block-wise and produce dk/dv
 (grid over k blocks) and dq (grid over q blocks) with no [S, S] HBM
 materialization.  The XLA reference path serves CPU and the bias/fallback
 cases.
+
+Inside a tile a causal call bounds its work by the diagonal (the section
+"The causal bound inside a tile" below): of the tile's 512-column
+sub-tiles, the ones wholly above the diagonal are not computed and only
+those it can cross are masked, in the forward and both backward kernels
+from the same dropout bits, each tile still being one step; the backward
+kernels, whose time follows the products they compute, bound each group of
+256 q rows by its own last row as well.  With the shipped 512 x 1024
+blocks at S=1024 the forward kernel computes 3/4 of the S x S square and
+masks 2/4, each backward kernel 5/8 and 1/4, where all three computed
+and masked all of it (causal_sub_tile_shares counts it).  Non-causal
+calls, and causal ones whose key block is no multiple of the sub-tile or
+a single one, lower to the kernels as they were.
 """
 
 import functools
@@ -80,9 +93,12 @@ def mha_reference(q, k, v, causal: bool = False,
 # --------------------------------------------------------------------------- #
 # Pallas kernel
 # --------------------------------------------------------------------------- #
-def _ld(ref):
-    """Load the [rows, d] tile from a (1, 1, rows, d) block."""
-    return ref[0, 0]
+def _ld(ref, rows=None, cols=False):
+    """Load the [rows, d] tile from a (1, 1, rows, d) block, or the rows
+    `rows` (a static slice) of it; with `cols`, its columns `rows`."""
+    if rows is None:
+        return ref[0, 0]
+    return ref[0, 0, :, rows] if cols else ref[0, 0, rows, :]
 
 
 def _st(ref, val):
@@ -98,6 +114,118 @@ def causal_keep_mask(qi_block, ki_block, block_q, block_k):
     col = ki_block * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
     return col <= row
+
+
+# --------------------------------------------------------------------------- #
+# The causal bound inside a tile
+# --------------------------------------------------------------------------- #
+# The shipped blocks are large (512 x 1024: small blocks are
+# grid-overhead-bound, see DEFAULT_BLOCK_Q), so at S=1024 one key block
+# spans the whole row and skipping whole blocks above the diagonal skips
+# nothing.  A causal call therefore bounds each [block_q, block_k] tile's
+# work by the diagonal, in sub-tiles of _CAUSAL_SUB_K key columns and in
+# all three kernels (_walk_tile): the sub-tiles wholly above the
+# diagonal are not computed, and of the leading ones that are, only those
+# the diagonal can cross pay for the mask's iota, compare and select.  A
+# tile is still ONE step (one online-softmax update, one accumulation),
+# at a width that is static in each of block_k // _CAUSAL_SUB_K bodies
+# picked by program_id: two with the shipped blocks.  In the backward
+# kernels, where the blocks are aligned, the step is taken in groups of
+# _CAUSAL_SUB_Q q rows, each as wide as its own last row reaches.
+#
+# Why one step of static width and not a loop over sub-tiles (v5e, PR 31,
+# PERF.md section 6): a forward step's cost is mostly its rows' running
+# max / sum / accumulator update and cross-lane reductions, paid per step
+# whatever the width (0.275 of the kernel's 0.47 ms at [4, 20, 1024, 64]),
+# so two lax.fori_loops over 512-column sub-tiles made the forward kernel
+# 29% SLOWER than no bound (three steps a head for two) where this form
+# makes it 4% faster; the backward kernels carry no such state, follow the
+# products they compute, and take the same form.
+#
+# Why 512 columns: the 8-bit dropout layout draws one PRNG word per four
+# columns, so a 512-wide sub-tile's words are exactly one 128-lane vreg
+# column ([block_q, 128]) and its four byte planes concatenate on vreg
+# boundaries: the dropout bits are drawn per 512-column unit whatever is
+# computed of it.  (Looped, 256- and 128-column sub-tiles made the
+# forward kernel 2.3 and 3.8 times slower than no bound.)
+_CAUSAL_SUB_K = 512
+
+
+def _causal_sub_tile(block_q: int, block_k: int, causal: bool) -> int:
+    """Width of the key-column sub-tiles a causal call walks its tiles
+    in, or 0 where the tile is walked whole as before: a non-causal call,
+    and a causal one whose resolved key block is no multiple of
+    _CAUSAL_SUB_K or a single sub-tile."""
+    if causal and block_k > _CAUSAL_SUB_K and block_k % _CAUSAL_SUB_K == 0:
+        return _CAUSAL_SUB_K
+    return 0
+
+
+def _causal_sub_range(qi, ki, block_q, block_k, sub):
+    """(n_full, n_end) for tile (qi, ki): of its block_k // sub sub-tiles,
+    [0, n_full) lie wholly under the diagonal (last column <= first row:
+    no mask), [n_full, n_end) are crossed by it (masked) and the rest lie
+    wholly above (first column > last row: not computed).  Python ints
+    for the counter, traced int32 scalars inside the kernels."""
+    lo, hi = ((max, min) if isinstance(qi, int)
+              else (jnp.maximum, jnp.minimum))
+    n_sub = block_k // sub
+    off = qi * block_q - ki * block_k        # first row - first column
+    n_full = hi(lo(off + 1, 0) // sub, n_sub)
+    n_end = hi(lo(off + block_q - 1 + sub, 0) // sub, n_sub)
+    return n_full, n_end
+
+
+def causal_pieces(q_len, k_len, block_q, block_k, causal=True,
+                  backward=False):
+    """What a call's forward kernel (or, `backward`, each of its two
+    backward kernels) computes, as rectangles of the S x S score matrix:
+    (row0, row1, col0, col1, mask0) for rows [row0, row1) against key
+    columns [col0, col1), the causal mask applied from column mask0 on
+    (mask0 == col1: none).  Static, from the lengths and the blocks as
+    the kernels fit them."""
+    _, block_q, block_k = _resolve_blocks(q_len, k_len, block_q, block_k)
+    walk = _causal_walk(q_len, k_len, block_q, block_k, causal, backward)
+    sub = walk.get("sub_k", block_k)
+    n_sub = block_k // sub
+    for qi in range(q_len // block_q):
+        for ki in range(k_len // block_k):
+            row0, col0 = qi * block_q, ki * block_k
+            n_full, n_end = ((n_sub, n_sub) if not causal else
+                             _causal_sub_range(qi, ki, block_q, block_k,
+                                               sub))
+            if n_end == 0:
+                continue
+            if not walk:    # walked whole: a causal tile is masked whole
+                parts = [(None, block_k, 0 if causal else None)]
+            elif n_full == n_sub:
+                parts = [(None, block_k, None)]
+            else:
+                parts = _prefix_parts(n_end, block_q, sub, walk["band"],
+                                      walk["sub_q"])
+            for qrows, width, mask_col in parts:
+                r0, r1 = (0, block_q) if qrows is None else (qrows.start,
+                                                             qrows.stop)
+                yield (row0 + r0, row0 + r1, col0, col0 + width,
+                       col0 + (width if mask_col is None else mask_col))
+
+
+def causal_sub_tile_shares(q_len, k_len, block_q, block_k, causal):
+    """{kernel: (computed, masked)}: the share of the S x S products each
+    kernel of a call computes, and the share that also pays for the
+    causal mask (causal_pieces, by area).  (1, 0) for a non-causal call,
+    (1, 1) for a causal one walked whole at one tile, (1/2, 0) in the
+    limit of an exact bound.  Static and exact: this is the mechanism's
+    counter."""
+    def shares(backward):
+        computed = masked = 0
+        for row0, row1, col0, col1, mask0 in causal_pieces(
+                q_len, k_len, block_q, block_k, causal, backward):
+            computed += (row1 - row0) * (col1 - col0)
+            masked += (row1 - row0) * (col1 - mask0)
+        return computed / (q_len * k_len), masked / (q_len * k_len)
+    return {"flash_fwd": shares(False), "flash_bwd_dkdv": shares(True),
+            "flash_bwd_dq": shares(True)}
 
 
 # Dropout PRNG width: 8 (default since r4 session 2) generates one
@@ -235,12 +363,14 @@ def _dropout_keep(seed_ref, b, h, qi, ki, rate, block_q, block_k,
     return rbits.astype(jnp.uint32) < threshold
 
 
-def _effective_dropout_bits(block_k: int) -> int:
+def _effective_dropout_bits(block_k: int, bits: Optional[int] = None) -> int:
     """The width BOTH the mask and the scale must use for this kernel
     call: 8-bit needs four byte-columns per word, so non-multiple-of-4
     k blocks fall back to 32 — decided once here so mask probability and
-    inverse scale can never disagree."""
-    return _dropout_bits if _dropout_bits == 32 or block_k % 4 == 0 else 32
+    inverse scale can never disagree.  `bits` is the selected width
+    (default: the module's, dropout_bits())."""
+    bits = _dropout_bits if bits is None else bits
+    return bits if bits == 32 or block_k % 4 == 0 else 32
 
 
 # --------------------------------------------------------------------------- #
@@ -323,12 +453,157 @@ def _mask_reuse_usable(block_q: int) -> bool:
     return block_q % 256 == 0
 
 
+def _walk_tile(qi, ki, block_q, block_k, causal, walk, step):
+    """Run a kernel's step on tile (qi, ki): step(kj, n, rows, parts).
+
+    Without a plan (`walk` empty: a non-causal call, or blocks the
+    sub-tile does not divide) the tile is one step over its whole key
+    block, masked whole if causal, and skipped if wholly above the
+    diagonal (the analog of the reference's triangular-launch trick).
+
+    With one (_causal_walk) the tile's work is bounded by the diagonal
+    inside it: one step over the n leading sub-tiles of walk["sub_k"] key
+    columns that hold a position under the diagonal, at a static width,
+    one body per n (two with the shipped blocks); n == 0, the tile wholly
+    above, runs none.  kj is the first sub-tile's index along the whole
+    key axis (the dropout stream's coordinate), rows the key rows inside
+    the block, and parts the step's static pieces (q rows, key columns,
+    first masked column; _prefix_parts):
+    - one piece, all q rows by n * sub columns.  The diagonal crosses at
+      most `band` sub-tiles of any tile, so the ones before the last
+      `band` lie wholly under it and go unmasked;
+    - or, where the blocks are aligned (sub_q > 0: the q block a multiple
+      of `sub`, the key block a multiple of the q block, so a tile on the
+      diagonal ends its n sub-tiles exactly at its last row), one piece a
+      group of sub_q rows, each as wide as its own last row reaches and
+      masked in its own diagonal square alone.
+    A tile wholly under the diagonal takes an unmasked body of its own,
+    traced only where the call has such a tile (`full`)."""
+    if not walk:
+        should_compute = True
+        if causal:
+            should_compute = qi * block_q + block_q - 1 >= ki * block_k
+
+        @pl.when(should_compute)
+        def _compute():
+            step(ki, 1, None, [(None, block_k, 0 if causal else None)])
+        return
+
+    sub, band, full, sub_q = (walk[key] for key in
+                              ("sub_k", "band", "full", "sub_q"))
+    n_sub = block_k // sub
+    n_full, n_end = _causal_sub_range(qi, ki, block_q, block_k, sub)
+    for n in range(1, n_sub + 1):
+        if sub_q and n * sub < block_q:
+            continue   # aligned, no tile on the diagonal ends this early
+        parts = _prefix_parts(n, block_q, sub, band, sub_q)
+        crossed = n_end == n
+        if full and n == n_sub:
+            crossed &= n_full < n_sub
+
+        @pl.when(crossed)
+        def _(n=n, parts=parts):
+            step(ki * n_sub, n, None if n == n_sub else slice(0, n * sub),
+                 parts)
+
+    if full:
+        @pl.when(n_full == n_sub)
+        def _():
+            step(ki * n_sub, n_sub, None, [(None, block_k, None)])
+
+
+def _prefix_parts(n, block_q, sub, band, sub_q):
+    """The static pieces (q rows, key columns, first masked column) of
+    the step over a tile's n leading sub-tiles (_walk_tile)."""
+    if sub_q:
+        return [(slice(r, r + sub_q), n * sub - block_q + r + sub_q,
+                 n * sub - block_q + r)
+                for r in range(0, block_q, sub_q)]
+    return [(None, n * sub, max(0, n - band) * sub)]
+
+
+# Rows of a q-row group of the aligned form, in the two backward kernels
+# (v5e, PR 31, kernels alone at [4, 20, 1024, 64], ms a call with groups
+# of 512 = none / 256 / 128 rows): dkdv 0.457 / 0.401 / 0.425, dq 0.304 /
+# 0.271 / 0.266; at 128 the modules pass twice the non-causal ones' size
+# (tests/unit/test_flash_setup_guard.py).  The forward kernel takes none:
+# 0.434 / 0.447 / 0.412, its cost being per row and step, not per column.
+_CAUSAL_SUB_Q = 256
+
+
+def _causal_walk(q_len, k_len, block_q, block_k, causal, row_groups):
+    """A kernel's plan for the causal bound (_walk_tile), static, from
+    the lengths and the resolved blocks: the sub-tile width, the most
+    sub-tiles of one tile that the diagonal crosses (`band`), whether
+    some tile lies wholly under it (`full`) and, for a kernel that takes
+    them (`row_groups`: the backward pair), the rows of a q-row group
+    where the blocks are aligned (`sub_q`, else 0).  {} where the tiles
+    are walked whole (_causal_sub_tile)."""
+    sub = _causal_sub_tile(block_q, block_k, causal)
+    if not sub:
+        return {}
+    ranges = [_causal_sub_range(qi, ki, block_q, block_k, sub)
+              for qi in range(q_len // block_q)
+              for ki in range(k_len // block_k)]
+    aligned = (block_q % sub == 0 and block_k % block_q == 0
+               and block_q % _CAUSAL_SUB_Q == 0)
+    return dict(sub_k=sub,
+                band=max(n_end - n_full for n_full, n_end in ranges),
+                full=any(n_full == block_k // sub for n_full, _ in ranges),
+                sub_q=_CAUSAL_SUB_Q if row_groups and aligned else 0)
+
+
+def _step_keep(seed_ref, b, h, qi, kj, n, rate, block_q, unit, num_units,
+               bits, interpret):
+    """Keep mask of a step over the n units of `unit` key columns from
+    unit kj on: each unit's own regenerable draw (_dropout_keep), side by
+    side, so the mask of a position does not depend on how a kernel
+    walks its tiles."""
+    draws = [_dropout_keep(seed_ref, b, h, qi, kj + j if j else kj, rate,
+                           block_q, unit, num_units, bits=bits,
+                           interpret=interpret)
+             for j in range(n)]
+    return draws[0] if n == 1 else jnp.concatenate(draws, axis=1)
+
+
+def _step_mask(x, qi, kj, block_q, unit, qrows, mask_col, fill):
+    """x, the scores or probabilities of q rows `qrows` (None: all) of
+    block qi against the key columns from unit kj on, with `fill` above
+    the diagonal from column mask_col on (None: the piece lies wholly
+    under the diagonal)."""
+    if mask_col is None:
+        return x
+    if qrows is None and mask_col == 0 and x.shape[1] == unit:
+        return jnp.where(causal_keep_mask(qi, kj, block_q, unit), x, fill)
+    shape = (x.shape[0], x.shape[1] - mask_col)
+    row = (qi * block_q + (qrows.start if qrows else 0)
+           + jax.lax.broadcasted_iota(jnp.int32, shape, 0))
+    col = kj * unit + mask_col + jax.lax.broadcasted_iota(
+        jnp.int32, shape, 1)
+    tail = jnp.where(col <= row, x[:, mask_col:], fill)
+    return (tail if mask_col == 0
+            else jnp.concatenate([x[:, :mask_col], tail], axis=1))
+
+
+def _piece(x, qrows, width):
+    """Rows `qrows` (None: all) and the first `width` columns of a
+    step-wide array."""
+    if qrows is not None:
+        x = x[qrows]
+    return x if width == x.shape[1] else x[:, :width]
+
+
+def _at(rows):
+    """Index of rows `rows` of a 2-D scratch (None: all of it)."""
+    return (Ellipsis,) if rows is None else (rows, slice(None))
+
+
 def _fa_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                *rest,
                causal: bool, sm_scale: float, block_q: int, block_k: int,
                num_k_blocks: int, dropout_rate: float,
                dropout_pbits: int = 32, save_mask: bool = False,
-               interpret: bool = False):
+               interpret: bool = False, walk=None):
     if save_mask:
         mask_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -344,33 +619,31 @@ def _fa_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # With causal masking, blocks strictly above the diagonal contribute
-    # nothing — skip their matmuls entirely (the analog of the reference's
-    # triangular-launch trick).
-    should_compute = True
-    if causal:
-        should_compute = qi * block_q + block_q - 1 >= ki * block_k
+    unit = walk["sub_k"] if walk else block_k
 
-    @pl.when(should_compute)
-    def _compute():
+    def _update(kj, n, rows, parts):
+        """One online-softmax step over the n units of `unit` key
+        columns from unit kj on: the whole block (n 1, rows None,
+        kj == ki) or sub-tiles of it, key rows `rows` of the block.  In
+        one piece: the forward kernel takes no q-row groups."""
+        (_, _, mask_col), = parts
         # bf16 operands straight into the MXU; fp32 accumulation via
         # preferred_element_type (upcasting first would force an fp32 matmul).
         q = _ld(q_ref)                               # [bq, d]
-        k = _ld(k_ref)                               # [bk, d]
+        k = _ld(k_ref, rows)                         # [width, d]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # [bq, bk] fp32
+            preferred_element_type=jnp.float32) * sm_scale  # [bq, width] fp32
 
-        if causal:
-            s = jnp.where(causal_keep_mask(qi, ki, block_q, block_k),
-                          s, DEFAULT_MASK_VALUE)
+        s = _step_mask(s, qi, kj, block_q, unit, None, mask_col,
+                       DEFAULT_MASK_VALUE)
 
         m_prev = m_scr[...]                           # [bq, LANES]
         l_prev = l_scr[...]
         m_curr = jnp.max(s, axis=-1, keepdims=True)   # [bq, 1]
         m_next = jnp.maximum(m_prev, m_curr)          # [bq, LANES]
         alpha = jnp.exp(m_prev[:, :1] - m_next[:, :1])   # [bq, 1]
-        p = jnp.exp(s - m_next[:, :1])                # [bq, bk] fp32
+        p = jnp.exp(s - m_next[:, :1])                # [bq, width] fp32
         l_corr = l_prev * alpha
         l_next = l_corr + jnp.sum(p, axis=-1, keepdims=True)
         m_scr[...] = m_next
@@ -381,22 +654,29 @@ def _fa_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             # normalizer l accumulates the RAW p (softmax normalizes true
             # probabilities; dropout applies to the normalized P, which
             # commutes with the final /l)
-            keep = _dropout_keep(seed_ref, b, h, qi, ki, dropout_rate,
-                                 block_q, block_k, num_k_blocks,
-                                 bits=dropout_pbits, interpret=interpret)
+            keep = _step_keep(seed_ref, b, h, qi, kj, n, dropout_rate,
+                              block_q, unit,
+                              num_k_blocks * (block_k // unit),
+                              dropout_pbits, interpret)
             inv = _keep_scale(dropout_rate, dropout_pbits)
             p = jnp.where(keep, p * inv, 0.0)
             if save_mask:
                 # bit-packed keep decisions for the backward kernels.
-                # Causally-skipped tiles never write (and the backward
-                # skips the same tiles, so their garbage is never read).
-                mask_ref[0, 0] = _pack_keep32(keep)
+                # Causally-skipped tiles and sub-tiles never write (and
+                # the backward skips the same ones, so their garbage is
+                # never read).
+                if rows is None:
+                    mask_ref[0, 0] = _pack_keep32(keep)
+                else:
+                    mask_ref[0, 0, :, rows] = _pack_keep32(keep)
 
-        v_blk = _ld(v_ref)                           # [bk, d]
+        v_blk = _ld(v_ref, rows)                     # [width, d]
         pv = jax.lax.dot_general(
             p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)       # [bq, d]
         acc_scr[...] = acc_scr[...] * alpha + pv
+
+    _walk_tile(qi, ki, block_q, block_k, causal, walk, _update)
 
     @pl.when(ki == num_k_blocks - 1)
     def _finalize():
@@ -500,6 +780,28 @@ def flash_attention_pallas(q, k, v, causal: bool = False,
     the bit-packed keep mask [B, H, S_q/32, S_k] uint32 — ALWAYS in the
     internal bhsd-derived index space regardless of layout — for
     flash_attention_bwd_pallas(dropout_mask=...)."""
+    return _flash_fwd_call(
+        q, k, v, dropout_seed, causal=causal, sm_scale=sm_scale,
+        block_q=block_q, block_k=block_k, interpret=interpret,
+        return_lse=return_lse, layout=layout,
+        dropout_rate=float(dropout_rate),
+        save_dropout_mask=save_dropout_mask, dropout_bits=_dropout_bits)
+
+
+# The calls' own programs are traced once a process and shape: every
+# pallas_call traces its kernel body in Python each time the function
+# around it is traced (four times a kernel in one grad program: the
+# custom_vjp's primal and forward rule, the rematerialised pass, the
+# transpose), in every program that holds it, warm compile cache or not,
+# and that time is the benchmark's gated setup_s.  Under an outer jit
+# these are inlined calls, not programs of their own.
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "sm_scale", "block_q", "block_k", "interpret", "return_lse",
+    "layout", "dropout_rate", "save_dropout_mask", "dropout_bits"))
+def _flash_fwd_call(q, k, v, dropout_seed, *, causal, sm_scale, block_q,
+                    block_k, interpret, return_lse, layout, dropout_rate,
+                    save_dropout_mask, dropout_bits):
+    """flash_attention_pallas, traced once a process and shape."""
     batch, heads, q_len, d = _dims(q, layout)
     k_len = _dims(k, layout)[2]
     if layout == "bshd":
@@ -531,12 +833,13 @@ def flash_attention_pallas(q, k, v, causal: bool = False,
                 "multiple of 256 (TransformerConfig.block_q / the block_q "
                 "argument), or stay on the regen path by disabling reuse "
                 "(set_dropout_mask_reuse(False) / DS_DROPOUT_REUSE=0)")
+    walk = _causal_walk(q_len, k_len, block_q, block_k, causal, False)
     kernel = functools.partial(
         _fa_kernel, causal=causal, sm_scale=float(sm_scale),
         block_q=block_q, block_k=block_k, num_k_blocks=nk,
         dropout_rate=float(dropout_rate),
-        dropout_pbits=_effective_dropout_bits(block_k),
-        save_mask=save_dropout_mask, interpret=interpret)
+        dropout_pbits=_effective_dropout_bits(block_k, dropout_bits),
+        save_mask=save_dropout_mask, interpret=interpret, walk=walk)
 
     scratch = [
         pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
@@ -597,7 +900,7 @@ def _fa_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                         delta_ref, *rest, causal, sm_scale, block_q,
                         block_k, num_q_blocks, num_k_blocks, dropout_rate,
                         dropout_pbits=32, reuse_mask: bool = False,
-                        interpret: bool = False):
+                        interpret: bool = False, walk=None):
     if reuse_mask:
         mask_ref, dk_ref, dv_ref, dk_scr, dv_scr = rest
     else:
@@ -612,56 +915,63 @@ def _fa_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    should_compute = True
-    if causal:  # q block fully above the diagonal contributes nothing
-        should_compute = qi * block_q + block_q - 1 >= ki * block_k
+    unit = walk["sub_k"] if walk else block_k
 
-    @pl.when(should_compute)
-    def _compute():
-        q = _ld(q_ref)                               # [bq, d]
-        k = _ld(k_ref)                               # [bk, d]
-        v = _ld(v_ref)                               # [bk, d]
-        do = _ld(do_ref)                             # [bq, d]
-        lse = lse_ref[0, 0][:, :1]                    # [bq, 1]
-        delta = delta_ref[0, 0][:, :1]                # [bq, 1]
+    def _update(kj, n, rows, parts):
+        """This q block's part of dk and dv for the n units of `unit`
+        keys from unit kj on: the whole block (n 1, rows None, kj == ki)
+        or sub-tiles of it, key rows `rows` of the block (the
+        accumulators' rows alike); in the static pieces `parts` (q rows,
+        keys, first masked key)."""
+        keep = None
+        for qrows, width, mask_col in parts:
+            krows = rows if width == n * unit else slice(0, width)
+            q = _ld(q_ref, qrows)                        # [bq, d]
+            k = _ld(k_ref, krows)                        # [width, d]
+            v = _ld(v_ref, krows)                        # [width, d]
+            do = _ld(do_ref, qrows)                      # [bq, d]
+            lse = _ld(lse_ref, qrows)[:, :1]              # [bq, 1]
+            delta = _ld(delta_ref, qrows)[:, :1]          # [bq, 1]
 
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale   # [bq, bk]
-        p = jnp.exp(s - lse)                          # [bq, bk] fp32
-        if causal:
-            p = jnp.where(causal_keep_mask(qi, ki, block_q, block_k),
-                          p, 0.0)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale  # [bq, width]
+            p = jnp.exp(s - lse)                          # [bq, width] fp32
+            p = _step_mask(p, qi, kj, block_q, unit, qrows, mask_col, 0.0)
 
-        dp = jax.lax.dot_general(                      # do @ v^T -> [bq, bk]
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if dropout_rate > 0.0:
-            # same mask as the forward — regenerated from the tile
-            # coordinates, or read back bit-packed (reuse mode; both
-            # give the IDENTICAL mask, so grads don't depend on the
-            # mode).  dV sees the DROPPED probabilities; dS =
-            # P*(D.dp - delta)
-            if reuse_mask:
-                keep = _unpack_keep32(mask_ref[0, 0])
+            dp = jax.lax.dot_general(                # do @ v^T -> [bq, width]
+                do, v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if dropout_rate > 0.0:
+                # same mask as the forward — regenerated from the
+                # sub-tile coordinates, or read back bit-packed (reuse
+                # mode; both give the IDENTICAL mask, so grads don't
+                # depend on the mode).  dV sees the DROPPED
+                # probabilities; dS = P*(D.dp - delta)
+                if keep is None:   # the step's bits, once
+                    if reuse_mask:
+                        keep = _unpack_keep32(_ld(mask_ref, rows, cols=True))
+                    else:
+                        keep = _step_keep(seed_ref, b, h, qi, kj, n,
+                                          dropout_rate, block_q, unit,
+                                          num_k_blocks * (block_k // unit),
+                                          dropout_pbits, interpret)
+                inv = _keep_scale(dropout_rate, dropout_pbits)
+                kept = _piece(keep, qrows, width)
+                p_drop = jnp.where(kept, p * inv, 0.0)
+                dp = jnp.where(kept, dp * inv, 0.0)
             else:
-                keep = _dropout_keep(seed_ref, b, h, qi, ki, dropout_rate,
-                                     block_q, block_k, num_k_blocks,
-                                     bits=dropout_pbits,
-                                     interpret=interpret)
-            inv = _keep_scale(dropout_rate, dropout_pbits)
-            p_drop = jnp.where(keep, p * inv, 0.0)
-            dp = jnp.where(keep, dp * inv, 0.0)
-        else:
-            p_drop = p
+                p_drop = p
 
-        dv_scr[...] += jax.lax.dot_general(            # p^T @ do -> [bk, d]
-            p_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale               # [bq, bk] fp32
-        dk_scr[...] += jax.lax.dot_general(            # ds^T @ q -> [bk, d]
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            dv_scr[_at(krows)] += jax.lax.dot_general(   # p^T @ do
+                p_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)       # [width, d]
+            ds = p * (dp - delta) * sm_scale               # [bq, width] fp32
+            dk_scr[_at(krows)] += jax.lax.dot_general(   # ds^T @ q
+                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)       # [width, d]
+
+    _walk_tile(qi, ki, block_q, block_k, causal, walk, _update)
 
     @pl.when(qi == num_q_blocks - 1)
     def _finalize():
@@ -673,7 +983,7 @@ def _fa_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                       delta_ref, *rest, causal, sm_scale, block_q,
                       block_k, num_k_blocks, dropout_rate,
                       dropout_pbits=32, reuse_mask: bool = False,
-                      interpret: bool = False):
+                      interpret: bool = False, walk=None):
     if reuse_mask:
         mask_ref, dq_ref, dq_scr = rest
     else:
@@ -687,43 +997,48 @@ def _fa_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    should_compute = True
-    if causal:
-        should_compute = qi * block_q + block_q - 1 >= ki * block_k
+    unit = walk["sub_k"] if walk else block_k
 
-    @pl.when(should_compute)
-    def _compute():
-        q = _ld(q_ref)
-        k = _ld(k_ref)
-        v = _ld(v_ref)
-        do = _ld(do_ref)
-        lse = lse_ref[0, 0][:, :1]
-        delta = delta_ref[0, 0][:, :1]
+    def _update(kj, n, rows, parts):
+        """dq's part from the n units of `unit` keys from unit kj on:
+        the whole block (n 1, rows None, kj == ki) or sub-tiles of it,
+        key rows `rows` of the block; in the static pieces `parts` (q
+        rows, keys, first masked key)."""
+        keep = None
+        for qrows, width, mask_col in parts:
+            krows = rows if width == n * unit else slice(0, width)
+            q = _ld(q_ref, qrows)
+            k = _ld(k_ref, krows)
+            v = _ld(v_ref, krows)
+            do = _ld(do_ref, qrows)
+            lse = _ld(lse_ref, qrows)[:, :1]
+            delta = _ld(delta_ref, qrows)[:, :1]
 
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        p = jnp.exp(s - lse)
-        if causal:
-            p = jnp.where(causal_keep_mask(qi, ki, block_q, block_k),
-                          p, 0.0)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if dropout_rate > 0.0:
-            if reuse_mask:
-                keep = _unpack_keep32(mask_ref[0, 0])
-            else:
-                keep = _dropout_keep(seed_ref, b, h, qi, ki, dropout_rate,
-                                     block_q, block_k, num_k_blocks,
-                                     bits=dropout_pbits,
-                                     interpret=interpret)
-            inv = _keep_scale(dropout_rate, dropout_pbits)
-            dp = jnp.where(keep, dp * inv, 0.0)
-        ds = p * (dp - delta) * sm_scale
-        dq_scr[...] += jax.lax.dot_general(            # ds @ k -> [bq, d]
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            p = jnp.exp(s - lse)
+            p = _step_mask(p, qi, kj, block_q, unit, qrows, mask_col, 0.0)
+            dp = jax.lax.dot_general(
+                do, v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if dropout_rate > 0.0:
+                if keep is None:   # the step's bits, once
+                    if reuse_mask:
+                        keep = _unpack_keep32(_ld(mask_ref, rows, cols=True))
+                    else:
+                        keep = _step_keep(seed_ref, b, h, qi, kj, n,
+                                          dropout_rate, block_q, unit,
+                                          num_k_blocks * (block_k // unit),
+                                          dropout_pbits, interpret)
+                inv = _keep_scale(dropout_rate, dropout_pbits)
+                dp = jnp.where(_piece(keep, qrows, width), dp * inv, 0.0)
+            ds = p * (dp - delta) * sm_scale
+            dq_scr[_at(qrows)] += jax.lax.dot_general(    # ds @ k -> [bq, d]
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+    _walk_tile(qi, ki, block_q, block_k, causal, walk, _update)
 
     @pl.when(ki == num_k_blocks - 1)
     def _finalize():
@@ -751,6 +1066,23 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
     block the forward packed with — the bit-group layout is a function
     of it, so a fwd/bwd block mismatch would silently permute mask rows;
     this check turns that into a loud error."""
+    return _flash_bwd_call(
+        q, k, v, out, lse, do, dropout_seed, dropout_mask, causal=causal,
+        sm_scale=sm_scale, block_q=block_q, block_k=block_k,
+        interpret=interpret, layout=layout,
+        dropout_rate=float(dropout_rate),
+        dropout_mask_block_q=dropout_mask_block_q,
+        dropout_bits=_dropout_bits)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "sm_scale", "block_q", "block_k", "interpret", "layout",
+    "dropout_rate", "dropout_mask_block_q", "dropout_bits"))
+def _flash_bwd_call(q, k, v, out, lse, do, dropout_seed, dropout_mask, *,
+                    causal, sm_scale, block_q, block_k, interpret, layout,
+                    dropout_rate, dropout_mask_block_q, dropout_bits):
+    """flash_attention_bwd_pallas, traced once a process and shape (see
+    _flash_fwd_call)."""
     batch, heads, q_len, d = _dims(q, layout)
     k_len = _dims(k, layout)[2]
     if layout == "bshd":
@@ -813,6 +1145,9 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
                 "flash_attention custom_vjp does this automatically; "
                 "manual callers must thread it through)")
     mask_in = (dropout_mask,) if reuse else ()
+    # as in the forward: the three kernels agree on the sub-tiles, whose
+    # coordinates seed the dropout bits
+    walk = _causal_walk(q_len, k_len, block_q, block_k, causal, True)
 
     # dk/dv: grid over k blocks (grid dim 2), inner loop over q blocks
     # (grid dim 3) — _tile_spec's "i"/"j" name grid dims 2/3, so q/do tiles
@@ -821,8 +1156,8 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
         _fa_bwd_dkdv_kernel, causal=causal, sm_scale=float(sm_scale),
         block_q=block_q, block_k=block_k, num_q_blocks=nq, num_k_blocks=nk,
         dropout_rate=float(dropout_rate),
-        dropout_pbits=_effective_dropout_bits(block_k), reuse_mask=reuse,
-        interpret=interpret)
+        dropout_pbits=_effective_dropout_bits(block_k, dropout_bits),
+        reuse_mask=reuse, interpret=interpret, walk=walk)
     dkdv_in_specs = [
         _tile_spec(block_q, d, "j"),
         _tile_spec(block_k, d, "i"),
@@ -867,8 +1202,8 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
         _fa_bwd_dq_kernel, causal=causal, sm_scale=float(sm_scale),
         block_q=block_q, block_k=block_k, num_k_blocks=nk,
         dropout_rate=float(dropout_rate),
-        dropout_pbits=_effective_dropout_bits(block_k), reuse_mask=reuse,
-        interpret=interpret)
+        dropout_pbits=_effective_dropout_bits(block_k, dropout_bits),
+        reuse_mask=reuse, interpret=interpret, walk=walk)
     dq_in_specs = [
         _tile_spec(block_q, d, "i"),
         _tile_spec(block_k, d, "j"),

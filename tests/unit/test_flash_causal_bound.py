@@ -1,0 +1,286 @@
+"""The causal bound inside a flash tile (ops/flash_attention.py
+_walk_tile): a causal call bounds each [block_q, block_k] tile's work
+by the diagonal in sub-tiles of _CAUSAL_SUB_K key columns and, in the
+backward kernels where the blocks are aligned, in groups of
+_CAUSAL_SUB_Q rows: what lies wholly above the diagonal is not computed,
+and of the rest only what it can cross is masked, from the same dropout
+bits in all three kernels.  Everything here runs
+through the Pallas interpreter on the CPU;
+tests/unit/test_flash_setup_guard.py compiles the same kernels for the
+v5e.
+
+With dropout on, the reference needs the kernel's own keep mask (the
+dropout stream is seeded by sub-tile coordinates): it is read back from
+the forward kernel position for position, and the forward and all three
+gradients are then held to a reference that applies exactly that mask —
+so a backward kernel that drew other bits for any position fails."""
+
+import base64
+import hashlib
+import importlib
+import json
+import os
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+fa = importlib.import_module("deepspeed_tpu.ops.flash_attention")
+
+GOLDEN = os.path.join(os.path.dirname(__file__), "golden",
+                      "flash_whole_tile_modules.json")
+SEED = 11
+
+
+def _inputs(heads, seq, d, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return tuple(jax.random.normal(k, (1, heads, seq, d), jnp.float32)
+                 for k in ks)
+
+
+def _kernel_keep_mask(heads, seq, block_q, block_k, rate):
+    """[heads, seq, seq] bool: the keep mask the forward kernel draws
+    (meaningful under the diagonal).  With q = k = 0 a causal row i puts
+    1/(i+1) on each of its columns 0..i, so against one-hot values a kept
+    position reads back positive and a dropped one reads 0.  The mask is
+    a function of the seed and the coordinates alone, not of q, k, v or
+    the head size."""
+    chunk = 256
+    zeros = jnp.zeros((1, heads, seq, chunk), jnp.float32)
+    keep = np.zeros((heads, seq, seq), bool)
+    for c in range(seq // chunk):
+        v = np.zeros((1, heads, seq, chunk), np.float32)
+        v[:, :, c * chunk + np.arange(chunk), np.arange(chunk)] = 1.0
+        out = fa.flash_attention_pallas(
+            zeros, zeros, jnp.asarray(v), causal=True, block_q=block_q,
+            block_k=block_k, interpret=True, dropout_rate=rate,
+            dropout_seed=SEED)
+        keep[:, :, c * chunk:(c + 1) * chunk] = np.asarray(out[0]) > 0
+    return keep
+
+
+def _reference(keep, inv):
+    """Causal attention with the given keep mask applied to the
+    normalized probabilities (None: no dropout), in plain XLA."""
+    def ref(q, k, v):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+        n = s.shape[-1]
+        s = jnp.where(np.tril(np.ones((n, n), bool)), s,
+                      fa.DEFAULT_MASK_VALUE)
+        p = jax.nn.softmax(s, axis=-1)
+        if keep is not None:
+            p = jnp.where(jnp.asarray(keep)[None], p * inv, 0.0)
+        return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+    return ref
+
+
+# (seq, block_q, block_k, dropout, heads, bound engages)
+CASES = [
+    # the shipped blocks: one 512 x 512 tile at 512 (walked whole), the
+    # bound alone at 1024, whole-block skip and the bound together at 2048
+    (512, 512, 1024, 0.0, 2, False),
+    (512, 512, 1024, 0.1, 2, False),
+    (1024, 512, 1024, 0.0, 2, True),
+    (1024, 512, 1024, 0.1, 2, True),
+    (2048, 512, 1024, 0.0, 2, True),
+    (2048, 512, 1024, 0.1, 2, True),
+    # the cells' head counts (gpt2-large 20, gpt2-xl 25)
+    (1024, 512, 1024, 0.1, 20, True),
+    (1024, 512, 1024, 0.0, 25, True),
+    # small explicit blocks: q blocks the sub-tile is not aligned with
+    (1024, 128, 1024, 0.1, 2, True),
+    (2048, 256, 1024, 0.0, 2, True),
+    # ... and blocks the sub-tile does not divide: walked whole, as before
+    (1024, 128, 128, 0.1, 2, False),
+    (1024, 256, 256, 0.0, 2, False),
+    # a q block taller than the sub-tile: rows 0..511 of sub-tile 1 have
+    # every column masked, and must add zeros, not NaN
+    (2048, 1024, 1024, 0.0, 2, True),
+    (2048, 1024, 1024, 0.1, 2, True),
+]
+
+
+@pytest.mark.parametrize("seq,block_q,block_k,rate,heads,engages", CASES)
+def test_causal_forward_and_gradients_match_reference(
+        seq, block_q, block_k, rate, heads, engages):
+    _, bq, bk = fa._resolve_blocks(seq, seq, block_q, block_k)
+    assert bool(fa._causal_sub_tile(bq, bk, True)) == engages
+    d = 16 if heads > 2 else 32
+    q, k, v, do = _inputs(heads, seq, d)
+    keep, inv = None, 1.0
+    if rate:
+        keep = _kernel_keep_mask(heads, seq, block_q, block_k, rate)
+        inv = fa._keep_scale(rate, fa._effective_dropout_bits(bk))
+        under = np.tril(np.ones((seq, seq), bool))
+        share = keep[:, under].mean()
+        assert abs(share - 1.0 / inv) < 0.01, share
+    ref = _reference(keep, inv)
+
+    out, lse = fa.flash_attention_pallas(
+        q, k, v, causal=True, block_q=block_q, block_k=block_k,
+        interpret=True, return_lse=True, dropout_rate=rate,
+        dropout_seed=SEED)
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+
+    grads = fa.flash_attention_bwd_pallas(
+        q, k, v, out, lse, do, causal=True, block_q=block_q,
+        block_k=block_k, interpret=True, dropout_rate=rate,
+        dropout_seed=SEED)
+    want = jax.grad(lambda q_, k_, v_: jnp.vdot(ref(q_, k_, v_), do),
+                    argnums=(0, 1, 2))(q, k, v)
+    for name, got, exp in zip(("dq", "dk", "dv"), grads, want):
+        assert np.isfinite(np.asarray(got)).all(), name
+        np.testing.assert_allclose(np.asarray(got), np.asarray(exp),
+                                   rtol=2e-4, atol=2e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("seq", [1024, 2048])
+def test_mask_reuse_keeps_working_under_the_bound(seq):
+    """The stored-mask mode (off by default) under the bound: the forward
+    stores the sub-tiles' keep bits it computed, the backward kernels read
+    them back, and outputs and gradients are bit for bit what the
+    regenerating mode gives (the stored mask IS the regenerated one)."""
+    bq, bk, rate = 512, 1024, 0.2
+    q, k, v, do = _inputs(2, seq, 32)
+    kw = dict(causal=True, block_q=bq, block_k=bk, interpret=True,
+              dropout_rate=rate)
+    out, lse = fa.flash_attention_pallas(q, k, v, return_lse=True,
+                                         dropout_seed=SEED, **kw)
+    out_s, lse_s, packed = fa.flash_attention_pallas(
+        q, k, v, return_lse=True, dropout_seed=SEED, save_dropout_mask=True,
+        **kw)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(out_s))
+    np.testing.assert_array_equal(np.asarray(lse), np.asarray(lse_s))
+    regen = fa.flash_attention_bwd_pallas(q, k, v, out, lse, do,
+                                          dropout_seed=SEED, **kw)
+    reuse = fa.flash_attention_bwd_pallas(
+        q, k, v, out, lse, do, dropout_mask=packed,
+        dropout_mask_block_q=bq, **kw)
+    for a, b in zip(regen, reuse):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# --------------------------------------------------------------------------- #
+# the counter
+# --------------------------------------------------------------------------- #
+def _painted(seq, block_q, block_k, backward):
+    """(computed, unmasked) [seq, seq] bool, painted position by position
+    from causal_pieces; no position is painted twice."""
+    computed = np.zeros((seq, seq), bool)
+    unmasked = np.zeros((seq, seq), bool)
+    for row0, row1, col0, col1, mask0 in fa.causal_pieces(
+            seq, seq, block_q, block_k, backward=backward):
+        assert not computed[row0:row1, col0:col1].any()
+        computed[row0:row1, col0:col1] = True
+        unmasked[row0:row1, col0:mask0] = True
+    return computed, unmasked
+
+
+# (seq, block_q, block_k, forward's shares, each backward kernel's)
+SHARES = [
+    # the shipped blocks: one tile on the diagonal at 512, walked whole
+    (512, 512, 1024, (1.0, 1.0), (1.0, 1.0)),
+    (1024, 512, 1024, (3 / 4, 2 / 4), (10 / 16, 4 / 16)),
+    (2048, 512, 1024, (10 / 16, 4 / 16), (36 / 64, 8 / 64)),
+    (4096, 512, 1024, (36 / 64, 8 / 64), (136 / 256, 16 / 256)),
+    # q blocks the sub-tile is not aligned with: one piece a tile
+    (1024, 128, 1024, (3 / 4, 2 / 4), (3 / 4, 2 / 4)),
+    (2048, 256, 1024, (5 / 8, 2 / 8), (5 / 8, 2 / 8)),
+    # taller q blocks, wider key blocks, blocks walked whole
+    (2048, 1024, 1024, (3 / 4, 2 / 4), (36 / 64, 8 / 64)),
+    (4096, 512, 2048, (36 / 64, 8 / 64), (136 / 256, 16 / 256)),
+    (1536, 512, 1024, (5 / 6, 5 / 6), (5 / 6, 5 / 6)),
+    (1024, 128, 128, (36 / 64, 36 / 64), (36 / 64, 36 / 64)),
+]
+
+
+@pytest.mark.parametrize("seq,block_q,block_k,forward,backward", SHARES)
+def test_computed_and_masked_shares_against_a_count_over_positions(
+        seq, block_q, block_k, forward, backward):
+    """In each kernel every position under the diagonal is computed,
+    every computed position above it is masked, and the counter's shares
+    are the painted areas."""
+    got = fa.causal_sub_tile_shares(seq, seq, block_q, block_k, True)
+    assert got["flash_fwd"] == pytest.approx(forward)
+    assert got["flash_bwd_dkdv"] == got["flash_bwd_dq"]
+    assert got["flash_bwd_dq"] == pytest.approx(backward)
+    under = np.tril(np.ones((seq, seq), bool))
+    for kernel, is_backward in (("flash_fwd", False), ("flash_bwd_dq", True)):
+        computed, unmasked = _painted(seq, block_q, block_k, is_backward)
+        assert computed[under].all()
+        assert not unmasked[~under].any()
+        assert got[kernel] == pytest.approx(
+            (computed.mean(), (computed & ~unmasked).mean()))
+    assert set(fa.causal_sub_tile_shares(
+        seq, seq, block_q, block_k, False).values()) == {(1.0, 0.0)}
+
+
+# --------------------------------------------------------------------------- #
+# calls the bound does not engage on lower as they did before it existed
+# --------------------------------------------------------------------------- #
+def _mosaic_modules(lowered_text):
+    """The Mosaic module of every tpu_custom_call in a lowering, as text
+    without locations (source lines move with every edit)."""
+    from jax.extend.mlir import ir
+    modules = []
+    for m in re.finditer(r'backend_config = "((?:[^"\\]|\\.)*)"',
+                         lowered_text):
+        config = m.group(1).replace("\\22", '"').replace("\\\\", "\\")
+        body = json.loads(config)["custom_call_config"]["body"]
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(body))
+            modules.append(module.operation.get_asm(enable_debug_info=False))
+    return modules
+
+
+WHOLE_TILE_CALLS = {
+    "noncausal_dropout": dict(causal=False, dropout_rate=0.1),
+    "noncausal": dict(causal=False, dropout_rate=0.0),
+    "causal_256x256": dict(causal=True, dropout_rate=0.1, block_q=256,
+                           block_k=256),
+    "causal_512x768": dict(causal=True, dropout_rate=0.1, seq=1536),
+}
+
+
+def whole_tile_digests(name):
+    """sha256 of the three kernels' Mosaic modules for one call of
+    WHOLE_TILE_CALLS at [4, 20, S, 64], lowered for the TPU from here."""
+    kw = dict(WHOLE_TILE_CALLS[name])
+    shape = (4, 20, kw.pop("seq", 1024), 64)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct(shape[:3], jnp.float32)
+    seed = jax.ShapeDtypeStruct((), jnp.int32)
+
+    def fwd(q, k, v, s):
+        return fa.flash_attention_pallas(q, k, v, return_lse=True,
+                                         dropout_seed=s, **kw)
+
+    def bwd(q, k, v, out, lse_, do, s):
+        return fa.flash_attention_bwd_pallas(q, k, v, out, lse_, do,
+                                             dropout_seed=s, **kw)
+
+    modules = []
+    for f, args in ((fwd, (x, x, x, seed)), (bwd, (x, x, x, x, lse, x, seed))):
+        text = jax.jit(f).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+        modules += _mosaic_modules(text)
+    assert len(modules) == 3
+    return [hashlib.sha256(m.encode()).hexdigest() for m in modules]
+
+
+@pytest.mark.parametrize("name", sorted(WHOLE_TILE_CALLS))
+def test_whole_tile_calls_lower_as_before_the_bound(name):
+    """A non-causal call, and a causal call whose key block the sub-tile
+    does not divide, give the Mosaic modules the kernels gave before the
+    bound existed: golden/flash_whole_tile_modules.json was recorded by
+    this same function from the commit before it (9885b32)."""
+    with open(GOLDEN) as f:
+        recorded = json.load(f)
+    assert whole_tile_digests(name) == recorded[name]
