@@ -89,9 +89,10 @@ class MarkovLanguage:
 
 def main():
     # Inside main, not module level: unit tests import MarkovLanguage
-    # from this module, and _harness's compile-cache side effect must
-    # not leak into the pytest process.
-    import _harness  # noqa: F401  — compile cache
+    # from this module, and the compile cache must not leak into the
+    # pytest process.
+    from deepspeed_tpu.utils.chip import enable_compile_cache
+    enable_compile_cache()
     import jax
 
     import deepspeed_tpu as ds

@@ -30,7 +30,9 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmarks import _harness  # noqa: F401,E402 — compile cache
+from deepspeed_tpu.utils.chip import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()
 
 
 def build_param_tree(cfg, seed=0):

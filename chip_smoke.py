@@ -194,6 +194,18 @@ def memory(devices, key):
     return [(d.memory_stats() or {}).get(key) for d in devices]
 
 
+def flops_per_token(cfg, seq):
+    """Training FLOPs a token that the MFU line divides by: the
+    benchmark's own count (perf/flops.py, causal attention at half the
+    square), so the smoke test's MFU and the ledger's `mfu_pct` are one
+    arithmetic.  Imported here, after the package has been: the script
+    runs from the checkout root, where `perf/` sits beside it."""
+    from perf.flops import decoder_train_flops_per_token
+    return decoder_train_flops_per_token(
+        cfg.hidden_size, cfg.num_layers, seq, cfg.vocab_size,
+        intermediate=cfg.intermediate_size)
+
+
 def mib(values):
     return "[" + ", ".join("n/a" if v is None else f"{v / 2**20:,.0f}"
                            for v in values) + "] MiB"
@@ -229,7 +241,7 @@ def run_phase(name, size, devices, stage, interpret, peaks):
     else:
         step = float(np.median(step_s))
         tokens = size["batch_per_chip"] * chips * size["seq"]
-        mfu = (tokens / step * cfg.flops_per_token()
+        mfu = (tokens / step * flops_per_token(cfg, size["seq"])
                / (chips * peaks["bf16_tflops"] * 1e12))
         say(f"    set-up (init + compile + first step): {setup_s:.1f} s")
         say(f"    step: median {step * 1e3:.1f} ms over {len(step_s)} "

@@ -520,7 +520,7 @@ class ProgramAuditor:
             report.signature, len(report.collective_sequence), self.cfg))
         contributors.sort(key=lambda kv: -kv[1])
         # budget is checked against the same gas-weighted per-step total
-        # the report (and bench rows) publish
+        # the report publishes
         report.findings.extend(comm_budget_finding(
             report.wire_bytes_per_step, contributors, self.cfg))
         report.donation_waste_bytes = donation_waste_bytes(targets,

@@ -22,14 +22,17 @@ module closes the loop:
   rank        by predicted_step_time_lb with per-lane attribution
               (compute / memory / hidden-comm / exposed-comm / swap) so
               the report says WHY each winner wins
-  emit        the top-K as bench-ready config JSONs — each must pass
+  emit        the top-K as engine-ready config JSONs — each must pass
               the same `cli.main --mode error` gate CI runs before it
               is written — plus a machine-readable leaderboard
-              (autotune_results.json) bench.py ingests as ladder rows
+              (autotune_results.json; nothing in the repo reads it back
+              since the ladder script that did was deleted — running a
+              ranked config on the chip is a benchmark cell's job)
   calibrate   fit the hw_{peak_tflops,hbm_gbps,ici_gbps} constants from
               measured-vs-predicted reconciliation windows (the
-              monitor's records or a bench row's embedded summary), so
-              the next search ranks with THIS hardware's numbers
+              monitor's records, or any JSON row that embeds such a
+              summary), so the next search ranks with THIS hardware's
+              numbers
 
 Mirrors the reference DeepSpeed's config-sweep culture and the
 interconnect-aware partitioning search of arXiv:2501.04266, applied to
@@ -390,7 +393,7 @@ def _empty_search_message(outcome: SearchOutcome) -> str:
 
 
 # --------------------------------------------------------------------- #
-# emission: bench-ready configs + machine-readable leaderboard
+# emission: engine-ready configs + machine-readable leaderboard
 # --------------------------------------------------------------------- #
 def _leaderboard_entry(rank: int, rc: RankedCandidate,
                        config_file: Optional[str]) -> Dict[str, Any]:
@@ -441,7 +444,7 @@ def results_payload(outcome: SearchOutcome, top_k: int,
 
 def emit_results(outcome: SearchOutcome, out_dir: str,
                  top_k: int) -> Dict[str, Any]:
-    """Write the top-K bench-ready configs plus autotune_results.json.
+    """Write the top-K engine-ready configs plus autotune_results.json.
 
     Every emitted config must itself pass the SAME ``cli.main --mode
     error`` gate CI runs over docs/examples — a config the auditor
@@ -540,8 +543,8 @@ def _emit_gate(cfg: Dict[str, Any], outcome: SearchOutcome,
 
 
 def validate_results(payload: Dict[str, Any]) -> None:
-    """Schema check for autotune_results.json — shared by the writer,
-    the bench-ladder ingester, and the CI smoke test, so a malformed
+    """Schema check for autotune_results.json — shared by the writer
+    and the CI smoke test, so a malformed
     artifact fails at the boundary with a named defect."""
     def _fail(msg):
         raise AutotuneError(f"invalid autotune results: {msg}")
@@ -590,9 +593,10 @@ def validate_results(payload: Dict[str, Any]) -> None:
 def extract_reconciliation_windows(path: str) -> List[Dict[str, Any]]:
     """Pull (measured step time, predicted lanes) pairs out of a
     records artifact: a monitor JSONL stream (kind == "reconcile"
-    records), a bench JSON line/file with an embedded "reconciliation"
-    summary (stale-marked rows included — the reconciliation is real
-    even when the row is stale), or a bare list of window dicts."""
+    records), a JSON line/file with an embedded "reconciliation"
+    summary (no script of the repo writes such rows any longer: only
+    tests exercise this form, ROADMAP D5), or a bare list of window
+    dicts."""
     objs: List[Any] = []
     with open(path) as f:
         text = f.read()

@@ -27,14 +27,14 @@ Subcommands (docs/autotuner.md):
         [--out DIR] [--json] [--update-golden]
 
 runs the offline cost-model-driven config search (analysis/autotuner.py)
-and emits the top-K bench-ready configs + autotune_results.json;
+and emits the top-K engine-ready configs + autotune_results.json;
 
     python -m deepspeed_tpu.analysis calibrate --records FILE \
         [--base-config base.json] [--out hw_calibration.json] [--json]
 
 fits the hw_{peak_tflops,hbm_gbps,ici_gbps} constants from
 measured-vs-predicted reconciliation windows (monitor JSONL records or
-a bench row's embedded reconciliation), writing the calibration file
+a JSON row that embeds a reconciliation), writing the calibration file
 the next ``tune --calibration`` loads.
 """
 
@@ -307,7 +307,7 @@ def build_tune_parser() -> argparse.ArgumentParser:
                     "(docs/autotuner.md): enumerate the decision space, "
                     "prune hard constraints, trace survivors on a "
                     "simulated mesh, rank by the static step-time lower "
-                    "bound, emit top-K bench-ready configs + "
+                    "bound, emit top-K engine-ready configs + "
                     "autotune_results.json.")
     p.add_argument("--config", required=True,
                    help="base DeepSpeed JSON config (its `autotuning` "
@@ -324,7 +324,7 @@ def build_tune_parser() -> argparse.ArgumentParser:
                         "(default: autotuning.hbm_budget_mb, then "
                         "analysis.hbm_budget_mb)")
     p.add_argument("--top-k", type=int, default=None,
-                   help="bench-ready configs to emit (default: "
+                   help="engine-ready configs to emit (default: "
                         "autotuning.top_k)")
     p.add_argument("--calibration", default=None,
                    help="hw-calibration file from the `calibrate` "
@@ -349,12 +349,12 @@ def build_calibrate_parser() -> argparse.ArgumentParser:
         description="Fit the canonical hardware constants "
                     "hw_{peak_tflops,hbm_gbps,ici_gbps} from measured-vs-"
                     "predicted reconciliation windows (monitor JSONL "
-                    "records, a bench row's embedded reconciliation — "
-                    "stale-marked rows included — or a bare window "
+                    "records, a JSON row that embeds a reconciliation, "
+                    "or a bare window "
                     "list), writing the calibration file the next "
                     "`tune --calibration` loads.")
     p.add_argument("--records", required=True,
-                   help="monitor JSONL stream / bench row JSON / window "
+                   help="monitor JSONL stream / row JSON / window "
                         "list to fit from")
     p.add_argument("--base-config", default=None,
                    help="config whose analysis block supplies the base "
@@ -467,7 +467,7 @@ def tune_main(argv) -> int:
               f"wire {entry['wire_bytes_per_step']} B  "
               f"peak_hbm {entry['peak_hbm_bytes'] / 2 ** 20:.1f} MiB")
     print(f"wrote {os.path.join(args.out, RESULTS_FILENAME)} "
-          f"(+{len(payload['leaderboard'])} bench-ready config(s))")
+          f"(+{len(payload['leaderboard'])} engine-ready config(s))")
     return 0
 
 
@@ -513,7 +513,7 @@ def calibrate_main(argv) -> int:
         print(f"calibrate: no reconciliation windows found in "
               f"{args.records} — need records carrying "
               "measured_step_time_s + lanes (monitor JSONL `reconcile` "
-              "records, or a bench row's embedded `reconciliation`)",
+              "records, or a JSON row's embedded `reconciliation`)",
               file=sys.stderr)
         return 1
     from .autotuner import fit_hw_calibration

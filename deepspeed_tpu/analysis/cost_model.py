@@ -1,10 +1,9 @@
 """Static step-time model — a roofline lower bound for the traced step.
 
-Every future perf PR ships with predicted-vs-measured provenance
-(ROADMAP items 1 and 3): bench rows embed this lower bound next to the
-lockstep signature and wire bytes, so "the fused step should be ~X ms"
-is a number computed from the program, not a hope.  Three terms, each a
-genuine lower bound:
+The audit report carries this lower bound next to the lockstep
+signature and wire bytes, and the monitor reconciles measured windows
+against it, so "the fused step should be ~X ms" is a number computed
+from the program, not a hope.  Three terms, each a genuine lower bound:
 
   compute   total jaxpr flops (profiling/flops_profiler walk, scan trip
             counts multiplied in) at the configured peak
@@ -49,9 +48,10 @@ def program_io_bytes(closed_jaxpr) -> int:
 
 def per_lane_predictions(step_time: Dict[str, Any]) -> Dict[str, Any]:
     """Normalize a step-time payload (build_step_time_model output) into
-    the per-lane form the runtime monitor's reconciliation consumes
-    (monitor/reconcile.py) and bench rows embed: one entry per cost-model
-    lane plus the binding term and the lower bound itself.  Single-sourced
+    the per-lane form the runtime monitor's reconciliation
+    (monitor/reconcile.py) and the autotuner consume: one entry per
+    cost-model lane plus the binding term and the lower bound itself.
+    Single-sourced
     here so the static and measured halves can never disagree on lane
     names."""
     return {

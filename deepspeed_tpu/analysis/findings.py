@@ -1,7 +1,7 @@
 """Structured lint findings + the audit report container.
 
 Every rule emits ``Finding`` records instead of log lines so that CI, the
-engine init summary, bench rows, and the CLI all consume the same data —
+engine init summary and the CLI all consume the same data —
 the reference DeepSpeed has no analog (its failure modes surface as hung
 pods and OOMs at runtime; see ISSUE 5 / docs/program_auditor.md).
 """

@@ -40,6 +40,10 @@ across jaxlib versions for the post-optimization program.  The parser
 is deliberately structural — computations, instructions, called
 computations, replica groups — and every quantity it extracts is pinned
 by fixture tests against real XLA output (tests/unit/test_hlo_audit.py).
+Two printed forms are read alike: operands with their shapes inline and
+``source_file=`` / ``source_line=`` metadata (jax 0.4.x), and operands
+by bare ``%name`` with ``stack_frame_id=`` metadata resolved through the
+module's FileNames / FileLocations / StackFrames tables (jax 0.9.0).
 """
 
 import re
@@ -92,7 +96,17 @@ _COMP_RE = re.compile(r"^\s*(?P<entry>ENTRY\s+)?%(?P<name>[^\s(]+)\s*\(")
 _METADATA_RE = re.compile(
     r'metadata=\{[^}]*?op_name="(?P<op_name>[^"]*)"'
     r'(?:[^}]*?source_file="(?P<file>[^"]*)")?'
-    r'(?:[^}]*?source_line=(?P<line>\d+))?')
+    r'(?:[^}]*?source_line=(?P<line>\d+))?'
+    r'(?:[^}]*?stack_frame_id=(?P<frame>\d+))?')
+_OPERAND_NAME_RE = re.compile(r"%([^\s,()]+)")
+# the module header's source tables (stack_frame_id -> file:line)
+_TABLE_RE = re.compile(
+    r"^(FileNames|FunctionNames|FileLocations|StackFrames)\s*$", re.M)
+_FILE_NAME_ROW_RE = re.compile(r'^(\d+)\s+"(.*)"\s*$', re.M)
+_FILE_LOCATION_ROW_RE = re.compile(
+    r"^(\d+)\s+\{file_name_id=(\d+)\b.*?\bline=(\d+)", re.M)
+_STACK_FRAME_ROW_RE = re.compile(
+    r"^(\d+)\s+\{file_location_id=(\d+)", re.M)
 _TRIP_RE = re.compile(r'"known_trip_count"\s*:\s*\{\s*"n"\s*:\s*"(\d+)"')
 # greedy digits/braces body (the lazy form would stop at the FIRST
 # inner '}' of {{0,1},{2,3}}); the [^a-z=] class halts at the next
@@ -144,6 +158,52 @@ def _paren_operands(line: str, opcode: str) -> str:
     return line[start + 1:]
 
 
+def _split_operands(text: str) -> List[str]:
+    """Top-level comma split of an operand list (commas inside a
+    shape's dims, layout or tuple belong to that operand)."""
+    out, depth, start = [], 0, 0
+    for i, ch in enumerate(text):
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+        elif ch == "," and depth == 0:
+            out.append(text[start:i])
+            start = i + 1
+    out.append(text[start:])
+    return [o for o in out if o.strip()]
+
+
+def _operand_bytes(text: str, shapes: Dict[str, str]) -> int:
+    """Bytes of an operand list.  An operand printed with its shape
+    (``f32[16,32]{1,0} %x``) is read off the line; one printed as a bare
+    ``%x`` takes the shape of the instruction (or parameter) of the same
+    computation that defines it, ``shapes``."""
+    total = 0
+    for operand in _split_operands(text):
+        name = _OPERAND_NAME_RE.search(operand)
+        if name is not None and not _SHAPE_RE.search(operand):
+            operand = shapes.get(name.group(1), "")
+        total += _shape_bytes(operand)
+    return total
+
+
+def _frame_sources(text: str) -> Dict[str, str]:
+    """stack_frame_id -> "file:line", from the tables a module printed
+    with ``stack_frame_id=`` metadata carries in its header (empty for
+    text that names the source in each instruction's metadata)."""
+    parts = _TABLE_RE.split(text)
+    tables = dict(zip(parts[1::2], parts[2::2]))
+    files = dict(_FILE_NAME_ROW_RE.findall(tables.get("FileNames", "")))
+    locations = {
+        loc: f"{files[name]}:{line}"
+        for loc, name, line in _FILE_LOCATION_ROW_RE.findall(
+            tables.get("FileLocations", "")) if name in files}
+    return {frame: locations[loc]
+            for frame, loc in _STACK_FRAME_ROW_RE.findall(
+                tables.get("StackFrames", "")) if loc in locations}
+
+
 class HloInstr(NamedTuple):
     name: str
     opcode: str
@@ -182,6 +242,7 @@ class HloProgram:
     def __init__(self, text: str):
         self.computations: Dict[str, List[HloInstr]] = {}
         self.entry: Optional[str] = None
+        self.frame_sources = _frame_sources(text)
         m = _NUM_PARTITIONS_RE.search(text)
         self.num_partitions = int(m.group(1)) if m else 1
         current: Optional[List[HloInstr]] = None
@@ -227,12 +288,15 @@ def _canonical_opcode(opcode: str) -> Optional[str]:
 
 
 def _collective_wire_bytes(instr: HloInstr, opcode: str,
-                           group_size: int) -> int:
+                           group_size: int,
+                           shapes: Dict[str, str]) -> int:
     """Replica-group-aware wire bytes, on the jaxpr accounting's
     conventions: a gather is priced at its group-sized OUTPUT (operand
     bytes x participants — matches `step_wire_bytes` counting gather
-    outvars), reductions/permutes at their operand bytes."""
-    operands = _shape_bytes(_paren_operands(instr.line, instr.opcode))
+    outvars), reductions/permutes at their operand bytes.  ``shapes``
+    names the shapes of the enclosing computation's instructions."""
+    operands = _operand_bytes(_paren_operands(instr.line, instr.opcode),
+                              shapes)
     if opcode in GATHER_OPCODES:
         return operands * group_size
     return operands
@@ -256,6 +320,7 @@ def walk_hlo_collectives(program: HloProgram,
         if comp is None or comp_name in visiting:
             return
         visiting.append(comp_name)
+        shapes = {i.name: i.shape for i in comp}
         for instr in comp:
             opcode = _canonical_opcode(instr.opcode)
             if opcode is not None:
@@ -268,6 +333,9 @@ def walk_hlo_collectives(program: HloProgram,
                     source = meta.group("file")
                     if meta.group("line"):
                         source += f":{meta.group('line')}"
+                elif meta and meta.group("frame"):
+                    source = program.frame_sources.get(
+                        meta.group("frame"), "")
                 last = op_name.rsplit("/", 1)[-1]
                 prim = next((p for p in _TRACED_PRIMS
                              if re.search(rf"\b{p}\b", last)), None)
@@ -281,7 +349,7 @@ def walk_hlo_collectives(program: HloProgram,
                     opcode=opcode, name=instr.name, target=target_label,
                     wire_bytes=(0 if degenerate else
                                 _collective_wire_bytes(instr, opcode,
-                                                       size)),
+                                                       size, shapes)),
                     mult=mult, group_size=size, n_groups=n_groups,
                     op_name=op_name, source=source,
                     traced=prim is not None, counted=counted,

@@ -141,7 +141,7 @@ def donation_waste_bytes(targets: List[AuditTarget], cfg) -> int:
 def lockstep_expectation_finding(signature: str, n_collectives: int,
                                  cfg) -> List[Finding]:
     """Report-level check of the COMBINED engine signature (what the CLI
-    prints, bench embeds, and users pin in analysis.expected_signature)
+    prints and users pin in analysis.expected_signature)
     against the configured expectation — per-target signatures feed into
     it but are not individually pinnable."""
     if not cfg.expected_signature or signature is None:

@@ -38,7 +38,7 @@ class AutotuneError(RuntimeError):
 
 @dataclass
 class Candidate:
-    """One enumerated point: a bench-ready engine config + the flat knob
+    """One enumerated point: a engine-ready engine config + the flat knob
     summary the leaderboard reports."""
     name: str
     config: Dict[str, Any]
@@ -137,7 +137,7 @@ def _build_config(base: Dict[str, Any], *, stage, streamed, pmode,
                   hpz, fused, offload, pdepth, odepth,
                   fixed, fcm=False, onebit=False) -> Dict[str, Any]:
     raw = copy.deepcopy(base)
-    # candidates are bench-ready engine configs: the search description
+    # candidates are engine-ready engine configs: the search description
     # itself must not ride along
     raw.pop(C.AUTOTUNING, None)
     raw[C.MESH] = {C.MESH_DATA_AXIS: data, C.MESH_MODEL_AXIS: model,

@@ -1008,7 +1008,7 @@ class AutotuningConfig:
     """Config-autotuner block (docs/autotuner.md): the offline search
     bounds, fixed knobs, and budget for ``python -m
     deepspeed_tpu.analysis tune``.  Purely a SEARCH description — the
-    engine never reads it, so a bench-ready emitted config can carry the
+    engine never reads it, so a engine-ready emitted config can carry the
     block that produced it as provenance."""
     chips: Optional[int] = C.AUTOTUNING_CHIPS_DEFAULT
     global_batch: Optional[int] = C.AUTOTUNING_GLOBAL_BATCH_DEFAULT

@@ -113,10 +113,10 @@ DUMP_STATE = "dump_state"
 DUMP_STATE_DEFAULT = False
 
 # Engine PRNG implementation for the default (no rng= passed) stream.
-# "rbg" is the fast TPU choice (~14 ms/step over threefry on the flagship
-# bench) but JAX documents rbg streams as NOT stable across backends or
-# JAX versions; set "threefry" for bit-reproducible default dropout/noise
-# across upgrades and CPU-vs-TPU runs.
+# "rbg" is the fast TPU choice (~14 ms/step over threefry at GPT-2 124M,
+# round 2, jax 0.4.37) but JAX documents rbg streams as NOT stable across
+# backends or JAX versions; set "threefry" for bit-reproducible default
+# dropout/noise across upgrades and CPU-vs-TPU runs.
 PRNG_IMPL = "prng_impl"
 PRNG_IMPL_DEFAULT = "rbg"
 
@@ -247,7 +247,7 @@ ANALYSIS_SPMD_MATCH_TOLERANCE_DEFAULT = 0.05
 # (mesh factorization, ZeRO stage/variant, gas/micro splits, qwZ/qgZ/
 # hpZ, fused vs modular, offload tier) — prune on hard constraints,
 # trace survivors on a simulated mesh, rank by the static step-time
-# lower bound, emit the top-K as bench-ready configs.  The block only
+# lower bound, emit the top-K as engine-ready configs.  The block only
 # configures `python -m deepspeed_tpu.analysis tune`; it never changes
 # engine behavior.
 #############################################

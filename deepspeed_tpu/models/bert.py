@@ -95,16 +95,6 @@ class BertConfig:
                   self.type_vocab_size) * self.hidden_size
         return n
 
-    def flops_per_token(self, seq_len: Optional[int] = None) -> int:
-        """Training FLOPs/token (fwd+bwd ≈ 6N + attention + MLM head), the
-        Megatron-style accounting used for MFU (matches GPT2Config: the
-        vocab projection is a real MXU matmul and belongs in the count)."""
-        n = self.num_params(include_embeddings=False)
-        s = seq_len if seq_len is not None else self.max_position_embeddings
-        attn = 12 * self.num_layers * self.hidden_size * s
-        head = 6 * self.hidden_size * self.vocab_size
-        return 6 * n + attn + head
-
 
 class BertModel:
     """Encoder LM over stacked DeepSpeedTransformerLayers (MLM objective)."""

@@ -53,7 +53,7 @@ class GPT2Config:
     tie_word_embeddings: bool = True
     # chunked LM-head + cross-entropy: never SAVES the [B,S,V] fp32 logits
     # (ops/fused_cross_entropy.py); None = auto chunk from the transient
-    # budget (largest chunk wins on speed — profile_ce_sweep.py)
+    # budget (largest chunk wins on speed — ops/fused_cross_entropy.py)
     fused_loss: bool = True
     fused_loss_chunk: Optional[int] = None
     # layer-stack execution: None = auto (unrolled up to the measured
@@ -98,16 +98,6 @@ class GPT2Config:
         if include_embeddings:
             n += (self.vocab_size + self.n_positions) * self.hidden_size
         return n
-
-    def flops_per_token(self) -> int:
-        """Training FLOPs/token (fwd+bwd ≈ 6N + attention + LM head), the
-        Megatron-style accounting used for MFU: the vocab projection is a
-        real [*, H]x[H, V] matmul on the MXU and belongs in the count
-        (the embedding LOOKUP does not)."""
-        n = self.num_params(include_embeddings=False)
-        attn = 12 * self.num_layers * self.hidden_size * self.n_positions
-        head = 6 * self.hidden_size * self.vocab_size
-        return 6 * n + attn + head
 
 
 class GPT2Model:

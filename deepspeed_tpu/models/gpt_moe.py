@@ -90,32 +90,6 @@ class GPTMoEConfig:
             bf16=self.bf16, pre_layer_norm=True, causal=True,
             attn_layout=self.attn_layout, ffn=ffn)
 
-    def flops_per_token(self) -> int:
-        """ACTIVE training FLOPs/token (fwd+bwd = 6N_active + attention +
-        LM head) — the MoE analog of GPT2Config.flops_per_token: only the
-        top_k routed experts' FFN parameters count per token (each routed
-        token does 6 x its expert-FFN params of work; the gate matmul is
-        included, the dispatch scatter/gather is not — it moves bytes,
-        not MACs).  This makes the MoE bench rows' TFLOPS/MFU comparable
-        with the dense ladder on the same accounting (VERDICT r4 weak #4:
-        'MoE rows have no comparator')."""
-        h, inter = self.hidden_size, self.intermediate_size
-        dense_layer = DeepSpeedTransformerLayer(self.layer_config("dense"))
-        attn_only = DeepSpeedTransformerLayer(self.layer_config("none"))
-        expert_ffn_active = self.top_k * (2 * h * inter + h + inter)
-        gate = h * self.num_experts
-        n_active = 0
-        for i in range(self.num_layers):
-            if self.is_moe_layer(i):
-                n_active += (attn_only.num_params() + 2 * h +
-                             expert_ffn_active + gate)
-            else:
-                n_active += dense_layer.num_params()
-        n_active += 2 * h  # ln_f
-        attn = 12 * self.num_layers * h * self.n_positions
-        head = 6 * h * self.vocab_size
-        return 6 * n_active + attn + head
-
     def num_params(self) -> int:
         dense = DeepSpeedTransformerLayer(self.layer_config("dense"))
         attn_only = DeepSpeedTransformerLayer(self.layer_config("none"))

@@ -5,9 +5,9 @@ one compiled body over them.  Two execution modes:
 
 - scan: `lax.scan` — one traced body regardless of depth, fastest compile;
 - unrolled: Python loop over the same body — XLA sees the whole depth and
-  fuses across layer boundaries (measured ~18 ms/step faster than scan on
-  the GPT-2 flagship bench, benchmarks/profile_ablations.py), at the cost
-  of compile time linear in depth.
+  fuses across layer boundaries (~18 ms/step faster than scan at GPT-2
+  124M in a round-2 host-clock ablation on jax 0.4.37; the script is
+  gone, git keeps it), at the cost of compile time linear in depth.
 
 The auto policy (`scan_layers=None` in the model configs) unrolls up to
 SCAN_LAYERS_AUTO_THRESHOLD layers and scans beyond.

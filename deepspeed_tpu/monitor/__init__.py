@@ -37,7 +37,7 @@ from .reconcile import (ATTR_COMM_EXPOSED, ATTR_COMM_HIDDEN, ATTR_COMPUTE,
                         FLAG_HBM_ABOVE_BAND,
                         FLAG_HBM_BELOW_BAND, FLAG_MODEL_VIOLATION,
                         FLAG_STEP_TIME_ABOVE_BAND, FLAG_SWAP_BELOW_CEILING,
-                        Bands, attribute_gap, bare_summary, format_line,
+                        Bands, attribute_gap, format_line,
                         reconcile_window)
 from .record import (EVENT_DEAD_EXPERT, EVENT_DIVERGENCE,
                      EVENT_EP_IMBALANCE, EVENT_ROUTER_COLLAPSE,
@@ -70,7 +70,7 @@ __all__ = [
     "TRIGGER_FLAGS",
     "TensorBoardWriter", "TraceEventBuffer", "TrainingMonitor",
     "VEC_FIELDS", "WriterThread", "annotate_stale", "attribute_gap",
-    "attribute_straggler_lane", "bare_summary", "decode_window_vector",
+    "attribute_straggler_lane", "decode_window_vector",
     "device_memory", "encode_window_vector", "format_fleet_line",
     "format_health_line", "format_line", "format_watch_table",
     "identity", "make_step_record", "read_heartbeats", "record",

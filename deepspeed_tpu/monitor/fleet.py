@@ -337,9 +337,7 @@ class FleetAggregator:
 
 
 def summarize_fleet(matrix: np.ndarray) -> Dict[str, Any]:
-    """Fleet-aggregate scalars from a [P, VEC_LEN] window matrix — also
-    the embeddable form bench rows carry (bench.py multichip rows land
-    with per-host attribution built in)."""
+    """Fleet-aggregate scalars from a [P, VEC_LEN] window matrix."""
     matrix = np.asarray(matrix, dtype=np.float64)
     times = matrix[:, _IDX["step_time_mean_s"]]
     losses = matrix[:, _IDX["loss_mean"]]

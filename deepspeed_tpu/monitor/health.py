@@ -483,9 +483,10 @@ def straggler_verdict(matrix: np.ndarray,
                       ep_imbalance_ratio: float =
                       C.MONITOR_MOE_EP_IMBALANCE_RATIO_DEFAULT
                       ) -> Dict[str, Any]:
-    """Single-window cross-sectional verdict (no EWMA history) — the
-    form bench rows embed: with one measured window there is no baseline
-    to z-score against, so the verdict is purely ratio-vs-fleet-median.
+    """Single-window cross-sectional verdict (no EWMA history): with
+    one measured window there is no baseline to z-score against, so the
+    verdict is purely ratio-vs-fleet-median.  Only tests call it now
+    (ROADMAP D5).
     A 1-host matrix is the degenerate case: ratio 1.0, no straggler.
     ``ep_imbalance_ratio`` gates the expert-hotspot lane exactly like
     the live detector — pass the configured monitor.moe value so the

@@ -168,15 +168,6 @@ def reconcile_window(measured: Dict[str, Any],
     return out
 
 
-def bare_summary(rec: Dict[str, Any]) -> Dict[str, Any]:
-    """A reconciliation payload without its stream-record envelope
-    (kind + window keys) — the embeddable form bench rows carry."""
-    out = dict(rec)
-    for key in (R.F_KIND, R.R_WINDOW_START, R.R_WINDOW_END):
-        out.pop(key, None)
-    return out
-
-
 def format_line(rec: Dict[str, Any]) -> str:
     """One-line log form of a reconciliation payload."""
     bits = []

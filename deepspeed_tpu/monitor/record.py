@@ -1,10 +1,9 @@
 """Step-record schema — the single source of metric field names.
 
 One optimizer step produces one structured record.  Every consumer —
-the JSONL/CSV/TensorBoard writers, the reconciliation report, and the
-bench ladder rows (bench.py) — imports these names instead of spelling
-its own, so a field rename is a one-file change and a bench row can
-never drift from the stream schema.
+the JSONL/CSV/TensorBoard writers and the reconciliation report —
+imports these names instead of spelling its own, so a field rename is a
+one-file change.
 
 The record is assembled with BOUNDARY-ONLY host reads: per-step fields
 are either pure host state (wall time, counters) or device scalar

@@ -5,7 +5,7 @@ arrays reach this module.  The ``WriterThread`` decouples file I/O from
 the step loop: the monitor enqueues record batches at flush boundaries
 and the daemon thread writes them, so a slow disk (or a wedged NFS
 mount) can never block a training step.  ``close()`` drains the queue
-before returning, so tests and benches read complete files.
+before returning, so tests and readers read complete files.
 
 ``ScalarJsonlWriter`` doubles as the torch-free TensorBoard stand-in:
 it implements the ``add_scalar``/``flush``/``close`` subset of

@@ -1365,8 +1365,9 @@ def _flash_over_mesh(q, k, v, seed, causal, sm_scale, block_q, block_k,
                                 (dims, dims, dims, None), dims)
 
 
-# Default block sizes, tuned on v5e (benchmarks/profile_flash_blocks.py,
-# state-feedback + fetch-sync measurement): large blocks dominate —
+# Default block sizes, tuned on v5e in round 3 (jax 0.4.37, a host-clock
+# block sweep with state feedback and a fetch sync; the script is gone,
+# git keeps it): large blocks dominate —
 # 128x128 is grid-overhead-bound (S=4096 fwd+bwd: 28.1 ms at 128x128 vs
 # 6.7 ms at 1024x1024; S=1024: 10.0 -> 4.3 ms).  With these blocks the
 # Pallas kernel beats the batched-XLA attention at the kernel level for

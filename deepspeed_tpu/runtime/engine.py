@@ -419,8 +419,9 @@ class DeepSpeedEngine:
             training_data, collate_fn)
         # Default-stream PRNG impl is a config knob ("prng_impl").  rbg:
         # split/fold_in are cheap and mask generation vectorizes on the TPU
-        # VPU — measured ~14 ms/step faster than threefry on the flagship
-        # bench (benchmarks/profile_ablations2.py) — but JAX documents rbg
+        # VPU — ~14 ms/step faster than threefry at GPT-2 124M in a round-2
+        # host-clock ablation on jax 0.4.37 (the script is gone, git keeps
+        # it; not measured since) — but JAX documents rbg
         # streams as NOT stable across backends/versions; configs needing
         # bit-reproducible default dropout across upgrades or CPU-vs-TPU
         # set prng_impl="threefry".  Callers passing their own `rng` keep
@@ -503,8 +504,8 @@ class DeepSpeedEngine:
         # severity findings; "warn" logs them.
         self.program_audit = None
         self._recompile_guard = None
-        # static step-time lower bound (analysis/cost_model.py) — bench
-        # rows and monitors read this for predicted-vs-measured rows
+        # static step-time lower bound (analysis/cost_model.py) — the
+        # monitor's reconciliation reads it for predicted-vs-measured
         self.predicted_step_time_lb_s = None
         self.analysis = self.config.analysis_config
         if self.analysis.enabled:
@@ -1121,7 +1122,7 @@ class DeepSpeedEngine:
         memory win is deliberately undone — docs/onebit.md), allocates
         the worker-stacked wire-error state, builds (or reuses) the
         phase-B programs, and tells the RecompileGuard this retrace was
-        PLANNED: counted in the tally (benches pin it at exactly one) but
+        PLANNED: counted in the tally (tests pin it at exactly one) but
         never charged against the storm budget.  A checkpoint load that
         lands past freeze_step re-enters with planned=False — the resume
         retrace is already accounted by the guard's restore contract."""

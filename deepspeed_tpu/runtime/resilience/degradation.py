@@ -8,13 +8,13 @@ reads, fleet aggregation → disabled, atomic checkpoint commit → legacy
 in-place.  Each used to warn (or not) in its own style; a run that
 silently landed on the slow tier was indistinguishable from the real
 thing — exactly the failure mode that costs the whole wire win in the
-low-bandwidth regimes the bench rows are meant to pin.
+low-bandwidth regimes.
 
 Every ladder now reports here: a structured :class:`DegradationEvent`
 (subsystem, from-tier, to-tier, reason) with a one-shot loud warning,
 deduplicated by (subsystem, from, to) with a repeat count.  The
-registry surfaces in three places: the monitor stream (``degradation``
-meta records), the engine init summary line, and audited bench rows.
+registry surfaces in two places: the monitor stream (``degradation``
+meta records) and the engine init summary line.
 
 Process-global by design — the ladders live in modules with no engine
 handle (aio_handle, stage3_streaming) and a degradation describes the
@@ -75,7 +75,7 @@ class DegradationRegistry:
             return [self._events[k].as_dict() for k in self._order]
 
     def summary(self) -> str:
-        """Compact one-line form for the engine init log and bench rows,
+        """Compact one-line form for the engine init log,
         e.g. ``aio:io_uring->python, tensorboard:torch->jsonl``."""
         with self._lock:
             return ", ".join(

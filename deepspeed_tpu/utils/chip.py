@@ -1,6 +1,7 @@
 """What every script that runs on the chip shares (chip_smoke.py,
-bench.py, benchmarks/): the device it ran on, that device's published
-peaks, and where compiled programs are cached.
+benchmarks/): the device it ran on, that device's published peaks, and
+where compiled programs are cached.  The benchmark (perf/) keeps its own
+copy of the peaks on purpose (perf/peaks.py says why).
 
 Importing this module does not initialize a JAX backend, so a launcher
 parent may import it without taking the chip from its children.

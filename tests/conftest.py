@@ -137,7 +137,6 @@ _SLOW_PREFIXES = (
     # HLO-compiles every candidate in the search (the dense twin's wire
     # is GSPMD-inserted, so monotonicity needs the compiled view)
     "test_autotuner.py::test_onebit_never_increases_wire_bytes",
-    "test_bench_harness.py::test_sigterm_emits_one_diagnostic_json_line",
     "test_checkpoint_matrix.py::test_roundtrip",
     "test_convergence.py::test_gpt2_engine_converges",
     "test_engine_couplings.py::test_eigenvalue_disabled_keeps_global_schedule",

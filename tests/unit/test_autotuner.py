@@ -11,7 +11,7 @@ bounded smoke search (<= 12 candidates on the simulated 8-device mesh,
 nonzero survivors, valid autotune_results.json schema), loud
 empty-search failures naming the binding constraint, the NVMe swap
 lane (a streamed config must NOT rank like a resident one), and the
-bench-ladder ingestion + row -> calibrate loop.
+row -> calibrate loop.
 
 The module-scoped fixture runs the example search ONCE (ten traced
 candidates, ~12 s); every cheap cell reads it instead of re-searching.
@@ -19,8 +19,6 @@ candidates, ~12 s); every cheap cell reads it instead of re-searching.
 
 import copy
 import json
-import os
-import sys
 from pathlib import Path
 
 import pytest
@@ -128,7 +126,7 @@ def test_golden_search_space_is_bounded(example_outcome):
 
 
 # --------------------------------------------------------------------- #
-# emission: schema + auditor-clean bench-ready configs
+# emission: schema + auditor-clean engine-ready configs
 # --------------------------------------------------------------------- #
 def test_emitted_results_schema_and_configs(emitted):
     out_dir, payload = emitted
@@ -139,7 +137,7 @@ def test_emitted_results_schema_and_configs(emitted):
     assert len(on_disk["leaderboard"]) == 3
     for entry in on_disk["leaderboard"]:
         cfg = json.loads((out_dir / entry["config_file"]).read_text())
-        # bench-ready: engine knobs only — the search block must not
+        # engine-ready: engine knobs only — the search block must not
         # ride along, the provenance block must
         assert C.AUTOTUNING not in cfg
         assert cfg["_autotune"]["name"] == entry["name"]
@@ -439,8 +437,8 @@ def test_calibrate_cli_no_windows_exits_nonzero(tmp_path, capsys):
 
 
 def test_bench_row_reconciliation_feeds_calibrate(tmp_path):
-    """A bench row's embedded reconciliation (stale-marked or not) is a
-    calibration source — the ISSUE's 'validate on chip once' loop."""
+    """A JSON row's embedded reconciliation is a calibration source,
+    whatever else the row carries."""
     row = {"metric": "x", "value": 1.0, "stale": True,
            "reconciliation": {"measured_step_time_s": 0.5,
                               "lanes": {"compute": 0.2, "memory": 0.1,
@@ -683,49 +681,3 @@ def test_enumeration_is_gated():
             "max_candidates": 12}), 8, 16)
     cpu_names = [c.name for c in cpu_space.candidates]
     assert cpu_names and all(n.endswith("off-cpu") for n in cpu_names)
-
-
-# --------------------------------------------------------------------- #
-# bench-ladder ingestion
-# --------------------------------------------------------------------- #
-def test_bench_autotune_ingests_top_rank(emitted, monkeypatch):
-    """bench.py --config autotune runs the rank-1 emitted config
-    verbatim and embeds the search's prediction next to the measured
-    step time (the reconciliation a later `calibrate` reads)."""
-    sys.path.insert(0, str(REPO))
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    out_dir, payload = emitted
-    monkeypatch.setenv("DS_BENCH_AUTOTUNE_RESULTS",
-                       str(out_dir / RESULTS_FILENAME))
-    monkeypatch.setenv("DS_BENCH_AUTOTUNE_RANK", "1")
-    ds.reset_mesh_context()
-    try:
-        row = bench.bench_autotune()
-    finally:
-        ds.reset_mesh_context()
-    assert row["metric"] == "autotune_candidate_train_tokens_per_sec"
-    assert row["value"] > 0
-    assert row["autotune_rank"] == 1
-    assert row["autotune_name"] == payload["leaderboard"][0]["name"]
-    assert row["autotune_predicted_step_time_lb_s"] == pytest.approx(
-        payload["leaderboard"][0]["predicted_step_time_lb_s"])
-    assert row["autotune_measured_over_predicted"] > 0
-    rec = row.get("reconciliation")
-    assert rec and rec["measured_step_time_s"] > 0 and rec["lanes"]
-
-
-def test_bench_autotune_missing_rank_fails_loudly(emitted, monkeypatch):
-    sys.path.insert(0, str(REPO))
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    out_dir, _ = emitted
-    monkeypatch.setenv("DS_BENCH_AUTOTUNE_RESULTS",
-                       str(out_dir / RESULTS_FILENAME))
-    monkeypatch.setenv("DS_BENCH_AUTOTUNE_RANK", "99")
-    with pytest.raises(RuntimeError, match="no rank 99"):
-        bench.bench_autotune()

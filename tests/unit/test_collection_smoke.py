@@ -52,7 +52,7 @@ def test_resilience_package_imports_cleanly():
             # auditor's hlo path and the CLI's --hlo-audit
             "deepspeed_tpu.analysis.hlo_audit",
             # config autotuner: lazily imported by the tune/calibrate
-            # subcommands and bench.py's autotune ladder row
+            # subcommands
             "deepspeed_tpu.analysis.search_space",
             "deepspeed_tpu.analysis.autotuner",
             # source-invariant lint (round 22): lazily imported by the
@@ -68,12 +68,11 @@ def test_resilience_package_imports_cleanly():
             "deepspeed_tpu.analysis.source_lint.rules_knobs",
             "deepspeed_tpu.analysis.source_lint.rules_checkpoint",
             # fused collective-matmul kernels: lazily reachable through
-            # the streaming context's fcm routing and the bench fcm row
+            # the streaming context's fcm routing
             "deepspeed_tpu.ops.collective_matmul",
             # 1-bit optimizer wire tier: the compressed transport and
             # wire accounting are lazily imported by the engine (only
-            # when low_bandwidth.onebit is on) and by bench.py's
-            # gpt2_onebit row
+            # when low_bandwidth.onebit is on)
             "deepspeed_tpu.runtime.comm.onebit",
             "deepspeed_tpu.runtime.comm.compressed",
             "deepspeed_tpu.runtime.comm.low_bandwidth",
@@ -92,7 +91,7 @@ def test_resilience_package_imports_cleanly():
             "deepspeed_tpu.monitor.heartbeat",
             "deepspeed_tpu.monitor.capture",
             # MoE routing observability (monitor.moe is lazily reachable
-            # through TrainingMonitor and the bench moe rows)
+            # through TrainingMonitor)
             "deepspeed_tpu.monitor.moe")
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"

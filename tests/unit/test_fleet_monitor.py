@@ -33,6 +33,7 @@ from deepspeed_tpu.monitor import (ATTR_COMPUTE, ATTR_EXPERT_HOTSPOT,
     KIND_STEP, SCHEMA_VERSION, STEP_RECORD_FIELDS, FleetAggregator,
     FleetHealth, HeartbeatWriter, ProfileCapture, TrainingMonitor,
     annotate_stale, format_watch_table, read_heartbeats, straggler_verdict,
+    summarize_fleet,
     validate_trace_events)
 from deepspeed_tpu.monitor import record as R
 from deepspeed_tpu.monitor.fleet import (VEC_LEN, _encode_host,
@@ -299,7 +300,7 @@ def test_two_host_straggler_not_masked_by_midpoint_median():
            if e[R.H_EVENT] == EVENT_STRAGGLER]
     assert len(evs) == 1 and evs[0][R.F_HOST] == "h1"
     assert evs[0][R.H_RATIO] == pytest.approx(1.3)
-    # one-shot verdict (the bench-row form) uses the same peer median
+    # one-shot verdict (one window, no history) uses the same peer median
     v = straggler_verdict(_matrix(rows), hosts, min_ratio=1.15)
     assert v["straggler"] is True and v["host"] == "h1"
     assert v["ratio"] == pytest.approx(1.3)
@@ -899,18 +900,22 @@ def test_tpu_pod_labels():
 
 
 # --------------------------------------------------------------------- #
-# bench satellite: fleet summary fields
+# one measured window, no history: summary + one-shot verdict
 # --------------------------------------------------------------------- #
 def test_bench_fleet_summary_degenerate_single_host():
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
-    import bench
-    out = bench._fleet_summary_fields(0.012, final_loss=3.3)
-    fl = out["fleet"]
+    """One process, one window: the exchange is the identity, the
+    summary is that host's numbers and the one-shot verdict names no
+    straggler."""
+    agg = FleetAggregator(process_index=0, process_count=1)
+    matrix = agg.exchange({"last_step": 0, "steps": 1,
+                           "step_time_mean_s": 0.012,
+                           "step_time_max_s": 0.012, "loss_mean": 3.3})
+    hosts = agg.host_names()
+    fl = summarize_fleet(matrix)
     assert fl[R.FL_HOSTS] == 1
     assert fl[R.FL_STEP_TIME_MEDIAN_S] == pytest.approx(0.012)
-    assert fl["straggler"]["straggler"] is False
-    assert len(fl["host_names"]) == 1
-    assert "error" not in fl
+    assert straggler_verdict(matrix, hosts)["straggler"] is False
+    assert len(hosts) == 1
 
 
 # --------------------------------------------------------------------- #

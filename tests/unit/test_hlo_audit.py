@@ -126,6 +126,77 @@ def test_parser_walks_synthetic_module():
     assert deg.degenerate and deg.wire_bytes == 0
 
 
+# One sharded reduction (jnp.sum over the data-sharded axis of an
+# f32[1024,128], 8 partitions), as two releases of the compiler print
+# it: jax 0.4.x puts each operand's shape in the operand list and the
+# source in the metadata; jax 0.9.0 prints operands by name only and a
+# stack_frame_id that the module's header tables resolve.
+_REDUCE_HLO_INLINE_SHAPES = """\
+HloModule jit_g, is_scheduled=true, num_partitions=8
+
+%region_0.0.clone (reduce_sum.6: f32[], reduce_sum.10: f32[]) -> f32[] {
+  %reduce_sum.6 = f32[] parameter(0)
+  %reduce_sum.10 = f32[] parameter(1)
+  ROOT %reduce_sum.11 = f32[] add(f32[] %reduce_sum.6, f32[] %reduce_sum.10)
+}
+
+ENTRY %main.0_spmd (param: f32[128,128]) -> f32[128] {
+  %param = f32[128,128]{1,0} parameter(0), sharding={devices=[8,1]<=[8]}
+  %constant.2 = f32[] constant(0)
+  %wrapped_reduce = f32[128]{0} fusion(f32[128,128]{1,0} %param, f32[] %constant.2), kind=kLoop, calls=%wrapped_reduce_computation
+  ROOT %all-reduce = f32[128]{0} all-reduce(f32[128]{0} %wrapped_reduce), channel_id=1, replica_groups=[1,8]<=[8], use_global_device_ids=true, to_apply=%region_0.0.clone, metadata={op_name="jit(g)/reduce_sum" source_file="model.py" source_line=12}
+}
+"""
+
+_REDUCE_HLO_OPERANDS_BY_NAME = """\
+HloModule jit_g, is_scheduled=true, entry_computation_layout={(f32[128,128]{1,0})->f32[128]{0}}, num_partitions=8
+
+FileNames
+1 "model.py"
+
+FunctionNames
+1 "<module>"
+2 "g"
+
+FileLocations
+1 {file_name_id=1 function_name_id=1 line=15 end_line=15 column=6 end_column=6}
+2 {file_name_id=1 function_name_id=2 line=12 end_line=12 column=17 end_column=17}
+
+StackFrames
+1 {file_location_id=1 parent_frame_id=1}
+2 {file_location_id=2 parent_frame_id=2}
+
+%region_0.0.clone (reduce_sum.6: f32[], reduce_sum.10: f32[]) -> f32[] {
+  %reduce_sum.6 = f32[] parameter(0), metadata={op_name="jit(g)/reduce_sum"}
+  %reduce_sum.10 = f32[] parameter(1), metadata={op_name="jit(g)/reduce_sum"}
+  ROOT %reduce_sum.11 = f32[] add(%reduce_sum.6, %reduce_sum.10), metadata={op_name="jit(g)/reduce_sum" stack_frame_id=2}
+}
+
+ENTRY %main.0_spmd (param: f32[128,128]) -> f32[128] {
+  %param = f32[128,128]{1,0} parameter(0), sharding={devices=[8,1]<=[8]}, metadata={op_name="x"}
+  %constant.2 = f32[] constant(0)
+  %wrapped_reduce = f32[128]{0} fusion(%param, %constant.2), kind=kLoop, calls=%wrapped_reduce_computation, metadata={op_name="jit(g)/reduce_sum" stack_frame_id=2}
+  ROOT %all-reduce = f32[128]{0} all-reduce(%wrapped_reduce), channel_id=1, replica_groups=[1,8]<=[8], use_global_device_ids=true, to_apply=%region_0.0.clone, metadata={op_name="jit(g)/reduce_sum" stack_frame_id=2}
+}
+"""
+
+
+@pytest.mark.parametrize("text", [
+    pytest.param(_REDUCE_HLO_INLINE_SHAPES, id="operands_with_shapes"),
+    pytest.param(_REDUCE_HLO_OPERANDS_BY_NAME, id="operands_by_name")])
+def test_reader_prices_both_printed_forms_alike(text):
+    """An operand printed as a bare %name is priced from the
+    instruction that defines it, and a stack_frame_id is resolved to
+    the file and line the older form printed in place."""
+    (rec,) = walk_hlo_collectives(HloProgram(text), "g")
+    assert rec.opcode == "all-reduce" and rec.name == "all-reduce"
+    assert rec.wire_bytes == 128 * 4
+    assert (rec.group_size, rec.n_groups) == (8, 1)
+    assert rec.op_name == "jit(g)/reduce_sum"
+    assert rec.source == "model.py:12"
+    assert not rec.traced and not rec.degenerate
+
+
 def test_parser_conditional_takes_worst_branch():
     text = """\
 HloModule jit_c, num_partitions=4

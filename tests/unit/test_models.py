@@ -145,8 +145,8 @@ def test_gpt2_tensor_parallel_training_on_mesh():
 
 def test_bert_activation_checkpointing_same_loss_and_grads():
     """BertConfig.activation_checkpointing must be a pure memory knob —
-    identical loss and gradients (it is what lets bert_s512 fit 24 layers
-    of seq-512 activations in HBM; bench.py r4)."""
+    identical loss and gradients (it is what lets BERT-large fit 24 layers
+    of seq-512 activations in HBM)."""
     cfg_kw = dict(vocab_size=128, max_position_embeddings=32,
                   hidden_size=32, num_layers=2, num_heads=2, bf16=False,
                   embd_dropout=0.0, attn_dropout=0.0, hidden_dropout=0.0)

@@ -379,28 +379,6 @@ def test_monitor_moe_config_validation():
         MonitorConfig.from_dict({"moe": "yes"})
 
 
-# --------------------------------------------------------------------- #
-# bench-row satellite: the moe row's routing summary helper
-# --------------------------------------------------------------------- #
-def test_bench_moe_routing_summary_helper(tmp_path):
-    import sys
-    from pathlib import Path
-    sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
-    import bench
-    engine, _ = _moe_engine(tmp_path)
-    _run(engine, 3)
-    routing = bench._moe_routing_summary(engine, hot_k=2)
-    engine.monitor.close()
-    assert routing is not None
-    assert 0.0 <= routing["drop_fraction"] <= 1.0
-    assert routing["imbalance_max_mean"] >= 1.0
-    assert 0.0 < routing["router_entropy"] <= 1.0
-    assert len(routing["popularity_top_k"]) == 2
-    assert routing["hit_rate_under_k"][-1] == pytest.approx(1.0)
-    # a dense engine yields None (the row embeds routing: null)
-    assert bench._moe_routing_summary(object()) is None
-
-
 def test_local_expert_slice_is_union_of_local_devices(tmp_path,
                                                       monkeypatch):
     """Review regression: a host whose local devices span SEVERAL

@@ -13,6 +13,7 @@ develops in does not ship ruff.
 """
 
 import ast
+import os
 import re
 import shutil
 import subprocess
@@ -20,7 +21,7 @@ import sys
 from pathlib import Path
 
 REPO = Path(__file__).resolve().parents[2]
-LINT_PATHS = ("deepspeed_tpu", "tests", "bench.py", "chip_smoke.py")
+LINT_PATHS = ("deepspeed_tpu", "tests", "chip_smoke.py")
 # mirrors [tool.ruff.lint.per-file-ignores]: __init__ re-export surfaces
 F401_EXEMPT = "__init__.py"
 
@@ -277,3 +278,43 @@ def test_lint_scope_matches_pyproject():
         "pyproject ruff select drifted from the fallback's rule "
         "families — update tests/unit/test_repo_lint.py to match")
     assert sys.version_info >= (3, 10)
+
+
+# Scripts PR 32 deleted because perf/ measures what they measured (the
+# root benchmark script, the host-clock profilers and their harness).
+_DELETED_SCRIPTS = re.compile(
+    r"\bbench\.py\b|\bprofile_[\w*]+\.py\b|\b_harness\b")
+# the records of what was, and this list
+_MAY_NAME_THEM = {"CHANGES.md", "PERF.md", "ROADMAP.md", "ISSUE.md",
+                  "SURVEY.md", "PERF_LEDGER.jsonl",
+                  "tests/unit/test_repo_lint.py"}
+
+
+def _checkout_files():
+    """Every file of the checkout, found by walking it (no call to git):
+    the directories .gitignore names and .git are not entered."""
+    ignored = {ln.strip().rstrip("/") for ln in
+               (REPO / ".gitignore").read_text().splitlines()
+               if ln.strip().endswith("/")} | {".git"}
+    for root, dirs, files in os.walk(REPO):
+        dirs[:] = sorted(d for d in dirs if d not in ignored)
+        yield from (Path(root) / name for name in sorted(files))
+
+
+def test_nothing_names_a_deleted_script():
+    """No source, test, workflow or document sends a reader to a file
+    that is gone."""
+    hits = []
+    for path in _checkout_files():
+        rel = path.relative_to(REPO).as_posix()
+        if rel in _MAY_NAME_THEM or re.fullmatch(
+                r"docs/ROUND\w*_NOTES\.md", rel):
+            continue
+        try:
+            lines = path.read_text().splitlines()
+        except UnicodeDecodeError:  # not a text file
+            continue
+        hits += [f"{rel}:{n}: {line.strip()[:100]}"
+                 for n, line in enumerate(lines, 1)
+                 if _DELETED_SCRIPTS.search(line)]
+    assert hits == [], "\n  ".join(["names a deleted script:"] + hits)

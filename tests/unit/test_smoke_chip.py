@@ -2,6 +2,9 @@
 no result, the script alone (without the package) fails, the explicit
 ``--tiny`` CPU lane walks every phase green, and the readers of the
 compiled program catch a kernel that is missing or fed the global batch.
+Plus the helpers it shares with benchmarks/ (deepspeed_tpu/utils/chip.py,
+the peaks table and the compile cache) and the FLOP count behind its MFU
+line, which is the benchmark's.
 """
 
 import json
@@ -14,10 +17,13 @@ from types import SimpleNamespace
 
 import pytest
 
+import jax
+
 REPO = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(REPO))
 
 import chip_smoke  # noqa: E402
+from deepspeed_tpu.utils import chip  # noqa: E402
 
 
 def _run(args, cwd=REPO, env=None, timeout=600):
@@ -116,3 +122,66 @@ def test_gathered_operand_fails_the_check():
     calls = chip_smoke.mosaic_flash_calls(_program(batch=32))
     with pytest.raises(AssertionError, match="no per-chip operand"):
         chip_smoke.check_flash_calls(calls, _CFG, _SIZE, streamed=False)
+
+
+def test_mfu_line_divides_by_the_benchmarks_flop_count():
+    """GPT-2 124M at S=1,024, the size the smoke test trains: the count
+    is perf/flops.py's for the same shapes (causal attention at half the
+    square), not one of the program's own."""
+    from deepspeed_tpu.models import GPT2Config
+    from perf.flops import decoder_train_flops_per_token
+    cfg = GPT2Config(**chip_smoke.FULL["model"])
+    seq = chip_smoke.FULL["seq"]
+    assert (cfg.hidden_size, cfg.num_layers, cfg.vocab_size, seq) == (
+        768, 12, 50304, 1024)
+    assert chip_smoke.flops_per_token(cfg, seq) == (
+        decoder_train_flops_per_token(768, 12, 1024, 50304))
+
+
+def test_peaks_table_is_keyed_by_device_kind():
+    assert chip.device_peaks("TPU v5 lite") == {"bf16_tflops": 197.0,
+                                                "hbm_gbps": 819.0}
+    # exact device_kind, not a substring guess that defaults to v5e
+    for kind in ("TPU v5", "TPU v6 lite", "cpu", ""):
+        with pytest.raises(ValueError, match="no published peaks"):
+            chip.device_peaks(kind)
+
+
+@pytest.fixture
+def cache_config():
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_placed_from_outside_sets_nothing(monkeypatch,
+                                                        cache_config):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    before = jax.config.jax_compilation_cache_dir
+    assert chip.enable_compile_cache() == "/somewhere/else"
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_one_fixed_path_in_the_checkout(
+        monkeypatch, cache_config):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert chip.enable_compile_cache() == str(REPO / ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == str(REPO / ".jax_cache")
+    # the same path from any process, and git ignores it
+    assert chip.enable_compile_cache() == chip.COMPILE_CACHE_DIR
+    assert ".jax_cache/" in (REPO / ".gitignore").read_text().splitlines()
+
+
+def test_harness_and_benchmarks_share_the_cache_helper():
+    """No second cache location: neither chip_smoke.py nor anything under
+    benchmarks/ names a cache directory of its own, and the scripts that
+    compile large programs take the package's."""
+    for path in [REPO / "chip_smoke.py", *sorted((REPO / "benchmarks").glob(
+            "*.py"))]:
+        text = path.read_text()
+        assert "jax_compilation_cache_dir" not in text, path.name
+        assert "ds_jax_cache" not in text, path.name
+    for name in ("chip_smoke.py", "benchmarks/convergence_run.py",
+                 "benchmarks/grad_diag.py",
+                 "benchmarks/infinity_capability.py"):
+        assert "enable_compile_cache()" in (REPO / name).read_text(), name
