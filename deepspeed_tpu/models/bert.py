@@ -21,6 +21,7 @@ from ..ops.transformer import (DeepSpeedTransformerConfig,
 from ..ops.normalize import fused_layer_norm
 from ..ops.activations import dropout
 from ..parallel.mesh import MODEL_AXIS
+from ..runtime.activation_checkpointing.checkpointing import checkpoint_layer
 
 
 @dataclass
@@ -102,6 +103,12 @@ class BertModel:
     def __init__(self, config: BertConfig):
         self.config = config
         self.layer = DeepSpeedTransformerLayer(config.layer_config())
+        self._remat_budget = None
+
+    def install_remat_budget(self, budget) -> None:
+        """Engine hook: the bytes the layer scan's checkpointing may spend
+        on saved residuals (checkpointing.RematBudget)."""
+        self._remat_budget = budget
 
     def init_params(self, rng):
         cfg = self.config
@@ -163,9 +170,11 @@ class BertModel:
                            deterministic=deterministic)
             return out, None
 
-        if cfg.activation_checkpointing:
-            body = jax.checkpoint(body)
         layer_rngs = jax.random.split(r_layers, cfg.num_layers)
+        if cfg.activation_checkpointing:
+            body = checkpoint_layer(body, self._remat_budget, h,
+                                    (params["h"], layer_rngs),
+                                    head_width=cfg.vocab_size)
         from .layer_stack import run_layer_stack
         return run_layer_stack(body, h, (params["h"], layer_rngs),
                                cfg.use_scan)

@@ -6,7 +6,11 @@ TPU-native structure:
     with `lax.scan` — one compiled layer body regardless of depth, the
     XLA-friendly analog of the reference's per-layer module list;
   - per-layer activation checkpointing = `jax.checkpoint` around the scanned
-    body (reference: runtime/activation_checkpointing/checkpointing.py);
+    body, keeping the flash kernel's outputs where the engine's byte
+    budget admits them (the kernel then runs once a layer) and
+    recomputing the rest; the layer's input alone with no budget or
+    under the streamed ZeRO-3 scan (runtime/activation_checkpointing/
+    checkpointing.py, `checkpoint_layer`);
   - tensor parallelism is declarative: `param_partition_specs` emits
     Megatron-style column/row specs over the "model" mesh axis, vocab-sharded
     embedding included (the role of Megatron's VocabParallelEmbedding).
@@ -27,6 +31,7 @@ from ..ops.transformer import (DeepSpeedTransformerConfig,
 from ..ops.normalize import fused_layer_norm
 from ..ops.activations import dropout
 from ..parallel.mesh import MODEL_AXIS
+from ..runtime.activation_checkpointing.checkpointing import checkpoint_layer
 
 
 @dataclass
@@ -118,6 +123,7 @@ class GPT2Model:
         self.config = config
         self.layer = DeepSpeedTransformerLayer(config.layer_config())
         self._zero3_stream = None
+        self._remat_budget = None
 
     def install_zero3_streaming(self, stream_ctx) -> None:
         """Engine hook: route the layer-stack scan through the explicit
@@ -125,6 +131,11 @@ class GPT2Model:
         the stage3_max_live_parameters / stage3_prefetch_bucket_size
         consumer; reference stage3.py:294 PartitionedParameterCoordinator)."""
         self._zero3_stream = stream_ctx
+
+    def install_remat_budget(self, budget) -> None:
+        """Engine hook: the bytes the layer scan's checkpointing may spend
+        on saved residuals (checkpointing.RematBudget)."""
+        self._remat_budget = budget
 
     # -- parameters ---------------------------------------------------- #
     def init_params(self, rng):
@@ -256,12 +267,17 @@ class GPT2Model:
                 out = jnp.where(keep, out, carry)
             return out, None
 
-        if cfg.activation_checkpointing:
-            body = jax.checkpoint(body)
-
         layer_rngs = jax.random.split(r_layers, n)
         extras = ((layer_rngs, keep_probs, pld_keys) if use_pld
                   else (layer_rngs,))
+        if cfg.activation_checkpointing:
+            # The carried stream takes each layer's VJP from its saved
+            # input carry, so a residual kept by the body's policy lives
+            # for one layer's VJP only: memory spent, no kernel spared
+            # (PERF.md section 7).  It keeps whole-layer recomputation.
+            body = checkpoint_layer(
+                body, None if streaming else self._remat_budget, h,
+                (params["h"],) + extras, head_width=cfg.vocab_size)
         if streaming:
             h = stream.scan(body, h, params["h"], extras,
                             param_tp_specs=self.param_partition_specs()["h"])

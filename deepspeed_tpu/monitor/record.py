@@ -91,6 +91,20 @@ STEP_RECORD_FIELDS = (
     F_IO_RETRIES,
 )
 
+# ---- recomputation-plan fields of a meta record ---------------------- #
+# What the checkpointed layer scan keeps (runtime/activation_checkpointing/
+# checkpointing.py checkpoint_layer): one meta record per traced plan,
+# at the first flush boundary after the trace.
+M_REMAT_OFFERED = "remat_offered"            # names the layer offers
+M_REMAT_KEPT = "remat_kept"                  # the prefix the budget admits
+M_REMAT_KEPT_BYTES_PER_LAYER = "remat_kept_bytes_per_layer"
+M_REMAT_KEPT_BYTES = "remat_kept_bytes"      # over all layers, a device
+M_REMAT_LAYERS = "remat_layers"
+M_REMAT_BUDGET_BYTES = "remat_budget_bytes"  # limit - state - working set
+M_REMAT_BYTES_LIMIT = "remat_bytes_limit"
+M_REMAT_STATE_BYTES = "remat_state_bytes"
+M_REMAT_WORKING_SET_BYTES = "remat_working_set_bytes"
+
 # ---- fleet field names (fleet.py / health.py payloads) --------------- #
 FL_WINDOW_START = "window_start_step"
 FL_WINDOW_END = "window_end_step"

@@ -37,6 +37,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -1286,6 +1287,13 @@ def _ref_in_layout(q, k, v, causal, sm_scale, layout, dropout_rate=0.0,
                                  dropout_seed=dropout_seed))
 
 
+# checkpoint_name of the residuals only the forward kernel can produce
+# (out, lse, the packed dropout mask): a jax.checkpoint policy that saves
+# this name spares the backward pass a second run of the kernel; with no
+# such policy the name is an identity.
+RESIDUAL_NAME = "flash_residuals"
+
+
 def _flash_fwd(q, k, v, seed, causal, sm_scale, block_q, block_k,
                layout="bhsd", dropout_rate=0.0):
     q_len, k_len = _dims(q, layout)[2], _dims(k, layout)[2]
@@ -1294,19 +1302,15 @@ def _flash_fwd(q, k, v, seed, causal, sm_scale, block_q, block_k,
         # mask-reuse mode (trace-time, like the PRNG width): store the
         # bit-packed keep mask in the residuals so the backward kernels
         # skip the PRNG — grads identical either way
-        if dropout_rate > 0.0 and _dropout_reuse and _mask_reuse_usable(bq):
-            out, lse, mask = flash_attention_pallas(
-                q, k, v, causal=causal, sm_scale=sm_scale,
-                block_q=bq, block_k=bk, return_lse=True, layout=layout,
-                dropout_rate=dropout_rate, dropout_seed=seed,
-                save_dropout_mask=True, interpret=pallas_interpret())
-            return out, (q, k, v, seed, out, lse, mask)
-        out, lse = flash_attention_pallas(
+        reuse = (dropout_rate > 0.0 and _dropout_reuse
+                 and _mask_reuse_usable(bq))
+        out, lse, *mask = checkpoint_name(flash_attention_pallas(
             q, k, v, causal=causal, sm_scale=sm_scale,
             block_q=bq, block_k=bk, return_lse=True, layout=layout,
             dropout_rate=dropout_rate, dropout_seed=seed,
-            interpret=pallas_interpret())
-        return out, (q, k, v, seed, out, lse, None)
+            save_dropout_mask=reuse, interpret=pallas_interpret()),
+            RESIDUAL_NAME)
+        return out, (q, k, v, seed, out, lse, mask[0] if reuse else None)
     out = _ref_in_layout(q, k, v, causal, sm_scale, layout, dropout_rate,
                          seed[0])
     return out, (q, k, v, seed, None, None, None)

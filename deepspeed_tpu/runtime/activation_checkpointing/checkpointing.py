@@ -9,7 +9,19 @@ gather_partitioned_activations:256), CPU checkpointing (:469), RNG forking
 
 TPU-native mapping — the four reference memory knobs become jax.checkpoint
 policies instead of hand-managed tensor stashes:
-  * plain checkpointing        -> jax.checkpoint(fn) (recompute everything)
+  * plain checkpointing        -> jax.checkpoint(fn): recompute what does
+                                  not fit.  The scanned layer of the model
+                                  families (checkpoint_layer) keeps the
+                                  named residuals a reckoned byte budget
+                                  admits (RematBudget: the device's memory
+                                  limit, less the engine's own state, less
+                                  the working set of the traced shapes):
+                                  the flash kernel's out / lse / packed
+                                  mask, so the kernel runs once a layer.
+                                  With no budget (no engine, a backend that
+                                  reports no memory limit, nothing fits) it
+                                  keeps the layer's input alone and
+                                  recomputes everything
   * partition_activations      -> saved residuals stay sharded over the
                                   "model" axis: the policy saves only
                                   outputs already annotated device-local,
@@ -25,11 +37,16 @@ policies instead of hand-managed tensor stashes:
                                   analog of CudaRNGStatesTracker
 """
 
-from typing import Any, Callable, Optional
+import math
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 from jax import lax
 
+from ...analysis.jaxpr_walk import sub_jaxprs
+from ...monitor import record as R
+from ...ops.flash_attention import RESIDUAL_NAME as FLASH_RESIDUALS_NAME
 from ...utils.logging import log_dist
 from ...parallel.mesh import MODEL_AXIS
 
@@ -113,6 +130,201 @@ def get_partition_policy():
         # the saved residuals stay partitioned across MP ranks)
         return jax.checkpoint_policies.dots_saveable
     return jax.checkpoint_policies.nothing_saveable
+
+
+# What a checkpointed layer may keep, in the order a budget admits it.
+# The flash kernel's residuals alone: keeping them took 15.3 ms off GPT-2
+# large's 267 ms step at B=4, S=1,024 on the v5e for 0.77 GB (20 ms a GB)
+# and left the step as steady as it was.  The widest matmul outputs were
+# tried under their own names and are not offered: 4.4 ms for the MLP
+# pre-activation's 1.51 GB, 1.7 for the attention projection's 0.38,
+# 2.2 ms SLOWER for the QKV output's 1.13, with one long step in a run in
+# three (PERF.md section 6, PR 33; ROADMAP S2 has what is left of them).
+RESIDUAL_ORDER = (FLASH_RESIDUALS_NAME,)
+
+# The allowance for what a device holds in a step beside the engine's
+# state and the saved residuals, in three terms (working_set_bytes).
+# LAYER_WIDTHS: one layer's forward and backward pass, in activations of
+# the model's width a token, beside the layer-input carry every layer
+# keeps.  The v5e compiler's temporaries for GPT-2 large's whole-layer-
+# recomputation grad program, less the compute-dtype copy of the weights,
+# come to 308 to 370 KB a token at 4,096 to 16,384 tokens (compiled ahead
+# of time, PERF.md section 6, PR 33); 36 carries, 32 more widths and the
+# head's fp32 logits make 375.  MARGIN_BYTES: what the allocator held on
+# the chip that no program of the step asked for (0.42 GB in the
+# benchmark's cells: what ran before the engine was built).
+LAYER_WIDTHS = 32
+MARGIN_BYTES = 420_000_000
+
+
+def working_set_bytes(tokens: int, width: int, num_layers: int,
+                      head_width: int, itemsize: int,
+                      cast_bytes: int = 0) -> int:
+    """Bytes a device holds in a step beside the engine's state and the
+    saved residuals: ``cast_bytes`` (the compute-dtype copy of the
+    weights), and for each of the device's ``tokens`` a layer-input carry
+    a layer, LAYER_WIDTHS more activations of ``width`` and a row of fp32
+    logits ``head_width`` wide; MARGIN_BYTES on top.  It grows with the
+    batch, the width and the depth as the grad program does, so a job
+    larger than the measured ones is refused its residuals before it is
+    refused its memory.  GPT-2 large at 4,096 tokens: 3,505,530,112, where
+    the chip's programs reserved 2.4 to 2.9 GB beside their residuals and
+    0.42 GB was held beside them."""
+    return (cast_bytes + MARGIN_BYTES + tokens * (
+        (num_layers + LAYER_WIDTHS) * width * itemsize + 4 * head_width))
+
+
+def _tiled_bytes(shape, dtype) -> int:
+    """Bytes of one saved array as the TPU compiler lays it out in HBM:
+    the minor dimension padded to 128 lanes.  Read off the v5e compiler's
+    buffer assignment for GPT-2 large's grad program (PR 33): the scan's
+    stack of the flash output, bf16[36,4,20,1024,64] tiled T(8,128)(2,1),
+    takes 754,974,720 B, twice its elements' bytes; the log-sum-exp
+    (f32[36,4,20,1024], 1,024 minor) takes its elements' bytes, the
+    compiler ordering the other dimensions so that no tile of 8 or 16
+    rows is padded."""
+    dims = list(shape)
+    if dims:
+        dims[-1] = -(-dims[-1] // 128) * 128
+    return math.prod(dims) * jnp.dtype(dtype).itemsize
+
+
+def device_bytes_limit(device) -> Optional[int]:
+    """The backend's memory limit for ``device``; None where it reports
+    none (the CPU)."""
+    return (device.memory_stats() or {}).get("bytes_limit")
+
+
+class RematBudget:
+    """Bytes a device may spend on a layer scan's saved residuals, from
+    steady quantities only: the backend's ``bytes_limit``, less
+    ``state_bytes`` (what the engine itself placed there: parameters,
+    optimizer state, gradient buffers; summed from its pytrees), less the
+    working set of the traced program (``working_set_bytes`` of its
+    shapes and of ``cast_bytes``, the engine's compute-dtype copy of the
+    weights; tests hand a fixed ``working_set`` in).  Never the
+    allocator's ``bytes_in_use``: it holds whatever ran before and would
+    give two runs of one job two programs.  ``bytes_limit`` None (a
+    backend that reports no limit) means no budget.  ``batch_shards``:
+    the ways the batch is split over devices, to turn a traced global
+    shape into bytes a device."""
+
+    def __init__(self, bytes_limit: Optional[int], state_bytes: int = 0,
+                 batch_shards: int = 1, cast_bytes: int = 0,
+                 working_set: Optional[int] = None):
+        self.bytes_limit = bytes_limit
+        self.state_bytes = int(state_bytes)
+        self.batch_shards = int(batch_shards)
+        self.cast_bytes = int(cast_bytes)
+        self.working_set = working_set
+        self.plan: Optional[Dict[str, Any]] = None  # the last one made
+        self._pending: Optional[Dict[str, Any]] = None  # not yet taken
+
+    def bytes(self, working_set: int) -> int:
+        return max(0, int(self.bytes_limit) - self.state_bytes - working_set)
+
+    def note_plan(self, plan: Dict[str, Any]) -> None:
+        """Log a plan and queue it for the monitor, once per distinct
+        plan (a model is traced several times for one program)."""
+        if plan == self.plan:
+            return
+        self.plan = self._pending = plan
+        log_dist(
+            "activation checkpointing: layer scan keeps "
+            f"{list(plan[R.M_REMAT_KEPT]) or 'the layer input only'} of "
+            f"{list(plan[R.M_REMAT_OFFERED])}: "
+            f"{plan[R.M_REMAT_KEPT_BYTES_PER_LAYER]:,} B a layer, "
+            f"{plan[R.M_REMAT_KEPT_BYTES]:,} B over "
+            f"{plan[R.M_REMAT_LAYERS]} layers, under a budget of "
+            f"{plan[R.M_REMAT_BUDGET_BYTES]:,} B (limit "
+            f"{plan[R.M_REMAT_BYTES_LIMIT]:,} - state "
+            f"{plan[R.M_REMAT_STATE_BYTES]:,} - working set "
+            f"{plan[R.M_REMAT_WORKING_SET_BYTES]:,})", ranks=[0])
+
+    def take_plan(self) -> Optional[Dict[str, Any]]:
+        """The plan, if the monitor has not had it yet."""
+        plan, self._pending = self._pending, None
+        return plan
+
+
+def offered_residuals(body: Callable, *args,
+                      batch_shards: int = 1) -> Dict[str, int]:
+    """``{checkpoint_name: bytes a device}`` of one call of ``body``, off
+    its jaxpr: every named value's tiled size, the names inside a
+    ``shard_map`` at their local shape, the others (traced at the global
+    batch) divided by ``batch_shards``."""
+    offered: Dict[str, int] = {}
+
+    def walk(jaxpr, local):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "name":
+                aval = eqn.outvars[0].aval
+                size = _tiled_bytes(aval.shape, aval.dtype)
+                offered[eqn.params["name"]] = offered.get(
+                    eqn.params["name"], 0) + (
+                    size if local else size // batch_shards)
+            for sub in sub_jaxprs(eqn):
+                walk(sub.jaxpr, local or eqn.primitive.name == "shard_map")
+
+    walk(jax.make_jaxpr(body)(*args).jaxpr, False)
+    return offered
+
+
+def saved_residual_names(offered: Dict[str, int], num_layers: int,
+                         budget_bytes: int) -> Tuple[str, ...]:
+    """The longest prefix of RESIDUAL_ORDER (names not offered skipped)
+    whose bytes over ``num_layers`` layers fit ``budget_bytes``."""
+    kept, total = [], 0
+    for name in RESIDUAL_ORDER:
+        if name not in offered:
+            continue
+        total += offered[name] * num_layers
+        if total > budget_bytes:
+            break
+        kept.append(name)
+    return tuple(kept)
+
+
+def checkpoint_layer(body: Callable, budget: Optional[RematBudget],
+                     carry, stacked_xs, head_width: int) -> Callable:
+    """``jax.checkpoint(body)`` for the layer ``body(carry, xs)`` that
+    ``run_layer_stack`` runs over the leading axis of ``stacked_xs``,
+    keeping the named residuals ``budget`` admits beside the working set
+    of a step on ``carry`` ([batch, sequence, width]) with a head
+    ``head_width`` wide.  No budget, or none that fits:
+    ``jax.checkpoint(body)`` as it always was, the layer's input kept and
+    the rest recomputed."""
+    if budget is None or budget.bytes_limit is None:
+        return jax.checkpoint(body)
+    num_layers = jax.tree.leaves(stacked_xs)[0].shape[0]
+    offered = offered_residuals(
+        body, jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), carry),
+        jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), stacked_xs),
+        batch_shards=budget.batch_shards)
+    working_set = budget.working_set
+    if working_set is None:
+        working_set = working_set_bytes(
+            math.prod(carry.shape[:-1]) // budget.batch_shards,
+            carry.shape[-1], num_layers, head_width, carry.dtype.itemsize,
+            budget.cast_bytes)
+    kept = saved_residual_names(offered, num_layers,
+                                budget.bytes(working_set))
+    per_layer = sum(offered[name] for name in kept)
+    budget.note_plan({
+        R.M_REMAT_OFFERED: tuple(n for n in RESIDUAL_ORDER if n in offered),
+        R.M_REMAT_KEPT: kept,
+        R.M_REMAT_KEPT_BYTES_PER_LAYER: per_layer,
+        R.M_REMAT_KEPT_BYTES: per_layer * num_layers,
+        R.M_REMAT_LAYERS: num_layers,
+        R.M_REMAT_BUDGET_BYTES: budget.bytes(working_set),
+        R.M_REMAT_BYTES_LIMIT: int(budget.bytes_limit),
+        R.M_REMAT_STATE_BYTES: budget.state_bytes,
+        R.M_REMAT_WORKING_SET_BYTES: working_set})
+    return jax.checkpoint(body, policy=(
+        jax.checkpoint_policies.save_only_these_names(*kept) if kept
+        else None))
 
 
 def checkpoint(function: Callable, *args) -> Any:

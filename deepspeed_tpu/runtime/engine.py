@@ -25,6 +25,7 @@ compiled grad step and caches grads; backward accumulates; step applies at
 gradient-accumulation boundaries.
 """
 
+import math
 import os
 import warnings
 from typing import Any, Callable, Dict, Optional
@@ -293,6 +294,11 @@ class DeepSpeedEngine:
                 self.tx.init, out_shardings=self.opt_shardings)(self.params)
         self.scaler_state = jax.device_put(
             scaler_state, self.mesh_ctx.replicated())
+        self._remat_budget = None
+        if (hasattr(model, "install_remat_budget")
+                and model.config.activation_checkpointing):
+            self._remat_budget = self._build_remat_budget()
+            model.install_remat_budget(self._remat_budget)
 
         # ---- resilience (all off by default; see docs/resilience.md) - #
         res = self.config.resilience_config
@@ -736,6 +742,49 @@ class DeepSpeedEngine:
         degrade("tensorboard", "torch", "jsonl", "; ".join(errors))
         return writer
 
+    @property
+    def _grads_half(self) -> bool:
+        """bf16 gradient buffers: the grad program hands its gradients
+        over in the compute dtype."""
+        return bool(self.config.bf16.enabled
+                    and self.config.bf16.grads_in_compute_dtype)
+
+    def _build_remat_budget(self):
+        """What the model's layer scan may spend on saved residuals
+        (activation_checkpointing.RematBudget): the memory limit of this
+        process's first device (the mesh's first may be another host's,
+        and every host has to reckon the same plan), less the state this
+        engine keeps on a device.  State offloaded to the host counts as
+        if it were on the device: the safe side."""
+        from .activation_checkpointing.checkpointing import (
+            RematBudget, device_bytes_limit)
+
+        def per_device(tree, shardings=None, dtype=None):
+            leaves = jax.tree.leaves(tree)
+            placed = (jax.tree.leaves(shardings) if shardings is not None
+                      else [getattr(x, "sharding", None) for x in leaves])
+            assert len(placed) == len(leaves)
+            return sum(math.prod(s.shard_shape(x.shape))
+                       * jnp.dtype(dtype or x.dtype).itemsize
+                       for x, s in zip(leaves, placed)
+                       if isinstance(x, jax.Array))
+
+        # the grad program's output and, under accumulation, the buffer
+        # it is added to
+        grads = per_device(
+            self.params, self.grad_shardings,
+            self.compute_dtype if self._grads_half else None) * (
+            2 if self.gradient_accumulation_steps() > 1 else 1)
+        # the grad program's compute-dtype copy of the weights
+        cast = per_device([x for x in jax.tree.leaves(self.params)
+                           if x.dtype != self.compute_dtype],
+                          dtype=self.compute_dtype)
+        return RematBudget(
+            device_bytes_limit(jax.local_devices()[0]),
+            state_bytes=(per_device(self.params)
+                         + per_device(self.opt_state) + grads),
+            batch_shards=self.world_size, cast_bytes=cast)
+
     # ------------------------------------------------------------------ #
     # compiled programs
     # ------------------------------------------------------------------ #
@@ -753,8 +802,7 @@ class DeepSpeedEngine:
         # boundary — accumulation then runs at half width and the apply
         # program's existing fp32 upcast (see apply_step) recovers fp32
         # optimizer math, exactly the reference's fp16 -> fp32 shape.
-        grads_half = (self.config.bf16.enabled
-                      and self.config.bf16.grads_in_compute_dtype)
+        grads_half = self._grads_half
 
         def _grads_out(grads):
             if grads_half:
@@ -1203,8 +1251,7 @@ class DeepSpeedEngine:
         scaler_cfg = self.scaler_cfg
         prescale = self.config.prescale_gradients
         predivide = self.config.gradient_predivide_factor
-        grads_half = (self.config.bf16.enabled
-                      and self.config.bf16.grads_in_compute_dtype)
+        grads_half = self._grads_half
         schedule = (self.lr_scheduler.lr_at
                     if self.lr_scheduler is not None
                     else float(self.config.optimizer_params.get("lr", 1e-3)))
@@ -1872,7 +1919,8 @@ class DeepSpeedEngine:
     def _drain_resilience_records(self):
         """Boundary-cadence drain: the chaos plane's fired-fault log
         and the degradation registry both ride the monitor stream as
-        structured meta records (docs/resilience.md)."""
+        structured meta records (docs/resilience.md), and so does the
+        layer scan's recomputation plan, once per traced plan."""
         from .resilience import chaos
         from .resilience.degradation import get_registry
         records = []
@@ -1880,6 +1928,10 @@ class DeepSpeedEngine:
         if plane is not None:
             records.extend(plane.drain_records())
         records.extend(get_registry().drain_records())
+        plan = self._remat_budget and self._remat_budget.take_plan()
+        if plan:
+            from ..monitor import record as mrec
+            records.append({mrec.F_KIND: mrec.KIND_META, **plan})
         return records
 
     def _monitor_counters(self) -> Dict[str, Any]:

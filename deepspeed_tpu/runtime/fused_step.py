@@ -158,8 +158,7 @@ def build_fused_step(engine, onebit=None):
     if apply_core is None:  # pragma: no cover — guarded by fallback_reason
         raise RuntimeError("fused_step requires the compiled apply path")
     compute_dtype = engine.compute_dtype
-    grads_half = (engine.config.bf16.enabled
-                  and engine.config.bf16.grads_in_compute_dtype)
+    grads_half = engine._grads_half
 
     sentinel = engine.sentinel
     sent_on = sentinel is not None
