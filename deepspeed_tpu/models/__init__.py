@@ -9,6 +9,8 @@ standalone.
 from .gpt2 import GPT2Config, GPT2Model
 from .bert import BertConfig, BertModel
 from .gpt_moe import GPTMoEConfig, GPTMoEModel
+from .phi4flash import Phi4FlashConfig, Phi4FlashModel
 
 __all__ = ["GPT2Config", "GPT2Model", "BertConfig", "BertModel",
-           "GPTMoEConfig", "GPTMoEModel"]
+           "GPTMoEConfig", "GPTMoEModel", "Phi4FlashConfig",
+           "Phi4FlashModel"]

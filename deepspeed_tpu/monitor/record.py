@@ -105,6 +105,13 @@ M_REMAT_BYTES_LIMIT = "remat_bytes_limit"
 M_REMAT_STATE_BYTES = "remat_state_bytes"
 M_REMAT_WORKING_SET_BYTES = "remat_working_set_bytes"
 
+# ---- the plan of a stack of unlike layers, on the same meta record --- #
+# (models/phi4flash.py; checkpointing.checkpoint_layers carries it)
+M_STACK_LAYERS = "stack_layers"      # [[published index, kind, window|0]]
+M_STACK_SCAN_CHUNK = "stack_scan_chunk"           # positions a chunk
+M_STACK_SCAN_ENTRY_BYTES = "stack_scan_entry_state_bytes"  # a scan call
+M_STACK_CROSS_LAYER_KEPT = "stack_cross_layer_kept"  # [[name, bytes]]
+
 # ---- fleet field names (fleet.py / health.py payloads) --------------- #
 FL_WINDOW_START = "window_start_step"
 FL_WINDOW_END = "window_end_step"

@@ -59,8 +59,13 @@ _STATS_LANES = 8
 # --------------------------------------------------------------------------- #
 def mha_reference(q, k, v, causal: bool = False,
                   sm_scale: Optional[float] = None, bias=None,
-                  dropout_rate: float = 0.0, dropout_seed=None):
+                  dropout_rate: float = 0.0, dropout_seed=None,
+                  window: Optional[int] = None):
     """Plain-XLA multi-head attention: q,k,v [B, H, S, D] -> [B, H, S, D].
+
+    k and v may have fewer heads than q (a divisor): KV head j serves the
+    query heads [j * group, (j + 1) * group).  `window` (causal only)
+    keeps the last `window` keys of each query, its own included.
 
     fp32 softmax regardless of input dtype (matches the reference kernels,
     which upcast for the softmax — softmax_kernels.cu attn_softmax).
@@ -71,6 +76,9 @@ def mha_reference(q, k, v, causal: bool = False,
     paths agree in distribution, not bit-for-bit."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    group = _kv_group(q.shape[1], k.shape[1])
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * sm_scale
     if bias is not None:
@@ -80,6 +88,8 @@ def mha_reference(q, k, v, causal: bool = False,
         idx_q = jax.lax.broadcasted_iota(jnp.int32, (q_len, k_len), 0)
         idx_k = jax.lax.broadcasted_iota(jnp.int32, (q_len, k_len), 1)
         s = jnp.where(idx_k > idx_q, DEFAULT_MASK_VALUE, s)
+        if window is not None:
+            s = jnp.where(idx_k <= idx_q - window, DEFAULT_MASK_VALUE, s)
     p = jax.nn.softmax(s, axis=-1)
     if dropout_rate > 0.0:
         if dropout_seed is None:
@@ -89,6 +99,14 @@ def mha_reference(q, k, v, causal: bool = False,
             1.0 - dropout_rate, p.shape)
         p = jnp.where(keep, p / (1.0 - dropout_rate), 0.0)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
+
+
+def _kv_group(q_heads: int, kv_heads: int) -> int:
+    """Query heads per key/value head."""
+    if q_heads % kv_heads:
+        raise ValueError(f"{q_heads} query heads are no multiple of "
+                         f"{kv_heads} key/value heads")
+    return q_heads // kv_heads
 
 
 # --------------------------------------------------------------------------- #
@@ -454,7 +472,53 @@ def _mask_reuse_usable(block_q: int) -> bool:
     return block_q % 256 == 0
 
 
-def _walk_tile(qi, ki, block_q, block_k, causal, walk, step):
+# --------------------------------------------------------------------------- #
+# The window: a band under the diagonal
+# --------------------------------------------------------------------------- #
+# A causal call with `window` = W lets a query see its last W keys, its
+# own included: column c of row r is kept where r - W < c <= r.  Only the
+# tiles the band crosses are visited at all: the inner grid dimension of
+# each kernel counts `steps` blocks from the first one its outer block
+# needs (_Band), the operands' index maps fetch those, and a step that
+# falls past the last block of the sequence, or on a tile outside the
+# band, computes nothing.  Tiles are walked whole and masked whole (no
+# sub-tile plan: a band of 512 in blocks of 512 has no sub-tile to skip).
+# A call without a window never comes here: its grid, index maps and
+# kernel bodies are what they were.
+class _Band:
+    """The band's geometry for blocks of block_q x block_k over nq x nk
+    tiles: the first inner block of an outer block and the inner steps,
+    for the kernels that walk key blocks under a q block (`first_k`,
+    `steps_k`: forward, dq) and q blocks over a key block (`first_q`,
+    `steps_q`: dkdv).  The first-block functions take Python ints or
+    traced int32 scalars."""
+
+    def __init__(self, window, block_q, block_k, nq, nk):
+        self.window, self.nq, self.nk = window, nq, nk
+        self.block_q, self.block_k = block_q, block_k
+        self.steps_k = max(
+            min((qi * block_q + block_q - 1) // block_k, nk - 1)
+            - self.first_k(qi) + 1 for qi in range(nq))
+        self.steps_q = max(
+            min((ki * block_k + block_k + window - 2) // block_q, nq - 1)
+            - self.first_q(ki) + 1 for ki in range(nk))
+
+    def first_k(self, qi):
+        lo = max if isinstance(qi, int) else jnp.maximum
+        return lo(qi * self.block_q - (self.window - 1), 0) // self.block_k
+
+    def first_q(self, ki):
+        return (ki * self.block_k) // self.block_q
+
+    def k_block(self, qi, j):
+        """Index map: the key block of inner step j under q block qi."""
+        return jnp.minimum(self.first_k(qi) + j, self.nk - 1)
+
+    def q_block(self, ki, j):
+        return jnp.minimum(self.first_q(ki) + j, self.nq - 1)
+
+
+def _walk_tile(qi, ki, block_q, block_k, causal, walk, step, band=None):
     """Run a kernel's step on tile (qi, ki): step(kj, n, rows, parts).
 
     Without a plan (`walk` empty: a non-causal call, or blocks the
@@ -484,6 +548,12 @@ def _walk_tile(qi, ki, block_q, block_k, causal, walk, step):
         should_compute = True
         if causal:
             should_compute = qi * block_q + block_q - 1 >= ki * block_k
+        if band is not None:
+            # inside the sequence, and the tile's last column within
+            # reach of its first row
+            should_compute &= (
+                (qi < band.nq) & (ki < band.nk)
+                & (ki * block_k + block_k - 1 > qi * block_q - band.window))
 
         @pl.when(should_compute)
         def _compute():
@@ -567,11 +637,17 @@ def _step_keep(seed_ref, b, h, qi, kj, n, rate, block_q, unit, num_units,
     return draws[0] if n == 1 else jnp.concatenate(draws, axis=1)
 
 
-def _step_mask(x, qi, kj, block_q, unit, qrows, mask_col, fill):
+def _step_mask(x, qi, kj, block_q, unit, qrows, mask_col, fill,
+               window=None):
     """x, the scores or probabilities of q rows `qrows` (None: all) of
     block qi against the key columns from unit kj on, with `fill` above
     the diagonal from column mask_col on (None: the piece lies wholly
-    under the diagonal)."""
+    under the diagonal); under a `window`, the whole tile, with `fill`
+    left of the band as well."""
+    if window is not None:
+        row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+        col = kj * unit + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+        return jnp.where((col <= row) & (col > row - window), x, fill)
     if mask_col is None:
         return x
     if qrows is None and mask_col == 0 and x.shape[1] == unit:
@@ -604,7 +680,7 @@ def _fa_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                causal: bool, sm_scale: float, block_q: int, block_k: int,
                num_k_blocks: int, dropout_rate: float,
                dropout_pbits: int = 32, save_mask: bool = False,
-               interpret: bool = False, walk=None):
+               interpret: bool = False, walk=None, band=None):
     if save_mask:
         mask_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -612,9 +688,14 @@ def _fa_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     b = pl.program_id(0)
     h = pl.program_id(1)
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    ki = step = pl.program_id(3)
+    last_step = num_k_blocks - 1
+    window = None
+    if band is not None:   # inner step -> key block of the band
+        ki, last_step, window = (band.first_k(qi) + step, band.steps_k - 1,
+                                 band.window)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, DEFAULT_MASK_VALUE)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -637,7 +718,7 @@ def _fa_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             preferred_element_type=jnp.float32) * sm_scale  # [bq, width] fp32
 
         s = _step_mask(s, qi, kj, block_q, unit, None, mask_col,
-                       DEFAULT_MASK_VALUE)
+                       DEFAULT_MASK_VALUE, window)
 
         m_prev = m_scr[...]                           # [bq, LANES]
         l_prev = l_scr[...]
@@ -677,9 +758,9 @@ def _fa_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
             preferred_element_type=jnp.float32)       # [bq, d]
         acc_scr[...] = acc_scr[...] * alpha + pv
 
-    _walk_tile(qi, ki, block_q, block_k, causal, walk, _update)
+    _walk_tile(qi, ki, block_q, block_k, causal, walk, _update, band)
 
-    @pl.when(ki == num_k_blocks - 1)
+    @pl.when(step == last_step)
     def _finalize():
         denom = l_scr[...][:, :1]
         # Fully-masked rows have l == 0; emit zeros not NaN.
@@ -739,11 +820,16 @@ def _dims(arr, layout):
     return b, h, s, d
 
 
-def _tile_spec(rows, d, seq_of):
+def _tile_spec(rows, d, seq_of, group=1, inner=None):
     """[B, H, S, D] BlockSpec for one [rows, d] tile per (b, h) grid
     cell; `seq_of` picks which grid index walks the sequence dim ('i' or
     'j').  The trailing *_ absorbs the scalar-prefetch ref (the dropout
     seed) that PrefetchScalarGridSpec appends to every index_map.
+
+    `group` > 1: the operand is a key or value array with one head for
+    every `group` heads of the grid, found by index and not by a
+    repeated copy.  `inner` (a banded call, seq_of 'j'): maps (outer
+    block, inner step) to the block fetched (_Band.k_block / q_block).
 
     (A native [B, S, heads, d] tiling — block (1, rows, 1, d) indexing
     the head dim — is Mosaic-ILLEGAL: the block's last two dims are then
@@ -751,11 +837,35 @@ def _tile_spec(rows, d, seq_of):
     nor the full head count.  Measured round 3 on v5e: such specs fail
     Pallas lowering outright, so the "bshd" layout transposes at the
     kernel boundary instead — see flash_attention_pallas.)"""
+    if group > 1 or inner is not None:
+        def index(b, h, i, j, *_):
+            head = h // group if group > 1 else h
+            if seq_of == "i":
+                return (b, head, i, 0)
+            return (b, head, j if inner is None else inner(i, j), 0)
+        return pl.BlockSpec((1, 1, rows, d), index)
     if seq_of == "i":
         return pl.BlockSpec((1, 1, rows, d),
                             lambda b, h, i, j, *_: (b, h, i, 0))
     return pl.BlockSpec((1, 1, rows, d),
                         lambda b, h, i, j, *_: (b, h, j, 0))
+
+
+def _kernel_name(base, band):
+    """A banded call's kernels carry their own names, so that a trace
+    tells them from the full causal ones."""
+    return base if band is None else base + "_band"
+
+
+def _check_window(window, causal, dropout_rate):
+    if window is None:
+        return
+    if not causal or window < 1:
+        raise ValueError(f"window={window}: a window is the last `window` "
+                         "keys of a causal call, at least 1")
+    if dropout_rate > 0.0:
+        raise ValueError("a windowed call takes no in-kernel dropout: the "
+                         "banded grid has no packed-mask layout")
 
 
 def flash_attention_pallas(q, k, v, causal: bool = False,
@@ -764,8 +874,14 @@ def flash_attention_pallas(q, k, v, causal: bool = False,
                            interpret: bool = False, return_lse: bool = False,
                            layout: str = "bhsd", dropout_rate: float = 0.0,
                            dropout_seed=None,
-                           save_dropout_mask: bool = False):
+                           save_dropout_mask: bool = False,
+                           window: Optional[int] = None):
     """Pallas flash attention.
+
+    k and v may have fewer heads than q (a divisor of its count; the
+    index maps fetch head h // group).  `window` (causal only, no
+    dropout): each query sees its last `window` keys, its own included,
+    and only the tiles that band crosses are visited (_Band).
 
     layout="bhsd" (default): q,k,v [B, H, S, D] -> [B, H, S, D].
     layout="bshd": q,k,v [B, S, heads, D] -> [B, S, heads, D], converted
@@ -786,7 +902,8 @@ def flash_attention_pallas(q, k, v, causal: bool = False,
         block_q=block_q, block_k=block_k, interpret=interpret,
         return_lse=return_lse, layout=layout,
         dropout_rate=float(dropout_rate),
-        save_dropout_mask=save_dropout_mask, dropout_bits=_dropout_bits)
+        save_dropout_mask=save_dropout_mask, dropout_bits=_dropout_bits,
+        window=window)
 
 
 # The calls' own programs are traced once a process and shape: every
@@ -798,13 +915,16 @@ def flash_attention_pallas(q, k, v, causal: bool = False,
 # these are inlined calls, not programs of their own.
 @functools.partial(jax.jit, static_argnames=(
     "causal", "sm_scale", "block_q", "block_k", "interpret", "return_lse",
-    "layout", "dropout_rate", "save_dropout_mask", "dropout_bits"))
+    "layout", "dropout_rate", "save_dropout_mask", "dropout_bits",
+    "window"))
 def _flash_fwd_call(q, k, v, dropout_seed, *, causal, sm_scale, block_q,
                     block_k, interpret, return_lse, layout, dropout_rate,
-                    save_dropout_mask, dropout_bits):
+                    save_dropout_mask, dropout_bits, window=None):
     """flash_attention_pallas, traced once a process and shape."""
     batch, heads, q_len, d = _dims(q, layout)
-    k_len = _dims(k, layout)[2]
+    kv_heads, k_len = _dims(k, layout)[1:3]
+    group = _kv_group(heads, kv_heads)
+    _check_window(window, causal, dropout_rate)
     if layout == "bshd":
         q, k, v = _t_bhsd(q), _t_bhsd(k), _t_bhsd(v)
     if sm_scale is None:
@@ -834,13 +954,19 @@ def _flash_fwd_call(q, k, v, dropout_seed, *, causal, sm_scale, block_q,
                 "multiple of 256 (TransformerConfig.block_q / the block_q "
                 "argument), or stay on the regen path by disabling reuse "
                 "(set_dropout_mask_reuse(False) / DS_DROPOUT_REUSE=0)")
-    walk = _causal_walk(q_len, k_len, block_q, block_k, causal, False)
+    band = None if window is None else _Band(window, block_q, block_k,
+                                             nq, nk)
+    walk = ({} if band else
+            _causal_walk(q_len, k_len, block_q, block_k, causal, False))
     kernel = functools.partial(
         _fa_kernel, causal=causal, sm_scale=float(sm_scale),
         block_q=block_q, block_k=block_k, num_k_blocks=nk,
         dropout_rate=float(dropout_rate),
         dropout_pbits=_effective_dropout_bits(block_k, dropout_bits),
-        save_mask=save_dropout_mask, interpret=interpret, walk=walk)
+        save_mask=save_dropout_mask, interpret=interpret, walk=walk,
+        band=band)
+    kv_spec = _tile_spec(block_k, d, "j", group,
+                         band.k_block if band else None)
 
     scratch = [
         pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
@@ -873,17 +999,13 @@ def _flash_fwd_call(q, k, v, dropout_seed, *, causal, sm_scale, block_q,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(batch, heads, nq, nk),
-            in_specs=[
-                _tile_spec(block_q, d, "i"),
-                _tile_spec(block_k, d, "j"),
-                _tile_spec(block_k, d, "j"),
-            ],
+            grid=(batch, heads, nq, band.steps_k if band else nk),
+            in_specs=[_tile_spec(block_q, d, "i"), kv_spec, kv_spec],
             out_specs=out_specs,
             scratch_shapes=scratch),
         out_shape=out_shape,
         interpret=interpret,
-        name="flash_fwd",
+        name=_kernel_name("flash_fwd", band),
         **params,
     )(seed, q, k, v)
     out, lse = res[0], res[1]
@@ -901,7 +1023,7 @@ def _fa_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                         delta_ref, *rest, causal, sm_scale, block_q,
                         block_k, num_q_blocks, num_k_blocks, dropout_rate,
                         dropout_pbits=32, reuse_mask: bool = False,
-                        interpret: bool = False, walk=None):
+                        interpret: bool = False, walk=None, band=None):
     if reuse_mask:
         mask_ref, dk_ref, dv_ref, dk_scr, dv_scr = rest
     else:
@@ -909,9 +1031,14 @@ def _fa_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     b = pl.program_id(0)
     h = pl.program_id(1)
     ki = pl.program_id(2)
-    qi = pl.program_id(3)
+    qi = step = pl.program_id(3)
+    last_step = num_q_blocks - 1
+    window = None
+    if band is not None:   # inner step -> q block of the band
+        qi, last_step, window = (band.first_q(ki) + step, band.steps_q - 1,
+                                 band.window)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
@@ -938,7 +1065,8 @@ def _fa_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * sm_scale  # [bq, width]
             p = jnp.exp(s - lse)                          # [bq, width] fp32
-            p = _step_mask(p, qi, kj, block_q, unit, qrows, mask_col, 0.0)
+            p = _step_mask(p, qi, kj, block_q, unit, qrows, mask_col, 0.0,
+                           window)
 
             dp = jax.lax.dot_general(                # do @ v^T -> [bq, width]
                 do, v, (((1,), (1,)), ((), ())),
@@ -972,9 +1100,9 @@ def _fa_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                 ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)       # [width, d]
 
-    _walk_tile(qi, ki, block_q, block_k, causal, walk, _update)
+    _walk_tile(qi, ki, block_q, block_k, causal, walk, _update, band)
 
-    @pl.when(qi == num_q_blocks - 1)
+    @pl.when(step == last_step)
     def _finalize():
         _st(dk_ref, dk_scr[...].astype(dk_ref.dtype))
         _st(dv_ref, dv_scr[...].astype(dv_ref.dtype))
@@ -984,7 +1112,7 @@ def _fa_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                       delta_ref, *rest, causal, sm_scale, block_q,
                       block_k, num_k_blocks, dropout_rate,
                       dropout_pbits=32, reuse_mask: bool = False,
-                      interpret: bool = False, walk=None):
+                      interpret: bool = False, walk=None, band=None):
     if reuse_mask:
         mask_ref, dq_ref, dq_scr = rest
     else:
@@ -992,9 +1120,14 @@ def _fa_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     b = pl.program_id(0)
     h = pl.program_id(1)
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    ki = step = pl.program_id(3)
+    last_step = num_k_blocks - 1
+    window = None
+    if band is not None:
+        ki, last_step, window = (band.first_k(qi) + step, band.steps_k - 1,
+                                 band.window)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
@@ -1019,7 +1152,8 @@ def _fa_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * sm_scale
             p = jnp.exp(s - lse)
-            p = _step_mask(p, qi, kj, block_q, unit, qrows, mask_col, 0.0)
+            p = _step_mask(p, qi, kj, block_q, unit, qrows, mask_col, 0.0,
+                           window)
             dp = jax.lax.dot_general(
                 do, v, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -1039,9 +1173,9 @@ def _fa_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                 ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-    _walk_tile(qi, ki, block_q, block_k, causal, walk, _update)
+    _walk_tile(qi, ki, block_q, block_k, causal, walk, _update, band)
 
-    @pl.when(ki == num_k_blocks - 1)
+    @pl.when(step == last_step)
     def _finalize():
         _st(dq_ref, dq_scr[...].astype(dq_ref.dtype))
 
@@ -1053,11 +1187,13 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
                                layout: str = "bhsd",
                                dropout_rate: float = 0.0,
                                dropout_seed=None, dropout_mask=None,
-                               dropout_mask_block_q=None):
+                               dropout_mask_block_q=None,
+                               window: Optional[int] = None):
     """Block-wise dq, dk, dv — no [S, S] materialization in HBM.  Inputs
     and grads follow `layout` (lse is always [B, H, S]); "bshd" converts
     to the kernel's [B, H, S, D] at this boundary (see
-    flash_attention_pallas).
+    flash_attention_pallas).  Fewer key/value heads than query heads and
+    `window` as there; dk and dv come back with k's and v's heads.
 
     dropout_mask: the bit-packed [B, H, S_q/32, S_k] uint32 keep mask a
     save_dropout_mask forward stored (always internal-layout).  When
@@ -1073,19 +1209,22 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
         interpret=interpret, layout=layout,
         dropout_rate=float(dropout_rate),
         dropout_mask_block_q=dropout_mask_block_q,
-        dropout_bits=_dropout_bits)
+        dropout_bits=_dropout_bits, window=window)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "causal", "sm_scale", "block_q", "block_k", "interpret", "layout",
-    "dropout_rate", "dropout_mask_block_q", "dropout_bits"))
+    "dropout_rate", "dropout_mask_block_q", "dropout_bits", "window"))
 def _flash_bwd_call(q, k, v, out, lse, do, dropout_seed, dropout_mask, *,
                     causal, sm_scale, block_q, block_k, interpret, layout,
-                    dropout_rate, dropout_mask_block_q, dropout_bits):
+                    dropout_rate, dropout_mask_block_q, dropout_bits,
+                    window=None):
     """flash_attention_bwd_pallas, traced once a process and shape (see
     _flash_fwd_call)."""
     batch, heads, q_len, d = _dims(q, layout)
-    k_len = _dims(k, layout)[2]
+    kv_heads, k_len = _dims(k, layout)[1:3]
+    group = _kv_group(heads, kv_heads)
+    _check_window(window, causal, dropout_rate)
     if layout == "bshd":
         q, k, v = _t_bhsd(q), _t_bhsd(k), _t_bhsd(v)
         out, do = _t_bhsd(out), _t_bhsd(do)
@@ -1148,7 +1287,21 @@ def _flash_bwd_call(q, k, v, out, lse, do, dropout_seed, dropout_mask, *,
     mask_in = (dropout_mask,) if reuse else ()
     # as in the forward: the three kernels agree on the sub-tiles, whose
     # coordinates seed the dropout bits
-    walk = _causal_walk(q_len, k_len, block_q, block_k, causal, True)
+    band = None if window is None else _Band(window, block_q, block_k,
+                                             nq, nk)
+    walk = ({} if band else
+            _causal_walk(q_len, k_len, block_q, block_k, causal, True))
+    if band or group > 1:
+        if reuse:
+            raise ValueError("a stored dropout mask is not read by a call "
+                             "with a window or grouped key/value heads")
+        return _flash_bwd_grouped(
+            q, k, v, do, lse, delta, seed, band=band, group=group,
+            layout=layout, params=params, causal=causal,
+            sm_scale=float(sm_scale), block_q=block_q, block_k=block_k,
+            dropout_rate=float(dropout_rate),
+            dropout_pbits=_effective_dropout_bits(block_k, dropout_bits),
+            interpret=interpret, walk=walk)
 
     # dk/dv: grid over k blocks (grid dim 2), inner loop over q blocks
     # (grid dim 3) — _tile_spec's "i"/"j" name grid dims 2/3, so q/do tiles
@@ -1235,14 +1388,85 @@ def _flash_bwd_call(q, k, v, out, lse, do, dropout_seed, dropout_mask, *,
     return dq, dk, dv
 
 
+def _flash_bwd_grouped(q, k, v, do, lse, delta, seed, *, band, group,
+                       layout, params, **common):
+    """The two backward kernels of a call with a window or with fewer
+    key/value heads than query heads (no dropout mask to read): the same
+    kernel bodies (`common`: their static arguments) on the band's grid
+    and with key/value heads found by index.  The dkdv kernel still
+    writes one dk and dv a QUERY head (its grid cell owns its output
+    block); the heads of a group are summed here, in float32."""
+    batch, heads, q_len, d = q.shape
+    kv_heads, k_len = k.shape[1:3]
+    block_q, block_k, interpret = (common[key] for key in (
+        "block_q", "block_k", "interpret"))
+    nq, nk = q_len // block_q, k_len // block_k
+    common["band"] = band
+    q_of_k = band.q_block if band else None
+    k_of_q = band.k_block if band else None
+
+    def stats(seq_of, inner):
+        return _tile_spec(block_q, _STATS_LANES, seq_of, inner=inner)
+
+    dk, dv = pl.pallas_call(
+        functools.partial(_fa_bwd_dkdv_kernel, num_q_blocks=nq,
+                          num_k_blocks=nk, **common),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(batch, heads, nk, band.steps_q if band else nq),
+            in_specs=[
+                _tile_spec(block_q, d, "j", inner=q_of_k),
+                _tile_spec(block_k, d, "i", group),
+                _tile_spec(block_k, d, "i", group),
+                _tile_spec(block_q, d, "j", inner=q_of_k),
+                stats("j", q_of_k), stats("j", q_of_k),
+            ],
+            out_specs=[_tile_spec(block_k, d, "i"),
+                       _tile_spec(block_k, d, "i")],
+            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                            pltpu.VMEM((block_k, d), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((batch, heads, k_len, d), k.dtype),
+                   jax.ShapeDtypeStruct((batch, heads, k_len, d), v.dtype)],
+        interpret=interpret,
+        name=_kernel_name("flash_bwd_dkdv", band),
+        **params,
+    )(seed, q, k, v, do, lse, delta)
+    if group > 1:
+        dk, dv = (t.astype(jnp.float32).reshape(
+            batch, kv_heads, group, k_len, d).sum(2).astype(t.dtype)
+            for t in (dk, dv))
+
+    kv_spec = _tile_spec(block_k, d, "j", group, k_of_q)
+    dq = pl.pallas_call(
+        functools.partial(_fa_bwd_dq_kernel, num_k_blocks=nk, **common),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(batch, heads, nq, band.steps_k if band else nk),
+            in_specs=[
+                _tile_spec(block_q, d, "i"), kv_spec, kv_spec,
+                _tile_spec(block_q, d, "i"),
+                stats("i", None), stats("i", None),
+            ],
+            out_specs=_tile_spec(block_q, d, "i"),
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=interpret,
+        name=_kernel_name("flash_bwd_dq", band),
+        **params,
+    )(seed, q, k, v, do, lse, delta)
+    if layout == "bshd":
+        dq, dk, dv = _t_bhsd(dq), _t_bhsd(dk), _t_bhsd(dv)
+    return dq, dk, dv
+
+
 # --------------------------------------------------------------------------- #
 # Differentiable public entry point
 # --------------------------------------------------------------------------- #
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, seed, causal, sm_scale, block_q, block_k,
-           layout="bhsd", dropout_rate=0.0):
+           layout="bhsd", dropout_rate=0.0, window=None):
     return _flash_fwd(q, k, v, seed, causal, sm_scale, block_q, block_k,
-                      layout, dropout_rate)[0]
+                      layout, dropout_rate, window)[0]
 
 
 # Auto-dispatch crossover (v5e, 2026-07-31, docs/ROUND4_NOTES.md bert_ab
@@ -1275,16 +1499,16 @@ def _t_bhsd(t):
 
 
 def _ref_in_layout(q, k, v, causal, sm_scale, layout, dropout_rate=0.0,
-                   dropout_seed=None):
+                   dropout_seed=None, window=None):
     """XLA fallback in the caller's layout."""
     if layout == "bhsd":
         return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale,
                              dropout_rate=dropout_rate,
-                             dropout_seed=dropout_seed)
+                             dropout_seed=dropout_seed, window=window)
     return _t_bhsd(mha_reference(_t_bhsd(q), _t_bhsd(k), _t_bhsd(v),
                                  causal=causal, sm_scale=sm_scale,
                                  dropout_rate=dropout_rate,
-                                 dropout_seed=dropout_seed))
+                                 dropout_seed=dropout_seed, window=window))
 
 
 # checkpoint_name of the residuals only the forward kernel can produce
@@ -1295,7 +1519,7 @@ RESIDUAL_NAME = "flash_residuals"
 
 
 def _flash_fwd(q, k, v, seed, causal, sm_scale, block_q, block_k,
-               layout="bhsd", dropout_rate=0.0):
+               layout="bhsd", dropout_rate=0.0, window=None):
     q_len, k_len = _dims(q, layout)[2], _dims(k, layout)[2]
     if _use_pallas(q_len, k_len, q.shape[3], block_q, block_k):
         _, bq, bk = _resolve_blocks(q_len, k_len, block_q, block_k)
@@ -1303,21 +1527,23 @@ def _flash_fwd(q, k, v, seed, causal, sm_scale, block_q, block_k,
         # bit-packed keep mask in the residuals so the backward kernels
         # skip the PRNG — grads identical either way
         reuse = (dropout_rate > 0.0 and _dropout_reuse
-                 and _mask_reuse_usable(bq))
+                 and _mask_reuse_usable(bq)
+                 and _dims(q, layout)[1] == _dims(k, layout)[1])
         out, lse, *mask = checkpoint_name(flash_attention_pallas(
             q, k, v, causal=causal, sm_scale=sm_scale,
             block_q=bq, block_k=bk, return_lse=True, layout=layout,
             dropout_rate=dropout_rate, dropout_seed=seed,
-            save_dropout_mask=reuse, interpret=pallas_interpret()),
+            save_dropout_mask=reuse, interpret=pallas_interpret(),
+            window=window),
             RESIDUAL_NAME)
         return out, (q, k, v, seed, out, lse, mask[0] if reuse else None)
     out = _ref_in_layout(q, k, v, causal, sm_scale, layout, dropout_rate,
-                         seed[0])
+                         seed[0], window)
     return out, (q, k, v, seed, None, None, None)
 
 
 def _flash_bwd(causal, sm_scale, block_q, block_k, layout, dropout_rate,
-               res, g):
+               window, res, g):
     q, k, v, seed, out, lse, mask = res
     if lse is not None:
         q_len, k_len = _dims(q, layout)[2], _dims(k, layout)[2]
@@ -1327,11 +1553,12 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, layout, dropout_rate,
             block_q=bq, block_k=bk, layout=layout,
             dropout_rate=dropout_rate, dropout_seed=seed,
             dropout_mask=mask, dropout_mask_block_q=bq,
-            interpret=pallas_interpret())
+            interpret=pallas_interpret(), window=window)
         return dq, dk, dv, None
     _, vjp = jax.vjp(
         lambda q_, k_, v_: _ref_in_layout(q_, k_, v_, causal, sm_scale,
-                                          layout, dropout_rate, seed[0]),
+                                          layout, dropout_rate, seed[0],
+                                          window),
         q, k, v)
     return (*vjp(g), None)
 
@@ -1345,7 +1572,7 @@ _SHARD_SEED_STRIDE = np.int32(-862048943)
 
 
 def _flash_over_mesh(q, k, v, seed, causal, sm_scale, block_q, block_k,
-                     layout, dropout_rate):
+                     layout, dropout_rate, window=None):
     """_flash, placed for the device mesh.  Where the Pallas kernel will
     run, the call sits in a region manual over every mesh axis
     (dispatch.manual_kernel_region) with the batch split over the data
@@ -1355,7 +1582,7 @@ def _flash_over_mesh(q, k, v, seed, causal, sm_scale, block_q, block_k,
     q_len, k_len = _dims(q, layout)[2], _dims(k, layout)[2]
     if not _use_pallas(q_len, k_len, q.shape[3], block_q, block_k):
         return _flash(q, k, v, seed, causal, sm_scale, block_q, block_k,
-                      layout, dropout_rate)
+                      layout, dropout_rate, window)
     dims = {0: BATCH_AXES, (1 if layout == "bhsd" else 2): HEAD_AXES}
 
     def local(shard_index, q, k, v, seed):
@@ -1363,7 +1590,7 @@ def _flash_over_mesh(q, k, v, seed, causal, sm_scale, block_q, block_k,
         # seed alone all shards would draw the same dropout masks
         seed = seed + shard_index * _SHARD_SEED_STRIDE
         return _flash(q, k, v, seed, causal, sm_scale, block_q, block_k,
-                      layout, dropout_rate)
+                      layout, dropout_rate, window)
 
     return manual_kernel_region(local, (q, k, v, seed),
                                 (dims, dims, dims, None), dims)
@@ -1390,8 +1617,14 @@ def flash_attention(q, k, v, causal: bool = False,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K,
                     impl: str = "auto", dropout_rate: float = 0.0,
-                    dropout_seed=None):
+                    dropout_seed=None, window: Optional[int] = None):
     """Fused multi-head attention: q,k,v [B, H, S, D] -> [B, H, S, D].
+
+    k and v may have fewer heads than q (grouped key/value heads: head j
+    serves query heads [j * group, (j + 1) * group)), found by index map
+    in the kernels.  `window` (causal calls): each query sees its last
+    `window` keys, its own included; the kernels visit the band's tiles
+    only.  A call with neither lowers to the kernels it always did.
 
     impl: "auto" (default) runs the Pallas flash kernel with blocks fitted
     to the sequence lengths (_resolve_blocks), falling back to the XLA
@@ -1422,17 +1655,17 @@ def flash_attention(q, k, v, causal: bool = False,
                 f"({q.shape[2]},{k.shape[2]}) or Pallas unavailable on this "
                 "backend — use impl='auto' for the XLA fallback")
         return _flash_over_mesh(q, k, v, seed, causal, sm_scale, block_q,
-                                block_k, "bhsd", dropout_rate)
+                                block_k, "bhsd", dropout_rate, window)
     if bias is not None:
         return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale,
                              bias=bias, dropout_rate=dropout_rate,
-                             dropout_seed=seed[0])
+                             dropout_seed=seed[0], window=window)
     if impl == "xla" or _auto_prefers_xla(k.shape[2]):
         return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale,
                              dropout_rate=dropout_rate,
-                             dropout_seed=seed[0])
+                             dropout_seed=seed[0], window=window)
     return _flash_over_mesh(q, k, v, seed, causal, sm_scale, block_q,
-                            block_k, "bhsd", dropout_rate)
+                            block_k, "bhsd", dropout_rate, window)
 
 
 def flash_attention_bsh(q, k, v, causal: bool = False,

@@ -8,8 +8,10 @@ rematted_computation/mlp/dot_general" ...}`` on every instruction.
 ``parse`` reads that text once and gives, for each instruction name,
 
   scope  the innermost of the model's named scopes on the ``op_name``:
-         ``embed``, ``attn``, ``mlp``, ``head``; ``layer`` for what is in
-         a layer and in neither ``attn`` nor ``mlp``; ``other`` where no
+         ``embed``, ``attn``, ``ssm`` (a Mamba mixer: projections, conv,
+         selective scan, gate), ``gmu`` (a gated-memory unit), ``mlp``,
+         ``head``; ``layer`` for what is in a layer and in no mixer's
+         scope nor ``mlp``; ``other`` where no
          scope is named or the instruction has no metadata.  A scope can
          sit inside ``jvp(...)`` or ``transpose(...)``; those are looked
          into.
@@ -29,7 +31,7 @@ nothing is lowered until it is called.
 import re
 import weakref
 
-SCOPES = ("embed", "attn", "mlp", "head", "layer")
+SCOPES = ("embed", "attn", "ssm", "gmu", "mlp", "head", "layer")
 OTHER = "other"
 PHASES = ("forward", "recompute", "backward")
 

@@ -37,6 +37,7 @@ policies instead of hand-managed tensor stashes:
                                   analog of CudaRNGStatesTracker
 """
 
+import functools
 import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -240,6 +241,8 @@ class RematBudget:
             f"{plan[R.M_REMAT_BYTES_LIMIT]:,} - state "
             f"{plan[R.M_REMAT_STATE_BYTES]:,} - working set "
             f"{plan[R.M_REMAT_WORKING_SET_BYTES]:,})", ranks=[0])
+        if R.M_STACK_LAYERS in plan:
+            log_dist(stack_plan_line(plan), ranks=[0])
 
     def take_plan(self) -> Optional[Dict[str, Any]]:
         """The plan, if the monitor has not had it yet."""
@@ -294,35 +297,69 @@ def checkpoint_layer(body: Callable, budget: Optional[RematBudget],
     ``head_width`` wide.  No budget, or none that fits:
     ``jax.checkpoint(body)`` as it always was, the layer's input kept and
     the rest recomputed."""
+    return checkpoint_layers([(body, stacked_xs)], budget, carry,
+                             head_width)(body)
+
+
+def stack_plan_line(plan: Dict[str, Any]) -> str:
+    """The log line of a stack of unlike layers (the M_STACK_* fields)."""
+    return (
+        "layer stack: " + ", ".join(
+            f"{i}:{kind}" + (f"(window {w})" if w else "")
+            for i, kind, w in plan[R.M_STACK_LAYERS])
+        + f"; selective scan in chunks of {plan[R.M_STACK_SCAN_CHUNK]}, "
+        f"{plan[R.M_STACK_SCAN_ENTRY_BYTES]:,} B of entry states a call; "
+        "kept across layers: " + ", ".join(
+            f"{name} {size:,} B"
+            for name, size in plan[R.M_STACK_CROSS_LAYER_KEPT]))
+
+
+def checkpoint_layers(groups, budget: Optional[RematBudget], carry,
+                      head_width: int,
+                      stack_plan: Optional[Dict[str, Any]] = None):
+    """``checkpoint_layer`` for a model whose stack is several scanned
+    groups of unlike layers: ``groups`` is a list of ``(body,
+    stacked_xs)``, every body taking the same ``carry``; one budget is
+    spent over all of them (a name is kept in every group or in none) and
+    one plan is noted, ``stack_plan`` (the model's M_STACK_* fields)
+    riding on it.  Returns the wrapper for every body of the stack: what
+    a wrapped body closes over (another layer's output that this one
+    reads; the plan may be made on a stand-in of its shape) is an input
+    of the checkpointed body: kept, never recomputed."""
     if budget is None or budget.bytes_limit is None:
-        return jax.checkpoint(body)
-    num_layers = jax.tree.leaves(stacked_xs)[0].shape[0]
-    offered = offered_residuals(
-        body, jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), carry),
-        jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), stacked_xs),
-        batch_shards=budget.batch_shards)
+        return jax.checkpoint
+    shape = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), carry)
+    steps = [jax.tree.leaves(xs)[0].shape[0] for _, xs in groups]
+    offers = [offered_residuals(
+        body, shape, jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), xs),
+        batch_shards=budget.batch_shards) for body, xs in groups]
+    # bytes of a name over the whole stack, as if it were one layer's
+    offered = {name: sum(o.get(name, 0) * n for o, n in zip(offers, steps))
+               for o in offers for name in o}
+    num_layers = sum(steps)
     working_set = budget.working_set
     if working_set is None:
         working_set = working_set_bytes(
             math.prod(carry.shape[:-1]) // budget.batch_shards,
             carry.shape[-1], num_layers, head_width, carry.dtype.itemsize,
             budget.cast_bytes)
-    kept = saved_residual_names(offered, num_layers,
-                                budget.bytes(working_set))
-    per_layer = sum(offered[name] for name in kept)
+    kept = saved_residual_names(offered, 1, budget.bytes(working_set))
+    total = sum(offered[name] for name in kept)
     budget.note_plan({
         R.M_REMAT_OFFERED: tuple(n for n in RESIDUAL_ORDER if n in offered),
         R.M_REMAT_KEPT: kept,
-        R.M_REMAT_KEPT_BYTES_PER_LAYER: per_layer,
-        R.M_REMAT_KEPT_BYTES: per_layer * num_layers,
+        R.M_REMAT_KEPT_BYTES_PER_LAYER: max(
+            sum(o.get(name, 0) for name in kept) for o in offers),
+        R.M_REMAT_KEPT_BYTES: total,
         R.M_REMAT_LAYERS: num_layers,
         R.M_REMAT_BUDGET_BYTES: budget.bytes(working_set),
         R.M_REMAT_BYTES_LIMIT: int(budget.bytes_limit),
         R.M_REMAT_STATE_BYTES: budget.state_bytes,
-        R.M_REMAT_WORKING_SET_BYTES: working_set})
-    return jax.checkpoint(body, policy=(
+        R.M_REMAT_WORKING_SET_BYTES: working_set,
+        **(stack_plan or {})})
+    return functools.partial(jax.checkpoint, policy=(
         jax.checkpoint_policies.save_only_these_names(*kept) if kept
         else None))
 

@@ -1,0 +1,72 @@
+"""The flash kernels with a window and with grouped key/value heads
+(interpret mode) against ``mha_reference`` with the band as a mask:
+forward and the three gradients, at 20 query heads on 10."""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deepspeed_tpu.ops import dispatch
+
+fa = importlib.import_module("deepspeed_tpu.ops.flash_attention")
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    monkeypatch.setenv("DS_FLASH_MIN_SEQ", "0")
+    dispatch.set_pallas_interpret(True)
+    yield
+    dispatch.set_pallas_interpret(False)
+
+
+def _compare(seq, heads, kv_heads, window, block_q, block_k, dim=64):
+    ks = jax.random.split(jax.random.PRNGKey(seq + heads), 4)
+    q, g = (jax.random.normal(k, (1, heads, seq, dim)) for k in ks[:2])
+    k, v = (jax.random.normal(k, (1, kv_heads, seq, dim)) for k in ks[2:])
+    ours = jax.value_and_grad(lambda *a: jnp.sum(fa.flash_attention(
+        *a, causal=True, window=window, block_q=block_q, block_k=block_k,
+        impl="pallas") * g), (0, 1, 2))(q, k, v)
+    want = jax.value_and_grad(lambda *a: jnp.sum(fa.mha_reference(
+        *a, causal=True, window=window) * g), (0, 1, 2))(q, k, v)
+    for a, b in zip(jax.tree.leaves(ours), jax.tree.leaves(want)):
+        assert a.shape == b.shape
+        assert float(jnp.max(jnp.abs(a - b))) <= 1e-4 * float(
+            jnp.max(jnp.abs(b)))
+
+
+@pytest.mark.parametrize("seq, heads, kv_heads, window, block_q, block_k", [
+    (512, 20, 10, 128, 128, 128),     # the cell's form: window = blocks
+    (512, 4, 2, 128, 64, 256),        # key blocks wider than q blocks
+    (512, 4, 4, 100, 128, 128),       # a window no block divides
+    (512, 2, 2, 200, 256, 128),       # q blocks wider than key blocks
+    (512, 4, 2, None, 128, 128),      # grouped heads, no window
+    (1024, 2, 1, None, 512, 1024),    # grouped heads, the sub-tile walk
+])
+def test_band_and_groups_match_the_masked_reference(
+        interpreted, seq, heads, kv_heads, window, block_q, block_k):
+    _compare(seq, heads, kv_heads, window, block_q, block_k)
+
+
+def test_the_band_visits_its_tiles_only():
+    band = fa._Band(512, 512, 512, 16, 16)
+    assert (band.steps_k, band.steps_q) == (2, 2)       # of 16
+    assert [band.first_k(qi) for qi in (0, 1, 5)] == [0, 0, 4]
+    assert [band.first_q(ki) for ki in (0, 15)] == [0, 15]
+    band = fa._Band(100, 128, 128, 4, 4)
+    assert (band.steps_k, band.steps_q) == (2, 2)
+
+
+def test_a_window_needs_a_causal_call_and_takes_no_dropout(interpreted):
+    x = jnp.zeros((1, 2, 256, 64))
+    with pytest.raises(ValueError, match="causal"):
+        fa.flash_attention_pallas(x, x, x, causal=False, window=64,
+                                  interpret=True)
+    with pytest.raises(ValueError, match="dropout"):
+        fa.flash_attention_pallas(x, x, x, causal=True, window=64,
+                                  dropout_rate=0.1, dropout_seed=1,
+                                  interpret=True)
+    with pytest.raises(ValueError, match="multiple"):
+        three = jnp.zeros((1, 3, 256, 64))
+        fa.mha_reference(x, three, three)

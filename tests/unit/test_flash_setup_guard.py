@@ -9,6 +9,13 @@ kernel's non-causal lowering: with the shipped blocks the causal bound
 (ops/flash_attention.py _walk_tile) costs a second body, at half the
 width, and no more.
 
+The window and the grouped key/value heads (PR 34) are held to the same
+measure: each banded kernel, at the shapes of `phi4-mini-flash.s8k`,
+compiles and lowers to a module at most twice its plain causal one's;
+and a GPT-2 call (no window, equal head counts) still traces the three
+kernels it traced before them, body for body, so that they cost the
+GPT-2 cells no set-up.
+
 The topology is described inside a module-scoped fixture, never while a
 module is imported (see tests/perf/test_aot_kernels.py).
 """
@@ -48,30 +55,35 @@ def one_chip(topo):
     compilation_cache.reset_cache()
 
 
-def _lowerings(shape, one_chip, causal):
-    """The forward call's and the backward pair's lowerings for the v5e."""
+def _lowerings(shape, one_chip, causal, kv_heads=None, dropout=DROPOUT,
+               **call):
+    """The forward call's and the backward pair's lowerings for the v5e;
+    `kv_heads` fewer key/value heads than `shape` has query heads, `call`
+    a window and its blocks."""
     import jax
     import jax.numpy as jnp
     from deepspeed_tpu.ops.flash_attention import (
         flash_attention_bwd_pallas, flash_attention_pallas)
 
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((shape[0], kv_heads or shape[1], *shape[2:]),
+                              jnp.bfloat16, sharding=one_chip)
     lse = jax.ShapeDtypeStruct(shape[:3], jnp.float32, sharding=one_chip)
     seed = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
 
     def fwd(q, k, v, s):
         return flash_attention_pallas(q, k, v, causal=causal,
-                                      return_lse=True, dropout_rate=DROPOUT,
-                                      dropout_seed=s)
+                                      return_lse=True, dropout_rate=dropout,
+                                      dropout_seed=s, **call)
 
     def bwd(q, k, v, out, lse, do, s):
         return flash_attention_bwd_pallas(q, k, v, out, lse, do,
                                           causal=causal,
-                                          dropout_rate=DROPOUT,
-                                          dropout_seed=s)
+                                          dropout_rate=dropout,
+                                          dropout_seed=s, **call)
 
-    return (jax.jit(fwd).lower(x, x, x, seed),
-            jax.jit(bwd).lower(x, x, x, x, lse, x, seed))
+    return (jax.jit(fwd).lower(x, kv, kv, seed),
+            jax.jit(bwd).lower(x, kv, kv, x, lse, x, seed))
 
 
 def _module_sizes(lowerings):
@@ -99,3 +111,80 @@ def test_causal_kernels_compile_and_stay_small(shape, one_chip):
             f"{kernel} at {shape}: the causal module is {size[kernel]} "
             f"bytes against {whole[kernel]} non-causal: a kernel body "
             "unrolled per sub-tile is traced and lowered at every start")
+
+
+# [B, H, S, D] of the query operand of one call in phi4-mini-flash.s8k:
+# 20 query heads on 10 key/value heads, 8,192 positions
+PHI4 = (1, 20, 8192, 64)
+BANDED = dict(window=512, block_q=512, block_k=512)
+
+
+def test_banded_kernels_compile_and_stay_small(one_chip):
+    banded = _lowerings(PHI4, one_chip, True, kv_heads=10, dropout=0.0,
+                        **BANDED)
+    for lowered in banded:
+        lowered.compile()
+    size = _module_sizes(banded)
+    plain = _module_sizes(_lowerings(PHI4, one_chip, True, kv_heads=10,
+                                     dropout=0.0, block_q=512, block_k=512))
+    assert sorted(size) == sorted(k + "_band" for k in KERNELS)
+    for kernel in KERNELS:
+        assert size[kernel + "_band"] <= 2 * plain[kernel], (
+            kernel, size[kernel + "_band"], plain[kernel])
+
+
+def test_grouped_causal_kernels_compile_at_the_cells_shape(one_chip):
+    for lowered in _lowerings(PHI4, one_chip, True, kv_heads=10,
+                              dropout=0.0):
+        lowered.compile()
+
+
+# The three kernels of a GPT-2 large / xl call at the parent of PR 34
+# (commit 0275698): equations of each kernel's body, sub-jaxprs in, and
+# the grid's two sequence dimensions.  (A lowered module's bytes carry
+# its source locations and the caller's stack, so they move with any
+# edit of the file; the traced body does not.)
+PARENT_KERNELS = {"flash_fwd": (246, (2, 1)), "flash_bwd_dkdv": (342, (1, 2)),
+                  "flash_bwd_dq": (297, (2, 1))}
+
+
+def _equations(jaxpr):
+    from deepspeed_tpu.analysis.jaxpr_walk import sub_jaxprs
+    return sum(1 + sum(_equations(sub.jaxpr) for sub in sub_jaxprs(eqn))
+               for eqn in jaxpr.eqns)
+
+
+def _traced_kernels(jaxpr, found):
+    """{kernel name: (equations of its body, grid)} of every pallas_call."""
+    from deepspeed_tpu.analysis.jaxpr_walk import sub_jaxprs
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            assert name not in found
+            found[name] = (_equations(eqn.params["jaxpr"]),
+                           tuple(eqn.params["grid_mapping"].grid))
+        else:
+            for sub in sub_jaxprs(eqn):
+                _traced_kernels(sub.jaxpr, found)
+    return found
+
+
+@pytest.mark.parametrize("shape", SHAPES, ids=["large", "xl"])
+def test_a_gpt2_call_traces_the_parents_kernels(shape):
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.ops.flash_attention import (
+        flash_attention_bwd_pallas, flash_attention_pallas)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct(shape[:3], jnp.float32)
+    seed = jax.ShapeDtypeStruct((), jnp.int32)
+    found = _traced_kernels(jax.make_jaxpr(
+        lambda q, k, v, s: flash_attention_pallas(
+            q, k, v, causal=True, return_lse=True, dropout_rate=DROPOUT,
+            dropout_seed=s))(x, x, x, seed).jaxpr, {})
+    _traced_kernels(jax.make_jaxpr(
+        lambda q, k, v, o, lse_, do, s: flash_attention_bwd_pallas(
+            q, k, v, o, lse_, do, causal=True, dropout_rate=DROPOUT,
+            dropout_seed=s))(x, x, x, x, lse, x, seed).jaxpr, found)
+    assert found == {name: (count, shape[:2] + grid)
+                     for name, (count, grid) in PARENT_KERNELS.items()}
