@@ -15,6 +15,22 @@ logsumexp; two Pallas kernels recompute P block-wise and produce dk/dv
 materialization.  The XLA reference path serves CPU and the bias/fallback
 cases.
 
+The forward kernel (_fa_kernel) computes a step transposed: scores
+k . q^T, [keys, block_q], q rows along the LANES.  A row's running max,
+sum and rescaling factor are then [1, block_q] rows that fill their
+vector registers, a step's max and sum reduce over the sublanes, and
+out = (v^T . p)^T is transposed once a q block.  With the rows on the
+sublanes (the kernel until PR 35, kept as _fa_kernel_rows for the calls
+the new one cannot serve) every per-row number was a cross-lane
+reduction and then a [block_q, 1] column, one lane in 128 at work, and
+that was six tenths of the kernel: 0.435 ms a call at [4, 20, 1024, 64],
+causal, dropout 0.1, on the v5e against 0.287 now (PERF.md section 6,
+PR 35).  What is left follows the score elements (scale, mask, exp, sum,
+the dropout planes and select, the cast), not the rows or the steps.
+The backward kernels carry no such state and keep q rows on the
+sublanes; the dropout bits are defined in that orientation
+(_dropout_keep), and the forward transposes the drawn WORDS.
+
 Inside a tile a causal call bounds its work by the diagonal (the section
 "The causal bound inside a tile" below): of the tile's 512-column
 sub-tiles, the ones wholly above the diagonal are not computed and only
@@ -153,13 +169,16 @@ def causal_keep_mask(qi_block, ki_block, block_q, block_k):
 # _CAUSAL_SUB_Q q rows, each as wide as its own last row reaches.
 #
 # Why one step of static width and not a loop over sub-tiles (v5e, PR 31,
-# PERF.md section 6): a forward step's cost is mostly its rows' running
-# max / sum / accumulator update and cross-lane reductions, paid per step
-# whatever the width (0.275 of the kernel's 0.47 ms at [4, 20, 1024, 64]),
-# so two lax.fori_loops over 512-column sub-tiles made the forward kernel
-# 29% SLOWER than no bound (three steps a head for two) where this form
-# makes it 4% faster; the backward kernels carry no such state, follow the
-# products they compute, and take the same form.
+# PERF.md section 6): with q rows on the sublanes, as the forward kernel
+# had them then, a step's cost was mostly its rows' running max / sum /
+# accumulator update and cross-lane reductions, paid per step whatever
+# the width (0.275 of the kernel's 0.47 ms at [4, 20, 1024, 64]), so two
+# lax.fori_loops over 512-column sub-tiles made the forward kernel 29%
+# SLOWER than no bound (three steps a head for two) where this form made
+# it 4% faster; the backward kernels carry no such state, follow the
+# products they compute, and take the same form.  PR 35 took that
+# per-step cost out of the forward (rows along the lanes: _fa_kernel) and
+# kept this form; a loop has not been tried on the new body.
 #
 # Why 512 columns: the 8-bit dropout layout draws one PRNG word per four
 # columns, so a 512-wide sub-tile's words are exactly one 128-lane vreg
@@ -329,7 +348,7 @@ def _interpret_random_bits(v1, v2, shape):
 
 
 def _dropout_keep(seed_ref, b, h, qi, ki, rate, block_q, block_k,
-                  num_k_blocks, bits=32, interpret=False):
+                  num_k_blocks, bits=32, interpret=False, keys_first=False):
     """Regenerable per-tile keep mask: the PRNG is reseeded from the step
     seed and the tile's ABSOLUTE coordinates, so the forward kernel and
     both backward kernels (whose grids order (qi, ki) differently)
@@ -354,7 +373,12 @@ def _dropout_keep(seed_ref, b, h, qi, ki, rate, block_q, block_k,
     vanishingly unlikely while tile counts stay tiny vs 2^32.  All
     arithmetic stays in plain int32: scalar casts/bitcasts are
     Mosaic-illegal ('tpu.bitcast' needs vector operands — measured on
-    v5e, round 4)."""
+    v5e, round 4).
+
+    `keys_first` (the forward kernel, whose scores are [keys, q rows]):
+    the same decision for every (row, key), as [block_k, block_q].  The
+    WORDS are drawn as ever and transposed, a quarter of the tile; their
+    four byte planes then stack along the sublanes."""
     v1 = seed_ref[0] + b * pl.num_programs(1) + h
     v2 = qi * num_k_blocks + ki + seed_ref[0] * np.int32(-1640531527)
     if interpret:
@@ -371,12 +395,22 @@ def _dropout_keep(seed_ref, b, h, qi, ki, rate, block_q, block_k,
         # bits where block_k is known (_effective_dropout_bits), so the
         # divisibility precondition holds here by construction.
         assert block_k % 4 == 0, "8-bit dropout requires block_k % 4 == 0"
-        w = random_bits((block_q, block_k // 4)).astype(jnp.uint32)
+        w = random_bits((block_q, block_k // 4))
+        if keys_first:
+            # signed words: the planes are 0..255 either way, and the
+            # v5e compares int32 natively (uint32: 0.010 ms a call more)
+            w = w.astype(jnp.int32).T
+            planes = [(w >> (8 * j)) & 0xFF for j in range(3)] + [
+                jax.lax.shift_right_logical(w, jnp.int32(24))]
+            return (jnp.concatenate(planes, axis=0)
+                    < np.int32(_quantized_threshold(rate, 8)))
+        w = w.astype(jnp.uint32)
         t8 = _quantized_threshold(rate, 8)
         m = jnp.concatenate(
             [(w >> np.uint32(8 * j)) & np.uint32(0xFF) for j in range(4)],
             axis=1)
         return m < np.uint32(t8)
+    assert not keys_first, "the keys-first forward draws 8-bit words"
     rbits = random_bits((block_q, block_k))
     threshold = np.uint32(_quantized_threshold(rate, 32))
     return rbits.astype(jnp.uint32) < threshold
@@ -598,7 +632,11 @@ def _prefix_parts(n, block_q, sub, band, sub_q):
 # of 512 = none / 256 / 128 rows): dkdv 0.457 / 0.401 / 0.425, dq 0.304 /
 # 0.271 / 0.266; at 128 the modules pass twice the non-causal ones' size
 # (tests/unit/test_flash_setup_guard.py).  The forward kernel takes none:
-# 0.434 / 0.447 / 0.412, its cost being per row and step, not per column.
+# 0.434 / 0.447 / 0.412 on the body of that time (rows on the sublanes,
+# its cost per row and step, not per column).  Since PR 35 its rows lie
+# along the lanes and its cost follows the score elements; a group of q
+# rows is then a lane slice of every [keys, block_q] array, and has not
+# been tried.
 _CAUSAL_SUB_Q = 256
 
 
@@ -625,41 +663,52 @@ def _causal_walk(q_len, k_len, block_q, block_k, causal, row_groups):
 
 
 def _step_keep(seed_ref, b, h, qi, kj, n, rate, block_q, unit, num_units,
-               bits, interpret):
+               bits, interpret, keys_first=False):
     """Keep mask of a step over the n units of `unit` key columns from
     unit kj on: each unit's own regenerable draw (_dropout_keep), side by
     side, so the mask of a position does not depend on how a kernel
-    walks its tiles."""
+    walks its tiles.  [block_q, n * unit], or `keys_first` its
+    transpose."""
     draws = [_dropout_keep(seed_ref, b, h, qi, kj + j if j else kj, rate,
                            block_q, unit, num_units, bits=bits,
-                           interpret=interpret)
+                           interpret=interpret, keys_first=keys_first)
              for j in range(n)]
-    return draws[0] if n == 1 else jnp.concatenate(draws, axis=1)
+    return draws[0] if n == 1 else jnp.concatenate(
+        draws, axis=0 if keys_first else 1)
 
 
 def _step_mask(x, qi, kj, block_q, unit, qrows, mask_col, fill,
-               window=None):
+               window=None, keys_first=False):
     """x, the scores or probabilities of q rows `qrows` (None: all) of
     block qi against the key columns from unit kj on, with `fill` above
     the diagonal from column mask_col on (None: the piece lies wholly
     under the diagonal); under a `window`, the whole tile, with `fill`
-    left of the band as well."""
+    left of the band as well.  x is [q rows, keys], or `keys_first`
+    [keys, q rows]."""
+    q_axis, k_axis = (1, 0) if keys_first else (0, 1)
     if window is not None:
-        row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
-        col = kj * unit + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+        row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, x.shape,
+                                                      q_axis)
+        col = kj * unit + jax.lax.broadcasted_iota(jnp.int32, x.shape,
+                                                   k_axis)
         return jnp.where((col <= row) & (col > row - window), x, fill)
     if mask_col is None:
         return x
-    if qrows is None and mask_col == 0 and x.shape[1] == unit:
+    if (not keys_first and qrows is None and mask_col == 0
+            and x.shape[1] == unit):
         return jnp.where(causal_keep_mask(qi, kj, block_q, unit), x, fill)
-    shape = (x.shape[0], x.shape[1] - mask_col)
+    shape = list(x.shape)
+    shape[k_axis] -= mask_col
     row = (qi * block_q + (qrows.start if qrows else 0)
-           + jax.lax.broadcasted_iota(jnp.int32, shape, 0))
+           + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis))
     col = kj * unit + mask_col + jax.lax.broadcasted_iota(
-        jnp.int32, shape, 1)
-    tail = jnp.where(col <= row, x[:, mask_col:], fill)
-    return (tail if mask_col == 0
-            else jnp.concatenate([x[:, :mask_col], tail], axis=1))
+        jnp.int32, shape, k_axis)
+    tail = jnp.where(col <= row,
+                     x[mask_col:] if keys_first else x[:, mask_col:], fill)
+    if mask_col == 0:
+        return tail
+    head = x[:mask_col] if keys_first else x[:, :mask_col]
+    return jnp.concatenate([head, tail], axis=k_axis)
 
 
 def _piece(x, qrows, width):
@@ -675,12 +724,118 @@ def _at(rows):
     return (Ellipsis,) if rows is None else (rows, slice(None))
 
 
-def _fa_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-               *rest,
+def _fa_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                causal: bool, sm_scale: float, block_q: int, block_k: int,
                num_k_blocks: int, dropout_rate: float,
-               dropout_pbits: int = 32, save_mask: bool = False,
                interpret: bool = False, walk=None, band=None):
+    """The forward kernel, q rows along the lanes: a step's scores are
+    k . q^T, [keys, block_q], so the row statistics (max, sum, the
+    rescaling of the accumulator) are [1, block_q] rows that fill their
+    vector registers, the max and the sum of a step reduce over the
+    sublanes (element-wise across registers, one 8-to-1 fold a lane
+    tile), and the accumulator is v^T . p, [d, block_q]; out and the
+    log-sum-exp are transposed back ONCE a q block.  Dropout is the
+    8-bit draw (_dropout_keep, keys_first).
+
+    Without `scratch` the call's inner grid dimension is one step: there
+    is no earlier max or sum to fold in, and the step finishes in place."""
+    b = pl.program_id(0)
+    h = pl.program_id(1)
+    qi = pl.program_id(2)
+    ki = step = pl.program_id(3)
+    last_step = num_k_blocks - 1
+    window = None
+    if band is not None:   # inner step -> key block of the band
+        ki, last_step, window = (band.first_k(qi) + step, band.steps_k - 1,
+                                 band.window)
+
+    def _finish(m, denom, acc):
+        """Write out = acc / denom, [block_q, d], and the log-sum-exp
+        m + log denom from the [1, block_q] statistics and the
+        [d, block_q] accumulator: the q block's one transpose of each."""
+        # logsumexp residual for the backward pass (FlashAttention-2 style)
+        lse = m + jnp.log(denom + 1e-37)
+        # Fully-masked rows have denom == 0; emit zeros not NaN.
+        out_t = acc / jnp.where(denom == 0.0, 1.0, denom)
+        _st(o_ref, out_t.T.astype(o_ref.dtype))
+        lse_ref[0, 0] = jnp.broadcast_to(lse, (_STATS_LANES, block_q)).T
+
+    if scratch:
+        m_scr, l_scr, acc_scr = scratch
+
+        @pl.when(step == 0)
+        def _init():
+            m_scr[...] = jnp.full_like(m_scr, DEFAULT_MASK_VALUE)
+            l_scr[...] = jnp.zeros_like(l_scr)
+            acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    unit = walk["sub_k"] if walk else block_k
+
+    def _update(kj, n, rows, parts):
+        """One online-softmax step over the n units of `unit` keys from
+        unit kj on: the whole block (n 1, rows None, kj == ki) or
+        sub-tiles of it, key rows `rows` of the block.  In one piece: the
+        forward kernel takes no q-row groups."""
+        (_, _, mask_col), = parts
+        # bf16 operands straight into the MXU; fp32 accumulation via
+        # preferred_element_type (upcasting first would force an fp32 matmul).
+        q = _ld(q_ref)                               # [bq, d]
+        k = _ld(k_ref, rows)                         # [width, d]
+        s = jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale  # [width, bq] fp32
+        s = _step_mask(s, qi, kj, block_q, unit, None, mask_col,
+                       DEFAULT_MASK_VALUE, window, keys_first=True)
+
+        m_next = jnp.max(s, axis=0, keepdims=True)    # [1, bq]
+        if scratch:
+            m_prev = m_scr[...]
+            m_next = jnp.maximum(m_prev, m_next)
+            alpha = jnp.exp(m_prev - m_next)          # [1, bq]
+        p = jnp.exp(s - m_next)                       # [width, bq] fp32
+        l_next = jnp.sum(p, axis=0, keepdims=True)    # [1, bq]
+
+        if dropout_rate > 0.0:
+            # probability dropout: the PV input is masked+rescaled but the
+            # normalizer l accumulates the RAW p (softmax normalizes true
+            # probabilities; dropout applies to the normalized P, which
+            # commutes with the final /l)
+            keep = _step_keep(seed_ref, b, h, qi, kj, n, dropout_rate,
+                              block_q, unit,
+                              num_k_blocks * (block_k // unit), 8,
+                              interpret, keys_first=True)
+            p = jnp.where(keep, p * _keep_scale(dropout_rate, 8), 0.0)
+
+        v_blk = _ld(v_ref, rows)                     # [width, d]
+        pv = jax.lax.dot_general(
+            v_blk, p.astype(v_blk.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)       # [d, bq]
+        if not scratch:
+            _finish(m_next, l_next, pv)
+            return
+        m_scr[...] = m_next
+        l_scr[...] = l_scr[...] * alpha + l_next
+        acc_scr[...] = acc_scr[...] * alpha + pv
+
+    _walk_tile(qi, ki, block_q, block_k, causal, walk, _update, band)
+
+    if scratch:
+        @pl.when(step == last_step)
+        def _finalize():
+            _finish(m_scr[...], l_scr[...], acc_scr[...])
+
+
+def _fa_kernel_rows(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                    *rest,
+                    causal: bool, sm_scale: float, block_q: int,
+                    block_k: int, num_k_blocks: int, dropout_rate: float,
+                    dropout_pbits: int = 32, save_mask: bool = False,
+                    interpret: bool = False, walk=None, band=None):
+    """The forward kernel with q rows along the sublanes, scores
+    [block_q, keys], for the calls _fa_kernel cannot serve
+    (_rows_on_lanes): it stores the packed dropout mask, whose
+    [block_q / 32, block_k] packing rides the sublanes of this
+    orientation, and draws 32-bit dropout words."""
     if save_mask:
         mask_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -769,6 +924,17 @@ def _fa_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         # logsumexp residual for the backward pass (FlashAttention-2 style)
         lse = m_scr[...][:, :1] + jnp.log(l_scr[...][:, :1] + 1e-37)
         lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
+
+
+def _rows_on_lanes(block_q, dropout_pbits, save_mask):
+    """Whether a forward call takes _fa_kernel (q rows along the lanes)
+    or the older _fa_kernel_rows, from what the call sees at trace time:
+    the q block fills whole lane tiles, dropout, if any (`dropout_pbits`
+    None: none), draws 8-bit words, and no packed mask is stored.  A
+    call on the older body runs under the kernel name flash_fwd_rows, so
+    a trace counts them: the cells make none."""
+    return (block_q % _LANES == 0 and not save_mask
+            and dropout_pbits in (None, 8))
 
 
 def _seed_arg(dropout_seed):
@@ -958,21 +1124,33 @@ def _flash_fwd_call(q, k, v, dropout_seed, *, causal, sm_scale, block_q,
                                              nq, nk)
     walk = ({} if band else
             _causal_walk(q_len, k_len, block_q, block_k, causal, False))
-    kernel = functools.partial(
-        _fa_kernel, causal=causal, sm_scale=float(sm_scale),
-        block_q=block_q, block_k=block_k, num_k_blocks=nk,
-        dropout_rate=float(dropout_rate),
-        dropout_pbits=_effective_dropout_bits(block_k, dropout_bits),
-        save_mask=save_dropout_mask, interpret=interpret, walk=walk,
-        band=band)
+    steps = band.steps_k if band else nk
+    pbits = _effective_dropout_bits(block_k, dropout_bits)
+    common = dict(causal=causal, sm_scale=float(sm_scale), block_q=block_q,
+                  block_k=block_k, num_k_blocks=nk,
+                  dropout_rate=float(dropout_rate), interpret=interpret,
+                  walk=walk, band=band)
+    on_lanes = _rows_on_lanes(block_q, pbits if dropout_rate > 0.0 else None,
+                              save_dropout_mask)
+    if on_lanes:
+        kernel = functools.partial(_fa_kernel, **common)
+        # an inner extent of one step carries nothing between steps
+        scratch = [] if steps == 1 else [
+            pltpu.VMEM((1, block_q), jnp.float32),   # running max
+            pltpu.VMEM((1, block_q), jnp.float32),   # running sum
+            pltpu.VMEM((d, block_q), jnp.float32),   # output accumulator
+        ]
+    else:
+        kernel = functools.partial(_fa_kernel_rows, dropout_pbits=pbits,
+                                   save_mask=save_dropout_mask, **common)
+        scratch = [
+            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
+            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running sum
+            pltpu.VMEM((block_q, d), jnp.float32),       # output accumulator
+        ]
     kv_spec = _tile_spec(block_k, d, "j", group,
                          band.k_block if band else None)
 
-    scratch = [
-        pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
-        pltpu.VMEM((block_q, _LANES), jnp.float32),  # running sum
-        pltpu.VMEM((block_q, d), jnp.float32),       # output accumulator
-    ]
     out_specs = [
         _tile_spec(block_q, d, "i"),
         pl.BlockSpec((1, 1, block_q, _STATS_LANES),
@@ -999,13 +1177,14 @@ def _flash_fwd_call(q, k, v, dropout_seed, *, causal, sm_scale, block_q,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(batch, heads, nq, band.steps_k if band else nk),
+            grid=(batch, heads, nq, steps),
             in_specs=[_tile_spec(block_q, d, "i"), kv_spec, kv_spec],
             out_specs=out_specs,
             scratch_shapes=scratch),
         out_shape=out_shape,
         interpret=interpret,
-        name=_kernel_name("flash_fwd", band),
+        name=_kernel_name("flash_fwd" if on_lanes else "flash_fwd_rows",
+                          band),
         **params,
     )(seed, q, k, v)
     out, lse = res[0], res[1]

@@ -348,25 +348,22 @@ def test_fused_dequant_matmul_parity_tpu():
 
 def test_flash_dropout_mask_reuse_tpu(monkeypatch):
     """Mask-reuse mode (store bit-packed keep mask in fwd, read it in
-    both bwd kernels) must be BIT-IDENTICAL to the regen default: the
-    stored mask IS the regenerated mask, so outputs and grads cannot
-    differ.  Also pins that reuse engages (residual mask present) rather
-    than silently falling back to regen."""
+    both bwd kernels): the stored mask IS the regenerated mask, so from
+    one forward's out and lse the gradients are BIT-IDENTICAL to the
+    regen default's.  The storing forward is the kernel with q rows on
+    the sublanes (flash_fwd_rows), the regenerating one carries them on
+    the lanes: their outputs differ by the order of float32 additions
+    and bf16 rounding alone.  Also pins that reuse engages (residual
+    mask present) rather than silently falling back to regen."""
     import importlib
     fa_mod = importlib.import_module("deepspeed_tpu.ops.flash_attention")
-    from deepspeed_tpu.ops.flash_attention import flash_attention
-    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    from deepspeed_tpu.ops.flash_attention import flash_attention_bwd_pallas
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
     shape = (2, 4, 1024, 64)
-    q, k, v = (jax.random.normal(kk, shape, jnp.float32) for kk in ks)
+    q, k, v, do = (jax.random.normal(kk, shape, jnp.float32) for kk in ks)
     rate = 0.2
-
-    def loss(q_, k_, v_):
-        o = flash_attention(q_, k_, v_, causal=True, impl="pallas",
-                            dropout_rate=rate, dropout_seed=11)
-        return jnp.sum(o.astype(jnp.float32) ** 2), o
-
-    (_, o_regen), g_regen = jax.jit(jax.value_and_grad(
-        loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    kw = dict(causal=True, dropout_rate=rate, block_q=fa_mod.DEFAULT_BLOCK_Q,
+              block_k=fa_mod.DEFAULT_BLOCK_K)
 
     monkeypatch.setattr(fa_mod, "_dropout_reuse", True)
     # reuse path engages: the fwd residuals carry a packed mask
@@ -376,8 +373,70 @@ def test_flash_dropout_mask_reuse_tpu(monkeypatch):
     assert res[-1] is not None and res[-1].dtype == jnp.uint32
     assert res[-1].shape == (2, 4, 1024 // 32, 1024)
 
-    (_, o_reuse), g_reuse = jax.jit(jax.value_and_grad(
-        loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
-    np.testing.assert_array_equal(np.asarray(o_regen), np.asarray(o_reuse))
+    out, lse = flash_attention_pallas(q, k, v, return_lse=True,
+                                      dropout_seed=11, **kw)
+    out_s, lse_s, packed = flash_attention_pallas(
+        q, k, v, return_lse=True, dropout_seed=11, save_dropout_mask=True,
+        **kw)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(out_s),
+                               rtol=BF16_RTOL, atol=BF16_ATOL)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_s),
+                               rtol=1e-5, atol=1e-5)
+    g_regen = flash_attention_bwd_pallas(q, k, v, out, lse, do,
+                                         dropout_seed=11, **kw)
+    g_reuse = flash_attention_bwd_pallas(
+        q, k, v, out, lse, do, dropout_mask=packed,
+        dropout_mask_block_q=fa_mod.DEFAULT_BLOCK_Q, **kw)
     for a, b in zip(g_regen, g_reuse):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("seq,block_q,block_k", [
+    (1024, 512, 1024),     # the cells' call: one inner step, the sub-tiles
+    (2048, 512, 1024),     # two inner steps
+    (1024, 256, 256),      # words of half a lane tile, transposed
+])
+def test_flash_forward_and_backward_pair_see_one_mask_tpu(seq, block_q,
+                                                          block_k):
+    """The chip's PRNG, position for position: the forward kernel (q
+    rows along the lanes: it transposes the drawn words) and the two
+    backward kernels (rows on the sublanes, as drawn) of one call hold
+    the identical keep mask under the diagonal, each read back through
+    one-hot operands (tests/unit/test_flash_causal_bound.py), and keep
+    the share the threshold names."""
+    from tests.unit.test_flash_causal_bound import (_backward_keep_masks,
+                                                    _kernel_keep_mask)
+    heads, rate = 2, 0.1
+    read = dict(interpret=False, chunk=128, dtype=jnp.bfloat16)
+    under = np.tril(np.ones((seq, seq), bool))
+    forward = _kernel_keep_mask(heads, seq, block_q, block_k, rate, **read)
+    in_dkdv, in_dq = _backward_keep_masks(heads, seq, block_q, block_k,
+                                          rate, **read)
+    assert abs(forward[:, under].mean() - 230 / 256) < 0.005
+    for name, mask in (("flash_bwd_dkdv", in_dkdv), ("flash_bwd_dq", in_dq)):
+        np.testing.assert_array_equal(mask[:, under], forward[:, under],
+                                      err_msg=name)
+    assert not forward[:, ~under].any()
+
+
+@pytest.mark.parametrize("seq,heads,kv_heads,window,causal", [
+    (1024, 4, 4, None, False),    # one step, no mask
+    (2048, 4, 2, None, True),     # two steps, grouped heads
+    (2048, 4, 2, 512, True),      # the band
+])
+def test_flash_forward_lse_tpu(seq, heads, kv_heads, window, causal):
+    """The log-sum-exp the forward stores (parallel/sequence.py combines
+    shards by it; the backward pair exponentiates against it) is
+    m + log l to float32 rounding, through the [1, block_q] statistics
+    and their one transpose."""
+    from tests.unit.test_flash_causal_bound import _reference_lse
+    ks = jax.random.split(jax.random.PRNGKey(seq), 3)
+    q = jax.random.normal(ks[0], (1, heads, seq, 64), jnp.bfloat16)
+    k, v = (jax.random.normal(kk, (1, kv_heads, seq, 64), jnp.bfloat16)
+            for kk in ks[1:])
+    call = dict(window=window, block_q=512, block_k=512) if window else {}
+    _, lse = flash_attention_pallas(q, k, v, causal=causal, return_lse=True,
+                                    **call)
+    want = _reference_lse(q, k, causal=causal, window=window)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)

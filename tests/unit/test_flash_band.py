@@ -9,6 +9,8 @@ import jax.numpy as jnp
 import pytest
 
 from deepspeed_tpu.ops import dispatch
+from tests.unit.test_flash_causal_bound import (_forward_kernel,
+                                                _reference_lse)
 
 fa = importlib.import_module("deepspeed_tpu.ops.flash_attention")
 
@@ -47,6 +49,46 @@ def _compare(seq, heads, kv_heads, window, block_q, block_k, dim=64):
 def test_band_and_groups_match_the_masked_reference(
         interpreted, seq, heads, kv_heads, window, block_q, block_k):
     _compare(seq, heads, kv_heads, window, block_q, block_k)
+
+
+def _forward_kernel_name(seq, heads, kv_heads, **call):
+    return _forward_kernel(jnp.zeros((1, heads, seq, 64)),
+                           jnp.zeros((1, kv_heads, seq, 64)), causal=True,
+                           **call)
+
+
+@pytest.mark.parametrize("seq, heads, kv_heads, window, block_q, block_k", [
+    (512, 4, 2, 128, 128, 128),       # two inner steps of the band
+    (1024, 2, 1, 300, 256, 128),      # five: a window no block divides
+    (1024, 2, 2, 512, 512, 512),      # the cell's blocks and window
+    (2048, 4, 2, None, 512, 1024),    # grouped heads, two key blocks
+])
+def test_forward_with_rows_on_the_lanes_under_a_band_and_groups(
+        seq, heads, kv_heads, window, block_q, block_k):
+    """The banded and the grouped forward take the kernel that carries q
+    rows along the lanes, several inner steps with the running max and
+    sum in its [1, block_q] scratch: out and the log-sum-exp, position
+    for position, against the masked reference."""
+    call = dict(window=window, block_q=block_q, block_k=block_k)
+    assert _forward_kernel_name(seq=seq, heads=heads, kv_heads=kv_heads,
+                                **call) == (
+        "flash_fwd_band" if window else "flash_fwd")
+    ks = jax.random.split(jax.random.PRNGKey(seq + block_q), 3)
+    q = jax.random.normal(ks[0], (1, heads, seq, 64))
+    k, v = (jax.random.normal(key, (1, kv_heads, seq, 64)) for key in ks[1:])
+    out, lse = fa.flash_attention_pallas(q, k, v, causal=True,
+                                         interpret=True, return_lse=True,
+                                         **call)
+    want = fa.mha_reference(q, k, v, causal=True, window=window)
+    want_lse = _reference_lse(q, k, window=window)
+    assert float(jnp.max(jnp.abs(out - want))) <= 2e-5
+    assert float(jnp.max(jnp.abs(lse - want_lse))) <= 2e-5
+
+
+def test_a_band_on_half_a_lane_tile_of_rows_keeps_the_older_forward():
+    assert _forward_kernel_name(seq=512, heads=4, kv_heads=2, window=128,
+                                block_q=64, block_k=256) == (
+        "flash_fwd_rows_band")
 
 
 def test_the_band_visits_its_tiles_only():

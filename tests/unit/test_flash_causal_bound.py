@@ -41,25 +41,62 @@ def _inputs(heads, seq, d, seed=0):
                  for k in ks)
 
 
-def _kernel_keep_mask(heads, seq, block_q, block_k, rate):
+def _kernel_keep_mask(heads, seq, block_q, block_k, rate, interpret=True,
+                      chunk=256, dtype=jnp.float32):
     """[heads, seq, seq] bool: the keep mask the forward kernel draws
     (meaningful under the diagonal).  With q = k = 0 a causal row i puts
     1/(i+1) on each of its columns 0..i, so against one-hot values a kept
     position reads back positive and a dropped one reads 0.  The mask is
     a function of the seed and the coordinates alone, not of q, k, v or
     the head size."""
-    chunk = 256
-    zeros = jnp.zeros((1, heads, seq, chunk), jnp.float32)
+    zeros = jnp.zeros((1, heads, seq, chunk), dtype)
     keep = np.zeros((heads, seq, seq), bool)
     for c in range(seq // chunk):
         v = np.zeros((1, heads, seq, chunk), np.float32)
         v[:, :, c * chunk + np.arange(chunk), np.arange(chunk)] = 1.0
         out = fa.flash_attention_pallas(
-            zeros, zeros, jnp.asarray(v), causal=True, block_q=block_q,
-            block_k=block_k, interpret=True, dropout_rate=rate,
-            dropout_seed=SEED)
-        keep[:, :, c * chunk:(c + 1) * chunk] = np.asarray(out[0]) > 0
+            zeros, zeros, jnp.asarray(v, dtype), causal=True,
+            block_q=block_q, block_k=block_k, interpret=interpret,
+            dropout_rate=rate, dropout_seed=SEED)
+        keep[:, :, c * chunk:(c + 1) * chunk] = np.asarray(
+            out[0], np.float32) > 0
     return keep
+
+
+def _backward_keep_masks(heads, seq, block_q, block_k, rate, interpret=True,
+                         chunk=256, dtype=jnp.float32):
+    """([heads, seq, seq] bool) x 2: the keep masks the dkdv and the dq
+    kernel regenerate, read back like the forward's.  With q = 0 the
+    scores are 0 whatever k is, and with lse = log(i + 1) row i's
+    probabilities are 1/(i+1) under the diagonal.  dv = P_dropped^T dO:
+    against one-hot dO rows, dv[c, x] is positive where (r0 + x, c) was
+    kept.  dq = dS k with dS = P (keep / (1 - rate) dP - delta): with
+    out = 0 (delta 0), dO . v^T = 1 everywhere and one-hot k rows,
+    dq[r, x] is positive where (r, c0 + x) was kept."""
+    shape = (1, heads, seq, chunk)
+    zeros = jnp.zeros(shape, dtype)
+    lse = jnp.broadcast_to(jnp.log(jnp.arange(1, seq + 1, dtype=jnp.float32)),
+                           shape[:3])
+    first = np.zeros(shape, np.float32)
+    first[..., 0] = 1.0
+    first = jnp.asarray(first, dtype)
+    kw = dict(causal=True, block_q=block_q, block_k=block_k,
+              interpret=interpret, dropout_rate=rate, dropout_seed=SEED)
+    in_dkdv = np.zeros((heads, seq, seq), bool)
+    in_dq = np.zeros((heads, seq, seq), bool)
+    for c in range(seq // chunk):
+        hot = np.zeros(shape, np.float32)
+        hot[:, :, c * chunk + np.arange(chunk), np.arange(chunk)] = 1.0
+        hot = jnp.asarray(hot, dtype)
+        _, _, dv = fa.flash_attention_bwd_pallas(
+            zeros, zeros, zeros, zeros, lse, hot, **kw)
+        in_dkdv[:, c * chunk:(c + 1) * chunk, :] = np.asarray(
+            dv[0], np.float32).transpose(0, 2, 1) > 0
+        dq, _, _ = fa.flash_attention_bwd_pallas(
+            zeros, hot, first, zeros, lse, first, **kw)
+        in_dq[:, :, c * chunk:(c + 1) * chunk] = np.asarray(
+            dq[0], np.float32) > 0
+    return in_dkdv, in_dq
 
 
 def _reference(keep, inv):
@@ -143,8 +180,12 @@ def test_causal_forward_and_gradients_match_reference(
 def test_mask_reuse_keeps_working_under_the_bound(seq):
     """The stored-mask mode (off by default) under the bound: the forward
     stores the sub-tiles' keep bits it computed, the backward kernels read
-    them back, and outputs and gradients are bit for bit what the
-    regenerating mode gives (the stored mask IS the regenerated one)."""
+    them back, and the gradients are bit for bit what the regenerating
+    mode gives (the stored mask IS the regenerated one).  The storing
+    forward is the kernel with q rows on the sublanes (flash_fwd_rows:
+    the packed mask rides them), the regenerating one has them on the
+    lanes: the same mask, and outputs that differ by the order of the
+    float32 additions alone."""
     bq, bk, rate = 512, 1024, 0.2
     q, k, v, do = _inputs(2, seq, 32)
     kw = dict(causal=True, block_q=bq, block_k=bk, interpret=True,
@@ -154,8 +195,20 @@ def test_mask_reuse_keeps_working_under_the_bound(seq):
     out_s, lse_s, packed = fa.flash_attention_pallas(
         q, k, v, return_lse=True, dropout_seed=SEED, save_dropout_mask=True,
         **kw)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(out_s))
-    np.testing.assert_array_equal(np.asarray(lse), np.asarray(lse_s))
+    assert _forward_kernel(q, dropout_seed=SEED, **kw) == "flash_fwd"
+    assert _forward_kernel(q, dropout_seed=SEED, save_dropout_mask=True,
+                           return_lse=True, **kw) == "flash_fwd_rows"
+    np.testing.assert_allclose(np.asarray(out), np.asarray(out_s),
+                               rtol=0, atol=2e-6)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_s),
+                               rtol=0, atol=2e-6)
+    # a dropped position is dropped in both: the same zeros
+    v_hot = jnp.zeros_like(v).at[:, :, jnp.arange(32), jnp.arange(32)].set(1.)
+    hot, hot_s = (fa.flash_attention_pallas(
+        jnp.zeros_like(q), jnp.zeros_like(k), v_hot, dropout_seed=SEED,
+        save_dropout_mask=save, return_lse=True, **kw)[0]
+        for save in (False, True))
+    np.testing.assert_array_equal(np.asarray(hot) > 0, np.asarray(hot_s) > 0)
     regen = fa.flash_attention_bwd_pallas(q, k, v, out, lse, do,
                                           dropout_seed=SEED, **kw)
     reuse = fa.flash_attention_bwd_pallas(
@@ -163,6 +216,140 @@ def test_mask_reuse_keeps_working_under_the_bound(seq):
         dropout_mask_block_q=bq, **kw)
     for a, b in zip(regen, reuse):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# --------------------------------------------------------------------------- #
+# the forward kernel with q rows along the lanes
+# --------------------------------------------------------------------------- #
+def _forward_kernel(q, kv=None, **call):
+    """The name of the one kernel a forward call traces, on q and on
+    keys and values shaped like `kv` (None: like q)."""
+    from deepspeed_tpu.analysis.jaxpr_walk import iter_eqns
+    kv = q if kv is None else kv
+    jaxpr = jax.make_jaxpr(lambda x, y: fa.flash_attention_pallas(
+        x, y, y, **call))(q, kv)
+    name, = [ctx.eqn.params["name"] for ctx in iter_eqns(jaxpr.jaxpr)
+             if ctx.eqn.primitive.name == "pallas_call"]
+    return name
+
+
+def _stream_keep_mask(heads, seq, block_q, block_k, rate):
+    """[heads, seq, seq] bool: the keep mask as the dropout stream
+    DEFINES it (_dropout_keep's docstring; the interpreter's stand-in for
+    the chip's PRNG), computed here with no kernel: tile (qi, unit kj)
+    of head h draws [block_q, unit / 4] words from (seed + h, qi * units
+    + kj + seed * 2654435761), and byte j of word w decides column
+    j * unit / 4 + w.  Whatever a kernel's orientation, it must apply
+    exactly this."""
+    _, bq, bk = fa._resolve_blocks(seq, seq, block_q, block_k)
+    unit = fa._causal_sub_tile(bq, bk, True) or bk
+    units = seq // unit
+    t8 = fa._quantized_threshold(rate, 8)
+    keep = np.zeros((heads, seq, seq), bool)
+    seed = jnp.int32(SEED)
+    for h in range(heads):
+        for qi in range(seq // bq):
+            for kj in range(units):
+                words = np.asarray(fa._interpret_random_bits(
+                    seed + h, qi * units + kj + seed * np.int32(-1640531527),
+                    (bq, unit // 4)))
+                planes = [(words >> np.uint32(8 * j)) & np.uint32(0xFF)
+                          for j in range(4)]
+                keep[h, qi * bq:(qi + 1) * bq, kj * unit:(kj + 1) * unit] = (
+                    np.concatenate(planes, axis=1) < t8)
+    return keep
+
+
+def _reference_lse(q, k, causal=True, window=None):
+    """[B, H, S] log-sum-exp of the scaled scores a query sees: all keys,
+    or `causal` those up to its own, the last `window` of them; k may
+    have fewer heads than q."""
+    k = jnp.repeat(k, q.shape[1] // k.shape[1], axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                   k.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST) / np.sqrt(q.shape[-1])
+    row = jnp.arange(s.shape[-2])[:, None]
+    col = jnp.arange(s.shape[-1])[None, :]
+    if causal:
+        seen = (col <= row) & (col > row - (window or s.shape[-1]))
+        s = jnp.where(seen, s, -jnp.inf)
+    return jax.scipy.special.logsumexp(s, axis=-1)
+
+
+# one inner step (512 and 1024: a key block spans the row) and several
+# (2048), at q blocks of one to eight lane tiles
+ON_LANES = [(seq, block_q, rate) for seq in (512, 1024, 2048)
+            for block_q in (128, 256, 512, 1024) for rate in (0.0, 0.1)]
+
+
+@pytest.mark.parametrize("seq,block_q,rate", ON_LANES)
+def test_forward_with_rows_on_the_lanes_position_for_position(
+        seq, block_q, rate):
+    """out, the log-sum-exp and the applied keep mask of the forward
+    kernel that carries q rows along the lanes, against the reference
+    under the mask the dropout stream defines: the transposed kernel
+    drops exactly the positions the stream names, so the backward pair,
+    which regenerates the stream untransposed, sees the same mask."""
+    heads, d, block_k = 1, 32, 1024
+    q, k, v, _ = _inputs(heads, seq, d, seed=seq + block_q)
+    kw = dict(causal=True, block_q=block_q, block_k=block_k,
+              dropout_rate=rate, dropout_seed=SEED)
+    assert _forward_kernel(q, **kw) == "flash_fwd"
+    keep, inv = None, 1.0
+    if rate:
+        keep = _stream_keep_mask(heads, seq, block_q, block_k, rate)
+        inv = fa._keep_scale(rate, 8)
+        applied = _kernel_keep_mask(heads, seq, block_q, block_k, rate)
+        under = np.tril(np.ones((seq, seq), bool))
+        np.testing.assert_array_equal(applied[:, under], keep[:, under])
+        assert not applied[:, ~under].any()
+    out, lse = fa.flash_attention_pallas(q, k, v, interpret=True,
+                                         return_lse=True, **kw)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_reference(keep, inv)(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse),
+                               np.asarray(_reference_lse(q, k)),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_forward_and_backward_pair_see_one_mask():
+    """One call's three kernels, each read back position for position:
+    the forward (q rows on the lanes, the words transposed) and the two
+    backward kernels (rows on the sublanes, as drawn) hold the identical
+    keep mask under the diagonal, which is the stream's."""
+    heads, seq, block_q, block_k, rate = 2, 1024, 512, 1024, 0.1
+    under = np.tril(np.ones((seq, seq), bool))
+    forward = _kernel_keep_mask(heads, seq, block_q, block_k, rate)
+    in_dkdv, in_dq = _backward_keep_masks(heads, seq, block_q, block_k, rate)
+    stream = _stream_keep_mask(heads, seq, block_q, block_k, rate)
+    for name, mask in (("flash_fwd", forward), ("flash_bwd_dkdv", in_dkdv),
+                       ("flash_bwd_dq", in_dq)):
+        np.testing.assert_array_equal(mask[:, under], stream[:, under],
+                                      err_msg=name)
+        assert not mask[:, ~under].any(), name
+
+
+@pytest.mark.parametrize("call", [
+    dict(dropout_rate=0.1, dropout_seed=SEED, save_dropout_mask=True,
+         return_lse=True),                      # the packed mask
+    dict(dropout_rate=0.1, dropout_seed=SEED, dropout_bits=32),
+    dict(block_q=64),                           # half a lane tile of rows
+], ids=["stored_mask", "32_bit_draws", "block_q_64"])
+def test_calls_the_new_body_cannot_serve_keep_the_older_one(call):
+    """... under a kernel name of its own that still begins flash_fwd,
+    so that a trace counts them (and the benchmark's flash_ms, a search
+    for flash_fwd, still sees them)."""
+    q = jnp.zeros((1, 2, 1024, 32), jnp.float32)
+    bits = call.pop("dropout_bits", 8)
+    was = fa.dropout_bits()
+    fa.set_dropout_bits(bits)
+    try:
+        name = _forward_kernel(q, causal=True, **call)
+    finally:
+        fa.set_dropout_bits(was)
+    assert name == "flash_fwd_rows"
+    assert re.search("flash_fwd", name)
 
 
 # --------------------------------------------------------------------------- #
@@ -280,7 +467,14 @@ def test_whole_tile_calls_lower_as_before_the_bound(name):
     """A non-causal call, and a causal call whose key block the sub-tile
     does not divide, give the Mosaic modules the kernels gave before the
     bound existed: golden/flash_whole_tile_modules.json was recorded by
-    this same function from the commit before it (9885b32)."""
+    this same function from the commit before it (9885b32).
+
+    That holds for the backward pair (entries 1 and 2, untouched since).
+    The forward kernel (entry 0) was rewritten by PR 35, q rows along the
+    lanes, for these calls as for every other, and its entries were
+    recorded again from that PR's tree: what they guard now is that a
+    later change to the causal bound or to the backward kernels leaves
+    the forward of a whole-tile call alone."""
     with open(GOLDEN) as f:
         recorded = json.load(f)
     assert whole_tile_digests(name) == recorded[name]

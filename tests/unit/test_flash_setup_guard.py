@@ -14,7 +14,8 @@ measure: each banded kernel, at the shapes of `phi4-mini-flash.s8k`,
 compiles and lowers to a module at most twice its plain causal one's;
 and a GPT-2 call (no window, equal head counts) still traces the three
 kernels it traced before them, body for body, so that they cost the
-GPT-2 cells no set-up.
+GPT-2 cells no set-up.  (PR 35 replaced the forward kernel's body by a
+smaller one; the pin says which.)
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported (see tests/perf/test_aot_kernels.py).
@@ -139,12 +140,19 @@ def test_grouped_causal_kernels_compile_at_the_cells_shape(one_chip):
         lowered.compile()
 
 
-# The three kernels of a GPT-2 large / xl call at the parent of PR 34
-# (commit 0275698): equations of each kernel's body, sub-jaxprs in, and
-# the grid's two sequence dimensions.  (A lowered module's bytes carry
-# its source locations and the caller's stack, so they move with any
-# edit of the file; the traced body does not.)
-PARENT_KERNELS = {"flash_fwd": (246, (2, 1)), "flash_bwd_dkdv": (342, (1, 2)),
+# The three kernels of a GPT-2 large / xl call: equations of each
+# kernel's body, sub-jaxprs in, and the grid's two sequence dimensions.
+# (A lowered module's bytes carry its source locations and the caller's
+# stack, so they move with any edit of the file; the traced body does
+# not.)  The backward pair's counts are those of the parent of PR 34
+# (commit 0275698), which added the window and the grouped heads beside
+# them, and PR 35 left them: it rewrote the forward kernel alone (q rows
+# along the lanes: 207 equations for the 246 of the body before it, the
+# smaller body at every start), and the forward's count is that PR's.
+# What it guards now: that a GPT-2 call takes the new body, under the
+# name flash_fwd, in one step and two bodies, and that nothing grows it
+# unseen.
+PARENT_KERNELS = {"flash_fwd": (207, (2, 1)), "flash_bwd_dkdv": (342, (1, 2)),
                   "flash_bwd_dq": (297, (2, 1))}
 
 
