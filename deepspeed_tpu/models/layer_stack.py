@@ -14,6 +14,7 @@ SCAN_LAYERS_AUTO_THRESHOLD layers and scans beyond.
 """
 
 import jax
+import jax.numpy as jnp
 
 SCAN_LAYERS_AUTO_THRESHOLD = 24
 
@@ -25,12 +26,17 @@ def resolve_use_scan(scan_layers, num_layers: int) -> bool:
     return num_layers > SCAN_LAYERS_AUTO_THRESHOLD
 
 
-def run_layer_stack(body, carry, xs, use_scan: bool):
-    """Run `body(carry, xs_i) -> (carry, _)` over the leading axis of xs."""
+def run_layer_stack(body, carry, xs, use_scan: bool, with_ys: bool = False):
+    """Run `body(carry, xs_i) -> (carry, y_i)` over the leading axis of xs;
+    returns the carry, or with `with_ys` (carry, the y_i stacked)."""
     if use_scan:
-        carry, _ = jax.lax.scan(body, carry, xs)
-        return carry
+        carry, ys = jax.lax.scan(body, carry, xs)
+        return (carry, ys) if with_ys else carry
     n = jax.tree.leaves(xs)[0].shape[0]
+    ys = []
     for i in range(n):
-        carry, _ = body(carry, jax.tree.map(lambda a: a[i], xs))
-    return carry
+        carry, y = body(carry, jax.tree.map(lambda a: a[i], xs))
+        ys.append(y)
+    if not with_ys:
+        return carry
+    return carry, jax.tree.map(lambda *a: jnp.stack(a), *ys)

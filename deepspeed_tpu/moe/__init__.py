@@ -1,4 +1,5 @@
-from .experts import ExpertMLP
+from .dropless import DroplessMoE, Routing, route_topk
+from .experts import ExpertMLP, GatedExpertMLP
 from .layer import MoE
 from .sharded_moe import (MOELayer, RoutingStats, TopKGate,
                           collect_routing_stats, emit_routing_stats,

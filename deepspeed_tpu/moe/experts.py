@@ -62,3 +62,41 @@ class ExpertMLP:
         from jax.sharding import PartitionSpec as P
         return {"wi": P(None, model_axis), "bi": P(model_axis),
                 "wo": P(model_axis, None), "bo": P()}
+
+
+class GatedExpertMLP:
+    """A gated expert, ``(silu(x Wg) * (x Wu)) Wd`` without bias, the
+    gate and the up projection in one matrix ``w1 [d, 2 ff]`` (gate
+    first).  ``apply`` is one expert on its rows; ``apply_grouped`` is a
+    stack of experts ``[G, ...]`` on rows sorted by expert, through the
+    grouped product (ops/grouped_matmul.py): no slot buffer, no capacity."""
+
+    def __init__(self, d_model: int, d_ff: int, init_std: float = 0.02):
+        self.d_model = d_model
+        self.d_ff = d_ff
+        self.init_std = init_std
+
+    def init_params(self, rng, x=None):
+        k1, k2 = jax.random.split(rng)
+        return {
+            "w1": self.init_std * jax.random.normal(
+                k1, (self.d_model, 2 * self.d_ff), jnp.float32),
+            "w2": self.init_std * jax.random.normal(
+                k2, (self.d_ff, self.d_model), jnp.float32),
+        }
+
+    @staticmethod
+    def _gated(h):
+        gate, up = jnp.split(h, 2, axis=-1)
+        return up * jax.nn.silu(gate)
+
+    def apply(self, params, x, rng=None):
+        h = x @ params["w1"].astype(x.dtype)
+        return self._gated(h) @ params["w2"].astype(x.dtype)
+
+    def apply_grouped(self, params, rows, counts):
+        """rows [R, d] sorted by expert, counts [G] rows an expert; zero
+        past their sum."""
+        from ..ops.grouped_matmul import gmm
+        h = gmm(rows, params["w1"], counts)
+        return gmm(self._gated(h), params["w2"], counts)

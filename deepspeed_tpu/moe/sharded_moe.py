@@ -65,16 +65,21 @@ class RoutingStats(NamedTuple):
     #                               confidence
     l_aux: jnp.ndarray            # f32[] summed load-balance loss
     layers: jnp.ndarray           # f32[] gate invocations folded in
+    held_rows_max: jnp.ndarray    # f32[] summed over gate invocations:
+    #                               rows of the busiest expert among those
+    #                               THIS program holds (all of them unless
+    #                               the layer was given a held range)
 
 
 def _routing_stats(gates, wanted_counts, routed_counts, topk_mass,
-                   l_aux, used_token=None) -> RoutingStats:
+                   l_aux, used_token=None, held=None) -> RoutingStats:
     """Assemble one gate invocation's RoutingStats.
 
     ``wanted_counts``/``routed_counts``: [E] pre-/post-capacity-mask
     token-slot counts; ``topk_mass``: [S] raw gate probability mass on
     the selected (pre-capacity) experts; ``used_token``: optional [S]
-    validity mask (padding tokens contribute nothing)."""
+    validity mask (padding tokens contribute nothing); ``held``:
+    optional static (first, count) of the experts this program holds."""
     ent = -jnp.sum(gates * jnp.log(jnp.clip(gates, _ENTROPY_EPS, 1.0)),
                    axis=-1)
     if used_token is not None:
@@ -86,6 +91,7 @@ def _routing_stats(gates, wanted_counts, routed_counts, topk_mass,
         gate_tokens = jnp.float32(gates.shape[0])
     wanted = wanted_counts.astype(jnp.float32)
     routed = routed_counts.astype(jnp.float32)
+    first, count = held if held is not None else (0, routed.shape[0])
     return RoutingStats(
         expert_counts=routed,
         overflow_counts=wanted - routed,
@@ -95,7 +101,8 @@ def _routing_stats(gates, wanted_counts, routed_counts, topk_mass,
         confidence=topk_mass.sum().astype(jnp.float32),
         gate_tokens=gate_tokens,
         l_aux=l_aux.astype(jnp.float32),
-        layers=jnp.float32(1.0))
+        layers=jnp.float32(1.0),
+        held_rows_max=routed[first:first + count].max())
 
 
 # ---- routing-stats collection tap ------------------------------------ #

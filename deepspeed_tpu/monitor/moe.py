@@ -103,6 +103,15 @@ def summarize_window(raw: Dict[str, Any]) -> Optional[Dict[str, Any]]:
                                    if fair > 0 else None)
     else:
         summary[R.M_LOCAL_LOAD] = None
+    held_max = raw.get("held_rows_max")
+    if held_max is not None and sl is not None and routed > 0:
+        lo, hi = int(sl[0]), int(sl[1])
+        held_rows = counts[lo:hi].sum()
+        summary[R.M_HELD_RANGE] = [lo, hi]
+        summary[R.M_HELD_ROWS_MAX] = round(_f(held_max) / layers, 2)
+        summary[R.M_HELD_ROWS_MEAN] = round(
+            float(held_rows / (hi - lo) / layers), 2)
+        summary[R.M_HELD_PICK_SHARE] = round(float(held_rows / tokens), 6)
     return summary
 
 

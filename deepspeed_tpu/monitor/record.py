@@ -111,6 +111,9 @@ M_STACK_LAYERS = "stack_layers"      # [[published index, kind, window|0]]
 M_STACK_SCAN_CHUNK = "stack_scan_chunk"           # positions a chunk
 M_STACK_SCAN_ENTRY_BYTES = "stack_scan_entry_state_bytes"  # a scan call
 M_STACK_CROSS_LAYER_KEPT = "stack_cross_layer_kept"  # [[name, bytes]]
+# (models/laguna.py) the routed experts this program holds of those the
+# router scores: [first, count, of]
+M_STACK_EXPERTS_HELD = "stack_experts_held"
 
 # ---- fleet field names (fleet.py / health.py payloads) --------------- #
 FL_WINDOW_START = "window_start_step"
@@ -163,6 +166,12 @@ M_ENTROPY = "router_entropy"       # normalized [0, 1] (1 = uniform)
 M_CONFIDENCE = "router_confidence"  # mean raw top-k gate mass per token
 M_LAUX = "l_aux_mean"              # per gate invocation
 M_LOCAL_LOAD = "local_expert_load"  # this host's load vs fair share
+# a layer that holds a range of the experts its router scores
+# (moe/dropless.py), per sparse layer and optimizer step:
+M_HELD_RANGE = "held_experts"          # [first, past the last]
+M_HELD_ROWS_MAX = "held_rows_max"      # rows of the busiest held expert
+M_HELD_ROWS_MEAN = "held_rows_mean"    # rows a held expert, on average
+M_HELD_PICK_SHARE = "held_pick_share"  # of the k x tokens picks: landed here
 M_POPULARITY = "popularity"        # embedded ExpertPopularitySnapshot
 
 # ---- reconciliation field names (reconcile.py payload) --------------- #

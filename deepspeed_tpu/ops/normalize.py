@@ -207,3 +207,12 @@ def fused_layer_norm(x, gamma, beta, eps: float = 1e-5):
     DS_LN_IMPL=pallas / dispatch.set_ln_impl("pallas") selects the
     Pallas kernels."""
     return _fused_ln(x, gamma, beta, eps)
+
+
+def rms_norm(x, gamma, eps: float = 1e-6):
+    """RMSNorm over the last dim with fp32 statistics: ``x / sqrt(mean(x^2)
+    + eps) * gamma``, in x's dtype.  Plain jnp: XLA fuses it into the
+    neighbouring element-wise work, as it does the default LayerNorm."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * gamma.astype(jnp.float32)).astype(x.dtype)

@@ -10,6 +10,10 @@ rematted_computation/mlp/dot_general" ...}`` on every instruction.
   scope  the innermost of the model's named scopes on the ``op_name``:
          ``embed``, ``attn``, ``ssm`` (a Mamba mixer: projections, conv,
          selective scan, gate), ``gmu`` (a gated-memory unit), ``mlp``,
+         the four of a sparse FFN (moe/dropless.py): ``router`` (scores,
+         top-k, counts), ``dispatch`` (the sorts, the gather of rows and
+         the weighted gather back), ``experts`` (the grouped products
+         and the gate between them), ``shared`` (the shared expert);
          ``head``; ``layer`` for what is in a layer and in no mixer's
          scope nor ``mlp``; ``other`` where no
          scope is named or the instruction has no metadata.  A scope can
@@ -31,7 +35,8 @@ nothing is lowered until it is called.
 import re
 import weakref
 
-SCOPES = ("embed", "attn", "ssm", "gmu", "mlp", "head", "layer")
+SCOPES = ("embed", "attn", "ssm", "gmu", "mlp", "router", "dispatch",
+          "experts", "shared", "head", "layer")
 OTHER = "other"
 PHASES = ("forward", "recompute", "backward")
 

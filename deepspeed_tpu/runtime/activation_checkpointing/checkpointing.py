@@ -142,6 +142,18 @@ def get_partition_policy():
 # 2.2 ms SLOWER for the QKV output's 1.13, with one long step in a run in
 # three (PERF.md section 6, PR 33; ROADMAP S2 has what is left of them).
 RESIDUAL_ORDER = (FLASH_RESIDUALS_NAME,)
+# Names kept wherever a layer offers them, with a budget, with none that
+# fits and with none at all (the CPU, a streamed ZeRO-3, a device that
+# reports no limit): a few integers a token, and what a recomputation
+# could not be trusted to find again (moe/dropless.py: a top-k recomputed
+# in other fusions may flip a near tie, and the backward pass must
+# differentiate the forward's picks).  A body that offers none of them
+# lowers to what ``jax.checkpoint(body)`` lowers to
+# (tests/unit/test_remat_policy.py).
+ALWAYS_KEPT = ("routing_picks",)
+_KEEP_ALWAYS = functools.partial(
+    jax.checkpoint,
+    policy=jax.checkpoint_policies.save_only_these_names(*ALWAYS_KEPT))
 
 # The allowance for what a device holds in a step beside the engine's
 # state and the saved residuals, in three terms (working_set_bytes).
@@ -303,31 +315,41 @@ def checkpoint_layer(body: Callable, budget: Optional[RematBudget],
 
 def stack_plan_line(plan: Dict[str, Any]) -> str:
     """The log line of a stack of unlike layers (the M_STACK_* fields)."""
-    return (
-        "layer stack: " + ", ".join(
-            f"{i}:{kind}" + (f"(window {w})" if w else "")
-            for i, kind, w in plan[R.M_STACK_LAYERS])
-        + f"; selective scan in chunks of {plan[R.M_STACK_SCAN_CHUNK]}, "
-        f"{plan[R.M_STACK_SCAN_ENTRY_BYTES]:,} B of entry states a call; "
-        "kept across layers: " + ", ".join(
-            f"{name} {size:,} B"
-            for name, size in plan[R.M_STACK_CROSS_LAYER_KEPT]))
+    line = "layer stack: " + ", ".join(
+        f"{i}:{kind}" + (f"(window {w})" if w else "")
+        for i, kind, w in plan[R.M_STACK_LAYERS])
+    if R.M_STACK_SCAN_CHUNK in plan:
+        line += (
+            f"; selective scan in chunks of {plan[R.M_STACK_SCAN_CHUNK]}, "
+            f"{plan[R.M_STACK_SCAN_ENTRY_BYTES]:,} B of entry states a call; "
+            "kept across layers: " + ", ".join(
+                f"{name} {size:,} B"
+                for name, size in plan[R.M_STACK_CROSS_LAYER_KEPT]))
+    if R.M_STACK_EXPERTS_HELD in plan:
+        first, count, of = plan[R.M_STACK_EXPERTS_HELD]
+        line += (f"; routed experts {first} to {first + count - 1} of {of} "
+                 "held here")
+    return line
 
 
 def checkpoint_layers(groups, budget: Optional[RematBudget], carry,
                       head_width: int,
-                      stack_plan: Optional[Dict[str, Any]] = None):
+                      stack_plan: Optional[Dict[str, Any]] = None,
+                      extra_working_set: int = 0):
     """``checkpoint_layer`` for a model whose stack is several scanned
     groups of unlike layers: ``groups`` is a list of ``(body,
     stacked_xs)``, every body taking the same ``carry``; one budget is
     spent over all of them (a name is kept in every group or in none) and
     one plan is noted, ``stack_plan`` (the model's M_STACK_* fields)
-    riding on it.  Returns the wrapper for every body of the stack: what
+    riding on it; ``extra_working_set``: bytes a layer of this model
+    holds that ``working_set_bytes`` does not know of (a sparse layer's
+    rows, sized for the worst case).  Returns the wrapper for every body
+    of the stack: what
     a wrapped body closes over (another layer's output that this one
     reads; the plan may be made on a stand-in of its shape) is an input
     of the checkpointed body: kept, never recomputed."""
     if budget is None or budget.bytes_limit is None:
-        return jax.checkpoint
+        return _KEEP_ALWAYS
     shape = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), carry)
     steps = [jax.tree.leaves(xs)[0].shape[0] for _, xs in groups]
@@ -345,6 +367,7 @@ def checkpoint_layers(groups, budget: Optional[RematBudget], carry,
             math.prod(carry.shape[:-1]) // budget.batch_shards,
             carry.shape[-1], num_layers, head_width, carry.dtype.itemsize,
             budget.cast_bytes)
+    working_set += extra_working_set
     kept = saved_residual_names(offered, 1, budget.bytes(working_set))
     total = sum(offered[name] for name in kept)
     budget.note_plan({
@@ -359,9 +382,11 @@ def checkpoint_layers(groups, budget: Optional[RematBudget], carry,
         R.M_REMAT_STATE_BYTES: budget.state_bytes,
         R.M_REMAT_WORKING_SET_BYTES: working_set,
         **(stack_plan or {})})
-    return functools.partial(jax.checkpoint, policy=(
-        jax.checkpoint_policies.save_only_these_names(*kept) if kept
-        else None))
+    if not kept:
+        return _KEEP_ALWAYS
+    return functools.partial(
+        jax.checkpoint, policy=jax.checkpoint_policies.save_only_these_names(
+            *kept, *ALWAYS_KEPT))
 
 
 def checkpoint(function: Callable, *args) -> Any:

@@ -1980,6 +1980,12 @@ class DeepSpeedEngine:
         when the process's expert coordinate cannot be resolved."""
         from ..parallel.mesh import EXPERT_AXIS
         ep = self.mesh_ctx.axis_size(EXPERT_AXIS)
+        held = getattr(self.module, "experts_held", None)
+        if ep <= 1 and callable(held):
+            # a model told which experts it holds (one rank's share of a
+            # larger expert-parallel layout): (first, count) -> (lo, hi)
+            first, count = held()
+            return (first, first + count)
         if ep <= 1 or num_experts % ep != 0 or jax.process_count() <= 1:
             return (0, num_experts)
         per = num_experts // ep

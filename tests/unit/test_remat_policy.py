@@ -471,3 +471,36 @@ def test_carried_stream_takes_no_names_policy():
     assert budget.plan is None
     assert _weighted_prim_count(kept, "tanh") == 2 * num_layers
     assert str(kept) == str(plain)
+
+
+@pytest.mark.parametrize("limit", [None, 1, EVERYTHING])
+def test_a_stack_without_picks_lowers_as_it_did(limit):
+    """Every policy ``checkpoint_layers`` hands out also names
+    ``ALWAYS_KEPT`` (a router's picks), with a budget, with one that
+    admits nothing and with none.  A stack that offers no such name (all
+    but the mixture-of-experts models) lowers to the text it lowered to
+    under the policy without it: plain ``jax.checkpoint`` with no budget
+    or nothing kept, ``save_only_these_names`` of what was kept."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    def body(carry, w):
+        h = checkpoint_name(jnp.tanh(carry @ w), FLASH)
+        return carry + jnp.sin(h) @ w.T, None
+
+    carry = jnp.ones((4, 8, 16), jnp.float32)
+    ws = jnp.full((3, 16, 16), 0.1, jnp.float32)
+    budget = None if limit is None else budget_of(limit)
+    wrap = ck.checkpoint_layers([(body, ws)], budget, carry, head_width=8)
+    kept = () if budget is None else budget.plan[R.M_REMAT_KEPT]
+    assert kept == ((FLASH,) if limit == EVERYTHING else ())
+    before = jax.checkpoint if not kept else (lambda f: jax.checkpoint(
+        f, policy=jax.checkpoint_policies.save_only_these_names(*kept)))
+
+    def text(wrapper):
+        def loss(carry, ws):
+            out, _ = jax.lax.scan(wrapper(body), carry, ws)
+            return jnp.sum(out)
+        return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            carry, ws).as_text()
+
+    assert text(wrap) == text(before)
