@@ -346,13 +346,15 @@ class LagunaModel:
 
             groups.append((body, (params[name], forced), sparse, count))
 
-        plan = self.stack_plan()
+        tokens = h.shape[0] * h.shape[1]
+        plan = {**self.stack_plan(),
+                R.M_STACK_DISPATCH_ROWS: self.moe.capacity(tokens)}
         if cfg.activation_checkpointing:
             wrap = checkpoint_layers(
                 [(body, xs) for body, xs, _, _ in groups],
                 self._remat_budget, h, cfg.vocab_size, plan,
                 extra_working_set=self.moe.working_set_bytes(
-                    h.shape[0] * h.shape[1], h.dtype.itemsize))
+                    tokens, h.dtype.itemsize))
         else:
             def wrap(body):
                 return body

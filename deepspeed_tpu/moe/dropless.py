@@ -12,6 +12,13 @@ normalisation.  The held range comes from ``experts_held`` (first,
 count); with a mesh whose expert axis is larger than one it would be the
 rank's share (not written yet: ROADMAP R1), so such a mesh is refused.
 
+The row buffers hold the held experts' even share of the picks
+(``dispatch_capacity``), not the ``tokens x k`` rows of the worst case:
+the routed rows are walked in chunks of that many, as many as they take
+(a trip count made on the device: none where no pick landed here, one
+under an even router, ``num_experts / count`` where every pick did), so
+that nothing is dropped and no shape is dynamic.
+
 The older ``MOELayer`` (top-1 / top-2 with a capacity and drops) is
 untouched beside it.
 """
@@ -24,6 +31,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from ..ops.grouped_matmul import TILE_ROWS
 from ..parallel import mesh as mesh_mod
 from .experts import GatedExpertMLP
 from .sharded_moe import RoutingStats, _routing_stats
@@ -85,48 +93,168 @@ def sort_by_expert(picks, first: int, count: int):
     return order, jnp.argsort(order).astype(jnp.int32)
 
 
-# The transpose of a gather by a permutation is the gather by its
-# inverse, which XLA cannot know and would scatter-add: both directions
-# of the dispatch are written as gathers.  At the benchmark cell's shapes
-# (131,072 rows of 2,048, bf16; my chip run, PR 36) the transposes written
-# here take 5.26 and 4.41 ms a layer where autodiff's of the plain
-# ``x[idx]`` take 9.67 and 14.76.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _to_rows(x, order, position, k):
-    """x [T, d] -> [T k, d]: sorted row r holds the token of flat pick
-    ``order[r]``."""
-    return x[order // k]
+def dispatch_capacity(tokens: int, k: int, count: int,
+                      num_experts: int) -> int:
+    """Rows of a chunk of the dispatch: the ``tokens k`` picks' even
+    share for ``count`` of ``num_experts`` experts, what a router that
+    favours nobody sends here, in whole tiles of the grouped product;
+    never more than the picks there are (every expert held: one chunk of
+    all of them)."""
+    picks = tokens * k
+    share = -(-picks * count // num_experts)
+    return min(-(-share // TILE_ROWS) * TILE_ROWS, picks)
 
 
-def _to_rows_fwd(x, order, position, k):
-    return x[order // k], (position,)
+def dispatch_chunks(counts, capacity: int):
+    """Chunks of ``capacity`` rows that hold the ``sum(counts)`` routed
+    rows (int32 scalar; 0 where no pick landed here)."""
+    return (jnp.sum(counts) + capacity - 1) // capacity
 
 
-def _to_rows_bwd(k, res, g):
-    (position,) = res
-    g = g[position].reshape(-1, k, g.shape[-1])
-    return jnp.sum(g.astype(jnp.float32), axis=1).astype(g.dtype), None, None
+def _chunk_counts(counts, start, capacity: int):
+    """Rows of each expert that lie in sorted rows ``[start, start +
+    capacity)``."""
+    hi = jnp.cumsum(counts)
+    lo = hi - counts
+    return (jnp.clip(hi, start, start + capacity)
+            - jnp.clip(lo, start, start + capacity))
 
 
-_to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
+# The two halves of the dispatch, each the other's transpose, both
+# gathers: the transpose of a gather by a permutation is the gather by
+# its inverse, which XLA cannot know and would scatter-add (1.3 us a row:
+# PERF.md section 5, ``embed``).
+def _chunk_picks(order, start, capacity: int):
+    """The flat picks at sorted rows ``[start, start + capacity)``."""
+    return jax.lax.dynamic_slice(order, (start,), (capacity,))
 
 
-@jax.custom_vjp
-def _to_picks(rows, order, position):
-    """rows [T k, d] -> the same in flat pick order."""
-    return rows[position]
+def _rows_from_tokens(x, picks, k: int):
+    """x [T, d] -> [C, d]: row r holds the token of flat pick
+    ``picks[r]``."""
+    return x[picks // k]
 
 
-def _to_picks_fwd(rows, order, position):
-    return rows[position], (order,)
+def _row_of_pick(position, start, capacity: int):
+    """Where each pick's row lies in the chunk at ``start``; ``capacity``
+    (the row after the chunk's) where it lies in another."""
+    at = position - start
+    return jnp.where(jnp.logical_and(at >= 0, at < capacity), at, capacity)
 
 
-def _to_picks_bwd(res, g):
-    (order,) = res
-    return g[order], None, None
+def _tokens_from_rows(rows, position, start, weights, acc):
+    """rows [C, d] -> acc [T, d] (float32) plus, for token t, the rows
+    of its picks that lie in sorted rows ``[start, start + C)``, each
+    times its pick's weight (``weights`` None: times one); a pick whose
+    row lies elsewhere reads a zero row.  One gather of T rows a pick:
+    no [T k, d] array."""
+    at = _row_of_pick(position, start, rows.shape[0])
+    rows = jnp.pad(rows, ((0, 1), (0, 0)))
+    for j in range(position.shape[1]):
+        picked = rows[at[:, j]].astype(jnp.float32)
+        if weights is not None:
+            picked = picked * weights[:, j, None]
+        acc = acc + picked
+    return acc
 
 
-_to_picks.defvjp(_to_picks_fwd, _to_picks_bwd)
+class _Chunks(NamedTuple):
+    """What the walk over chunks is told and does not differentiate."""
+    expert: GatedExpertMLP
+    k: int
+    capacity: int
+
+
+def _padded_order(order, capacity):
+    """``order`` to a whole number of chunks, so that the last chunk's
+    slice starts where it says (the rows added lie past every count)."""
+    return jnp.pad(order, (0, -order.shape[0] % capacity))
+
+
+# A loop with a trip count made on the device has no reverse-mode rule,
+# so the routed experts are one custom_vjp: the backward rule walks the
+# same chunks, runs a chunk's experts again and takes their vjp.  Nothing
+# of a chunk's size is kept between the passes.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _routed_experts(chunks: _Chunks, x, params, weights, order, position,
+                    counts):
+    """The held experts' part of the layer's sum: x [T, d], ``params``
+    the experts' stacked weights, ``weights`` f32 [T, k], ``order`` /
+    ``position`` of ``sort_by_expert`` (position as [T, k]), ``counts``
+    [G] rows a held expert -> [T, d] in x's dtype, summed in float32."""
+    expert, k, capacity = chunks
+    order = _padded_order(order, capacity)
+
+    def chunk(c, acc):
+        start = c * capacity
+        with jax.named_scope("dispatch"):
+            rows = _rows_from_tokens(
+                x, _chunk_picks(order, start, capacity), k)
+        with jax.named_scope("experts"):
+            out = expert.apply_grouped(
+                params, rows, _chunk_counts(counts, start, capacity))
+        with jax.named_scope("dispatch"):
+            return _tokens_from_rows(out, position, start, weights, acc)
+
+    y = jax.lax.fori_loop(0, dispatch_chunks(counts, capacity), chunk,
+                          jnp.zeros(x.shape, jnp.float32))
+    return y.astype(x.dtype)
+
+
+def _routed_experts_fwd(chunks, x, params, weights, order, position, counts):
+    return (_routed_experts(chunks, x, params, weights, order, position,
+                            counts),
+            (x, params, weights, order, position, counts))
+
+
+def _routed_experts_bwd(chunks, res, g):
+    expert, k, capacity = chunks
+    x, params, weights, order, position, counts = res
+    order = _padded_order(order, capacity)
+
+    def chunk(c, carry):
+        dx, dparams, dweights = carry
+        start = c * capacity
+        with jax.named_scope("dispatch"):
+            picks = _chunk_picks(order, start, capacity)
+            rows = _rows_from_tokens(x, picks, k)
+            g_rows = _rows_from_tokens(g, picks, k).astype(jnp.float32)
+
+        def experts_of(params, rows):
+            # the scope next under jax.vjp's mark is the one XLA names an
+            # operation by: the kernels keep their names (gmm_rows, ...)
+            with jax.named_scope("experts"):
+                return expert.apply_grouped(
+                    params, rows, _chunk_counts(counts, start, capacity))
+
+        out, vjp = jax.vjp(experts_of, params, rows)
+        with jax.named_scope("dispatch"):
+            # a pick's weight: its row's output against its token's
+            # cotangent, read by the pick's position like the row itself
+            per_row = jnp.sum(g_rows * out.astype(jnp.float32), axis=-1)
+            dweights = dweights + jnp.pad(per_row, (0, 1))[
+                _row_of_pick(position, start, capacity)]
+            # the transpose of tokens-from-rows: a row's cotangent is its
+            # token's times its pick's weight
+            dout = g_rows * weights.reshape(-1)[picks][:, None]
+        dp, drows = vjp(dout.astype(out.dtype))
+        with jax.named_scope("experts"):
+            dparams = jax.tree.map(
+                lambda a, b: a + b.astype(jnp.float32), dparams, dp)
+        with jax.named_scope("dispatch"):
+            dx = _tokens_from_rows(drows, position, start, None, dx)
+        return dx, dparams, dweights
+
+    dx, dparams, dweights = jax.lax.fori_loop(
+        0, dispatch_chunks(counts, capacity), chunk,
+        jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.float32),
+                     (x, params, weights)))
+    return (dx.astype(x.dtype),
+            jax.tree.map(lambda d, p: d.astype(p.dtype), dparams, params),
+            dweights.astype(weights.dtype), None, None, None)
+
+
+_routed_experts.defvjp(_routed_experts_fwd, _routed_experts_bwd)
 
 
 class DroplessMoE:
@@ -183,14 +311,24 @@ class DroplessMoE:
             params = jax.eval_shape(self.init_params, jax.random.PRNGKey(0))
         return jax.tree.map(lambda _: P(), params)
 
+    def capacity(self, tokens: int) -> int:
+        """Rows of the layer's row buffers on ``tokens`` tokens: the held
+        experts' even share of the picks (``dispatch_capacity``)."""
+        return dispatch_capacity(tokens, self.k, self.experts_held[1],
+                                 self.num_experts)
+
     def working_set_bytes(self, tokens: int, itemsize: int) -> int:
         """Bytes of the rows one layer holds at once, forward or backward:
-        every pick a row (the worst case the buffers are sized for), in
-        and out of the experts at the model's width three times over (the
-        sorted rows, the experts' output, the same back in pick order)
-        and three times the experts' width between the two products."""
-        return tokens * self.k * itemsize * 3 * (
+        a chunk of ``capacity`` rows three times at the model's width
+        (the gathered rows, their tokens' cotangents, the experts'
+        output) and three times at the experts' width (the first
+        product's result, which is twice as wide, and the gated rows
+        between the two products), and the float32 [tokens, d] sum the
+        chunks add into (tests/unit/test_dropless_chunks.py reads the
+        same off the backward walk's jaxpr)."""
+        return (self.capacity(tokens) * itemsize * 3 * (
             self.hidden_size + self.expert.d_ff)
+            + tokens * self.hidden_size * 4)
 
     def route(self, params, x, picks=None) -> Routing:
         """x [T, d] -> the routing; the product in float32 whatever x is."""
@@ -206,8 +344,12 @@ class DroplessMoE:
         entropy of the scores normalised to sum to one."""
         share = routing.scores / jnp.sum(routing.scores, -1, keepdims=True)
         mass = jnp.sum(jnp.take_along_axis(share, routing.picks, -1), -1)
-        return _routing_stats(share, routing.counts, routing.counts, mass,
-                              jnp.float32(0.0), held=self.experts_held)
+        first, count = self.experts_held
+        return _routing_stats(
+            share, routing.counts, routing.counts, mass, jnp.float32(0.0),
+            held=self.experts_held, chunks=dispatch_chunks(
+                routing.counts[first:first + count],
+                self.capacity(share.shape[0])))
 
     def apply(self, params, x, picks=None):
         """x [..., d] -> (y [..., d], Routing).  ``picks`` forces the
@@ -220,17 +362,10 @@ class DroplessMoE:
         routing = self.route(params, x, picks)
         with jax.named_scope("dispatch"):
             order, position = sort_by_expert(routing.picks, first, count)
-            rows = routing.counts[first:first + count]
-            sorted_x = _to_rows(x, order, position, k)
-        with jax.named_scope("experts"):
-            out = self.expert.apply_grouped(params["experts"], sorted_x,
-                                            rows)
-        with jax.named_scope("dispatch"):
-            # back in (token, pick) order; a pick that landed elsewhere
-            # reads a row past the held ones: zero
-            out = _to_picks(out, order, position).reshape(tokens, k, -1)
-            y = jnp.sum(out.astype(jnp.float32)
-                        * routing.weights[..., None], axis=1).astype(x.dtype)
+        y = _routed_experts(
+            _Chunks(self.expert, k, self.capacity(tokens)), x,
+            params["experts"], routing.weights, order,
+            position.reshape(tokens, k), routing.counts[first:first + count])
         if self.shared is not None:
             with jax.named_scope("shared"):
                 y = y + self.shared.apply(params["shared"], x)

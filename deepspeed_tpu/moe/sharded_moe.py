@@ -69,17 +69,24 @@ class RoutingStats(NamedTuple):
     #                               rows of the busiest expert among those
     #                               THIS program holds (all of them unless
     #                               the layer was given a held range)
+    dispatch_chunks: jnp.ndarray  # f32[] summed over gate invocations:
+    #                               passes over the row buffers it took
+    #                               to move the routed rows (one, unless
+    #                               the layer sizes its buffers under the
+    #                               worst case: moe/dropless.py)
 
 
 def _routing_stats(gates, wanted_counts, routed_counts, topk_mass,
-                   l_aux, used_token=None, held=None) -> RoutingStats:
+                   l_aux, used_token=None, held=None,
+                   chunks=1) -> RoutingStats:
     """Assemble one gate invocation's RoutingStats.
 
     ``wanted_counts``/``routed_counts``: [E] pre-/post-capacity-mask
     token-slot counts; ``topk_mass``: [S] raw gate probability mass on
     the selected (pre-capacity) experts; ``used_token``: optional [S]
     validity mask (padding tokens contribute nothing); ``held``:
-    optional static (first, count) of the experts this program holds."""
+    optional static (first, count) of the experts this program holds;
+    ``chunks``: passes over the row buffers this invocation made."""
     ent = -jnp.sum(gates * jnp.log(jnp.clip(gates, _ENTROPY_EPS, 1.0)),
                    axis=-1)
     if used_token is not None:
@@ -102,7 +109,8 @@ def _routing_stats(gates, wanted_counts, routed_counts, topk_mass,
         gate_tokens=gate_tokens,
         l_aux=l_aux.astype(jnp.float32),
         layers=jnp.float32(1.0),
-        held_rows_max=routed[first:first + count].max())
+        held_rows_max=routed[first:first + count].max(),
+        dispatch_chunks=jnp.asarray(chunks, jnp.float32))
 
 
 # ---- routing-stats collection tap ------------------------------------ #

@@ -112,6 +112,9 @@ def summarize_window(raw: Dict[str, Any]) -> Optional[Dict[str, Any]]:
         summary[R.M_HELD_ROWS_MEAN] = round(
             float(held_rows / (hi - lo) / layers), 2)
         summary[R.M_HELD_PICK_SHARE] = round(float(held_rows / tokens), 6)
+    chunks = raw.get("dispatch_chunks")
+    if chunks is not None:
+        summary[R.M_DISPATCH_CHUNKS] = round(_f(chunks) / layers, 4)
     return summary
 
 

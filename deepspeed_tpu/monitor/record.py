@@ -114,6 +114,9 @@ M_STACK_CROSS_LAYER_KEPT = "stack_cross_layer_kept"  # [[name, bytes]]
 # (models/laguna.py) the routed experts this program holds of those the
 # router scores: [first, count, of]
 M_STACK_EXPERTS_HELD = "stack_experts_held"
+# rows of a sparse layer's row buffers: the held experts' even share of
+# the picks, which the dispatch walks in as many chunks as it takes
+M_STACK_DISPATCH_ROWS = "stack_dispatch_rows"
 
 # ---- fleet field names (fleet.py / health.py payloads) --------------- #
 FL_WINDOW_START = "window_start_step"
@@ -172,6 +175,7 @@ M_HELD_RANGE = "held_experts"          # [first, past the last]
 M_HELD_ROWS_MAX = "held_rows_max"      # rows of the busiest held expert
 M_HELD_ROWS_MEAN = "held_rows_mean"    # rows a held expert, on average
 M_HELD_PICK_SHARE = "held_pick_share"  # of the k x tokens picks: landed here
+M_DISPATCH_CHUNKS = "dispatch_chunks"  # passes over the row buffers, a layer
 M_POPULARITY = "popularity"        # embedded ExpertPopularitySnapshot
 
 # ---- reconciliation field names (reconcile.py payload) --------------- #

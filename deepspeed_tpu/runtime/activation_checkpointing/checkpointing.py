@@ -343,7 +343,7 @@ def checkpoint_layers(groups, budget: Optional[RematBudget], carry,
     one plan is noted, ``stack_plan`` (the model's M_STACK_* fields)
     riding on it; ``extra_working_set``: bytes a layer of this model
     holds that ``working_set_bytes`` does not know of (a sparse layer's
-    rows, sized for the worst case).  Returns the wrapper for every body
+    rows).  Returns the wrapper for every body
     of the stack: what
     a wrapped body closes over (another layer's output that this one
     reads; the plan may be made on a stand-in of its shape) is an input

@@ -219,6 +219,8 @@ def test_the_plan_names_the_layers_and_the_held_experts():
         (4, "full_attention+experts")]
     assert plan[R.M_STACK_LAYERS][1][2] == WINDOW
     assert tuple(plan[R.M_STACK_EXPERTS_HELD]) == (4, 8, 16)
+    # 80 tokens x 4 picks, half the experts held: 160 rows, a whole tile
+    assert plan[R.M_STACK_DISPATCH_ROWS] == 256
     assert plan[R.M_REMAT_LAYERS] == 5
 
 
@@ -275,5 +277,7 @@ def test_trains_through_initialize_with_the_routing_counters(tmp_path):
     assert summary[R.M_HELD_RANGE] == [4, 12]
     assert summary[R.M_HELD_ROWS_MAX] >= summary[R.M_HELD_ROWS_MEAN] > 0
     assert 0 < summary[R.M_HELD_PICK_SHARE] < 1
+    # 256 rows a chunk for at most 320 picks: one trip or two a layer
+    assert 1.0 <= summary[R.M_DISPATCH_CHUNKS] <= 2.0
     engine.monitor.close()
     ds.reset_mesh_context()

@@ -393,3 +393,63 @@ def test_local_expert_slice_is_union_of_local_devices(tmp_path,
     assert engine._moe_local_expert_slice(8) == (0, 8)
     # indivisible expert counts and ep=1 meshes degrade to exactly-fair
     assert engine._moe_local_expert_slice(6) == (0, 6)
+
+
+# --------------------------------------------------------------------- #
+# dispatch_chunks: passes over a dropless layer's row buffers
+# --------------------------------------------------------------------- #
+def _chunk_raw(chunks, layers, steps):
+    counts = np.array([40., 10., 30., 20.])
+    return {"expert_counts": counts, "overflow_counts": np.zeros(4),
+            "tokens": counts.sum(), "dropped": 0.0, "entropy": 1.0,
+            "confidence": 1.0, "gate_tokens": 4.0, "l_aux": 0.0,
+            "layers": layers, "steps": steps, "held_rows_max": 40.0,
+            "dispatch_chunks": chunks, "local_expert_slice": (0, 2)}
+
+
+@pytest.mark.parametrize("chunks, layers, steps, mean", [
+    (8.0, 8.0, 2, 1.0),      # one trip a layer and step
+    (11.0, 8.0, 2, 1.375),   # three layer-steps took a second trip
+    (6.0, 8.0, 2, 0.75),     # two saw no pick on a held expert
+])
+def test_summary_reports_chunks_a_layer_and_step(chunks, layers, steps,
+                                                 mean):
+    summary = summarize_window(_chunk_raw(chunks, layers, steps))
+    assert summary[R.M_DISPATCH_CHUNKS] == mean
+    assert summary[R.M_HELD_ROWS_MAX] == 40.0 / layers
+    # an accumulator from before the field (an older checkpointed
+    # monitor state) summarises as it did
+    older = _chunk_raw(chunks, layers, steps)
+    del older["dispatch_chunks"]
+    assert R.M_DISPATCH_CHUNKS not in summarize_window(older)
+
+
+def test_dispatch_chunks_sum_like_held_rows_max():
+    """The field rides the RoutingStats pytree: a tap's entries sum with
+    the same ``jax.tree.map(jnp.add)`` as every other field, and the
+    capacity layer's gates each count one pass."""
+    import jax.numpy as jnp
+    from deepspeed_tpu.moe.dropless import DroplessMoE
+    from deepspeed_tpu.moe.sharded_moe import (
+        RoutingStats, sum_routing_stats, top1gating)
+    assert RoutingStats._fields[-2:] == ("held_rows_max", "dispatch_chunks")
+    logits = jax.random.normal(jax.random.PRNGKey(0), (32, 4))
+    capped = top1gating(logits, capacity_factor=1.0, min_capacity=2)[-1]
+    assert float(capped.dispatch_chunks) == 1.0
+    layer = DroplessMoE(8, 4, 2, 8, experts_held=(0, 1))
+    x = jax.random.normal(jax.random.PRNGKey(1), (1024, 8))
+    params = layer.init_params(jax.random.PRNGKey(2))
+    # 2,048 picks, a held share of 512: every token's first pick here is
+    # 1,024 rows, two chunks; none here, none
+    here = layer.stats(layer.route(params, x, jnp.tile(
+        jnp.asarray([[0, 3]], jnp.int32), (1024, 1))))
+    away = layer.stats(layer.route(params, x, jnp.tile(
+        jnp.asarray([[2, 3]], jnp.int32), (1024, 1))))
+    assert layer.capacity(1024) == 512
+    assert (float(here.dispatch_chunks), float(away.dispatch_chunks)) == (
+        2.0, 0.0)
+    total = sum_routing_stats([capped, here, away])
+    assert float(total.dispatch_chunks) == 3.0
+    assert float(total.layers) == 3.0
+    assert float(total.held_rows_max) == float(
+        capped.held_rows_max + here.held_rows_max + away.held_rows_max)

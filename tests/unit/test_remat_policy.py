@@ -504,3 +504,39 @@ def test_a_stack_without_picks_lowers_as_it_did(limit):
             carry, ws).as_text()
 
     assert text(wrap) == text(before)
+
+
+# -- (e) a sparse stack's row buffers leave room for the kernel residuals ---- #
+
+def test_laguna_cell_keeps_the_flash_residuals(interpret):
+    """The benchmark cell's stack (five layers at the published widths, 32
+    of 256 experts held, two rows of 8,192 tokens) under the v5e's memory
+    limit and the cell's state: a sparse layer's row buffers hold the held
+    experts' even share of the picks, 16,384 rows and not the 131,072 of
+    the worst case, so the budget admits the flash kernels' residuals (it
+    admitted nothing while ``DroplessMoE.working_set_bytes`` counted 2.01
+    GB of worst-case rows)."""
+    from deepspeed_tpu.models.laguna import LagunaConfig, LagunaModel
+    model = LagunaModel(LagunaConfig(
+        num_hidden_layers=5, experts_held=(0, 32), vocab_size=12544,
+        activation_checkpointing=True))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16),
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    entries = model.num_params()
+    assert entries == 691_623_936
+    # fp32 weights and two moments, bf16 gradients, the step count
+    budget = ck.RematBudget(16_909_336_064, state_bytes=14 * entries + 4,
+                            cast_bytes=2 * entries)
+    model.install_remat_budget(budget)
+    jax.eval_shape(model.loss, params, None,
+                   jax.ShapeDtypeStruct((2, 8192), jnp.int32))
+    plan = budget.plan
+    rows = model.moe.working_set_bytes(2 * 8192, 2)
+    assert rows == 16384 * 2 * 3 * (2048 + 512) + 16384 * 2048 * 4
+    assert plan[R.M_STACK_DISPATCH_ROWS] == 16384
+    assert plan[R.M_REMAT_STATE_BYTES] == 9_682_735_108
+    assert plan[R.M_REMAT_WORKING_SET_BYTES] == 5_108_359_424 + rows
+    assert plan[R.M_REMAT_KEPT] == (FLASH,)
+    assert plan[R.M_REMAT_KEPT_BYTES] <= plan[R.M_REMAT_BUDGET_BYTES]
+    assert 1.2e9 < plan[R.M_REMAT_KEPT_BYTES] < 1.4e9
