@@ -277,24 +277,39 @@ class LagunaModel:
         cfg = self.config
         batch, seq, _ = u.shape
         dim, kv = cfg.head_dim, cfg.num_key_value_heads
+        # inside "attn" the work is named once more, by part
+        # (profiling/scope_map.py PARTS); names only
         with jax.named_scope("attn"):
-            qkv = u @ p["qkv_w"]
-            q, k, v = jnp.split(qkv, [heads * dim, (heads + kv) * dim],
-                                axis=-1)
+            with jax.named_scope("attn_qkv"):
+                qkv = u @ p["qkv_w"]
+                q, k, v = jnp.split(qkv, [heads * dim, (heads + kv) * dim],
+                                    axis=-1)
 
             def by_head(t, n):
-                return t.reshape(batch, seq, n, dim).transpose(0, 2, 1, 3)
+                with jax.named_scope("attn_layout"):
+                    return t.reshape(batch, seq, n, dim).transpose(0, 2, 1, 3)
 
-            q = apply_rotary(by_head(q, heads), table)
-            k = apply_rotary(by_head(k, kv), table)
+            def rotary(t):
+                with jax.named_scope("attn_rotary"):
+                    return apply_rotary(t, table)
+
+            q = rotary(by_head(q, heads))
+            k = rotary(by_head(k, kv))
+            v = by_head(v, kv)
             window = cfg.sliding_window
             banded = {"window": window, "block_q": window,
                       "block_k": window} if kind == SLIDING else {}
-            a = flash_attention(q, k, by_head(v, kv), causal=True,
-                                sm_scale=1.0 / math.sqrt(dim), **banded)
-            gate = jax.nn.sigmoid((u @ p["gate_w"]).astype(jnp.float32))
-            a = a.transpose(0, 2, 1, 3) * gate[..., None].astype(a.dtype)
-            return a.reshape(batch, seq, heads * dim) @ p["out_w"]
+            with jax.named_scope("attn_core"):
+                a = flash_attention(q, k, v, causal=True,
+                                    sm_scale=1.0 / math.sqrt(dim), **banded)
+            with jax.named_scope("attn_gate"):
+                gate = jax.nn.sigmoid((u @ p["gate_w"]).astype(jnp.float32))
+            with jax.named_scope("attn_layout"):
+                a = a.transpose(0, 2, 1, 3)
+            with jax.named_scope("attn_gate"):
+                a = a * gate[..., None].astype(a.dtype)
+            with jax.named_scope("attn_out"):
+                return a.reshape(batch, seq, heads * dim) @ p["out_w"]
 
     def _dense_ffn(self, p, u):
         with jax.named_scope("mlp"):

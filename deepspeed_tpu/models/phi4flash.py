@@ -273,9 +273,10 @@ class Phi4FlashModel:
         """[B, S, heads * d] -> the even and the odd heads, each
         [B, heads / 2, S, d]."""
         batch, seq, _ = t.shape
-        t = t.reshape(batch, seq, heads // 2, 2, self.config.head_dim)
-        t = t.transpose(3, 0, 2, 1, 4)
-        return t[0], t[1]
+        with jax.named_scope("attn_layout"):
+            t = t.reshape(batch, seq, heads // 2, 2, self.config.head_dim)
+            t = t.transpose(3, 0, 2, 1, 4)
+            return t[0], t[1]
 
     def _diff_attention(self, p, q, kv, lam0, window):
         """q [B, S, H]; kv = (k1, k2, v1, v2), each [B, kv_heads / 2, S,
@@ -287,23 +288,33 @@ class Phi4FlashModel:
             "window": window, "block_q": window, "block_k": window}
 
         def att(q_, k_, v_):
-            return flash_attention(q_, k_, v_, causal=True,
-                                   sm_scale=1.0 / math.sqrt(cfg.head_dim),
-                                   **block)
+            with jax.named_scope("attn_core"):
+                return flash_attention(
+                    q_, k_, v_, causal=True,
+                    sm_scale=1.0 / math.sqrt(cfg.head_dim), **block)
 
-        a1 = jnp.concatenate([att(q1, k1, v1), att(q1, k1, v2)], axis=-1)
-        a2 = jnp.concatenate([att(q2, k2, v1), att(q2, k2, v2)], axis=-1)
+        def pair(a_, b_):
+            with jax.named_scope("attn_diff"):
+                return jnp.concatenate([a_, b_], axis=-1)
+
+        a1 = pair(att(q1, k1, v1), att(q1, k1, v2))
+        a2 = pair(att(q2, k2, v1), att(q2, k2, v2))
         f32 = jnp.float32
-        lam = (jnp.exp(jnp.sum(p["lq1"].astype(f32) * p["lk1"].astype(f32)))
-               - jnp.exp(jnp.sum(p["lq2"].astype(f32)
-                                 * p["lk2"].astype(f32))) + lam0)
-        a = a1.astype(f32) - lam * a2.astype(f32)      # [B, H/2, S, 2d]
-        a = a * jax.lax.rsqrt(jnp.mean(a * a, axis=-1, keepdims=True)
-                              + cfg.layer_norm_eps)
-        a = (a * p["subln_w"].astype(f32) * (1.0 - lam0)).astype(q.dtype)
+        with jax.named_scope("attn_diff"):
+            lam = (jnp.exp(jnp.sum(p["lq1"].astype(f32)
+                                   * p["lk1"].astype(f32)))
+                   - jnp.exp(jnp.sum(p["lq2"].astype(f32)
+                                     * p["lk2"].astype(f32))) + lam0)
+            a = a1.astype(f32) - lam * a2.astype(f32)      # [B, H/2, S, 2d]
+            a = a * jax.lax.rsqrt(jnp.mean(a * a, axis=-1, keepdims=True)
+                                  + cfg.layer_norm_eps)
+            a = (a * p["subln_w"].astype(f32) * (1.0 - lam0)).astype(
+                q.dtype)
         batch, _, seq, _ = a.shape
-        a = a.transpose(0, 2, 1, 3).reshape(batch, seq, cfg.hidden_size)
-        return a @ p["out_w"] + p["out_b"]
+        with jax.named_scope("attn_layout"):
+            a = a.transpose(0, 2, 1, 3).reshape(batch, seq, cfg.hidden_size)
+        with jax.named_scope("attn_out"):
+            return a @ p["out_w"] + p["out_b"]
 
     def _attn(self, p, x, lam0, window):
         """(mixer output, (k1, k2, v1, v2))."""
@@ -311,16 +322,20 @@ class Phi4FlashModel:
         with jax.named_scope("attn"):
             hid = cfg.hidden_size
             kv_w = cfg.num_key_value_heads * cfg.head_dim
-            qkv = x @ p["qkv_w"] + p["qkv_b"]
-            q, k, v = jnp.split(qkv, [hid, hid + kv_w], axis=-1)
+            # inside "attn" the work is named once more, by part
+            # (profiling/scope_map.py PARTS); names only
+            with jax.named_scope("attn_qkv"):
+                qkv = x @ p["qkv_w"] + p["qkv_b"]
+                q, k, v = jnp.split(qkv, [hid, hid + kv_w], axis=-1)
             kv = (*self._pairs(k, cfg.num_key_value_heads),
                   *self._pairs(v, cfg.num_key_value_heads))
             return self._diff_attention(p, q, kv, lam0, window), kv
 
     def _cross(self, p, x, kv, lam0):
         with jax.named_scope("attn"):
-            return self._diff_attention(p, x @ p["q_w"] + p["q_b"], kv,
-                                        lam0, None)
+            with jax.named_scope("attn_qkv"):
+                q = x @ p["q_w"] + p["q_b"]
+            return self._diff_attention(p, q, kv, lam0, None)
 
     def _layer(self, p, x, mixer):
         """One layer around `mixer(p['mixer'], LN1(x)) -> (out, kept)`."""

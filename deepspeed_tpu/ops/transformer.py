@@ -59,8 +59,13 @@ class DeepSpeedTransformerConfig:
     # "bhsd" (default): classic [B,H,S,D] kernel layout.  "bshd": API
     # convenience for [B,S,H,D] callers — NOT transpose-free: a native
     # bshd BlockSpec is Mosaic-illegal (measured round 3, v5e), so the
-    # layout converts at the Pallas boundary; the transposes are <1% of
-    # step traffic.
+    # layout converts at the Pallas boundary.  Neither layout's
+    # transposes are cheap: under "bhsd" they are eight stand-alone
+    # copies a layer (q, k, v in, the context out, and their transposes
+    # in the backward pass; three more in the recomputation), read by
+    # the part metric attn_layout_ms on the v5e (PERF.md section 5,
+    # PR 38): 12.5 ms of gpt2-large.s1024's 246.5 ms step (5.1%), 12.2
+    # of gpt2-large.s512's 237.6, 50.1 of gpt2-large.gas4's 913.3.
     attn_layout: str = "bhsd"
     # "kernel" = in-kernel attention-probability dropout (reference
     # semantics, ~10% step cost at S=1024); "ctx" = cheap dropout on the
@@ -313,7 +318,10 @@ class DeepSpeedTransformerLayer:
         if tp_axis is not None:
             attn_in = tp_fcast(attn_in, tp_axis)
 
-        with jax.named_scope("attn"):
+        # Inside "attn" the work is named once more, by part
+        # (profiling/scope_map.py PARTS): attn_qkv, attn_layout, attn_core,
+        # attn_out here.  Names only: the compiled programs are the same.
+        with jax.named_scope("attn"), jax.named_scope("attn_qkv"):
             if tp_axis is None:
                 qkv = matmul_maybe_int8(attn_in, params["attn_qkvw"]) + \
                     params["attn_qkvb"].astype(attn_in.dtype)
@@ -349,6 +357,17 @@ class DeepSpeedTransformerLayer:
                 return None
             return jax.random.randint(r_attn, (), 0, 2 ** 31 - 1, jnp.int32)
 
+        def to_heads(t):
+            with jax.named_scope("attn_layout"):
+                return t.reshape(b, s, heads, d).transpose(0, 2, 1, 3)
+
+        def from_heads(t):
+            # the context's way back, [B,H,S,D] -> [B,S,H*D]: written
+            # between two "attn" blocks, so its scope is "layer"; the
+            # part counts in either (scope_map.PARTS)
+            with jax.named_scope("attn_layout"):
+                return t.transpose(0, 2, 1, 3).reshape(b, s, hw)
+
         if seq_axis is not None:
             # ring / Ulysses attention over the manual seq axis on the
             # local chunk (lazy import: parallel.sequence pulls in
@@ -360,15 +379,13 @@ class DeepSpeedTransformerLayer:
             if mode == "auto":
                 mode = "ulysses" if heads % sp == 0 else "ring"
 
-            def to_heads(t):
-                return t.reshape(b, s, heads, d).transpose(0, 2, 1, 3)
-
             with jax.named_scope("attn"):
-                ctx = sp_attention_inner(to_heads(q), to_heads(k),
-                                         to_heads(v), mode=mode,
-                                         axis_name=seq_axis,
-                                         causal=cfg.causal)
-            ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, hw)
+                qkv_heads = to_heads(q), to_heads(k), to_heads(v)
+                with jax.named_scope("attn_core"):
+                    ctx = sp_attention_inner(*qkv_heads, mode=mode,
+                                             axis_name=seq_axis,
+                                             causal=cfg.causal)
+            ctx = from_heads(ctx)
             # kernel-dropout fallback: output ('ctx') dropout on the chunk
             ctx = dropout(ctx, cfg.attn_dropout_ratio, r_attn, deterministic)
         elif self._sparse_attn is not None:
@@ -393,15 +410,13 @@ class DeepSpeedTransformerLayer:
                         "softmax.py:attn_mask is 2D-only); got shape "
                         f"{attn_mask.shape}")
 
-            def to_heads(t):
-                return t.reshape(b, s, heads, d).transpose(0, 2, 1, 3)
-
             with jax.named_scope("attn"):
-                ctx = self._sparse_attn(to_heads(q), to_heads(k),
-                                        to_heads(v), causal=cfg.causal,
-                                        key_padding_mask=sparse_kp,
-                                        attn_mask=sparse_am)
-            ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, hw)
+                qkv_heads = to_heads(q), to_heads(k), to_heads(v)
+                with jax.named_scope("attn_core"):
+                    ctx = self._sparse_attn(*qkv_heads, causal=cfg.causal,
+                                            key_padding_mask=sparse_kp,
+                                            attn_mask=sparse_am)
+            ctx = from_heads(ctx)
             ctx = dropout(ctx, cfg.attn_dropout_ratio, r_attn, deterministic)
         elif cfg.attn_layout == "bshd":
             # [B,S,H] -> [B,S,heads,d] is a free view; the layout
@@ -409,40 +424,45 @@ class DeepSpeedTransformerLayer:
             # boundary (a native bshd BlockSpec is Mosaic-illegal —
             # measured round 3; see flash_attention.py::_tile_spec)
             def split_heads(t):
-                return t.reshape(b, s, heads, d)
+                with jax.named_scope("attn_layout"):
+                    return t.reshape(b, s, heads, d)
 
             with jax.named_scope("attn"):
-                ctx = flash_attention_bsh(
-                    split_heads(q), split_heads(k), split_heads(v),
-                    causal=cfg.causal, bias=attn_mask,
-                    block_q=cfg.block_q, block_k=cfg.block_k,
-                    impl=cfg.attn_impl, dropout_rate=attn_rate,
-                    dropout_seed=attn_seed())
-            ctx = ctx.reshape(b, s, hw)
+                qkv_heads = split_heads(q), split_heads(k), split_heads(v)
+                with jax.named_scope("attn_core"):
+                    ctx = flash_attention_bsh(
+                        *qkv_heads, causal=cfg.causal, bias=attn_mask,
+                        block_q=cfg.block_q, block_k=cfg.block_k,
+                        impl=cfg.attn_impl, dropout_rate=attn_rate,
+                        dropout_seed=attn_seed())
+            with jax.named_scope("attn_layout"):
+                ctx = ctx.reshape(b, s, hw)
             if not kernel_drop:
                 ctx = dropout(ctx, cfg.attn_dropout_ratio, r_attn,
                               deterministic)
         else:
-            def to_heads(t):
-                return t.reshape(b, s, heads, d).transpose(0, 2, 1, 3)
-
             with jax.named_scope("attn"):
-                ctx = flash_attention(
-                    to_heads(q), to_heads(k), to_heads(v),
-                    causal=cfg.causal, bias=attn_mask,
-                    block_q=cfg.block_q, block_k=cfg.block_k,
-                    impl=cfg.attn_impl, dropout_rate=attn_rate,
-                    dropout_seed=attn_seed())
-            ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, hw)
+                qkv_heads = to_heads(q), to_heads(k), to_heads(v)
+                with jax.named_scope("attn_core"):
+                    ctx = flash_attention(
+                        *qkv_heads, causal=cfg.causal, bias=attn_mask,
+                        block_q=cfg.block_q, block_k=cfg.block_k,
+                        impl=cfg.attn_impl, dropout_rate=attn_rate,
+                        dropout_seed=attn_seed())
+            ctx = from_heads(ctx)
             if not kernel_drop:
                 ctx = dropout(ctx, cfg.attn_dropout_ratio, r_attn,
                               deterministic)
 
         # NOTE: "attn" opens as several blocks (the dispatch branches
         # prevent one contiguous region); the scope KEY is identical so
-        # module_tree merges them — only free reshapes/transposes between
-        # blocks fall to the parent "layer" scope.
-        with jax.named_scope("attn"):
+        # module_tree merges them — only the context's way back
+        # (from_heads) falls between blocks, to the parent "layer" scope.
+        # It is not free: a copy a layer and pass, 7.5 ms an optimizer
+        # step of attn_layout_ms's 50.1 in gpt2-large.gas4 and 1.9 of
+        # 12.2 in gpt2-large.s512 (PERF.md section 5, PR 38).  It stays
+        # where it is so that attn_ms reads what it always read.
+        with jax.named_scope("attn"), jax.named_scope("attn_out"):
             attn_out = matmul_maybe_int8(ctx, params["attn_ow"])
             if tp_axis is not None:
                 # row-parallel output projection: merge the per-peer

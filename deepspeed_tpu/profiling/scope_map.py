@@ -26,10 +26,48 @@ rematted_computation/mlp/dot_general" ...}`` on every instruction.
 A fusion takes the tag of its own instruction's metadata (XLA gives a
 fusion the metadata of its root), not of what it fused.
 
-``live()`` is the one door for a reader outside the program (the
-benchmark's per-layer readers): the maps of the step programs of the
-engines alive in this process.  Engines are held by weak reference, and
-nothing is lowered until it is called.
+One level further down, ``part`` names what an instruction does INSIDE
+its scope (``PARTS``: the name the program writes with
+``jax.named_scope``, the part it means, the scopes it may follow):
+
+  qkv, rotary, layout, core, gate, diff, out
+         the parts of ``attn``: the input projections with their bias
+         and split; the rotary embedding of q and k; the reshapes and
+         transposes to and from the kernels' head-major layout
+         (``layout`` also counts in scope ``layer``, where
+         ops/transformer.py leaves the context's transpose back); the
+         call of the attention itself, kernels and all the wrapper puts
+         around them, or the whole XLA path; a per-head gate; the
+         differential combination and its norm; the output projection
+         with bias, dropout and residual.
+  cast   outside every scope: the engine's casts of the weights to the
+         compute dtype in the grad programs (runtime/engine.py
+         ``_cast_weights``), and their transposes.
+  stack  outside every scope and written by no ``named_scope``: a
+         ``lax.scan`` slices its own stacked operands, so an instruction
+         whose path ENDS directly in a ``while`` body with one of the
+         four primitives a scan writes there (``STACK_OPS``:
+         ``dynamic_slice`` and ``squeeze`` to read a layer's entry,
+         ``broadcast_in_dim`` and ``dynamic_update_slice`` to write
+         one): the layer scan's reads of stacked weights and saved
+         carries, its writes of kept residuals and gradient stacks.  The
+         v5e's compiler roots most of the reading fusions at the
+         ``squeeze``, so the two slice names alone miss them.
+
+A part counts only where it follows one of its scopes on the path with
+no other scope between them, so that a ``gate`` elsewhere is none.
+``tag`` knows nothing of parts: (scope, phase) are what they were.
+
+``live()`` and ``live_parts()`` are the doors for a reader outside the
+program (the benchmark's per-layer readers): the maps of the step
+programs of the engines alive in this process.  Engines are held by weak
+reference, nothing is lowered until one of them is called, and a
+program's text is lowered once for both.  The text is the persistent
+compile cache's where one is on, and its key leaves metadata out: a
+program compiled earlier from a tree with other names, and no other
+difference, is served with THAT tree's ``op_name``s.  A program with a
+Mosaic call carries source locations in the call's bytes and misses
+(seen on the chip: PERF.md section 7, PR 38).
 """
 
 import re
@@ -39,6 +77,21 @@ SCOPES = ("embed", "attn", "ssm", "gmu", "mlp", "router", "dispatch",
           "experts", "shared", "head", "layer")
 OTHER = "other"
 PHASES = ("forward", "recompute", "backward")
+CAST_SCOPE = "weight_cast"
+# the name on the path -> (part, the scopes it counts in)
+PARTS = {
+    "attn_qkv": ("qkv", ("attn",)),
+    "attn_rotary": ("rotary", ("attn",)),
+    "attn_layout": ("layout", ("attn", "layer")),
+    "attn_core": ("core", ("attn",)),
+    "attn_gate": ("gate", ("attn",)),
+    "attn_diff": ("diff", ("attn",)),
+    "attn_out": ("out", ("attn",)),
+    CAST_SCOPE: ("cast", (OTHER,)),
+}
+STACK = "stack"
+STACK_OPS = ("dynamic_slice", "squeeze", "broadcast_in_dim",
+             "dynamic_update_slice")
 
 # ``%name = shape opcode(...)`` or ``ROOT %name = ...``; the name is
 # what a device trace shows.
@@ -50,6 +103,9 @@ _REMAT = "rematted_computation"
 _TRANSPOSE = "transpose("
 
 _engines = []  # weak references, in order of construction
+# engine -> the optimized HLO texts of its step programs, in their
+# order: one lowering serves live() and live_parts()
+_texts = weakref.WeakKeyDictionary()
 
 
 def tag(op_name):
@@ -67,19 +123,45 @@ def tag(op_name):
     return scope, phase
 
 
-def parse(hlo_text):
-    """{instruction name: (scope, phase)} for every instruction of an
-    optimized HLO module's text, the bodies of its fusions, loops and
-    calls included.  Instruction names are unique within a module."""
-    tags = {}
+def part(op_name):
+    """The part of its scope one ``op_name`` path names, or None."""
+    scope, found = OTHER, None
+    components = [c for c in _COMPONENT.split(op_name) if c]
+    for component in components:
+        if component in SCOPES:
+            scope, found = component, None
+        elif component in PARTS and scope in PARTS[component][1]:
+            found = PARTS[component][0]
+    if found is None and scope == OTHER and \
+            components[-3:-1] == ["while", "body"] and \
+            components[-1] in STACK_OPS:
+        return STACK
+    return found
+
+
+def _parse(hlo_text, of):
+    out = {}
     for line in hlo_text.splitlines():
         m = _INSTRUCTION.match(line)
         if m is None:
             continue
         named = _OP_NAME.search(line)
-        # no metadata reads as an empty path: ("other", "forward")
-        tags[m.group(1)] = tag(named.group(1) if named else "")
-    return tags
+        # no metadata reads as an empty path
+        out[m.group(1)] = of(named.group(1) if named else "")
+    return out
+
+
+def parse(hlo_text):
+    """{instruction name: (scope, phase)} for every instruction of an
+    optimized HLO module's text, the bodies of its fusions, loops and
+    calls included.  Instruction names are unique within a module."""
+    return _parse(hlo_text, tag)
+
+
+def parse_parts(hlo_text):
+    """{instruction name: part or None}, over the same instructions as
+    ``parse``."""
+    return _parse(hlo_text, part)
 
 
 def register(engine):
@@ -88,16 +170,31 @@ def register(engine):
     _engines.append(weakref.ref(engine))
 
 
-def live():
-    """{program name: {instruction: (scope, phase)}} over the step
-    programs the engines alive in this process have launched, a later
-    engine's program taking the place of an earlier one's of the same
-    name.  Lowers (through the compile cache) each program once per
-    call: for after a measured window, never inside one."""
+def _live(parser):
     maps = {}
     for ref in _engines:
         engine = ref()
         if engine is not None:
-            for name, text in engine.step_programs():
-                maps[name] = parse(text())
+            # the engine's list only grows, a program keeps its place
+            texts = _texts.setdefault(engine, [])
+            for i, (name, text) in enumerate(engine.step_programs()):
+                if i == len(texts):
+                    texts.append(text())
+                maps[name] = parser(texts[i])
     return maps
+
+
+def live():
+    """{program name: {instruction: (scope, phase)}} over the step
+    programs the engines alive in this process have launched, a later
+    engine's program taking the place of an earlier one's of the same
+    name.  Lowers (through the compile cache) each program once, at the
+    first call of this or of ``live_parts``: for after a measured
+    window, never inside one."""
+    return _live(parse)
+
+
+def live_parts():
+    """{program name: {instruction: part or None}} over the same
+    programs, from the same texts."""
+    return _live(parse_parts)

@@ -102,6 +102,15 @@ def _tree_cast(tree, dtype):
             x.dtype, jnp.floating) else x, tree)
 
 
+def _cast_weights(params, dtype):
+    """``_tree_cast`` of a grad program's weights, under the name that
+    profiling/scope_map.py reads as part ``cast``: once a micro-batch
+    the fp32 master weights go to the compute dtype, outside every scope
+    of the model."""
+    with jax.named_scope(scope_map.CAST_SCOPE):
+        return _tree_cast(params, dtype)
+
+
 def resolve_mesh_ctx(config, mesh) -> MeshContext:
     """Resolve the engine's MeshContext from (in order) an explicit `mesh`
     argument, the global registry, or the config's "mesh" block.  Only the
@@ -385,10 +394,12 @@ class DeepSpeedEngine:
         self._fused_sent_state = ()
         self._fused_pending_flags = []
         self.fused_step_reason = None
-        # telemetry provenance: XLA dispatches issued per optimizer step
-        # (gas grad programs + gas-1 accumulation adds + 1 apply); the
-        # fused build overrides this to 1
-        self._dispatches_per_step = 2 * self.gradient_accumulation_steps()
+        # telemetry provenance: step programs launched since the last
+        # optimizer step (_launch counts; _monitor_counters reads and
+        # clears).  Modular: gas grad programs + gas-1 accumulation adds
+        # + 1 apply; fused: 1.  The one-operation programs of the eager
+        # rng split (_next_rng) are no step programs and are not in it.
+        self._launches = 0
         if self.config.fused_step_config.enabled:
             from .fused_step import (build_fused_step, fused_fallback_reason,
                                      sentinel_state_from_host)
@@ -850,7 +861,7 @@ class DeepSpeedEngine:
                 # interleaved per tick and cannot be split into jax's
                 # forward-then-backward phases without losing the 1F1B
                 # memory bound.
-                cp = _tree_cast(params, compute_dtype)
+                cp = _cast_weights(params, compute_dtype)
                 loss, grads = custom_grad_program(
                     cp, scaler_state.loss_scale, rng, *args, **kwargs)
                 if prescale and predivide:
@@ -858,7 +869,7 @@ class DeepSpeedEngine:
                 return loss, _grads_out(grads)
 
             def loss_fn(p):
-                cp = _tree_cast(p, compute_dtype)
+                cp = _cast_weights(p, compute_dtype)
                 if moe_stats:
                     # tap installed in the SAME trace scope as the gate
                     # emissions (moe/sharded_moe.py); the summed pytree
@@ -927,7 +938,7 @@ class DeepSpeedEngine:
                         r = jax.random.fold_in(r, lax.axis_index(ax))
 
                     def loss_fn(pp):
-                        cp = _tree_cast(pp, compute_dtype)
+                        cp = _cast_weights(pp, compute_dtype)
                         out = apply_model(cp, r, *rargs, **rkwargs)
                         loss = out[0] if isinstance(out, tuple) else out
                         return loss.astype(jnp.float32) * ls, loss
@@ -1278,7 +1289,7 @@ class DeepSpeedEngine:
                 r = jax.random.fold_in(r, lax.axis_index(axis))
 
                 def loss_fn(pp):
-                    cp = _tree_cast(pp, compute_dtype)
+                    cp = _cast_weights(pp, compute_dtype)
                     out = apply_model(cp, r, *rargs, **rkwargs)
                     loss = out[0] if isinstance(out, tuple) else out
                     return loss.astype(jnp.float32) * ls, loss
@@ -1493,6 +1504,7 @@ class DeepSpeedEngine:
         are kept, so that step_programs() can give its compiled text."""
         if fn not in self._launched:
             self._launched[fn] = jax.tree.map(_abstract, (args, kwargs))
+        self._launches += 1
         return fn(*args, **kwargs)
 
     def step_programs(self):
@@ -1935,10 +1947,12 @@ class DeepSpeedEngine:
         return records
 
     def _monitor_counters(self) -> Dict[str, Any]:
-        """Host-side integers only — free to copy every step."""
+        """Host-side integers only — free to copy every step.  Called
+        once an optimizer step: it takes the count of launches."""
         from ..monitor import record as mrec
+        launches, self._launches = self._launches, 0
         counters = {mrec.F_SKIPPED_STEPS: self.skipped_steps,
-                    mrec.F_DISPATCHES_PER_STEP: self._dispatches_per_step}
+                    mrec.F_DISPATCHES_PER_STEP: launches}
         if self.sentinel is not None:
             c = self.sentinel.counters()
             counters[mrec.F_SENTINEL_ANOMALIES] = c["anomalies_seen"]

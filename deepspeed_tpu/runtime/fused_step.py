@@ -292,9 +292,6 @@ def build_fused_step(engine, onebit=None):
     engine._fused_step_raw = fused_step
     engine._fused_donate_argnums = (0, 1)
     engine._fused_scan_info = {"gas_scan_length": gas}
-    # telemetry provenance (monitor/record.py dispatches_per_step): the
-    # fused path is ONE dispatch where the modular loop issues 2*gas
-    engine._dispatches_per_step = 1
     out_shardings = (engine.param_shardings, engine.opt_shardings,
                      replicated, sent_shardings, replicated, replicated,
                      (replicated, replicated))

@@ -321,6 +321,22 @@ def test_monitor_on_fused_step_audit_clean(tmp_path):
     assert all(r[R.F_DISPATCHES_PER_STEP] == 1 for r in steps)
 
 
+def test_dispatches_per_step_counts_the_launches_of_step_programs(tmp_path):
+    """The counter is counted where the engine launches a step program,
+    not computed from the configuration: gas grad programs, gas - 1
+    accumulates and one apply; the eager programs of the rng split are
+    not in it."""
+    engine = _engine(tmp_path, gas=3, monitor={"writers": ["jsonl"]})
+    _run_steps(engine, 2, gas=3)
+    engine.monitor.close()
+    recs = [json.loads(line) for line in open(engine.monitor.jsonl_path)]
+    steps = [r for r in recs if r.get(R.F_KIND) == KIND_STEP]
+    assert [r[R.F_DISPATCHES_PER_STEP] for r in steps] == [6, 6]
+    names = sorted(name for name, _ in engine.step_programs())
+    assert names == ["jit_accumulate", "jit_apply_step",
+                     "jit_loss_and_grads"]
+
+
 # --------------------------------------------------------------------- #
 # telemetry overhead bound
 # --------------------------------------------------------------------- #
