@@ -13,7 +13,9 @@ without bias.  By published index i:
               banded grid.  Rotary positions, rotate-half over the first
               ``r`` dimensions of a head: sliding layers all 128 at theta
               10,000; full layers half of them with YaRN frequencies and
-              the attention factor on cos and sin.  ``g = sigmoid(u Wg)``
+              the attention factor on cos and sin; each element widened
+              to float32, multiplied by float32 tables, rounded once.
+              ``g = sigmoid(u Wg)``
               with ``Wg [hidden, heads]`` scales each head's output
               before the output projection.
   FFN         ``mlp_only_layers`` (layer 0) a dense gated FFN; the others
@@ -26,7 +28,15 @@ head count, FFN kind) are one stacked group run by one body; a cut of
 the model is ``num_hidden_layers`` (the first layers, which keep their
 published indices), ``experts_held`` (first, count) and ``vocab_size``.
 The rotary tables are built once a step outside the bodies, in float32.
-The head is its own matrix, not the embedding table.
+Where a head is one lane tile of 128 and the sequence is whole blocks
+(ops/rotary.py ``rotary_block``: the shape decides, and the stack's
+plan says which), q and k are rotated by ``rotate_qkv``, one Pallas pass
+that reads the QKV product flat and writes q, k and v head-major for the
+flash kernels, the float32 values in registers and the tables per lane;
+its backward pass is the same pass the other way.  Elsewhere
+``apply_rotary`` on the transposed halves, which is also the plain
+definition the tests hold the kernels to; its float32 tensors are
+XLA's to place.  The head is its own matrix, not the embedding table.
 """
 
 import math
@@ -43,6 +53,7 @@ from ..monitor import record as R
 from ..ops.flash_attention import flash_attention
 from ..ops.fused_cross_entropy import fused_linear_cross_entropy
 from ..ops.normalize import rms_norm
+from ..ops.rotary import lane_tables, rotary_block, rotate_qkv
 from ..runtime.activation_checkpointing.checkpointing import (
     checkpoint_layers, stack_plan_line)
 from ..utils.logging import log_dist
@@ -85,7 +96,8 @@ def rotary_table(seq: int, inv_freq, attention_factor: float = 1.0):
 
 def apply_rotary(x, table):
     """x [B, heads, S, D]: rotate-half pairing (i, i + r/2) over the first
-    r = 2 x table width dimensions, the rest unchanged; float32 inside."""
+    r = 2 x table width dimensions, the rest unchanged; float32 inside.
+    The plain form: ops/rotary.py does the same where its kernels run."""
     cos, sin = table
     half = cos.shape[-1]
     xf = x.astype(jnp.float32)
@@ -258,13 +270,15 @@ class LagunaModel:
         return sum(math.prod(x.shape) for x in jax.tree.leaves(shapes))
 
     # -- the layer ------------------------------------------------------ #
-    def rotary_tables(self, seq):
-        """{attention kind: (cos, sin)} for ``seq`` positions, float32."""
+    def rotary_tables(self, seq, lanes=False):
+        """{attention kind: (cos, sin)} for ``seq`` positions, float32
+        [seq, r / 2]; ``lanes``: (cos, sin, r / 2) with the tables per
+        lane of a head, as ops/rotary.py's kernels read them."""
         cfg = self.config
         dim = cfg.head_dim
         rotated = int(dim * cfg.full_partial_rotary_factor)
         i = jnp.arange(dim // 2, dtype=jnp.float32)
-        return {
+        tables = {
             SLIDING: rotary_table(
                 seq, cfg.sliding_rope_theta ** (-2.0 * i / dim)),
             FULL: rotary_table(seq, yarn_inv_freq(
@@ -272,6 +286,19 @@ class LagunaModel:
                 cfg.yarn_original_max_position_embeddings,
                 cfg.yarn_beta_fast, cfg.yarn_beta_slow),
                 cfg.yarn_attention_factor)}
+        if lanes:
+            tables = {kind: (*lane_tables(cos, sin, dim), cos.shape[-1])
+                      for kind, (cos, sin) in tables.items()}
+        return tables
+
+    def rotary_plan(self, seq):
+        """{attention kind: (positions, heads) of the rotary kernels'
+        block, or None where ``apply_rotary`` runs}: the shape decides
+        (ops/rotary.py rotary_block)."""
+        cfg = self.config
+        return {kind: rotary_block(seq, cfg.head_dim, heads,
+                                   cfg.num_key_value_heads)
+                for _, kind, heads, _ in cfg.layer_plan()}
 
     def _attention(self, p, u, kind, heads, table):
         cfg = self.config
@@ -282,20 +309,28 @@ class LagunaModel:
         with jax.named_scope("attn"):
             with jax.named_scope("attn_qkv"):
                 qkv = u @ p["qkv_w"]
-                q, k, v = jnp.split(qkv, [heads * dim, (heads + kv) * dim],
-                                    axis=-1)
-
-            def by_head(t, n):
-                with jax.named_scope("attn_layout"):
-                    return t.reshape(batch, seq, n, dim).transpose(0, 2, 1, 3)
-
-            def rotary(t):
+            if rotary_block(seq, dim, heads, kv) is not None:
+                # the split, the head transpose and the rotation in one
+                # pass over qkv (ops/rotary.py); `table` its lane tables
                 with jax.named_scope("attn_rotary"):
-                    return apply_rotary(t, table)
+                    q, k, v = rotate_qkv(qkv, *table, heads, kv)
+            else:
+                with jax.named_scope("attn_qkv"):
+                    q, k, v = jnp.split(
+                        qkv, [heads * dim, (heads + kv) * dim], axis=-1)
 
-            q = rotary(by_head(q, heads))
-            k = rotary(by_head(k, kv))
-            v = by_head(v, kv)
+                def by_head(t, n):
+                    with jax.named_scope("attn_layout"):
+                        return t.reshape(batch, seq, n, dim).transpose(
+                            0, 2, 1, 3)
+
+                def rotary(t):
+                    with jax.named_scope("attn_rotary"):
+                        return apply_rotary(t, table)
+
+                q = rotary(by_head(q, heads))
+                k = rotary(by_head(k, kv))
+                v = by_head(v, kv)
             window = cfg.sliding_window
             banded = {"window": window, "block_q": window,
                       "block_k": window} if kind == SLIDING else {}
@@ -345,7 +380,9 @@ class LagunaModel:
         cfg = self.config
         with jax.named_scope("embed"):
             h = params["wte"].astype(cfg.dtype)[input_ids]
-        tables = self.rotary_tables(input_ids.shape[1])
+        seq = input_ids.shape[1]
+        rotary = self.rotary_plan(seq)
+        tables = self.rotary_tables(seq, lanes=all(rotary.values()))
         groups, sparse_before = [], 0
         for name, kind, heads, sparse, _, count in cfg.groups():
             forced = None
@@ -363,7 +400,10 @@ class LagunaModel:
 
         tokens = h.shape[0] * h.shape[1]
         plan = {**self.stack_plan(),
-                R.M_STACK_DISPATCH_ROWS: self.moe.capacity(tokens)}
+                R.M_STACK_DISPATCH_ROWS: self.moe.capacity(tokens),
+                R.M_STACK_ROTARY: tuple(
+                    (kind, "kernel", *block) if block else (kind, "xla")
+                    for kind, block in rotary.items())}
         if cfg.activation_checkpointing:
             wrap = checkpoint_layers(
                 [(body, xs) for body, xs, _, _ in groups],

@@ -117,6 +117,9 @@ M_STACK_EXPERTS_HELD = "stack_experts_held"
 # rows of a sparse layer's row buffers: the held experts' even share of
 # the picks, which the dispatch walks in as many chunks as it takes
 M_STACK_DISPATCH_ROWS = "stack_dispatch_rows"
+# which rotation of q and k each attention kind runs: [[kind, "kernel",
+# positions, heads a block]] (ops/rotary.py) or [[kind, "xla"]]
+M_STACK_ROTARY = "stack_rotary"
 
 # ---- fleet field names (fleet.py / health.py payloads) --------------- #
 FL_WINDOW_START = "window_start_step"

@@ -329,6 +329,12 @@ def stack_plan_line(plan: Dict[str, Any]) -> str:
         first, count, of = plan[R.M_STACK_EXPERTS_HELD]
         line += (f"; routed experts {first} to {first + count - 1} of {of} "
                  "held here")
+    if R.M_STACK_ROTARY in plan:
+        line += "; rotary: " + ", ".join(
+            f"{kind} {path}" + (
+                f" (blocks of {block[0]} positions x {block[1]} heads)"
+                if block else "")
+            for kind, path, *block in plan[R.M_STACK_ROTARY])
     return line
 
 
