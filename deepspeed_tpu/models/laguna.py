@@ -108,6 +108,61 @@ def apply_rotary(x, table):
         axis=-1).astype(x.dtype)
 
 
+def gated_ffn(p, u):
+    """A dense gated FFN, ``(silu(u Wg) * (u Wu)) Wd`` with gate and up
+    in one matrix ``w1`` (gate first), under scope ``mlp``."""
+    with jax.named_scope("mlp"):
+        gate, up = jnp.split(u @ p["w1"], 2, axis=-1)
+        return (up * jax.nn.silu(gate)) @ p["w2"]
+
+
+class ExpertStack:
+    """What the models of stacked layer groups around a ``DroplessMoE``
+    share (``self.config``, ``self.moe``): the engine's byte budget and
+    the wrapper every group's body runs in."""
+
+    def __init__(self, config, moe):
+        self.config = config
+        self.moe = moe
+        self._remat_budget = None
+        self._stack_plan_logged = None
+
+    def install_remat_budget(self, budget) -> None:
+        """Engine hook: the bytes the layer groups' checkpointing may
+        spend on saved residuals (checkpointing.RematBudget)."""
+        self._remat_budget = budget
+
+    def experts_held(self):
+        """(first, count) of the routed experts this program holds."""
+        return self.config.experts_held
+
+    def _layer_wrapper(self, groups, h, plan):
+        """What every body of ``groups`` ([(body, stacked xs, ...)]) is
+        wrapped in before it runs on ``h``: the byte budget's checkpoint
+        policy where ``config.activation_checkpointing`` says so, or
+        nothing.  ``plan`` (the model's M_STACK_* fields) gets the sparse
+        FFN's row buffers and goes to the budget's log line, or is logged
+        here, once, where no budget will."""
+        cfg = self.config
+        tokens = h.shape[0] * h.shape[1]
+        plan = {**plan, R.M_STACK_DISPATCH_ROWS: self.moe.capacity(tokens)}
+        if cfg.activation_checkpointing:
+            wrap = checkpoint_layers(
+                [group[:2] for group in groups],
+                self._remat_budget, h, cfg.vocab_size, plan,
+                extra_working_set=self.moe.working_set_bytes(
+                    tokens, h.dtype.itemsize))
+        else:
+            def wrap(body):
+                return body
+        budget = self._remat_budget
+        if ((budget is None or budget.bytes_limit is None)
+                and plan != self._stack_plan_logged):
+            self._stack_plan_logged = plan
+            log_dist(stack_plan_line(plan), ranks=[0])
+        return wrap
+
+
 @dataclass
 class LagunaConfig:
     vocab_size: int = 100352
@@ -190,30 +245,18 @@ class LagunaConfig:
         return [tuple(r) for r in runs]
 
 
-class LagunaModel:
+class LagunaModel(ExpertStack):
     """The decoder over stacked groups of like layers; trained through
     ``deepspeed_tpu.initialize`` like GPT2Model."""
 
     def __init__(self, config: LagunaConfig):
-        self.config = config
-        self.moe = DroplessMoE(
+        super().__init__(config, DroplessMoE(
             config.hidden_size, config.num_experts,
             config.num_experts_per_tok, config.moe_intermediate_size,
             config.shared_expert_intermediate_size, score="sigmoid",
             renormalize=True, scale=config.moe_routed_scaling_factor,
             experts_held=config.experts_held,
-            init_std=config.initializer_range)
-        self._remat_budget = None
-        self._stack_plan_logged = None
-
-    def install_remat_budget(self, budget) -> None:
-        """Engine hook: the bytes the layer groups' checkpointing may
-        spend on saved residuals (checkpointing.RematBudget)."""
-        self._remat_budget = budget
-
-    def experts_held(self):
-        """(first, count) of the routed experts this program holds."""
-        return self.config.experts_held
+            init_std=config.initializer_range))
 
     # -- parameters ---------------------------------------------------- #
     def _init_layer(self, rng, heads, sparse):
@@ -346,10 +389,7 @@ class LagunaModel:
             with jax.named_scope("attn_out"):
                 return a.reshape(batch, seq, heads * dim) @ p["out_w"]
 
-    def _dense_ffn(self, p, u):
-        with jax.named_scope("mlp"):
-            gate, up = jnp.split(u @ p["w1"], 2, axis=-1)
-            return (up * jax.nn.silu(gate)) @ p["w2"]
+    _dense_ffn = staticmethod(gated_ffn)
 
     def _layer(self, p, x, kind, heads, sparse, table, picks=None):
         """(layer output, the sparse FFN's Routing or None)."""
@@ -398,27 +438,10 @@ class LagunaModel:
 
             groups.append((body, (params[name], forced), sparse, count))
 
-        tokens = h.shape[0] * h.shape[1]
-        plan = {**self.stack_plan(),
-                R.M_STACK_DISPATCH_ROWS: self.moe.capacity(tokens),
-                R.M_STACK_ROTARY: tuple(
-                    (kind, "kernel", *block) if block else (kind, "xla")
-                    for kind, block in rotary.items())}
-        if cfg.activation_checkpointing:
-            wrap = checkpoint_layers(
-                [(body, xs) for body, xs, _, _ in groups],
-                self._remat_budget, h, cfg.vocab_size, plan,
-                extra_working_set=self.moe.working_set_bytes(
-                    tokens, h.dtype.itemsize))
-        else:
-            def wrap(body):
-                return body
-        budget = self._remat_budget
-        if ((budget is None or budget.bytes_limit is None)
-                and plan != self._stack_plan_logged):
-            self._stack_plan_logged = plan
-            log_dist(stack_plan_line(plan), ranks=[0])
-
+        wrap = self._layer_wrapper(groups, h, {
+            **self.stack_plan(), R.M_STACK_ROTARY: tuple(
+                (kind, "kernel", *block) if block else (kind, "xla")
+                for kind, block in rotary.items())})
         kept = []
         for body, xs, sparse, count in groups:
             # a group of several layers is scanned: one traced body

@@ -17,7 +17,11 @@ The row buffers hold the held experts' even share of the picks
 the routed rows are walked in chunks of that many, as many as they take
 (a trip count made on the device: none where no pick landed here, one
 under an even router, ``num_experts / count`` where every pick did), so
-that nothing is dropped and no shape is dynamic.
+that nothing is dropped and no shape is dynamic.  A layer built with
+``first_chunk_always`` walks its first chunk whatever the counts and
+leaves the chunks beyond it to the trip count: a trip costs what its
+buffers cost, not what its rows do, so a rank whose experts get no pick
+in one step and a stray one in the next pays by the step otherwise.
 
 The older ``MOELayer`` (top-1 / top-2 with a capacity and drops) is
 untouched beside it.
@@ -53,19 +57,25 @@ class Routing(NamedTuple):
 
 def route_topk(logits, k: int, score: str = "sigmoid",
                renormalize: bool = True, scale: float = 1.0,
-               picks=None) -> Routing:
+               picks=None, bias=None) -> Routing:
     """k of E without a capacity.  ``logits`` f32 [T, E]; ``score``:
     "sigmoid" or "softmax" over the E; ``renormalize``: the k picked
     scores are divided by their sum; ``scale`` multiplies the weights.
     ``picks`` (int32 [T, k]) replaces the choice and keeps everything
-    else: the weights are the scores at those picks."""
+    else: the weights are the scores at those picks.  ``bias`` (f32 [E])
+    is added to the scores for the choice alone: the k largest of
+    ``scores + bias`` are picked, ``scores`` and the weights are what
+    they are without it, and no gradient reaches it (a choice has
+    none)."""
     if score not in ("sigmoid", "softmax"):
         raise ValueError(f"score must be sigmoid or softmax, got {score!r}")
     logits = logits.astype(jnp.float32)
     scores = (jax.nn.sigmoid(logits) if score == "sigmoid"
               else jax.nn.softmax(logits, axis=-1))
     if picks is None:
-        _, picks = jax.lax.top_k(scores, k)
+        chosen_by = scores if bias is None else (
+            scores + jax.lax.stop_gradient(bias.astype(jnp.float32)))
+        _, picks = jax.lax.top_k(chosen_by, k)
     picks = checkpoint_name(picks.astype(jnp.int32), PICKS_NAME)
     picked = jnp.take_along_axis(scores, picks, axis=-1)
     weights = picked / jnp.sum(picked, axis=-1, keepdims=True) if (
@@ -163,6 +173,17 @@ class _Chunks(NamedTuple):
     expert: GatedExpertMLP
     k: int
     capacity: int
+    first_always: bool = False
+
+
+def _walk(chunks: _Chunks, trips, chunk, init):
+    """``chunk(c, carry)`` over the ``trips`` chunks that hold the routed
+    rows, from ``init``.  With ``first_always`` chunk 0 runs outside the
+    loop, rows or none (its sums start from zeros that XLA folds away),
+    and the loop takes the chunks beyond it."""
+    if chunks.first_always:
+        return jax.lax.fori_loop(1, trips, chunk, chunk(0, init))
+    return jax.lax.fori_loop(0, trips, chunk, init)
 
 
 def _padded_order(order, capacity):
@@ -182,7 +203,7 @@ def _routed_experts(chunks: _Chunks, x, params, weights, order, position,
     the experts' stacked weights, ``weights`` f32 [T, k], ``order`` /
     ``position`` of ``sort_by_expert`` (position as [T, k]), ``counts``
     [G] rows a held expert -> [T, d] in x's dtype, summed in float32."""
-    expert, k, capacity = chunks
+    expert, k, capacity = chunks[:3]
     order = _padded_order(order, capacity)
 
     def chunk(c, acc):
@@ -196,8 +217,8 @@ def _routed_experts(chunks: _Chunks, x, params, weights, order, position,
         with jax.named_scope("dispatch"):
             return _tokens_from_rows(out, position, start, weights, acc)
 
-    y = jax.lax.fori_loop(0, dispatch_chunks(counts, capacity), chunk,
-                          jnp.zeros(x.shape, jnp.float32))
+    y = _walk(chunks, dispatch_chunks(counts, capacity), chunk,
+              jnp.zeros(x.shape, jnp.float32))
     return y.astype(x.dtype)
 
 
@@ -208,7 +229,7 @@ def _routed_experts_fwd(chunks, x, params, weights, order, position, counts):
 
 
 def _routed_experts_bwd(chunks, res, g):
-    expert, k, capacity = chunks
+    expert, k, capacity = chunks[:3]
     x, params, weights, order, position, counts = res
     order = _padded_order(order, capacity)
 
@@ -245,8 +266,8 @@ def _routed_experts_bwd(chunks, res, g):
             dx = _tokens_from_rows(drows, position, start, None, dx)
         return dx, dparams, dweights
 
-    dx, dparams, dweights = jax.lax.fori_loop(
-        0, dispatch_chunks(counts, capacity), chunk,
+    dx, dparams, dweights = _walk(
+        chunks, dispatch_chunks(counts, capacity), chunk,
         jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.float32),
                      (x, params, weights)))
     return (dx.astype(x.dtype),
@@ -266,7 +287,8 @@ class DroplessMoE:
                  score: str = "sigmoid", renormalize: bool = True,
                  scale: float = 1.0,
                  experts_held: Optional[Tuple[int, int]] = None,
-                 init_std: float = 0.02):
+                 init_std: float = 0.02, selection_bias: bool = False,
+                 first_chunk_always: bool = False):
         first, count = experts_held or (0, num_experts)
         if not (0 <= first and count >= 1 and first + count <= num_experts):
             raise ValueError(
@@ -281,6 +303,12 @@ class DroplessMoE:
         self.shared = (GatedExpertMLP(hidden_size, shared_ff_size, init_std)
                        if shared_ff_size else None)
         self.init_std = init_std
+        # a float32 leaf [E] beside the router's matrix, added to the
+        # scores for the choice alone (route_topk); no gradient moves it:
+        # whoever trains the layer moves it from the experts' counts
+        self.selection_bias = selection_bias
+        # the walk's first chunk runs whatever the counts (``_walk``)
+        self.first_chunk_always = first_chunk_always
 
     def _check_mesh(self):
         ctx = mesh_mod.get_mesh_context(required=False)
@@ -303,6 +331,8 @@ class DroplessMoE:
             "experts": jax.vmap(self.expert.init_params)(keys)}
         if self.shared is not None:
             params["shared"] = self.shared.init_params(k_shared)
+        if self.selection_bias:
+            params["bias"] = jnp.zeros((self.num_experts,), jnp.float32)
         return params
 
     def param_partition_specs(self, params=None):
@@ -316,6 +346,11 @@ class DroplessMoE:
         experts' even share of the picks (``dispatch_capacity``)."""
         return dispatch_capacity(tokens, self.k, self.experts_held[1],
                                  self.num_experts)
+
+    def chunks_walked(self, counts, tokens: int):
+        """Trips of the walk over ``counts`` [G] rows a held expert."""
+        trips = dispatch_chunks(counts, self.capacity(tokens))
+        return jnp.maximum(trips, 1) if self.first_chunk_always else trips
 
     def working_set_bytes(self, tokens: int, itemsize: int) -> int:
         """Bytes of the rows one layer holds at once, forward or backward:
@@ -337,7 +372,8 @@ class DroplessMoE:
                              params["router"].astype(jnp.float32),
                              precision=jax.lax.Precision.HIGHEST)
             return route_topk(logits, self.k, self.score, self.renormalize,
-                              self.scale, picks)._replace(inputs=x)
+                              self.scale, picks, params.get("bias")
+                              )._replace(inputs=x)
 
     def stats(self, routing: Routing) -> RoutingStats:
         """The layer's RoutingStats: every pick is routed (none dropped);
@@ -347,9 +383,8 @@ class DroplessMoE:
         first, count = self.experts_held
         return _routing_stats(
             share, routing.counts, routing.counts, mass, jnp.float32(0.0),
-            held=self.experts_held, chunks=dispatch_chunks(
-                routing.counts[first:first + count],
-                self.capacity(share.shape[0])))
+            held=self.experts_held, chunks=self.chunks_walked(
+                routing.counts[first:first + count], share.shape[0]))
 
     def apply(self, params, x, picks=None):
         """x [..., d] -> (y [..., d], Routing).  ``picks`` forces the
@@ -363,7 +398,8 @@ class DroplessMoE:
         with jax.named_scope("dispatch"):
             order, position = sort_by_expert(routing.picks, first, count)
         y = _routed_experts(
-            _Chunks(self.expert, k, self.capacity(tokens)), x,
+            _Chunks(self.expert, k, self.capacity(tokens),
+                    self.first_chunk_always), x,
             params["experts"], routing.weights, order,
             position.reshape(tokens, k), routing.counts[first:first + count])
         if self.shared is not None:

@@ -74,6 +74,12 @@ class RoutingStats(NamedTuple):
     #                               to move the routed rows (one, unless
     #                               the layer sizes its buffers under the
     #                               worst case: moe/dropless.py)
+    # What a model fills when it emits ONE entry for all its gates
+    # (models/glm4_moe_lite.py); None elsewhere, which is no leaf, so
+    # every other model's programs are what they were.
+    layer_counts: Optional[jnp.ndarray] = None  # f32[L, E] picks an
+    #                               expert, a row a gate: what a selection
+    #                               bias is moved by after the step
 
 
 def _routing_stats(gates, wanted_counts, routed_counts, topk_mass,

@@ -115,6 +115,10 @@ def summarize_window(raw: Dict[str, Any]) -> Optional[Dict[str, Any]]:
     chunks = raw.get("dispatch_chunks")
     if chunks is not None:
         summary[R.M_DISPATCH_CHUNKS] = round(_f(chunks) / layers, 4)
+    # what the model counts of its own (engine.model_counters(): the
+    # names are the model's, averaged over the window's micro-batches)
+    for name, value in (raw.get("model_counters") or {}).items():
+        summary[name] = round(value, 6)
     return summary
 
 

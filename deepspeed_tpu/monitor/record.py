@@ -120,6 +120,12 @@ M_STACK_DISPATCH_ROWS = "stack_dispatch_rows"
 # which rotation of q and k each attention kind runs: [[kind, "kernel",
 # positions, heads a block]] (ops/rotary.py) or [[kind, "xla"]]
 M_STACK_ROTARY = "stack_rotary"
+# (models/glm4_moe_lite.py) latent attention's widths: [query latent,
+# key/value latent, unrotated and rotated dimensions of a query and key
+# head, value head, heads]
+M_STACK_LATENT = "stack_latent_attention"
+# multi-token prediction: [modules, the weight of their loss]
+M_STACK_MTP = "stack_prediction_modules"
 
 # ---- fleet field names (fleet.py / health.py payloads) --------------- #
 FL_WINDOW_START = "window_start_step"
@@ -179,6 +185,12 @@ M_HELD_ROWS_MAX = "held_rows_max"      # rows of the busiest held expert
 M_HELD_ROWS_MEAN = "held_rows_mean"    # rows a held expert, on average
 M_HELD_PICK_SHARE = "held_pick_share"  # of the k x tokens picks: landed here
 M_DISPATCH_CHUNKS = "dispatch_chunks"  # passes over the row buffers, a layer
+# (a model that emits them: models/glm4_moe_lite.py) picks of the busiest
+# of ALL experts over the mean, averaged over gates and steps; the
+# objective's two terms, averaged over micro-batches
+M_LOAD_MAX_OVER_MEAN = "load_max_over_mean"
+M_MAIN_LOSS = "main_loss"
+M_MTP_LOSS = "mtp_loss"
 M_POPULARITY = "popularity"        # embedded ExpertPopularitySnapshot
 
 # ---- reconciliation field names (reconcile.py payload) --------------- #

@@ -30,9 +30,13 @@ One level further down, ``part`` names what an instruction does INSIDE
 its scope (``PARTS``: the name the program writes with
 ``jax.named_scope``, the part it means, the scopes it may follow):
 
-  qkv, rotary, layout, core, gate, diff, out
-         the parts of ``attn``: the input projections with their bias
-         and split; the rotary embedding of q and k; the reshapes and
+  latent, qkv, rotary, layout, core, gate, diff, out
+         the parts of ``attn``: the down-projections to the query and
+         key/value latents and the latents' norms (latent attention:
+         models/glm4_moe_lite.py, where ``qkv`` is the two
+         up-projections and ``layout`` holds the broadcast of the one
+         rotated key and the joins); the input projections with their
+         bias and split; the rotary embedding of q and k; the reshapes and
          transposes to and from the kernels' head-major layout
          (``layout`` also counts in scope ``layer``, where
          ops/transformer.py leaves the context's transpose back); the
@@ -58,7 +62,14 @@ A part counts only where it follows one of its scopes on the path with
 no other scope between them, so that a ``gate`` elsewhere is none.
 ``tag`` knows nothing of parts: (scope, phase) are what they were.
 
-``live()`` and ``live_parts()`` are the doors for a reader outside the
+Across the scopes, ``region`` names a whole module of the model whose
+work lies in several of them (``REGIONS``: ``mtp``, a multi-token-
+prediction module: its norms and projection, its block with the block's
+own ``attn`` / ``router`` / ``experts``, its pass over the head): the
+outermost region name anywhere on the path, or None.  ``tag`` and
+``part`` know nothing of regions.
+
+``live()``, ``live_parts()`` and ``live_regions()`` are the doors for a reader outside the
 program (the benchmark's per-layer readers): the maps of the step
 programs of the engines alive in this process.  Engines are held by weak
 reference, nothing is lowered until one of them is called, and a
@@ -80,6 +91,7 @@ PHASES = ("forward", "recompute", "backward")
 CAST_SCOPE = "weight_cast"
 # the name on the path -> (part, the scopes it counts in)
 PARTS = {
+    "attn_latent": ("latent", ("attn",)),
     "attn_qkv": ("qkv", ("attn",)),
     "attn_rotary": ("rotary", ("attn",)),
     "attn_layout": ("layout", ("attn", "layer")),
@@ -89,6 +101,7 @@ PARTS = {
     "attn_out": ("out", ("attn",)),
     CAST_SCOPE: ("cast", (OTHER,)),
 }
+REGIONS = ("mtp",)
 STACK = "stack"
 STACK_OPS = ("dynamic_slice", "squeeze", "broadcast_in_dim",
              "dynamic_update_slice")
@@ -139,6 +152,12 @@ def part(op_name):
     return found
 
 
+def region(op_name):
+    """The region one ``op_name`` path lies in, or None."""
+    return next((c for c in _COMPONENT.split(op_name) if c in REGIONS),
+                None)
+
+
 def _parse(hlo_text, of):
     out = {}
     for line in hlo_text.splitlines():
@@ -162,6 +181,12 @@ def parse_parts(hlo_text):
     """{instruction name: part or None}, over the same instructions as
     ``parse``."""
     return _parse(hlo_text, part)
+
+
+def parse_regions(hlo_text):
+    """{instruction name: region or None}, over the same instructions as
+    ``parse``."""
+    return _parse(hlo_text, region)
 
 
 def register(engine):
@@ -198,3 +223,9 @@ def live_parts():
     """{program name: {instruction: part or None}} over the same
     programs, from the same texts."""
     return _live(parse_parts)
+
+
+def live_regions():
+    """{program name: {instruction: region or None}} over the same
+    programs, from the same texts."""
+    return _live(parse_regions)

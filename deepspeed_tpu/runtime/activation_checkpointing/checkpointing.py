@@ -329,6 +329,15 @@ def stack_plan_line(plan: Dict[str, Any]) -> str:
         first, count, of = plan[R.M_STACK_EXPERTS_HELD]
         line += (f"; routed experts {first} to {first + count - 1} of {of} "
                  "held here")
+    if R.M_STACK_LATENT in plan:
+        q, kv, nope, rope, v, heads = plan[R.M_STACK_LATENT]
+        line += (f"; latent attention: queries through {q}, keys and "
+                 f"values through {kv}, {heads} heads of {nope} + {rope} "
+                 f"rotated (one rotated key a position) and values of {v}")
+    if R.M_STACK_MTP in plan:
+        modules, weight = plan[R.M_STACK_MTP]
+        line += (f"; {modules} multi-token-prediction module(s), loss "
+                 f"weight {weight}")
     if R.M_STACK_ROTARY in plan:
         line += "; rotary: " + ", ".join(
             f"{kind} {path}" + (

@@ -111,6 +111,50 @@ def _cast_weights(params, dtype):
         return _tree_cast(params, dtype)
 
 
+@jax.jit
+def _tree_add(a, b):
+    return jax.tree.map(jnp.add, a, b)
+
+
+def _tree_add_counted(acc, tree):
+    """``(sums, n)`` with one more ``tree`` in it; ``acc`` None: the
+    first."""
+    if acc is None:
+        return tree, 1
+    return _tree_add(acc[0], tree), acc[1] + 1
+
+
+class ExemptLeaves:
+    """Leaves of the parameter tree that the optimizer does not own: a
+    model's ``optimizer_exempt()`` gives ``(mask, update)``, ``mask`` a
+    tree of booleans over its parameters (True: exempt) and ``update`` a
+    pure function ``(leaves, stats) -> leaves`` on the tree with None in
+    place of every other leaf, ``stats`` the RoutingStats of the step
+    summed over its micro-batches."""
+
+    def __init__(self, mask, update):
+        self.mask, self.treedef = jax.tree.flatten(mask)
+        self.update = update
+
+    def select(self, exempt_from, rest_from):
+        """The tree with the exempt leaves of the first, the others of
+        the second."""
+        return self.treedef.unflatten([
+            a if m else b for m, a, b in zip(
+                self.mask, self.treedef.flatten_up_to(exempt_from),
+                self.treedef.flatten_up_to(rest_from))])
+
+    def moved(self, params, stats):
+        """``params`` with ``update`` applied to the exempt leaves."""
+        leaves = self.treedef.flatten_up_to(params)
+        held = self.treedef.unflatten([
+            p if m else None for m, p in zip(self.mask, leaves)])
+        moved = self.treedef.flatten_up_to(self.update(held, stats))
+        return self.treedef.unflatten([
+            new.astype(old.dtype) if m else old
+            for m, old, new in zip(self.mask, leaves, moved)])
+
+
 def resolve_mesh_ctx(config, mesh) -> MeshContext:
     """Resolve the engine's MeshContext from (in order) an explicit `mesh`
     argument, the global registry, or the config's "mesh" block.  Only the
@@ -383,6 +427,21 @@ class DeepSpeedEngine:
         # (step_programs(); profiling/scope_map.py reads them)
         self._launched = {}
         scope_map.register(self)
+        # ---- leaves the optimizer does not own ------------------------ #
+        # A model may declare leaves of its parameter tree that take no
+        # optimizer update and are moved, once an optimizer step and
+        # after it, by a pure function of the step's summed RoutingStats
+        # (``model.optimizer_exempt()``: a selection bias moved by the
+        # experts' counts).  They stay in ``self.params``.
+        self._exempt = self._resolve_optimizer_exempt()
+        self._exempt_stats = None
+        # ---- the model's own counters --------------------------------- #
+        # Scalars of the dict a model's apply returns beside its loss,
+        # named in its ``aux_counters``: they leave the grad program with
+        # the loss, are summed on the device over micro-batches and read
+        # by ``model_counters()`` alone.  The modular loop collects them.
+        self._aux_names = tuple(getattr(self.module, "aux_counters", ()))
+        self._aux_acc = None
         self._build_functions()
 
         # ---- fused whole-step program (off by default) --------------- #
@@ -401,6 +460,8 @@ class DeepSpeedEngine:
         # rng split (_next_rng) are no step programs and are not in it.
         self._launches = 0
         if self.config.fused_step_config.enabled:
+            self._refuse_optimizer_exempt(
+                "fused_step (train_batch's one-dispatch step)")
             from .fused_step import (build_fused_step, fused_fallback_reason,
                                      sentinel_state_from_host)
             reason = fused_fallback_reason(self)
@@ -821,6 +882,22 @@ class DeepSpeedEngine:
             return grads
 
         custom_grad_program = getattr(self, "_custom_grad_program", None)
+        exempt = self._exempt
+        if custom_grad_program is not None:
+            self._refuse_optimizer_exempt(
+                "the pipeline engine's custom grad program")
+        if self._zero3_stream is not None:
+            self._refuse_optimizer_exempt("the streamed ZeRO-3 layer scan")
+        if self._offload_enabled:
+            self._refuse_optimizer_exempt("the host-side offload optimizer")
+        if self._onebit is not None:
+            self._refuse_optimizer_exempt("the 1-bit compressed-phase step")
+        if (custom_grad_program is not None or self._onebit is not None
+                or self.config.fused_step_config.enabled
+                or self.config.sparse_gradients_enabled):
+            # these build or schedule a grad program of their own
+            self._aux_names = ()
+        aux_names = self._aux_names
         moe_stats = self._moe_stats_enabled
         if moe_stats and custom_grad_program is not None:
             logger.warning(
@@ -870,7 +947,10 @@ class DeepSpeedEngine:
 
             def loss_fn(p):
                 cp = _cast_weights(p, compute_dtype)
-                if moe_stats:
+                if exempt is not None:
+                    # the model reads these as they are stored
+                    cp = exempt.select(p, cp)
+                if moe_stats or exempt is not None:
                     # tap installed in the SAME trace scope as the gate
                     # emissions (moe/sharded_moe.py); the summed pytree
                     # rides out as a grad aux output — pure device math,
@@ -889,16 +969,18 @@ class DeepSpeedEngine:
                     loss = out[0]
                 else:
                     loss = out
+                aux = {name: out[1][name].astype(jnp.float32)
+                       for name in aux_names}
                 scaled = (loss.astype(jnp.float32) *
                           scaler_state.loss_scale)
-                return scaled, (loss, stats)
-            (_, (loss, stats)), grads = jax.value_and_grad(
+                return scaled, (loss, stats, aux)
+            (_, (loss, stats, aux)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
             if prescale and predivide:
                 grads = jax.tree.map(lambda g: g / predivide, grads)
-            if moe_stats:
-                return loss, _grads_out(grads), stats
-            return loss, _grads_out(grads)
+            extras = ((stats,) if moe_stats or exempt is not None else ()) + (
+                (aux,) if aux_names else ())
+            return (loss, _grads_out(grads)) + extras
 
         from ..parallel.mesh import ZERO_AXES
         manual = tuple(a for a in ZERO_AXES
@@ -908,6 +990,8 @@ class DeepSpeedEngine:
             # engine.py:1729-1792): each shard ships (token indices, touched
             # rows) and every shard scatter-adds the gathered pairs — comm
             # volume O(batch·seq·hidden·dp) instead of O(vocab·hidden).
+            self._refuse_optimizer_exempt(
+                "the sparse_gradients shard_map region")
             if moe_stats:
                 logger.warning(
                     "monitor.moe: the sparse_gradients shard_map region "
@@ -995,10 +1079,12 @@ class DeepSpeedEngine:
         # body (runtime/fused_step.py) — one definition, two compilations
         self._loss_and_grads = loss_and_grads
         grad_out_shardings = (replicated, self.grad_shardings)
-        if moe_stats:
+        if moe_stats or exempt is not None:
             # the RoutingStats aux (a prefix `replicated` broadcasts
             # over the pytree — or over None when the model has no MoE
             # layers, in which case the accumulator simply never fills)
+            grad_out_shardings = grad_out_shardings + (replicated,)
+        if aux_names:
             grad_out_shardings = grad_out_shardings + (replicated,)
         self._grad_fn = jax.jit(
             loss_and_grads, out_shardings=grad_out_shardings)
@@ -1030,7 +1116,8 @@ class DeepSpeedEngine:
             self._apply_core = None
             return
 
-        def apply_step(params, opt_state, scaler_state, grads, healthy=None):
+        def apply_step(params, opt_state, scaler_state, grads, healthy=None,
+                       stats=None):
             inv = 1.0 / (scaler_state.loss_scale * gas)
             grads = jax.tree.map(
                 lambda g: g.astype(jnp.float32) * inv, grads)
@@ -1061,6 +1148,15 @@ class DeepSpeedEngine:
             new_params = jax.tree.map(
                 lambda p, u: p + jnp.where(finite, u, 0).astype(p.dtype),
                 params, updates)
+            if exempt is not None and stats is not None:
+                # after the optimizer and in its place: no decay, no
+                # moment has moved these; a skipped step moves none
+                # (stats is None only in the Program Auditor's lowering)
+                new_params = exempt.select(
+                    jax.tree.map(
+                        lambda old, new: jnp.where(finite, new, old),
+                        params, exempt.moved(params, stats)),
+                    new_params)
             new_opt = jax.tree.map(
                 lambda n, o: jnp.where(finite, n, o), cand_opt, opt_state)
             new_scaler = update_loss_scale(scaler_cfg, scaler_state, overflow)
@@ -1615,15 +1711,22 @@ class DeepSpeedEngine:
                 # compressed phase: local (unreduced) stacked grads — the
                 # dense allreduce left the program at the freeze boundary
                 grad_fn = self._onebit_programs["grad_fn"]
-            with self._span("forward.dispatch",
-                            program=_program_name(grad_fn)):
+            with self._grad_launch(grad_fn), self._span(
+                    "forward.dispatch", program=_program_name(grad_fn)):
                 out = self._launch(grad_fn, self.params, self.scaler_state,
                                    rng, *args, **kwargs)
-            if self._moe_stats_enabled:
-                loss, grads, moe_stats = out
-                self._moe_note_stats(moe_stats)
-            else:
-                loss, grads = out
+            loss, grads, *extras = out
+            if self._exempt is not None or self._moe_stats_enabled:
+                moe_stats = extras.pop(0)
+                if self._exempt is not None:
+                    # before the monitor's accumulator may donate the
+                    # first micro-batch's stats, which this sum reads
+                    self._exempt_note_stats(moe_stats)
+                if self._moe_stats_enabled:
+                    self._moe_note_stats(moe_stats)
+            if self._aux_names:
+                self._aux_acc = _tree_add_counted(self._aux_acc,
+                                                  extras.pop(0))
             if profile_now:
                 jax.block_until_ready(loss)
                 prof.stop_profile()
@@ -1722,12 +1825,21 @@ class DeepSpeedEngine:
             # the sentinel's verdict rides the apply's healthy flag
             verdict = (() if self.sentinel is None
                        else (jnp.asarray(not sentinel_skip),))
+            # the step's RoutingStats, summed over its micro-batches
+            stats, self._exempt_stats = self._exempt_stats, None
+            if self._exempt is not None and stats is None:
+                raise RuntimeError(
+                    "optimizer_exempt(): the model emitted no RoutingStats "
+                    "in this step's grad programs (moe/sharded_moe.py "
+                    "emit_routing_stats), so its exempt leaves cannot be "
+                    "moved")
+            counted = {} if self._exempt is None else {"stats": stats}
             with self._span("step.dispatch",
                             program=_program_name(self._apply_fn)):
                 (self.params, self.opt_state, self.scaler_state,
                  overflow) = self._launch(
                     self._apply_fn, self.params, self.opt_state,
-                    self.scaler_state, self._grad_acc, *verdict)
+                    self.scaler_state, self._grad_acc, *verdict, **counted)
         with self._span("step.bookkeeping"):
             self._after_apply(overflow, sentinel_skip, lr_kwargs)
         if self.wall_clock_breakdown():
@@ -1968,6 +2080,59 @@ class DeepSpeedEngine:
     # ------------------------------------------------------------------ #
     # MoE routing stats accumulator (monitor.moe; docs/telemetry.md)
     # ------------------------------------------------------------------ #
+    # -- a grad program whose launch stalls on the host ----------------- #
+    # Seconds on the host above which the launch of a grad program that is
+    # already compiled has stalled: a dispatch takes 1 to 5 ms, a launch
+    # that has to wait for memory the 30 ms of the apply program before it
+    # or the 0.7 s of a whole grad program (my chip runs, PR 42).
+    LAUNCH_STALL_S = 0.02
+    _launch_stalls = 0
+    _await_loss_before_launch = False
+
+    def _grad_launch(self, grad_fn):
+        """Context of one launch of the grad program: waits for the last
+        loss first where launches have been seen to stall, and times the
+        launch on the host (``_note_grad_launch``)."""
+        # imported here so that no line above ``forward`` moves: the
+        # Mosaic kernels' compile-cache keys carry the line numbers of
+        # the frames they were traced under, this file's among them
+        import contextlib
+        import time
+
+        @contextlib.contextmanager
+        def launch():
+            if (self._await_loss_before_launch
+                    and self._last_loss is not None):
+                jax.block_until_ready(self._last_loss)
+            compiled, started = grad_fn in self._launched, time.perf_counter()
+            yield
+            self._note_grad_launch(compiled, time.perf_counter() - started)
+        return launch()
+
+    def _note_grad_launch(self, compiled: bool, seconds: float) -> None:
+        """The grad program's launch took ``seconds`` on the host.  Two
+        stalled launches in a row mean the device cannot take a second
+        micro-batch's buffers beside the running one's: the runtime then
+        holds each launch until the memory is there, now for one program
+        and now for another, and whoever reads a loss as it becomes ready
+        finds steps that alternate 30 ms long and 30 ms short about an
+        unchanged mean.  From then on forward() waits for the last loss
+        before it launches the next grad program: the launch finds the
+        device at the same place every time, and the wait costs nothing
+        while an apply program, which takes longer than a dispatch, runs
+        behind it."""
+        if self._await_loss_before_launch or not compiled:
+            return
+        stalled = seconds > self.LAUNCH_STALL_S
+        self._launch_stalls = self._launch_stalls + 1 if stalled else 0
+        if self._launch_stalls >= 2:
+            self._await_loss_before_launch = True
+            log_dist(
+                "the grad program's launch stalled on the host twice in a "
+                f"row ({1e3 * seconds:.0f} ms): the device has no room for "
+                "a second micro-batch in flight; waiting for the loss "
+                "before each launch from here on", ranks=[0])
+
     def _moe_note_stats(self, stats) -> None:
         """Fold one dispatch's RoutingStats into the device-resident
         accumulator.  Pure dispatch work: the add is a tiny jitted
@@ -1984,6 +2149,52 @@ class DeepSpeedEngine:
                 lambda a, b: jax.tree.map(jnp.add, a, b),
                 donate_argnums=(0,))
         self._moe_stats_acc = self._moe_acc_fn(self._moe_stats_acc, stats)
+
+    def model_counters(self):
+        """{name: mean} of the scalars the model names in its
+        ``aux_counters``, over the micro-batches run since the last call
+        (one host read, which empties the sums), or None where there are
+        none."""
+        acc, self._aux_acc = self._aux_acc, None
+        if acc is None:
+            return None
+        sums, n = acc
+        return {name: float(v) / n
+                for name, v in jax.device_get(sums).items()}
+
+    def _resolve_optimizer_exempt(self):
+        """The model's ``optimizer_exempt()`` as an ``ExemptLeaves``, or
+        None where it declares none."""
+        declare = getattr(self.module, "optimizer_exempt", None)
+        declared = declare() if callable(declare) else None
+        if declared is None:
+            return None
+        mask, update = declared
+        if jax.tree.structure(mask) != jax.tree.structure(self.params):
+            raise ValueError(
+                "optimizer_exempt(): the mask is not a tree of booleans "
+                "over the model's parameter tree")
+        return ExemptLeaves(mask, update)
+
+    def _refuse_optimizer_exempt(self, path: str) -> None:
+        """One error for every path that cannot thread the step's
+        RoutingStats to the apply program: training such a model there
+        would leave its exempt leaves where they started."""
+        if self._exempt is not None:
+            raise NotImplementedError(
+                f"{type(self.module).__name__} declares leaves the "
+                "optimizer does not own (optimizer_exempt(): moved after "
+                "each optimizer step from the step's routing counts); "
+                f"{path} does not thread those counts to the apply "
+                "program and would train the model with the leaves frozen. "
+                "Use the modular forward / backward / step loop.")
+
+    def _exempt_note_stats(self, stats) -> None:
+        """Sum one micro-batch's RoutingStats into the step's, for the
+        apply program; no donation: the monitor's accumulator may hold
+        the same buffers."""
+        self._exempt_stats = stats if self._exempt_stats is None else (
+            _tree_add(self._exempt_stats, stats))
 
     def _moe_local_expert_slice(self, num_experts: int):
         """(lo, hi) — the contiguous range of expert ids whose parameters
@@ -2045,10 +2256,11 @@ class DeepSpeedEngine:
             logger.warning(f"monitor.moe: stats fetch failed ({e})")
             return None
         raw = {name: np.asarray(v)
-               for name, v in zip(type(acc)._fields, host)}
+               for name, v in zip(type(acc)._fields, host) if v is not None}
         raw["steps"] = max(1, int(steps))
         raw["local_expert_slice"] = self._moe_local_expert_slice(
             int(raw["expert_counts"].shape[0]))
+        raw["model_counters"] = self.model_counters()
         return raw
 
     def _monitor_note_batch(self, tree) -> None:

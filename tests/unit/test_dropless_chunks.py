@@ -132,6 +132,43 @@ def test_chunked_layer_against_the_dense_loop(interpret, case):
                    for g in jax.tree.leaves(got[1][0]["experts"]))
 
 
+@pytest.mark.parametrize("here, trips", [("none", 1), ("a few", 1),
+                                         ("all", 4)])
+def test_first_chunk_always_walks_a_chunk_whatever_the_counts(
+        interpret, here, trips):
+    """The same layer with ``first_chunk_always``: one trip where no pick
+    landed (it adds exact zeros and every expert's gradient is zero), the
+    trips the rows take elsewhere, value and gradients those of the walk
+    by the count alone."""
+    by_count = _layer()
+    always = DroplessMoE(HIDDEN, EXPERTS, K, FF, FF, scale=2.5,
+                         experts_held=HELD, init_std=0.1,
+                         first_chunk_always=True)
+    params, x, cot = _inputs(by_count, TOKENS)
+    rng = np.random.default_rng(1)
+    picks = _picks(TOKENS, {"a few": rng.integers(0, 2, TOKENS),
+                            "all": np.full(TOKENS, K),
+                            "none": np.zeros(TOKENS, int)}[here])
+
+    def run(layer):
+        return jax.jit(jax.value_and_grad(
+            lambda p, x: jnp.sum(layer.apply(p, x, picks)[0] * cot),
+            argnums=(0, 1)))(params, x)
+
+    with jax.default_matmul_precision("highest"):
+        got, want = run(always), run(by_count)
+        routing = always.apply(params, x, picks)[1]
+    assert float(always.stats(routing).dispatch_chunks) == trips
+    assert float(by_count.stats(routing).dispatch_chunks) == (
+        0 if here == "none" else trips)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                    strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if here == "none":
+        assert all(float(jnp.max(jnp.abs(g))) == 0.0
+                   for g in jax.tree.leaves(got[1][0]["experts"]))
+
+
 def test_capacity_is_the_held_experts_even_share_in_whole_tiles():
     # the benchmark's cell: 32 of 256 held, 8 picks of 16,384 tokens
     assert dispatch_capacity(16384, 8, 32, 256) == 16384

@@ -432,7 +432,11 @@ def test_dispatch_chunks_sum_like_held_rows_max():
     from deepspeed_tpu.moe.dropless import DroplessMoE
     from deepspeed_tpu.moe.sharded_moe import (
         RoutingStats, sum_routing_stats, top1gating)
-    assert RoutingStats._fields[-2:] == ("held_rows_max", "dispatch_chunks")
+    # the last two every gate fills; after them what a model that emits
+    # one entry for all its gates adds, None (no leaf) elsewhere
+    assert RoutingStats._fields[-3:] == (
+        "held_rows_max", "dispatch_chunks", "layer_counts")
+    assert RoutingStats._field_defaults == {"layer_counts": None}
     logits = jax.random.normal(jax.random.PRNGKey(0), (32, 4))
     capped = top1gating(logits, capacity_factor=1.0, min_capacity=2)[-1]
     assert float(capped.dispatch_chunks) == 1.0
