@@ -14,12 +14,22 @@ without bias.
               head and on the ONE ``k_rope`` a position, which all heads
               share; ``k = [k_nope | k_rope]``, softmax(q k^T / sqrt(nope
               + rope)) v, causal, through the flash kernels at a head of
-              ``nope + rope`` = ``v_head_dim`` (256 as published).  The
-              rotation is ``apply_rotary`` on the ``rope``-wide slices
-              (ops/rotary.py's kernel takes whole heads of 128 of a
-              fused product; ``rotary_block`` says no here); the one
-              rotated key is broadcast to the heads and joined to
-              ``k_nope`` by XLA, under ``attn_layout``.
+              ``nope + rope`` = ``v_head_dim`` (256 as published).
+              Between the flat products and the kernels' head-major
+              operands, where the shape is one ``latent_block`` takes
+              (ops/latent_layout.py: heads of whole lane tiles whose
+              last 64 lanes turn, an even number of them, whole blocks
+              of positions, a TPU or the interpreter): one pass that
+              rotates, joins the one key to every head and transposes,
+              and one pass back for the context; ``kv_b``'s product is
+              then taken in three column sets so that every head starts
+              on a tile.  Elsewhere (the CPU, the small shapes of the
+              tests and of the parity's reference): ``apply_rotary`` on
+              the ``rope``-wide slices, the one rotated key broadcast to
+              the heads and joined to ``k_nope`` by XLA, under
+              ``attn_layout``.  The shape alone decides; the stack's
+              log line says which (``rotary: latent kernel (...)`` or
+              ``latent xla``).
   FFN         the first ``first_k_dense_replace`` layers a dense gated
               FFN; the others ``moe.DroplessMoE`` with a selection bias:
               sigmoid scores over all E experts in float32, the k
@@ -61,6 +71,8 @@ from ..moe.sharded_moe import emit_routing_stats
 from ..monitor import record as R
 from ..ops.flash_attention import flash_attention
 from ..ops.fused_cross_entropy import fused_linear_cross_entropy
+from ..ops.latent_layout import (heads_to_flat, latent_block, latent_heads,
+                                 latent_tables, split_kv_columns)
 from ..ops.normalize import rms_norm
 from .laguna import (EMBEDDING_STD, ExpertStack, apply_rotary, gated_ffn,
                      rotary_table)
@@ -266,50 +278,82 @@ class Glm4MoeLiteModel(ExpertStack):
         return mask, update
 
     # -- the layer ------------------------------------------------------ #
-    def rotary_plan(self):
-        """Which rotation the latent heads run: the rotated slice is
-        ``qk_rope_head_dim`` wide, no whole head of 128 of a fused
-        product, so ``apply_rotary`` (ops/rotary.py ``rotary_block``
-        gives None for it)."""
-        return (("latent", "xla"),)
-
-    def _attention(self, p, u, table):
+    def latent_block(self, seq):
+        """(positions, heads) of the block in which ops/latent_layout.py's
+        kernels carry q, k and v between the flat products and the flash
+        kernels at ``seq`` positions, or None where XLA does: the shape
+        decides."""
         cfg = self.config
-        batch, seq, _ = u.shape
-        heads, eps = cfg.num_attention_heads, cfg.rms_norm_eps
+        return latent_block(seq, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                            cfg.v_head_dim, cfg.num_attention_heads)
+
+    def rotary_plan(self, seq):
+        """The M_STACK_ROTARY field: which path the latent heads'
+        rotation and layout take."""
+        block = self.latent_block(seq)
+        return ((("latent", "kernel", *block) if block
+                 else ("latent", "xla")),)
+
+    def _heads(self, p, cq, ckv, k_rope, table, kernels):
+        """q, k, v ``[B, H, S, 256]`` from the two latents and the one
+        key a position; ``table`` (cos, sin) per lane of a head's last
+        tile where the ``kernels`` run, else ``rotary_table``'s."""
+        cfg = self.config
+        batch, seq, _ = cq.shape
+        heads = cfg.num_attention_heads
         nope, rope, vdim = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                             cfg.v_head_dim)
+        if kernels:
+            with jax.named_scope("attn_layout"):
+                k_lo, k_hi, v_w = split_kv_columns(p["kv_b"], heads, nope,
+                                                   vdim)
+            with jax.named_scope("attn_qkv"):
+                q = cq @ p["q_b"]
+                k_lo, k_hi, v = ckv @ k_lo, ckv @ k_hi, ckv @ v_w
+            with jax.named_scope("attn_rotary"):
+                return latent_heads(q, k_lo, k_hi, v, k_rope, *table, heads)
 
         def by_head(t, dim):
             with jax.named_scope("attn_layout"):
                 return t.reshape(batch, seq, heads, dim).transpose(0, 2, 1, 3)
 
+        with jax.named_scope("attn_qkv"):
+            q = cq @ p["q_b"]
+            kv = ckv @ p["kv_b"]
+        q = by_head(q, nope + rope)
+        kv = by_head(kv, nope + vdim)
+        with jax.named_scope("attn_rotary"):
+            q_rope = apply_rotary(q[..., nope:], table)
+            # one rotated key a position, for every head
+            k_rope = apply_rotary(k_rope[:, None], table)
+        with jax.named_scope("attn_layout"):
+            q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+            k = jnp.concatenate([
+                kv[..., :nope], jnp.broadcast_to(
+                    k_rope, (batch, heads, seq, rope))], axis=-1)
+            return q, k, kv[..., nope:]
+
+    def _attention(self, p, u, table):
+        cfg = self.config
+        batch, seq, _ = u.shape
+        heads, eps = cfg.num_attention_heads, cfg.rms_norm_eps
+        kernels = self.latent_block(seq) is not None
         with jax.named_scope("attn"):
             with jax.named_scope("attn_latent"):
                 cq = rms_norm(u @ p["q_a"], p["q_norm"], eps)
                 ckv, k_rope = jnp.split(u @ p["kv_a"], [cfg.kv_lora_rank],
                                         axis=-1)
                 ckv = rms_norm(ckv, p["kv_norm"], eps)
-            with jax.named_scope("attn_qkv"):
-                q = cq @ p["q_b"]
-                kv = ckv @ p["kv_b"]
-            q = by_head(q, nope + rope)
-            kv = by_head(kv, nope + vdim)
-            with jax.named_scope("attn_rotary"):
-                q_rope = apply_rotary(q[..., nope:], table)
-                # one rotated key a position, for every head
-                k_rope = apply_rotary(k_rope[:, None], table)
-            with jax.named_scope("attn_layout"):
-                q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
-                k = jnp.concatenate([
-                    kv[..., :nope], jnp.broadcast_to(
-                        k_rope, (batch, heads, seq, rope))], axis=-1)
-                v = kv[..., nope:]
+            q, k, v = self._heads(p, cq, ckv, k_rope, table, kernels)
             with jax.named_scope("attn_core"):
                 a = flash_attention(q, k, v, causal=True,
-                                    sm_scale=1.0 / math.sqrt(nope + rope))
+                                    sm_scale=1.0 / math.sqrt(cfg.head_dim))
             with jax.named_scope("attn_layout"):
-                a = a.transpose(0, 2, 1, 3).reshape(batch, seq, heads * vdim)
+                if kernels:
+                    a = heads_to_flat(a)
+                else:
+                    a = a.transpose(0, 2, 1, 3).reshape(
+                        batch, seq, heads * cfg.v_head_dim)
             with jax.named_scope("attn_out"):
                 return a @ p["out_w"]
 
@@ -327,7 +371,8 @@ class Glm4MoeLiteModel(ExpertStack):
 
     # -- the stack ------------------------------------------------------ #
     def stack_plan(self):
-        """The M_STACK_* fields of this stack."""
+        """The M_STACK_* fields of this stack but the rotation's, which
+        the sequence decides (``rotary_plan``)."""
         cfg = self.config
         layers = [(i, "latent+" + ("experts" if sparse else "dense"), 0)
                   for _, sparse, first, count in cfg.groups()
@@ -342,8 +387,7 @@ class Glm4MoeLiteModel(ExpertStack):
                                cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                                cfg.v_head_dim, cfg.num_attention_heads),
             R.M_STACK_MTP: (cfg.num_nextn_predict_layers,
-                            cfg.mtp_loss_weight),
-            R.M_STACK_ROTARY: self.rotary_plan()}
+                            cfg.mtp_loss_weight)}
 
     def _bodies(self, params, seq, picks, keep):
         """[(body, stacked xs, sparse, layers)] of the stack's groups and
@@ -353,6 +397,8 @@ class Glm4MoeLiteModel(ExpertStack):
         rope = cfg.qk_rope_head_dim
         table = rotary_table(seq, cfg.rope_theta ** (
             -2.0 * jnp.arange(rope // 2, dtype=jnp.float32) / rope))
+        if self.latent_block(seq) is not None:
+            table = latent_tables(*table)
         stacks = [(params[name], sparse, count)
                   for name, sparse, _, count in cfg.groups()]
         if cfg.num_nextn_predict_layers:
@@ -390,8 +436,10 @@ class Glm4MoeLiteModel(ExpertStack):
         wte = params["wte"].astype(cfg.dtype)
         with jax.named_scope("embed"):
             h = wte[input_ids]
-        bodies = self._bodies(params, input_ids.shape[1], picks, keep)
-        wrap = self._layer_wrapper(bodies, h, self.stack_plan())
+        seq = input_ids.shape[1]
+        bodies = self._bodies(params, seq, picks, keep)
+        wrap = self._layer_wrapper(bodies, h, {
+            **self.stack_plan(), R.M_STACK_ROTARY: self.rotary_plan(seq)})
         module = bodies.pop() if cfg.num_nextn_predict_layers else None
         kept = []
         for body, xs, sparse, count in bodies:

@@ -440,3 +440,32 @@ def test_flash_forward_lse_tpu(seq, heads, kv_heads, window, causal):
     want = _reference_lse(q, k, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(lse), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("heads", [2, 20])
+def test_latent_layout_kernels_equal_the_xla_lines_tpu(heads, monkeypatch):
+    """ops/latent_layout.py on the chip, two blocks of positions: q, k
+    and v bit for bit what ``by_head`` + ``apply_rotary`` + the joins
+    give (the TPU contracts no multiply-add), d(q product) too, the one
+    key's cotangent to the plain path's two roundings, the way back and
+    its VJP copies."""
+    from tests.unit import test_latent_layout as unit
+    monkeypatch.setattr(unit, "SEQ", 1024)
+    args = unit._inputs(heads, jnp.bfloat16, seed=heads)
+    cts = unit._cotangents(heads, jnp.bfloat16)
+    ours = jax.jit(unit._kernels, static_argnums=4)(*args, heads)
+    want = jax.jit(unit._plain, static_argnums=4)(*args, heads)
+    for a, b in zip(ours, want):
+        unit._same(a, b)
+    ours_b = unit._pulled(unit._kernels, args, cts, heads)
+    want_b = unit._pulled(unit._plain, args, cts, heads)
+    unit._same(ours_b[0], want_b[0])
+    size = float(jnp.max(jnp.abs(want_b[3])))
+    np.testing.assert_allclose(
+        np.asarray(ours_b[3], np.float32), np.asarray(want_b[3], np.float32),
+        rtol=2 ** -6, atol=size * 2 ** -7)
+    from deepspeed_tpu.ops.latent_layout import heads_to_flat
+    flat, pull = jax.vjp(heads_to_flat, cts[2])
+    unit._same(flat, cts[2].transpose(0, 2, 1, 3).reshape(
+        unit.BATCH, 1024, heads * unit.VDIM))
+    unit._same(pull(flat)[0], cts[2])

@@ -5,9 +5,9 @@ objective, its two terms and every gradient leaf; the shares of the
 experts adding up to the uncut layer; the selection bias (no gradient,
 no decay, the sign update over summed micro-batches, bit-equal to the
 reference over three steps, kept by a checkpoint); the prediction
-module's inputs and targets; the shared leaves' gradients; which
-rotation a 64-wide slice takes; the engine paths that refuse a model
-with leaves the optimizer does not own."""
+module's inputs and targets; the shared leaves' gradients; which path
+the latent heads' rotation and layout take; the engine paths that refuse
+a model with leaves the optimizer does not own."""
 
 
 import zlib
@@ -25,6 +25,8 @@ from deepspeed_tpu.moe.dropless import DroplessMoE, route_topk
 from deepspeed_tpu.monitor import record as R
 from deepspeed_tpu.ops import dispatch
 from deepspeed_tpu.ops.rotary import rotary_block
+from deepspeed_tpu.runtime.activation_checkpointing.checkpointing import (
+    RematBudget, stack_plan_line)
 from perf.families import glm4_moe_lite as family
 from perf.families import glm4_moe_lite_reference as reference
 
@@ -388,27 +390,53 @@ def test_the_shared_leaves_get_both_uses_gradients():
 
 
 # ---------------------------------------------------------------------- #
-# which rotation a 64-wide rotated slice takes
+# which path the latent heads' rotation and layout take
 # ---------------------------------------------------------------------- #
-def test_a_64_wide_rotated_slice_takes_apply_rotary(monkeypatch):
+@pytest.mark.parametrize("interpret, over, seq, path", [
+    (True, {}, 128, ("kernel", 128, 2)),
+    (True, {}, 96, ("xla",)),                      # no whole loop trips
+    (True, {"num_attention_heads": 3}, 128, ("xla",)),
+    (True, {"qk_nope_head_dim": 96, "qk_rope_head_dim": 32,
+            "v_head_dim": 128}, 128, ("xla",)),
+    (False, {}, 128, ("xla",))],
+    ids=["kernels", "S=96", "three heads", "a 32-wide slice",
+         "no interpreter"])
+def test_the_shape_decides_the_latent_path_and_the_plan_says_which(
+        interpret, over, seq, path):
     """ops/rotary.py's kernels take whole heads of 128 of a fused QKV
-    product: for the latent heads' 64 rotated dimensions ``rotary_block``
-    gives None with the interpreter on, the model says ``xla``, and no
-    rotary kernel is in its program.  A widening of ops/rotary.py to
-    other head sizes changes this test knowingly."""
-    dispatch.set_pallas_interpret(True)
+    product and never this model's; ops/latent_layout.py's take heads of
+    whole tiles whose last 64 lanes turn, in pairs, over whole blocks of
+    positions, with a TPU or the interpreter.  The stack's plan, its log
+    line and the kernels in the program say the same."""
+    dispatch.set_pallas_interpret(interpret)
     try:
-        assert rotary_block(8192, 128, 20, 20) is not None
+        assert rotary_block(8192, 128, 20, 20) is not None or not interpret
         assert rotary_block(8192, 64, 20, 1) is None
         assert rotary_block(8192, 256, 20, 20) is None
-        cfg = _config(qk_nope_head_dim=192, qk_rope_head_dim=64,
-                      v_head_dim=256, num_attention_heads=2)
+        cfg = _config(**{**dict(
+            qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+            num_attention_heads=2, activation_checkpointing=True), **over})
         model = Glm4MoeLiteModel(cfg)
-        assert model.rotary_plan() == (("latent", "xla"),)
-        text = str(jax.make_jaxpr(model.loss)(
+        assert model.rotary_plan(seq) == (("latent", *path),)
+        assert (model.latent_block(seq) is None) == (path == ("xla",))
+        budget = RematBudget(10 ** 12, working_set=0)
+        model.install_remat_budget(budget)
+        text = str(jax.make_jaxpr(jax.grad(model.loss))(
             model.init_params(jax.random.PRNGKey(0)), None,
-            jnp.zeros((1, 128), jnp.int32)))
+            jnp.zeros((1, seq), jnp.int32)))
+        plan = budget.take_plan()
+        assert plan[R.M_STACK_ROTARY] == (("latent", *path),)
+        line = stack_plan_line(plan)
         assert "rotary_fwd" not in text and "rotary_bwd" not in text
+        kernels = ("latent_heads_fwd", "latent_heads_bwd",
+                   "latent_flat_fwd", "latent_flat_bwd")
+        if path == ("xla",):
+            assert line.endswith("rotary: latent xla")
+            assert "latent_" not in text
+        else:
+            assert line.endswith("rotary: latent kernel (blocks of 128 "
+                                 "positions x 2 heads)")
+            assert all(name in text for name in kernels)
     finally:
         dispatch.set_pallas_interpret(False)
 

@@ -7,6 +7,11 @@ query heads, 64 of 128 lanes, YaRN frequencies, the attention factor),
 both on 8 key/value heads.  Then the model: which path a shape takes,
 and that the stack's plan says so."""
 
+import hashlib
+import json
+import os
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -227,3 +232,25 @@ def test_the_model_through_the_kernels_equals_the_model_through_xla(
     want = jax.jit(jax.value_and_grad(model.loss))(params, None, ids)
     for a, b in zip(jax.tree.leaves(ours), jax.tree.leaves(want)):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
+
+
+def test_lagunas_grad_program_is_the_recorded_one():
+    """The toy's value-and-gradient program through the kernels, as text,
+    against the record of tests/unit/golden/laguna_grad_jaxpr.json: a
+    change to what this model shares with another (``lane_tables`` with
+    ops/latent_layout.py, ``ExpertStack`` and ``DroplessMoE`` with
+    models/glm4_moe_lite.py) that was not meant for Laguna shows here.
+    One that was records the new text's numbers in the file, with the
+    commit they were read on."""
+    model = LagunaModel(_toy())
+    params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(
+        jax.value_and_grad(model.loss))(
+            params, None, jnp.zeros((2, 128), jnp.int32))))
+    assert "rotary_fwd" in text and "rotary_bwd" in text
+    assert "latent_" not in text
+    with open(os.path.join(os.path.dirname(__file__), "golden",
+                           "laguna_grad_jaxpr.json")) as f:
+        want = json.load(f)
+    assert (len(text), text.count("\n")) == (want["chars"], want["lines"])
+    assert hashlib.sha256(text.encode()).hexdigest() == want["sha256"]

@@ -9,6 +9,10 @@ body must be the loop it is written as: as many equations whatever the
 heads of the layer and the positions of a block, and no more than
 twice as many for twice the heads a block holds.
 
+The same for ops/latent_layout.py's four passes at `glm47-flash.s8k`'s
+shapes (its grad program holds eighteen of each way), in this file so
+that one worker describes the topology once for both.
+
 The topology is described inside a module-scoped fixture, never while a
 module is imported (see tests/perf/test_aot_kernels.py).
 """
@@ -143,3 +147,135 @@ def test_a_body_is_a_loop_over_positions_and_a_blocks_heads(kernels,
         assert count < sliding[name][0] <= 2 * count
     # and small: three walks (q, k rotated, v copied) of 8 heads
     assert max(e for e, _ in full.values()) <= 600
+
+
+# ---------------------------------------------------------------------- #
+# ops/latent_layout.py: glm47-flash.s8k's passes
+# ---------------------------------------------------------------------- #
+# (batch, positions, heads, unrotated and rotated lanes of a query or key
+# head, lanes of a value head)
+LATENT = (2, 8192, 20, 192, 64, 256)
+
+
+@pytest.fixture
+def latent(monkeypatch):
+    from deepspeed_tpu.ops import latent_layout
+    monkeypatch.setattr(latent_layout, "pallas_available", lambda: True)
+    return latent_layout
+
+
+def _latent_passes(ll, shape, sharding=None):
+    """{kernel name: (the pass, its arguments' shapes)}."""
+    import jax
+    import jax.numpy as jnp
+    batch, seq, heads, nope, rope, vdim = shape
+
+    def array(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    flat = (array(batch, seq, heads * (nope + rope)),
+            array(batch, seq, heads * (nope - 64)),
+            array(batch, seq, heads * 64), array(batch, seq, heads * vdim),
+            array(batch, seq, rope))
+    tables = (array(seq, DIM, dtype=jnp.float32),) * 2
+    by_head = tuple(array(batch, heads, seq, dim)
+                    for dim in (nope + rope, nope + rope, vdim))
+
+    def forward(*args):
+        return ll.latent_heads(*args, heads)
+
+    def backward(*args):
+        *flat, cos, sin, cotangents = args
+        return jax.vjp(lambda *x: forward(*x, cos, sin), *flat)[1](
+            cotangents)
+
+    def back_backward(a, d_flat):
+        return jax.vjp(ll.heads_to_flat, a)[1](d_flat)
+
+    return {
+        "latent_heads_fwd": (forward, (*flat, *tables)),
+        "latent_heads_bwd": (backward, (*flat, *tables, by_head)),
+        "latent_flat_fwd": (ll.heads_to_flat, by_head[2:]),
+        "latent_flat_bwd": (back_backward, (by_head[2], flat[3]))}
+
+
+@pytest.mark.parametrize("name", ["latent_heads_fwd", "latent_heads_bwd",
+                                  "latent_flat_fwd", "latent_flat_bwd"])
+def test_a_latent_pass_compiles_as_one_kernel(name, latent, one_chip):
+    import jax
+    assert latent.latent_block(*LATENT[1:2], *LATENT[3:], LATENT[2]) == (
+        512, 4)
+    fn, args = _latent_passes(latent, LATENT, one_chip)[name]
+    lowered = jax.jit(fn).lower(*args)
+    assert re.findall(r'kernel_name = "(\w+)"', lowered.as_text()) == [name]
+    text = lowered.compile().as_text()      # raises what the chip would
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    # nothing of a tensor's size beside the kernel: no transpose, no
+    # float32 tensor, no broadcast of the one key to the heads, no copy
+    # but the one key's own [2, 8192, 64]
+    assert not re.search(r"= f32\[2,\d+,8192,|= f32\[2,8192,\d{3,}", text)
+    assert " transpose(" not in text
+    assert not re.search(r"\[2,20,8192,\d+\]\S* broadcast\(", text)
+    for line in text.splitlines():
+        if " copy(" in line:
+            assert re.search(r"= bf16\[2,8192,64\]", line), line
+
+
+def test_the_latent_bodies_are_traced_once_a_process_and_shape(
+        latent, monkeypatch):
+    """Two layers' calls under two outer traces of value and gradient:
+    each kernel's body runs through Python once (the calls sit behind
+    cached jits, as ops/rotary.py's), and a body is a loop over
+    positions: as many equations whatever the heads of the layer."""
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.analysis.jaxpr_walk import iter_eqns
+    traced = []
+    for body in ("_heads_fwd_kernel", "_heads_bwd_kernel", "_flat_kernel"):
+        def counting(*refs, _body=getattr(latent, body), _name=body, **kw):
+            traced.append((_name, kw.get("backward")))
+            return _body(*refs, **kw)
+        monkeypatch.setattr(latent, body, counting)
+    # a shape no other test of this process has traced
+    shape = (3, 1536, 12, 192, 64, 256)
+    fn, args = _latent_passes(latent, shape)["latent_heads_fwd"]
+
+    def two_layers(*args):
+        total = 0.0
+        for scale in (1.0, 2.0):
+            q, k, v = fn(args[0] * scale, *args[1:])
+            total += jnp.sum(latent.heads_to_flat(q * k * v).astype(
+                jnp.float32))
+        return total
+
+    sizes = {}
+    for _ in range(2):
+        jaxpr = jax.make_jaxpr(jax.value_and_grad(two_layers, argnums=(
+            0, 1, 2, 3, 4)))(*args)
+        for eqn in (c.eqn for c in iter_eqns(jaxpr.jaxpr)):
+            if eqn.primitive.name == "pallas_call":
+                sizes.setdefault(eqn.params["name"], set()).add(
+                    (_equations(eqn.params["jaxpr"]),
+                     tuple(eqn.params["grid_mapping"].grid)))
+    assert sorted(traced) == [
+        ("_flat_kernel", False), ("_flat_kernel", True),
+        ("_heads_bwd_kernel", None), ("_heads_fwd_kernel", None)]
+    assert {name: grids for name, grids in sizes.items()} == {
+        name: {(next(iter(grids))[0], (3, 3, 3))}
+        for name, grids in sizes.items()}
+    assert sorted(sizes) == ["latent_flat_bwd", "latent_flat_fwd",
+                             "latent_heads_bwd", "latent_heads_fwd"]
+    # the cell's 20 heads: the same bodies, five blocks of heads
+    cell = {}
+    for name, (fn, args) in _latent_passes(latent, LATENT).items():
+        for eqn in (c.eqn for c in iter_eqns(
+                jax.make_jaxpr(fn)(*args).jaxpr)):
+            if eqn.primitive.name == "pallas_call":
+                cell[eqn.params["name"]] = (
+                    _equations(eqn.params["jaxpr"]),
+                    tuple(eqn.params["grid_mapping"].grid))
+    assert {grid for _, grid in cell.values()} == {(2, 16, 5)}
+    assert {n: e for n, (e, _) in cell.items()} == {
+        n: next(iter(s))[0] for n, s in sizes.items()}
+    # and small: four heads of six tiles a trip
+    assert max(e for e, _ in cell.values()) <= 400
