@@ -11,6 +11,11 @@ one compiled body over them.  Two execution modes:
 
 The auto policy (`scan_layers=None` in the model configs) unrolls up to
 SCAN_LAYERS_AUTO_THRESHOLD layers and scans beyond.
+
+A stack may also run SEVERAL TIMES on the same weights
+(`run_layer_recurrence`: a loop of passes around either mode, a per-pass
+function between them), the gradient of every weight the sum
+over its uses.
 """
 
 import jax
@@ -39,4 +44,23 @@ def run_layer_stack(body, carry, xs, use_scan: bool, with_ys: bool = False):
         ys.append(y)
     if not with_ys:
         return carry
+    return carry, jax.tree.map(lambda *a: jnp.stack(a), *ys)
+
+
+def run_layer_recurrence(body, carry, xs, passes: int, use_scan: bool,
+                         after_pass):
+    """A stack run ``passes`` times on the SAME stacked weights: each pass
+    is ``run_layer_stack(body, carry, xs, use_scan)``, and ``after_pass(
+    carry) -> (carry, y)`` takes its output (a final norm, the keep of
+    the pass's hidden states); the next pass starts from what it hands
+    back.  Returns (carry, the ``passes`` y stacked).  A weight's
+    gradient is the sum over its ``passes`` uses, which differentiation
+    gives.  The passes are a Python loop, so XLA sees all of them: as an
+    outer ``lax.scan`` over one traced pass the v5e ran 8 layers x 4
+    passes 1.6% slower for half the compile time (PERF.md section 6,
+    PR 45)."""
+    ys = []
+    for _ in range(passes):
+        carry, y = after_pass(run_layer_stack(body, carry, xs, use_scan))
+        ys.append(y)
     return carry, jax.tree.map(lambda *a: jnp.stack(a), *ys)

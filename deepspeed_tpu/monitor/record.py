@@ -104,6 +104,9 @@ M_REMAT_BUDGET_BYTES = "remat_budget_bytes"  # limit - state - working set
 M_REMAT_BYTES_LIMIT = "remat_bytes_limit"
 M_REMAT_STATE_BYTES = "remat_state_bytes"
 M_REMAT_WORKING_SET_BYTES = "remat_working_set_bytes"
+# times the whole stack runs on the same weights; on the record only where
+# it is not 1, and then M_REMAT_LAYERS and the bytes count applications
+M_REMAT_PASSES = "remat_passes"
 
 # ---- the plan of a stack of unlike layers, on the same meta record --- #
 # (models/phi4flash.py; checkpointing.checkpoint_layers carries it)
@@ -126,6 +129,9 @@ M_STACK_ROTARY = "stack_rotary"
 M_STACK_LATENT = "stack_latent_attention"
 # multi-token prediction: [modules, the weight of their loss]
 M_STACK_MTP = "stack_prediction_modules"
+# (models/ouro.py) a stack run several times on the same weights:
+# [passes, layer applications a step]
+M_STACK_PASSES = "stack_passes"
 
 # ---- fleet field names (fleet.py / health.py payloads) --------------- #
 FL_WINDOW_START = "window_start_step"
@@ -192,6 +198,15 @@ M_LOAD_MAX_OVER_MEAN = "load_max_over_mean"
 M_MAIN_LOSS = "main_loss"
 M_MTP_LOSS = "mtp_loss"
 M_POPULARITY = "popularity"        # embedded ExpertPopularitySnapshot
+# (a model that emits them: models/ouro.py) the exit gate's counters,
+# averaged over micro-batches: the objective's two terms (the exit
+# distribution's mix of the passes' cross-entropies; KL(p || uniform)),
+# the mean pass of exit sum_t t p_t, and M_EXIT_MASS + "1".."T", the mean
+# probability of each exit
+M_TASK_LOSS = "task_loss"
+M_EXIT_KL = "exit_kl"
+M_EXIT_STEP_MEAN = "exit_step_mean"
+M_EXIT_MASS = "exit_mass_"
 
 # ---- reconciliation field names (reconcile.py payload) --------------- #
 R_WINDOW_START = "window_start_step"

@@ -75,12 +75,20 @@ def _valid_mask(labels, ignore_index):
     return valid, jnp.maximum(valid.sum(), 1.0)
 
 
-def _forward(h, w, labels, chunk_size, ignore_index):
-    n, hid = h.shape
-    vocab = w.shape[1]
-    c, n_chunks, padded = _plan(vocab, chunk_size, n)
-    wc = _padded_w(w, padded).reshape(hid, n_chunks, c).transpose(1, 0, 2)
-    valid, denom = _valid_mask(labels, ignore_index)
+def _chunked(h, w, chunk_size):
+    """(w as [n_chunks, H, chunk] padded to whole chunks, its vocab)."""
+    hid, vocab = w.shape
+    c, n_chunks, padded = _plan(vocab, chunk_size, h.shape[0])
+    return _padded_w(w, padded).reshape(hid, n_chunks, c).transpose(
+        1, 0, 2), vocab
+
+
+def _token_losses(h, chunks, labels):
+    """(lse, label logit), each [N] fp32: the streamed pass over the
+    vocabulary's ``chunks`` that both forms share; a token's loss is
+    their difference."""
+    wc, vocab = chunks
+    n, c = h.shape[0], wc.shape[2]
 
     def body(carry, w_i):
         m, s, idx = carry
@@ -102,8 +110,13 @@ def _forward(h, w, labels, chunk_size, ignore_index):
     m0 = jnp.full((n,), -jnp.inf, jnp.float32)
     s0 = jnp.zeros((n,), jnp.float32)
     (m, s, _), lab_parts = lax.scan(body, (m0, s0, jnp.int32(0)), wc)
-    lse = m + jnp.log(s)
-    label_logit = lab_parts.sum(axis=0)
+    return m + jnp.log(s), lab_parts.sum(axis=0)
+
+
+def _forward(h, w, labels, chunk_size, ignore_index):
+    chunks = _chunked(h, w, chunk_size)
+    valid, denom = _valid_mask(labels, ignore_index)
+    lse, label_logit = _token_losses(h, chunks, labels)
     loss = ((lse - label_logit) * valid).sum() / denom
     return loss.astype(jnp.float32), (lse,)
 
@@ -115,12 +128,18 @@ def _fwd(h, w, labels, chunk_size, ignore_index):
 
 def _bwd(chunk_size, ignore_index, res, g):
     h, w, labels, lse = res
-    n, hid = h.shape
-    vocab = w.shape[1]
-    c, n_chunks, padded = _plan(vocab, chunk_size, n)
-    wc = _padded_w(w, padded).reshape(hid, n_chunks, c).transpose(1, 0, 2)
+    chunks = _chunked(h, w, chunk_size)
     valid, denom = _valid_mask(labels, ignore_index)
     scale = (g / denom) * valid  # [N] d mean / d token (0 on ignored)
+    return _input_grads(h, chunks, labels, lse, scale, w.dtype)
+
+
+def _input_grads(h, chunks, labels, lse, scale, w_dtype):
+    """(dh, dw, None) for the cotangent ``scale`` [N] of the tokens'
+    losses: each chunk's logits recomputed, ``(softmax - onehot) x
+    scale`` sent back through the product."""
+    wc, vocab = chunks
+    n_chunks, hid, c = wc.shape
 
     def body(carry, w_i):
         dh, idx = carry
@@ -142,8 +161,51 @@ def _bwd(chunk_size, ignore_index, res, g):
 
     dh0 = jnp.zeros(h.shape, jnp.float32)
     (dh, _), dw_chunks = lax.scan(body, (dh0, jnp.int32(0)), wc)
-    dw = dw_chunks.transpose(1, 0, 2).reshape(hid, padded)[:, :vocab]
-    return dh.astype(h.dtype), dw.astype(w.dtype), None
+    dw = dw_chunks.transpose(1, 0, 2).reshape(hid, n_chunks * c)[:, :vocab]
+    return dh.astype(h.dtype), dw.astype(w_dtype), None
 
 
 fused_linear_cross_entropy.defvjp(_fwd, _bwd)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def fused_linear_cross_entropy_per_token(h, w, labels, chunk_size=None,
+                                         ignore_index=None):
+    """CE(softmax(h @ w), labels) of every token, fp32 [N], 0 where the
+    label is ``ignore_index``; the logits never materialised, as in the
+    mean form above, which is this under a mean.  For an objective that
+    weighs each token's loss itself (a per-token exit probability that
+    takes a gradient through the loss: models/ouro.py): the VJP takes
+    the cotangent [N] of the losses."""
+    return _per_token_fwd(h, w, labels, chunk_size, ignore_index)[0]
+
+
+def _per_token_fwd(h, w, labels, chunk_size, ignore_index):
+    valid, _ = _valid_mask(labels, ignore_index)
+    lse, label_logit = _token_losses(h, _chunked(h, w, chunk_size), labels)
+    return (lse - label_logit) * valid, (h, w, labels, lse)
+
+
+def _per_token_bwd(chunk_size, ignore_index, res, g):
+    h, w, labels, lse = res
+    valid, _ = _valid_mask(labels, ignore_index)
+    return _input_grads(h, _chunked(h, w, chunk_size), labels, lse,
+                        g * valid, w.dtype)
+
+
+fused_linear_cross_entropy_per_token.defvjp(_per_token_fwd, _per_token_bwd)
+
+
+def even_chunk(vocab: int, n_tokens: int):
+    """A ``chunk_size`` that splits ``vocab`` into EQUAL parts of whole
+    lane tiles (128) under the auto policy's transient budget, in at most
+    twice the chunks the auto plan takes, or None where there is none
+    (the auto plan pads its last chunk: 49,152 rows at 16,384 tokens go
+    in two chunks of 32,768, a quarter of the products' columns
+    padding)."""
+    limit = max(4096, _CE_CHUNK_ELEM_BUDGET // max(1, n_tokens))
+    fewest = -(-vocab // limit)
+    for parts in range(fewest, 2 * fewest + 1):
+        if vocab % parts == 0 and (vocab // parts) % 128 == 0:
+            return vocab // parts
+    return None

@@ -65,7 +65,10 @@ no other scope between them, so that a ``gate`` elsewhere is none.
 Across the scopes, ``region`` names a whole module of the model whose
 work lies in several of them (``REGIONS``: ``mtp``, a multi-token-
 prediction module: its norms and projection, its block with the block's
-own ``attn`` / ``router`` / ``experts``, its pass over the head): the
+own ``attn`` / ``router`` / ``experts``, its pass over the head;
+``exit``, the exit work of a stack run several times on the same weights
+(models/ouro.py): the final norm after every pass, the exit gate, the
+passes over the head, the exit distribution and its KL term): the
 outermost region name anywhere on the path, or None.  ``tag`` and
 ``part`` know nothing of regions.
 
@@ -101,7 +104,7 @@ PARTS = {
     "attn_out": ("out", ("attn",)),
     CAST_SCOPE: ("cast", (OTHER,)),
 }
-REGIONS = ("mtp",)
+REGIONS = ("mtp", "exit")
 STACK = "stack"
 STACK_OPS = ("dynamic_slice", "squeeze", "broadcast_in_dim",
              "dynamic_update_slice")

@@ -248,7 +248,7 @@ class RematBudget:
             f"{list(plan[R.M_REMAT_OFFERED])}: "
             f"{plan[R.M_REMAT_KEPT_BYTES_PER_LAYER]:,} B a layer, "
             f"{plan[R.M_REMAT_KEPT_BYTES]:,} B over "
-            f"{plan[R.M_REMAT_LAYERS]} layers, under a budget of "
+            f"{_layers_phrase(plan)}, under a budget of "
             f"{plan[R.M_REMAT_BUDGET_BYTES]:,} B (limit "
             f"{plan[R.M_REMAT_BYTES_LIMIT]:,} - state "
             f"{plan[R.M_REMAT_STATE_BYTES]:,} - working set "
@@ -260,6 +260,16 @@ class RematBudget:
         """The plan, if the monitor has not had it yet."""
         plan, self._pending = self._pending, None
         return plan
+
+
+def _layers_phrase(plan: Dict[str, Any]) -> str:
+    """``N layers``, or where the stack runs several times on the same
+    weights its applications and what they are made of."""
+    layers, passes = plan[R.M_REMAT_LAYERS], plan.get(R.M_REMAT_PASSES, 1)
+    if passes == 1:
+        return f"{layers} layers"
+    return (f"{layers} layer applications ({layers // passes} layers x "
+            f"{passes} passes)")
 
 
 def offered_residuals(body: Callable, *args,
@@ -338,6 +348,10 @@ def stack_plan_line(plan: Dict[str, Any]) -> str:
         modules, weight = plan[R.M_STACK_MTP]
         line += (f"; {modules} multi-token-prediction module(s), loss "
                  f"weight {weight}")
+    if R.M_STACK_PASSES in plan:
+        passes, applications = plan[R.M_STACK_PASSES]
+        line += (f"; run {passes} times on the same weights: "
+                 f"{applications} layer applications a step")
     if R.M_STACK_ROTARY in plan:
         line += "; rotary: " + ", ".join(
             f"{kind} {path}" + (
@@ -350,7 +364,7 @@ def stack_plan_line(plan: Dict[str, Any]) -> str:
 def checkpoint_layers(groups, budget: Optional[RematBudget], carry,
                       head_width: int,
                       stack_plan: Optional[Dict[str, Any]] = None,
-                      extra_working_set: int = 0):
+                      extra_working_set: int = 0, passes: int = 1):
     """``checkpoint_layer`` for a model whose stack is several scanned
     groups of unlike layers: ``groups`` is a list of ``(body,
     stacked_xs)``, every body taking the same ``carry``; one budget is
@@ -358,7 +372,12 @@ def checkpoint_layers(groups, budget: Optional[RematBudget], carry,
     one plan is noted, ``stack_plan`` (the model's M_STACK_* fields)
     riding on it; ``extra_working_set``: bytes a layer of this model
     holds that ``working_set_bytes`` does not know of (a sparse layer's
-    rows).  Returns the wrapper for every body
+    rows); ``passes``: how many times the whole stack runs on the same
+    weights (models/layer_stack.py ``run_layer_recurrence``): every
+    APPLICATION of a layer keeps its carry and its residuals, so offered
+    and kept bytes, the working set's carries and M_REMAT_LAYERS count
+    applications, and M_REMAT_PASSES rides on the plan (1: today's plan,
+    the field left out).  Returns the wrapper for every body
     of the stack: what
     a wrapped body closes over (another layer's output that this one
     reads; the plan may be made on a stand-in of its shape) is an input
@@ -367,7 +386,7 @@ def checkpoint_layers(groups, budget: Optional[RematBudget], carry,
         return _KEEP_ALWAYS
     shape = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), carry)
-    steps = [jax.tree.leaves(xs)[0].shape[0] for _, xs in groups]
+    steps = [passes * jax.tree.leaves(xs)[0].shape[0] for _, xs in groups]
     offers = [offered_residuals(
         body, shape, jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), xs),
@@ -396,6 +415,7 @@ def checkpoint_layers(groups, budget: Optional[RematBudget], carry,
         R.M_REMAT_BYTES_LIMIT: int(budget.bytes_limit),
         R.M_REMAT_STATE_BYTES: budget.state_bytes,
         R.M_REMAT_WORKING_SET_BYTES: working_set,
+        **({R.M_REMAT_PASSES: passes} if passes != 1 else {}),
         **(stack_plan or {})})
     if not kept:
         return _KEEP_ALWAYS

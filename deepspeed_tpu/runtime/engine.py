@@ -232,6 +232,17 @@ class DeepSpeedEngine:
 
         # ---- ZeRO sharding ------------------------------------------- #
         stage = self.config.zero_optimization_stage
+        # paths a model says it cannot run (a class attribute ``refuses``:
+        # {path: the reason in one sentence}), refused before anything
+        # is built for them
+        for path, asked in (
+                ("zero3_streaming", stage >= 3),
+                ("pipeline", self.mesh_ctx.pipe_parallel_world_size > 1),
+                ("fused_step", self.config.fused_step_config.enabled)):
+            reason = getattr(model, "refuses", {}).get(path)
+            if asked and reason:
+                raise NotImplementedError(
+                    f"{type(model).__name__} under {path}: {reason}.")
         self.zero_partitioner = ZeroPartitioner(
             self.mesh_ctx, stage,
             persistence_threshold=self.config.zero_config.
