@@ -45,6 +45,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..moe.dropless import DroplessMoE
@@ -55,7 +56,7 @@ from ..ops.fused_cross_entropy import fused_linear_cross_entropy
 from ..ops.normalize import rms_norm
 from ..ops.rotary import lane_tables, rotary_block, rotate_qkv
 from ..runtime.activation_checkpointing.checkpointing import (
-    checkpoint_layers, stack_plan_line)
+    FFN_PRODUCT_NAME, checkpoint_layers, stack_plan_line)
 from ..utils.logging import log_dist
 from .layer_stack import run_layer_stack
 
@@ -110,9 +111,13 @@ def apply_rotary(x, table):
 
 def gated_ffn(p, u):
     """A dense gated FFN, ``(silu(u Wg) * (u Wu)) Wd`` with gate and up
-    in one matrix ``w1`` (gate first), under scope ``mlp``."""
+    in one matrix ``w1`` (gate first), under scope ``mlp``.  The first
+    product is offered whole to a checkpointed layer's byte budget: kept,
+    the backward pass rebuilds ``silu(gate)`` and its product with ``up``
+    element-wise and runs no second ``u @ w1``."""
     with jax.named_scope("mlp"):
-        gate, up = jnp.split(u @ p["w1"], 2, axis=-1)
+        gate, up = jnp.split(
+            checkpoint_name(u @ p["w1"], FFN_PRODUCT_NAME), 2, axis=-1)
         return (up * jax.nn.silu(gate)) @ p["w2"]
 
 

@@ -50,6 +50,7 @@ from ..ops.selective_scan import CHUNK, entry_state_bytes, selective_scan
 from ..runtime.activation_checkpointing.checkpointing import (
     checkpoint_layers, stack_plan_line)
 from ..utils.logging import log_dist
+from .laguna import gated_ffn
 from .layer_stack import resolve_use_scan, run_layer_stack
 
 @dataclass
@@ -234,11 +235,6 @@ class Phi4FlashModel:
         return sum(math.prod(x.shape) for x in jax.tree.leaves(shapes))
 
     # -- the four mixers ------------------------------------------------ #
-    def _ffn(self, p, x):
-        with jax.named_scope("mlp"):
-            gate, up = jnp.split(x @ p["w1"], 2, axis=-1)
-            return (up * jax.nn.silu(gate)) @ p["w2"]
-
     def _mamba(self, p, x):
         """(mixer output, the scan's output y before its gate)."""
         cfg = self.config
@@ -344,7 +340,7 @@ class Phi4FlashModel:
             out, kept = mixer(p["mixer"], fused_layer_norm(
                 x, p["ln1"]["w"], p["ln1"]["b"], eps))
             h = x + out
-            return h + self._ffn(p["ffn"], fused_layer_norm(
+            return h + gated_ffn(p["ffn"], fused_layer_norm(
                 h, p["ln2"]["w"], p["ln2"]["b"], eps)), kept
 
     # -- the stack ------------------------------------------------------ #

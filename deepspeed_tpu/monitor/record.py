@@ -99,6 +99,9 @@ M_REMAT_OFFERED = "remat_offered"            # names the layer offers
 M_REMAT_KEPT = "remat_kept"                  # the prefix the budget admits
 M_REMAT_KEPT_BYTES_PER_LAYER = "remat_kept_bytes_per_layer"
 M_REMAT_KEPT_BYTES = "remat_kept_bytes"      # over all layers, a device
+# its parts, [[name, bytes]]; on the record only where several names are
+# kept (one name's bytes are M_REMAT_KEPT_BYTES)
+M_REMAT_KEPT_BYTES_BY_NAME = "remat_kept_bytes_by_name"
 M_REMAT_LAYERS = "remat_layers"
 M_REMAT_BUDGET_BYTES = "remat_budget_bytes"  # limit - state - working set
 M_REMAT_BYTES_LIMIT = "remat_bytes_limit"

@@ -17,7 +17,9 @@ policies instead of hand-managed tensor stashes:
                                   limit, less the engine's own state, less
                                   the working set of the traced shapes):
                                   the flash kernel's out / lse / packed
-                                  mask, so the kernel runs once a layer.
+                                  mask, so the kernel runs once a layer,
+                                  then a gated FFN's first product, so
+                                  that matmul runs once too.
                                   With no budget (no engine, a backend that
                                   reports no memory limit, nothing fits) it
                                   keeps the layer's input alone and
@@ -134,14 +136,32 @@ def get_partition_policy():
 
 
 # What a checkpointed layer may keep, in the order a budget admits it.
-# The flash kernel's residuals alone: keeping them took 15.3 ms off GPT-2
-# large's 267 ms step at B=4, S=1,024 on the v5e for 0.77 GB (20 ms a GB)
-# and left the step as steady as it was.  The widest matmul outputs were
-# tried under their own names and are not offered: 4.4 ms for the MLP
-# pre-activation's 1.51 GB, 1.7 for the attention projection's 0.38,
-# 2.2 ms SLOWER for the QKV output's 1.13, with one long step in a run in
-# three (PERF.md section 6, PR 33; ROADMAP S2 has what is left of them).
-RESIDUAL_ORDER = (FLASH_RESIDUALS_NAME,)
+# What each name costs and buys on the v5e (PERF.md section 6, PR 33 and
+# PR 46):
+# 1. The flash kernel's residuals, 20 ms a GB: 15.3 ms off GPT-2 large's
+#    267 ms step at B=4, S=1,024 for 0.77 GB, the step as steady as it
+#    was.
+# 2. A gated FFN's first product, gate and up together before the split
+#    (models/laguna.py ``gated_ffn``): a kept byte spares ``contraction
+#    width`` FLOPs of the recomputation pass.  Contracting over 2,560 in
+#    an unrolled stack (Phi-4-mini-flash, six products of 8,192 x
+#    20,480), 13.8 ms a GB: 27.7 ms off a 478.1 ms step for 2.01 GB, the
+#    products plain buffers that stay alive.  Over 2,048 in a scanned
+#    stack (Ouro, 32 products of 4,096 x 11,264), 7.1 ms a GB: 20.9 ms
+#    off 561.3 for 2.95 GB; 33.9 ms of recomputation spared, 9.2 paid
+#    reading each product back out of its [8, ...] stack (a slice of its
+#    own at 640 GB/s) and 4.2 filling the stacks before the loop; the
+#    forward matmul writes its slice from its own fusion.  Whole and not
+#    ``up * silu(gate)``: half the bytes would slip under the 505 MB that
+#    Laguna-XS.2's plan has left and move a program that must not move.
+# GPT-2's MLP is NOT offered the name.  Contracting over 1,280, its
+# pre-activation's recomputation costs 7.3 ms a GB and the scan's write
+# of the kept value into its [36, ...] stack cost 7.1: 4.4 ms for 1.51 GB
+# at B=4, S=1,024 (the attention projection's 0.38 GB bought 1.7, the QKV
+# output's 1.13 cost 2.2), with one long step in a run in three (PR 33).
+# ROADMAP S2b has what is left of the matmul outputs.
+FFN_PRODUCT_NAME = "ffn_gate_up"
+RESIDUAL_ORDER = (FLASH_RESIDUALS_NAME, FFN_PRODUCT_NAME)
 # Names kept wherever a layer offers them, with a budget, with none that
 # fits and with none at all (the CPU, a streamed ZeRO-3, a device that
 # reports no limit): a few integers a token, and what a recomputation
@@ -248,8 +268,8 @@ class RematBudget:
             f"{list(plan[R.M_REMAT_OFFERED])}: "
             f"{plan[R.M_REMAT_KEPT_BYTES_PER_LAYER]:,} B a layer, "
             f"{plan[R.M_REMAT_KEPT_BYTES]:,} B over "
-            f"{_layers_phrase(plan)}, under a budget of "
-            f"{plan[R.M_REMAT_BUDGET_BYTES]:,} B (limit "
+            f"{_layers_phrase(plan)}{_by_name_phrase(plan)}, under a budget "
+            f"of {plan[R.M_REMAT_BUDGET_BYTES]:,} B (limit "
             f"{plan[R.M_REMAT_BYTES_LIMIT]:,} - state "
             f"{plan[R.M_REMAT_STATE_BYTES]:,} - working set "
             f"{plan[R.M_REMAT_WORKING_SET_BYTES]:,})", ranks=[0])
@@ -270,6 +290,14 @@ def _layers_phrase(plan: Dict[str, Any]) -> str:
         return f"{layers} layers"
     return (f"{layers} layer applications ({layers // passes} layers x "
             f"{passes} passes)")
+
+
+def _by_name_phrase(plan: Dict[str, Any]) -> str:
+    """`` (name N B, name M B)`` where several names are kept."""
+    by_name = plan.get(R.M_REMAT_KEPT_BYTES_BY_NAME)
+    if not by_name:
+        return ""
+    return " (" + ", ".join(f"{n} {b:,} B" for n, b in by_name) + ")"
 
 
 def offered_residuals(body: Callable, *args,
@@ -403,13 +431,14 @@ def checkpoint_layers(groups, budget: Optional[RematBudget], carry,
             budget.cast_bytes)
     working_set += extra_working_set
     kept = saved_residual_names(offered, 1, budget.bytes(working_set))
-    total = sum(offered[name] for name in kept)
+    by_name = tuple((name, offered[name]) for name in kept)
     budget.note_plan({
         R.M_REMAT_OFFERED: tuple(n for n in RESIDUAL_ORDER if n in offered),
         R.M_REMAT_KEPT: kept,
         R.M_REMAT_KEPT_BYTES_PER_LAYER: max(
             sum(o.get(name, 0) for name in kept) for o in offers),
-        R.M_REMAT_KEPT_BYTES: total,
+        R.M_REMAT_KEPT_BYTES: sum(size for _, size in by_name),
+        **({R.M_REMAT_KEPT_BYTES_BY_NAME: by_name} if len(kept) > 1 else {}),
         R.M_REMAT_LAYERS: num_layers,
         R.M_REMAT_BUDGET_BYTES: budget.bytes(working_set),
         R.M_REMAT_BYTES_LIMIT: int(budget.bytes_limit),

@@ -301,7 +301,9 @@ def test_the_cells_plan_keeps_the_flash_residuals(monkeypatch):
     """The benchmark cell's stack (8 layers at the published widths run 4
     times, one row of 4,096 tokens) under the v5e's memory limit and the
     cell's state: 32 applications' carries in the working set, 32 sets of
-    flash residuals kept."""
+    flash residuals and 32 first products of the gated FFN kept (the
+    second name since PR 46: tests/unit/test_remat_policy.py has the
+    bytes by name)."""
     monkeypatch.setattr(dispatch, "_interpret", True)
     model = OuroModel(OuroConfig(num_hidden_layers=8,
                                  activation_checkpointing=True))
@@ -321,9 +323,10 @@ def test_the_cells_plan_keeps_the_flash_residuals(monkeypatch):
         2 * entries + ck.MARGIN_BYTES
         + 4096 * ((32 + ck.LAYER_WIDTHS) * 2048 * 2 + 4 * 49152))
     assert plan[R.M_REMAT_KEPT] == ck.RESIDUAL_ORDER
-    # out bf16 [1, 16, 4096, 128] and the row statistics, 32 times
+    # out bf16 [1, 16, 4096, 128], the row statistics and the gate and up
+    # product bf16 [1, 4096, 2 x 5632], 32 times
     assert plan[R.M_REMAT_KEPT_BYTES_PER_LAYER] == (
-        16 * 4096 * 128 * 2 + 16 * 4096 * 4)
+        16 * 4096 * 128 * 2 + 16 * 4096 * 4 + 4096 * 11264 * 2)
     assert plan[R.M_REMAT_KEPT_BYTES] == 32 * plan[
         R.M_REMAT_KEPT_BYTES_PER_LAYER] <= plan[R.M_REMAT_BUDGET_BYTES]
     assert plan[R.M_STACK_PASSES] == (4, 32)
@@ -353,7 +356,8 @@ def _older_family(name):
 def plan_and_lines(name):
     """An older family's plan as JSON and the lines the budget logs for
     it (the golden file is what this gave on the parent of PR 45, commit
-    68bc076, from a scratch script that holds a copy of it)."""
+    68bc076, from a scratch script that holds a copy of it; three of its
+    entries are PR 46's: the test below says which fields moved)."""
     model, rows, seq = _older_family(name)
     lines = []
 
@@ -374,6 +378,17 @@ def plan_and_lines(name):
 
 @pytest.mark.parametrize("name", ["gpt2", "phi4flash", "laguna", "glm"])
 def test_one_pass_is_the_parents_plan_byte_for_byte(name, monkeypatch):
+    """``gpt2`` is still what the parent of PR 45 planned and logged, byte
+    for byte.  PR 46 recorded ``phi4flash``, ``laguna`` and ``glm`` anew:
+    their gated FFN offers its first product as the second name of
+    ``RESIDUAL_ORDER`` and the toys' 15 GB budgets admit it, so
+    ``remat_offered`` and ``remat_kept`` gained ``ffn_gate_up``,
+    ``remat_kept_bytes`` and ``remat_kept_bytes_per_layer`` its bytes,
+    the new ``remat_kept_bytes_by_name`` appeared (it rides on a plan that
+    keeps several names) and the first log line says the same; budget,
+    state, working set, layers and every ``stack_*`` field and the second
+    line are the parent's (GPT-2's MLP offers no such name: PERF.md
+    section 6, PR 33 and PR 46)."""
     monkeypatch.setattr(dispatch, "_interpret", True)
     monkeypatch.setenv("DS_FLASH_MIN_SEQ", "0")
     want = json.loads(GOLDEN.read_text())[name]

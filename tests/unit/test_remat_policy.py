@@ -1,13 +1,15 @@
 """The scanned layer's recomputation policy (runtime/activation_checkpointing/
 checkpointing.py ``checkpoint_layer``): which named residuals a byte
 budget keeps, that keeping them spares the backward pass the flash forward
-kernel and changes no number, and that the carried ZeRO-3 stream keeps
-whole-layer recomputation.  The kernels run through the Pallas
+kernel and the gated FFN's first product and changes no number, and that
+the carried ZeRO-3 stream keeps whole-layer recomputation.  The kernels run through the Pallas
 interpreter; counts are taken on the DCE'd grad jaxpr (the CPU backend
 drops ``jax.checkpoint``'s barriers, so compiled CPU text cannot show
 them)."""
 
+import importlib
 import json
+import pathlib
 import re
 
 import numpy as np
@@ -27,10 +29,9 @@ from deepspeed_tpu.runtime.activation_checkpointing import checkpointing as ck
 from tests.unit.test_zero3_streaming import (_group_cfg, _tiny_engine,
                                              _tiny_ids, _weighted_prim_count)
 
-import importlib
 fa = importlib.import_module("deepspeed_tpu.ops.flash_attention")
 
-(FLASH,) = ck.RESIDUAL_ORDER
+FLASH, FFN = ck.RESIDUAL_ORDER
 EVERYTHING = 10 ** 12
 
 
@@ -105,7 +106,7 @@ def test_kept_kernel_residuals_spare_the_second_forward(interpret, family,
     assert _kernel_runs(jaxpr, "flash_bwd_dkdv") == layers
     assert _kernel_runs(jaxpr, "flash_bwd_dq") == layers
     kept = model._remat_budget.plan[R.M_REMAT_KEPT]
-    assert kept == (ck.RESIDUAL_ORDER if budget else ())
+    assert kept == ((FLASH,) if budget else ())  # GPT-2's MLP offers no name
 
 
 # -- (b) the same numbers ---------------------------------------------------- #
@@ -175,6 +176,9 @@ def _cell_offer(batch, seq, heads, flash):
 LARGE_S1024 = _cell_offer(4, 1024, 20, True)
 LARGE_S128 = _cell_offer(32, 128, 20, False)
 XL_B8 = _cell_offer(8, 1024, 25, True)
+# heads of 128 fill their lanes; gate and up together, 2 x 5,632 wide
+OURO_S4K = {FLASH: 16 * 4096 * (128 * 2 + 4), FFN: 4096 * 11264 * 2}
+LAGUNA_S8K = {FLASH: 1_226_833_920, FFN: 16384 * 16384 * 2}
 GB = 10 ** 9
 
 
@@ -191,6 +195,22 @@ GB = 10 ** 9
     # GPT-2 XL, B=8 a chip: 2.556 GB
     (XL_B8, 48, 2.5 * GB, ()),
     (XL_B8, 48, 2.6 * GB, (FLASH,)),
+    # Ouro-2.6B, one row of 4,096: 32 applications of 17,039,360 B of
+    # kernel residuals and 92,274,688 B of the FFN's first product
+    (OURO_S4K, 32, 545_259_519, ()),
+    (OURO_S4K, 32, 545_259_520, (FLASH,)),           # flash alone fits
+    (OURO_S4K, 32, 3_498_049_535, (FLASH,)),         # the FFN one byte over
+    (OURO_S4K, 32, 3_498_049_536, (FLASH, FFN)),     # both fit
+    (OURO_S4K, 32, 4_811_279_596, (FLASH, FFN)),     # the cell's budget
+    # the FFN's name without the kernel's (attention on the XLA path)
+    ({FFN: OURO_S4K[FFN]}, 32, 2_952_790_015, ()),
+    ({FFN: OURO_S4K[FFN]}, 32, 2_952_790_016, (FFN,)),
+    # a later name never jumps the queue: the FFN would fit alone
+    (OURO_S4K, 32, 3 * GB, (FLASH,)),
+    # Laguna-XS.2's whole stack as one layer: layer 0's product is 31 MB
+    # over what the kernels' residuals leave of the cell's budget
+    (LAGUNA_S8K, 1, 1_732_365_564, (FLASH,)),
+    (LAGUNA_S8K, 1, 1_763_704_832, (FLASH, FFN)),
 ])
 def test_budget_keeps_a_prefix_of_the_fixed_order(offer, layers, budget,
                                                   kept):
@@ -389,7 +409,7 @@ def test_data_parallel_engine_reckons_bytes_a_device(interpret, monkeypatch):
     working_set = ck.working_set_bytes(2 * 256, 128, 2, 256, 4)
     kept = train(working_set + GB)
     none = train(working_set)  # state alone overdraws it
-    assert kept[2].plan[R.M_REMAT_KEPT] == ck.RESIDUAL_ORDER
+    assert kept[2].plan[R.M_REMAT_KEPT] == (FLASH,)
     assert kept[2].plan[R.M_REMAT_WORKING_SET_BYTES] == working_set
     assert none[2].plan[R.M_REMAT_KEPT] == ()
     # 2 heads of 64 (in 128 lanes) and their log-sum-exp
@@ -473,26 +493,30 @@ def test_carried_stream_takes_no_names_policy():
     assert str(kept) == str(plain)
 
 
+@pytest.mark.parametrize("names", [(FLASH,), (FLASH, FFN), (FFN,)])
 @pytest.mark.parametrize("limit", [None, 1, EVERYTHING])
-def test_a_stack_without_picks_lowers_as_it_did(limit):
+def test_a_stack_without_picks_lowers_as_it_did(limit, names):
     """Every policy ``checkpoint_layers`` hands out also names
     ``ALWAYS_KEPT`` (a router's picks), with a budget, with one that
     admits nothing and with none.  A stack that offers no such name (all
     but the mixture-of-experts models) lowers to the text it lowered to
     under the policy without it: plain ``jax.checkpoint`` with no budget
-    or nothing kept, ``save_only_these_names`` of what was kept."""
+    or nothing kept, ``save_only_these_names`` of what was kept, whichever
+    of the order's names the body offers."""
     from jax.ad_checkpoint import checkpoint_name
 
     def body(carry, w):
-        h = checkpoint_name(jnp.tanh(carry @ w), FLASH)
-        return carry + jnp.sin(h) @ w.T, None
+        h = jnp.tanh(carry @ w)
+        for name in names:
+            h = checkpoint_name(jnp.sin(h), name)
+        return carry + h @ w.T, None
 
     carry = jnp.ones((4, 8, 16), jnp.float32)
     ws = jnp.full((3, 16, 16), 0.1, jnp.float32)
     budget = None if limit is None else budget_of(limit)
     wrap = ck.checkpoint_layers([(body, ws)], budget, carry, head_width=8)
     kept = () if budget is None else budget.plan[R.M_REMAT_KEPT]
-    assert kept == ((FLASH,) if limit == EVERYTHING else ())
+    assert kept == (names if limit == EVERYTHING else ())
     before = jax.checkpoint if not kept else (lambda f: jax.checkpoint(
         f, policy=jax.checkpoint_policies.save_only_these_names(*kept)))
 
@@ -540,3 +564,149 @@ def test_laguna_cell_keeps_the_flash_residuals(interpret):
     assert plan[R.M_REMAT_KEPT] == (FLASH,)
     assert plan[R.M_REMAT_KEPT_BYTES] <= plan[R.M_REMAT_BUDGET_BYTES]
     assert 1.2e9 < plan[R.M_REMAT_KEPT_BYTES] < 1.4e9
+
+
+# -- (f) the gated FFN's first product, the name after the flash residuals --- #
+
+def _cell_model(cell):
+    """The model of a benchmark cell, built from the cell's own files as
+    ``perf/run.py`` builds it, with its batch shape and its traffic."""
+    def read(kind, name):
+        return json.loads(
+            (pathlib.Path(ds.__file__).parents[1] / "perf" / kind
+             / f"{name}.json").read_text())
+
+    workload = read("workloads", cell)
+    config = read("configs", workload["config"])
+    traffic = read("traffic", workload["traffic"])
+    family = importlib.import_module("perf.families." + config["family"])
+    models = importlib.import_module(
+        "deepspeed_tpu.models." + config["family"])
+    model_class = {"phi4flash": "Phi4FlashModel", "ouro": "OuroModel",
+                   "laguna": "LagunaModel",
+                   "glm4_moe_lite": "Glm4MoeLiteModel"}[config["family"]]
+    model = getattr(models, model_class)(
+        family.model_config(config, workload["job"]))
+    return model, (traffic["batch_per_chip"], traffic["seq"])
+
+
+MB = 2 ** 20
+
+
+@pytest.mark.parametrize("cell,budget,flash,ffn,kept", [
+    # six FFNs of 8,192 x 20,480 (the three attention layers' residuals)
+    ("phi4-mini-flash.s8k", 3_000_517_884, 511_180_800,
+     6 * 8192 * 20480 * 2, (FLASH, FFN)),
+    # 32 layer applications of 4,096 x 11,264
+    ("ouro-2.6b.s4k", 4_811_279_596, 545_259_520,
+     32 * 4096 * 11264 * 2, (FLASH, FFN)),
+    # layer 0's dense FFN alone offers the name: 512 MiB where the kernels'
+    # residuals leave 505,531,644 B
+    ("laguna-xs2.s8k", 1_732_365_564, 1_226_833_920, 512 * MB, (FLASH,)),
+    # 640 MiB where they leave 28,662,012 B
+    ("glm47-flash.s8k", 1_043_159_292, 1_014_497_280, 640 * MB, (FLASH,)),
+])
+def test_cells_are_offered_the_ffn_product(interpret, monkeypatch, cell,
+                                           budget, flash, ffn, kept):
+    """The four drawn cells' stacks at the published widths, by shapes
+    alone, under the v5e's memory limit and the state their engines sum
+    (fp32 weights and two moments, bf16 gradients, the step count): every
+    one offers the FFN's first product after the kernels' residuals,
+    ``phi4`` and ``ouro`` have the room for it in every layer, ``laguna``
+    (31 MB short) and ``glm47`` keep the plan they had.  The budgets are
+    the chip's own log lines (PERF.md sections 5 and 6)."""
+    model, batch = _cell_model(cell)
+    entries = model.num_params()
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16),
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    model.install_remat_budget(ck.RematBudget(
+        V5E_LIMIT, state_bytes=14 * entries + 4, cast_bytes=2 * entries))
+    seen = {}  # the stack's bytes by name, kept or not
+    admit = ck.saved_residual_names
+    monkeypatch.setattr(ck, "saved_residual_names", lambda offered, *a: (
+        seen.update(offered) or admit(offered, *a)))
+    jax.eval_shape(model.loss, params, None,
+                   jax.ShapeDtypeStruct(batch, jnp.int32))
+    plan = model._remat_budget.plan
+    assert plan[R.M_REMAT_BUDGET_BYTES] == budget
+    assert plan[R.M_REMAT_OFFERED] == (FLASH, FFN)
+    assert (seen[FLASH], seen[FFN]) == (flash, ffn)
+    assert plan[R.M_REMAT_KEPT] == kept
+    if kept == (FLASH,):
+        assert flash <= budget < flash + ffn
+        assert plan[R.M_REMAT_KEPT_BYTES] == flash
+        assert R.M_REMAT_KEPT_BYTES_BY_NAME not in plan
+    else:
+        assert plan[R.M_REMAT_KEPT_BYTES] == flash + ffn <= budget
+        assert plan[R.M_REMAT_KEPT_BYTES_BY_NAME] == (
+            (FLASH, flash), (FFN, ffn))
+        assert ck._by_name_phrase(plan) == (
+            f" ({FLASH} {flash:,} B, {FFN} {ffn:,} B)")
+
+
+def _toy(family):
+    """(model, ids, gated FFN applications a step) of a family's toy as
+    its own tests build it, checkpointing on, attention on the XLA path:
+    the FFN's name is the only one offered."""
+    if family == "ouro":
+        from deepspeed_tpu.models.ouro import OuroModel
+        from tests.unit.test_ouro import _config
+        model = OuroModel(_config(activation_checkpointing=True))
+        ffns = model.config.num_hidden_layers * model.config.total_ut_steps
+    else:
+        from deepspeed_tpu.models.phi4flash import Phi4FlashModel
+        from tests.unit.test_phi4flash import _config
+        model = Phi4FlashModel(_config(activation_checkpointing=True))
+        ffns = len(model.config.layer_plan())
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, 250)
+    return model, ids, ffns
+
+
+def _dots(jaxpr):
+    return sum(c.mult for c in iter_eqns(jaxpr)
+               if c.eqn.primitive.name == "dot_general")
+
+
+@pytest.mark.parametrize("family,ulps", [("ouro", 0), ("phi4flash", 16)])
+def test_kept_ffn_product_spares_its_recomputation(family, ulps):
+    """With the name kept the backward program runs one ``dot_general``
+    fewer for every application of a gated FFN (``u @ w1`` is not run
+    again: the gate, its product with ``up`` and what else the backward
+    pass reads are rebuilt from the kept value), and loss and every
+    gradient leaf are the zero budget's: bit for bit in ``ouro``; in
+    ``phi4flash`` the CPU compiler sums the scanned pairs' weight
+    gradients in another order once the product is a stacked operand
+    (within 16 units in the last place of a leaf's largest entry).  A zero budget,
+    no budget and a layer that names nothing lower to one text: what
+    ``jax.checkpoint(body)`` lowers to."""
+    model, ids, ffns = _toy(family)
+    params = model.init_params(jax.random.PRNGKey(0))
+
+    def loss(p):
+        return model.loss(p, None, ids)
+
+    def program(budget):
+        model.install_remat_budget(budget)
+        step = jax.jit(jax.value_and_grad(loss))
+        text = re.sub(r"(@\w+?)_\d+\b", r"\1", step.lower(params).as_text())
+        return (_dots(_grad_jaxpr(loss, params)), text,
+                jax.tree.leaves(step(params)))
+
+    full, zero = budget_of(EVERYTHING), budget_of(0)
+    kept_dots, kept_text, kept_numbers = program(full)
+    zero_dots, zero_text, zero_numbers = program(zero)
+    assert full.plan[R.M_REMAT_KEPT] == (FFN,)
+    assert zero.plan[R.M_REMAT_OFFERED] == (FFN,)
+    assert zero.plan[R.M_REMAT_KEPT] == ()
+    assert zero_dots - kept_dots == ffns
+    for a, b in zip(kept_numbers, zero_numbers):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=0,
+            atol=ulps * 1.2e-7 * float(jnp.abs(b).max()))
+    assert zero_text != kept_text
+    assert zero_text == program(None)[1]
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr("deepspeed_tpu.models.laguna.checkpoint_name",
+                      lambda x, name: x)
+        assert zero_text == program(None)[1]
