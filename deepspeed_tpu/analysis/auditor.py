@@ -421,24 +421,25 @@ def engine_targets(engine, sample_batch: Optional[Tuple] = None,
 
     if engine._apply_core is not None:
         grads = _grads_template(engine)
-        closed = jax.make_jaxpr(
-            lambda p, o, s, g: engine._apply_core(p, o, s, g))(
-            engine.params, engine.opt_state, engine.scaler_state, grads)
+        arg_trees = (engine.params, engine.opt_state, engine.scaler_state,
+                     grads)
+        apply = engine._apply_core
+        if getattr(engine, "_weights", None) is not None:
+            # the dispatched apply also writes the weights' compute-dtype
+            # copy, into the old copy's donated buffers: audit that one
+            from ..runtime.engine import _masked_leaves
+            apply = engine._apply_fn.__wrapped__
+            arg_trees += (_masked_leaves(engine._weights,
+                                         engine._copy_mask),)
+        closed = jax.make_jaxpr(lambda *a: apply(*a))(*arg_trees)
         donated = getattr(engine, "_apply_donate_argnums", (0, 1, 3))
-        args = [
-            ArgInfo("params", _tree_bytes(engine.params),
-                    0 in donated, True),
-            ArgInfo("opt_state", _tree_bytes(engine.opt_state),
-                    1 in donated, True),
-            ArgInfo("scaler_state", _tree_bytes(engine.scaler_state),
-                    2 in donated, True),
-            ArgInfo("grads", _tree_bytes(grads), 3 in donated, True),
-        ]
+        names = ("params", "opt_state", "scaler_state", "grads",
+                 "weight_copy")
+        args = [ArgInfo(name, _tree_bytes(tree), k in donated, True)
+                for k, (name, tree) in enumerate(zip(names, arg_trees))]
         donated_invars, labels = _expand_invars(
-            (engine.params, engine.opt_state, engine.scaler_state,
-             grads),
-            [(0 in donated, "params"), (1 in donated, "opt_state"),
-             (2 in donated, "scaler_state"), (3 in donated, "grads")])
+            arg_trees, [(k in donated, name)
+                        for k, name in enumerate(names[:len(arg_trees)])])
         grads_sharded = None
         if engine._apply_fn is not None and engine.grad_shardings is not None:
             grads_sharded = jax.tree.map(
@@ -452,8 +453,8 @@ def engine_targets(engine, sample_batch: Optional[Tuple] = None,
             lower=(None if grads_sharded is None else
                    lambda: engine._apply_fn.lower(
                        engine.params, engine.opt_state,
-                       engine.scaler_state,
-                       grads_sharded).compile().as_text()),
+                       engine.scaler_state, grads_sharded,
+                       *arg_trees[4:]).compile().as_text()),
             spmd_waivers=_engine_spmd_waivers(engine, "apply")))
     return targets
 

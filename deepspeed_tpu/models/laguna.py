@@ -126,6 +126,18 @@ class ExpertStack:
     share (``self.config``, ``self.moe``): the engine's byte budget and
     the wrapper every group's body runs in."""
 
+    # What the engine reads before it plans its weight copy
+    # (runtime/engine.py ``_plan_weight_copy``): these models' grad
+    # programs cast the master themselves.  Launched on the engine's bf16
+    # copy, the v5e compiler's program for the same step is slower than
+    # the cast it saves (my chip runs, PR 48, PERF.md section 6:
+    # ``laguna-xs2.s8k`` 561 to 614 and 633 ms a step with ``moe_ms`` 73.7
+    # to 117.8, ``glm47-flash.s8k`` 688.3 to 693.4): with the weights as
+    # parameters it prefetches 50 slices of activations to the fast memory
+    # where it prefetched 137 (the compiled texts, ahead of time).
+    casts_own_weights = ("the routed experts' grad program, which the v5e "
+                         "compiler schedules worse on bf16 parameters")
+
     def __init__(self, config, moe):
         self.config = config
         self.moe = moe

@@ -45,8 +45,11 @@ its scope (``PARTS``: the name the program writes with
          differential combination and its norm; the output projection
          with bias, dropout and residual.
   cast   outside every scope: the engine's casts of the weights to the
-         compute dtype in the grad programs (runtime/engine.py
-         ``_cast_weights``), and their transposes.
+         compute dtype (runtime/engine.py ``_cast_weights``): in the
+         default apply program, which writes the copy that the grad
+         program reads, once an optimizer step; in the grad programs of
+         the paths that keep no copy, once a micro-batch, with their
+         transposes.
   stack  outside every scope and written by no ``named_scope``: a
          ``lax.scan`` slices its own stacked operands, so an instruction
          whose path ENDS directly in a ``while`` body with one of the

@@ -195,7 +195,9 @@ def working_set_bytes(tokens: int, width: int, num_layers: int,
                       cast_bytes: int = 0) -> int:
     """Bytes a device holds in a step beside the engine's state and the
     saved residuals: ``cast_bytes`` (the compute-dtype copy of the
-    weights), and for each of the device's ``tokens`` a layer-input carry
+    weights where the grad program casts it; 0 where the engine keeps
+    the copy, which is then among its state), and for each of the
+    device's ``tokens`` a layer-input carry
     a layer, LAYER_WIDTHS more activations of ``width`` and a row of fp32
     logits ``head_width`` wide; MARGIN_BYTES on top.  It grows with the
     batch, the width and the depth as the grad program does, so a job
@@ -232,10 +234,11 @@ class RematBudget:
     """Bytes a device may spend on a layer scan's saved residuals, from
     steady quantities only: the backend's ``bytes_limit``, less
     ``state_bytes`` (what the engine itself placed there: parameters,
-    optimizer state, gradient buffers; summed from its pytrees), less the
-    working set of the traced program (``working_set_bytes`` of its
-    shapes and of ``cast_bytes``, the engine's compute-dtype copy of the
-    weights; tests hand a fixed ``working_set`` in).  Never the
+    optimizer state, gradient buffers, the compute-dtype copy of the
+    weights where the apply program writes it; summed from its pytrees),
+    less the working set of the traced program (``working_set_bytes`` of
+    its shapes and of ``cast_bytes``, that copy where the grad program
+    casts it; tests hand a fixed ``working_set`` in).  Never the
     allocator's ``bytes_in_use``: it holds whatever ran before and would
     give two runs of one job two programs.  ``bytes_limit`` None (a
     backend that reports no limit) means no budget.  ``batch_shards``:

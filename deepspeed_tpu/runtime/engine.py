@@ -6,7 +6,9 @@ gradient-accumulation loss scaling (:1204), checkpoint save/load (:1880-2430).
 
 TPU-native architecture: instead of an nn.Module wrapper with autograd hooks,
 the engine owns
-  - fp32 master parameters as a sharded pytree (ZeRO stage decides sharding),
+  - fp32 master parameters as a sharded pytree (ZeRO stage decides sharding)
+    and, on the default path, their compute-dtype copy, which the apply
+    program writes and the grad program reads,
   - an optax optimizer whose state is sharded per stage,
   - three compiled programs:
       _grad_fn   — value_and_grad of the (loss-scaled) model loss; XLA turns
@@ -103,12 +105,20 @@ def _tree_cast(tree, dtype):
 
 
 def _cast_weights(params, dtype):
-    """``_tree_cast`` of a grad program's weights, under the name that
-    profiling/scope_map.py reads as part ``cast``: once a micro-batch
-    the fp32 master weights go to the compute dtype, outside every scope
-    of the model."""
+    """``_tree_cast`` of a program's weights, under the name that
+    profiling/scope_map.py reads as part ``cast``: the fp32 master
+    weights go to the compute dtype, outside every scope of the model.
+    Once an optimizer step in the default apply program, whose copy the
+    grad program reads (``DeepSpeedEngine._plan_weight_copy``; a leaf
+    already in ``dtype`` passes through and costs nothing); once a
+    micro-batch in the grad programs of the paths that keep no copy."""
     with jax.named_scope(scope_map.CAST_SCOPE):
         return _tree_cast(params, dtype)
+
+
+def _masked_leaves(tree, mask):
+    """The leaves of ``tree`` where ``mask``, a list over them, is True."""
+    return [x for x, m in zip(jax.tree.leaves(tree), mask) if m]
 
 
 @jax.jit
@@ -358,6 +368,17 @@ class DeepSpeedEngine:
                 self.tx.init, out_shardings=self.opt_shardings)(self.params)
         self.scaler_state = jax.device_put(
             scaler_state, self.mesh_ctx.replicated())
+        # ---- leaves the optimizer does not own ------------------------ #
+        # A model may declare leaves of its parameter tree that take no
+        # optimizer update and are moved, once an optimizer step and
+        # after it, by a pure function of the step's summed RoutingStats
+        # (``model.optimizer_exempt()``: a selection bias moved by the
+        # experts' counts).  They stay in ``self.params``.
+        self._exempt = self._resolve_optimizer_exempt()
+        self._exempt_stats = None
+        # which leaves the apply program also writes in the compute
+        # dtype, or why this engine's grad program casts for itself
+        self._copy_mask, self._copy_refused = self._plan_weight_copy()
         self._remat_budget = None
         if (hasattr(model, "install_remat_budget")
                 and model.config.activation_checkpointing):
@@ -438,14 +459,6 @@ class DeepSpeedEngine:
         # (step_programs(); profiling/scope_map.py reads them)
         self._launched = {}
         scope_map.register(self)
-        # ---- leaves the optimizer does not own ------------------------ #
-        # A model may declare leaves of its parameter tree that take no
-        # optimizer update and are moved, once an optimizer step and
-        # after it, by a pure function of the step's summed RoutingStats
-        # (``model.optimizer_exempt()``: a selection bias moved by the
-        # experts' counts).  They stay in ``self.params``.
-        self._exempt = self._resolve_optimizer_exempt()
-        self._exempt_stats = None
         # ---- the model's own counters --------------------------------- #
         # Scalars of the dict a model's apply returns beside its loss,
         # named in its ``aux_counters``: they leave the grad program with
@@ -832,6 +845,88 @@ class DeepSpeedEngine:
         return bool(self.config.bf16.enabled
                     and self.config.bf16.grads_in_compute_dtype)
 
+    # -- the weights as the grad program reads them --------------------- #
+    # The master changes once an optimizer step, so the default apply
+    # program writes its compute-dtype copy in the pass that writes the
+    # master, and every micro-batch's grad program reads that copy where
+    # it used to cast the whole master for itself.  ``_copy_mask`` says
+    # which leaves have a copy (None: this engine keeps no copy and its
+    # grad program casts, ``_copy_refused`` names the path); ``_weights``
+    # is the tree the grad program is launched on, the copies beside the
+    # stored leaves that have none.  It is state of the engine like
+    # ``params``, and never saved: a checkpoint holds the master alone.
+    _copy_mask = None
+    _copy_refused = None
+    _copy_fn = None
+    _weights = None
+
+    @property
+    def params(self):
+        return self._params
+
+    @params.setter
+    def params(self, tree):
+        """Every writer of the master but the apply program comes through
+        here (construction, ``load_checkpoint``, the quantizer, a caller's
+        ``engine.params = ...``), and the copy is cast anew."""
+        self._params = tree
+        if self._copy_fn is not None:
+            self._weights = None  # the old copy goes before the new one comes
+            self._weights = self._with_copy(self._copy_fn(tree))
+
+    def _plan_weight_copy(self):
+        """``(mask, refused)``: a list of booleans over the master's
+        leaves, True where the apply program also writes the leaf in the
+        compute dtype, or ``(None, why)`` where this engine's grad
+        program keeps its own ``_cast_weights``: a model that says so
+        (``casts_own_weights``) and the paths that build a grad or an
+        apply program of their own, told by what the engine can see when
+        it builds its programs."""
+        # the model's own word (a class attribute, the reason in a
+        # phrase), where the copy is measured to cost more than the cast
+        own = getattr(self.module, "casts_own_weights", None)
+        for refused, why in (
+                (own, own),
+                (getattr(self, "_custom_grad_program", None) is not None,
+                 "the pipeline engine's custom grad program"),
+                (self._zero3_stream is not None,
+                 "the streamed ZeRO-3 layer scan, where a chip casts its "
+                 "own shard"),
+                (self._offload_enabled,
+                 "the host-side offload optimizer, which has no compiled "
+                 "apply program"),
+                (self.config.zero_config.low_bandwidth.onebit,
+                 "the 1-bit tier's phase programs"),
+                (self.config.fused_step_config.enabled,
+                 "fused_step, where the cast and the update share one "
+                 "program"),
+                (self.config.sparse_gradients_enabled,
+                 "the sparse_gradients shard_map region")):
+            if refused:
+                return None, why
+        leaves = jax.tree.leaves(self._params)
+        exempt = (self._exempt.mask if self._exempt is not None
+                  else [False] * len(leaves))
+        mask = [bool(jnp.issubdtype(x.dtype, jnp.floating)
+                     and x.dtype != self.compute_dtype and not own)
+                for x, own in zip(leaves, exempt)]
+        if not any(mask):
+            return None, "no leaf differs from the compute dtype"
+        return mask, None
+
+    def _with_copy(self, copies):
+        """``_weights`` from the apply program's ``copies``: the stored
+        leaf wherever there is none."""
+        copies = iter(copies)
+        leaves, treedef = jax.tree.flatten(self._params)
+        return treedef.unflatten([
+            next(copies) if m else x
+            for x, m in zip(leaves, self._copy_mask)])
+
+    def _grad_weights(self):
+        """What a grad program is launched on."""
+        return self._params if self._weights is None else self._weights
+
     def _build_remat_budget(self):
         """What the model's layer scan may spend on saved residuals
         (activation_checkpointing.RematBudget): the memory limit of this
@@ -858,15 +953,18 @@ class DeepSpeedEngine:
             self.params, self.grad_shardings,
             self.compute_dtype if self._grads_half else None) * (
             2 if self.gradient_accumulation_steps() > 1 else 1)
-        # the grad program's compute-dtype copy of the weights
+        # the compute-dtype copy of the weights: the engine's state where
+        # the apply program writes it, else the grad program's own
         cast = per_device([x for x in jax.tree.leaves(self.params)
                            if x.dtype != self.compute_dtype],
                           dtype=self.compute_dtype)
+        kept = self._copy_mask is not None
         return RematBudget(
             device_bytes_limit(jax.local_devices()[0]),
             state_bytes=(per_device(self.params)
-                         + per_device(self.opt_state) + grads),
-            batch_shards=self.world_size, cast_bytes=cast)
+                         + per_device(self.opt_state) + grads
+                         + (cast if kept else 0)),
+            batch_shards=self.world_size, cast_bytes=0 if kept else cast)
 
     # ------------------------------------------------------------------ #
     # compiled programs
@@ -937,7 +1035,13 @@ class DeepSpeedEngine:
                     "sparse_gradients enabled but the model declares no "
                     "sparse_grad_paths — falling back to dense reduction")
 
+        master_dtypes = jax.tree.map(lambda x: x.dtype, self._params)
+
         def loss_and_grads(params, scaler_state, rng, *args, **kwargs):
+            """``params``: the master, which is then cast here (the fused
+            step's scan body, the Program Auditor, the paths that keep no
+            copy), or the engine's ``_weights``, whose copies pass
+            through ``_cast_weights`` as they are."""
             # inputs follow the compute dtype too — otherwise f32 activations
             # silently promote every matmul back to f32 and the MXU runs fp32
             args = _tree_cast(args, compute_dtype)
@@ -987,6 +1091,12 @@ class DeepSpeedEngine:
                 return scaled, (loss, stats, aux)
             (_, (loss, stats, aux)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
+            # launched on the engine's copy, the gradients come in the
+            # copy's dtype: the cast's transpose, which the copy's
+            # writer has not got, is this convert (on the master itself
+            # it is none)
+            grads = jax.tree.map(lambda g, dtype: g.astype(dtype), grads,
+                                 master_dtypes)
             if prescale and predivide:
                 grads = jax.tree.map(lambda g: g / predivide, grads)
             extras = ((stats,) if moe_stats or exempt is not None else ()) + (
@@ -1120,6 +1230,11 @@ class DeepSpeedEngine:
             self._grad_norm_fn = jax.jit(global_grad_norm,
                                          out_shardings=replicated)
 
+        mask = self._copy_mask
+        if mask is None:
+            log_dist(
+                "weight copy: none, the grad program casts the master "
+                f"itself ({self._copy_refused})", ranks=[0])
         if self._offload_enabled:
             # Offload path: the optimizer step is host-side (HostOffload /
             # NVMe swapper); no compiled apply program.
@@ -1179,18 +1294,54 @@ class DeepSpeedEngine:
         # compile time.  The donation is still wanted — grad buffers become
         # in-place scratch for the unscale/update temporaries — and the
         # expected warning is filtered once, on first engine build
-        # (_install_donation_warning_filter at top of file).
+        # (_install_donation_warning_filter at top of file).  Where the
+        # program writes the weights' copy too, the old copy is donated
+        # and the new one takes its buffers (or, under bf16 gradient
+        # buffers, a gradient's: same shapes, same dtype).
         _install_donation_warning_filter()
         # un-jitted apply body reused as the fused program's epilogue;
         # the donate tuple is recorded for the Program Auditor's donation
         # rule (analysis/auditor.py) so the audit reflects the dispatch
-        self._apply_core = apply_step
+        self._apply_core = apply_core = apply_step
         self._apply_donate_argnums = (0, 1, 3)
+        apply_shardings = (self.param_shardings, self.opt_shardings,
+                           replicated, replicated)
+        if mask is None:
+            self._apply_fn = jax.jit(
+                apply_step, out_shardings=apply_shardings,
+                donate_argnums=self._apply_donate_argnums)
+            return
+
+        def weight_copy(params):
+            return _cast_weights(_masked_leaves(params, mask), compute_dtype)
+
+        def apply_step(params, opt_state, scaler_state, grads, weights=None,
+                       healthy=None, stats=None):
+            """``apply_core`` and, in the pass that writes the new master,
+            its compute-dtype copy.  ``weights``: the old copy, for its
+            buffers alone."""
+            new_params, *rest = apply_core(params, opt_state, scaler_state,
+                                           grads, healthy, stats)
+            return (new_params, *rest, weight_copy(new_params))
+
+        copy_shardings = _masked_leaves(self.param_shardings, mask)
+        self._apply_donate_argnums = (0, 1, 3, 4)
+        # keep_unused: a donated buffer that the program does not read
+        # (the old copy) still goes to an output
         self._apply_fn = jax.jit(
-            apply_step,
-            out_shardings=(self.param_shardings, self.opt_shardings,
-                           replicated, replicated),
-            donate_argnums=self._apply_donate_argnums)
+            apply_step, out_shardings=apply_shardings + (copy_shardings,),
+            donate_argnums=self._apply_donate_argnums, keep_unused=True)
+        # no step program: launched where the master is written outside
+        # the apply (the ``params`` setter), construction being the first
+        self._copy_fn = jax.jit(weight_copy, out_shardings=copy_shardings)
+        copies = self._copy_fn(self._params)
+        self._weights = self._with_copy(copies)
+        log_dist(
+            f"weight copy: {_program_name(apply_step)} writes "
+            f"{len(copies)} of {len(mask)} leaves, "
+            f"{sum(x.nbytes for x in copies):,} B, in "
+            f"{jnp.dtype(compute_dtype).name} for "
+            f"{_program_name(loss_and_grads)} to read", ranks=[0])
 
     # ------------------------------------------------------------------ #
     # 1-bit optimizer wire tier (docs/onebit.md)
@@ -1710,8 +1861,8 @@ class DeepSpeedEngine:
                 prof = FlopsProfiler(config=fp_cfg)
                 prof.set_params(self.params)
                 prof.start_profile()
-                prof.profile_fn(self._grad_fn, self.params, self.scaler_state,
-                                rng, *args, **kwargs)
+                prof.profile_fn(self._grad_fn, self._grad_weights(),
+                                self.scaler_state, rng, *args, **kwargs)
             if (self.eigenvalue is not None and self.quantizer is not None
                     and self._is_train_mode):
                 # curvature probes re-run the loss on the latest TRAIN batch;
@@ -1724,8 +1875,8 @@ class DeepSpeedEngine:
                 grad_fn = self._onebit_programs["grad_fn"]
             with self._grad_launch(grad_fn), self._span(
                     "forward.dispatch", program=_program_name(grad_fn)):
-                out = self._launch(grad_fn, self.params, self.scaler_state,
-                                   rng, *args, **kwargs)
+                out = self._launch(grad_fn, self._grad_weights(),
+                                   self.scaler_state, rng, *args, **kwargs)
             loss, grads, *extras = out
             if self._exempt is not None or self._moe_stats_enabled:
                 moe_stats = extras.pop(0)
@@ -1845,12 +1996,20 @@ class DeepSpeedEngine:
                     "emit_routing_stats), so its exempt leaves cannot be "
                     "moved")
             counted = {} if self._exempt is None else {"stats": stats}
+            # the old copy, donated; written past the setter, the apply
+            # program having cast the new one
+            copy = (() if self._weights is None else (
+                _masked_leaves(self._weights, self._copy_mask),))
             with self._span("step.dispatch",
                             program=_program_name(self._apply_fn)):
-                (self.params, self.opt_state, self.scaler_state,
-                 overflow) = self._launch(
-                    self._apply_fn, self.params, self.opt_state,
-                    self.scaler_state, self._grad_acc, *verdict, **counted)
+                out = self._launch(
+                    self._apply_fn, self._params, self.opt_state,
+                    self.scaler_state, self._grad_acc, *copy, *verdict,
+                    **counted)
+            (self._params, self.opt_state, self.scaler_state,
+             overflow) = out[:4]
+            if copy:
+                self._weights = self._with_copy(out[4])
         with self._span("step.bookkeeping"):
             self._after_apply(overflow, sentinel_skip, lr_kwargs)
         if self.wall_clock_breakdown():

@@ -323,7 +323,7 @@ FAMILIES = {
 UNSPLIT_ALLOWED = 4
 
 
-def _check_parts(found, want):
+def _check_parts(found, want, casts=False):
     attn = found["attn"]
     layout = attn["layout"] + found["layer"]["layout"]
     named = {part for part in attn if part} | ({"layout"} if layout else
@@ -331,8 +331,11 @@ def _check_parts(found, want):
     assert named == want
     assert attn[None] <= UNSPLIT_ALLOWED, attn
     assert sum(attn.values()) > 100 * UNSPLIT_ALLOWED
-    # outside every scope: the weight casts, and nowhere else a part
-    assert found["other"]["cast"] > 0
+    # outside every scope no part but the scan's, and the weight casts
+    # where the model keeps them in its grad program (Laguna's does:
+    # ``ExpertStack.casts_own_weights``); elsewhere they are the apply
+    # program's (tests/unit/test_weight_copy.py)
+    assert (found["other"]["cast"] > 0) == casts
     assert set(found["other"]) <= {"cast", "stack", None}
     for scope, parts in found.items():
         if scope not in ("attn", "layer", "other"):
@@ -344,7 +347,7 @@ def _check_parts(found, want):
 def test_the_parts_of_a_grad_program(family):
     build, want = FAMILIES[family]
     found = _parts_by_scope(build)
-    _check_parts(found, want)
+    _check_parts(found, want, casts=family == "laguna")
     if family != "phi4flash":  # XLA unrolls its toy's two-trip scan
         assert found["other"]["stack"] > 0
 
