@@ -145,7 +145,7 @@ def main():
     # does NOT toggle (it is plain XLA either way, but with a
     # hand-written VJP worth isolating)
     fused = bool(int(os.environ.get("DS_CONV_FUSED", "1")))
-    # PRODUCTION optimization config (r4 chip sweep, docs/ROUND4_NOTES.md):
+    # PRODUCTION optimization config (r4 chip sweep; git keeps the notes):
     # at 8192 tokens/step, lr 6e-4 (and 3e-4) pins the model on the
     # ln(support)=8.32 unigram shelf — trajectories identical across
     # fp32/bf16/Pallas/XLA, so pure dynamics, not numerics; 2e-4 + clip

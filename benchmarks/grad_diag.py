@@ -1,6 +1,6 @@
 """One-step gradient A/B: Pallas kernels vs forced-XLA, same batch.
 
-Round-4 convergence triage (docs/ROUND4_NOTES.md): GPT-2 124M on the chip
+Round-4 convergence triage (git keeps the notes): GPT-2 124M on the chip
 plateaus at the support entropy ln(4096) — it never learns even
 p(next|prev), a task the residual path alone (embedding -> FFN -> logits)
 can solve.  The dropout-OFF probe plateaus too, so the in-kernel dropout

@@ -253,7 +253,7 @@ def run_phase(name, size, devices, stage, interpret, peaks):
     say(f"    flash kernels in the grad program: {counts}")
     if plan is not None:
         say(f"    ZeRO-3 stream: {plan.num_layers} layers in groups of "
-            f"{plan.layers_per_step}, prefetch mode {plan.mode}")
+            f"{plan.layers_per_step}, prefetch {plan.prefetch}")
     for tree, (total, held) in state.items():
         say(f"    {tree}: {mib([total])} in all, per device {mib(held)}")
     say(f"    bytes in use after init, per device: {mib(in_use)}")

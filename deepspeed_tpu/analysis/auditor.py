@@ -76,7 +76,6 @@ def _engine_scan_info(engine) -> dict:
         info["zero3_streaming"] = {
             "layers_per_step": plan.layers_per_step,
             "prefetch": plan.prefetch,
-            "mode": plan.mode,
             "forfeited": plan.forfeited,
             "num_layers": plan.num_layers,
             "params_per_layer": plan.params_per_layer,

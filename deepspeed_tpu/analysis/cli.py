@@ -120,7 +120,7 @@ _SCHEDULE_COMMENT = (
     "Schedule golden of the streamed-ZeRO-3 gpt2 config "
     "(docs/examples/gpt2_zero3_stream_analysis.json, --devices 8): the "
     "explicit per-layer gathers plus their overlap classification under "
-    "stage3_prefetch_mode=carried (docs/zero3_streaming.md). "
+    "the carried prefetch (docs/zero3_streaming.md). "
     "n_serialized_hot_loop == 0 with n_carried > 0 pins the carried "
     "double-buffer schedule — every hot-loop weight gather is issued one "
     "group ahead through the scan carry, forward and backward — and "

@@ -319,12 +319,13 @@ def overlap_rule_findings(records: List[CollectiveOverlap], cfg,
     plus a warning when the streamed-ZeRO-3 plan FORFEITED a requested
     prefetch (the fallback would otherwise be silent).
 
-    With ``stage3_prefetch_mode: carried`` (the default) the streamed
-    layer scan issues group i+1's gather into the scan carry under
-    group i's compute — in both directions — so its hot-loop gathers
-    classify as ``carried`` and this rule stays silent; the serialized
-    shape survives in ``unrolled``/``off`` modes and is what
-    ``require_overlap`` gates in CI."""
+    With prefetch (``stage3_prefetch_bucket_size`` covering a layer
+    group, the default) the streamed layer scan issues group i+1's
+    gather into the scan carry under group i's compute — in both
+    directions — so its hot-loop gathers classify as ``carried`` and
+    this rule stays silent; the serialized shape survives where the
+    groups are gathered at use and is what ``require_overlap`` gates in
+    CI."""
     out: List[Finding] = []
     severity = "error" if cfg.require_overlap else "warning"
     plan = (scan_info or {}).get("zero3_streaming")
@@ -339,9 +340,8 @@ def overlap_rule_findings(records: List[CollectiveOverlap], cfg,
             target=next(r.target for r in records
                         if r.loop_depth > 0
                         and r.prim in _WIRE_GATHER_PRIMS),
-            # the forfeit reason itself names the failed constraint (and,
-            # for the unrolled even-group case, that carried mode lifts
-            # it) — the hint covers the budget levers common to all modes
+            # the forfeit reason itself names the failed constraint; the
+            # hint covers the budget levers
             fix_hint=("raise stage3_max_live_parameters / "
                       "stage3_prefetch_bucket_size until a double-buffer "
                       "budget fits — the finding names the constraint "
@@ -353,8 +353,7 @@ def overlap_rule_findings(records: List[CollectiveOverlap], cfg,
         if plan is not None and r.prim in _WIRE_GATHER_PRIMS:
             plan_note = (f" (streamed ZeRO-3 plan: groups of "
                          f"{plan['layers_per_step']}, "
-                         f"prefetch={plan['prefetch']}, "
-                         f"mode={plan.get('mode', 'off')})")
+                         f"prefetch={plan['prefetch']})")
         out.append(Finding(
             rule=RULE_OVERLAP, severity=severity,
             message=(f"collective `{r.prim}` ({r.wire_bytes} B x{r.mult}) "
@@ -366,8 +365,9 @@ def overlap_rule_findings(records: List[CollectiveOverlap], cfg,
                      + plan_note),
             target=r.target, scope=r.scope,
             fix_hint=("issue the gather for iteration i+1 under "
-                      "iteration i's compute (stage3_prefetch_mode="
-                      "carried, the double-buffered carry prefetch), or "
+                      "iteration i's compute (a stage3_prefetch_bucket_"
+                      "size that covers a layer group: the double-"
+                      "buffered carry prefetch), or "
                       "shrink the wire (qwZ/hpZ) until the slack "
                       "covers it")))
     return out

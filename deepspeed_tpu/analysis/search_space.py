@@ -101,15 +101,13 @@ def _deep_merge(dst: Dict[str, Any], overlay: Dict[str, Any]) -> None:
             dst[k] = copy.deepcopy(v)
 
 
-def _candidate_name(stage, streamed, pmode, bucket, micro, gas, data,
+def _candidate_name(stage, streamed, bucket, micro, gas, data,
                     model, expert, qwz, qgz, hpz, fused, offload,
                     pdepth, odepth, multi_bucket, fcm=False,
                     onebit=False) -> str:
     bits = [f"z{stage}" + ("s" if streamed else "")]
-    if streamed:
-        bits.append(pmode)
-        if multi_bucket:
-            bits.append(f"g{bucket}")
+    if streamed and multi_bucket:
+        bits.append(f"g{bucket}")
     bits.append(f"b{micro}x{gas}")
     bits.append(f"d{data}m{model}e{expert}")
     if qwz:
@@ -132,7 +130,7 @@ def _candidate_name(stage, streamed, pmode, bucket, micro, gas, data,
     return "-".join(bits)
 
 
-def _build_config(base: Dict[str, Any], *, stage, streamed, pmode,
+def _build_config(base: Dict[str, Any], *, stage, streamed,
                   bucket, micro, gas, data, model, expert, qwz, qgz,
                   hpz, fused, offload, pdepth, odepth,
                   fixed, fcm=False, onebit=False) -> Dict[str, Any]:
@@ -152,7 +150,6 @@ def _build_config(base: Dict[str, Any], *, stage, streamed, pmode,
     for key in (C.ZERO_OPTIMIZATION_PARAM_PERSISTENCE_THRESHOLD,
                 C.ZERO_OPTIMIZATION_MAX_LIVE_PARAMETERS,
                 C.ZERO_OPTIMIZATION_PREFETCH_BUCKET_SIZE,
-                C.ZERO_OPTIMIZATION_PREFETCH_MODE,
                 C.ZERO_OPTIMIZATION_LOW_BANDWIDTH,
                 C.ZERO_OPTIMIZATION_OFFLOAD_PARAM,
                 C.ZERO_OPTIMIZATION_OFFLOAD_OPTIMIZER):
@@ -161,7 +158,6 @@ def _build_config(base: Dict[str, Any], *, stage, streamed, pmode,
         zo[C.ZERO_OPTIMIZATION_PARAM_PERSISTENCE_THRESHOLD] = 0
         zo[C.ZERO_OPTIMIZATION_MAX_LIVE_PARAMETERS] = bucket
         zo[C.ZERO_OPTIMIZATION_PREFETCH_BUCKET_SIZE] = bucket
-        zo[C.ZERO_OPTIMIZATION_PREFETCH_MODE] = pmode
     lb = {}
     if qwz:
         lb[C.LOW_BANDWIDTH_QWZ_BITS] = qwz
@@ -302,7 +298,6 @@ def enumerate_candidates(base: Dict[str, Any], tune_cfg,
             else:
                 variants = [False]
             for streamed in variants:
-                pmodes = tune_cfg.prefetch_modes if streamed else (None,)
                 buckets = (tune_cfg.stage3_bucket_sizes if streamed
                            else (None,))
                 # qwZ/hpZ modulate the streamed stage-3 weight gathers;
@@ -314,9 +309,9 @@ def enumerate_candidates(base: Dict[str, Any], tune_cfg,
                 fcms = (tuple(sorted(set(
                     tune_cfg.fused_collective_matmul)))
                     if streamed else (False,))
-                for (pmode, bucket, micro_gas, qwz, qgz, hpz, fcm,
+                for (bucket, micro_gas, qwz, qgz, hpz, fcm,
                      offload) in itertools.product(
-                        pmodes, buckets, splits, qwzs, qgzs, hpzs, fcms,
+                        buckets, splits, qwzs, qgzs, hpzs, fcms,
                         tune_cfg.offload):
                     micro, gas = micro_gas
                     if (offload == C.AUTOTUNING_OFFLOAD_TIER_NVME
@@ -352,13 +347,13 @@ def enumerate_candidates(base: Dict[str, Any], tune_cfg,
                                               onebits):
                         space.n_enumerated += 1
                         name = _candidate_name(
-                            stage, streamed, pmode, bucket, micro, gas,
+                            stage, streamed, bucket, micro, gas,
                             data, model, expert, qwz, qgz, hpz, fused,
                             offload, pdepth, odepth, multi_bucket,
                             fcm=fcm, onebit=onebit)
                         cfg = _build_config(
                             base, stage=stage, streamed=streamed,
-                            pmode=pmode, bucket=bucket, micro=micro,
+                            bucket=bucket, micro=micro,
                             gas=gas, data=data, model=model,
                             expert=expert, qwz=qwz, qgz=qgz, hpz=hpz,
                             fused=fused, offload=offload, pdepth=pdepth,
@@ -374,7 +369,6 @@ def enumerate_candidates(base: Dict[str, Any], tune_cfg,
                             knobs={
                                 "zero_stage": stage,
                                 "streamed": streamed,
-                                "prefetch_mode": pmode,
                                 "stage3_bucket": bucket,
                                 "micro_batch": micro, "gas": gas,
                                 "mesh": {"data": data, "model": model,
@@ -392,7 +386,7 @@ def enumerate_candidates(base: Dict[str, Any], tune_cfg,
         raise AutotuneError(
             f"search space has {len(space.candidates)} candidates, over "
             f"autotuning.max_candidates={tune_cfg.max_candidates} — "
-            "narrow the axes (zero_stages, prefetch_modes, qwz_bits, "
+            "narrow the axes (zero_stages, stage3_bucket_sizes, qwz_bits, "
             "micro_batches, ...) or raise the cap explicitly; the "
             "autotuner never truncates silently")
     return space

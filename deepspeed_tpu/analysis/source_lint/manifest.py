@@ -193,9 +193,6 @@ DEGRADATION_WAIVERS = {
     ("deepspeed_tpu/utils/logging.py", "_process_index"):
         "jax absent or uninitialized at log-format time; rank label "
         "defaults to 0",
-    ("deepspeed_tpu/utils/timer.py",
-     "SynchronizedWallClockTimer.memory_usage"):
-        "debug memory probe for a log line",
 }
 
 # ---------------------------------------------------------------- #

@@ -260,13 +260,28 @@ class ZeroLowBandwidthConfig:
         return cfg
 
 
-def _validated_prefetch_mode(mode: str) -> str:
-    if mode not in C.ZERO_OPTIMIZATION_PREFETCH_MODES:
-        raise DeepSpeedConfigError(
-            f"zero_optimization.{C.ZERO_OPTIMIZATION_PREFETCH_MODE}="
-            f"{mode!r} — supported modes are "
-            f"{list(C.ZERO_OPTIMIZATION_PREFETCH_MODES)}")
-    return mode
+# Keys this package once read and no longer does: (section, key) -> what
+# to write instead.  Unknown keys are otherwise ignored silently, and an
+# ignored "stage3_prefetch_mode": "off" would turn prefetch ON.
+REMOVED_KEYS = {
+    (C.ZERO_OPTIMIZATION, "stage3_prefetch_mode"): (
+        f"the streamed ZeRO-3 scan prefetches whenever "
+        f"{C.ZERO_OPTIMIZATION_PREFETCH_BUCKET_SIZE} covers a layer group "
+        f"(what \"carried\" did); set "
+        f"{C.ZERO_OPTIMIZATION_PREFETCH_BUCKET_SIZE}: 0 to gather at use "
+        f"(what \"off\" did)"),
+    (C.AUTOTUNING, "prefetch_modes"): (
+        f"a candidate without prefetch is the entry 0 of "
+        f"{C.AUTOTUNING_STAGE3_BUCKET_SIZES} (it becomes the candidate's "
+        f"{C.ZERO_OPTIMIZATION_PREFETCH_BUCKET_SIZE})"),
+}
+
+
+def _refuse_removed_keys(section: str, d: Dict[str, Any]) -> None:
+    for (where, key), instead in REMOVED_KEYS.items():
+        if where == section and key in d:
+            raise DeepSpeedConfigError(
+                f"{section}.{key} was removed: {instead}")
 
 
 @dataclass
@@ -285,7 +300,6 @@ class ZeroConfig:
     max_live_parameters: int = C.ZERO_OPTIMIZATION_MAX_LIVE_PARAMETERS_DEFAULT
     max_reuse_distance: int = C.ZERO_OPTIMIZATION_MAX_REUSE_DISTANCE_DEFAULT
     prefetch_bucket_size: int = C.ZERO_OPTIMIZATION_PREFETCH_BUCKET_SIZE_DEFAULT
-    prefetch_mode: str = C.ZERO_OPTIMIZATION_PREFETCH_MODE_DEFAULT
     param_persistence_threshold: int = (
         C.ZERO_OPTIMIZATION_PARAM_PERSISTENCE_THRESHOLD_DEFAULT)
     gather_fp16_weights_on_model_save: bool = (
@@ -305,6 +319,7 @@ class ZeroConfig:
             d = {}
         if isinstance(d, bool):  # "zero_optimization": true → stage 1
             d = {C.ZERO_OPTIMIZATION_STAGE: 1 if d else 0}
+        _refuse_removed_keys(C.ZERO_OPTIMIZATION, d)
         stage = get_scalar_param(d, C.ZERO_OPTIMIZATION_STAGE,
                                  C.ZERO_OPTIMIZATION_STAGE_DEFAULT)
         # Legacy cpu_offload flags map onto the offload_* sub-dicts
@@ -361,9 +376,6 @@ class ZeroConfig:
             prefetch_bucket_size=int(get_scalar_param(
                 d, C.ZERO_OPTIMIZATION_PREFETCH_BUCKET_SIZE,
                 C.ZERO_OPTIMIZATION_PREFETCH_BUCKET_SIZE_DEFAULT)),
-            prefetch_mode=_validated_prefetch_mode(get_scalar_param(
-                d, C.ZERO_OPTIMIZATION_PREFETCH_MODE,
-                C.ZERO_OPTIMIZATION_PREFETCH_MODE_DEFAULT)),
             param_persistence_threshold=int(get_scalar_param(
                 d, C.ZERO_OPTIMIZATION_PARAM_PERSISTENCE_THRESHOLD,
                 C.ZERO_OPTIMIZATION_PARAM_PERSISTENCE_THRESHOLD_DEFAULT)),
@@ -1019,7 +1031,6 @@ class AutotuningConfig:
     mesh_expert: tuple = C.AUTOTUNING_MESH_EXPERT_DEFAULT
     zero_stages: tuple = C.AUTOTUNING_ZERO_STAGES_DEFAULT
     stage3_variants: tuple = C.AUTOTUNING_STAGE3_VARIANTS_DEFAULT
-    prefetch_modes: tuple = C.AUTOTUNING_PREFETCH_MODES_DEFAULT
     stage3_bucket_sizes: tuple = C.AUTOTUNING_STAGE3_BUCKET_SIZES_DEFAULT
     micro_batches: Optional[tuple] = C.AUTOTUNING_MICRO_BATCHES_DEFAULT
     qwz_bits: tuple = C.AUTOTUNING_QWZ_BITS_DEFAULT
@@ -1037,6 +1048,7 @@ class AutotuningConfig:
     @staticmethod
     def from_dict(d: Optional[Dict[str, Any]]) -> "AutotuningConfig":
         d = d or {}
+        _refuse_removed_keys(C.AUTOTUNING, d)
         chips = get_scalar_param(d, C.AUTOTUNING_CHIPS,
                                  C.AUTOTUNING_CHIPS_DEFAULT)
         gb = get_scalar_param(d, C.AUTOTUNING_GLOBAL_BATCH,
@@ -1066,9 +1078,6 @@ class AutotuningConfig:
             stage3_variants=_as_tuple(d.get(
                 C.AUTOTUNING_STAGE3_VARIANTS,
                 C.AUTOTUNING_STAGE3_VARIANTS_DEFAULT), str),
-            prefetch_modes=_as_tuple(d.get(
-                C.AUTOTUNING_PREFETCH_MODES,
-                C.AUTOTUNING_PREFETCH_MODES_DEFAULT), str),
             stage3_bucket_sizes=_as_tuple(d.get(
                 C.AUTOTUNING_STAGE3_BUCKET_SIZES,
                 C.AUTOTUNING_STAGE3_BUCKET_SIZES_DEFAULT), int),
@@ -1119,7 +1128,7 @@ class AutotuningConfig:
                 (C.AUTOTUNING_MESH_MODEL, cfg.mesh_model, 1),
                 (C.AUTOTUNING_MESH_EXPERT, cfg.mesh_expert, 1),
                 (C.AUTOTUNING_STAGE3_BUCKET_SIZES,
-                 cfg.stage3_bucket_sizes, 1),
+                 cfg.stage3_bucket_sizes, 0),   # 0: gather at use
                 (C.AUTOTUNING_NVME_PREFETCH_DEPTHS,
                  cfg.nvme_prefetch_depths, 1),
                 (C.AUTOTUNING_OPT_PIPELINE_DEPTHS,
@@ -1135,8 +1144,6 @@ class AutotuningConfig:
                 (C.AUTOTUNING_ZERO_STAGES, cfg.zero_stages, (1, 2, 3)),
                 (C.AUTOTUNING_STAGE3_VARIANTS, cfg.stage3_variants,
                  C.AUTOTUNING_STAGE3_VARIANTS_ALL),
-                (C.AUTOTUNING_PREFETCH_MODES, cfg.prefetch_modes,
-                 C.ZERO_OPTIMIZATION_PREFETCH_MODES),
                 (C.AUTOTUNING_QWZ_BITS, cfg.qwz_bits, (0, 4, 8)),
                 (C.AUTOTUNING_QGZ_BITS, cfg.qgz_bits, (0, 4, 8)),
                 (C.AUTOTUNING_OFFLOAD_TIERS, cfg.offload,

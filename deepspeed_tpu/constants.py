@@ -275,8 +275,6 @@ AUTOTUNING_STAGE3_VARIANT_STREAMED = "streamed"
 AUTOTUNING_STAGE3_VARIANTS_ALL = (AUTOTUNING_STAGE3_VARIANT_RESIDENT,
                                   AUTOTUNING_STAGE3_VARIANT_STREAMED)
 AUTOTUNING_STAGE3_VARIANTS_DEFAULT = AUTOTUNING_STAGE3_VARIANTS_ALL
-AUTOTUNING_PREFETCH_MODES = "prefetch_modes"
-AUTOTUNING_PREFETCH_MODES_DEFAULT = ("carried", "off")
 AUTOTUNING_STAGE3_BUCKET_SIZES = "stage3_bucket_sizes"
 AUTOTUNING_STAGE3_BUCKET_SIZES_DEFAULT = (200_000,)
 AUTOTUNING_MICRO_BATCHES = "micro_batches"
@@ -506,16 +504,6 @@ ZERO_OPTIMIZATION_MAX_REUSE_DISTANCE_DEFAULT = 1_000_000_000
 
 ZERO_OPTIMIZATION_PREFETCH_BUCKET_SIZE = "stage3_prefetch_bucket_size"
 ZERO_OPTIMIZATION_PREFETCH_BUCKET_SIZE_DEFAULT = 50_000_000
-
-# Prefetch program structure for the streamed layer scan
-# (zero/stage3_streaming.py): "carried" = double-buffered scan carry,
-# gathers verified statically off the critical path in both directions;
-# "unrolled" = legacy unroll-2 body (overlap left to XLA's scheduler);
-# "off" = gather at use.  Prefetch engages in any mode only when
-# stage3_prefetch_bucket_size covers a layer group.
-ZERO_OPTIMIZATION_PREFETCH_MODE = "stage3_prefetch_mode"
-ZERO_OPTIMIZATION_PREFETCH_MODE_DEFAULT = "carried"
-ZERO_OPTIMIZATION_PREFETCH_MODES = ("carried", "unrolled", "off")
 
 ZERO_OPTIMIZATION_PARAM_PERSISTENCE_THRESHOLD = "stage3_param_persistence_threshold"
 ZERO_OPTIMIZATION_PARAM_PERSISTENCE_THRESHOLD_DEFAULT = 100_000

@@ -1,7 +1,7 @@
 """Kernel-backend dispatch: which implementation an op takes, and where a
 Pallas call may sit in a sharded program.
 
-Pallas TPU kernels (flash attention, fused LN) must not lower on CPU
+Pallas TPU kernels (flash attention) must not lower on CPU
 (pallas supports only interpret mode there), and the usual gate —
 ``jax.default_backend() == "tpu"`` — is wrong in one real scenario: a
 process that touched the TPU backend first and then forced
@@ -21,15 +21,6 @@ from ..parallel.mesh import MODEL_AXIS, ZERO_AXES, get_mesh_context
 
 _force_xla = bool(int(os.environ.get("DS_FORCE_XLA_OPS", "0")))
 _interpret = False
-
-# Per-op implementation preferences, where measurement picked a default
-# that differs from "pallas wherever possible".  LayerNorm: measured on
-# v5e (round 3, 2026-07-31) the XLA LN beats the Pallas LN kernels by
-# ~2 ms on the flagship step — XLA fuses LN into neighboring elementwise
-# work, which a pallas_call is opaque to.  DS_LN_IMPL=pallas (or
-# set_ln_impl) re-enables the kernels for re-measurement on new
-# hardware/toolchains.
-_ln_impl = os.environ.get("DS_LN_IMPL", "xla")
 
 
 def force_xla_kernels(on: bool = True) -> None:
@@ -54,20 +45,6 @@ def set_pallas_interpret(on: bool) -> None:
 
 def pallas_interpret() -> bool:
     return _interpret and not _force_xla
-
-
-def set_ln_impl(impl: str) -> None:
-    """Select the LayerNorm implementation: "xla" (measured default) or
-    "pallas" (the Pallas kernels, for re-measurement)."""
-    if impl not in ("xla", "pallas"):
-        raise ValueError(f"ln impl must be 'xla' or 'pallas', got {impl!r}")
-    global _ln_impl
-    _ln_impl = impl
-
-
-def ln_impl() -> str:
-    """Active LayerNorm implementation ("xla" wins under force_xla)."""
-    return "xla" if _force_xla else _ln_impl
 
 
 # ---------------------------------------------------------------------- #

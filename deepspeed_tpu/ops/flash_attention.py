@@ -20,8 +20,7 @@ k . q^T, [keys, block_q], q rows along the LANES.  A row's running max,
 sum and rescaling factor are then [1, block_q] rows that fill their
 vector registers, a step's max and sum reduce over the sublanes, and
 out = (v^T . p)^T is transposed once a q block.  With the rows on the
-sublanes (the kernel until PR 35, kept as _fa_kernel_rows for the calls
-the new one cannot serve) every per-row number was a cross-lane
+sublanes (the kernel until PR 35) every per-row number was a cross-lane
 reduction and then a [block_q, 1] column, one lane in 128 at work, and
 that was six tenths of the kernel: 0.435 ms a call at [4, 20, 1024, 64],
 causal, dropout 0.1, on the v5e against 0.287 now (PERF.md section 6,
@@ -128,12 +127,12 @@ def _kv_group(q_heads: int, kv_heads: int) -> int:
 # --------------------------------------------------------------------------- #
 # Pallas kernel
 # --------------------------------------------------------------------------- #
-def _ld(ref, rows=None, cols=False):
+def _ld(ref, rows=None):
     """Load the [rows, d] tile from a (1, 1, rows, d) block, or the rows
-    `rows` (a static slice) of it; with `cols`, its columns `rows`."""
+    `rows` (a static slice) of it."""
     if rows is None:
         return ref[0, 0]
-    return ref[0, 0, :, rows] if cols else ref[0, 0, rows, :]
+    return ref[0, 0, rows, :]
 
 
 def _st(ref, val):
@@ -266,65 +265,26 @@ def causal_sub_tile_shares(q_len, k_len, block_q, block_k, causal):
             "flash_bwd_dq": shares(True)}
 
 
-# Dropout PRNG width: 8 (default since r4 session 2) generates one
-# random word per FOUR mask positions and compares bytes — 4x fewer
-# PRNG words in each of the three kernels that regenerate the mask,
-# bias-corrected by the exact quantized keep probability; 32 is one
-# word per mask BIT (the conservative fallback, and forced whenever
-# block_k % 4 != 0 — _effective_dropout_bits).  Chip-validated r4 at
-# both widths (statistics + FD); flagship A/B: 86.99 vs 84.67 TFLOPS
-# dropout-on (+2.7%).  Flip with DS_DROPOUT_BITS or set_dropout_bits;
-# the mode is read at TRACE time, so fwd and bwd of one step always
-# agree (both trace under one jit).
-def _parse_dropout_bits(raw: str) -> int:
-    try:
-        n = int(raw)
-    except ValueError:
-        raise ValueError(
-            f"DS_DROPOUT_BITS={raw!r}: must be 8 or 32") from None
-    if n not in (8, 32):
-        raise ValueError(f"DS_DROPOUT_BITS must be 8 or 32, got {n}")
-    return n
-
-
-_DEFAULT_DROPOUT_BITS = 8
-_dropout_bits = _parse_dropout_bits(
-    os.environ.get("DS_DROPOUT_BITS", str(_DEFAULT_DROPOUT_BITS)))
-
-
-def set_dropout_bits(n: int) -> None:
-    """Select the in-kernel dropout PRNG width (8 default — 4x cheaper
-    mask generation at 1/256 keep-probability granularity, bias-corrected
-    by the exact quantized scale; 32 = one word per bit).
-
-    Read at TRACE time: already-jit-compiled functions keep the width
-    they were traced with (XLA caches the executable) — re-trace (fresh
-    jax.jit, or new shapes) after flipping for the change to take
-    effect."""
-    if n not in (8, 32):
-        raise ValueError(f"dropout bits must be 8 or 32, got {n}")
-    global _dropout_bits
-    _dropout_bits = n
-
-
-def dropout_bits() -> int:
-    return _dropout_bits
-
-
-def _quantized_threshold(rate: float, bits: int) -> int:
-    """The integer threshold the kernel compares random values against —
+# The dropout draw: one PRNG word per FOUR mask positions, compared byte
+# by byte against the quantized keep probability (1/256 granularity,
+# corrected by the exact inverse scale, _keep_scale), a quarter of the
+# PRNG words of a draw per position in each of the three kernels that
+# regenerate the mask (v5e, round 4: +2.7% on the flagship step).  The
+# dispatcher keeps every key block the kernels see a multiple of 128
+# columns (_use_pallas: any other length goes to XLA), so a block always
+# holds whole words.
+def _quantized_threshold(rate: float) -> int:
+    """The integer threshold the kernel compares random bytes against —
     the ONE definition shared by mask generation and its inverse scale
     (two copies drifting apart would bias E[output])."""
-    if bits == 8:
-        return max(1, min(256, round((1.0 - rate) * 256)))
-    return min(int((1.0 - rate) * 2 ** 32), 2 ** 32 - 1)
+    return max(1, min(256, round((1.0 - rate) * 256)))
 
 
-def _keep_scale(rate: float, bits: int) -> float:
+def _keep_scale(rate: float) -> float:
     """Exact inverse keep-probability for the quantized threshold the
     kernel actually compares against — using 1/(1-rate) with the 8-bit
     threshold would bias E[output] by up to ~0.2%."""
-    return float(2 ** bits) / _quantized_threshold(rate, bits)
+    return 256.0 / _quantized_threshold(rate)
 
 
 def _fmix32(x):
@@ -348,7 +308,7 @@ def _interpret_random_bits(v1, v2, shape):
 
 
 def _dropout_keep(seed_ref, b, h, qi, ki, rate, block_q, block_k,
-                  num_k_blocks, bits=32, interpret=False, keys_first=False):
+                  num_k_blocks, interpret=False, keys_first=False):
     """Regenerable per-tile keep mask: the PRNG is reseeded from the step
     seed and the tile's ABSOLUTE coordinates, so the forward kernel and
     both backward kernels (whose grids order (qi, ki) differently)
@@ -387,123 +347,25 @@ def _dropout_keep(seed_ref, b, h, qi, ki, rate, block_q, block_k,
     else:
         pltpu.prng_seed(v1, v2)
         random_bits = pltpu.prng_random_bits
-    if bits == 8:
-        # one 32-bit word per FOUR mask positions: byte j of word w maps
-        # to column j*block_k/4 + w (column-GROUP layout — no Mosaic
-        # lane interleave needed; each (word, byte) is used exactly
-        # once, so positions stay iid uniform bytes).  Callers decide
-        # bits where block_k is known (_effective_dropout_bits), so the
-        # divisibility precondition holds here by construction.
-        assert block_k % 4 == 0, "8-bit dropout requires block_k % 4 == 0"
-        w = random_bits((block_q, block_k // 4))
-        if keys_first:
-            # signed words: the planes are 0..255 either way, and the
-            # v5e compares int32 natively (uint32: 0.010 ms a call more)
-            w = w.astype(jnp.int32).T
-            planes = [(w >> (8 * j)) & 0xFF for j in range(3)] + [
-                jax.lax.shift_right_logical(w, jnp.int32(24))]
-            return (jnp.concatenate(planes, axis=0)
-                    < np.int32(_quantized_threshold(rate, 8)))
-        w = w.astype(jnp.uint32)
-        t8 = _quantized_threshold(rate, 8)
-        m = jnp.concatenate(
-            [(w >> np.uint32(8 * j)) & np.uint32(0xFF) for j in range(4)],
-            axis=1)
-        return m < np.uint32(t8)
-    assert not keys_first, "the keys-first forward draws 8-bit words"
-    rbits = random_bits((block_q, block_k))
-    threshold = np.uint32(_quantized_threshold(rate, 32))
-    return rbits.astype(jnp.uint32) < threshold
-
-
-def _effective_dropout_bits(block_k: int, bits: Optional[int] = None) -> int:
-    """The width BOTH the mask and the scale must use for this kernel
-    call: 8-bit needs four byte-columns per word, so non-multiple-of-4
-    k blocks fall back to 32 — decided once here so mask probability and
-    inverse scale can never disagree.  `bits` is the selected width
-    (default: the module's, dropout_bits())."""
-    bits = _dropout_bits if bits is None else bits
-    return bits if bits == 32 or block_k % 4 == 0 else 32
-
-
-# --------------------------------------------------------------------------- #
-# Dropout mask reuse (store-in-forward / read-in-backward)
-# --------------------------------------------------------------------------- #
-# The regen scheme above pays the PRNG three times per step (fwd, dq,
-# dkv) — measured ~2.6% of the flagship step per kernel at 8-bit
-# (docs/ROUND5_NOTES.md).  Mask REUSE stores the keep decisions once in
-# the forward and the backward kernels read them: the PRNG runs once,
-# and the stored mask costs only 1-bit-per-position of HBM traffic.
-#
-# Packing rides the SUBLANE axis: 32 q-rows fold into one uint32 word
-# row, so the packed tile is [block_q/32, block_k] — the lane dim stays
-# the full lane-aligned block_k and the sublane dim is block_q/32 (16 at
-# the default 512 block), satisfying Mosaic's (8, 128) int32 tiling
-# without any padding.  (Lane-axis packing would shrink the minor dim to
-# block_k/32 < 128, which is only legal as a full-extent dim — i.e. a
-# single k block — while sublane packing is legal whenever
-# block_q % 256 == 0.)  Pack/unpack are 32 aligned sublane slices with
-# shift+or — no cross-lane movement, pure VPU work.
-#
-# The reference's analog is checkpointing the dropout mask with the
-# activation (dropout_kernels.cu stores the uint8 mask tensor the
-# backward kernels consume); here the mask lives bit-packed in the
-# custom-VJP residuals instead.
-_MASK_PACK = 32  # q rows per packed uint32 word
-
-
-def _pack_keep32(keep):
-    """[rows, cols] bool -> [rows//32, cols] uint32.  Bit j of word row
-    r holds keep[j*(rows//32) + r] (group layout: 32 aligned sublane
-    slices, no interleave)."""
-    gr = keep.shape[0] // _MASK_PACK
-    ku = keep.astype(jnp.uint32)
-    packed = ku[0:gr]
-    for j in range(1, _MASK_PACK):
-        packed = packed | (ku[j * gr:(j + 1) * gr] << np.uint32(j))
-    return packed
-
-
-def _unpack_keep32(packed):
-    """Inverse of _pack_keep32: [gr, cols] uint32 -> [gr*32, cols] bool."""
-    one = np.uint32(1)
-    return jnp.concatenate(
-        [(packed >> np.uint32(j)) & one for j in range(_MASK_PACK)],
-        axis=0) > 0
-
-
-def _parse_dropout_reuse(raw: str) -> bool:
-    return raw not in ("", "0", "false", "False", "no")
-
-
-_DEFAULT_DROPOUT_REUSE = False
-_dropout_reuse = _parse_dropout_reuse(
-    os.environ.get("DS_DROPOUT_REUSE",
-                   "1" if _DEFAULT_DROPOUT_REUSE else "0"))
-
-
-def set_dropout_mask_reuse(on: bool) -> None:
-    """Store the forward keep mask (bit-packed) and reuse it in the
-    backward kernels instead of regenerating it from the PRNG.  Grads
-    are BIT-IDENTICAL either way (the stored mask equals the regenerated
-    one); the modes differ only in where the step spends time — regen
-    pays the PRNG 3x, reuse pays S^2/8 bytes of residual traffic.  Read
-    at TRACE time like set_dropout_bits; falls back to regen when the
-    resolved q block is not a multiple of 256 (packed-tile sublane
-    alignment)."""
-    global _dropout_reuse
-    _dropout_reuse = bool(on)
-
-
-def dropout_mask_reuse() -> bool:
-    return _dropout_reuse
-
-
-def _mask_reuse_usable(block_q: int) -> bool:
-    """Packed tile legality: sublane dim block_q/32 must be a multiple
-    of 8 -> block_q % 256 == 0 (512-default and 256 blocks qualify;
-    smaller resolved blocks regen)."""
-    return block_q % 256 == 0
+    # one 32-bit word per FOUR mask positions: byte j of word w maps to
+    # column j*block_k/4 + w (column-GROUP layout — no Mosaic lane
+    # interleave needed; each (word, byte) is used exactly once, so
+    # positions stay iid uniform bytes).
+    assert block_k % 4 == 0, "the dropout draw requires block_k % 4 == 0"
+    w = random_bits((block_q, block_k // 4))
+    if keys_first:
+        # signed words: the planes are 0..255 either way, and the
+        # v5e compares int32 natively (uint32: 0.010 ms a call more)
+        w = w.astype(jnp.int32).T
+        planes = [(w >> (8 * j)) & 0xFF for j in range(3)] + [
+            jax.lax.shift_right_logical(w, jnp.int32(24))]
+        return (jnp.concatenate(planes, axis=0)
+                < np.int32(_quantized_threshold(rate)))
+    w = w.astype(jnp.uint32)
+    m = jnp.concatenate(
+        [(w >> np.uint32(8 * j)) & np.uint32(0xFF) for j in range(4)],
+        axis=1)
+    return m < np.uint32(_quantized_threshold(rate))
 
 
 # --------------------------------------------------------------------------- #
@@ -663,15 +525,15 @@ def _causal_walk(q_len, k_len, block_q, block_k, causal, row_groups):
 
 
 def _step_keep(seed_ref, b, h, qi, kj, n, rate, block_q, unit, num_units,
-               bits, interpret, keys_first=False):
+               interpret, keys_first=False):
     """Keep mask of a step over the n units of `unit` key columns from
     unit kj on: each unit's own regenerable draw (_dropout_keep), side by
     side, so the mask of a position does not depend on how a kernel
     walks its tiles.  [block_q, n * unit], or `keys_first` its
     transpose."""
     draws = [_dropout_keep(seed_ref, b, h, qi, kj + j if j else kj, rate,
-                           block_q, unit, num_units, bits=bits,
-                           interpret=interpret, keys_first=keys_first)
+                           block_q, unit, num_units, interpret=interpret,
+                           keys_first=keys_first)
              for j in range(n)]
     return draws[0] if n == 1 else jnp.concatenate(
         draws, axis=0 if keys_first else 1)
@@ -734,8 +596,9 @@ def _fa_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     vector registers, the max and the sum of a step reduce over the
     sublanes (element-wise across registers, one 8-to-1 fold a lane
     tile), and the accumulator is v^T . p, [d, block_q]; out and the
-    log-sum-exp are transposed back ONCE a q block.  Dropout is the
-    8-bit draw (_dropout_keep, keys_first).
+    log-sum-exp are transposed back ONCE a q block.  It takes the q
+    block it is given: whole lane tiles or less (64, 8, a short sequence
+    whole).  Dropout is _dropout_keep's draw, keys_first.
 
     Without `scratch` the call's inner grid dimension is one step: there
     is no earlier max or sum to fold in, and the step finishes in place."""
@@ -802,9 +665,9 @@ def _fa_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             # commutes with the final /l)
             keep = _step_keep(seed_ref, b, h, qi, kj, n, dropout_rate,
                               block_q, unit,
-                              num_k_blocks * (block_k // unit), 8,
+                              num_k_blocks * (block_k // unit),
                               interpret, keys_first=True)
-            p = jnp.where(keep, p * _keep_scale(dropout_rate, 8), 0.0)
+            p = jnp.where(keep, p * _keep_scale(dropout_rate), 0.0)
 
         v_blk = _ld(v_ref, rows)                     # [width, d]
         pv = jax.lax.dot_general(
@@ -823,118 +686,6 @@ def _fa_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         @pl.when(step == last_step)
         def _finalize():
             _finish(m_scr[...], l_scr[...], acc_scr[...])
-
-
-def _fa_kernel_rows(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                    *rest,
-                    causal: bool, sm_scale: float, block_q: int,
-                    block_k: int, num_k_blocks: int, dropout_rate: float,
-                    dropout_pbits: int = 32, save_mask: bool = False,
-                    interpret: bool = False, walk=None, band=None):
-    """The forward kernel with q rows along the sublanes, scores
-    [block_q, keys], for the calls _fa_kernel cannot serve
-    (_rows_on_lanes): it stores the packed dropout mask, whose
-    [block_q / 32, block_k] packing rides the sublanes of this
-    orientation, and draws 32-bit dropout words."""
-    if save_mask:
-        mask_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        m_scr, l_scr, acc_scr = rest
-    b = pl.program_id(0)
-    h = pl.program_id(1)
-    qi = pl.program_id(2)
-    ki = step = pl.program_id(3)
-    last_step = num_k_blocks - 1
-    window = None
-    if band is not None:   # inner step -> key block of the band
-        ki, last_step, window = (band.first_k(qi) + step, band.steps_k - 1,
-                                 band.window)
-
-    @pl.when(step == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, DEFAULT_MASK_VALUE)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    unit = walk["sub_k"] if walk else block_k
-
-    def _update(kj, n, rows, parts):
-        """One online-softmax step over the n units of `unit` key
-        columns from unit kj on: the whole block (n 1, rows None,
-        kj == ki) or sub-tiles of it, key rows `rows` of the block.  In
-        one piece: the forward kernel takes no q-row groups."""
-        (_, _, mask_col), = parts
-        # bf16 operands straight into the MXU; fp32 accumulation via
-        # preferred_element_type (upcasting first would force an fp32 matmul).
-        q = _ld(q_ref)                               # [bq, d]
-        k = _ld(k_ref, rows)                         # [width, d]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # [bq, width] fp32
-
-        s = _step_mask(s, qi, kj, block_q, unit, None, mask_col,
-                       DEFAULT_MASK_VALUE, window)
-
-        m_prev = m_scr[...]                           # [bq, LANES]
-        l_prev = l_scr[...]
-        m_curr = jnp.max(s, axis=-1, keepdims=True)   # [bq, 1]
-        m_next = jnp.maximum(m_prev, m_curr)          # [bq, LANES]
-        alpha = jnp.exp(m_prev[:, :1] - m_next[:, :1])   # [bq, 1]
-        p = jnp.exp(s - m_next[:, :1])                # [bq, width] fp32
-        l_corr = l_prev * alpha
-        l_next = l_corr + jnp.sum(p, axis=-1, keepdims=True)
-        m_scr[...] = m_next
-        l_scr[...] = jnp.broadcast_to(l_next[:, :1], l_scr.shape)
-
-        if dropout_rate > 0.0:
-            # probability dropout: the PV input is masked+rescaled but the
-            # normalizer l accumulates the RAW p (softmax normalizes true
-            # probabilities; dropout applies to the normalized P, which
-            # commutes with the final /l)
-            keep = _step_keep(seed_ref, b, h, qi, kj, n, dropout_rate,
-                              block_q, unit,
-                              num_k_blocks * (block_k // unit),
-                              dropout_pbits, interpret)
-            inv = _keep_scale(dropout_rate, dropout_pbits)
-            p = jnp.where(keep, p * inv, 0.0)
-            if save_mask:
-                # bit-packed keep decisions for the backward kernels.
-                # Causally-skipped tiles and sub-tiles never write (and
-                # the backward skips the same ones, so their garbage is
-                # never read).
-                if rows is None:
-                    mask_ref[0, 0] = _pack_keep32(keep)
-                else:
-                    mask_ref[0, 0, :, rows] = _pack_keep32(keep)
-
-        v_blk = _ld(v_ref, rows)                     # [width, d]
-        pv = jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)       # [bq, d]
-        acc_scr[...] = acc_scr[...] * alpha + pv
-
-    _walk_tile(qi, ki, block_q, block_k, causal, walk, _update, band)
-
-    @pl.when(step == last_step)
-    def _finalize():
-        denom = l_scr[...][:, :1]
-        # Fully-masked rows have l == 0; emit zeros not NaN.
-        denom = jnp.where(denom == 0.0, 1.0, denom)
-        _st(o_ref, (acc_scr[...] / denom).astype(o_ref.dtype))
-        # logsumexp residual for the backward pass (FlashAttention-2 style)
-        lse = m_scr[...][:, :1] + jnp.log(l_scr[...][:, :1] + 1e-37)
-        lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
-
-
-def _rows_on_lanes(block_q, dropout_pbits, save_mask):
-    """Whether a forward call takes _fa_kernel (q rows along the lanes)
-    or the older _fa_kernel_rows, from what the call sees at trace time:
-    the q block fills whole lane tiles, dropout, if any (`dropout_pbits`
-    None: none), draws 8-bit words, and no packed mask is stored.  A
-    call on the older body runs under the kernel name flash_fwd_rows, so
-    a trace counts them: the cells make none."""
-    return (block_q % _LANES == 0 and not save_mask
-            and dropout_pbits in (None, 8))
 
 
 def _seed_arg(dropout_seed):
@@ -1031,7 +782,7 @@ def _check_window(window, causal, dropout_rate):
                          "keys of a causal call, at least 1")
     if dropout_rate > 0.0:
         raise ValueError("a windowed call takes no in-kernel dropout: the "
-                         "banded grid has no packed-mask layout")
+                         "banded grid has no dropout units")
 
 
 def flash_attention_pallas(q, k, v, causal: bool = False,
@@ -1040,7 +791,6 @@ def flash_attention_pallas(q, k, v, causal: bool = False,
                            interpret: bool = False, return_lse: bool = False,
                            layout: str = "bhsd", dropout_rate: float = 0.0,
                            dropout_seed=None,
-                           save_dropout_mask: bool = False,
                            window: Optional[int] = None):
     """Pallas flash attention.
 
@@ -1056,20 +806,12 @@ def flash_attention_pallas(q, k, v, causal: bool = False,
     and fails Pallas lowering on real TPUs, measured round 3; the
     transposes here are cheap relative to the attention itself and XLA
     fuses them into neighbors where it can.)
-    logsumexp (when return_lse) is [B, H, S] in BOTH layouts.
-
-    save_dropout_mask (requires return_lse and dropout_rate > 0, and a
-    resolved q block that is a multiple of 256): additionally returns
-    the bit-packed keep mask [B, H, S_q/32, S_k] uint32 — ALWAYS in the
-    internal bhsd-derived index space regardless of layout — for
-    flash_attention_bwd_pallas(dropout_mask=...)."""
+    logsumexp (when return_lse) is [B, H, S] in BOTH layouts."""
     return _flash_fwd_call(
         q, k, v, dropout_seed, causal=causal, sm_scale=sm_scale,
         block_q=block_q, block_k=block_k, interpret=interpret,
         return_lse=return_lse, layout=layout,
-        dropout_rate=float(dropout_rate),
-        save_dropout_mask=save_dropout_mask, dropout_bits=_dropout_bits,
-        window=window)
+        dropout_rate=float(dropout_rate), window=window)
 
 
 # The calls' own programs are traced once a process and shape: every
@@ -1081,11 +823,10 @@ def flash_attention_pallas(q, k, v, causal: bool = False,
 # these are inlined calls, not programs of their own.
 @functools.partial(jax.jit, static_argnames=(
     "causal", "sm_scale", "block_q", "block_k", "interpret", "return_lse",
-    "layout", "dropout_rate", "save_dropout_mask", "dropout_bits",
-    "window"))
+    "layout", "dropout_rate", "window"))
 def _flash_fwd_call(q, k, v, dropout_seed, *, causal, sm_scale, block_q,
                     block_k, interpret, return_lse, layout, dropout_rate,
-                    save_dropout_mask, dropout_bits, window=None):
+                    window=None):
     """flash_attention_pallas, traced once a process and shape."""
     batch, heads, q_len, d = _dims(q, layout)
     kv_heads, k_len = _dims(k, layout)[1:3]
@@ -1107,47 +848,21 @@ def _flash_fwd_call(q, k, v, dropout_seed, *, causal, sm_scale, block_q,
     nq, nk = q_len // block_q, k_len // block_k
     seed = _seed_arg(dropout_seed)
 
-    if save_dropout_mask:
-        if not (return_lse and dropout_rate > 0.0):
-            raise ValueError(
-                "save_dropout_mask requires return_lse and dropout_rate > 0")
-        if not _mask_reuse_usable(block_q):
-            raise ValueError(
-                f"save_dropout_mask: q_len={q_len} resolved a q block of "
-                f"{block_q}, which is not a multiple of 256 (the packed "
-                "mask tile needs sublane dim block_q/32 % 8 == 0).  Fix: "
-                "pick a block_q whose resolved divisor of q_len is a "
-                "multiple of 256 (TransformerConfig.block_q / the block_q "
-                "argument), or stay on the regen path by disabling reuse "
-                "(set_dropout_mask_reuse(False) / DS_DROPOUT_REUSE=0)")
     band = None if window is None else _Band(window, block_q, block_k,
                                              nq, nk)
     walk = ({} if band else
             _causal_walk(q_len, k_len, block_q, block_k, causal, False))
     steps = band.steps_k if band else nk
-    pbits = _effective_dropout_bits(block_k, dropout_bits)
-    common = dict(causal=causal, sm_scale=float(sm_scale), block_q=block_q,
-                  block_k=block_k, num_k_blocks=nk,
-                  dropout_rate=float(dropout_rate), interpret=interpret,
-                  walk=walk, band=band)
-    on_lanes = _rows_on_lanes(block_q, pbits if dropout_rate > 0.0 else None,
-                              save_dropout_mask)
-    if on_lanes:
-        kernel = functools.partial(_fa_kernel, **common)
-        # an inner extent of one step carries nothing between steps
-        scratch = [] if steps == 1 else [
-            pltpu.VMEM((1, block_q), jnp.float32),   # running max
-            pltpu.VMEM((1, block_q), jnp.float32),   # running sum
-            pltpu.VMEM((d, block_q), jnp.float32),   # output accumulator
-        ]
-    else:
-        kernel = functools.partial(_fa_kernel_rows, dropout_pbits=pbits,
-                                   save_mask=save_dropout_mask, **common)
-        scratch = [
-            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
-            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running sum
-            pltpu.VMEM((block_q, d), jnp.float32),       # output accumulator
-        ]
+    kernel = functools.partial(
+        _fa_kernel, causal=causal, sm_scale=float(sm_scale), block_q=block_q,
+        block_k=block_k, num_k_blocks=nk, dropout_rate=float(dropout_rate),
+        interpret=interpret, walk=walk, band=band)
+    # an inner extent of one step carries nothing between steps
+    scratch = [] if steps == 1 else [
+        pltpu.VMEM((1, block_q), jnp.float32),   # running max
+        pltpu.VMEM((1, block_q), jnp.float32),   # running sum
+        pltpu.VMEM((d, block_q), jnp.float32),   # output accumulator
+    ]
     kv_spec = _tile_spec(block_k, d, "j", group,
                          band.k_block if band else None)
 
@@ -1161,19 +876,12 @@ def _flash_fwd_call(q, k, v, dropout_seed, *, causal, sm_scale, block_q,
         jax.ShapeDtypeStruct((batch, heads, q_len, _STATS_LANES),
                              jnp.float32),
     ]
-    if save_dropout_mask:
-        out_specs.append(
-            pl.BlockSpec((1, 1, block_q // _MASK_PACK, block_k),
-                         lambda b, h, i, j, *_: (b, h, i, j)))
-        out_shape.append(
-            jax.ShapeDtypeStruct(
-                (batch, heads, q_len // _MASK_PACK, k_len), jnp.uint32))
     params = {}
     if not interpret:
         params["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"))
-    res = pl.pallas_call(
+    out, lse = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -1183,15 +891,11 @@ def _flash_fwd_call(q, k, v, dropout_seed, *, causal, sm_scale, block_q,
             scratch_shapes=scratch),
         out_shape=out_shape,
         interpret=interpret,
-        name=_kernel_name("flash_fwd" if on_lanes else "flash_fwd_rows",
-                          band),
+        name=_kernel_name("flash_fwd", band),
         **params,
     )(seed, q, k, v)
-    out, lse = res[0], res[1]
     if layout == "bshd":
         out = _t_bhsd(out)
-    if save_dropout_mask:
-        return out, lse[..., 0], res[2]
     return (out, lse[..., 0]) if return_lse else out
 
 
@@ -1199,14 +903,10 @@ def _flash_fwd_call(q, k, v, dropout_seed, *, causal, sm_scale, block_q,
 # Pallas backward kernels (FlashAttention-2 style)
 # --------------------------------------------------------------------------- #
 def _fa_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                        delta_ref, *rest, causal, sm_scale, block_q,
-                        block_k, num_q_blocks, num_k_blocks, dropout_rate,
-                        dropout_pbits=32, reuse_mask: bool = False,
-                        interpret: bool = False, walk=None, band=None):
-    if reuse_mask:
-        mask_ref, dk_ref, dv_ref, dk_scr, dv_scr = rest
-    else:
-        dk_ref, dv_ref, dk_scr, dv_scr = rest
+                        delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
+                        causal, sm_scale, block_q, block_k, num_q_blocks,
+                        num_k_blocks, dropout_rate, interpret: bool = False,
+                        walk=None, band=None):
     b = pl.program_id(0)
     h = pl.program_id(1)
     ki = pl.program_id(2)
@@ -1252,19 +952,14 @@ def _fa_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                 preferred_element_type=jnp.float32)
             if dropout_rate > 0.0:
                 # same mask as the forward — regenerated from the
-                # sub-tile coordinates, or read back bit-packed (reuse
-                # mode; both give the IDENTICAL mask, so grads don't
-                # depend on the mode).  dV sees the DROPPED
+                # sub-tile coordinates.  dV sees the DROPPED
                 # probabilities; dS = P*(D.dp - delta)
                 if keep is None:   # the step's bits, once
-                    if reuse_mask:
-                        keep = _unpack_keep32(_ld(mask_ref, rows, cols=True))
-                    else:
-                        keep = _step_keep(seed_ref, b, h, qi, kj, n,
-                                          dropout_rate, block_q, unit,
-                                          num_k_blocks * (block_k // unit),
-                                          dropout_pbits, interpret)
-                inv = _keep_scale(dropout_rate, dropout_pbits)
+                    keep = _step_keep(seed_ref, b, h, qi, kj, n,
+                                      dropout_rate, block_q, unit,
+                                      num_k_blocks * (block_k // unit),
+                                      interpret)
+                inv = _keep_scale(dropout_rate)
                 kept = _piece(keep, qrows, width)
                 p_drop = jnp.where(kept, p * inv, 0.0)
                 dp = jnp.where(kept, dp * inv, 0.0)
@@ -1288,14 +983,9 @@ def _fa_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 
 def _fa_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                      delta_ref, *rest, causal, sm_scale, block_q,
-                      block_k, num_k_blocks, dropout_rate,
-                      dropout_pbits=32, reuse_mask: bool = False,
+                      delta_ref, dq_ref, dq_scr, *, causal, sm_scale,
+                      block_q, block_k, num_k_blocks, dropout_rate,
                       interpret: bool = False, walk=None, band=None):
-    if reuse_mask:
-        mask_ref, dq_ref, dq_scr = rest
-    else:
-        dq_ref, dq_scr = rest
     b = pl.program_id(0)
     h = pl.program_id(1)
     qi = pl.program_id(2)
@@ -1338,14 +1028,11 @@ def _fa_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                 preferred_element_type=jnp.float32)
             if dropout_rate > 0.0:
                 if keep is None:   # the step's bits, once
-                    if reuse_mask:
-                        keep = _unpack_keep32(_ld(mask_ref, rows, cols=True))
-                    else:
-                        keep = _step_keep(seed_ref, b, h, qi, kj, n,
-                                          dropout_rate, block_q, unit,
-                                          num_k_blocks * (block_k // unit),
-                                          dropout_pbits, interpret)
-                inv = _keep_scale(dropout_rate, dropout_pbits)
+                    keep = _step_keep(seed_ref, b, h, qi, kj, n,
+                                      dropout_rate, block_q, unit,
+                                      num_k_blocks * (block_k // unit),
+                                      interpret)
+                inv = _keep_scale(dropout_rate)
                 dp = jnp.where(_piece(keep, qrows, width), dp * inv, 0.0)
             ds = p * (dp - delta) * sm_scale
             dq_scr[_at(qrows)] += jax.lax.dot_general(    # ds @ k -> [bq, d]
@@ -1365,39 +1052,28 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
                                interpret: bool = False,
                                layout: str = "bhsd",
                                dropout_rate: float = 0.0,
-                               dropout_seed=None, dropout_mask=None,
-                               dropout_mask_block_q=None,
+                               dropout_seed=None,
                                window: Optional[int] = None):
     """Block-wise dq, dk, dv — no [S, S] materialization in HBM.  Inputs
     and grads follow `layout` (lse is always [B, H, S]); "bshd" converts
     to the kernel's [B, H, S, D] at this boundary (see
     flash_attention_pallas).  Fewer key/value heads than query heads and
-    `window` as there; dk and dv come back with k's and v's heads.
-
-    dropout_mask: the bit-packed [B, H, S_q/32, S_k] uint32 keep mask a
-    save_dropout_mask forward stored (always internal-layout).  When
-    given, the kernels READ it instead of regenerating from the PRNG —
-    identical grads, one PRNG pass per step instead of three.
-    dropout_mask_block_q (REQUIRED with dropout_mask): the RESOLVED q
-    block the forward packed with — the bit-group layout is a function
-    of it, so a fwd/bwd block mismatch would silently permute mask rows;
-    this check turns that into a loud error."""
+    `window` as there; dk and dv come back with k's and v's heads.  The
+    dropout mask is regenerated from dropout_seed and the tile
+    coordinates, the forward's own (_dropout_keep)."""
     return _flash_bwd_call(
-        q, k, v, out, lse, do, dropout_seed, dropout_mask, causal=causal,
+        q, k, v, out, lse, do, dropout_seed, causal=causal,
         sm_scale=sm_scale, block_q=block_q, block_k=block_k,
         interpret=interpret, layout=layout,
-        dropout_rate=float(dropout_rate),
-        dropout_mask_block_q=dropout_mask_block_q,
-        dropout_bits=_dropout_bits, window=window)
+        dropout_rate=float(dropout_rate), window=window)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "causal", "sm_scale", "block_q", "block_k", "interpret", "layout",
-    "dropout_rate", "dropout_mask_block_q", "dropout_bits", "window"))
-def _flash_bwd_call(q, k, v, out, lse, do, dropout_seed, dropout_mask, *,
-                    causal, sm_scale, block_q, block_k, interpret, layout,
-                    dropout_rate, dropout_mask_block_q, dropout_bits,
-                    window=None):
+    "dropout_rate", "window"))
+def _flash_bwd_call(q, k, v, out, lse, do, dropout_seed, *, causal,
+                    sm_scale, block_q, block_k, interpret, layout,
+                    dropout_rate, window=None):
     """flash_attention_bwd_pallas, traced once a process and shape (see
     _flash_fwd_call)."""
     batch, heads, q_len, d = _dims(q, layout)
@@ -1437,33 +1113,6 @@ def _flash_bwd_call(q, k, v, out, lse, do, dropout_seed, dropout_mask, *,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"))
 
-    reuse = dropout_mask is not None
-    if reuse:
-        if not dropout_rate > 0.0:
-            raise ValueError(
-                f"dropout_mask given but dropout_rate={dropout_rate} — a "
-                "mask only applies to a dropout backward.  Fix: pass the "
-                "forward's dropout_rate, or drop the dropout_mask argument")
-        if not _mask_reuse_usable(block_q):
-            raise ValueError(
-                f"dropout_mask given but this backward resolved q block "
-                f"{block_q} (from q_len={q_len}, requested block_q), which "
-                "is not a multiple of 256 — the forward could not have "
-                "packed a mask at this block.  Fix: use the same block_q "
-                "in forward and backward (TransformerConfig.block_q), or "
-                "disable reuse (set_dropout_mask_reuse(False) / "
-                "DS_DROPOUT_REUSE=0) so both sides regen from the PRNG")
-        if dropout_mask_block_q != block_q:
-            raise ValueError(
-                f"dropout_mask was packed with resolved block_q="
-                f"{dropout_mask_block_q}, but this backward resolved "
-                f"block_q={block_q} — the packed bit layout depends on the "
-                "forward's q block, so the grads would be silently wrong.  "
-                "Fix: pass dropout_mask_block_q=<the forward's resolved "
-                "block> and call with the forward's block_q (the "
-                "flash_attention custom_vjp does this automatically; "
-                "manual callers must thread it through)")
-    mask_in = (dropout_mask,) if reuse else ()
     # as in the forward: the three kernels agree on the sub-tiles, whose
     # coordinates seed the dropout bits
     band = None if window is None else _Band(window, block_q, block_k,
@@ -1471,16 +1120,12 @@ def _flash_bwd_call(q, k, v, out, lse, do, dropout_seed, dropout_mask, *,
     walk = ({} if band else
             _causal_walk(q_len, k_len, block_q, block_k, causal, True))
     if band or group > 1:
-        if reuse:
-            raise ValueError("a stored dropout mask is not read by a call "
-                             "with a window or grouped key/value heads")
         return _flash_bwd_grouped(
             q, k, v, do, lse, delta, seed, band=band, group=group,
             layout=layout, params=params, causal=causal,
             sm_scale=float(sm_scale), block_q=block_q, block_k=block_k,
-            dropout_rate=float(dropout_rate),
-            dropout_pbits=_effective_dropout_bits(block_k, dropout_bits),
-            interpret=interpret, walk=walk)
+            dropout_rate=float(dropout_rate), interpret=interpret,
+            walk=walk)
 
     # dk/dv: grid over k blocks (grid dim 2), inner loop over q blocks
     # (grid dim 3) — _tile_spec's "i"/"j" name grid dims 2/3, so q/do tiles
@@ -1488,9 +1133,7 @@ def _flash_bwd_call(q, k, v, out, lse, do, dropout_seed, dropout_mask, *,
     dkdv_kernel = functools.partial(
         _fa_bwd_dkdv_kernel, causal=causal, sm_scale=float(sm_scale),
         block_q=block_q, block_k=block_k, num_q_blocks=nq, num_k_blocks=nk,
-        dropout_rate=float(dropout_rate),
-        dropout_pbits=_effective_dropout_bits(block_k, dropout_bits),
-        reuse_mask=reuse, interpret=interpret, walk=walk)
+        dropout_rate=float(dropout_rate), interpret=interpret, walk=walk)
     dkdv_in_specs = [
         _tile_spec(block_q, d, "j"),
         _tile_spec(block_k, d, "i"),
@@ -1501,10 +1144,6 @@ def _flash_bwd_call(q, k, v, out, lse, do, dropout_seed, dropout_mask, *,
         pl.BlockSpec((1, 1, block_q, _STATS_LANES),
                      lambda b, h, j, i, *_: (b, h, i, 0)),
     ]
-    if reuse:  # mask tile (q_block, k_block) = (grid dim 3, grid dim 2)
-        dkdv_in_specs.append(
-            pl.BlockSpec((1, 1, block_q // _MASK_PACK, block_k),
-                         lambda b, h, i, j, *_: (b, h, j, i)))
     dk, dv = pl.pallas_call(
         dkdv_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1526,7 +1165,7 @@ def _flash_bwd_call(q, k, v, out, lse, do, dropout_seed, dropout_mask, *,
         interpret=interpret,
         name="flash_bwd_dkdv",
         **params,
-    )(seed, q, k, v, do, lse, delta, *mask_in)
+    )(seed, q, k, v, do, lse, delta)
 
     # dq: grid over q blocks, inner loop over k blocks
     r_spec = pl.BlockSpec((1, 1, block_q, _STATS_LANES),
@@ -1534,9 +1173,7 @@ def _flash_bwd_call(q, k, v, out, lse, do, dropout_seed, dropout_mask, *,
     dq_kernel = functools.partial(
         _fa_bwd_dq_kernel, causal=causal, sm_scale=float(sm_scale),
         block_q=block_q, block_k=block_k, num_k_blocks=nk,
-        dropout_rate=float(dropout_rate),
-        dropout_pbits=_effective_dropout_bits(block_k, dropout_bits),
-        reuse_mask=reuse, interpret=interpret, walk=walk)
+        dropout_rate=float(dropout_rate), interpret=interpret, walk=walk)
     dq_in_specs = [
         _tile_spec(block_q, d, "i"),
         _tile_spec(block_k, d, "j"),
@@ -1544,10 +1181,6 @@ def _flash_bwd_call(q, k, v, out, lse, do, dropout_seed, dropout_mask, *,
         _tile_spec(block_q, d, "i"),
         r_spec, r_spec,
     ]
-    if reuse:
-        dq_in_specs.append(
-            pl.BlockSpec((1, 1, block_q // _MASK_PACK, block_k),
-                         lambda b, h, i, j, *_: (b, h, i, j)))
     dq = pl.pallas_call(
         dq_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1560,7 +1193,7 @@ def _flash_bwd_call(q, k, v, out, lse, do, dropout_seed, dropout_mask, *,
         interpret=interpret,
         name="flash_bwd_dq",
         **params,
-    )(seed, q, k, v, do, lse, delta, *mask_in)
+    )(seed, q, k, v, do, lse, delta)
 
     if layout == "bshd":
         dq, dk, dv = _t_bhsd(dq), _t_bhsd(dk), _t_bhsd(dv)
@@ -1570,7 +1203,7 @@ def _flash_bwd_call(q, k, v, out, lse, do, dropout_seed, dropout_mask, *,
 def _flash_bwd_grouped(q, k, v, do, lse, delta, seed, *, band, group,
                        layout, params, **common):
     """The two backward kernels of a call with a window or with fewer
-    key/value heads than query heads (no dropout mask to read): the same
+    key/value heads than query heads: the same
     kernel bodies (`common`: their static arguments) on the band's grid
     and with key/value heads found by index.  The dkdv kernel still
     writes one dk and dv a QUERY head (its grid cell owns its output
@@ -1648,7 +1281,7 @@ def _flash(q, k, v, seed, causal, sm_scale, block_q, block_k,
                       layout, dropout_rate, window)[0]
 
 
-# Auto-dispatch crossover (v5e, 2026-07-31, docs/ROUND4_NOTES.md bert_ab
+# Auto-dispatch crossover (v5e, 2026-07-31, round 4's bert_ab
 # 2x2): at S=128 the XLA attention beats the Pallas flash kernel
 # by ~25% on the full BERT-large step (90.3 vs 115.5 ms dropout-on) —
 # short sequences leave the streaming kernel overhead-bound while XLA
@@ -1691,7 +1324,7 @@ def _ref_in_layout(q, k, v, causal, sm_scale, layout, dropout_rate=0.0,
 
 
 # checkpoint_name of the residuals only the forward kernel can produce
-# (out, lse, the packed dropout mask): a jax.checkpoint policy that saves
+# (out, lse): a jax.checkpoint policy that saves
 # this name spares the backward pass a second run of the kernel; with no
 # such policy the name is an identity.
 RESIDUAL_NAME = "flash_residuals"
@@ -1702,28 +1335,21 @@ def _flash_fwd(q, k, v, seed, causal, sm_scale, block_q, block_k,
     q_len, k_len = _dims(q, layout)[2], _dims(k, layout)[2]
     if _use_pallas(q_len, k_len, q.shape[3], block_q, block_k):
         _, bq, bk = _resolve_blocks(q_len, k_len, block_q, block_k)
-        # mask-reuse mode (trace-time, like the PRNG width): store the
-        # bit-packed keep mask in the residuals so the backward kernels
-        # skip the PRNG — grads identical either way
-        reuse = (dropout_rate > 0.0 and _dropout_reuse
-                 and _mask_reuse_usable(bq)
-                 and _dims(q, layout)[1] == _dims(k, layout)[1])
-        out, lse, *mask = checkpoint_name(flash_attention_pallas(
+        out, lse = checkpoint_name(flash_attention_pallas(
             q, k, v, causal=causal, sm_scale=sm_scale,
             block_q=bq, block_k=bk, return_lse=True, layout=layout,
             dropout_rate=dropout_rate, dropout_seed=seed,
-            save_dropout_mask=reuse, interpret=pallas_interpret(),
-            window=window),
+            interpret=pallas_interpret(), window=window),
             RESIDUAL_NAME)
-        return out, (q, k, v, seed, out, lse, mask[0] if reuse else None)
+        return out, (q, k, v, seed, out, lse)
     out = _ref_in_layout(q, k, v, causal, sm_scale, layout, dropout_rate,
                          seed[0], window)
-    return out, (q, k, v, seed, None, None, None)
+    return out, (q, k, v, seed, None, None)
 
 
 def _flash_bwd(causal, sm_scale, block_q, block_k, layout, dropout_rate,
                window, res, g):
-    q, k, v, seed, out, lse, mask = res
+    q, k, v, seed, out, lse = res
     if lse is not None:
         q_len, k_len = _dims(q, layout)[2], _dims(k, layout)[2]
         _, bq, bk = _resolve_blocks(q_len, k_len, block_q, block_k)
@@ -1731,7 +1357,6 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, layout, dropout_rate,
             q, k, v, out, lse, g, causal=causal, sm_scale=sm_scale,
             block_q=bq, block_k=bk, layout=layout,
             dropout_rate=dropout_rate, dropout_seed=seed,
-            dropout_mask=mask, dropout_mask_block_q=bq,
             interpret=pallas_interpret(), window=window)
         return dq, dk, dv, None
     _, vjp = jax.vjp(

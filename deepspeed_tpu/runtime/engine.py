@@ -60,7 +60,7 @@ from ..parallel import mesh as mesh_mod
 from ..parallel.mesh import MeshContext
 from ..profiling import scope_map
 from ..utils.logging import log_dist, logger
-from ..utils.timer import SynchronizedWallClockTimer, ThroughputTimer
+from ..utils.timer import ThroughputTimer
 from . import checkpoint as ckpt_mod
 from .dataloader import DeepSpeedDataLoader
 from .fp16.loss_scaler import (create_loss_scaler,
@@ -68,17 +68,6 @@ from .fp16.loss_scaler import (create_loss_scaler,
 from .lr_schedules import get_lr_schedule
 from .optimizers import build_optimizer
 from .zero.partition import ZeroPartitioner
-
-FORWARD_MICRO_TIMER = "forward_microstep"
-FORWARD_GLOBAL_TIMER = "forward"
-BACKWARD_MICRO_TIMER = "backward_microstep"
-BACKWARD_GLOBAL_TIMER = "backward"
-STEP_MICRO_TIMER = "step_microstep"
-STEP_GLOBAL_TIMER = "step"
-# window-level timer for the fused whole-step path: the gas window is ONE
-# dispatch, so forward/backward micro timers cannot exist there
-FUSED_STEP_TIMER = "fused_train_batch"
-
 
 def _program_name(fn):
     """What a device trace's ``XLA Modules`` line calls the jitted
@@ -284,8 +273,7 @@ class DeepSpeedEngine:
                 self.config.zero_config.max_live_parameters,
                 self.config.zero_config.prefetch_bucket_size,
                 self.config.zero_config.param_persistence_threshold,
-                low_bandwidth=lbc if lbc.enabled else None,
-                prefetch_mode=self.config.zero_config.prefetch_mode)
+                low_bandwidth=lbc if lbc.enabled else None)
             model.install_zero3_streaming(self._zero3_stream)
         elif lbc.enabled and stage >= 3:
             logger.warning(
@@ -504,17 +492,6 @@ class DeepSpeedEngine:
                     f"(gas={self.gradient_accumulation_steps()}; modular "
                     f"loop would issue "
                     f"{2 * self.gradient_accumulation_steps()})", ranks=[0])
-                if self.wall_clock_breakdown():
-                    # the forward/backward/step micro timers never run
-                    # under the fused program (the whole window is one
-                    # dispatch) — say so ONCE instead of printing an
-                    # empty breakdown every window
-                    logger.warning(
-                        "wall_clock_breakdown: forward/backward micro "
-                        "timers are unavailable under fused_step (the "
-                        "window is one compiled dispatch) — the window-"
-                        f"level '{FUSED_STEP_TIMER}' timer reports the "
-                        "whole optimizer step instead")
 
         # ---- data ---------------------------------------------------- #
         self.training_dataloader = self._configure_dataloader(
@@ -581,7 +558,14 @@ class DeepSpeedEngine:
                 gas_boundary_resolution=ec.gas_boundary_resolution)
 
         # ---- bookkeeping --------------------------------------------- #
-        self.timers = SynchronizedWallClockTimer()
+        if self.wall_clock_breakdown():
+            # upstream configurations carry the key; the breakdown itself
+            # is the always-on host spans
+            logger.warning(
+                "wall_clock_breakdown: the per-phase timers are gone; the "
+                "same breakdown is the ds.forward / ds.backward / ds.step "
+                "spans (and their children) of any jax.profiler trace or "
+                "of the monitor's Chrome trace (docs/telemetry.md)")
         self.tput_timer = ThroughputTimer(
             batch_size=self.train_micro_batch_size_per_gpu(),
             num_workers=self.world_size,
@@ -1794,8 +1778,6 @@ class DeepSpeedEngine:
             # only at gas-window starts: a phase switch mid-window would
             # mix dense and local gradients in one accumulation
             self._maybe_onebit_switch()
-        if self.wall_clock_breakdown():
-            self.timers(FORWARD_MICRO_TIMER).start()
         if self._is_train_mode:
             self.tput_timer.start()
             if self.monitor is not None:
@@ -1899,8 +1881,6 @@ class DeepSpeedEngine:
                 self.flops_profiler = prof
             self._cached_grads = grads
             self._last_loss = loss
-            if self.wall_clock_breakdown():
-                self.timers(FORWARD_MICRO_TIMER).stop()
             return loss
 
     __call__ = forward
@@ -1914,8 +1894,6 @@ class DeepSpeedEngine:
             "backward() called before forward()"
         with self._span("backward", step=self.global_steps + 1,
                         micro=self._micro_index()):
-            if self.wall_clock_breakdown():
-                self.timers(BACKWARD_MICRO_TIMER).start()
             if self._grad_acc is None:
                 self._grad_acc = self._cached_grads
             else:
@@ -1930,8 +1908,6 @@ class DeepSpeedEngine:
                                                   self._cached_grads)
             self._cached_grads = None
             self.micro_steps += 1
-            if self.wall_clock_breakdown():
-                self.timers(BACKWARD_MICRO_TIMER).stop()
             return loss if loss is not None else self._last_loss
 
     def step(self, lr_kwargs=None):
@@ -1944,9 +1920,6 @@ class DeepSpeedEngine:
             self._take_step(lr_kwargs)
 
     def _take_step(self, lr_kwargs):
-        if self.wall_clock_breakdown():
-            self.timers(STEP_MICRO_TIMER).start()
-
         sentinel_skip = False
         if self.sentinel is not None:
             verdict = self._sentinel_check()
@@ -1960,8 +1933,6 @@ class DeepSpeedEngine:
                     # no record for the rewound step — reset the arrival
                     # clock so the next record's wall time stays per-step
                     self.monitor.discard_step()
-                if self.wall_clock_breakdown():
-                    self.timers(STEP_MICRO_TIMER).stop()
                 self._maybe_handle_preemption()
                 return
             sentinel_skip = verdict == "skip"
@@ -2012,8 +1983,6 @@ class DeepSpeedEngine:
                 self._weights = self._with_copy(out[4])
         with self._span("step.bookkeeping"):
             self._after_apply(overflow, sentinel_skip, lr_kwargs)
-        if self.wall_clock_breakdown():
-            self.timers(STEP_MICRO_TIMER).stop()
         self._maybe_handle_preemption()
 
     def _after_apply(self, overflow, sentinel_skip, lr_kwargs):
@@ -2824,11 +2793,6 @@ class DeepSpeedEngine:
         for _ in range(gas):
             b = next(data_iter)
             batches.append(b if isinstance(b, tuple) else (b,))
-        if self.wall_clock_breakdown():
-            # window-level timer: the whole gas window is ONE dispatch, so
-            # forward/backward micro timers cannot exist here (logged once
-            # at build time)
-            self.timers(FUSED_STEP_TIMER).start()
         self.tput_timer.start()
         if self.monitor is not None:
             self.monitor.mark_step_start()
@@ -2918,8 +2882,6 @@ class DeepSpeedEngine:
                                   tokens=self._monitor_tokens_per_step(),
                                   counters=self._monitor_counters())
         self._boundary_logging()
-        if self.wall_clock_breakdown():
-            self.timers(FUSED_STEP_TIMER).stop()
         self._maybe_handle_preemption()
         return loss
 

@@ -17,8 +17,7 @@ fired fault also emits a structured ``chaos`` monitor record, so a
 post-mortem can separate injected faults from organic ones.
 
 The pre-existing single-purpose injectors (``crash_after_bytes``,
-``poison_batch``, ``InjectedCrash``) live here now;
-``fault_injection.py`` re-exports them as a deprecated shim.
+``poison_batch``, ``InjectedCrash``) live here now.
 """
 
 import builtins
@@ -382,7 +381,7 @@ def installed(plane: ChaosPlane):
 
 
 # --------------------------------------------------------------------- #
-# folded legacy injectors (previously fault_injection.py)
+# the single-purpose injectors
 # --------------------------------------------------------------------- #
 class _CountingFile:
     def __init__(self, f, injector):

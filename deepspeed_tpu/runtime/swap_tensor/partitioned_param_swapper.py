@@ -4,7 +4,7 @@ pinned host window.
 Reference: runtime/swap_tensor/partitioned_param_swapper.py:36
 (AsyncPartitionedParameterSwapper) — the ZeRO-Infinity piece that lets the
 *parameters themselves* live on NVMe, wired into stage 3 at stage3.py:932 so
-a 40B-param model trains on one device (BASELINE.md).
+a 40B-param model trains on one device (SURVEY.md).
 
 TPU recasting: the unit of paging is a LAYER GROUP (one scanned layer's
 param pytree, or the embed/head chains) — the natural streaming granule of

@@ -6,7 +6,7 @@ fetch time (runtime/swap_tensor/partitioned_param_swapper.py:36, wired at
 stage3.py:932), gradients partitioned to CPU/NVMe (stage3.py:2088), and
 optimizer states swapped around a sub_group-wise step (stage3.py:2777,
 2633-2686).  That is the reference's "40B params on one V100" story
-(BASELINE.md).
+(SURVEY.md).
 
 TPU recasting (no autograd hooks; a Python-driven streaming step around
 small jitted programs):

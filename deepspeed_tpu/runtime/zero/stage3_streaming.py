@@ -21,16 +21,15 @@ over the ZeRO ("data","expert") axes:
   - with prefetch enabled (``stage3_prefetch_bucket_size`` covering a
     group) the scan carries a double buffer: the gather for group ``i+1``
     is ISSUED into the scan carry before group ``i``'s compute and
-    consumed one iteration later (``stage3_prefetch_mode: carried``, the
-    default), so the gather's issue→first-consume distance spans a full
-    group of MXU work — overlap as a *program-graph property* (T3,
-    arXiv:2401.16677) that the Schedule Auditor verifies statically,
+    consumed one iteration later, so the gather's issue→first-consume
+    distance spans a full group of MXU work — overlap as a
+    *program-graph property* (T3, arXiv:2401.16677) that the Schedule
+    Auditor verifies statically,
     rather than a scheduling opportunity XLA may or may not take.  The
     backward re-gather sweep is double-buffered the same way.  This is
     the role of PrefetchCoordinator's trace-based lookahead, without
-    needing a trace (the scan order IS the trace);
-    ``stage3_prefetch_mode: unrolled`` keeps the legacy unroll-2 body
-    (overlap left to XLA's latency-hiding scheduler);
+    needing a trace (the scan order IS the trace).  A bucket under one
+    layer group (0) gathers each group at use instead;
   - the backward of a tiled all-gather over the ZeRO axes is a
     psum-scatter — run in fp32 regardless of compute dtype
     (_all_gather_f32grad): layer gradients leave the region already
@@ -45,14 +44,14 @@ Scan-in-scan (fused whole-step program, runtime/fused_step.py): the fused
 train step wraps this layer scan in an OUTER ``lax.scan`` over the
 microbatch axis.  No special casing is needed here, but the composition
 leans on an invariant of this file: gathered layer groups are NEVER saved
-as residuals.  In ``carried`` mode that is structural — the hand-written
+as residuals.  With prefetch that is structural — the hand-written
 VJP's residuals are each layer's input activation carry plus the
 sharded inputs, and the backward re-gathers (``_build_carried_stream``);
-in ``unrolled``/``off`` modes the ``zero3_gathered`` checkpoint-name
-policy (see ``gather_group``) does the same job through the remat
-machinery.  Without the invariant the fused program would save gas ×
-(full unsharded model) and defeat max_live across microbatches, not just
-within one.  Tested by test_fused_step.py::test_fused_zero3_streaming_parity.
+gathering at use, the ``zero3_gathered`` checkpoint-name policy (see
+``gather_group``) does the same job through the remat machinery.
+Without the invariant the fused program would save gas × (full unsharded
+model) and defeat max_live across microbatches, not just within one.
+Tested by test_fused_step.py::test_fused_zero3_streaming_parity.
 """
 
 import logging
@@ -68,7 +67,6 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec
 
-from ...constants import ZERO_OPTIMIZATION_PREFETCH_MODES as PREFETCH_MODES
 from ...ops.collective_matmul import fcm_all_gather, fcm_reduce_scatter
 from ...parallel.mesh import MeshContext, ZERO_AXES
 from ...utils.logging import log_dist
@@ -84,19 +82,16 @@ from .partition import (filter_spec_axes, resolve_hpz_axes,
 class StreamPlan:
     """How the layer stack is grouped and prefetched.
 
-    ``mode`` is the prefetch structure actually applied: ``carried`` is
-    the double-buffered scan carry (gather for group i+1 issued under
-    group i's compute, in both the forward and the backward re-gather
-    sweep), ``unrolled`` is the legacy unroll-2 loop body (XLA's
-    latency-hiding scheduler must find the overlap), ``off`` gathers
-    each group at use.  ``forfeited`` records WHY a requested prefetch
-    degraded to ``off`` (surfaced by the Schedule Auditor's overlap
-    report and logged once at trace time)."""
+    ``prefetch`` is the structure applied: the double-buffered scan
+    carry (gather for group i+1 issued under group i's compute, in both
+    the forward and the backward re-gather sweep), or each group
+    gathered at use.  ``forfeited`` records WHY a requested prefetch
+    degraded to gathers at use (surfaced by the Schedule Auditor's
+    overlap report and logged once at trace time)."""
     layers_per_step: int
     prefetch: bool
     num_layers: int
     params_per_layer: int
-    mode: str = "off"
     forfeited: Optional[str] = None
 
     @property
@@ -108,79 +103,45 @@ class StreamPlan:
 
 def plan_layer_streaming(num_layers: int, params_per_layer: int,
                          max_live_parameters: int,
-                         prefetch_bucket_size: int,
-                         prefetch_mode: str = "carried") -> StreamPlan:
+                         prefetch_bucket_size: int) -> StreamPlan:
     """Consume the stage-3 knobs into a concrete (group, prefetch) plan.
 
     ``stage3_max_live_parameters`` bounds the gathered set (reference
     zero/config.py ``max_live_parameters``); ``stage3_prefetch_bucket_size``
-    enables lookahead when it covers at least one more layer group;
-    ``stage3_prefetch_mode`` picks the prefetch program structure:
-
-      carried   (default) the gather for group i+1 rides the scan carry —
-                issue→first-consume spans a full group of MXU work, and
-                the only constraint is >= 2 groups (any divisor group
-                count works);
-      unrolled  the legacy unroll-2 loop body — needs an EVEN group
-                count (otherwise prefetch would cost double the gathers
-                for zero overlap) and leaves the overlap to XLA's
-                latency-hiding scheduler;
-      off       gather at use, no lookahead.
+    enables lookahead when it covers at least one more layer group: the
+    gather for group i+1 then rides the scan carry, issue→first-consume
+    spans a full group of MXU work, and the only constraint is >= 2
+    groups (any divisor group count works).  Otherwise each group is
+    gathered at use.
     """
-    if prefetch_mode not in PREFETCH_MODES:
-        raise ValueError(
-            f"stage3_prefetch_mode={prefetch_mode!r} — supported modes are "
-            f"{list(PREFETCH_MODES)}")
     base_budget = max(1, int(max_live_parameters) // max(
         1, params_per_layer))
     # a bucket smaller than one layer group is the documented prefetch
     # OFF switch (no forfeit); a bucket that ASKS for prefetch which the
     # live-parameter budget then cannot honor is a loud forfeit below
-    wants = (prefetch_mode != "off" and
-             int(prefetch_bucket_size) >= params_per_layer)
-    want_prefetch = wants and base_budget >= 2
+    wants = int(prefetch_bucket_size) >= params_per_layer
     forfeited = None
-    if wants and not want_prefetch:
+    if wants and base_budget < 2:
         forfeited = (
             f"stage3_max_live_parameters holds {base_budget} layer(s) — "
             "a double buffer needs at least 2 (current + prefetched "
             "group)")
-    if want_prefetch:
+    elif wants:
         # live set holds current + prefetched group
         budget = base_budget // 2
-        if prefetch_mode == "carried":
-            candidates = [g for g in range(1, budget + 1)
-                          if num_layers % g == 0 and num_layers // g >= 2]
-            if candidates:
-                return StreamPlan(layers_per_step=max(candidates),
-                                  prefetch=True, num_layers=num_layers,
-                                  params_per_layer=params_per_layer,
-                                  mode="carried")
-            forfeited = (
-                f"{num_layers} layer(s) cannot form >= 2 groups within "
-                f"the double-buffer budget of {budget} group(s)")
-        else:
-            # the unroll-2 execution needs an EVEN number of groups —
-            # otherwise prefetch would silently cost double the gathers
-            # for zero overlap
-            candidates = [g for g in range(1, budget + 1)
-                          if num_layers % g == 0 and
-                          (num_layers // g) % 2 == 0
-                          and num_layers // g >= 2]
-            if candidates:
-                return StreamPlan(layers_per_step=max(candidates),
-                                  prefetch=True, num_layers=num_layers,
-                                  params_per_layer=params_per_layer,
-                                  mode="unrolled")
-            forfeited = (
-                f"no group size with an EVEN group count divides "
-                f"{num_layers} layers within the double-buffer budget of "
-                f"{budget} group(s) (unrolled prefetch pairs groups; "
-                f"stage3_prefetch_mode=carried has no such constraint)")
+        candidates = [g for g in range(1, budget + 1)
+                      if num_layers % g == 0 and num_layers // g >= 2]
+        if candidates:
+            return StreamPlan(layers_per_step=max(candidates),
+                              prefetch=True, num_layers=num_layers,
+                              params_per_layer=params_per_layer)
+        forfeited = (
+            f"{num_layers} layer(s) cannot form >= 2 groups within "
+            f"the double-buffer budget of {budget} group(s)")
     g = largest_divisor_at_most(num_layers, base_budget)
     return StreamPlan(layers_per_step=g, prefetch=False,
                       num_layers=num_layers,
-                      params_per_layer=params_per_layer, mode="off",
+                      params_per_layer=params_per_layer,
                       forfeited=forfeited)
 
 
@@ -282,9 +243,9 @@ def _layer_of(full, extras, j):
 
 def _body_closes_over_tracers(body) -> bool:
     """True when the user body (or a callable it closes over, two levels
-    deep) captures live JAX tracers.  NO streaming mode differentiates
+    deep) captures live JAX tracers.  Neither structure differentiates
     such a body — shard_map cannot transpose captured tracers
-    (NotImplementedError in off/unrolled), and the carried custom_vjp
+    (NotImplementedError gathering at use), and the carried custom_vjp
     differentiates only its explicit inputs (UnexpectedTracerError) —
     both failures surface deep inside grad with no hint at the cause,
     so scan() detects the capture up front and logs the actionable
@@ -323,7 +284,7 @@ def _build_carried_stream(steps: int, g: int, gather_group, run_layer,
                           scatter_grads):
     """Carried double-buffer executor with a hand-scheduled VJP.
 
-    Program structure (``stage3_prefetch_mode: carried``)::
+    Program structure::
 
         forward:  full(0) = gather(group 0)                 # prologue
                   scan i = 0 .. S-2, carry (act, full(i)):
@@ -368,7 +329,7 @@ def _build_carried_stream(steps: int, g: int, gather_group, run_layer,
     time).  Peak gathered memory stays at ``2 x layers_per_step x
     params_per_layer``.
 
-    ``steps`` must be >= 2 (the plan guarantees it in carried mode).
+    ``steps`` must be >= 2 (a plan that prefetches guarantees it).
     ``gather_group(shards) -> full``, ``run_layer(act, layer_leaves,
     layer_extras) -> act`` for ONE of a group's ``g`` layers and
     ``scatter_grads(g_full) -> g_shards`` (the exact transpose of
@@ -379,7 +340,7 @@ def _build_carried_stream(steps: int, g: int, gather_group, run_layer,
     if steps < 2:
         raise ValueError(
             f"carried prefetch needs >= 2 layer groups, got {steps} — "
-            "plan_layer_streaming should have forfeited to mode=off")
+            "plan_layer_streaming should have forfeited the prefetch")
 
     # a list of leaves is a pytree: _index_tree slices shard groups too
     _group_shards = _index_tree
@@ -532,13 +493,10 @@ class Zero3StreamContext:
     def __init__(self, mesh_ctx: MeshContext, max_live_parameters: int,
                  prefetch_bucket_size: int,
                  persistence_threshold: int = 0,
-                 low_bandwidth=None, prefetch_mode: str = "carried"):
-        # validation lives at the config boundary (config.py) and in
-        # plan_layer_streaming (the public planner); no third copy here
+                 low_bandwidth=None):
         self.ctx = mesh_ctx
         self.max_live_parameters = int(max_live_parameters)
         self.prefetch_bucket_size = int(prefetch_bucket_size)
-        self.prefetch_mode = prefetch_mode
         self.persistence_threshold = int(persistence_threshold)
         self.axis_sizes = {a: mesh_ctx.axis_size(a) for a in ZERO_AXES}
         self.manual = frozenset(
@@ -684,8 +642,7 @@ class Zero3StreamContext:
             int(np.prod(leaf.shape[1:])) for leaf in leaves)
         return plan_layer_streaming(num_layers, per_layer,
                                     self.max_live_parameters,
-                                    self.prefetch_bucket_size,
-                                    self.prefetch_mode)
+                                    self.prefetch_bucket_size)
 
     def _leaf_transpose_plan(self, local_shape, dtype, dims):
         """Static transpose schedule of ``gather_group``'s wire for one
@@ -732,7 +689,7 @@ class Zero3StreamContext:
 
         plan = self.plan_for(stacked_params)
         if not self._plan_logged and _body_closes_over_tracers(body):
-            # no streaming mode can DIFFERENTIATE a body that captures
+            # neither structure can DIFFERENTIATE a body that captures
             # traced values (shard_map cannot transpose captured
             # tracers; the carried custom_vjp differentiates only its
             # explicit inputs) — both failures are opaque deep inside
@@ -766,7 +723,7 @@ class Zero3StreamContext:
                       f"qgz={self.lbc.qgz_bits}b hpz={hpz}"
                       f"{' fcm' if self.fcm else ''}")
             saved = ""
-            if plan.mode == "carried":
+            if plan.prefetch:
                 # the carried VJP's residuals: every layer's input carry
                 zero_world = int(np.prod(
                     [self.axis_sizes[a] for a in self.manual]))
@@ -778,8 +735,8 @@ class Zero3StreamContext:
                          f"per shard)")
             log_dist(
                 f"ZeRO-3 streaming: {plan.num_layers} layers in groups of "
-                f"{plan.layers_per_step}, prefetch={plan.prefetch} "
-                f"(mode={plan.mode}), live<= {plan.live_parameters:,} "
+                f"{plan.layers_per_step}, prefetch={plan.prefetch}, "
+                f"live<= {plan.live_parameters:,} "
                 f"params (max_live={self.max_live_parameters:,}){saved}{lb}",
                 ranks=[0])
             if plan.forfeited:
@@ -886,7 +843,7 @@ class Zero3StreamContext:
                             tuple(layer_extras))
             return carry
 
-        if plan.mode == "carried":
+        if plan.prefetch:
             # Carried double-buffer prefetch (_build_carried_stream): the
             # gather for group i+1 rides the scan carry, issued under
             # group i's compute, and the hand-written VJP re-gathers in a
@@ -957,20 +914,9 @@ class Zero3StreamContext:
                 policy=jax.checkpoint_policies.
                 save_anything_except_these_names("zero3_gathered"))
 
-            # Unrolled prefetch = unroll-2 over groups: the two gathers in
-            # the unrolled loop body are independent of each other's
-            # compute, so XLA's latency-hiding scheduler MAY hoist
-            # gather(i+1) alongside compute(i) — the PrefetchCoordinator's
-            # lookahead (stage3.py:169) as a loop structure, but only as a
-            # scheduling opportunity, not a program property (the carried
-            # mode makes it structural).  The plan guarantees an even
-            # group count whenever unrolled prefetch is on.
-            unroll = 2 if plan.prefetch else 1
-
             def region_fn(carry, params_grouped, extras_grouped):
                 carry, _ = lax.scan(
-                    step, carry, (params_grouped, extras_grouped),
-                    unroll=unroll)
+                    step, carry, (params_grouped, extras_grouped))
                 return carry
 
         # check_vma SCOPED (advisor r3): pallas_call outputs carry no

@@ -1,2 +1,2 @@
 from .logging import logger, log_dist
-from .timer import SynchronizedWallClockTimer, ThroughputTimer
+from .timer import ThroughputTimer

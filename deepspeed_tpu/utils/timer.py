@@ -1,4 +1,4 @@
-"""Wall-clock and throughput timers (reference: deepspeed/utils/timer.py:19).
+"""The throughput timer (reference: deepspeed/utils/timer.py:19).
 
 Where the reference synchronizes CUDA streams, we synchronize XLA's async
 dispatch queue: `_device_sync` runs a trivial computation and blocks on it,
@@ -6,7 +6,6 @@ which (by in-order execution per device) drains previously dispatched work.
 """
 
 import time
-from typing import Dict, List, Optional
 
 from .logging import log_dist, logger
 
@@ -33,81 +32,6 @@ def _device_sync():
             logger.debug(f"timer device sync unavailable "
                          f"({type(e).__name__}: {e}) — timings will not "
                          "drain the dispatch queue")
-
-
-class SynchronizedWallClockTimer:
-    """Named timer group; `elapsed` drains the device queue before reading."""
-
-    class Timer:
-        def __init__(self, name: str):
-            self.name_ = name
-            self.elapsed_ = 0.0
-            self.started_ = False
-            self.start_time = time.time()
-
-        def start(self):
-            assert not self.started_, f"timer {self.name_} has already been started"
-            _device_sync()
-            self.start_time = time.time()
-            self.started_ = True
-
-        def stop(self, reset=False):
-            assert self.started_, "timer is not started"
-            _device_sync()
-            if reset:
-                self.elapsed_ = time.time() - self.start_time
-            else:
-                self.elapsed_ += time.time() - self.start_time
-            self.started_ = False
-
-        def reset(self):
-            self.elapsed_ = 0.0
-            self.started_ = False
-
-        def elapsed(self, reset=True):
-            started_ = self.started_
-            if started_:
-                self.stop()
-            elapsed_ = self.elapsed_
-            if reset:
-                self.reset()
-            if started_:
-                self.start()
-            return elapsed_
-
-        def mean(self):
-            return self.elapsed(reset=False)
-
-    def __init__(self):
-        self.timers: Dict[str, "SynchronizedWallClockTimer.Timer"] = {}
-
-    def __call__(self, name: str):
-        if name not in self.timers:
-            self.timers[name] = self.Timer(name)
-        return self.timers[name]
-
-    @staticmethod
-    def memory_usage():
-        try:
-            import jax
-            stats = jax.local_devices()[0].memory_stats() or {}
-            in_use = stats.get("bytes_in_use", 0)
-            peak = stats.get("peak_bytes_in_use", 0)
-            return (f"MemAllocated={in_use / 2**30:.2f} GB "
-                    f"MaxMemAllocated={peak / 2**30:.2f} GB")
-        except Exception:
-            return "MemAllocated=? MaxMemAllocated=?"
-
-    def log(self, names: List[str], normalizer: float = 1.0, reset: bool = True,
-            memory_breakdown: bool = False, ranks: Optional[List[int]] = None):
-        assert normalizer > 0.0
-        string = "time (ms)"
-        for name in names:
-            if name in self.timers:
-                elapsed_time = self.timers[name].elapsed(
-                    reset=reset) * 1000.0 / normalizer
-                string += " | {}: {:.2f}".format(name, elapsed_time)
-        log_dist(string, ranks=ranks or [0])
 
 
 class ThroughputTimer:

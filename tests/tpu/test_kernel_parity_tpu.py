@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from deepspeed_tpu.ops.flash_attention import (flash_attention,
                                                flash_attention_pallas,
                                                mha_reference)
-from deepspeed_tpu.ops.normalize import fused_layer_norm
 from deepspeed_tpu.runtime.quantize import quantize_dequantize
 
 # bf16 has ~3 decimal digits; sums over S=1024 add noise
@@ -108,42 +107,6 @@ def test_flash_dispatcher_unaligned_length_falls_back():
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32),
         rtol=BF16_RTOL, atol=BF16_ATOL)
-
-
-def test_fused_layer_norm_fwd_bwd_parity_bf16(monkeypatch):
-    """Compiled-Mosaic parity of the PALLAS LN kernels (they are no
-    longer the dispatch default — XLA LN measured faster — so this test
-    must select them explicitly or it compares XLA against XLA)."""
-    monkeypatch.setattr("deepspeed_tpu.ops.dispatch._ln_impl", "pallas")
-    x = jax.random.normal(jax.random.PRNGKey(3), (8, 1024, 768),
-                          jnp.bfloat16)
-    w = jnp.ones((768,), jnp.float32) * 1.1
-    b = jnp.zeros((768,), jnp.float32) + 0.1
-
-    def ref_ln(x, w, b):
-        xf = x.astype(jnp.float32)
-        mu = xf.mean(-1, keepdims=True)
-        var = ((xf - mu) ** 2).mean(-1, keepdims=True)
-        return (((xf - mu) / jnp.sqrt(var + 1e-5)) * w + b).astype(x.dtype)
-
-    out = fused_layer_norm(x, w, b, 1e-5)
-    ref = ref_ln(x, w, b)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
-                               rtol=BF16_RTOL, atol=BF16_ATOL)
-
-    def loss(f):
-        def inner(x, w, b):
-            return jnp.sum(f(x, w, b).astype(jnp.float32) ** 2)
-        return inner
-
-    gf = jax.grad(loss(lambda x, w, b: fused_layer_norm(x, w, b, 1e-5)),
-                  argnums=(0, 1, 2))(x, w, b)
-    gr = jax.grad(loss(ref_ln), argnums=(0, 1, 2))(x, w, b)
-    for a, b_ in zip(gf, gr):
-        np.testing.assert_allclose(
-            np.asarray(a, np.float32), np.asarray(b_, np.float32),
-            rtol=5e-2, atol=5e-1)  # wide: bf16 sums over 8*1024 rows
 
 
 def test_group_quantizer_roundtrip_tpu():
@@ -266,22 +229,13 @@ def test_block_sparse_flash_parity_bf16_tpu(causal):
                                    atol=5e-2, rtol=5e-2)
 
 
-@pytest.mark.parametrize("pbits", [32, 8])
-def test_flash_inkernel_dropout_tpu(pbits, monkeypatch):
+def test_flash_inkernel_dropout_tpu():
     """In-kernel probability dropout on the compiled Mosaic path:
     determinism per seed, drop-rate statistics via a ones-valued v, exact
     rate-0 equality, and a directional finite-difference check of the
     custom VJP (valid because a fixed seed makes the function
-    deterministic).  Parametrized over the PRNG width: 8-bit mode packs
-    four mask bytes per random word (4x cheaper generation) and must pass
-    the same statistics/FD bars as the 32-bit default."""
-    import importlib
+    deterministic).  The draw packs four mask bytes per random word."""
     from deepspeed_tpu.ops.flash_attention import flash_attention
-    # monkeypatch by module OBJECT: the string path resolves through
-    # deepspeed_tpu.ops.__init__, where the re-exported flash_attention
-    # FUNCTION shadows the submodule attribute of the same name
-    fa_mod = importlib.import_module("deepspeed_tpu.ops.flash_attention")
-    monkeypatch.setattr(fa_mod, "_dropout_bits", pbits)
     ks = jax.random.split(jax.random.PRNGKey(3), 4)
     shape = (2, 4, 1024, 64)
     q, k, v = (jax.random.normal(kk, shape, jnp.float32) for kk in ks[:3])
@@ -346,49 +300,34 @@ def test_fused_dequant_matmul_parity_tpu():
                                    np.asarray(ref), rtol=2e-2, atol=2.0)
 
 
-def test_flash_dropout_mask_reuse_tpu(monkeypatch):
-    """Mask-reuse mode (store bit-packed keep mask in fwd, read it in
-    both bwd kernels): the stored mask IS the regenerated mask, so from
-    one forward's out and lse the gradients are BIT-IDENTICAL to the
-    regen default's.  The storing forward is the kernel with q rows on
-    the sublanes (flash_fwd_rows), the regenerating one carries them on
-    the lanes: their outputs differ by the order of float32 additions
-    and bf16 rounding alone.  Also pins that reuse engages (residual
-    mask present) rather than silently falling back to regen."""
-    import importlib
-    fa_mod = importlib.import_module("deepspeed_tpu.ops.flash_attention")
-    from deepspeed_tpu.ops.flash_attention import flash_attention_bwd_pallas
-    ks = jax.random.split(jax.random.PRNGKey(5), 4)
-    shape = (2, 4, 1024, 64)
-    q, k, v, do = (jax.random.normal(kk, shape, jnp.float32) for kk in ks)
-    rate = 0.2
-    kw = dict(causal=True, dropout_rate=rate, block_q=fa_mod.DEFAULT_BLOCK_Q,
-              block_k=fa_mod.DEFAULT_BLOCK_K)
-
-    monkeypatch.setattr(fa_mod, "_dropout_reuse", True)
-    # reuse path engages: the fwd residuals carry a packed mask
-    _, res = fa_mod._flash_fwd(q, k, v, jnp.array([11], jnp.int32), True,
-                               None, fa_mod.DEFAULT_BLOCK_Q,
-                               fa_mod.DEFAULT_BLOCK_K, "bhsd", rate)
-    assert res[-1] is not None and res[-1].dtype == jnp.uint32
-    assert res[-1].shape == (2, 4, 1024 // 32, 1024)
-
-    out, lse = flash_attention_pallas(q, k, v, return_lse=True,
-                                      dropout_seed=11, **kw)
-    out_s, lse_s, packed = flash_attention_pallas(
-        q, k, v, return_lse=True, dropout_seed=11, save_dropout_mask=True,
-        **kw)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(out_s),
-                               rtol=BF16_RTOL, atol=BF16_ATOL)
-    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_s),
-                               rtol=1e-5, atol=1e-5)
-    g_regen = flash_attention_bwd_pallas(q, k, v, out, lse, do,
-                                         dropout_seed=11, **kw)
-    g_reuse = flash_attention_bwd_pallas(
-        q, k, v, out, lse, do, dropout_mask=packed,
-        dropout_mask_block_q=fa_mod.DEFAULT_BLOCK_Q, **kw)
-    for a, b in zip(g_regen, g_reuse):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+@pytest.mark.parametrize("block_q", [64, 8])
+@pytest.mark.parametrize("rate", [0.0, 0.1])
+def test_flash_forward_below_a_lane_tile_tpu(block_q, rate):
+    """The one forward body (q rows along the lanes) compiled by Mosaic
+    at q blocks under a lane tile, half of one and a single sublane
+    tile: out and the log-sum-exp against the reference without dropout;
+    with it, the mask read back position for position keeps the share
+    the threshold names and nothing above the diagonal."""
+    from tests.unit.test_flash_causal_bound import (_kernel_keep_mask,
+                                                    _reference_lse)
+    seq, heads = 512, 2
+    call = dict(causal=True, block_q=block_q, block_k=512)
+    if not rate:
+        q, k, v = _qkv(1, heads, seq, 64, jnp.bfloat16, seed=block_q)
+        out, lse = flash_attention_pallas(q, k, v, return_lse=True, **call)
+        np.testing.assert_allclose(
+            np.asarray(out, np.float32),
+            np.asarray(mha_reference(q, k, v, causal=True), np.float32),
+            rtol=BF16_RTOL, atol=BF16_ATOL)
+        np.testing.assert_allclose(np.asarray(lse),
+                                   np.asarray(_reference_lse(q, k)),
+                                   rtol=1e-4, atol=1e-4)
+        return
+    under = np.tril(np.ones((seq, seq), bool))
+    keep = _kernel_keep_mask(heads, seq, block_q, 512, rate,
+                             interpret=False, chunk=128, dtype=jnp.bfloat16)
+    assert abs(keep[:, under].mean() - 230 / 256) < 0.005
+    assert not keep[:, ~under].any()
 
 
 @pytest.mark.parametrize("seq,block_q,block_k", [

@@ -119,11 +119,6 @@ def test_pipe_seq_matches_baseline(pipe, tp, seq, zero):
         np.testing.assert_allclose(a, b, rtol=5e-5, atol=1e-5)
 
 
-from tests.unit.seed_xfails import (  # noqa: E402 — marker for the triaged seed failures
-    PARTITION_ID_XFAIL as _PARTITION_ID_XFAIL)
-
-
-@_PARTITION_ID_XFAIL
 def test_plain_body_pipe_expert_matches_baseline():
     """A PLAIN (dense GPT-2) body with an expert axis: the expert axis only
     shards the batch (expert-data parallelism), so the gated executor stays

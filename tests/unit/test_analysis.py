@@ -615,14 +615,14 @@ def test_zero3_streaming_gather_on_critical_path_pinned():
     pre-carried schedule, frozen in golden/gpt2_zero3_stream_schedule_
     serialized.json) the streamed stage-3 program gathers each group at
     use, and the overlap rule must flag the serialized hot-loop gathers
-    with the plan's provenance.  ISSUE 7's carried mode flips this to
+    with the plan's provenance.  ISSUE 7's carried prefetch flips this to
     zero findings — pinned by test_zero3_streaming_carried_flips_
     overlap_gate_green."""
     engine = _tiny_engine(extra_config={"zero_optimization": {
         "stage": 3, "stage3_param_persistence_threshold": 0,
         "stage3_max_live_parameters": 1,
         "stage3_prefetch_bucket_size": 0}})
-    assert engine._zero3_stream.last_plan.mode == "off"
+    assert not engine._zero3_stream.last_plan.prefetch
     report = engine.program_audit
     assert report.wire_bytes_per_step > 0
     assert any("all_gather" in s for s in report.collective_sequence)
@@ -637,31 +637,30 @@ def test_zero3_streaming_gather_on_critical_path_pinned():
                    if "all_gather" in f.message]
     assert gather_hits
     assert any("streamed ZeRO-3 plan" in f.message for f in gather_hits)
-    assert any("mode=off" in f.message for f in gather_hits)
+    assert any("prefetch=False" in f.message for f in gather_hits)
     assert report.overlap["n_serialized_hot_loop"] > 0
     assert report.overlap_efficiency < 1.0
 
 
-def _stream_engine(mode, layers=2, bucket=200_000, max_live=200_000):
+def _stream_engine(layers=2, bucket=200_000, max_live=200_000):
     cfg = {"stage": 3, "stage3_param_persistence_threshold": 0,
            "stage3_max_live_parameters": max_live,
-           "stage3_prefetch_bucket_size": bucket,
-           "stage3_prefetch_mode": mode}
+           "stage3_prefetch_bucket_size": bucket}
     return _tiny_engine(extra_config={"zero_optimization": cfg},
                         num_layers=layers)
 
 
 def test_zero3_streaming_carried_flips_overlap_gate_green():
-    """ISSUE 7 tentpole pin: with stage3_prefetch_mode=carried (the
-    default) the hot-loop weight gathers ride the scan carry — the
+    """ISSUE 7 tentpole pin: with a prefetch bucket that covers a layer
+    group the hot-loop weight gathers ride the scan carry — the
     overlap rule verifies the double buffer statically (zero findings
     even under require_overlap), every hot-loop gather record is
     ``carried``, and the bytes-weighted efficiency beats the frozen
     serialized baseline."""
     from deepspeed_tpu.analysis import audit_engine
-    engine = _stream_engine("carried")
+    engine = _stream_engine()
     plan = engine._zero3_stream.last_plan
-    assert plan.mode == "carried" and plan.prefetch
+    assert plan.prefetch
     report = engine.program_audit
     assert report.findings == [], [f.format() for f in report.findings]
     assert report.overlap["n_serialized_hot_loop"] == 0
@@ -691,10 +690,10 @@ def test_zero3_streaming_carried_liveness_within_plan_bound():
     at-use program's peak plus the plan's 2x-group live-parameter bound
     — a full-model stacking regression would blow past it by
     (num_layers - 2) x group."""
-    carried = _stream_engine("carried")
-    at_use = _stream_engine("off")
+    carried = _stream_engine()
+    at_use = _stream_engine(bucket=0)
     plan = carried._zero3_stream.last_plan
-    assert plan.mode == "carried"
+    assert plan.prefetch and not at_use._zero3_stream.last_plan.prefetch
     group_bytes = plan.layers_per_step * plan.params_per_layer * 4
     peak_carried = carried.program_audit.peak_hbm_bytes
     peak_at_use = at_use.program_audit.peak_hbm_bytes
@@ -704,20 +703,18 @@ def test_zero3_streaming_carried_liveness_within_plan_bound():
 
 def test_zero3_streaming_forfeited_prefetch_surfaced():
     """plan_layer_streaming forfeits a requested prefetch when no legal
-    group split exists (e.g. unrolled mode on an odd prime layer count)
-    — the auditor must surface the forfeit as a warning finding instead
-    of silently falling back to serialized gathers."""
-    engine = _stream_engine("unrolled", layers=3)
+    group split exists (a single layer cannot form two groups) — the
+    auditor must surface the forfeit as a warning finding instead of
+    silently falling back to serialized gathers."""
+    engine = _stream_engine(layers=1)
     plan = engine._zero3_stream.last_plan
     assert not plan.prefetch and plan.forfeited is not None
     report = engine.program_audit
     forfeits = [f for f in report.findings
                 if f.rule == RULE_OVERLAP and "FORFEITED" in f.message]
     assert len(forfeits) >= 1
-    assert "EVEN" in forfeits[0].message
-    # the unrolled forfeit reason names the mode that lifts the
-    # constraint (plan_layer_streaming's message rides into the finding)
-    assert "carried" in forfeits[0].message
+    # plan_layer_streaming's reason rides into the finding
+    assert ">= 2 groups" in forfeits[0].message
     # the serialized gathers themselves are still flagged alongside
     assert any("critical path" in f.message for f in report.findings)
 

@@ -216,7 +216,7 @@ def test_fcm_never_increases_wire_bytes():
     the bytes to the hidden-comm lane."""
     outcome = _search({
         "zero_stages": [3], "stage3_variants": ["streamed"],
-        "prefetch_modes": ["carried"], "micro_batches": [2],
+        "micro_batches": [2],
         "qwz_bits": [8], "qgz_bits": [8],
         "fused_collective_matmul": [False, True], "top_k": 2})
     by_name = {rc.candidate.name: rc for rc in outcome.ranked}
@@ -296,7 +296,7 @@ def test_shrinking_hbm_budget_never_adds_candidates(example_outcome):
     mid = (min(peaks.values()) + max(peaks.values())) / 2 / 2 ** 20
     restricted = _search(
         {"zero_stages": [2, 3], "stage3_variants": ["streamed"],
-         "prefetch_modes": ["carried", "off"], "micro_batches": [1, 2],
+         "stage3_bucket_sizes": [200000, 0], "micro_batches": [1, 2],
          "qwz_bits": [0, 8], "top_k": 3},
         hbm_budget_mb=mid)
     survivors = {rc.candidate.name for rc in restricted.ranked}
@@ -396,7 +396,7 @@ def test_calibration_roundtrip_flips_winner(tmp_path, capsys):
         C.ANALYSIS_HW_HBM_GBPS_DEFAULT * 10, rel=1e-6)
 
     axes = {"zero_stages": [2, 3], "stage3_variants": ["streamed"],
-            "prefetch_modes": ["off"], "micro_batches": [2],
+            "stage3_bucket_sizes": [0], "micro_batches": [2],
             "qwz_bits": [8]}
     default = _search(axes)
     calibrated = _search(axes, calibration=str(cal_file))
@@ -508,11 +508,10 @@ def test_nvme_candidate_ranks_slower_than_resident():
     """End-to-end through the search: the NVMe candidate audits its
     resident twin but pays the disk trips via the swap lane."""
     nvme = _search({"zero_stages": [3], "stage3_variants": ["streamed"],
-                    "prefetch_modes": ["carried"], "micro_batches": [2],
+                    "micro_batches": [2],
                     "offload": ["nvme"]})
     resident = _search({"zero_stages": [3],
                         "stage3_variants": ["streamed"],
-                        "prefetch_modes": ["carried"],
                         "micro_batches": [2], "offload": ["none"]})
     n, r = nvme.ranked[0], resident.ranked[0]
     assert "off-nvme" in n.candidate.name
@@ -632,8 +631,8 @@ def test_autotuning_config_validation():
         AutotuningConfig.from_dict({"hbm_budget_mb": -1})
     with pytest.raises(DeepSpeedConfigError, match="fixed"):
         AutotuningConfig.from_dict({"fixed": ["not-a-dict"]})
-    with pytest.raises(DeepSpeedConfigError, match="prefetch_modes"):
-        AutotuningConfig.from_dict({"prefetch_modes": ["bogus"]})
+    with pytest.raises(DeepSpeedConfigError, match="stage3_bucket_sizes"):
+        AutotuningConfig.from_dict({"stage3_bucket_sizes": [-1]})
     cfg = AutotuningConfig.from_dict({"chips": 8, "qwz_bits": [0, 8]})
     assert cfg.chips == 8 and cfg.qwz_bits == (0, 8)
 
@@ -657,7 +656,7 @@ def test_enumeration_is_gated():
     requires the streamed stage-3 shape; hpZ must divide the dp world."""
     tune = AutotuningConfig.from_dict({
         "chips": 8, "global_batch": 16, "zero_stages": [1, 3],
-        "stage3_variants": ["streamed"], "prefetch_modes": ["carried"],
+        "stage3_variants": ["streamed"],
         "micro_batches": [2], "qwz_bits": [0, 8],
         "offload": ["none", "nvme"], "hpz_group_sizes": [0, 3],
         "max_candidates": 64})
@@ -681,3 +680,27 @@ def test_enumeration_is_gated():
             "max_candidates": 12}), 8, 16)
     cpu_names = [c.name for c in cpu_space.candidates]
     assert cpu_names and all(n.endswith("off-cpu") for n in cpu_names)
+
+
+def test_a_bucket_of_zero_is_the_candidate_without_prefetch():
+    """The search has no prefetch axis of its own: an entry 0 of
+    stage3_bucket_sizes is the streamed candidate that gathers at use,
+    and the engine config it emits says so by the bucket alone."""
+    from deepspeed_tpu.config import DeepSpeedConfig
+    from deepspeed_tpu.runtime.zero.stage3_streaming import (
+        plan_layer_streaming)
+    tune = AutotuningConfig.from_dict({
+        "chips": 8, "global_batch": 16, "zero_stages": [3],
+        "stage3_variants": ["streamed"], "micro_batches": [2],
+        "stage3_bucket_sizes": [200000, 0]})
+    space = enumerate_candidates(dict(BASE), tune, 8, 16)
+    by_bucket = {c.knobs["stage3_bucket"]: c for c in space.candidates}
+    assert sorted(by_bucket) == [0, 200000]
+    assert "-g0-" in by_bucket[0].name and "-g200000-" in by_bucket[200000].name
+    assert all("prefetch_mode" not in c.knobs for c in space.candidates)
+    for bucket, cand in by_bucket.items():
+        zero = DeepSpeedConfig(cand.config, world_size=8).zero_config
+        assert zero.prefetch_bucket_size == bucket
+        plan = plan_layer_streaming(4, 50_000, zero.max_live_parameters,
+                                    zero.prefetch_bucket_size)
+        assert plan.prefetch == bool(bucket) and plan.forfeited is None

@@ -580,37 +580,6 @@ def test_matrix_step_boundary_crash_raises_injected_crash():
     assert chaos.active().fired[0]["step"] == 2
 
 
-def test_legacy_fault_injection_shim_still_works(tmp_path):
-    # deprecated import path (test_resilience/test_infinity_prefetch
-    # call sites): same objects, no behavior change
-    from deepspeed_tpu.runtime.resilience import fault_injection as fi
-    assert fi.InjectedCrash is InjectedCrash
-    assert fi.poison_batch is chaos.poison_batch
-    with fi.crash_after_bytes(4, path_prefix=str(tmp_path)):
-        with pytest.raises(InjectedCrash):
-            with open(tmp_path / "f.bin", "wb") as f:
-                f.write(b"12345")
-
-
-def test_legacy_fault_injection_shim_names_its_replacement():
-    # the deprecation must point movers at the chaos plane by module
-    # path — a bare "deprecated" is not actionable
-    import importlib
-    import warnings as _warnings
-
-    from deepspeed_tpu.runtime.resilience import fault_injection as fi
-    with _warnings.catch_warnings(record=True) as caught:
-        _warnings.simplefilter("always")
-        importlib.reload(fi)  # the warning fires at import time
-    dep = [w for w in caught
-           if issubclass(w.category, DeprecationWarning)]
-    assert len(dep) == 1
-    msg = str(dep[0].message)
-    assert "deepspeed_tpu.runtime.resilience.fault_injection is " \
-        "deprecated" in msg
-    assert "deepspeed_tpu.runtime.resilience.chaos" in msg
-
-
 def test_engine_drains_degradation_records():
     from deepspeed_tpu.monitor import record as R
     e = make_engine()

@@ -30,7 +30,6 @@ def test_resilience_package_imports_cleanly():
             "deepspeed_tpu.runtime.resilience.recovery",
             "deepspeed_tpu.runtime.resilience.preemption",
             "deepspeed_tpu.runtime.resilience.sentinel",
-            "deepspeed_tpu.runtime.resilience.fault_injection",
             # chaos plane + retry/degradation (round 21): fired lazily
             # from guarded imports at every injection surface — a broken
             # standalone import would silently disable fault injection

@@ -4,7 +4,8 @@ import json
 
 import pytest
 
-from deepspeed_tpu.config import DeepSpeedConfig, DeepSpeedConfigError
+from deepspeed_tpu.config import (REMOVED_KEYS, DeepSpeedConfig,
+                                  DeepSpeedConfigError)
 
 
 def base_config():
@@ -199,3 +200,36 @@ def test_prng_impl_config_knob():
     cfg2 = DeepSpeedConfig({"train_micro_batch_size_per_gpu": 1},
                            world_size=1)
     assert cfg2.prng_impl == "rbg"
+
+
+@pytest.mark.parametrize("section,key", sorted(REMOVED_KEYS))
+def test_removed_keys_are_refused_and_name_their_replacement(section, key):
+    """A key the package no longer reads is refused, not ignored: a
+    silently ignored "stage3_prefetch_mode": "off" would turn the
+    prefetch ON.  Every message names what to write instead."""
+    value = ["off"] if key.endswith("s") else "off"
+    with pytest.raises(DeepSpeedConfigError) as refused:
+        DeepSpeedConfig({"train_micro_batch_size_per_gpu": 1,
+                         section: {key: value}}, world_size=1)
+    message = str(refused.value)
+    assert f"{section}.{key}" in message
+    assert REMOVED_KEYS[(section, key)] in message
+    assert "stage3_prefetch_bucket_size" in message
+
+
+def test_the_prefetch_structure_follows_the_bucket():
+    """stage3_prefetch_bucket_size: 0 is the at-use plan, the default the
+    carried one."""
+    from deepspeed_tpu.runtime.zero.stage3_streaming import (
+        plan_layer_streaming)
+
+    def plan(zero):
+        z = DeepSpeedConfig({"train_micro_batch_size_per_gpu": 1,
+                             "zero_optimization": dict(stage=3, **zero)},
+                            world_size=1).zero_config
+        return plan_layer_streaming(8, 1000, z.max_live_parameters,
+                                    z.prefetch_bucket_size)
+
+    assert plan({}).prefetch
+    at_use = plan({"stage3_prefetch_bucket_size": 0})
+    assert not at_use.prefetch and at_use.forfeited is None

@@ -85,10 +85,20 @@ def test_forward_with_rows_on_the_lanes_under_a_band_and_groups(
     assert float(jnp.max(jnp.abs(lse - want_lse))) <= 2e-5
 
 
-def test_a_band_on_half_a_lane_tile_of_rows_keeps_the_older_forward():
-    assert _forward_kernel_name(seq=512, heads=4, kv_heads=2, window=128,
-                                block_q=64, block_k=256) == (
-        "flash_fwd_rows_band")
+def test_a_band_on_half_a_lane_tile_of_rows_takes_the_one_forward():
+    call = dict(window=128, block_q=64, block_k=256)
+    assert _forward_kernel_name(seq=512, heads=4, kv_heads=2,
+                                **call) == "flash_fwd_band"
+    ks = jax.random.split(jax.random.PRNGKey(64), 3)
+    q = jax.random.normal(ks[0], (1, 4, 512, 64))
+    k, v = (jax.random.normal(key, (1, 2, 512, 64)) for key in ks[1:])
+    out, lse = fa.flash_attention_pallas(q, k, v, causal=True,
+                                         interpret=True, return_lse=True,
+                                         **call)
+    want = fa.mha_reference(q, k, v, causal=True, window=128)
+    assert float(jnp.max(jnp.abs(out - want))) <= 2e-5
+    assert float(jnp.max(jnp.abs(lse - _reference_lse(q, k, window=128)))
+                 ) <= 2e-5
 
 
 def test_the_band_visits_its_tiles_only():

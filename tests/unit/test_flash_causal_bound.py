@@ -150,7 +150,7 @@ def test_causal_forward_and_gradients_match_reference(
     keep, inv = None, 1.0
     if rate:
         keep = _kernel_keep_mask(heads, seq, block_q, block_k, rate)
-        inv = fa._keep_scale(rate, fa._effective_dropout_bits(bk))
+        inv = fa._keep_scale(rate)
         under = np.tril(np.ones((seq, seq), bool))
         share = keep[:, under].mean()
         assert abs(share - 1.0 / inv) < 0.01, share
@@ -174,48 +174,6 @@ def test_causal_forward_and_gradients_match_reference(
         assert np.isfinite(np.asarray(got)).all(), name
         np.testing.assert_allclose(np.asarray(got), np.asarray(exp),
                                    rtol=2e-4, atol=2e-4, err_msg=name)
-
-
-@pytest.mark.parametrize("seq", [1024, 2048])
-def test_mask_reuse_keeps_working_under_the_bound(seq):
-    """The stored-mask mode (off by default) under the bound: the forward
-    stores the sub-tiles' keep bits it computed, the backward kernels read
-    them back, and the gradients are bit for bit what the regenerating
-    mode gives (the stored mask IS the regenerated one).  The storing
-    forward is the kernel with q rows on the sublanes (flash_fwd_rows:
-    the packed mask rides them), the regenerating one has them on the
-    lanes: the same mask, and outputs that differ by the order of the
-    float32 additions alone."""
-    bq, bk, rate = 512, 1024, 0.2
-    q, k, v, do = _inputs(2, seq, 32)
-    kw = dict(causal=True, block_q=bq, block_k=bk, interpret=True,
-              dropout_rate=rate)
-    out, lse = fa.flash_attention_pallas(q, k, v, return_lse=True,
-                                         dropout_seed=SEED, **kw)
-    out_s, lse_s, packed = fa.flash_attention_pallas(
-        q, k, v, return_lse=True, dropout_seed=SEED, save_dropout_mask=True,
-        **kw)
-    assert _forward_kernel(q, dropout_seed=SEED, **kw) == "flash_fwd"
-    assert _forward_kernel(q, dropout_seed=SEED, save_dropout_mask=True,
-                           return_lse=True, **kw) == "flash_fwd_rows"
-    np.testing.assert_allclose(np.asarray(out), np.asarray(out_s),
-                               rtol=0, atol=2e-6)
-    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_s),
-                               rtol=0, atol=2e-6)
-    # a dropped position is dropped in both: the same zeros
-    v_hot = jnp.zeros_like(v).at[:, :, jnp.arange(32), jnp.arange(32)].set(1.)
-    hot, hot_s = (fa.flash_attention_pallas(
-        jnp.zeros_like(q), jnp.zeros_like(k), v_hot, dropout_seed=SEED,
-        save_dropout_mask=save, return_lse=True, **kw)[0]
-        for save in (False, True))
-    np.testing.assert_array_equal(np.asarray(hot) > 0, np.asarray(hot_s) > 0)
-    regen = fa.flash_attention_bwd_pallas(q, k, v, out, lse, do,
-                                          dropout_seed=SEED, **kw)
-    reuse = fa.flash_attention_bwd_pallas(
-        q, k, v, out, lse, do, dropout_mask=packed,
-        dropout_mask_block_q=bq, **kw)
-    for a, b in zip(regen, reuse):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # --------------------------------------------------------------------------- #
@@ -244,7 +202,7 @@ def _stream_keep_mask(heads, seq, block_q, block_k, rate):
     _, bq, bk = fa._resolve_blocks(seq, seq, block_q, block_k)
     unit = fa._causal_sub_tile(bq, bk, True) or bk
     units = seq // unit
-    t8 = fa._quantized_threshold(rate, 8)
+    t8 = fa._quantized_threshold(rate)
     keep = np.zeros((heads, seq, seq), bool)
     seed = jnp.int32(SEED)
     for h in range(heads):
@@ -277,9 +235,12 @@ def _reference_lse(q, k, causal=True, window=None):
 
 
 # one inner step (512 and 1024: a key block spans the row) and several
-# (2048), at q blocks of one to eight lane tiles
+# (2048), at q blocks of one to eight lane tiles; and below a lane tile,
+# half of one (64 rows) and a single sublane tile (8)
 ON_LANES = [(seq, block_q, rate) for seq in (512, 1024, 2048)
             for block_q in (128, 256, 512, 1024) for rate in (0.0, 0.1)]
+ON_LANES += [(512, block_q, rate) for block_q in (64, 8)
+             for rate in (0.0, 0.1)]
 
 
 @pytest.mark.parametrize("seq,block_q,rate", ON_LANES)
@@ -298,7 +259,7 @@ def test_forward_with_rows_on_the_lanes_position_for_position(
     keep, inv = None, 1.0
     if rate:
         keep = _stream_keep_mask(heads, seq, block_q, block_k, rate)
-        inv = fa._keep_scale(rate, 8)
+        inv = fa._keep_scale(rate)
         applied = _kernel_keep_mask(heads, seq, block_q, block_k, rate)
         under = np.tril(np.ones((seq, seq), bool))
         np.testing.assert_array_equal(applied[:, under], keep[:, under])
@@ -331,25 +292,16 @@ def test_forward_and_backward_pair_see_one_mask():
 
 
 @pytest.mark.parametrize("call", [
-    dict(dropout_rate=0.1, dropout_seed=SEED, save_dropout_mask=True,
-         return_lse=True),                      # the packed mask
-    dict(dropout_rate=0.1, dropout_seed=SEED, dropout_bits=32),
     dict(block_q=64),                           # half a lane tile of rows
-], ids=["stored_mask", "32_bit_draws", "block_q_64"])
-def test_calls_the_new_body_cannot_serve_keep_the_older_one(call):
-    """... under a kernel name of its own that still begins flash_fwd,
-    so that a trace counts them (and the benchmark's flash_ms, a search
-    for flash_fwd, still sees them)."""
+    dict(block_q=8),                            # one sublane tile
+    dict(block_q=64, dropout_rate=0.1, dropout_seed=SEED),
+], ids=["block_q_64", "block_q_8", "block_q_64_dropout"])
+def test_every_forward_call_traces_the_one_body(call):
+    """There is one forward body: a q block under a lane tile, with
+    dropout or without, traces the kernel the cells run, under the name
+    the benchmark's flash_ms searches for."""
     q = jnp.zeros((1, 2, 1024, 32), jnp.float32)
-    bits = call.pop("dropout_bits", 8)
-    was = fa.dropout_bits()
-    fa.set_dropout_bits(bits)
-    try:
-        name = _forward_kernel(q, causal=True, **call)
-    finally:
-        fa.set_dropout_bits(was)
-    assert name == "flash_fwd_rows"
-    assert re.search("flash_fwd", name)
+    assert _forward_kernel(q, causal=True, **call) == "flash_fwd"
 
 
 # --------------------------------------------------------------------------- #

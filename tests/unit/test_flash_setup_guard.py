@@ -15,7 +15,8 @@ compiles and lowers to a module at most twice its plain causal one's;
 and a GPT-2 call (no window, equal head counts) still traces the three
 kernels it traced before them, body for body, so that they cost the
 GPT-2 cells no set-up.  (PR 35 replaced the forward kernel's body by a
-smaller one; the pin says which.)
+smaller one; the pin says which.  PR 49 deleted the older body: every q
+block, under a lane tile too, compiles through the one that is left.)
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported (see tests/perf/test_aot_kernels.py).
@@ -138,6 +139,21 @@ def test_grouped_causal_kernels_compile_at_the_cells_shape(one_chip):
     for lowered in _lowerings(PHI4, one_chip, True, kv_heads=10,
                               dropout=0.0):
         lowered.compile()
+
+
+@pytest.mark.parametrize("dropout", [0.0, DROPOUT])
+@pytest.mark.parametrize("block_q", [64, 8])
+def test_kernels_compile_below_a_lane_tile_of_q_rows(block_q, dropout,
+                                                     one_chip):
+    """There is one forward body, q rows along the lanes, and it takes
+    the q block it is given: half a lane tile and a single sublane tile
+    compile for the v5e, with dropout and without (PR 35's check, kept;
+    tests/tpu runs them)."""
+    lowerings = _lowerings((2, 4, 512, 64), one_chip, True, dropout=dropout,
+                           block_q=block_q, block_k=512)
+    for lowered in lowerings:
+        lowered.compile()
+    assert sorted(_module_sizes(lowerings)) == sorted(KERNELS)
 
 
 # The three kernels of a GPT-2 large / xl call: equations of each

@@ -140,8 +140,7 @@ def test_fused_matches_modular_fp16_overflow_skip():
     # layers' input carries, so the outer scan never stacks gathered
     # groups across microbatches (ISSUE 7)
     pytest.param({"stage3_max_live_parameters": 100_000,
-                  "stage3_prefetch_bucket_size": 100_000,
-                  "stage3_prefetch_mode": "carried"}, id="carried"),
+                  "stage3_prefetch_bucket_size": 100_000}, id="carried"),
 ])
 def test_fused_zero3_streaming_parity(stream_cfg):
     """Scan-in-scan: the fused program's microbatch scan wraps the ZeRO-3
@@ -189,10 +188,9 @@ def test_fused_zero3_streaming_parity(stream_cfg):
     e_fus = build(True)
     assert e_fus._fused_step_fn is not None, e_fus.fused_step_reason
     l_fus = run_fused(e_fus, batches, gas=gas)
-    if stream_cfg.get("stage3_prefetch_mode") == "carried":
-        # the plan is recorded when the fused program traces the scan
-        assert e_fus._zero3_stream.last_plan.mode == "carried"
-        assert e_fus._zero3_stream.last_plan.prefetch
+    # the plan is recorded when the fused program traces the scan
+    assert e_fus._zero3_stream.last_plan.prefetch == bool(
+        stream_cfg["stage3_prefetch_bucket_size"])
     np.testing.assert_allclose(l_mod, l_fus, rtol=2e-4)
     assert_tree_close(e_mod.params, e_fus.params, 2e-5)
 

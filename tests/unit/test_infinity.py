@@ -4,7 +4,7 @@ the host/NVMe optimizer tier, HBM never holding the full model.
 
 Reference parity targets: stage3 + offload_param (stage3.py:932 NVMe param
 swapping; partitioned_param_swapper.py:36), sub_group-wise optimizer sweep
-(stage3.py:2777), "max model per device" (BASELINE.md 40B/V100 row).
+(stage3.py:2777), "max model per device" (SURVEY.md: 40B on one V100).
 """
 
 import numpy as np

@@ -17,8 +17,8 @@ import jax
 import deepspeed_tpu as ds
 from deepspeed_tpu.config import DeepSpeedConfigError
 from deepspeed_tpu.models import GPT2Config, GPT2Model
-from deepspeed_tpu.runtime.resilience.fault_injection import (InjectedCrash,
-                                                              crash_after_bytes)
+from deepspeed_tpu.runtime.resilience.chaos import (InjectedCrash,
+                                                    crash_after_bytes)
 from deepspeed_tpu.runtime.swap_tensor import aio_handle as aio_handle_mod
 from deepspeed_tpu.runtime.swap_tensor import (NVMeOffloadOptimizer,
                                                PartitionedParamSwapper)

@@ -281,7 +281,9 @@ class TestManualTP:
             y, aux = grp.apply_with_aux(p, x, rng=None)
             return (y.astype(jnp.float32) ** 2).sum() * 1e-3 + aux
 
-        g_ref = jax.grad(loss_ref)(params)
+        # jitted: un-jitted, the region and the reference's grad dispatch
+        # op by op over the eight devices
+        l_ref, g_ref = jax.jit(jax.value_and_grad(loss_ref))(params)
 
         pv = grp.tp_manual_views(params)
         specs = grp.tp_manual_view_specs()
@@ -293,12 +295,12 @@ class TestManualTP:
                 return (y.astype(jnp.float32) ** 2).sum() * 1e-3 + aux
             return jax.value_and_grad(f)(pl)
 
-        fn = jax.shard_map(region, mesh=ctx.mesh, in_specs=(specs, P()),
-                           out_specs=(P(), specs), check_vma=False)
+        fn = jax.jit(jax.shard_map(
+            region, mesh=ctx.mesh, in_specs=(specs, P()),
+            out_specs=(P(), specs), check_vma=False))
         l_tp, g_tp_v = fn(pv, x)
         g_tp = grp.tp_manual_unview(g_tp_v)
-        np.testing.assert_allclose(float(l_tp), float(loss_ref(params)),
-                                   rtol=1e-6)
+        np.testing.assert_allclose(float(l_tp), float(l_ref), rtol=1e-6)
         for a, b in zip(jax.tree.leaves(g_ref), jax.tree.leaves(g_tp)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-4, atol=2e-5)
@@ -335,7 +337,9 @@ class TestManualTP:
             y, aux, _ = layer.apply(p, x)
             return (y.astype(jnp.float32) ** 2).mean() + 0.01 * aux
 
-        g_ref = jax.grad(loss_ref)(params)
+        # jitted: un-jitted, the region and the reference's grad dispatch
+        # op by op over the eight devices
+        l_ref, g_ref = jax.jit(jax.value_and_grad(loss_ref))(params)
 
         specs = {"gate": {"wg": P()},
                  "experts": jax.tree.map(
@@ -349,11 +353,11 @@ class TestManualTP:
                 return (y.astype(jnp.float32) ** 2).mean() + 0.01 * aux
             return jax.value_and_grad(f)(pl)
 
-        fn = jax.shard_map(region, mesh=ctx.mesh, in_specs=(specs, P()),
-                           out_specs=(P(), specs), check_vma=False)
+        fn = jax.jit(jax.shard_map(
+            region, mesh=ctx.mesh, in_specs=(specs, P()),
+            out_specs=(P(), specs), check_vma=False))
         l_tp, g_tp = fn(params, x)
-        np.testing.assert_allclose(float(l_tp), float(loss_ref(params)),
-                                   rtol=1e-6)
+        np.testing.assert_allclose(float(l_tp), float(l_ref), rtol=1e-6)
         for a, b in zip(jax.tree.leaves(g_ref), jax.tree.leaves(g_tp)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-4, atol=2e-5)

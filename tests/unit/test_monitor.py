@@ -7,7 +7,7 @@ audit regression (monitor-on adds zero hot-loop host callbacks and does
 not change the program shape), a telemetry-overhead bound, the swap-tier
 integration (ZeRO-Infinity records + swap-I/O trace spans), and the
 satellite fixes (tensorboard fallback chain, timer exception narrowing,
-fused wall_clock_breakdown window timer).
+the wall_clock_breakdown key's one warning).
 """
 
 import csv
@@ -498,28 +498,30 @@ def test_device_sync_narrowed_exceptions(monkeypatch):
     timer_mod._device_sync()  # swallowed (logged once at debug)
 
 
-def test_fused_wall_clock_breakdown_window_timer(tmp_path):
-    """Satellite: under fused_step the gas window is one dispatch, so the
-    forward/backward micro timers never run — the window-level
-    'fused_train_batch' timer must report instead of an empty
-    breakdown."""
-    from deepspeed_tpu.runtime.engine import (FORWARD_MICRO_TIMER,
-                                              FUSED_STEP_TIMER)
-    engine = _engine(tmp_path, gas=2, fused=True,
-                     extra={"wall_clock_breakdown": True,
-                            "bf16": {"enabled": True}})
-    assert engine._fused_step_fn is not None, engine.fused_step_reason
-    rng = np.random.RandomState(0)
-    ids = rng.randint(0, 64, size=(2, 16)).astype(np.int32)
-
-    def it():
-        while True:
-            yield (ids,)
-
-    engine.train_batch(it())
-    assert FUSED_STEP_TIMER in engine.timers.timers
-    assert engine.timers.timers[FUSED_STEP_TIMER].elapsed(reset=False) > 0
-    assert FORWARD_MICRO_TIMER not in engine.timers.timers
+@pytest.mark.parametrize("asked", [True, False])
+def test_wall_clock_breakdown_warns_once_and_the_spans_remain(
+        tmp_path, caplog, asked):
+    """The key stays accepted (upstream configurations carry it): `true`
+    logs ONE warning that names the ds.* spans, which are the breakdown
+    and are emitted whatever the key says."""
+    from deepspeed_tpu.utils.logging import logger as ds_logger
+    ds_logger.addHandler(caplog.handler)  # the DS logger is non-propagating
+    try:
+        engine = _engine(tmp_path, extra={"wall_clock_breakdown": asked},
+                         monitor={"writers": ["jsonl"], "trace": True})
+        _run_steps(engine, 2)
+    finally:
+        ds_logger.removeHandler(caplog.handler)
+    warned = [r.getMessage() for r in caplog.records
+              if r.getMessage().startswith("wall_clock_breakdown:")]
+    assert len(warned) == int(asked), warned
+    assert all(name in line for line in warned
+               for name in ("ds.forward", "ds.backward", "ds.step"))
+    assert not hasattr(engine, "timers")
+    engine.monitor.close()
+    names = {e["name"] for e in
+             json.load(open(engine.monitor.trace_path))["traceEvents"]}
+    assert {"ds.forward", "ds.backward", "ds.step"} <= names
 
 
 def test_inflight_tensor_write_timestamps_feed_trace(tmp_path):

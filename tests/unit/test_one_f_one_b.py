@@ -90,11 +90,6 @@ def test_1f1b_matches_gpipe_trajectory():
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
-from tests.unit.seed_xfails import (  # noqa: E402 — marker for the triaged seed failures
-    PARTITION_ID_XFAIL as _PARTITION_ID_XFAIL)
-
-
-@_PARTITION_ID_XFAIL
 def test_gated_matches_masked_trajectory():
     """The gated (lax.cond under shard_map) and masked (branch-free)
     executors run the same schedule — full-trajectory equality keeps the
@@ -196,7 +191,6 @@ def test_gated_with_tensor_parallel_guard():
     deepspeed_tpu.reset_mesh_context()
 
 
-@_PARTITION_ID_XFAIL
 def test_gated_tp_manual_default():
     """pipe×model with a manual-TP-capable body (GPT2BlockPipe) defaults
     to the GATED executor — the round-4 explicit-collective Megatron

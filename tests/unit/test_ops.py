@@ -17,7 +17,6 @@ from deepspeed_tpu.ops import (DeepSpeedTransformerConfig,
                                flash_attention, fused_layer_norm, gelu,
                                layer_norm_reference, mha_reference)
 from deepspeed_tpu.ops.flash_attention import flash_attention_pallas
-from deepspeed_tpu.ops.normalize import layer_norm_pallas
 
 
 def _qkv(b=2, h=4, s=128, d=32, dtype=jnp.float32, seed=0):
@@ -43,8 +42,7 @@ def test_interpret_mode_dropout_keeps_the_kernel_contract():
     same two seed values instead (_interpret_random_bits): the keep rate
     matches, the mask is a function of (seed, tile), and the backward
     kernels regenerate the forward's mask — everything the chip's stream
-    is relied on for, on another stream (tests/tpu checks the chip's, at
-    both PRNG widths)."""
+    is relied on for, on another stream (tests/tpu checks the chip's)."""
     from deepspeed_tpu.ops.flash_attention import flash_attention_bwd_pallas
     q, k, _v = _qkv(s=128)
     v = jnp.ones_like(q)
@@ -126,16 +124,6 @@ def test_flash_attention_bias_path():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5)
 
 
-def test_layer_norm_pallas_matches_reference():
-    x = jax.random.normal(jax.random.PRNGKey(0), (4, 96, 256))
-    gamma = jax.random.normal(jax.random.PRNGKey(1), (256,)) + 1.0
-    beta = jax.random.normal(jax.random.PRNGKey(2), (256,))
-    ref = layer_norm_reference(x, gamma, beta)
-    out = layer_norm_pallas(x, gamma, beta, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
-
-
 def test_fused_layer_norm_grad():
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
     gamma, beta = jnp.ones((64,)), jnp.zeros((64,))
@@ -145,6 +133,42 @@ def test_fused_layer_norm_grad():
         lambda x_: jnp.sum(layer_norm_reference(x_, gamma, beta) ** 2))(x)
     np.testing.assert_allclose(np.asarray(g), np.asarray(gr), rtol=1e-4,
                                atol=1e-5)
+
+
+def test_fused_layer_norm_saves_its_inputs_and_no_statistics():
+    """The rule's residuals are (x, gamma, beta): the backward recomputes
+    the forward from them.  Plain autodiff of the same lines would save
+    the row statistics and the normalized rows as well, which is another
+    grad program for every model that calls this."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (8, 64), jnp.bfloat16)
+    gamma, beta = jnp.ones((64,)), jnp.zeros((64,))
+
+    def shapes(fn):
+        _, pull = jax.vjp(lambda *a: fn(*a), x, gamma, beta)
+        return sorted(tuple(leaf.shape) for leaf in jax.tree.leaves(pull))
+
+    assert shapes(fused_layer_norm) == [(8, 64), (64,), (64,)]
+    assert (8, 1) in shapes(layer_norm_reference)   # the statistics
+
+
+@pytest.mark.parametrize("seq", [200, 8])
+def test_dropout_on_a_key_block_of_no_whole_words_takes_xla(seq,
+                                                            monkeypatch):
+    """The draw packs four key columns a PRNG word, and a key block the
+    kernels see is whole lane tiles: any other length, with dropout or
+    without, is the XLA path's (no Pallas call in the program)."""
+    from deepspeed_tpu.ops import dispatch
+    monkeypatch.setattr(dispatch, "_interpret", True)
+    monkeypatch.setenv("DS_FLASH_MIN_SEQ", "0")
+    q = jnp.zeros((1, 2, seq, 32), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda x: flash_attention(
+        x, x, x, causal=True, dropout_rate=0.1, dropout_seed=3))(q)
+    assert "pallas_call" not in str(jaxpr)
+    # the same call on whole lane tiles does take the kernels
+    q = jnp.zeros((1, 2, 256, 32), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda x: flash_attention(
+        x, x, x, causal=True, dropout_rate=0.1, dropout_seed=3))(q)
+    assert "flash_fwd" in str(jaxpr)
 
 
 def test_gelu_matches_tanh_formula():
@@ -358,81 +382,6 @@ def test_transformer_layer_bshd_layout_matches_bhsd():
     np.testing.assert_allclose(outs[0], outs[1], rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("rows,hidden", [(64, 128), (96, 256)])
-def test_layer_norm_bwd_pallas_matches_autodiff(rows, hidden):
-    """One-pass LN backward kernel vs XLA autodiff of the reference
-    (reference analog: normalize_kernels.cu backward)."""
-    from deepspeed_tpu.ops.normalize import (layer_norm_bwd_pallas,
-                                             layer_norm_reference)
-
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    x = jax.random.normal(ks[0], (rows, hidden), jnp.float32)
-    gamma = 1.0 + 0.1 * jax.random.normal(ks[1], (hidden,), jnp.float32)
-    beta = 0.1 * jax.random.normal(ks[2], (hidden,), jnp.float32)
-    dy = jax.random.normal(jax.random.PRNGKey(7), (rows, hidden),
-                           jnp.float32)
-
-    dx, dg, db = layer_norm_bwd_pallas(x, gamma, dy, eps=1e-5,
-                                       block_rows=32, interpret=True)
-    _, vjp = jax.vjp(
-        lambda x_, g_, b_: layer_norm_reference(x_, g_, b_, 1e-5),
-        x, gamma, beta)
-    rx, rg, rb = vjp(dy)
-    np.testing.assert_allclose(np.asarray(dx), np.asarray(rx), rtol=1e-5,
-                               atol=1e-5)
-    np.testing.assert_allclose(np.asarray(dg), np.asarray(rg), rtol=1e-5,
-                               atol=1e-5)
-    np.testing.assert_allclose(np.asarray(db), np.asarray(rb), rtol=1e-5,
-                               atol=1e-5)
-
-
-def test_fused_ln_bwd_dispatch_via_pallas(monkeypatch):
-    """The production gradient path of fused_layer_norm on TPU — the
-    pallas_available() branch in _fused_ln_bwd with its block guard and
-    dgamma/dbeta dtype casts — exercised here by forcing the dispatch and
-    running the kernel in interpret mode on a 3-D bf16 activation."""
-    import functools as ft
-
-    from deepspeed_tpu.ops import normalize as nm
-
-    monkeypatch.setattr("deepspeed_tpu.ops.dispatch._ln_impl", "pallas")
-    monkeypatch.setattr(
-        "deepspeed_tpu.ops.dispatch.pallas_available", lambda: True)
-    monkeypatch.setattr(
-        nm, "layer_norm_pallas",
-        ft.partial(nm.layer_norm_pallas, interpret=True))
-    monkeypatch.setattr(
-        nm, "layer_norm_bwd_pallas",
-        ft.partial(nm.layer_norm_bwd_pallas, interpret=True))
-
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 64, 128),
-                          jnp.bfloat16)
-    gamma = jnp.ones((128,), jnp.float32) * 1.05
-    beta = jnp.zeros((128,), jnp.float32) + 0.05
-    dy = jax.random.normal(jax.random.PRNGKey(1), x.shape, jnp.bfloat16)
-
-    def loss(f):
-        def inner(x_, g_, b_):
-            return jnp.vdot(f(x_, g_, b_).astype(jnp.float32),
-                            dy.astype(jnp.float32))
-        return inner
-
-    gx, gg, gb = jax.grad(
-        loss(lambda a, b, c: nm.fused_layer_norm(a, b, c, 1e-5)),
-        argnums=(0, 1, 2))(x, gamma, beta)
-    rx, rg, rb = jax.grad(
-        loss(lambda a, b, c: nm.layer_norm_reference(a, b, c, 1e-5)),
-        argnums=(0, 1, 2))(x, gamma, beta)
-    assert gx.dtype == x.dtype and gg.dtype == gamma.dtype
-    np.testing.assert_allclose(np.asarray(gx, np.float32),
-                               np.asarray(rx, np.float32),
-                               rtol=5e-2, atol=5e-2)
-    np.testing.assert_allclose(np.asarray(gg), np.asarray(rg), rtol=2e-2,
-                               atol=2e-1)
-    np.testing.assert_allclose(np.asarray(gb), np.asarray(rb), rtol=2e-2,
-                               atol=2e-1)
-
-
 def test_transformer_layer_bshd_under_tensor_parallel():
     """attn_layout='bshd' with Megatron-split qkv over the model axis:
     the head dim the BlockSpecs index is the SHARDED dim under TP, so
@@ -629,43 +578,16 @@ def test_dequantize_weight_delegates():
 
 def test_dropout_keep_scale_quantization():
     """The in-kernel dropout scale must invert the EXACT quantized keep
-    probability the kernel thresholds against — 8-bit mode quantizes the
+    probability the kernel thresholds against: the draw quantizes the
     keep probability to n/256, and using 1/(1-rate) there would bias
     E[attention output] by up to ~0.2%."""
     from deepspeed_tpu.ops.flash_attention import (_keep_scale,
-                                                   _quantized_threshold,
-                                                   _effective_dropout_bits,
-                                                   set_dropout_bits,
-                                                   dropout_bits)
-    assert abs(_keep_scale(0.1, 32) - 1 / 0.9) < 1e-6
-    assert _keep_scale(0.1, 8) == 256.0 / round(0.9 * 256)
-    assert _keep_scale(0.0, 8) == 1.0   # keep-all: no scaling
+                                                   _quantized_threshold)
+    assert _keep_scale(0.1) == 256.0 / round(0.9 * 256)
+    assert _keep_scale(0.0) == 1.0   # keep-all: no scaling
     # threshold*scale == 2^width exactly (the shared-definition invariant)
     for rate in (0.05, 0.1, 0.2, 0.5):
-        for bits in (8, 32):
-            assert (_keep_scale(rate, bits)
-                    * _quantized_threshold(rate, bits) == float(2 ** bits))
-    # non-multiple-of-4 k blocks force the 32-bit width for mask AND scale
-    from deepspeed_tpu.ops.flash_attention import _DEFAULT_DROPOUT_BITS
-    # the SHIPPED default (not the live global, which DS_DROPOUT_BITS or
-    # an earlier set_dropout_bits may have overridden)
-    assert _DEFAULT_DROPOUT_BITS == 8, \
-        "repo default is 8-bit since r4 (chip-validated A/B)"
-    prior = dropout_bits()
-    try:
-        set_dropout_bits(8)
-        assert _effective_dropout_bits(128) == 8
-        assert _effective_dropout_bits(6) == 32
-        set_dropout_bits(32)
-        assert _effective_dropout_bits(6) == 32
-        assert _effective_dropout_bits(128) == 32
-        assert dropout_bits() == 32
-    finally:
-        set_dropout_bits(prior)
-    import pytest as _pytest
-    with _pytest.raises(ValueError):
-        set_dropout_bits(16)
-    assert dropout_bits() == prior
+        assert _keep_scale(rate) * _quantized_threshold(rate) == 256.0
 
 
 def test_tp_psum_native_width_knob(monkeypatch):
@@ -695,138 +617,3 @@ def test_tp_psum_native_width_knob(monkeypatch):
     assert "bf16" not in jaxpr_of(jnp.ones((8,), jnp.float32))
 
 
-# --------------------------------------------------------------------------- #
-# Dropout mask reuse: packing layout (CPU-checkable; the kernel-level
-# reuse-vs-regen grad identity is chip-only — tests/tpu)
-# --------------------------------------------------------------------------- #
-def test_dropout_mask_pack_roundtrip():
-    from deepspeed_tpu.ops.flash_attention import (_pack_keep32,
-                                                   _unpack_keep32)
-    rng = np.random.RandomState(3)
-    for rows, cols in [(512, 1024), (256, 128), (1024, 256)]:
-        keep = jnp.asarray(rng.rand(rows, cols) < 0.8)
-        packed = _pack_keep32(keep)
-        assert packed.shape == (rows // 32, cols)
-        assert packed.dtype == jnp.uint32
-        np.testing.assert_array_equal(np.asarray(_unpack_keep32(packed)),
-                                      np.asarray(keep))
-
-
-def test_dropout_mask_pack_bit_layout():
-    """Bit j of word row r must hold keep[j*gr + r] — the fwd kernel
-    packs and BOTH bwd kernels unpack through this exact layout, so pin
-    it (a silent layout change would corrupt grads, not fail loudly)."""
-    from deepspeed_tpu.ops.flash_attention import _pack_keep32
-    rows, cols = 64, 128
-    gr = rows // 32
-    keep = np.zeros((rows, cols), bool)
-    keep[5 * gr + 1, 7] = True  # -> word row 1, bit 5, col 7
-    packed = np.asarray(_pack_keep32(jnp.asarray(keep)))
-    assert packed[1, 7] == np.uint32(1 << 5)
-    assert packed.sum() == np.uint32(1 << 5)
-
-
-def test_dropout_mask_reuse_mode_guards():
-    """save_dropout_mask demands return_lse + dropout; bwd rejects a
-    mask when the fwd/bwd modes disagree.  Every guard must name the
-    OFFENDING VALUE and the config knob that fixes it (round-5 feedback:
-    'multiple of 256' / mask_block_q failures were not actionable)."""
-    import importlib
-    fa = importlib.import_module("deepspeed_tpu.ops.flash_attention")
-    q = k = v = jnp.zeros((1, 1, 512, 64), jnp.float32)
-    with pytest.raises(ValueError, match="save_dropout_mask"):
-        fa.flash_attention_pallas(q, k, v, save_dropout_mask=True,
-                                  interpret=True)
-    # fwd 256-alignment guard: names q_len, the resolved block, and both
-    # ways out (block_q config / reuse off).  q_len=384 resolves a 384
-    # block — aligned but not packable.
-    q384 = jnp.zeros((1, 1, 384, 64), jnp.float32)
-    with pytest.raises(ValueError) as ei:
-        fa.flash_attention_pallas(q384, q384, q384, save_dropout_mask=True,
-                                  return_lse=True, dropout_rate=0.1)
-    msg = str(ei.value)
-    assert "q_len=384" in msg and "384" in msg
-    assert "block_q" in msg and "DS_DROPOUT_REUSE" in msg
-    lse = jnp.zeros((1, 1, 512), jnp.float32)
-    mask = jnp.zeros((1, 1, 16, 512), jnp.uint32)
-    # mask without dropout_rate: names the rate and the fix
-    with pytest.raises(ValueError, match=r"dropout_rate=0\.0"):
-        fa.flash_attention_bwd_pallas(q, k, v, q, lse, q, dropout_mask=mask,
-                                      interpret=True)
-    # mask at a non-packable backward block: names the value + knobs
-    lse384 = jnp.zeros((1, 1, 384), jnp.float32)
-    mask384 = jnp.zeros((1, 1, 12, 384), jnp.uint32)
-    with pytest.raises(ValueError,
-                       match=r"384.*not a multiple of 256.*DS_DROPOUT_REUSE"):
-        fa.flash_attention_bwd_pallas(
-            q384, q384, q384, q384, lse384, q384, dropout_rate=0.1,
-            dropout_mask=mask384, dropout_mask_block_q=384, interpret=True)
-    # block_q mismatch: the packed bit layout depends on the forward's
-    # resolved q block — a mismatched direct call must error, not
-    # corrupt, and the error names both blocks and the fix
-    with pytest.raises(ValueError,
-                       match=r"block_q=256.*block_q=512.*dropout_mask_block_q"):
-        fa.flash_attention_bwd_pallas(
-            q, k, v, q, lse, q, dropout_rate=0.1, dropout_mask=mask,
-            dropout_mask_block_q=256, block_q=512, interpret=True)
-
-
-def test_dropout_mask_reuse_setter():
-    import importlib
-    fa = importlib.import_module("deepspeed_tpu.ops.flash_attention")
-    prev = fa.dropout_mask_reuse()
-    try:
-        fa.set_dropout_mask_reuse(True)
-        assert fa.dropout_mask_reuse() is True
-        fa.set_dropout_mask_reuse(False)
-        assert fa.dropout_mask_reuse() is False
-    finally:
-        fa.set_dropout_mask_reuse(prev)
-    assert fa._mask_reuse_usable(512)
-    assert fa._mask_reuse_usable(256)
-    assert not fa._mask_reuse_usable(128)
-    assert not fa._mask_reuse_usable(384)
-
-
-def test_dropout_mask_reuse_bwd_interpret_matches_reference():
-    """Reuse-mode backward in interpret mode (legal: it never touches
-    the TPU PRNG): pack a KNOWN keep mask the way the fwd kernel does
-    (per-q-block tiles), run both bwd kernels with it, and compare
-    against autodiff of a reference that applies exactly that mask with
-    the kernel's quantized inverse scale.  Covers the unpack bit layout
-    AND the dropout grad math on the CPU lane."""
-    import importlib
-    fa = importlib.import_module("deepspeed_tpu.ops.flash_attention")
-    rng = np.random.RandomState(7)
-    B, H, S, D = 1, 2, 512, 32
-    bq, bk = 256, 128
-    rate = 0.2
-    q, k, v, do = (jnp.asarray(rng.standard_normal((B, H, S, D)),
-                               jnp.float32) for _ in range(4))
-    keep = rng.rand(B, H, S, S) < (1.0 - rate)
-    inv = fa._keep_scale(rate, fa._effective_dropout_bits(bk))
-    sm = 1.0 / np.sqrt(D)
-
-    def ref(q_, k_, v_):
-        s = jnp.einsum("bhqd,bhkd->bhqk", q_, k_) * sm
-        p = jax.nn.softmax(s, axis=-1)
-        a = jnp.where(jnp.asarray(keep), p * inv, 0.0)
-        return jnp.einsum("bhqk,bhkd->bhqd", a, v_)
-
-    out = ref(q, k, v)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * sm
-    lse = jax.nn.logsumexp(s, axis=-1)
-    # pack exactly as the fwd kernel does: per q-block tile, local rows
-    packed = jnp.concatenate(
-        [fa._pack_keep32(jnp.asarray(keep[b, h, i * bq:(i + 1) * bq]))
-         for b in range(B) for h in range(H) for i in range(S // bq)],
-        axis=0).reshape(B, H, S // 32, S)
-    dq, dk, dv = fa.flash_attention_bwd_pallas(
-        q, k, v, out, lse, do, block_q=bq, block_k=bk, interpret=True,
-        dropout_rate=rate, dropout_mask=packed, dropout_mask_block_q=bq)
-    gq, gk, gv = jax.grad(
-        lambda q_, k_, v_: jnp.vdot(ref(q_, k_, v_), do),
-        argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip((dq, dk, dv), (gq, gk, gv)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-4, atol=2e-4)

@@ -142,12 +142,7 @@ class TestPipelineModule:
         assert out.shape == (4, IN_DIM)
 
 
-from tests.unit.seed_xfails import (  # noqa: E402 — marker for the triaged seed failures
-    PARTITION_ID_XFAIL as _PARTITION_ID_XFAIL)
-
-
 class TestPipelineEngine:
-    @_PARTITION_ID_XFAIL
     def test_parity_with_sequential(self):
         """The pipelined program computes exactly what the sequential layer
         chain computes."""
@@ -174,7 +169,6 @@ class TestPipelineEngine:
             total += float(mse_loss(pred, ym[m]))
         assert loss_pipe == pytest.approx(total / M, rel=1e-4)
 
-    @_PARTITION_ID_XFAIL
     def test_train_batch_convergence(self):
         engine = _engine()
         x, y = make_data(256, rng_seed=2)
@@ -189,7 +183,6 @@ class TestPipelineEngine:
         loss = engine.eval_batch(_batch_iter(x, y, micro_global=4))
         assert np.isfinite(loss)
 
-    @_PARTITION_ID_XFAIL
     def test_checkpoint_roundtrip(self, tmp_path):
         engine = _engine()
         x, y = make_data(64, rng_seed=4)

@@ -224,11 +224,16 @@ def test_compressed_allreduce_int8_wire():
     x = jnp.asarray(rs.randn(w, 64), jnp.float32)
     true_mean = np.asarray(x).mean(axis=0)
 
+    # one jitted region for the 73 calls: un-jitted, every call builds and
+    # dispatches a fresh shard_map, op by op, over the eight devices
+    sync = jax.jit(lambda a, b: compressed_allreduce(a, b, mesh_ctx=mesh,
+                                                     wire="int8"))
+
     def avg_err(n):
         acc = np.zeros(64)
         e = jnp.zeros_like(x)
         for _ in range(n):
-            red, e = compressed_allreduce(x, e, mesh_ctx=mesh, wire="int8")
+            red, e = sync(x, e)
             acc += np.asarray(red)[0]
         return np.abs(acc / n - true_mean).max()
 
@@ -236,8 +241,7 @@ def test_compressed_allreduce_int8_wire():
     assert e64 < e8 / 2, (e8, e64)
     assert e64 < 0.3, e64
     # every worker sees the identical reduced tensor (psum symmetry)
-    red, _ = compressed_allreduce(x, jnp.zeros_like(x), mesh_ctx=mesh,
-                                  wire="int8")
+    red, _ = sync(x, jnp.zeros_like(x))
     red = np.asarray(red)
     np.testing.assert_array_equal(red[0], red[-1])
     reset_mesh_context()

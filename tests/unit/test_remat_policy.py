@@ -112,17 +112,14 @@ def test_kept_kernel_residuals_spare_the_second_forward(interpret, family,
 # -- (b) the same numbers ---------------------------------------------------- #
 
 @pytest.mark.parametrize("pld", [False, True])
-@pytest.mark.parametrize("mask_reuse", [False, True])
-def test_kept_residuals_change_no_number(interpret, monkeypatch, pld,
-                                         mask_reuse):
+def test_kept_residuals_change_no_number(interpret, pld):
     """Loss and every gradient leaf, dropout 0.1 and one rng: a full
     budget against a zero one, bit for bit.  What is kept is what would
     have been recomputed by the same operations on the same operands
     (the kernel is deterministic in its operands and seed), and the CPU
     backend compiles both programs from the same primitives in the same
     order, so not even an ulp is allowed."""
-    monkeypatch.setattr(fa, "_dropout_reuse", mask_reuse)
-    seq = 256  # q block 256: the packed mask is usable
+    seq = 256
     model = _gpt2(seq, dropout=0.1)
     params = model.init_params(jax.random.PRNGKey(0))
     ids = jax.random.randint(jax.random.PRNGKey(1), (2, seq), 0, 256)
@@ -482,7 +479,7 @@ def test_carried_stream_takes_no_names_policy():
         jaxpr = _grad_jaxpr(
             lambda p: model.loss(p, jax.random.PRNGKey(3), ids),
             engine.params)
-        assert engine._zero3_stream.last_plan.mode == "carried"
+        assert engine._zero3_stream.last_plan.prefetch
         ds.reset_mesh_context()
         return jaxpr
 

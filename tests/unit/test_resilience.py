@@ -2,7 +2,7 @@
 crash-mid-save recovery (both checkpoint layouts), manifest verification
 + fallback, retention GC, the preemption handler, and the
 training-health sentinel.  All deterministic via the fault-injection
-harness (runtime/resilience/fault_injection.py) — fast lane."""
+harness (runtime/resilience/chaos.py) — fast lane."""
 
 import json
 import os
@@ -14,9 +14,8 @@ import pytest
 import jax
 
 import deepspeed_tpu as ds
-from deepspeed_tpu.runtime.resilience import (atomic, fault_injection,
-                                              recovery)
-from deepspeed_tpu.runtime.resilience.fault_injection import (
+from deepspeed_tpu.runtime.resilience import atomic, recovery
+from deepspeed_tpu.runtime.resilience.chaos import (
     InjectedCrash, crash_after_bytes, measure_save_bytes, poison_batch)
 from deepspeed_tpu.runtime.resilience.preemption import TrainingInterrupted
 from deepspeed_tpu.runtime.resilience.sentinel import (SentinelAbort,

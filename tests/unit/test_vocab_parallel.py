@@ -83,11 +83,6 @@ def test_embedding_and_ce_match_replicated(tp, data):
                                atol=1e-5)
 
 
-from tests.unit.seed_xfails import (  # noqa: E402 — marker for the triaged seed failures
-    PARTITION_ID_XFAIL as _PARTITION_ID_XFAIL)
-
-
-@_PARTITION_ID_XFAIL
 def test_indivisible_vocab_declines_aux_manual():
     """A vocab the model axis can't divide must fall back to replicated
     aux chains (tp_manual_aux_supports False) while the BLOCKS still
@@ -122,7 +117,6 @@ def test_indivisible_vocab_declines_aux_manual():
     deepspeed_tpu.reset_mesh_context()
 
 
-@_PARTITION_ID_XFAIL
 def test_gated_tp_bf16_smoke():
     """bf16 gated-TP with vocab-parallel aux: the manual branches cast
     params/activations at several boundaries (qkv einsum, psum merges,
@@ -158,7 +152,6 @@ def test_gated_tp_bf16_smoke():
     deepspeed_tpu.reset_mesh_context()
 
 
-@_PARTITION_ID_XFAIL
 def test_untied_head_vocab_parallel_trajectory():
     """Untied-head GPT-2 (independent lm_head, vocab-sharded over the
     model axis through pre_s/post_s specs) under pipe=2 x tp=2 matches

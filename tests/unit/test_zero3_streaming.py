@@ -55,37 +55,24 @@ def test_plan_degenerate():
     # (same live set as one giant group, but the gathers overlap compute)
     plan = plan_layer_streaming(4, 10, 10 ** 9, 10 ** 9)
     assert plan.layers_per_step == 2 and plan.prefetch
-    # carried mode has NO even-group-count constraint: 18 layers at a
-    # 6-group budget take groups of 6 (3 groups) — the larger group size
-    # the unrolled mode was forfeiting
+    # the carried prefetch has NO even-group-count constraint: 18 layers
+    # at a 6-group budget take groups of 6 (3 groups)
     plan = plan_layer_streaming(18, 100, 1300, 100)
-    assert plan.prefetch and plan.mode == "carried"
-    assert plan.layers_per_step == 6
-    # unrolled mode keeps the even constraint (18//6 = 3 is odd -> g=3)
-    plan = plan_layer_streaming(18, 100, 1300, 100,
-                                prefetch_mode="unrolled")
-    assert not plan.prefetch or (18 // plan.layers_per_step) % 2 == 0
+    assert plan.prefetch and plan.layers_per_step == 6
 
 
-def test_plan_prefetch_modes():
-    # off: never prefetches even with room to spare
-    plan = plan_layer_streaming(8, 100, 10 ** 9, 10 ** 9,
-                                prefetch_mode="off")
-    assert not plan.prefetch and plan.mode == "off"
-    assert plan.forfeited is None  # off was requested, nothing forfeited
-    # unrolled on an odd prime layer count FORFEITS prefetch and says why
-    plan = plan_layer_streaming(7, 100, 10 ** 9, 10 ** 9,
-                                prefetch_mode="unrolled")
-    assert not plan.prefetch and plan.mode == "off"
-    assert plan.forfeited is not None and "EVEN" in plan.forfeited
-    assert "carried" in plan.forfeited  # names the fix
-    # carried handles the same shape: groups of 1, 7 carried steps
+def test_plan_prefetch_structures():
+    # a bucket of 0 never prefetches, even with room to spare
+    plan = plan_layer_streaming(8, 100, 10 ** 9, 0)
+    assert not plan.prefetch
+    assert plan.forfeited is None  # none was requested, nothing forfeited
+    # an odd prime layer count prefetches: groups of 1, 7 carried steps
     plan = plan_layer_streaming(7, 100, 10 ** 9, 10 ** 9)
-    assert plan.prefetch and plan.mode == "carried"
-    assert plan.layers_per_step == 1
-    # carried cannot form 2 groups from a single layer: forfeits loudly
+    assert plan.prefetch and plan.layers_per_step == 1
+    # a single layer cannot form 2 groups: forfeits loudly, and says why
     plan = plan_layer_streaming(1, 100, 10 ** 9, 10 ** 9)
-    assert not plan.prefetch and plan.forfeited is not None
+    assert not plan.prefetch and plan.layers_per_step == 1
+    assert plan.forfeited is not None and ">= 2 groups" in plan.forfeited
     # a bucket that asks for prefetch which max_live cannot double-buffer
     # is a forfeit too (bucket < one layer stays the silent off switch)
     plan = plan_layer_streaming(8, 100, 150, prefetch_bucket_size=100)
@@ -93,8 +80,6 @@ def test_plan_prefetch_modes():
     assert "double buffer" in plan.forfeited
     plan = plan_layer_streaming(8, 100, 150, prefetch_bucket_size=50)
     assert not plan.prefetch and plan.forfeited is None
-    with pytest.raises(ValueError, match="stage3_prefetch_mode"):
-        plan_layer_streaming(8, 100, 400, 100, prefetch_mode="eager")
 
 
 def test_body_closing_over_tracers_is_diagnosed(monkeypatch):
@@ -143,7 +128,7 @@ def test_body_closing_over_tracers_is_diagnosed(monkeypatch):
         return stream2.scan(body, x, {"w": params}, ()).sum()
 
     jax.jit(jax.grad(clean_loss))(stacked)
-    assert stream2.last_plan.mode == "carried"
+    assert stream2.last_plan.prefetch
     assert not any("closes over traced values" in m for m in logged)
     assert not _body_closes_over_tracers(lambda c, xs: (c, None))
     ds.reset_mesh_context()
@@ -369,11 +354,13 @@ def _train_tiny(zero_cfg, steps=2, seed_ids=1, **engine_kw):
     return losses, final, plan
 
 
-def _mode_cfg(mode, extra=None):
+def _mode_cfg(mode, extra=None, live=2 * 12832):
+    """A streamed stage-3 block whose structure follows the bucket:
+    "carried" asks for a prefetch bucket of the live budget, "off" for
+    none (gathers at use)."""
     cfg = {"stage": 3, "stage3_param_persistence_threshold": 0,
-           "stage3_max_live_parameters": 2 * 12832,
-           "stage3_prefetch_bucket_size": 2 * 12832,
-           "stage3_prefetch_mode": mode}
+           "stage3_max_live_parameters": live,
+           "stage3_prefetch_bucket_size": live if mode == "carried" else 0}
     cfg.update(extra or {})
     return cfg
 
@@ -382,23 +369,24 @@ def _group_cfg(mode, layers_per_step):
     """``_mode_cfg`` whose plan comes out at ``layers_per_step`` (12,832
     parameters a layer; carried halves the live budget for its double
     buffer)."""
-    live = layers_per_step * 12832 * (2 if mode == "carried" else 1)
-    return _mode_cfg(mode, {"stage3_max_live_parameters": live,
-                            "stage3_prefetch_bucket_size": live})
+    return _mode_cfg(mode, live=layers_per_step * 12832
+                     * (2 if mode == "carried" else 1))
 
 
-@pytest.mark.parametrize("mode", ["carried", "unrolled"])
+# The three parity tests below step with SGD, whose update is linear in
+# the gradient, so their tolerances bound the GRADIENTS: under Adam an
+# ulp on a gradient that is zero in exact arithmetic (the key bias's)
+# becomes 2e-6 of parameter between any two fusings of one program.
+@pytest.mark.parametrize("mode", ["carried"])
 def test_carried_mode_parity_fp32(mode):
-    """Prefetch-mode parity (ISSUE 7): the carried double-buffer program
-    and the unrolled program must train identically to the at-use
-    gather-per-group program — 5 layers, an ODD group count only the
-    carried structure can prefetch."""
-    l_off, p_off, plan_off = _train_tiny(_mode_cfg("off"))
-    assert plan_off.mode == "off" and not plan_off.prefetch
-    l_m, p_m, plan_m = _train_tiny(_mode_cfg(mode))
-    if mode == "carried":
-        assert plan_m.mode == "carried" and plan_m.prefetch
-        assert plan_m.num_layers // plan_m.layers_per_step == 5
+    """Prefetch parity (ISSUE 7): the carried double-buffer program must
+    train identically to the at-use gather-per-group program — 5 layers,
+    an ODD group count."""
+    l_off, p_off, plan_off = _train_tiny(_mode_cfg("off"), optimizer="sgd")
+    assert not plan_off.prefetch
+    l_m, p_m, plan_m = _train_tiny(_mode_cfg(mode), optimizer="sgd")
+    assert plan_m.prefetch
+    assert plan_m.num_layers // plan_m.layers_per_step == 5
     np.testing.assert_allclose(l_m, l_off, rtol=1e-6)
     for a, b in zip(jax.tree.leaves(p_m), jax.tree.leaves(p_off)):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
@@ -407,7 +395,7 @@ def test_carried_mode_parity_fp32(mode):
 def test_carried_mode_parity_bf16():
     l_off, p_off, _ = _train_tiny(_mode_cfg("off"), bf16=True)
     l_car, p_car, plan = _train_tiny(_mode_cfg("carried"), bf16=True)
-    assert plan.mode == "carried"
+    assert plan.prefetch
     # bf16 rounds differently under the two program structures (XLA
     # fuses the carried and at-use bodies differently); the tolerance
     # admits half-precision noise, nothing structural
@@ -429,9 +417,10 @@ def test_carried_low_bandwidth_parity():
     quantize identically (same blockwise layout, straight-through
     backward), so the trajectories match tightly."""
     lb = {"low_bandwidth": {"enabled": True, "qwz_bits": 8}}
-    l_off, p_off, _ = _train_tiny(_mode_cfg("off", lb))
-    l_car, p_car, plan = _train_tiny(_mode_cfg("carried", lb))
-    assert plan.mode == "carried"
+    l_off, p_off, _ = _train_tiny(_mode_cfg("off", lb), optimizer="sgd")
+    l_car, p_car, plan = _train_tiny(_mode_cfg("carried", lb),
+                                     optimizer="sgd")
+    assert plan.prefetch
     np.testing.assert_allclose(l_car, l_off, rtol=1e-6)
     for a, b in zip(jax.tree.leaves(p_car), jax.tree.leaves(p_off)):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
@@ -444,10 +433,10 @@ def test_carried_hpz_parity():
     lb = {"low_bandwidth": {"enabled": True, "hpz_group_size": 2}}
     mesh_axes = {"data": 4, "expert": 2}
     l_off, p_off, _ = _train_tiny(_mode_cfg("off", lb),
-                                  mesh_axes=mesh_axes)
+                                  mesh_axes=mesh_axes, optimizer="sgd")
     l_car, p_car, plan = _train_tiny(_mode_cfg("carried", lb),
-                                     mesh_axes=mesh_axes)
-    assert plan.mode == "carried"
+                                     mesh_axes=mesh_axes, optimizer="sgd")
+    assert plan.prefetch
     np.testing.assert_allclose(l_car, l_off, rtol=1e-6)
     for a, b in zip(jax.tree.leaves(p_car), jax.tree.leaves(p_off)):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
@@ -596,7 +585,7 @@ def test_carried_stream_runs_each_layer_forward_twice(num_layers, g,
     closed = jax.make_jaxpr(jax.grad(
         lambda p: model.loss(p, jax.random.PRNGKey(3), ids)))(engine.params)
     plan = engine._zero3_stream.last_plan
-    assert plan.mode == "carried" and plan.layers_per_step == g
+    assert plan.prefetch and plan.layers_per_step == g
     jaxpr, _ = pe.dce_jaxpr(closed.jaxpr, [True] * len(closed.jaxpr.outvars))
     assert _weighted_prim_count(jaxpr, "tanh") == 2 * num_layers
     ds.reset_mesh_context()
@@ -670,9 +659,9 @@ def test_carried_parity_with_checkpointing_and_dropout(pld):
     kw = dict(num_layers=8, checkpointing=True, dropout=0.1, pld=pld,
               optimizer="sgd")
     l_off, p_off, plan = _train_tiny(_group_cfg("off", 4), **kw)
-    assert plan.mode == "off"
+    assert not plan.prefetch
     l_car, p_car, plan = _train_tiny(_group_cfg("carried", 4), **kw)
-    assert plan.mode == "carried" and plan.layers_per_step == 4
+    assert plan.prefetch and plan.layers_per_step == 4
     np.testing.assert_allclose(l_car, l_off, rtol=1e-6)
     for a, b in zip(jax.tree.leaves(p_car), jax.tree.leaves(p_off)):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
