@@ -10,7 +10,8 @@ from .gpt2 import GPT2Config, GPT2Model
 from .bert import BertConfig, BertModel
 from .gpt_moe import GPTMoEConfig, GPTMoEModel
 from .phi4flash import Phi4FlashConfig, Phi4FlashModel
+from .keye_vl2 import KeyeVL2Config, KeyeVL2Model
 
 __all__ = ["GPT2Config", "GPT2Model", "BertConfig", "BertModel",
            "GPTMoEConfig", "GPTMoEModel", "Phi4FlashConfig",
-           "Phi4FlashModel"]
+           "Phi4FlashModel", "KeyeVL2Config", "KeyeVL2Model"]

@@ -28,6 +28,7 @@ untouched beside it.
 """
 
 import functools
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -104,14 +105,16 @@ def sort_by_expert(picks, first: int, count: int):
 
 
 def dispatch_capacity(tokens: int, k: int, count: int,
-                      num_experts: int) -> int:
+                      num_experts: int, headroom: float = 1.0) -> int:
     """Rows of a chunk of the dispatch: the ``tokens k`` picks' even
     share for ``count`` of ``num_experts`` experts, what a router that
-    favours nobody sends here, in whole tiles of the grouped product;
-    never more than the picks there are (every expert held: one chunk of
-    all of them)."""
+    favours nobody sends here, times ``headroom``, in whole tiles of the
+    grouped product; never more than the picks there are (every expert
+    held: one chunk of all of them)."""
     picks = tokens * k
     share = -(-picks * count // num_experts)
+    if headroom != 1.0:
+        share = math.ceil(share * headroom)
     return min(-(-share // TILE_ROWS) * TILE_ROWS, picks)
 
 
@@ -288,7 +291,8 @@ class DroplessMoE:
                  scale: float = 1.0,
                  experts_held: Optional[Tuple[int, int]] = None,
                  init_std: float = 0.02, selection_bias: bool = False,
-                 first_chunk_always: bool = False):
+                 first_chunk_always: bool = False,
+                 dispatch_headroom: float = 1.0):
         first, count = experts_held or (0, num_experts)
         if not (0 <= first and count >= 1 and first + count <= num_experts):
             raise ValueError(
@@ -309,6 +313,15 @@ class DroplessMoE:
         self.selection_bias = selection_bias
         # the walk's first chunk runs whatever the counts (``_walk``)
         self.first_chunk_always = first_chunk_always
+        # even shares of the picks the row buffers hold.  At 1 a router
+        # that favours nobody sits on the edge between one trip and two
+        # (the routed rows scatter about the share, and a trip costs its
+        # buffers); with room above the share an even router takes one
+        # trip every step (models/keye_vl2.py has the chip's numbers)
+        if dispatch_headroom < 1.0:
+            raise ValueError("dispatch_headroom is at least one even "
+                             f"share, got {dispatch_headroom}")
+        self.dispatch_headroom = dispatch_headroom
 
     def _check_mesh(self):
         ctx = mesh_mod.get_mesh_context(required=False)
@@ -343,9 +356,10 @@ class DroplessMoE:
 
     def capacity(self, tokens: int) -> int:
         """Rows of the layer's row buffers on ``tokens`` tokens: the held
-        experts' even share of the picks (``dispatch_capacity``)."""
+        experts' even share of the picks times ``dispatch_headroom``
+        (``dispatch_capacity``)."""
         return dispatch_capacity(tokens, self.k, self.experts_held[1],
-                                 self.num_experts)
+                                 self.num_experts, self.dispatch_headroom)
 
     def chunks_walked(self, counts, tokens: int):
         """Trips of the walk over ``counts`` [G] rows a held expert."""

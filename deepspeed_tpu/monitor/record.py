@@ -135,6 +135,10 @@ M_STACK_MTP = "stack_prediction_modules"
 # (models/ouro.py) a stack run several times on the same weights:
 # [passes, layer applications a step]
 M_STACK_PASSES = "stack_passes"
+# (models/keye_vl2.py) attention over keys a learned indexer chooses:
+# [indexer heads, their size, keys kept a query, "kernels" or "xla"
+# (ops/indexed_attention.py: which form index, select, core and align run)]
+M_STACK_INDEXER = "stack_indexer"
 
 # ---- fleet field names (fleet.py / health.py payloads) --------------- #
 FL_WINDOW_START = "window_start_step"
@@ -210,6 +214,11 @@ M_TASK_LOSS = "task_loss"
 M_EXIT_KL = "exit_kl"
 M_EXIT_STEP_MEAN = "exit_step_mean"
 M_EXIT_MASS = "exit_mass_"
+# (models/keye_vl2.py) beside M_MAIN_LOSS: the indexer's alignment term
+# summed over the layers, and the pairs the selection kept over the causal
+# pairs (min(t + 1, topk) summed over t, over S (S + 1) / 2)
+M_INDEX_LOSS = "index_loss"
+M_KEPT_SHARE = "kept_share"
 
 # ---- reconciliation field names (reconcile.py payload) --------------- #
 R_WINDOW_START = "window_start_step"

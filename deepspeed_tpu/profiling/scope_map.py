@@ -44,6 +44,13 @@ its scope (``PARTS``: the name the program writes with
          around them, or the whole XLA path; a per-head gate; the
          differential combination and its norm; the output projection
          with bias, dropout and residual.
+  qk_norm, index, select, align
+         four more parts of ``attn`` (models/keye_vl2.py): the RMSNorm
+         over every head of q and of k; the indexer's projections, norm
+         and rotation (and its scores where no kernel fuses them into
+         the select); the exact top-k over every query's causal index
+         scores; the alignment term with the indexer's gradients.  There
+         ``core`` is the attention restricted to the selected keys.
   cast   outside every scope: the engine's casts of the weights to the
          compute dtype (runtime/engine.py ``_cast_weights``): in the
          default apply program, which writes the copy that the grad
@@ -105,6 +112,10 @@ PARTS = {
     "attn_gate": ("gate", ("attn",)),
     "attn_diff": ("diff", ("attn",)),
     "attn_out": ("out", ("attn",)),
+    "attn_qk_norm": ("qk_norm", ("attn",)),
+    "attn_index": ("index", ("attn",)),
+    "attn_select": ("select", ("attn",)),
+    "attn_align": ("align", ("attn",)),
     CAST_SCOPE: ("cast", (OTHER,)),
 }
 REGIONS = ("mtp", "exit")

@@ -160,17 +160,22 @@ def get_partition_policy():
 # at B=4, S=1,024 (the attention projection's 0.38 GB bought 1.7, the QKV
 # output's 1.13 cost 2.2), with one long step in a run in three (PR 33).
 # ROADMAP S2b has what is left of the matmul outputs.
+# 3. (ops/indexed_attention.py) the alignment term's value and its
+#    gradients, then the restricted attention's (out, lse): each spares
+#    the backward pass a second run of its kernel.
 FFN_PRODUCT_NAME = "ffn_gate_up"
-RESIDUAL_ORDER = (FLASH_RESIDUALS_NAME, FFN_PRODUCT_NAME)
+RESIDUAL_ORDER = (FLASH_RESIDUALS_NAME, FFN_PRODUCT_NAME, "dsa_align",
+                  "dsa_residuals")
 # Names kept wherever a layer offers them, with a budget, with none that
 # fits and with none at all (the CPU, a streamed ZeRO-3, a device that
 # reports no limit): a few integers a token, and what a recomputation
 # could not be trusted to find again (moe/dropless.py: a top-k recomputed
 # in other fusions may flip a near tie, and the backward pass must
-# differentiate the forward's picks).  A body that offers none of them
-# lowers to what ``jax.checkpoint(body)`` lowers to
-# (tests/unit/test_remat_policy.py).
-ALWAYS_KEPT = ("routing_picks",)
+# differentiate the forward's picks; ops/indexed_attention.py: the packed
+# keep-set of a learned selection, a bit a pair, for the same reason).  A
+# body that offers none of them lowers to what ``jax.checkpoint(body)``
+# lowers to (tests/unit/test_remat_policy.py).
+ALWAYS_KEPT = ("routing_picks", "dsa_keep")
 _KEEP_ALWAYS = functools.partial(
     jax.checkpoint,
     policy=jax.checkpoint_policies.save_only_these_names(*ALWAYS_KEPT))
@@ -383,6 +388,11 @@ def stack_plan_line(plan: Dict[str, Any]) -> str:
         passes, applications = plan[R.M_STACK_PASSES]
         line += (f"; run {passes} times on the same weights: "
                  f"{applications} layer applications a step")
+    if R.M_STACK_INDEXER in plan:
+        heads, size, topk, form = plan[R.M_STACK_INDEXER]
+        line += (f"; indexer: {heads} heads of {size} on one key head, "
+                 f"{topk} keys kept a query; index, select, core and "
+                 f"align: {form}")
     if R.M_STACK_ROTARY in plan:
         line += "; rotary: " + ", ".join(
             f"{kind} {path}" + (

@@ -64,7 +64,10 @@ class _ScheduleBase:
         if last_batch_iteration is None:
             last_batch_iteration = self.last_batch_iteration + 1
         self.last_batch_iteration = last_batch_iteration
-        self._last_lr = self.get_lr()
+        # read on demand (get_last_lr): evaluated here, the schedule cost
+        # every optimizer step a round trip to the device, behind the
+        # step just dispatched, and the host could not run ahead of it
+        self._last_lr = None
 
     def state_dict(self) -> Dict[str, Any]:
         return {"last_batch_iteration": self.last_batch_iteration}

@@ -5,6 +5,8 @@ dense loop over the experts), and no array of the worst case's size in
 what it lowers to.  Float32 on the CPU, the grouped product's kernels
 through the Pallas interpreter."""
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -169,6 +171,45 @@ def test_first_chunk_always_walks_a_chunk_whatever_the_counts(
                    for g in jax.tree.leaves(got[1][0]["experts"]))
 
 
+@pytest.mark.parametrize("room, here, trips", [
+    (1.0, "most", 4), (1.5, "most", 2), (3.0, "most", 2),
+    (2.0, "a few", 1), (4.0, "all", 1)])
+def test_headroom_takes_fewer_trips_to_the_same_result(interpret, room,
+                                                       here, trips):
+    """Row buffers of ``room`` even shares: the same rows in fewer, larger
+    chunks, the result and every gradient the dense loop's."""
+    layer = DroplessMoE(HIDDEN, EXPERTS, K, FF, FF, scale=2.5,
+                        experts_held=HELD, init_std=0.1,
+                        first_chunk_always=True, dispatch_headroom=room)
+    params, x, cot = _inputs(layer, TOKENS)
+    rng = np.random.default_rng(1)
+    picks = _picks(TOKENS, {"a few": rng.integers(0, 2, TOKENS),
+                            "most": rng.integers(3, 5, TOKENS),
+                            "all": np.full(TOKENS, K)}[here])
+    # the even share is 256 rows, one tile of the grouped product
+    assert layer.capacity(TOKENS) == 256 * min(math.ceil(room), K)
+
+    def loss(f):
+        return lambda params, x: jnp.sum(f(params, x) * cot)
+
+    with jax.default_matmul_precision("highest"):
+        _, routing = layer.apply(params, x, picks)
+        got = jax.jit(jax.value_and_grad(
+            loss(lambda p, x: layer.apply(p, x, picks)[0]),
+            argnums=(0, 1)))(params, x)
+        want = jax.jit(jax.value_and_grad(
+            loss(lambda p, x: plain(layer, p, x, picks)),
+            argnums=(0, 1)))(params, x)
+    assert float(layer.stats(routing).dispatch_chunks) == trips
+    assert abs(float(got[0]) - float(want[0])) <= 2e-4 * abs(float(want[0]))
+    _close(got[1], want[1])
+
+
+def test_headroom_is_at_least_one_share():
+    with pytest.raises(ValueError, match="dispatch_headroom"):
+        DroplessMoE(HIDDEN, EXPERTS, K, FF, dispatch_headroom=0.5)
+
+
 def test_capacity_is_the_held_experts_even_share_in_whole_tiles():
     # the benchmark's cell: 32 of 256 held, 8 picks of 16,384 tokens
     assert dispatch_capacity(16384, 8, 32, 256) == 16384
@@ -177,6 +218,10 @@ def test_capacity_is_the_held_experts_even_share_in_whole_tiles():
     assert dispatch_capacity(1000, 8, 32, 256) == 1024
     assert dispatch_capacity(80, 4, 8, 16) == 256
     assert dispatch_capacity(40, 4, 8, 16) == 160
+    # with head-room: that many even shares, still in whole tiles
+    assert dispatch_capacity(16384, 8, 16, 128, 2.0) == 32768
+    assert dispatch_capacity(1000, 8, 32, 256, 1.5) == 1536
+    assert dispatch_capacity(16384, 8, 16, 128, 16.0) == 131072
     assert int(dispatch_chunks(jnp.asarray([0, 0]), 256)) == 0
     assert int(dispatch_chunks(jnp.asarray([200, 56]), 256)) == 1
     assert int(dispatch_chunks(jnp.asarray([200, 57]), 256)) == 2

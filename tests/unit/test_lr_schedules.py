@@ -74,3 +74,17 @@ def test_step_protocol_and_state_dict():
     s2 = WarmupLR(warmup_max_lr=0.01, warmup_num_steps=10)
     s2.load_state_dict(sd)
     assert s2.last_batch_iteration == 4
+
+
+def test_step_reads_nothing_from_the_device(monkeypatch):
+    """``step()`` runs once an optimizer step on the engine's hot path: it
+    counts, and the rate is evaluated when someone asks for it."""
+    s = WarmupLR(warmup_max_lr=0.01, warmup_num_steps=10)
+    calls = []
+    lr_at = s.lr_at
+    monkeypatch.setattr(s, "lr_at", lambda step: calls.append(1) or lr_at(step))
+    for _ in range(5):
+        s.step()
+    assert not calls
+    assert s.get_last_lr() == [pytest.approx(0.004)]
+    assert s.get_last_lr() == s.get_lr() and len(calls) == 3

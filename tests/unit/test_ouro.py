@@ -284,7 +284,7 @@ def test_four_passes_offer_and_keep_four_times_the_bytes(monkeypatch):
     one = _plan(model, seq=seq)
     assert four[R.M_REMAT_PASSES] == 4 and R.M_REMAT_PASSES not in one
     assert four[R.M_REMAT_LAYERS] == 8 and one[R.M_REMAT_LAYERS] == 2
-    assert one[R.M_REMAT_KEPT] == four[R.M_REMAT_KEPT] == ck.RESIDUAL_ORDER
+    assert one[R.M_REMAT_KEPT] == four[R.M_REMAT_KEPT] == ck.RESIDUAL_ORDER[:2]
     assert one[R.M_REMAT_KEPT_BYTES] > 0
     assert four[R.M_REMAT_KEPT_BYTES] == 4 * one[R.M_REMAT_KEPT_BYTES]
     assert four[R.M_REMAT_KEPT_BYTES_PER_LAYER] == one[
@@ -322,7 +322,7 @@ def test_the_cells_plan_keeps_the_flash_residuals(monkeypatch):
     assert plan[R.M_REMAT_WORKING_SET_BYTES] == (
         2 * entries + ck.MARGIN_BYTES
         + 4096 * ((32 + ck.LAYER_WIDTHS) * 2048 * 2 + 4 * 49152))
-    assert plan[R.M_REMAT_KEPT] == ck.RESIDUAL_ORDER
+    assert plan[R.M_REMAT_KEPT] == ck.RESIDUAL_ORDER[:2]
     # out bf16 [1, 16, 4096, 128], the row statistics and the gate and up
     # product bf16 [1, 4096, 2 x 5632], 32 times
     assert plan[R.M_REMAT_KEPT_BYTES_PER_LAYER] == (
