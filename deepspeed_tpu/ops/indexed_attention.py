@@ -33,10 +33,11 @@ block_k]`` at a time.
 Each piece has two forms.  On a TPU (and under ``set_pallas_interpret``)
 where ``kernels_take`` says the shape is theirs, Pallas kernels:
 ``dsa_select`` (index and select in one pass, a q block's scores held in
-VMEM as sortable keys), ``dsa_attn_fwd`` / ``dsa_attn_bwd_dq`` /
-``dsa_attn_bwd_dkdv`` (flash-style, the packed mask unpacked per tile,
-tiles beyond the diagonal skipped; a tile with no kept pair is still
-computed: with a random indexer almost none is empty) and ``dsa_align``
+VMEM as sortable keys), ``dsa_attn_fwd`` / ``dsa_attn_bwd_dkdv``
+(flash-style, the packed mask unpacked per tile, tiles beyond the
+diagonal skipped; a tile with no kept pair is still computed: with a
+random indexer almost none is empty; the backward is ONE kernel that
+builds a tile once and makes dq, dk and dv from it) and ``dsa_align``
 (value and the indexer's gradients in one pass: the loss is a scalar, so
 its backward rule only scales them).  Elsewhere blocked XLA forms of the
 same mathematics, a q block at a time under ``lax.map``; the tests hold
@@ -74,9 +75,11 @@ PACK = 32                 # queries a packed word
 # positions (my chip run, PR 50, the kernels alone): ``dsa_attn_fwd`` 44.7
 # ms at 256 x 512, 35.7 at 512 x 512, 26.7 at 512 x 1,024, 24.3 at 1,024
 # x 1,024 (a grid step costs what it costs whatever it holds, and the
-# steps beyond the diagonal are not free); the backward pair 88.6, 65.3,
-# 54.6, 51.1; ``dsa_align`` 28.4 to 31.6 at every block tried;
-# ``dsa_select`` 10.2 to 10.5 at 512 to 2,048 columns a step.
+# steps beyond the diagonal are not free); ``dsa_align`` 28.4 to 31.6 at
+# every block tried; ``dsa_select`` 10.2 to 10.5 at 512 to 2,048 columns a
+# step.  The backward (my chip run, PR 51): 35.4 ms at 1,024 x 1,024, 37.0
+# at 512 x 1,024, 37.6 at 1,024 x 512, 45.5 at 512 x 512, 38.0 at 2,048 x
+# 1,024, 36.4 at 1,024 x 2,048 (the two kernels it replaced: 51.0).
 BLOCK_Q = 256
 BLOCK_K = 512
 ATTN_BLOCK = 1024
@@ -86,13 +89,30 @@ _MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
 _VMEM_LIMIT = 100 * 1024 * 1024
 
 
+def _attn_bwd_vmem(seq, head_dim):
+    """The VMEM ``dsa_attn_bwd_dkdv`` asks for on two-byte operands, in
+    bytes and from above: what grows with the sequence (float32 dq, dk and
+    dv of ``[seq, head_dim]`` as scratch, their outputs double-buffered)
+    and a tile's share (four float32 ``[ATTN_BLOCK, ATTN_BLOCK]``
+    temporaries; q, dO, k, v, two statistics at a lane tile a row and the
+    mask's words, double-buffered).  68 MiB at 16,384 x 128, where the
+    v5e's compiler counts 58 (82 at 24,576, and 107 at 32,768, which it
+    refuses)."""
+    resident = 3 * seq * head_dim * (4 + 2 * 2)
+    tile = 4 * 4 * ATTN_BLOCK * ATTN_BLOCK + 2 * ATTN_BLOCK * (
+        4 * head_dim * 2 + 2 * 128 * 4 + ATTN_BLOCK // PACK * 4)
+    return resident + tile
+
+
 def kernels_take(seq, head_dim, index_dim):
     """Whether the Pallas kernels take this shape (whole tiles of
-    positions, heads of whole lane tiles, a TPU or the interpreter); else
-    the blocked XLA forms run."""
+    positions, heads of whole lane tiles, a sequence whose backward's
+    resident gradients fit in VMEM, a TPU or the interpreter); else the
+    blocked XLA forms run."""
     return bool((pallas_available() or pallas_interpret())
                 and seq % ATTN_BLOCK == 0 and head_dim % 128 == 0
-                and index_dim % 8 == 0)
+                and index_dim % 8 == 0
+                and _attn_bwd_vmem(seq, head_dim) <= _VMEM_LIMIT)
 
 
 def pack_block(seq):
@@ -602,58 +622,41 @@ def indexed_attention_fwd_pallas(q, k, v, packed, *, sm_scale,
     return out, lse[..., 0]
 
 
-def _tile_probs(q, k, keep, lse, sm_scale):
-    """The kept pairs' probabilities on a tile, float32 [bq, bk]."""
-    s = _nt(q, k) * sm_scale
-    return jnp.where(keep, jnp.exp(s - lse), 0.0)
+def _attn_bwd_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, lse_ref,
+                     delta_ref, dq_ref, dk_ref, dv_ref, dq_scr, dk_scr,
+                     dv_scr, *, sm_scale, block_q, block_k, sub):
+    g, j, i = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+    first_step = (j == 0) & (i == 0)
+    last_step = ((j == pl.num_programs(3) - 1)
+                 & (i == pl.num_programs(4) - 1))
 
-
-def _attn_dq_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, lse_ref,
-                    delta_ref, dq_ref, acc_scr, *, sm_scale, block_q,
-                    block_k, sub):
-    i, j = pl.program_id(2), pl.program_id(3)
-    last = _last_k(i, block_q, block_k)
-
-    @pl.when(j == 0)
+    @pl.when(first_step)
     def _():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    @pl.when(j <= last)
-    def _():
-        k = k_ref[0, 0]
-        p = _tile_probs(q_ref[0, 0], k, _unpack_tile(keep_ref[0], sub),
-                        lse_ref[0, 0][:, :1], sm_scale)
-        dp = _nt(do_ref[0, 0], v_ref[0, 0])
-        ds = p * (dp - delta_ref[0, 0][:, :1]) * sm_scale
-        acc_scr[...] += _nn(ds.astype(k.dtype), k)
-
-    @pl.when(j == last)
-    def _():
-        dq_ref[0, 0] = acc_scr[...].astype(dq_ref.dtype)
-
-
-def _attn_dkdv_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, lse_ref,
-                      delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                      sm_scale, block_q, block_k, q_blocks, sub):
-    j, g, i = pl.program_id(2), pl.program_id(3), pl.program_id(4)
-    first = _first_q(j, block_q, block_k)
-
-    @pl.when((g == 0) & (i == 0))
+    @pl.when(first_step & (g == 0))
     def _():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    @pl.when(i >= first)
+    @pl.when(i >= _first_q(j, block_q, block_k))
     def _():
-        q, do = q_ref[0, 0], do_ref[0, 0]
-        p = _tile_probs(q, k_ref[0, 0], _unpack_tile(keep_ref[0], sub),
-                        lse_ref[0, 0][:, :1], sm_scale)
-        dv_scr[...] += _tn(p.astype(do.dtype), do)
+        q, k, do = q_ref[0, 0], k_ref[0, 0], do_ref[0, 0]
+        rows, cols = pl.ds(i * block_q, block_q), pl.ds(j * block_k, block_k)
+        s = _nt(q, k) * sm_scale
+        p = jnp.where(_unpack_tile(keep_ref[0], sub),
+                      jnp.exp(s - lse_ref[0, 0][:, :1]), 0.0)
+        dv_scr[cols, :] += _tn(p.astype(do.dtype), do)
         dp = _nt(do, v_ref[0, 0])
-        ds = p * (dp - delta_ref[0, 0][:, :1]) * sm_scale
-        dk_scr[...] += _tn(ds.astype(q.dtype), q)
+        ds = (p * (dp - delta_ref[0, 0][:, :1]) * sm_scale).astype(q.dtype)
+        dk_scr[cols, :] += _tn(ds, q)
+        dq_scr[rows, :] += _nn(ds, k)
 
-    @pl.when((g == pl.num_programs(3) - 1) & (i == q_blocks - 1))
+    @pl.when(last_step)
+    def _():
+        dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
+
+    @pl.when(last_step & (g == pl.num_programs(2) - 1))
     def _():
         dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
@@ -670,69 +673,50 @@ def _wide(x):
 def indexed_attention_bwd_pallas(q, k, v, packed, out, lse, do, *, sm_scale,
                                  block_q=BLOCK_Q, block_k=BLOCK_K,
                                  pack=None, interpret=False):
-    """``dsa_attn_bwd_dq`` and ``dsa_attn_bwd_dkdv``: (dq, dk, dv)."""
+    """``dsa_attn_bwd_dkdv``: (dq, dk, dv) from one visit of each tile.  A
+    key/value head's query heads in turn, key blocks outermost: the head's
+    whole dq and the key/value head's dk and dv stay in VMEM as float32
+    (``_attn_bwd_vmem``) and leave once."""
     batch, heads, seq, dim = q.shape
     kv_heads = k.shape[1]
     group = heads // kv_heads
     sub = block_q // PACK
     block_k = min(block_k, seq)
-    nq, nk = seq // block_q, seq // block_k
     delta = _wide(jnp.sum(out.astype(jnp.float32) * do.astype(jnp.float32),
                           axis=-1))
-    lse = _wide(lse)
-
-    def last(i, j):
-        return jnp.minimum(j, _last_k(i, block_q, block_k))
-
-    q_spec = pl.BlockSpec((1, 1, block_q, dim),
-                          lambda b, h, i, j: (b, h, i, 0))
-    stat = pl.BlockSpec((1, 1, block_q, _STATS_LANES),
-                        lambda b, h, i, j: (b, h, i, 0))
-    kv = _kv_specs(block_q, block_k, dim, group)
-    dq = pl.pallas_call(
-        functools.partial(_attn_dq_kernel, sm_scale=sm_scale,
-                          block_q=block_q, block_k=block_k,
-                          sub=(pack or block_q) // PACK),
-        grid=(batch, heads, nq, nk),
-        in_specs=[q_spec, kv, kv, pl.BlockSpec(
-            (1, sub, block_k), lambda b, h, i, j: (b, i, last(i, j))),
-            q_spec, stat, stat],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, dim), jnp.float32)],
-        interpret=interpret, name="dsa_attn_bwd_dq",
-        **_compiler_params(("parallel", "parallel", "parallel", "arbitrary"),
-                           interpret),
-    )(q, k, v, packed, do, lse, delta)
 
     def first(i, j):
         return jnp.maximum(i, _first_q(j, block_q, block_k))
 
-    q_of = pl.BlockSpec((1, 1, block_q, dim), lambda b, n, j, g, i: (
+    q_of = pl.BlockSpec((1, 1, block_q, dim), lambda b, n, g, j, i: (
         b, n * group + g, first(i, j), 0))
     stat_of = pl.BlockSpec(
-        (1, 1, block_q, _STATS_LANES), lambda b, n, j, g, i: (
+        (1, 1, block_q, _STATS_LANES), lambda b, n, g, j, i: (
             b, n * group + g, first(i, j), 0))
     kv_of = pl.BlockSpec((1, 1, block_k, dim),
-                         lambda b, n, j, g, i: (b, n, j, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_attn_dkdv_kernel, sm_scale=sm_scale,
-                          block_q=block_q, block_k=block_k, q_blocks=nq,
+                         lambda b, n, g, j, i: (b, n, j, 0))
+    # the resident outputs: written at a head's (a group's) last step
+    whole_q = pl.BlockSpec((1, 1, seq, dim), lambda b, n, g, j, i: (
+        b, n * group + g, 0, 0))
+    whole_kv = pl.BlockSpec((1, 1, seq, dim),
+                            lambda b, n, g, j, i: (b, n, 0, 0))
+    return tuple(pl.pallas_call(
+        functools.partial(_attn_bwd_kernel, sm_scale=sm_scale,
+                          block_q=block_q, block_k=block_k,
                           sub=(pack or block_q) // PACK),
-        grid=(batch, kv_heads, nk, group, nq),
+        grid=(batch, kv_heads, group, seq // block_k, seq // block_q),
         in_specs=[q_of, kv_of, kv_of, pl.BlockSpec(
-            (1, sub, block_k), lambda b, n, j, g, i: (b, first(i, j), j)),
+            (1, sub, block_k), lambda b, n, g, j, i: (b, first(i, j), j)),
             q_of, stat_of, stat_of],
-        out_specs=[kv_of, kv_of],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+        out_specs=[whole_q, whole_kv, whole_kv],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, dim), jnp.float32),
-                        pltpu.VMEM((block_k, dim), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((seq, dim), jnp.float32)] * 3,
         interpret=interpret, name="dsa_attn_bwd_dkdv",
-        **_compiler_params(("parallel", "parallel", "parallel", "arbitrary",
+        **_compiler_params(("parallel", "parallel", "arbitrary", "arbitrary",
                             "arbitrary"), interpret),
-    )(q, k, v, packed, do, lse, delta)
-    return dq, dk, dv
+    )(q, k, v, packed, do, _wide(lse), delta))
 
 
 # ---------------------------------------------------------------------- #
