@@ -10,10 +10,16 @@ accumulators (flash-attention style).  The MXU sees two big matmuls per block
 pair; HBM traffic is O(S*d) instead of O(S^2).
 
 Backward is the FlashAttention-2 scheme: forward saves only the per-row
-logsumexp; two Pallas kernels recompute P block-wise and produce dk/dv
-(grid over k blocks) and dq (grid over q blocks) with no [S, S] HBM
-materialization.  The XLA reference path serves CPU and the bias/fallback
-cases.
+logsumexp; ONE Pallas kernel (_fa_bwd_kernel, `flash_bwd_dkdv` in a
+trace) recomputes P block-wise, each tile once, and makes dk, dv and dq
+from it with no [S, S] HBM materialization: key blocks outermost, q
+blocks inner, a head's whole dq resident in VMEM as float32 and written
+once a head: five products a tile.  (A second kernel that rebuilt every
+tile for dq, seven products in all, took half as long again: the call
+at [2, 20, 8192, 256] 36.2 ms on the v5e against 24.1 now, 1.91 against
+1.29 at [1, 16, 4096, 128], the gradients equal bit for bit: PERF.md
+section 6, PR 52.)  The XLA reference path serves CPU and the
+bias/fallback cases.
 
 The forward kernel (_fa_kernel) computes a step transposed: scores
 k . q^T, [keys, block_q], q rows along the LANES.  A row's running max,
@@ -26,19 +32,19 @@ that was six tenths of the kernel: 0.435 ms a call at [4, 20, 1024, 64],
 causal, dropout 0.1, on the v5e against 0.287 now (PERF.md section 6,
 PR 35).  What is left follows the score elements (scale, mask, exp, sum,
 the dropout planes and select, the cast), not the rows or the steps.
-The backward kernels carry no such state and keep q rows on the
+The backward kernel carries no such state and keeps q rows on the
 sublanes; the dropout bits are defined in that orientation
 (_dropout_keep), and the forward transposes the drawn WORDS.
 
 Inside a tile a causal call bounds its work by the diagonal (the section
 "The causal bound inside a tile" below): of the tile's 512-column
 sub-tiles, the ones wholly above the diagonal are not computed and only
-those it can cross are masked, in the forward and both backward kernels
+those it can cross are masked, in the forward and the backward kernel
 from the same dropout bits, each tile still being one step; the backward
-kernels, whose time follows the products they compute, bound each group of
+kernel, whose time follows the products it computes, bounds each group of
 256 q rows by its own last row as well.  With the shipped 512 x 1024
 blocks at S=1024 the forward kernel computes 3/4 of the S x S square and
-masks 2/4, each backward kernel 5/8 and 1/4, where all three computed
+masks 2/4, the backward kernel 5/8 and 1/4, where both computed
 and masked all of it (causal_sub_tile_shares counts it).  Non-causal
 calls, and causal ones whose key block is no multiple of the sub-tile or
 a single one, lower to the kernels as they were.
@@ -158,13 +164,13 @@ def causal_keep_mask(qi_block, ki_block, block_q, block_k):
 # spans the whole row and skipping whole blocks above the diagonal skips
 # nothing.  A causal call therefore bounds each [block_q, block_k] tile's
 # work by the diagonal, in sub-tiles of _CAUSAL_SUB_K key columns and in
-# all three kernels (_walk_tile): the sub-tiles wholly above the
+# both kernels (_walk_tile): the sub-tiles wholly above the
 # diagonal are not computed, and of the leading ones that are, only those
 # the diagonal can cross pay for the mask's iota, compare and select.  A
 # tile is still ONE step (one online-softmax update, one accumulation),
 # at a width that is static in each of block_k // _CAUSAL_SUB_K bodies
 # picked by program_id: two with the shipped blocks.  In the backward
-# kernels, where the blocks are aligned, the step is taken in groups of
+# kernel, where the blocks are aligned, the step is taken in groups of
 # _CAUSAL_SUB_Q q rows, each as wide as its own last row reaches.
 #
 # Why one step of static width and not a loop over sub-tiles (v5e, PR 31,
@@ -174,8 +180,8 @@ def causal_keep_mask(qi_block, ki_block, block_q, block_k):
 # the width (0.275 of the kernel's 0.47 ms at [4, 20, 1024, 64]), so two
 # lax.fori_loops over 512-column sub-tiles made the forward kernel 29%
 # SLOWER than no bound (three steps a head for two) where this form made
-# it 4% faster; the backward kernels carry no such state, follow the
-# products they compute, and take the same form.  PR 35 took that
+# it 4% faster; the backward kernel carries no such state, follows the
+# products it computes, and takes the same form.  PR 35 took that
 # per-step cost out of the forward (rows along the lanes: _fa_kernel) and
 # kept this form; a loop has not been tried on the new body.
 #
@@ -215,8 +221,8 @@ def _causal_sub_range(qi, ki, block_q, block_k, sub):
 
 def causal_pieces(q_len, k_len, block_q, block_k, causal=True,
                   backward=False):
-    """What a call's forward kernel (or, `backward`, each of its two
-    backward kernels) computes, as rectangles of the S x S score matrix:
+    """What a call's forward kernel (or, `backward`, its backward
+    kernel) computes, as rectangles of the S x S score matrix:
     (row0, row1, col0, col1, mask0) for rows [row0, row1) against key
     columns [col0, col1), the causal mask applied from column mask0 on
     (mask0 == col1: none).  Static, from the lengths and the blocks as
@@ -261,14 +267,13 @@ def causal_sub_tile_shares(q_len, k_len, block_q, block_k, causal):
             computed += (row1 - row0) * (col1 - col0)
             masked += (row1 - row0) * (col1 - mask0)
         return computed / (q_len * k_len), masked / (q_len * k_len)
-    return {"flash_fwd": shares(False), "flash_bwd_dkdv": shares(True),
-            "flash_bwd_dq": shares(True)}
+    return {"flash_fwd": shares(False), "flash_bwd_dkdv": shares(True)}
 
 
 # The dropout draw: one PRNG word per FOUR mask positions, compared byte
 # by byte against the quantized keep probability (1/256 granularity,
 # corrected by the exact inverse scale, _keep_scale), a quarter of the
-# PRNG words of a draw per position in each of the three kernels that
+# PRNG words of a draw per position in each of the kernels that
 # regenerate the mask (v5e, round 4: +2.7% on the flagship step).  The
 # dispatcher keeps every key block the kernels see a multiple of 128
 # columns (_use_pallas: any other length goes to XLA), so a block always
@@ -311,13 +316,13 @@ def _dropout_keep(seed_ref, b, h, qi, ki, rate, block_q, block_k,
                   num_k_blocks, interpret=False, keys_first=False):
     """Regenerable per-tile keep mask: the PRNG is reseeded from the step
     seed and the tile's ABSOLUTE coordinates, so the forward kernel and
-    both backward kernels (whose grids order (qi, ki) differently)
+    the backward kernel (whose grids order (qi, ki) differently)
     reproduce the identical mask — the TPU analog of the reference's
     philox-offset dropout (dropout_kernels.cu:868).
 
     Mosaic on current TPUs rejects prng_seed with more than 2 values, so
     the coordinates are folded exactly into two: (seed, b, h) -> value 1
-    (grid dim 1 is the head axis in all three kernels, so num_programs(1)
+    (grid dim 1 is the head axis in both kernels, so num_programs(1)
     is the head count) and (qi, ki, seed) -> value 2 via the static
     k-block count.  The seed rides in BOTH values: with value 1 alone,
     sequential per-step seeds (the natural dropout_seed=step usage) would
@@ -384,9 +389,9 @@ def _dropout_keep(seed_ref, b, h, qi, ki, rate, block_q, block_k,
 class _Band:
     """The band's geometry for blocks of block_q x block_k over nq x nk
     tiles: the first inner block of an outer block and the inner steps,
-    for the kernels that walk key blocks under a q block (`first_k`,
-    `steps_k`: forward, dq) and q blocks over a key block (`first_q`,
-    `steps_q`: dkdv).  The first-block functions take Python ints or
+    for the kernel that walks key blocks under a q block (`first_k`,
+    `steps_k`: the forward) and the one that walks q blocks over a key
+    block (`first_q`, `steps_q`: the backward).  The first-block functions take Python ints or
     traced int32 scalars."""
 
     def __init__(self, window, block_q, block_k, nq, nk):
@@ -489,10 +494,10 @@ def _prefix_parts(n, block_q, sub, band, sub_q):
     return [(None, n * sub, max(0, n - band) * sub)]
 
 
-# Rows of a q-row group of the aligned form, in the two backward kernels
-# (v5e, PR 31, kernels alone at [4, 20, 1024, 64], ms a call with groups
-# of 512 = none / 256 / 128 rows): dkdv 0.457 / 0.401 / 0.425, dq 0.304 /
-# 0.271 / 0.266; at 128 the modules pass twice the non-causal ones' size
+# Rows of a q-row group of the aligned form, in the backward kernel
+# (v5e, PR 31, when it was two, alone at [4, 20, 1024, 64], ms a call
+# with groups of 512 = none / 256 / 128 rows): dkdv 0.457 / 0.401 /
+# 0.425, dq 0.304 / 0.271 / 0.266; at 128 the modules pass twice the non-causal ones' size
 # (tests/unit/test_flash_setup_guard.py).  The forward kernel takes none:
 # 0.434 / 0.447 / 0.412 on the body of that time (rows on the sublanes,
 # its cost per row and step, not per column).  Since PR 35 its rows lie
@@ -507,7 +512,7 @@ def _causal_walk(q_len, k_len, block_q, block_k, causal, row_groups):
     the lengths and the resolved blocks: the sub-tile width, the most
     sub-tiles of one tile that the diagonal crosses (`band`), whether
     some tile lies wholly under it (`full`) and, for a kernel that takes
-    them (`row_groups`: the backward pair), the rows of a q-row group
+    them (`row_groups`: the backward), the rows of a q-row group
     where the blocks are aligned (`sub_q`, else 0).  {} where the tiles
     are walked whole (_causal_sub_tile)."""
     sub = _causal_sub_tile(block_q, block_k, causal)
@@ -745,8 +750,10 @@ def _tile_spec(rows, d, seq_of, group=1, inner=None):
 
     `group` > 1: the operand is a key or value array with one head for
     every `group` heads of the grid, found by index and not by a
-    repeated copy.  `inner` (a banded call, seq_of 'j'): maps (outer
-    block, inner step) to the block fetched (_Band.k_block / q_block).
+    repeated copy.  `inner` (a banded call): maps (outer block, inner
+    step) to the block fetched (_Band.k_block).  Both with seq_of 'j':
+    the forward's key and value tiles.  (The backward call writes its
+    own index maps: _flash_bwd_call.)
 
     (A native [B, S, heads, d] tiling — block (1, rows, 1, d) indexing
     the head dim — is Mosaic-ILLEGAL: the block's last two dims are then
@@ -756,10 +763,8 @@ def _tile_spec(rows, d, seq_of, group=1, inner=None):
     kernel boundary instead — see flash_attention_pallas.)"""
     if group > 1 or inner is not None:
         def index(b, h, i, j, *_):
-            head = h // group if group > 1 else h
-            if seq_of == "i":
-                return (b, head, i, 0)
-            return (b, head, j if inner is None else inner(i, j), 0)
+            return (b, h // group if group > 1 else h,
+                    j if inner is None else inner(i, j), 0)
         return pl.BlockSpec((1, 1, rows, d), index)
     if seq_of == "i":
         return pl.BlockSpec((1, 1, rows, d),
@@ -900,32 +905,86 @@ def _flash_fwd_call(q, k, v, dropout_seed, *, causal, sm_scale, block_q,
 
 
 # --------------------------------------------------------------------------- #
-# Pallas backward kernels (FlashAttention-2 style)
+# Pallas backward kernel (FlashAttention-2 style, one visit of each tile)
 # --------------------------------------------------------------------------- #
-def _fa_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                        delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                        causal, sm_scale, block_q, block_k, num_q_blocks,
-                        num_k_blocks, dropout_rate, interpret: bool = False,
-                        walk=None, band=None):
+# What a call gets that declares no limit, the most a call may ask of
+# the v5e's 128 MiB, and the float32 [block_q, block_k] arrays a step of
+# the backward kernel is reckoned to hold at once (scores, probabilities,
+# dO v^T, dS, the dropout bits: the v5e's compiler counts fewer).
+_VMEM_DEFAULT = 16 * 1024 * 1024
+_VMEM_LIMIT = 100 * 1024 * 1024
+_BWD_TILE_TEMPS = 5
+
+
+def _bwd_vmem(span_rows, d, block_q, block_k, itemsize):
+    """The VMEM `flash_bwd_dkdv` asks for, in bytes and from above: what
+    grows with the sequence (dq of a span of q rows: float32 scratch and
+    its output, double-buffered), a key block's share (dk and dv alike,
+    and k and v double-buffered), a q block's (q and dO, and the two row
+    statistics at a lane tile a row, double-buffered) and the step's
+    temporaries.  A head narrower than a lane tile still takes one.  On
+    two-byte operands in blocks of 512 x 1024: 15.5 MiB at 1,024 x 64
+    where the v5e's compiler counts 6.1, 22.5 at 8,192 x 128 (15.0), 34
+    at 8,192 x 256 (28.3), 82 at 32,768 x 256 (my compiles for the v5e,
+    PR 52)."""
+    lanes = -(-d // _LANES) * _LANES
+    resident = span_rows * lanes * (4 + 2 * itemsize)
+    keys = 2 * block_k * lanes * (4 + 2 * itemsize + 2 * itemsize)
+    rows = 2 * 2 * block_q * (lanes * itemsize + _LANES * 4)
+    tile = _BWD_TILE_TEMPS * 4 * block_q * block_k
+    return resident + keys + rows + tile
+
+
+def _bwd_spans(q_len, d, block_q, block_k, itemsize):
+    """Spans of q rows the backward call walks: the fewest whole shares
+    of the q blocks whose dq fits (_bwd_vmem under _VMEM_LIMIT).  One at
+    every shape a cell or a test runs; from the shape alone."""
+    nq = q_len // block_q
+    for spans in range(1, nq):
+        if nq % spans == 0 and _bwd_vmem(
+                q_len // spans, d, block_q, block_k, itemsize) <= _VMEM_LIMIT:
+            return spans
+    return nq
+
+
+def _fa_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                   delta_ref, dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
+                   *, causal, sm_scale, block_q, block_k, num_k_blocks,
+                   steps, num_spans, dropout_rate, interpret: bool = False,
+                   walk=None, band=None):
+    """The backward kernel: each tile built once (q k^T, the
+    exponentials, the mask, the dropout bits, dO v^T, dS) and dv, dk and
+    dq made from it.  Key blocks outermost, `steps` q blocks inner: dk
+    and dv of the key block are carried over the q blocks, and dq of the
+    head's whole span of q rows stays in VMEM as float32 over the key
+    blocks (added to key blocks ascending, a q row group at a time) and
+    leaves once."""
     b = pl.program_id(0)
     h = pl.program_id(1)
     ki = pl.program_id(2)
     qi = step = pl.program_id(3)
-    last_step = num_q_blocks - 1
-    window = None
-    if band is not None:   # inner step -> q block of the band
-        qi, last_step, window = (band.first_q(ki) + step, band.steps_q - 1,
-                                 band.window)
+    window = None if band is None else band.window
+    if num_spans > 1:   # the key blocks, once a span of `steps` q blocks
+        ki, qi = ki % num_k_blocks, (ki // num_k_blocks) * steps + step
+    elif band is not None:   # inner step -> q block of the band
+        qi = band.first_q(ki) + step
+    last_step = steps - 1
+    # the q block's first row in dq_scr, which holds the span's rows
+    dq_row = (qi if num_spans == 1 else step) * block_q
 
     @pl.when(step == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
+    @pl.when((ki == 0) & (step == 0))
+    def _init_dq():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
     unit = walk["sub_k"] if walk else block_k
 
     def _update(kj, n, rows, parts):
-        """This q block's part of dk and dv for the n units of `unit`
+        """This tile's part of dk, dv and dq for the n units of `unit`
         keys from unit kj on: the whole block (n 1, rows None, kj == ki)
         or sub-tiles of it, key rows `rows` of the block (the
         accumulators' rows alike); in the static pieces `parts` (q rows,
@@ -969,10 +1028,15 @@ def _fa_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             dv_scr[_at(krows)] += jax.lax.dot_general(   # p^T @ do
                 p_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)       # [width, d]
-            ds = p * (dp - delta) * sm_scale               # [bq, width] fp32
+            ds = (p * (dp - delta) * sm_scale).astype(q.dtype)  # [bq, width]
             dk_scr[_at(krows)] += jax.lax.dot_general(   # ds^T @ q
-                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                ds, q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)       # [width, d]
+            first, count = ((0, block_q) if qrows is None else
+                            (qrows.start, qrows.stop - qrows.start))
+            dq_scr[pl.ds(dq_row + first, count), :] += jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),          # ds @ k
+                preferred_element_type=jnp.float32)       # [bq, d]
 
     _walk_tile(qi, ki, block_q, block_k, causal, walk, _update, band)
 
@@ -981,68 +1045,8 @@ def _fa_bwd_dkdv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         _st(dk_ref, dk_scr[...].astype(dk_ref.dtype))
         _st(dv_ref, dv_scr[...].astype(dv_ref.dtype))
 
-
-def _fa_bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                      delta_ref, dq_ref, dq_scr, *, causal, sm_scale,
-                      block_q, block_k, num_k_blocks, dropout_rate,
-                      interpret: bool = False, walk=None, band=None):
-    b = pl.program_id(0)
-    h = pl.program_id(1)
-    qi = pl.program_id(2)
-    ki = step = pl.program_id(3)
-    last_step = num_k_blocks - 1
-    window = None
-    if band is not None:
-        ki, last_step, window = (band.first_k(qi) + step, band.steps_k - 1,
-                                 band.window)
-
-    @pl.when(step == 0)
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
-
-    unit = walk["sub_k"] if walk else block_k
-
-    def _update(kj, n, rows, parts):
-        """dq's part from the n units of `unit` keys from unit kj on:
-        the whole block (n 1, rows None, kj == ki) or sub-tiles of it,
-        key rows `rows` of the block; in the static pieces `parts` (q
-        rows, keys, first masked key)."""
-        keep = None
-        for qrows, width, mask_col in parts:
-            krows = rows if width == n * unit else slice(0, width)
-            q = _ld(q_ref, qrows)
-            k = _ld(k_ref, krows)
-            v = _ld(v_ref, krows)
-            do = _ld(do_ref, qrows)
-            lse = _ld(lse_ref, qrows)[:, :1]
-            delta = _ld(delta_ref, qrows)[:, :1]
-
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale
-            p = jnp.exp(s - lse)
-            p = _step_mask(p, qi, kj, block_q, unit, qrows, mask_col, 0.0,
-                           window)
-            dp = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            if dropout_rate > 0.0:
-                if keep is None:   # the step's bits, once
-                    keep = _step_keep(seed_ref, b, h, qi, kj, n,
-                                      dropout_rate, block_q, unit,
-                                      num_k_blocks * (block_k // unit),
-                                      interpret)
-                inv = _keep_scale(dropout_rate)
-                dp = jnp.where(_piece(keep, qrows, width), dp * inv, 0.0)
-            ds = p * (dp - delta) * sm_scale
-            dq_scr[_at(qrows)] += jax.lax.dot_general(    # ds @ k -> [bq, d]
-                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-
-    _walk_tile(qi, ki, block_q, block_k, causal, walk, _update, band)
-
-    @pl.when(step == last_step)
-    def _finalize():
+    @pl.when((ki == num_k_blocks - 1) & (step == last_step))
+    def _finalize_dq():
         _st(dq_ref, dq_scr[...].astype(dq_ref.dtype))
 
 
@@ -1075,7 +1079,15 @@ def _flash_bwd_call(q, k, v, out, lse, do, dropout_seed, *, causal,
                     sm_scale, block_q, block_k, interpret, layout,
                     dropout_rate, window=None):
     """flash_attention_bwd_pallas, traced once a process and shape (see
-    _flash_fwd_call)."""
+    _flash_fwd_call): ONE pallas_call, `flash_bwd_dkdv` (`_band` under a
+    window), for every caller.  Its grid is (batch, heads, key blocks, q
+    blocks), the q blocks of the band alone under a window.  The kernel
+    writes one dk and dv a QUERY head (its grid cell owns its output
+    block); the heads of a group are summed here, in float32.  A
+    sequence whose dq does not fit in VMEM (_bwd_spans: none that a cell
+    runs) is walked in spans of q rows, the key blocks once a span on
+    the same grid axis: dk and dv then leave as a partial a span, summed
+    with the group's."""
     batch, heads, q_len, d = _dims(q, layout)
     kv_heads, k_len = _dims(k, layout)[1:3]
     group = _kv_group(heads, kv_heads)
@@ -1107,165 +1119,82 @@ def _flash_bwd_call(q, k, v, out, lse, do, dropout_seed, *, causal,
     delta = jnp.broadcast_to(delta[..., None], stats_shape)
     lse = jnp.broadcast_to(lse[..., None], stats_shape)
 
-    params = {}
-    if not interpret:
-        params["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
-
-    # as in the forward: the three kernels agree on the sub-tiles, whose
+    # as in the forward: the kernels agree on the sub-tiles, whose
     # coordinates seed the dropout bits
     band = None if window is None else _Band(window, block_q, block_k,
                                              nq, nk)
     walk = ({} if band else
             _causal_walk(q_len, k_len, block_q, block_k, causal, True))
-    if band or group > 1:
-        return _flash_bwd_grouped(
-            q, k, v, do, lse, delta, seed, band=band, group=group,
-            layout=layout, params=params, causal=causal,
-            sm_scale=float(sm_scale), block_q=block_q, block_k=block_k,
-            dropout_rate=float(dropout_rate), interpret=interpret,
-            walk=walk)
+    spans = _bwd_spans(q_len, d, block_q, block_k, q.dtype.itemsize)
+    span_rows = q_len // spans
+    # inner steps: the q blocks of the band, or of the span
+    steps = band.steps_q if band is not None and spans == 1 else nq // spans
+    params = {}
+    if not interpret:
+        # dq is carried over the key blocks: they run in order
+        params["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=max(_VMEM_DEFAULT, _bwd_vmem(
+                span_rows, d, block_q, block_k, q.dtype.itemsize)))
 
-    # dk/dv: grid over k blocks (grid dim 2), inner loop over q blocks
-    # (grid dim 3) — _tile_spec's "i"/"j" name grid dims 2/3, so q/do tiles
-    # use "j" here
-    dkdv_kernel = functools.partial(
-        _fa_bwd_dkdv_kernel, causal=causal, sm_scale=float(sm_scale),
-        block_q=block_q, block_k=block_k, num_q_blocks=nq, num_k_blocks=nk,
-        dropout_rate=float(dropout_rate), interpret=interpret, walk=walk)
-    dkdv_in_specs = [
-        _tile_spec(block_q, d, "j"),
-        _tile_spec(block_k, d, "i"),
-        _tile_spec(block_k, d, "i"),
-        _tile_spec(block_q, d, "j"),
-        pl.BlockSpec((1, 1, block_q, _STATS_LANES),
-                     lambda b, h, j, i, *_: (b, h, i, 0)),
-        pl.BlockSpec((1, 1, block_q, _STATS_LANES),
-                     lambda b, h, j, i, *_: (b, h, i, 0)),
-    ]
-    dk, dv = pl.pallas_call(
-        dkdv_kernel,
+    # the index maps: grid dim 2 walks the key blocks (once a span), grid
+    # dim 3 the inner steps; the trailing *_ absorbs the dropout seed's ref
+    def key_block(t):
+        return t if spans == 1 else t % nk
+
+    def span_of(t):
+        return 0 if spans == 1 else t // nk
+
+    def q_block(t, j):
+        if band is not None and spans == 1:
+            return band.q_block(t, j)
+        return j if spans == 1 else span_of(t) * steps + j
+
+    def q_spec(width):
+        return pl.BlockSpec((1, 1, block_q, width), lambda b, h, t, j, *_: (
+            b, h, q_block(t, j), 0))
+
+    kv_spec = pl.BlockSpec((1, 1, block_k, d), lambda b, h, t, j, *_: (
+        b, h // group if group > 1 else h, key_block(t), 0))
+    # one dk and dv a query head, and a span: a partial's batch index is
+    # span * batch + b
+    part_spec = pl.BlockSpec((1, 1, block_k, d), lambda b, h, t, j, *_: (
+        span_of(t) * batch + b, h, key_block(t), 0))
+    # the resident output: written at the span's last step of the head
+    dq_spec = pl.BlockSpec((1, 1, span_rows, d),
+                           lambda b, h, t, j, *_: (b, h, span_of(t), 0))
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(
+            _fa_bwd_kernel, causal=causal, sm_scale=float(sm_scale),
+            block_q=block_q, block_k=block_k, num_k_blocks=nk,
+            steps=steps, num_spans=spans,
+            dropout_rate=float(dropout_rate), interpret=interpret,
+            walk=walk, band=band),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(batch, heads, nk, nq),
-            in_specs=dkdv_in_specs,
-            out_specs=[
-                _tile_spec(block_k, d, "i"),
-                _tile_spec(block_k, d, "i"),
-            ],
+            grid=(batch, heads, spans * nk, steps),
+            in_specs=[q_spec(d), kv_spec, kv_spec, q_spec(d),
+                      q_spec(_STATS_LANES), q_spec(_STATS_LANES)],
+            out_specs=[dq_spec, part_spec, part_spec],
             scratch_shapes=[
+                pltpu.VMEM((span_rows, d), jnp.float32),
                 pltpu.VMEM((block_k, d), jnp.float32),
                 pltpu.VMEM((block_k, d), jnp.float32),
             ]),
         out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((spans * batch, heads, k_len, d), k.dtype),
+            jax.ShapeDtypeStruct((spans * batch, heads, k_len, d), v.dtype),
         ],
-        interpret=interpret,
-        name="flash_bwd_dkdv",
-        **params,
-    )(seed, q, k, v, do, lse, delta)
-
-    # dq: grid over q blocks, inner loop over k blocks
-    r_spec = pl.BlockSpec((1, 1, block_q, _STATS_LANES),
-                          lambda b, h, i, j, *_: (b, h, i, 0))
-    dq_kernel = functools.partial(
-        _fa_bwd_dq_kernel, causal=causal, sm_scale=float(sm_scale),
-        block_q=block_q, block_k=block_k, num_k_blocks=nk,
-        dropout_rate=float(dropout_rate), interpret=interpret, walk=walk)
-    dq_in_specs = [
-        _tile_spec(block_q, d, "i"),
-        _tile_spec(block_k, d, "j"),
-        _tile_spec(block_k, d, "j"),
-        _tile_spec(block_q, d, "i"),
-        r_spec, r_spec,
-    ]
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(batch, heads, nq, nk),
-            in_specs=dq_in_specs,
-            out_specs=_tile_spec(block_q, d, "i"),
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-        name="flash_bwd_dq",
-        **params,
-    )(seed, q, k, v, do, lse, delta)
-
-    if layout == "bshd":
-        dq, dk, dv = _t_bhsd(dq), _t_bhsd(dk), _t_bhsd(dv)
-    return dq, dk, dv
-
-
-def _flash_bwd_grouped(q, k, v, do, lse, delta, seed, *, band, group,
-                       layout, params, **common):
-    """The two backward kernels of a call with a window or with fewer
-    key/value heads than query heads: the same
-    kernel bodies (`common`: their static arguments) on the band's grid
-    and with key/value heads found by index.  The dkdv kernel still
-    writes one dk and dv a QUERY head (its grid cell owns its output
-    block); the heads of a group are summed here, in float32."""
-    batch, heads, q_len, d = q.shape
-    kv_heads, k_len = k.shape[1:3]
-    block_q, block_k, interpret = (common[key] for key in (
-        "block_q", "block_k", "interpret"))
-    nq, nk = q_len // block_q, k_len // block_k
-    common["band"] = band
-    q_of_k = band.q_block if band else None
-    k_of_q = band.k_block if band else None
-
-    def stats(seq_of, inner):
-        return _tile_spec(block_q, _STATS_LANES, seq_of, inner=inner)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_fa_bwd_dkdv_kernel, num_q_blocks=nq,
-                          num_k_blocks=nk, **common),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(batch, heads, nk, band.steps_q if band else nq),
-            in_specs=[
-                _tile_spec(block_q, d, "j", inner=q_of_k),
-                _tile_spec(block_k, d, "i", group),
-                _tile_spec(block_k, d, "i", group),
-                _tile_spec(block_q, d, "j", inner=q_of_k),
-                stats("j", q_of_k), stats("j", q_of_k),
-            ],
-            out_specs=[_tile_spec(block_k, d, "i"),
-                       _tile_spec(block_k, d, "i")],
-            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                            pltpu.VMEM((block_k, d), jnp.float32)]),
-        out_shape=[jax.ShapeDtypeStruct((batch, heads, k_len, d), k.dtype),
-                   jax.ShapeDtypeStruct((batch, heads, k_len, d), v.dtype)],
         interpret=interpret,
         name=_kernel_name("flash_bwd_dkdv", band),
         **params,
     )(seed, q, k, v, do, lse, delta)
-    if group > 1:
+    if spans > 1 or group > 1:
         dk, dv = (t.astype(jnp.float32).reshape(
-            batch, kv_heads, group, k_len, d).sum(2).astype(t.dtype)
-            for t in (dk, dv))
-
-    kv_spec = _tile_spec(block_k, d, "j", group, k_of_q)
-    dq = pl.pallas_call(
-        functools.partial(_fa_bwd_dq_kernel, num_k_blocks=nk, **common),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(batch, heads, nq, band.steps_k if band else nk),
-            in_specs=[
-                _tile_spec(block_q, d, "i"), kv_spec, kv_spec,
-                _tile_spec(block_q, d, "i"),
-                stats("i", None), stats("i", None),
-            ],
-            out_specs=_tile_spec(block_q, d, "i"),
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-        name=_kernel_name("flash_bwd_dq", band),
-        **params,
-    )(seed, q, k, v, do, lse, delta)
+            spans, batch, kv_heads, group, k_len, d).sum((0, 3)).astype(
+                t.dtype) for t in (dk, dv))
     if layout == "bshd":
         dq, dk, dv = _t_bhsd(dq), _t_bhsd(dk), _t_bhsd(dv)
     return dq, dk, dv

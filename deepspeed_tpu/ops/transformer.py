@@ -340,7 +340,7 @@ class DeepSpeedTransformerLayer:
         #   "kernel" (default) — probability dropout INSIDE the flash
         #     kernel, the reference's semantics (dropout_kernels.cu
         #     attn-dropout on the softmax output).  Costs O(S^2) PRNG
-        #     bits regenerated in all three kernels: measured ~10% of
+        #     bits regenerated in both kernels (three then): measured ~10% of
         #     the flagship step on v5e (94.3 nodrop vs 84.7 TFLOPS).
         #   "ctx" — cheap dropout on the attention OUTPUT (O(S*d) bits,
         #     one pass).  Different regularizer than the reference's;

@@ -335,11 +335,12 @@ def test_flash_forward_below_a_lane_tile_tpu(block_q, rate):
     (2048, 512, 1024),     # two inner steps
     (1024, 256, 256),      # words of half a lane tile, transposed
 ])
-def test_flash_forward_and_backward_pair_see_one_mask_tpu(seq, block_q,
-                                                          block_k):
+def test_flash_forward_and_backward_see_one_mask_tpu(seq, block_q,
+                                                     block_k):
     """The chip's PRNG, position for position: the forward kernel (q
-    rows along the lanes: it transposes the drawn words) and the two
-    backward kernels (rows on the sublanes, as drawn) of one call hold
+    rows along the lanes: it transposes the drawn words) and the
+    backward kernel (rows on the sublanes, as drawn; through its dv and
+    through its dq) of one call hold
     the identical keep mask under the diagonal, each read back through
     one-hot operands (tests/unit/test_flash_causal_bound.py), and keep
     the share the threshold names."""
@@ -352,7 +353,8 @@ def test_flash_forward_and_backward_pair_see_one_mask_tpu(seq, block_q,
     in_dkdv, in_dq = _backward_keep_masks(heads, seq, block_q, block_k,
                                           rate, **read)
     assert abs(forward[:, under].mean() - 230 / 256) < 0.005
-    for name, mask in (("flash_bwd_dkdv", in_dkdv), ("flash_bwd_dq", in_dq)):
+    for name, mask in (("flash_bwd_dkdv's dv", in_dkdv),
+                       ("flash_bwd_dkdv's dq", in_dq)):
         np.testing.assert_array_equal(mask[:, under], forward[:, under],
                                       err_msg=name)
     assert not forward[:, ~under].any()
@@ -365,7 +367,7 @@ def test_flash_forward_and_backward_pair_see_one_mask_tpu(seq, block_q,
 ])
 def test_flash_forward_lse_tpu(seq, heads, kv_heads, window, causal):
     """The log-sum-exp the forward stores (parallel/sequence.py combines
-    shards by it; the backward pair exponentiates against it) is
+    shards by it; the backward kernel exponentiates against it) is
     m + log l to float32 rounding, through the [1, block_q] statistics
     and their one transpose."""
     from tests.unit.test_flash_causal_bound import _reference_lse

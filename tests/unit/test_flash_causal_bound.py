@@ -1,17 +1,17 @@
 """The causal bound inside a flash tile (ops/flash_attention.py
 _walk_tile): a causal call bounds each [block_q, block_k] tile's work
 by the diagonal in sub-tiles of _CAUSAL_SUB_K key columns and, in the
-backward kernels where the blocks are aligned, in groups of
+backward kernel where the blocks are aligned, in groups of
 _CAUSAL_SUB_Q rows: what lies wholly above the diagonal is not computed,
 and of the rest only what it can cross is masked, from the same dropout
-bits in all three kernels.  Everything here runs
+bits in both kernels.  Everything here runs
 through the Pallas interpreter on the CPU;
 tests/unit/test_flash_setup_guard.py compiles the same kernels for the
 v5e.
 
 With dropout on, the reference needs the kernel's own keep mask (the
 dropout stream is seeded by sub-tile coordinates): it is read back from
-the forward kernel position for position, and the forward and all three
+the forward kernel position for position, and the forward and the three
 gradients are then held to a reference that applies exactly that mask —
 so a backward kernel that drew other bits for any position fails."""
 
@@ -65,8 +65,9 @@ def _kernel_keep_mask(heads, seq, block_q, block_k, rate, interpret=True,
 
 def _backward_keep_masks(heads, seq, block_q, block_k, rate, interpret=True,
                          chunk=256, dtype=jnp.float32):
-    """([heads, seq, seq] bool) x 2: the keep masks the dkdv and the dq
-    kernel regenerate, read back like the forward's.  With q = 0 the
+    """([heads, seq, seq] bool) x 2: the keep mask the backward kernel
+    regenerates, as its dv sees it and as its dq does, read back like
+    the forward's.  With q = 0 the
     scores are 0 whatever k is, and with lse = log(i + 1) row i's
     probabilities are 1/(i+1) under the diagonal.  dv = P_dropped^T dO:
     against one-hot dO rows, dv[c, x] is positive where (r0 + x, c) was
@@ -249,7 +250,7 @@ def test_forward_with_rows_on_the_lanes_position_for_position(
     """out, the log-sum-exp and the applied keep mask of the forward
     kernel that carries q rows along the lanes, against the reference
     under the mask the dropout stream defines: the transposed kernel
-    drops exactly the positions the stream names, so the backward pair,
+    drops exactly the positions the stream names, so the backward kernel,
     which regenerates the stream untransposed, sees the same mask."""
     heads, d, block_k = 1, 32, 1024
     q, k, v, _ = _inputs(heads, seq, d, seed=seq + block_q)
@@ -274,18 +275,20 @@ def test_forward_with_rows_on_the_lanes_position_for_position(
                                rtol=2e-5, atol=2e-5)
 
 
-def test_forward_and_backward_pair_see_one_mask():
-    """One call's three kernels, each read back position for position:
-    the forward (q rows on the lanes, the words transposed) and the two
-    backward kernels (rows on the sublanes, as drawn) hold the identical
-    keep mask under the diagonal, which is the stream's."""
+def test_forward_and_backward_see_one_mask():
+    """One call's two kernels, each read back position for position:
+    the forward (q rows on the lanes, the words transposed) and the
+    backward (rows on the sublanes, as drawn; through dv and through dq)
+    hold the identical keep mask under the diagonal, which is the
+    stream's."""
     heads, seq, block_q, block_k, rate = 2, 1024, 512, 1024, 0.1
     under = np.tril(np.ones((seq, seq), bool))
     forward = _kernel_keep_mask(heads, seq, block_q, block_k, rate)
     in_dkdv, in_dq = _backward_keep_masks(heads, seq, block_q, block_k, rate)
     stream = _stream_keep_mask(heads, seq, block_q, block_k, rate)
-    for name, mask in (("flash_fwd", forward), ("flash_bwd_dkdv", in_dkdv),
-                       ("flash_bwd_dq", in_dq)):
+    for name, mask in (("flash_fwd", forward),
+                       ("flash_bwd_dkdv's dv", in_dkdv),
+                       ("flash_bwd_dkdv's dq", in_dq)):
         np.testing.assert_array_equal(mask[:, under], stream[:, under],
                                       err_msg=name)
         assert not mask[:, ~under].any(), name
@@ -320,7 +323,7 @@ def _painted(seq, block_q, block_k, backward):
     return computed, unmasked
 
 
-# (seq, block_q, block_k, forward's shares, each backward kernel's)
+# (seq, block_q, block_k, forward's shares, the backward kernel's)
 SHARES = [
     # the shipped blocks: one tile on the diagonal at 512, walked whole
     (512, 512, 1024, (1.0, 1.0), (1.0, 1.0)),
@@ -346,10 +349,11 @@ def test_computed_and_masked_shares_against_a_count_over_positions(
     are the painted areas."""
     got = fa.causal_sub_tile_shares(seq, seq, block_q, block_k, True)
     assert got["flash_fwd"] == pytest.approx(forward)
-    assert got["flash_bwd_dkdv"] == got["flash_bwd_dq"]
-    assert got["flash_bwd_dq"] == pytest.approx(backward)
+    assert sorted(got) == ["flash_bwd_dkdv", "flash_fwd"]
+    assert got["flash_bwd_dkdv"] == pytest.approx(backward)
     under = np.tril(np.ones((seq, seq), bool))
-    for kernel, is_backward in (("flash_fwd", False), ("flash_bwd_dq", True)):
+    for kernel, is_backward in (("flash_fwd", False),
+                                ("flash_bwd_dkdv", True)):
         computed, unmasked = _painted(seq, block_q, block_k, is_backward)
         assert computed[under].all()
         assert not unmasked[~under].any()
@@ -389,7 +393,7 @@ WHOLE_TILE_CALLS = {
 
 
 def whole_tile_digests(name):
-    """sha256 of the three kernels' Mosaic modules for one call of
+    """sha256 of the two kernels' Mosaic modules for one call of
     WHOLE_TILE_CALLS at [4, 20, S, 64], lowered for the TPU from here."""
     kw = dict(WHOLE_TILE_CALLS[name])
     shape = (4, 20, kw.pop("seq", 1024), 64)
@@ -410,23 +414,25 @@ def whole_tile_digests(name):
         text = jax.jit(f).trace(*args).lower(
             lowering_platforms=("tpu",)).as_text()
         modules += _mosaic_modules(text)
-    assert len(modules) == 3
+    assert len(modules) == 2
     return [hashlib.sha256(m.encode()).hexdigest() for m in modules]
 
 
 @pytest.mark.parametrize("name", sorted(WHOLE_TILE_CALLS))
 def test_whole_tile_calls_lower_as_before_the_bound(name):
     """A non-causal call, and a causal call whose key block the sub-tile
-    does not divide, give the Mosaic modules the kernels gave before the
-    bound existed: golden/flash_whole_tile_modules.json was recorded by
-    this same function from the commit before it (9885b32).
+    does not divide, give the Mosaic modules the kernels gave before a
+    change that was not meant for them:
+    golden/flash_whole_tile_modules.json is recorded by this same
+    function, first from the commit before the bound existed (9885b32).
 
-    That holds for the backward pair (entries 1 and 2, untouched since).
     The forward kernel (entry 0) was rewritten by PR 35, q rows along the
     lanes, for these calls as for every other, and its entries were
-    recorded again from that PR's tree: what they guard now is that a
-    later change to the causal bound or to the backward kernels leaves
-    the forward of a whole-tile call alone."""
+    recorded again from that PR's tree; PR 52 made the backward ONE
+    kernel for every call (entry 1, recorded from that PR's tree, where
+    the pair's two entries were) and left entry 0 as it found it.  What
+    they guard now is that a later change to the causal bound leaves a
+    whole-tile call alone."""
     with open(GOLDEN) as f:
         recorded = json.load(f)
     assert whole_tile_digests(name) == recorded[name]

@@ -1,8 +1,8 @@
 """The flash kernels at a head of 256 for q, k and v (latent attention:
-models/glm4_moe_lite.py): forward and both backward kernels against
+models/glm4_moe_lite.py): forward and the backward kernel against
 ``mha_reference`` in the Pallas interpreter, causal, at the blocks the
 chip plan uses (512 x 1024, the defaults, fitted to the length); and the
-three kernels compiled ahead of time for the v5e at the cell's own shape
+two kernels compiled ahead of time for the v5e at the cell's own shape
 [2, 20, 8192, 256], where the TPU's compiler can be described (a compile
 is not a run).  The topology is described inside a fixture, never while
 a module is imported."""
@@ -48,8 +48,9 @@ def test_head_256_matches_the_reference(interpreted, seq, blocks):
                                         sm_scale=scale) * g)
 
     text = str(jax.make_jaxpr(jax.grad(ours, (0, 1, 2)))(q, k, v))
-    for kernel in ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq"):
+    for kernel in ("flash_fwd", "flash_bwd_dkdv"):
         assert kernel in text
+    assert "flash_bwd_dq" not in text
     got = jax.value_and_grad(ours, (0, 1, 2))(q, k, v)
     ref = jax.value_and_grad(want, (0, 1, 2))(q, k, v)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
@@ -85,9 +86,10 @@ def one_chip():
 
 
 def test_head_256_kernels_compile_for_v5e_at_the_cells_shape(one_chip):
-    """Forward with its residuals and both backward kernels at the
-    default blocks: the VMEM plan holds at 256 (1024 x 1024 does not:
-    ``flash_bwd_dkdv`` runs out of VMEM there)."""
+    """Forward with its residuals and the backward kernel at the
+    default blocks: the VMEM plan holds at 256, the head's 8 MB of dq
+    resident as float32 with its output beside it, under the limit the
+    call reckons for itself (``_bwd_vmem``)."""
     operands = [jax.ShapeDtypeStruct(CELL, jnp.bfloat16, sharding=one_chip)
                 for _ in range(5)]
     lse = jax.ShapeDtypeStruct(CELL[:3], jnp.float32, sharding=one_chip)
@@ -105,5 +107,5 @@ def test_head_256_kernels_compile_for_v5e_at_the_cells_shape(one_chip):
     assert "flash_fwd" in text
     q, k, v, out, do = operands
     text = jax.jit(bwd).lower(q, k, v, out, lse, do).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 2
-    assert "flash_bwd_dkdv" in text and "flash_bwd_dq" in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "flash_bwd_dkdv" in text and "flash_bwd_dq" not in text

@@ -12,11 +12,13 @@ width, and no more.
 The window and the grouped key/value heads (PR 34) are held to the same
 measure: each banded kernel, at the shapes of `phi4-mini-flash.s8k`,
 compiles and lowers to a module at most twice its plain causal one's;
-and a GPT-2 call (no window, equal head counts) still traces the three
+and a GPT-2 call (no window, equal head counts) still traces the
 kernels it traced before them, body for body, so that they cost the
 GPT-2 cells no set-up.  (PR 35 replaced the forward kernel's body by a
 smaller one; the pin says which.  PR 49 deleted the older body: every q
-block, under a lane tile too, compiles through the one that is left.)
+block, under a lane tile too, compiles through the one that is left.
+PR 52 made the backward ONE kernel: the pin says what its body grew by
+and what went.)
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported (see tests/perf/test_aot_kernels.py).
@@ -29,7 +31,7 @@ import pytest
 # [B, H, S, D] of one chip's call in gpt2-large.s1024 and gpt2-xl.z3x4
 SHAPES = [(4, 20, 1024, 64), (4, 25, 1024, 64)]
 DROPOUT = 0.1
-KERNELS = ("flash_fwd", "flash_bwd_dkdv", "flash_bwd_dq")
+KERNELS = ("flash_fwd", "flash_bwd_dkdv")
 
 
 @pytest.fixture(scope="module")
@@ -59,7 +61,7 @@ def one_chip(topo):
 
 def _lowerings(shape, one_chip, causal, kv_heads=None, dropout=DROPOUT,
                **call):
-    """The forward call's and the backward pair's lowerings for the v5e;
+    """The forward call's and the backward call's lowerings for the v5e;
     `kv_heads` fewer key/value heads than `shape` has query heads, `call`
     a window and its blocks."""
     import jax
@@ -156,20 +158,19 @@ def test_kernels_compile_below_a_lane_tile_of_q_rows(block_q, dropout,
     assert sorted(_module_sizes(lowerings)) == sorted(KERNELS)
 
 
-# The three kernels of a GPT-2 large / xl call: equations of each
+# The two kernels of a GPT-2 large / xl call: equations of each
 # kernel's body, sub-jaxprs in, and the grid's two sequence dimensions.
 # (A lowered module's bytes carry its source locations and the caller's
 # stack, so they move with any edit of the file; the traced body does
-# not.)  The backward pair's counts are those of the parent of PR 34
-# (commit 0275698), which added the window and the grouped heads beside
-# them, and PR 35 left them: it rewrote the forward kernel alone (q rows
-# along the lanes: 207 equations for the 246 of the body before it, the
-# smaller body at every start), and the forward's count is that PR's.
-# What it guards now: that a GPT-2 call takes the new body, under the
-# name flash_fwd, in one step and two bodies, and that nothing grows it
-# unseen.
-PARENT_KERNELS = {"flash_fwd": (207, (2, 1)), "flash_bwd_dkdv": (342, (1, 2)),
-                  "flash_bwd_dq": (297, (2, 1))}
+# not.)  The forward's count is PR 35's (q rows along the lanes: 207
+# equations for the 246 of the body before it).  The backward's is PR
+# 52's: one body of 378 equations where the pair traced 342
+# (flash_bwd_dkdv, the count of the parent of PR 34, commit 0275698) and
+# 297 (flash_bwd_dq): dq's product and its slice of the resident scratch
+# a row group and body, its zeroing and its store.  What it guards now:
+# that a GPT-2 call takes these bodies, under these names, the forward
+# in one step and two bodies, and that nothing grows them unseen.
+PARENT_KERNELS = {"flash_fwd": (207, (2, 1)), "flash_bwd_dkdv": (378, (1, 2))}
 
 
 def _equations(jaxpr):

@@ -96,12 +96,12 @@ def _program(batch=8, layers=2, kernels=chip_smoke.FLASH_KERNELS):
 def test_reader_counts_mosaic_calls_by_kernel():
     calls = chip_smoke.mosaic_flash_calls(_program())
     assert {k: len(v) for k, v in calls.items()} == {
-        "flash_fwd": 2, "flash_bwd_dkdv": 2, "flash_bwd_dq": 2}
+        "flash_fwd": 2, "flash_bwd_dkdv": 2}
     assert calls["flash_fwd"][0] == [(1,), (8, 12, 1024, 64),
                                      (8, 12, 1024, 64), (8, 12, 1024, 64)]
     counts = chip_smoke.check_flash_calls(calls, _CFG, _SIZE,
                                           streamed=False)
-    assert sum(counts.values()) == 3 * _CFG.num_layers
+    assert sum(counts.values()) == 2 * _CFG.num_layers
 
 
 def test_missing_kernel_fails_the_check():
@@ -111,7 +111,7 @@ def test_missing_kernel_fails_the_check():
     with pytest.raises(AssertionError, match="expected 2 calls"):
         chip_smoke.check_flash_calls(calls, _CFG, _SIZE, streamed=False)
     calls = chip_smoke.mosaic_flash_calls(
-        _program(kernels=("flash_fwd", "flash_bwd_dq")))
+        _program(kernels=("flash_fwd",)))
     with pytest.raises(AssertionError, match="flash kernels missing"):
         chip_smoke.check_flash_calls(calls, _CFG, _SIZE, streamed=True)
 
