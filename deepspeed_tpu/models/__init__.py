@@ -11,7 +11,9 @@ from .bert import BertConfig, BertModel
 from .gpt_moe import GPTMoEConfig, GPTMoEModel
 from .phi4flash import Phi4FlashConfig, Phi4FlashModel
 from .keye_vl2 import KeyeVL2Config, KeyeVL2Model
+from .granite_hybrid import GraniteHybridConfig, GraniteHybridModel
 
 __all__ = ["GPT2Config", "GPT2Model", "BertConfig", "BertModel",
            "GPTMoEConfig", "GPTMoEModel", "Phi4FlashConfig",
-           "Phi4FlashModel", "KeyeVL2Config", "KeyeVL2Model"]
+           "Phi4FlashModel", "KeyeVL2Config", "KeyeVL2Model",
+           "GraniteHybridConfig", "GraniteHybridModel"]

@@ -139,6 +139,11 @@ M_STACK_PASSES = "stack_passes"
 # [indexer heads, their size, keys kept a query, "kernels" or "xla"
 # (ops/indexed_attention.py: which form index, select, core and align run)]
 M_STACK_INDEXER = "stack_indexer"
+# (models/granite_hybrid.py) Mamba-2 mixers on ops/ssd_scan.py: ["kernel"
+# or "xla", positions a chunk, bytes of chunk-entry states a layer's scan
+# saves, the runs of like layers ("mamba x5, attention, mamba x4"),
+# "scanned" or "unrolled"]
+M_STACK_SSD = "stack_ssd"
 
 # ---- fleet field names (fleet.py / health.py payloads) --------------- #
 FL_WINDOW_START = "window_start_step"

@@ -1,6 +1,10 @@
 """The selective scan of a Mamba-1 mixer (Gu & Dao 2023, section 3.2;
 the reference's selective_scan_fn), forward and hand-written backward
-under one custom_vjp.
+under one custom_vjp.  This is the scan of models/phi4flash.py: a decay
+for every (channel, state) pair, walked position by position.  The
+Mamba-2 mixer of models/granite_hybrid.py (one decay a head, B and C
+shared by the heads) runs ops/ssd_scan.py, the same recurrence's chunked
+matrix form on the MXU; neither op calls the other.
 
 Per batch row, channel c and state n, over positions t:
 

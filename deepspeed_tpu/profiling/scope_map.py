@@ -9,7 +9,9 @@ rematted_computation/mlp/dot_general" ...}`` on every instruction.
 
   scope  the innermost of the model's named scopes on the ``op_name``:
          ``embed``, ``attn``, ``ssm`` (a Mamba mixer: projections, conv,
-         selective scan, gate), ``gmu`` (a gated-memory unit), ``mlp``,
+         scan, gate; the Mamba-1 mixer of models/phi4flash.py on
+         ops/selective_scan.py and the Mamba-2 mixer of
+         models/granite_hybrid.py on ops/ssd_scan.py alike), ``gmu`` (a gated-memory unit), ``mlp``,
          the four of a sparse FFN (moe/dropless.py): ``router`` (scores,
          top-k, counts), ``dispatch`` (the sorts, the gather of rows and
          the weighted gather back), ``experts`` (the grouped products
@@ -51,6 +53,17 @@ its scope (``PARTS``: the name the program writes with
          the select); the exact top-k over every query's causal index
          scores; the alignment term with the indexer's gradients.  There
          ``core`` is the attention restricted to the selected keys.
+  in, conv, scan, gate, out
+         the parts of ``ssm`` that a Mamba-2 mixer names
+         (models/granite_hybrid.py; the Mamba-1 mixer of
+         models/phi4flash.py names none and reads as one lump): the input
+         projections and their split; the depthwise causal conv, its
+         bias and silu; the softplus of the steps, the chunked scan
+         (ops/ssd_scan.py: its kernels and what its wrapper puts around
+         them); the gated RMSNorm; the output projection.  ``gate`` and
+         ``out`` are also parts of ``attn`` under other names on the
+         path (``attn_gate``, ``attn_out``): a name counts only under
+         its own scope.
   cast   outside every scope: the engine's casts of the weights to the
          compute dtype (runtime/engine.py ``_cast_weights``): in the
          default apply program, which writes the copy that the grad
@@ -116,6 +129,11 @@ PARTS = {
     "attn_index": ("index", ("attn",)),
     "attn_select": ("select", ("attn",)),
     "attn_align": ("align", ("attn",)),
+    "ssm_in": ("in", ("ssm",)),
+    "ssm_conv": ("conv", ("ssm",)),
+    "ssm_scan": ("scan", ("ssm",)),
+    "ssm_gate": ("gate", ("ssm",)),
+    "ssm_out": ("out", ("ssm",)),
     CAST_SCOPE: ("cast", (OTHER,)),
 }
 REGIONS = ("mtp", "exit")

@@ -399,6 +399,11 @@ def stack_plan_line(plan: Dict[str, Any]) -> str:
                 f" (blocks of {block[0]} positions x {block[1]} heads)"
                 if block else "")
             for kind, path, *block in plan[R.M_STACK_ROTARY])
+    if R.M_STACK_SSD in plan:
+        form, chunk, entry_bytes, runs, mode = plan[R.M_STACK_SSD]
+        line += (f"; runs of like layers: {runs}, {mode}; state-space "
+                 f"duality scan: {form} in chunks of {chunk}, "
+                 f"{entry_bytes:,} B of chunk-entry states a layer")
     return line
 
 

@@ -1,0 +1,227 @@
+"""ops/ssd_scan.py: the chunked matrix form of Mamba-2's scan, both forms
+(the plain XLA one and the Pallas kernels through the interpreter),
+against the recurrence walked position by position: values and all six
+gradients, decays near 0 and near 1, a sequence that is no multiple of
+the chunk, what is refused at trace time, the bytes of the saved
+chunk-entry states, and the kernels compiled ahead of time for the v5e
+at the benchmark cell's shapes where the TPU's compiler can be described
+(a compile is not a run).  The topology is described inside a fixture,
+never while a module is imported."""
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.analysis.jaxpr_walk import iter_eqns
+from deepspeed_tpu.ops import ssd_scan as ssd
+from deepspeed_tpu.ops.dispatch import set_pallas_interpret
+
+NAMES = ("x", "dt", "a", "b", "c", "d")
+
+
+def recurrence(x, dt, a, b, c, d):
+    """The module's first two equations, one position at a time."""
+    def row(x, dt, b, c):
+        def step(state, at):
+            x_t, dt_t, b_t, c_t = at
+            state = (jnp.exp(dt_t * a)[:, None, None] * state
+                     + (dt_t[:, None] * x_t)[:, :, None]
+                     * b_t[0][None, None, :])
+            return state, (jnp.sum(state * c_t[0][None, None, :], axis=-1)
+                           + d[:, None] * x_t)
+        zero = jnp.zeros(x.shape[1:] + b.shape[-1:], jnp.float32)
+        return jax.lax.scan(step, zero, (x, dt, b, c))[1]
+    return jax.vmap(row)(x, dt, b, c)
+
+
+def operands(batch, seq, heads, dim, states, seed=0, dt_shift=-2.0,
+             dt_scale=1.0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 7)
+    args = (jax.random.normal(k[0], (batch, seq, heads, dim)),
+            dt_scale * jax.nn.softplus(
+                jax.random.normal(k[1], (batch, seq, heads)) + dt_shift),
+            -jnp.exp(jax.random.uniform(k[2], (heads,), minval=0.0,
+                                        maxval=2.7)),
+            0.3 * jax.random.normal(k[3], (batch, seq, 1, states)),
+            0.3 * jax.random.normal(k[4], (batch, seq, 1, states)),
+            jax.random.normal(k[5], (heads,)))
+    return args, jax.random.normal(k[6], (batch, seq, heads, dim))
+
+
+def compare(args, weight, chunk, tol):
+    """The op's y and six gradients against the recurrence's, each by
+    its norm."""
+    y, want = ssd.ssd_scan(*args, chunk=chunk), recurrence(*args)
+    assert y.shape == want.shape and y.dtype == args[0].dtype
+    assert bool(jnp.isfinite(y).all())
+    assert float(jnp.linalg.norm(y - want) / jnp.linalg.norm(want)) < tol
+    ours = jax.grad(lambda *a: jnp.sum(ssd.ssd_scan(*a, chunk=chunk)
+                                       * weight), argnums=range(6))(*args)
+    theirs = jax.grad(lambda *a: jnp.sum(recurrence(*a) * weight),
+                      argnums=range(6))(*args)
+    for name, g, r in zip(NAMES, ours, theirs):
+        assert g.shape == r.shape and bool(jnp.isfinite(g).all()), name
+        err = float(jnp.linalg.norm(g - r) / jnp.linalg.norm(r))
+        assert err < tol, (name, err)
+
+
+@pytest.fixture
+def interpreter():
+    set_pallas_interpret(True)
+    yield
+    set_pallas_interpret(False)
+
+
+@pytest.mark.parametrize("seq", [96, 80])
+def test_the_xla_form_is_the_recurrence(seq):
+    """Whole chunks, and a sequence padded to them (80 = 2.5 chunks)."""
+    args, weight = operands(2, seq, 4, 8, 16)
+    assert not ssd.uses_kernels(4, 8, 16, 32)
+    compare(args, weight, 32, 1e-4)
+
+
+def test_the_kernels_are_the_recurrence(interpreter):
+    """Two chunks of 128, one block of eight heads of 64: the carried
+    state, the entry states and their cotangents all in play.  bf16
+    operands into float32 sums: a few parts in a thousand."""
+    args, weight = operands(1, 256, 8, 64, 128)
+    assert ssd.uses_kernels(8, 64, 128, 128)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(
+        ssd.ssd_scan(*a, chunk=128) * weight), argnums=range(6)))(*args)
+    kernels = sorted({ctx.eqn.params["name"] for ctx in iter_eqns(jaxpr)
+                      if ctx.eqn.primitive.name == "pallas_call"})
+    assert kernels == ["ssd_bwd", "ssd_fwd"]
+    assert all(k.startswith("ssd_") for k in kernels)
+    compare(args, weight, 128, 2e-2)
+
+
+def test_the_kernels_take_a_padded_sequence_and_two_blocks(interpreter):
+    """200 positions in chunks of 128 (the second chunk 72 real
+    positions), sixteen heads (two blocks of eight: C B^T and its
+    cotangent are shared across the blocks), two rows."""
+    args, weight = operands(2, 200, 16, 64, 128, seed=3)
+    compare(args, weight, 128, 2e-2)
+
+
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+@pytest.mark.parametrize("case", ["near_one", "near_zero"])
+def test_decays_near_one_and_near_zero(form, case, request):
+    """Steps of 1e-4 (a decay of 0.999 and more a position: the state
+    carries across every chunk) and steps of 30 (a decay under 1e-13: a
+    position forgets everything before it, and exp(s_i - s_j) underflows
+    for every pair but the diagonal); nothing overflows, nothing is
+    NaN."""
+    shift, scale = {"near_one": (-9.0, 1.0), "near_zero": (3.0, 10.0)}[case]
+    if form == "kernel":
+        request.getfixturevalue("interpreter")
+        args, weight = operands(1, 256, 8, 64, 128, seed=5, dt_shift=shift,
+                                dt_scale=scale)
+        compare(args, weight, 128, 2e-2)
+    else:
+        args, weight = operands(1, 64, 4, 8, 16, seed=5, dt_shift=shift,
+                                dt_scale=scale)
+        compare(args, weight, 16, 2e-4)
+
+
+def test_what_is_refused_is_refused_with_a_message():
+    (x, dt, a, b, c, d), _ = operands(1, 32, 4, 8, 16)
+    two = jnp.concatenate([b, b], axis=2)
+    with pytest.raises(NotImplementedError, match="2 groups"):
+        ssd.ssd_scan(x, dt, a, two, two, d, chunk=16)
+    with pytest.raises(ValueError, match="must be"):
+        ssd.ssd_scan(x.reshape(1, 32, 32), dt, a, b, c, d)
+    with pytest.raises(ValueError, match="must be"):
+        ssd.ssd_scan(x, dt[:, :16], a, b, c, d)
+    # shapes the kernels are not written for take the XLA form, on any
+    # backend: heads of 32, six heads, 64 states, a chunk of 96
+    assert ssd.kernels_take(64, 64, 128, 256)
+    for shape in ((64, 32, 128, 256), (6, 64, 128, 256), (64, 64, 64, 256),
+                  (64, 64, 128, 96)):
+        assert not ssd.kernels_take(*shape), shape
+
+
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+def test_what_the_forward_saves_is_the_chunk_entry_states(form, request):
+    """At the benchmark cell's shapes, by shapes alone: sixteen entry
+    states a row, 33,554,432 B, and nothing of a position's state's size
+    nor a decay matrix for every head and chunk anywhere in the
+    program."""
+    if form == "kernel":
+        request.getfixturevalue("interpreter")
+    batch, seq, heads, dim, states, chunk = 1, 4096, 64, 64, 128, 256
+    assert ssd.entry_state_bytes(batch, seq, heads, dim, states,
+                                 chunk) == 33_554_432
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    shapes = (jax.ShapeDtypeStruct((batch, seq, heads, dim), bf16),
+              jax.ShapeDtypeStruct((batch, seq, heads), f32),
+              jax.ShapeDtypeStruct((heads,), f32),
+              jax.ShapeDtypeStruct((batch, seq, states), bf16),
+              jax.ShapeDtypeStruct((batch, seq, states), bf16),
+              jax.ShapeDtypeStruct((heads,), f32))
+    y, saved = jax.eval_shape(
+        lambda *a: ssd._scan_fwd(*a, chunk), *shapes)
+    assert y.shape == shapes[0].shape and y.dtype == bf16
+    entries = saved[-1]
+    assert entries.shape == (batch, seq // chunk, heads, dim, states)
+    assert entries.dtype == f32
+    assert entries.size * 4 == 33_554_432
+    # the other residuals are the operands themselves
+    assert [s.shape for s in saved[:-1]] == [s.shape for s in shapes]
+    if form == "kernel":
+        jaxpr = jax.make_jaxpr(lambda *a: jax.vjp(
+            lambda *b: ssd._scan(*b, chunk), *a)[1](a[0]))(*(
+                jnp.zeros(s.shape, s.dtype) for s in shapes))
+        largest = max(v.aval.size for ctx in iter_eqns(jaxpr)
+                      for v in ctx.eqn.outvars if hasattr(v.aval, "size"))
+        # x's own 16.8M elements are the largest array; a decay matrix a
+        # head and chunk would be 67M, every position's state 2,147M
+        assert largest <= batch * seq * heads * dim, largest
+
+
+# --------------------------------------------------------------------------- #
+# the kernels, compiled for the chip
+# --------------------------------------------------------------------------- #
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # whatever the plugin raises where it cannot
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # such a compile is written to the persistent cache and cannot be
+    # read back without a chip; keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def test_the_kernels_compile_for_v5e_at_the_cells_shapes(one_chip):
+    """[1, 4096, 64, 64], N 128, chunks of 256: the chip's compiler takes
+    both calls, each ONE Mosaic call named ``ssd_*``."""
+    batch, seq, heads, dim, states, chunk = 1, 4096, 64, 64, 128, 256
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    x = on_chip((batch, seq, heads, dim), bf16)
+    per_head = on_chip((batch, seq, heads), f32)
+    narrow = on_chip((batch, seq, states), bf16)
+    entries = on_chip((batch, seq // chunk, heads, dim, states), f32)
+    for name, fn, args in (
+            ("ssd_fwd", ssd._pallas_fwd.__wrapped__,
+             (x, per_head, per_head, narrow, narrow, on_chip((heads,), f32))),
+            ("ssd_bwd", ssd._pallas_bwd.__wrapped__,
+             (x, per_head, per_head, narrow, narrow, entries, x))):
+        text = jax.jit(lambda *a, fn=fn: fn(
+            *a, chunk=chunk, interpret=False)).lower(*args).compile(
+            ).as_text()
+        assert text.count('custom_call_target="tpu_custom_call"') == 1, name
+        assert f"{name}/pallas_call" in text
