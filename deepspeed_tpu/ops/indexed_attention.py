@@ -36,13 +36,17 @@ where ``kernels_take`` says the shape is theirs, Pallas kernels:
 VMEM as sortable keys), ``dsa_attn_fwd`` / ``dsa_attn_bwd_dkdv``
 (flash-style, the packed mask unpacked per tile, tiles beyond the
 diagonal skipped; a tile with no kept pair is still computed: with a
-random indexer almost none is empty; the backward is ONE kernel that
-builds a tile once and makes dq, dk and dv from it) and ``dsa_align``
-(value and the indexer's gradients in one pass: the loss is a scalar, so
-its backward rule only scales them).  Elsewhere blocked XLA forms of the
-same mathematics, a q block at a time under ``lax.map``; the tests hold
-the kernels to them.  ``ops/flash_attention.py`` knows nothing of this
-file and is not touched by it.
+random indexer almost none is empty; a forward step is a tile of a
+KEY/VALUE head and serves that head's group of query heads, as many as
+``_attn_fwd_heads`` says fit: the keep-set knows no head, so the key,
+value and mask tiles arrive and the mask is unpacked once for them, and
+the heads go through the step's stages four abreast; the backward is ONE
+kernel that builds a tile once and makes dq, dk and dv from it) and
+``dsa_align`` (value and the indexer's gradients in one pass: the loss is
+a scalar, so its backward rule only scales them).  Elsewhere blocked XLA
+forms of the same mathematics, a q block at a time under ``lax.map``; the
+tests hold the kernels to them.  ``ops/flash_attention.py`` knows nothing
+of this file and is not touched by it.
 """
 
 import functools
@@ -72,14 +76,24 @@ PACK = 32                 # queries a packed word
 # BLOCK_K columns at a time) and, by BLOCK_K keys, ``dsa_align``'s tile,
 # which holds every head's q block.  The restricted attention's tiles are
 # ATTN_BLOCK square, several packed blocks tall.  On the v5e at 16,384
-# positions (my chip run, PR 50, the kernels alone): ``dsa_attn_fwd`` 44.7
-# ms at 256 x 512, 35.7 at 512 x 512, 26.7 at 512 x 1,024, 24.3 at 1,024
-# x 1,024 (a grid step costs what it costs whatever it holds, and the
-# steps beyond the diagonal are not free); ``dsa_align`` 28.4 to 31.6 at
+# positions (my chip run, PR 50, the kernels alone): ``dsa_attn_fwd``, then
+# a step a QUERY head, 44.7 ms at 256 x 512, 35.7 at 512 x 512, 26.7 at
+# 512 x 1,024, 24.3 at 1,024 x 1,024 (a grid step costs what it costs
+# whatever it holds, and the steps beyond the diagonal are not free; less
+# 0.35 us a step that is 21 to 24 ms at every tile: the time followed the
+# elements).  A step a key/value head's group of 8 at 1,024 x 1,024 (PR
+# 54's builder's chip runs, the parent's 24.0 beside each; the first
+# three read again to 0.05 ms by my chip run, PR 55): 17.9 ms head by
+# head, whether the mask is added, selected on a stored boolean or
+# compared and selected, and 17.3 with no mask at all; 16.3 with the heads
+# two abreast stage by stage and 15.6 four abreast (16.7 and 16.6 with the
+# stages skewed, 17.7 and 17.2 with only the first products ahead, 18.2
+# with the loop unrolled whole).  ``dsa_align`` (PR 50): 28.4 to 31.6 at
 # every block tried; ``dsa_select`` 10.2 to 10.5 at 512 to 2,048 columns a
-# step.  The backward (my chip run, PR 51): 35.4 ms at 1,024 x 1,024, 37.0
-# at 512 x 1,024, 37.6 at 1,024 x 512, 45.5 at 512 x 512, 38.0 at 2,048 x
-# 1,024, 36.4 at 1,024 x 2,048 (the two kernels it replaced: 51.0).
+# step.
+# The backward (my chip run, PR 51): 35.4 ms at 1,024 x 1,024, 37.0 at 512
+# x 1,024, 37.6 at 1,024 x 512, 45.5 at 512 x 512, 38.0 at 2,048 x 1,024,
+# 36.4 at 1,024 x 2,048 (the two kernels it replaced: 51.0).
 BLOCK_Q = 256
 BLOCK_K = 512
 ATTN_BLOCK = 1024
@@ -541,11 +555,18 @@ def _first_q(j, block_q, block_k):
     return (j * block_k) // block_q
 
 
+def _abreast(heads):
+    """How many of a step's heads go through its stages side by side."""
+    return next(n for n in (4, 2, 1) if heads % n == 0)
+
+
 def _attn_fwd_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, lse_ref,
-                     m_scr, l_scr, acc_scr, *, sm_scale, block_q, block_k,
-                     sub):
+                     m_scr, l_scr, acc_scr, bias_scr, *, sm_scale, block_q,
+                     block_k, sub):
     i, j = pl.program_id(2), pl.program_id(3)
     last = _last_k(i, block_q, block_k)
+    heads = q_ref.shape[1]      # the query heads this step serves
+    abreast = _abreast(heads)
 
     @pl.when(j == 0)
     def _():
@@ -555,32 +576,72 @@ def _attn_fwd_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, lse_ref,
 
     @pl.when(j <= last)
     def _():
-        keep = _unpack_tile(keep_ref[0], sub)
-        s = _nt(q_ref[0, 0], k_ref[0, 0]) * sm_scale
-        s = jnp.where(keep, s, _MASKED)
-        m_prev = m_scr[...]
-        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_next)
-        p = jnp.where(keep, jnp.exp(s - m_next), 0.0)
-        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0, 0]
-        acc_scr[...] = alpha * acc_scr[...] + _nn(p.astype(v.dtype), v)
-        m_scr[...] = m_next
+        # the tile's own, once for every head: the mask as what a score
+        # takes on (a score is nothing beside _MASKED, so the sum IS
+        # _MASKED).  A masked pair's exponential against a real row
+        # maximum is 0.0 exactly; a row that has met no kept key yet
+        # gathers ones, and ``alpha`` = 0.0 wipes them at the tile where
+        # it meets one (every causal row keeps a key)
+        bias_scr[...] = jnp.where(_unpack_tile(keep_ref[0], sub), 0.0,
+                                  _MASKED)
+
+        def some(n, carry):
+            # stage by stage across the heads, not head by head: the
+            # compiler keeps the order it is given, and so one head's
+            # products run under another's vector work
+            hs = [n * abreast + x for x in range(abreast)]
+            s = [_nt(q_ref[0, h], k_ref[0, 0]) * sm_scale + bias_scr[...]
+                 for h in hs]
+            m_prev = [m_scr[h] for h in hs]
+            m_next = [jnp.maximum(m, jnp.max(x, axis=1, keepdims=True))
+                      for m, x in zip(m_prev, s)]
+            p = [jnp.exp(x - m) for x, m in zip(s, m_next)]
+            v = v_ref[0, 0]
+            for h, was, now, p_h in zip(hs, m_prev, m_next, p):
+                alpha = jnp.exp(was - now)
+                l_scr[h] = alpha * l_scr[h] + jnp.sum(p_h, axis=1,
+                                                      keepdims=True)
+                acc_scr[h] = alpha * acc_scr[h] + _nn(p_h.astype(v.dtype), v)
+                m_scr[h] = now
+            return carry
+
+        lax.fori_loop(0, heads // abreast, some, 0)
 
     @pl.when(j == last)
     def _():
-        denom = l_scr[...]
-        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
-        lse_ref[0, 0] = _stats(m_scr[...] + jnp.log(denom), block_q)
+        def head(h, carry):
+            denom = l_scr[h]
+            o_ref[0, h] = (acc_scr[h] / denom).astype(o_ref.dtype)
+            lse_ref[0, h] = _stats(m_scr[h] + jnp.log(denom), block_q)
+            return carry
+
+        lax.fori_loop(0, heads, head, 0)
 
 
-def _kv_specs(block_q, block_k, dim, group):
-    """The key/value tile of step (b, h, i, j): the head's key/value head,
-    the key block clamped to the last one the q block needs (a skipped
-    step then asks for the block it already has)."""
-    return pl.BlockSpec(
-        (1, 1, block_k, dim), lambda b, h, i, j: (
-            b, h // group, jnp.minimum(j, _last_k(i, block_q, block_k)), 0))
+def _attn_fwd_vmem(heads, block_q, block_k, head_dim):
+    """The VMEM ``dsa_attn_fwd`` asks for with ``heads`` query heads a
+    step on two-byte operands, in bytes and from above: a head's share (q
+    and out double-buffered, the float32 accumulator, and the log-sum-exp
+    block double-buffered, the running maximum and the running sum at a
+    lane tile a row) and the tile's (the mask as float32, a float32
+    ``[block_q, block_k]`` for each head abreast and one more; k, v and
+    the mask's words, double-buffered).  53 MiB at 8 heads of 128 on
+    1,024 x 1,024, where the v5e's compiler counts 52 (81 and 80 at 16
+    heads)."""
+    lanes = -(-head_dim // 128) * 128
+    head = block_q * (2 * 2 * lanes * 2 + lanes * 4 + 4 * 128 * 4)
+    tile = (2 + _abreast(heads)) * 4 * block_q * block_k + 2 * block_k * (
+        2 * lanes * 2 + block_q // PACK * 4)
+    return heads * head + tile
+
+
+def _attn_fwd_heads(group, block_q, block_k, head_dim):
+    """The query heads a step of ``dsa_attn_fwd`` serves: a key/value
+    head's whole group where ``_attn_fwd_vmem`` is under the limit, else
+    the group's largest divisor that is."""
+    return next(n for n in range(group, 0, -1) if group % n == 0 and (
+        n == 1 or _attn_fwd_vmem(n, block_q, block_k, head_dim)
+        <= _VMEM_LIMIT))
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -590,31 +651,42 @@ def indexed_attention_fwd_pallas(q, k, v, packed, *, sm_scale,
                                  pack=None, interpret=False):
     """``dsa_attn_fwd``: q [B, H, S, D], k, v [B, KV, S, D], packed keep
     (in blocks of ``pack`` queries, ``block_q`` a multiple of it; None:
-    ``block_q``) -> (out [B, H, S, D], lse float32 [B, H, S])."""
+    ``block_q``) -> (out [B, H, S, D], lse float32 [B, H, S]).  A grid
+    step is a tile of a key/value head and serves ``_attn_fwd_heads`` of
+    its query heads: the key, value and mask tiles arrive once for them."""
     batch, heads, seq, dim = q.shape
     group = heads // k.shape[1]
     sub = block_q // PACK
     block_k = min(block_k, seq)
-    kv = _kv_specs(block_q, block_k, dim, group)
-    q_spec = pl.BlockSpec((1, 1, block_q, dim),
-                          lambda b, h, i, j: (b, h, i, 0))
+    step = _attn_fwd_heads(group, block_q, block_k, dim)
+
+    def key_block(i, j):
+        # clamped to the last one the q block needs: a skipped step asks
+        # for the block it already has
+        return jnp.minimum(j, _last_k(i, block_q, block_k))
+
+    kv = pl.BlockSpec((1, 1, block_k, dim), lambda b, n, i, j: (
+        b, n * step // group, key_block(i, j), 0))
+    q_spec = pl.BlockSpec((1, step, block_q, dim),
+                          lambda b, n, i, j: (b, n, i, 0))
     out, lse = pl.pallas_call(
         functools.partial(_attn_fwd_kernel, sm_scale=sm_scale,
                           block_q=block_q, block_k=block_k,
                           sub=(pack or block_q) // PACK),
-        grid=(batch, heads, seq // block_q, seq // block_k),
+        grid=(batch, heads // step, seq // block_q, seq // block_k),
         in_specs=[q_spec, kv, kv, pl.BlockSpec(
-            (1, sub, block_k), lambda b, h, i, j: (
-                b, i, jnp.minimum(j, _last_k(i, block_q, block_k))))],
+            (1, sub, block_k), lambda b, n, i, j: (b, i, key_block(i, j)))],
         out_specs=[q_spec, pl.BlockSpec(
-            (1, 1, block_q, _STATS_LANES), lambda b, h, i, j: (b, h, i, 0))],
+            (1, step, block_q, _STATS_LANES),
+            lambda b, n, i, j: (b, n, i, 0))],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((batch, heads, seq, _STATS_LANES),
                                  jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32),
-                        pltpu.VMEM((block_q, 1), jnp.float32),
-                        pltpu.VMEM((block_q, dim), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((step, block_q, 1), jnp.float32),
+                        pltpu.VMEM((step, block_q, 1), jnp.float32),
+                        pltpu.VMEM((step, block_q, dim), jnp.float32),
+                        pltpu.VMEM((block_q, block_k), jnp.float32)],
         interpret=interpret, name="dsa_attn_fwd",
         **_compiler_params(("parallel", "parallel", "parallel", "arbitrary"),
                            interpret),
