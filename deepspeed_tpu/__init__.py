@@ -17,6 +17,11 @@ from .utils import logger, log_dist
 from .utils.distributed import init_distributed
 from . import moe
 from .runtime import zero  # deepspeed.zero.Init / GatheredParameters parity
+from .monitor import trace as _trace
+
+# the compile record starts here: every program JAX is asked to compile
+# from now on, whoever asks (monitor/trace.py)
+_trace.install()
 
 
 def initialize(args=None, model=None, config=None, config_params=None,
@@ -30,6 +35,24 @@ def initialize(args=None, model=None, config=None, config_params=None,
     `model` is a flax module or an apply-style callable; see
     deepspeed_tpu.runtime.engine for details.
     """
+    with _trace.span("initialize") as init:
+        init.phase("config")
+        engine = _initialize(args, model, config, config_params, optimizer,
+                             model_parameters, lr_scheduler, mesh,
+                             collate_fn, training_data, mpu, rng,
+                             example_input, param_partition_specs)
+    marks = getattr(engine, "trace_marks", None)
+    if marks is not None:
+        marks["initialize_ns"] = _trace.last_span()[1:3]
+    return (engine, engine.optimizer, engine.training_dataloader,
+            engine.lr_scheduler)
+
+
+def _initialize(args, model, config, config_params, optimizer,
+                model_parameters, lr_scheduler, mesh, collate_fn,
+                training_data, mpu, rng, example_input,
+                param_partition_specs):
+    """The engine ``initialize`` returns, under its ``ds.initialize``."""
     from .runtime.engine import DeepSpeedEngine
     from .runtime.pipe.module import PipelineModule
 
@@ -63,8 +86,7 @@ def initialize(args=None, model=None, config=None, config_params=None,
             optimizer=optimizer, lr_scheduler=lr_scheduler, mesh=mesh,
             rng=rng, mpu=mpu, training_data=training_data,
             collate_fn=collate_fn)
-        return (engine, engine.optimizer, engine.training_dataloader,
-                engine.lr_scheduler)
+        return engine
 
     if isinstance(model, PipelineModule):
         from .runtime.pipe.engine import PipelineEngine
@@ -85,7 +107,7 @@ def initialize(args=None, model=None, config=None, config_params=None,
                                  training_data=training_data,
                                  collate_fn=collate_fn, rng=rng,
                                  param_partition_specs=param_partition_specs)
-    return engine, engine.optimizer, engine.training_dataloader, engine.lr_scheduler
+    return engine
 
 
 def init_inference(model, mp_size=1, mesh=None, checkpoint=None, dtype=None,

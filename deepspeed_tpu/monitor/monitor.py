@@ -16,8 +16,10 @@ file I/O runs on the WriterThread — a slow disk never blocks a step.
 """
 
 import atexit
+import functools
 import os
 import time
+import weakref
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
@@ -29,7 +31,8 @@ from .fleet import FleetAggregator, format_fleet_line
 from .health import FleetHealth, format_health_line
 from .heartbeat import HEARTBEAT_DIR, HeartbeatWriter
 from .reconcile import Bands, format_line, reconcile_window
-from .trace import TraceEventBuffer
+from . import trace as T
+from .trace import TraceEventBuffer, span
 from .writers import (CsvWriter, JsonlWriter, MetricsWriter,
                       TensorBoardWriter, WriterThread)
 
@@ -59,6 +62,29 @@ def _batched_loss_fetch(refs):
         except Exception:  # noqa: BLE001
             out.append(None)
     return out
+
+
+def _close_if_alive(monitor_ref) -> None:
+    """The hook at interpreter exit.  It writes the last window's records
+    and prints none of their lines: the program has printed its last
+    line by then, and a harness that reads it (perf/run.py's result) must
+    find it last."""
+    monitor = monitor_ref()
+    if monitor is not None:
+        monitor._at_exit_close = True
+        monitor.close()
+
+
+def _flush_span(flush):
+    """``ds.monitor.flush`` around a flush that has records to write:
+    the boundary, the one place the monitor reads the device (the
+    batched fetch of the window)."""
+    @functools.wraps(flush)
+    def spanned(self, final: bool = True) -> None:
+        if self._pending:
+            with span("monitor.flush", window=len(self._pending)):
+                flush(self, final)
+    return spanned
 
 
 class MetricsStream:
@@ -185,6 +211,7 @@ class MetricsStream:
         """True while the fleet window hook (the allgather) is armed."""
         return self._window_hook is not None
 
+    @_flush_span
     def flush(self, final: bool = True) -> None:
         if not self._pending:
             return
@@ -417,7 +444,8 @@ class TrainingMonitor:
 
         self.trace: Optional[TraceEventBuffer] = None
         if cfg.trace and self.is_emitter:
-            self.trace = TraceEventBuffer(max_steps=cfg.trace_steps)
+            self.trace = TraceEventBuffer(max_steps=cfg.trace_steps,
+                                          origin=time.perf_counter())
             self.trace_path = os.path.join(self.out_dir, TRACE_JSON)
 
         # ---- fleet layer (docs/telemetry.md "Fleet observability") --- #
@@ -485,6 +513,14 @@ class TrainingMonitor:
         reconciler = None
         if cfg.reconcile:
             reconciler = self._reconcile
+        # what rides the stream at flush cadence beside the window's own
+        # records: the engine's out-of-band records, the compile record
+        # (monitor/trace.py) from the process's first line on, and what
+        # add_record queued (the engine's slow_step records)
+        self._engine_records_fn = extra_records_fn
+        self._compile_seq = -1
+        self._compiles_seen = T.compile_count()
+        self._queued: List[dict] = []
         self.stream = MetricsStream(
             window, self._sink,
             boundary_fn=boundary_fn,
@@ -497,7 +533,7 @@ class TrainingMonitor:
                           else None),
             moe_hook=(self._moe_window if self.moe_agg is not None
                       else None),
-            extra_records_fn=extra_records_fn,
+            extra_records_fn=self._extra_records,
             # non-emitter ranks have no writers: skip record assembly
             # and the records-only boundary reads on them
             assemble_records=self.is_emitter)
@@ -510,8 +546,18 @@ class TrainingMonitor:
                                           "predicted_step_time_lb_s")}
                                      if predictions else {})}])
         self._closed = False
+        self._at_exit_close = False
         self._warned_fleet_flush = False
-        atexit.register(self.close)
+        # at exit through a weak reference: the registry must not be what
+        # keeps a discarded engine, its parameters and optimizer state on
+        # the device for the rest of the process (the benchmark's parity
+        # engine stayed, 1.18 GB beside the timed one: PERF.md section 6,
+        # PR 56); a monitor collected unclosed still closes its writers
+        self._at_exit = functools.partial(_close_if_alive,
+                                          weakref.ref(self))
+        atexit.register(self._at_exit)
+        self._finalizer = (weakref.finalize(self, self._thread.close)
+                           if self._thread is not None else None)
         log_dist(
             f"monitor: writers={list(cfg.writers)} window={window} "
             f"trace={'on' if self.trace else 'off'} "
@@ -548,26 +594,52 @@ class TrainingMonitor:
                  counters: Optional[Dict[str, Any]] = None,
                  swap: Optional[Dict[str, Any]] = None,
                  grad_norm: Optional[float] = None) -> None:
-        if self.trace is not None:
-            self.trace.note_untraced_step(step)
-        self._last_step = int(step)
-        if self.capture is not None:
-            # one predicate check when idle; while armed, counts the
-            # captured steps and stops the profiler after the K-th.
-            # BEFORE the stream call: a flush inside end_step may ARM
-            # the capture, and the arming step itself is not captured
-            # (the profiler starts after this step already ended)
-            self.capture.observe_step_end(step)
-        self.stream.end_step(step, loss=loss, tokens=tokens,
-                             counters=counters, swap=swap,
-                             grad_norm=grad_norm)
+        """Under ``ds.monitor.record``: what telemetry costs the step
+        loop, a flush (``ds.monitor.flush``, inside it) included."""
+        with span("monitor.record", step=int(step)):
+            if self.trace is not None:
+                self.trace.note_untraced_step(step)
+            self._last_step = int(step)
+            # programs JAX was asked to compile during this step:
+            # measured, beside the predicted ``retraces``; 0 when steady
+            seen = T.compile_count()
+            counters = {**(counters or {}),
+                        R.F_COMPILES: seen - self._compiles_seen}
+            self._compiles_seen = seen
+            if self.capture is not None:
+                # one predicate check when idle; while armed, counts the
+                # captured steps and stops the profiler after the K-th.
+                # BEFORE the stream call: a flush inside end_step may ARM
+                # the capture, and the arming step itself is not captured
+                # (the profiler starts after this step already ended)
+                self.capture.observe_step_end(step)
+            self.stream.end_step(step, loss=loss, tokens=tokens,
+                                 counters=counters, swap=swap,
+                                 grad_norm=grad_norm)
 
     # ------------------------------------------------------------------ #
+    def add_record(self, rec: dict) -> None:
+        """Queue an out-of-band record (the engine's ``slow_step``) for
+        the next flush."""
+        self._queued.append(rec)
+
+    def _extra_records(self) -> List[dict]:
+        out = list(self._engine_records_fn() or []
+                   ) if self._engine_records_fn is not None else []
+        fresh = T.compiles(self._compile_seq)
+        if fresh:
+            self._compile_seq = fresh[-1]["seq"]
+            out.extend({R.F_KIND: R.KIND_COMPILE, **rec} for rec in fresh)
+        queued, self._queued = self._queued, []
+        return out + queued
+
     def _sink(self, records: List[dict]) -> None:
         """Flush-boundary sink: hand the window to the writer thread,
         beat the heartbeat, and mark the boundary on the trace timeline
         (the flush is where the batched device reads happen — worth
         seeing next to the spans)."""
+        if self.trace is not None:
+            self.trace.collect()
         if self.trace is not None and not self.trace.saturated:
             self.trace.add_instant("flush", time.perf_counter(),
                                    args={"records": len(records)})
@@ -579,19 +651,21 @@ class TrainingMonitor:
     def _reconcile(self, measured: Dict[str, Any]) -> Optional[dict]:
         rec = reconcile_window(measured, self.predictions, self.bands)
         self.last_reconciliation = rec
-        if rec.get(R.R_FLAGS):
-            logger.warning(format_line(rec))
-            if self.capture is not None and not self._closed:
-                # a breached band arms a bounded profiler capture for
-                # the NEXT K steps — the first bad window ships with
-                # xplane evidence (monitor/capture.py rate limits).
-                # Never during close()'s final flush: there are no next
-                # steps, so arming would burn a max_captures slot on an
-                # empty trace
-                self.capture.maybe_arm_for_flags(
-                    rec[R.R_FLAGS], rec.get(R.R_WINDOW_END) or 0)
-        else:
-            log_dist(format_line(rec), ranks=[0])
+        flags = rec.get(R.R_FLAGS)
+        if not self._at_exit_close:
+            if flags:
+                logger.warning(format_line(rec))
+            else:
+                log_dist(format_line(rec), ranks=[0])
+        if flags and self.capture is not None and not self._closed:
+            # a breached band arms a bounded profiler capture for
+            # the NEXT K steps — the first bad window ships with
+            # xplane evidence (monitor/capture.py rate limits).
+            # Never during close()'s final flush: there are no next
+            # steps, so arming would burn a max_captures slot on an
+            # empty trace
+            self.capture.maybe_arm_for_flags(
+                flags, rec.get(R.R_WINDOW_END) or 0)
         return rec
 
     def _moe_window(self, raw: Dict[str, Any],
@@ -612,7 +686,8 @@ class TrainingMonitor:
                     "moe routing", time.perf_counter(),
                     {"drop_fraction": rec.get(R.M_DROP_FRAC),
                      "imbalance": rec.get(R.M_IMBALANCE)})
-            log_dist(format_moe_line(rec), ranks=[0])
+            if not self._at_exit_close:
+                log_dist(format_moe_line(rec), ranks=[0])
         return rec, fields
 
     def _heartbeat_ages(self) -> Dict[int, float]:
@@ -733,9 +808,11 @@ class TrainingMonitor:
         # drop the atexit registry's reference so a discarded engine's
         # monitor (trace buffer + writer thread) is actually reclaimable
         try:
-            atexit.unregister(self.close)
+            atexit.unregister(self._at_exit)
         except Exception:  # noqa: BLE001
             pass
+        if self._finalizer is not None:
+            self._finalizer.detach()
         try:
             # final=True: a partial last window never runs the fleet
             # collective — hosts may be exiting at different times
@@ -749,6 +826,7 @@ class TrainingMonitor:
             self.heartbeat.close(step=self._last_step)
         if self.trace is not None and self.trace_path is not None:
             try:
+                self.trace.collect()
                 self.trace.write(self.trace_path)
             except Exception as e:  # noqa: BLE001
                 logger.warning(f"monitor: trace export failed ({e})")

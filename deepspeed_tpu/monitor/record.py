@@ -43,6 +43,14 @@ KIND_MOE = "moe"
 # ladder step-down from the degradation registry
 KIND_CHAOS = "chaos"
 KIND_DEGRADATION = "degradation"
+# what the host thread waited for (monitor/trace.py): one record per
+# program JAX was asked to compile, from the import of the package on
+# (program, trace_s, lower_s, backend_s, outcome compiled | fetched |
+# uncached, fetch_s, saved_s, during, step), and one per optimizer step
+# that took over twice the median of the last 32 (runtime/engine.py
+# _note_step_interval)
+KIND_COMPILE = "compile"
+KIND_SLOW_STEP = "slow_step"
 
 # ---- per-step field names (the schema) ------------------------------- #
 F_KIND = "kind"
@@ -63,6 +71,11 @@ F_DISPATCHES_PER_STEP = "dispatches_per_step"
 # cumulative transient-I/O retries absorbed by the RetryPolicy
 # (resilience/retry.py) — nonzero means the run rode out real faults
 F_IO_RETRIES = "io_retries"
+# programs JAX was asked to compile during the step (measured; the
+# compile record has each), and launches of the grad program that
+# stalled on the host (engine LAUNCH_STALL_S)
+F_COMPILES = "compiles"
+F_STALLED_LAUNCHES = "stalled_launches"
 F_SWAP_READ_GBPS = "swap_read_gbps"
 F_SWAP_OVERLAP_FRACTION = "swap_overlap_fraction"
 F_SWAP_READ_VS_CEILING = "swap_read_vs_ceiling"
@@ -89,6 +102,8 @@ STEP_RECORD_FIELDS = (
     # appended after the released v2 set (position-readers keep their
     # shared prefix): retry counters ride every step record
     F_IO_RETRIES,
+    # PR 56
+    F_COMPILES, F_STALLED_LAUNCHES,
 )
 
 # ---- recomputation-plan fields of a meta record ---------------------- #
@@ -322,7 +337,8 @@ def make_step_record(step: int, loss: Optional[float], wall_s: float,
     rec[F_LOSS_SCALE] = boundary.get("loss_scale")
     rec.update(memory)
     for k in (F_SKIPPED_STEPS, F_SENTINEL_ANOMALIES, F_SENTINEL_SKIPS,
-              F_RETRACES, F_DISPATCHES_PER_STEP, F_IO_RETRIES):
+              F_RETRACES, F_DISPATCHES_PER_STEP, F_IO_RETRIES,
+              F_COMPILES, F_STALLED_LAUNCHES):
         rec[k] = counters.get(k)
     if swap:
         rec[F_SWAP_READ_GBPS] = swap.get("read_gbps")

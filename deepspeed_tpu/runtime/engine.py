@@ -29,6 +29,9 @@ gradient-accumulation boundaries.
 
 import math
 import os
+import resource
+import statistics
+import time
 import warnings
 from typing import Any, Callable, Dict, Optional
 
@@ -55,6 +58,7 @@ def _install_donation_warning_filter():
         _donation_filter_installed = True
 
 from ..config import DeepSpeedConfig
+from ..monitor import trace as host_trace
 from ..monitor.trace import span
 from ..parallel import mesh as mesh_mod
 from ..parallel.mesh import MeshContext
@@ -190,7 +194,10 @@ class DeepSpeedEngine:
         self.global_steps = 0
         self.micro_steps = 0
         self.skipped_steps = 0
+        # where set-up ends for this engine (monitor/trace.py new_engine)
+        self.trace_marks = host_trace.new_engine()
         # ---- mesh ---------------------------------------------------- #
+        host_trace.phase("initialize", "mesh")
         self.mesh_ctx = resolve_mesh_ctx(config, mesh)
 
         # Tensor-parallel base specs: models that declare a Megatron-style
@@ -206,6 +213,7 @@ class DeepSpeedEngine:
             self.param_specs = model.param_partition_specs()
 
         dp_world = self.mesh_ctx.data_parallel_world_size
+        host_trace.phase("initialize", "config")
         self.config = (config if isinstance(config, DeepSpeedConfig)
                        else DeepSpeedConfig(config, world_size=dp_world))
         self.world_size = dp_world
@@ -230,6 +238,7 @@ class DeepSpeedEngine:
                 "parameters live outside the module")
 
         # ---- ZeRO sharding ------------------------------------------- #
+        host_trace.phase("initialize", "params")
         stage = self.config.zero_optimization_stage
         # paths a model says it cannot run (a class attribute ``refuses``:
         # {path: the reason in one sentence}), refused before anything
@@ -315,6 +324,7 @@ class DeepSpeedEngine:
                                        self.param_shardings)
 
         # ---- LR schedule + optimizer --------------------------------- #
+        host_trace.phase("initialize", "optimizer")
         self.lr_scheduler = self._configure_lr_scheduler(lr_scheduler)
         schedule = (self.lr_scheduler.lr_at if self.lr_scheduler is not None
                     else None)
@@ -362,6 +372,7 @@ class DeepSpeedEngine:
         # after it, by a pure function of the step's summed RoutingStats
         # (``model.optimizer_exempt()``: a selection bias moved by the
         # experts' counts).  They stay in ``self.params``.
+        host_trace.phase("initialize", "remat_plan")
         self._exempt = self._resolve_optimizer_exempt()
         self._exempt_stats = None
         # which leaves the apply program also writes in the compute
@@ -374,6 +385,7 @@ class DeepSpeedEngine:
             model.install_remat_budget(self._remat_budget)
 
         # ---- resilience (all off by default; see docs/resilience.md) - #
+        host_trace.phase("initialize", None)
         res = self.config.resilience_config
         self.resilience = res
         # chaos plane: installed process-globally (chaos.install) because
@@ -445,6 +457,7 @@ class DeepSpeedEngine:
         # ---- compiled programs --------------------------------------- #
         # step programs launched so far -> the shapes of their first call
         # (step_programs(); profiling/scope_map.py reads them)
+        host_trace.phase("initialize", "programs")
         self._launched = {}
         scope_map.register(self)
         # ---- the model's own counters --------------------------------- #
@@ -494,6 +507,7 @@ class DeepSpeedEngine:
                     f"{2 * self.gradient_accumulation_steps()})", ranks=[0])
 
         # ---- data ---------------------------------------------------- #
+        host_trace.phase("initialize", None)
         self.training_dataloader = self._configure_dataloader(
             training_data, collate_fn)
         # Default-stream PRNG impl is a config knob ("prng_impl").  rbg:
@@ -608,6 +622,7 @@ class DeepSpeedEngine:
         # Per-step structured records with boundary-only batched host
         # reads, background writers, optional trace export, and the
         # measured-vs-predicted reconciliation against the static model.
+        host_trace.phase("initialize", "monitor")
         self.monitor = None
         self._monitor_seq = None
         # single-host posture: rank 0 only.  Fleet/heartbeat posture:
@@ -1733,9 +1748,9 @@ class DeepSpeedEngine:
     # tracing: the engine's spans and the programs it launched
     # ------------------------------------------------------------------ #
     def _span(self, name, **ids):
-        """``ds.<name>`` in the profiler's trace and, when the monitor's
-        Chrome export is on, on its step lane (monitor/trace.py)."""
-        return span(name, self.monitor, **ids)
+        """``ds.<name>`` in the profiler's trace and in the collector,
+        which the monitor's Chrome export reads (monitor/trace.py)."""
+        return span(name, **ids)
 
     def _micro_index(self):
         """Which micro-batch of the accumulation window is under way."""
@@ -1743,11 +1758,16 @@ class DeepSpeedEngine:
 
     def _launch(self, fn, *args, **kwargs):
         """Call the step program ``fn``.  The shapes of its first call
-        are kept, so that step_programs() can give its compiled text."""
-        if fn not in self._launched:
-            self._launched[fn] = jax.tree.map(_abstract, (args, kwargs))
+        are kept, so that step_programs() can give its compiled text,
+        and that call (trace, lower, compile or fetch, first dispatch)
+        is a span of its own, ``ds.launch.first``."""
         self._launches += 1
-        return fn(*args, **kwargs)
+        if fn in self._launched:
+            return fn(*args, **kwargs)
+        self._launched[fn] = jax.tree.map(_abstract, (args, kwargs))
+        self._first_launches += 1
+        with self._span("launch.first", program=_program_name(fn)):
+            return fn(*args, **kwargs)
 
     def step_programs(self):
         """[(name, text)] of the step programs this engine has launched:
@@ -1855,8 +1875,7 @@ class DeepSpeedEngine:
                 # compressed phase: local (unreduced) stacked grads — the
                 # dense allreduce left the program at the freeze boundary
                 grad_fn = self._onebit_programs["grad_fn"]
-            with self._grad_launch(grad_fn), self._span(
-                    "forward.dispatch", program=_program_name(grad_fn)):
+            with self._grad_launch(grad_fn):
                 out = self._launch(grad_fn, self._grad_weights(),
                                    self.scaler_state, rng, *args, **kwargs)
             loss, grads, *extras = out
@@ -1992,6 +2011,7 @@ class DeepSpeedEngine:
         self._grad_acc = None
         self._last_overflow = overflow
         self.global_steps += 1
+        self._note_step_interval()
         self._chaos_step_boundary()
         if self._moe_stats_enabled:
             self._moe_stats_steps += 1
@@ -2202,8 +2222,10 @@ class DeepSpeedEngine:
         once an optimizer step: it takes the count of launches."""
         from ..monitor import record as mrec
         launches, self._launches = self._launches, 0
+        stalled, self._stalled_launches = self._stalled_launches, 0
         counters = {mrec.F_SKIPPED_STEPS: self.skipped_steps,
-                    mrec.F_DISPATCHES_PER_STEP: launches}
+                    mrec.F_DISPATCHES_PER_STEP: launches,
+                    mrec.F_STALLED_LAUNCHES: stalled}
         if self.sentinel is not None:
             c = self.sentinel.counters()
             counters[mrec.F_SENTINEL_ANOMALIES] = c["anomalies_seen"]
@@ -2227,11 +2249,17 @@ class DeepSpeedEngine:
     LAUNCH_STALL_S = 0.02
     _launch_stalls = 0
     _await_loss_before_launch = False
+    # launches of a compiled grad program over LAUNCH_STALL_S since the
+    # last optimizer step (the step record's ``stalled_launches``)
+    _stalled_launches = 0
 
     def _grad_launch(self, grad_fn):
-        """Context of one launch of the grad program: waits for the last
-        loss first where launches have been seen to stall, and times the
-        launch on the host (``_note_grad_launch``)."""
+        """Context of one launch of the grad program, the span
+        ``ds.forward.dispatch``: waits for the last loss first where
+        launches have been seen to stall (``ds.forward.await_loss``:
+        the host blocked on the device), and times the launch on the
+        host (``_note_grad_launch``); a launch that stalled carries the
+        id ``stalled=1``."""
         # imported here so that no line above ``forward`` moves: the
         # Mosaic kernels' compile-cache keys carry the line numbers of
         # the frames they were traced under, this file's among them
@@ -2242,11 +2270,92 @@ class DeepSpeedEngine:
         def launch():
             if (self._await_loss_before_launch
                     and self._last_loss is not None):
-                jax.block_until_ready(self._last_loss)
+                with self._span("forward.await_loss",
+                                step=self.global_steps + 1,
+                                micro=self._micro_index()):
+                    jax.block_until_ready(self._last_loss)
             compiled, started = grad_fn in self._launched, time.perf_counter()
-            yield
-            self._note_grad_launch(compiled, time.perf_counter() - started)
+            with self._span("forward.dispatch",
+                            program=_program_name(grad_fn)) as dispatch:
+                yield
+                seconds = time.perf_counter() - started
+                if compiled and seconds > self.LAUNCH_STALL_S:
+                    self._stalled_launches += 1
+                    dispatch.note(stalled=1)
+            self._note_grad_launch(compiled, seconds)
         return launch()
+
+    # -- where set-up ends, and a step of seconds ----------------------- #
+    # Host intervals between optimizer steps kept for the median, and how
+    # many times it a step must take to get its line.
+    STEP_INTERVALS = 32
+    SLOW_STEP_RATIO = 2.0
+    SLOW_STEP_MIN_INTERVALS = 4
+    _first_launches = 0
+
+    @property
+    def steady_since(self):
+        """``time.perf_counter_ns`` at the end of the first optimizer
+        step in which every launch was of a program this engine had
+        launched before; None until then."""
+        return self.trace_marks["steady_since_ns"]
+
+    def _note_step_interval(self):
+        """Once an optimizer step, from its bookkeeping: marks
+        ``steady_since``, and from then on keeps the host intervals
+        between steps and says of one over ``SLOW_STEP_RATIO`` times
+        their median where its time went.  No device read, no profiler:
+        the collector's leaf spans, the collector of garbage's seconds,
+        and the kernel's count of involuntary context switches and major
+        page faults."""
+        now = time.perf_counter_ns()
+        first, self._first_launches = self._first_launches, 0
+        usage = resource.getrusage(resource.RUSAGE_SELF)
+        mark = (now, host_trace.gc_ns(), usage.ru_nivcsw, usage.ru_majflt)
+        if self.steady_since is None:
+            if not first:
+                self.trace_marks["steady_since_ns"] = now
+                self._step_mark, self._step_intervals = mark, []
+            return
+        before, self._step_mark = self._step_mark, mark
+        interval = now - before[0]
+        kept = self._step_intervals
+        if len(kept) >= self.SLOW_STEP_MIN_INTERVALS:
+            median = statistics.median(kept)
+            if interval > self.SLOW_STEP_RATIO * median:
+                self._say_slow_step(before, mark, median)
+        kept.append(interval)
+        del kept[:-self.STEP_INTERVALS]
+
+    def _say_slow_step(self, before, mark, median_ns):
+        """One log line and, where a monitor is on, one ``slow_step``
+        record for the step that ended at ``mark``."""
+        from ..monitor import record as mrec
+        interval = mark[0] - before[0]
+        under = host_trace.leaf_times(host_trace.spans(before[0]),
+                                      before[0], mark[0])
+        rec = {
+            mrec.F_KIND: mrec.KIND_SLOW_STEP, mrec.F_STEP: self.global_steps,
+            "ms": round(interval / 1e6, 3),
+            "median_ms": round(median_ns / 1e6, 3),
+            "spans_ms": {name: round(ns / 1e6, 3) for name, ns in sorted(
+                under.items(), key=lambda kv: -kv[1])},
+            "rest_ms": round((interval - sum(under.values())) / 1e6, 3),
+            "gc_ms": round((mark[1] - before[1]) / 1e6, 3),
+            "involuntary_switches": mark[2] - before[2],
+            "major_faults": mark[3] - before[3],
+        }
+        log_dist(
+            f"slow step {rec[mrec.F_STEP]}: {rec['ms']:.1f} ms on the host "
+            f"against a median of {rec['median_ms']:.1f}; under "
+            + ", ".join(f"{n} {ms:.1f}" for n, ms in rec["spans_ms"].items())
+            + f"; under no span {rec['rest_ms']:.1f} (the caller's loop, "
+            f"or a host that did not run); garbage collection "
+            f"{rec['gc_ms']:.1f} ms, {rec['involuntary_switches']} "
+            f"involuntary context switches, {rec['major_faults']} major "
+            "page faults", ranks=[0])
+        if self.monitor is not None:
+            self.monitor.add_record(rec)
 
     def _note_grad_launch(self, compiled: bool, seconds: float) -> None:
         """The grad program's launch took ``seconds`` on the host.  Two
@@ -2266,6 +2375,9 @@ class DeepSpeedEngine:
         self._launch_stalls = self._launch_stalls + 1 if stalled else 0
         if self._launch_stalls >= 2:
             self._await_loss_before_launch = True
+            # the host stops running a step ahead: one interval of two
+            # steps, and a median that starts over (_note_step_interval)
+            self._step_intervals = []
             log_dist(
                 "the grad program's launch stalled on the host twice in a "
                 f"row ({1e3 * seconds:.0f} ms): the device has no room for "
@@ -2835,6 +2947,7 @@ class DeepSpeedEngine:
         self._last_overflow = overflow
         self.micro_steps += gas
         self.global_steps += 1
+        self._note_step_interval()
         # Mirror step()'s skip/scheduler chain exactly: a sentinel skip
         # wins over the overflow branch (counted once), and the host
         # scheduler never advances on a skipped step.  The skip_step

@@ -1,0 +1,532 @@
+"""What the host thread waited for (deepspeed_tpu/monitor/trace.py and
+the engine's use of it): the one collector of ``ds.*`` spans and the
+Chrome export that reads it, a span's cost, the compile record and
+``explain_compiles``, ``ds.forward.await_loss``, ``steady_since`` and
+the slow-step line."""
+
+import json
+import logging
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.monitor import record as R
+from deepspeed_tpu.monitor import trace as T
+from deepspeed_tpu.monitor import validate_trace_events
+from deepspeed_tpu.monitor.trace import TraceEventBuffer, span
+
+from .test_engine import make_engine, train_steps
+from .test_monitor import _engine as _monitored_engine
+from .test_monitor import _run_steps
+
+
+def _since(mark_ns, name=None):
+    return [s for s in T.spans(mark_ns) if name is None or s[0] == name]
+
+
+# --------------------------------------------------------------------- #
+# the collector
+# --------------------------------------------------------------------- #
+def test_the_collector_keeps_closed_spans_in_closing_order():
+    mark = time.perf_counter_ns()
+    with span("outer", step=7):
+        with span("outer.first"):
+            pass
+        with span("outer.second", program="p") as second:
+            second.note(stalled=1)
+    got = _since(mark)
+    assert [s[0] for s in got] == ["ds.outer.first", "ds.outer.second",
+                                   "ds.outer"]
+    assert [s[3] for s in got] == [{}, {"program": "p", "stalled": 1},
+                                   {"step": 7}]
+    first, second, outer = got
+    assert outer[1] <= first[1] <= first[2] <= second[1] <= second[2] \
+        <= outer[2]
+    assert T.last_span() == outer
+    assert T.open_span() == (None, None)
+
+
+def test_the_collector_is_bounded_and_drops_the_oldest():
+    with span("bound.first"):
+        pass
+    for _ in range(T.COLLECTOR_SPANS):
+        with span("bound.fill"):
+            pass
+    everything = T.spans()
+    assert len(everything) == T.COLLECTOR_SPANS
+    assert "ds.bound.first" not in {s[0] for s in everything}
+
+
+def test_open_span_names_the_innermost_and_the_step_of_an_outer_one():
+    with span("a", step=3, micro=1):
+        with span("a.b", program="q"):
+            assert T.open_span() == ("ds.a.b", 3)
+    with pytest.raises(KeyError):
+        with span("c"):
+            span("c.left_open").__enter__()
+            raise KeyError("the child goes with its parent")
+    assert T.open_span() == (None, None)
+
+
+def test_phases_are_consecutive_leaves_without_a_with_block():
+    mark = time.perf_counter_ns()
+    T.phase("long", "nobody_listens")       # no such span open: nothing
+    with span("long") as whole:
+        T.phase("long", "one")
+        whole.phase("two", size=2)
+    names = [s[0] for s in _since(mark)]
+    assert names == ["ds.long.one", "ds.long.two", "ds.long"]
+    one, two, outer = _since(mark)
+    assert outer[1] <= one[1] and one[2] <= two[1] and two[2] <= outer[2]
+    assert two[3] == {"size": 2}
+
+
+def test_leaf_times_clip_and_leave_parents_out():
+    spans = [("ds.step", 0, 100, {}), ("ds.step.dispatch", 10, 40, {}),
+             ("ds.monitor.record", 50, 90, {}),
+             ("ds.monitor.flush", 60, 80, {}),
+             ("ds.forward.await_loss", 120, 220, {})]
+    assert T.leaf_times(spans, 20, 200) == {
+        "ds.step.dispatch": 20, "ds.monitor.flush": 20,
+        "ds.forward.await_loss": 80}
+
+
+# --------------------------------------------------------------------- #
+# the Chrome export is a writer over the collector
+# --------------------------------------------------------------------- #
+def test_the_chrome_export_reads_the_collector_within_its_step_bound():
+    with span("before_the_origin", step=0):
+        pass
+    buf = TraceEventBuffer(max_steps=2, origin=time.perf_counter())
+    for step in (1, 2, 3):
+        with span("forward", step=step, micro=0):
+            with span("forward.dispatch", program="jit_f"):
+                pass
+        buf.collect()       # as the monitor does at a flush
+    buf.collect()
+    payload = buf.to_json()
+    assert validate_trace_events(payload) == []
+    events = [e for e in payload["traceEvents"] if e["ph"] == "X"]
+    assert [(e["name"], e["args"]) for e in events] == [
+        ("ds.forward", {"micro": 0, "step": 1}),
+        ("ds.forward.dispatch", {"program": "jit_f"}),
+        ("ds.forward", {"micro": 0, "step": 2}),
+        ("ds.forward.dispatch", {"program": "jit_f"})]
+    assert {e["tid"] for e in events} == {T.TID_STEP}
+    assert payload["otherData"]["steps_traced"] == 2
+    assert payload["otherData"]["truncated_at_max_steps"] is True
+    # it holds no bookkeeping of its own for the spans: no second route
+    assert not hasattr(buf, "open_at")
+    assert "_buffer" not in span.__slots__
+
+
+# --------------------------------------------------------------------- #
+# what a span costs with nothing listening
+# --------------------------------------------------------------------- #
+class _SpanBeforeTheCollector:
+    """The span as it was before PR 56, nothing listening: the
+    annotation alone behind the same class."""
+
+    __slots__ = ("_name", "_ids", "_buffer", "_annotation")
+
+    def __init__(self, name, monitor=None, **ids):
+        self._name = T.SPAN_PREFIX + name
+        self._ids = ids
+        # what made a second collector of it: the monitor's buffer
+        self._buffer = (monitor.trace if monitor is not None
+                        and monitor.trace_active else None)
+
+    def __enter__(self):
+        self._annotation = jax.profiler.TraceAnnotation(self._name,
+                                                        **self._ids)
+        self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        if self._buffer is not None:
+            raise AssertionError
+        return self._annotation.__exit__(*exc)
+
+
+def _burst_us(cls, n=2000):
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with cls("cost", step=1):
+            pass
+    return 1e6 * (time.perf_counter() - t0) / n
+
+
+def test_the_collector_adds_under_a_microsecond_to_a_span():
+    """docs/telemetry.md states the budget: under 1 us added to a span
+    with nothing listening (measured 0.4 to 0.6 us here), at about 20
+    spans a micro-batch.  Short bursts of the two in turn and the best
+    of forty each, so that a neighbour's load (six test workers) falls
+    on both alike, as the monitor's overhead test takes its best of
+    three."""
+    before = now = float("inf")
+    for _ in range(40):
+        before = min(before, _burst_us(_SpanBeforeTheCollector))
+        now = min(now, _burst_us(span))
+    assert now - before < 1.0, (before, now)
+
+
+# --------------------------------------------------------------------- #
+# the compile record
+# --------------------------------------------------------------------- #
+@pytest.fixture
+def compile_cache(tmp_path):
+    """An empty persistent cache that takes every program, and the
+    process's own settings back afterwards."""
+    from jax.experimental.compilation_cache import compilation_cache
+    keys = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cache"))
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    compilation_cache.reset_cache()
+    yield compilation_cache
+    for k, v in saved.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
+
+
+def _probe():
+    @jax.jit
+    def host_trace_probe(x):
+        return jnp.sin(x) * 2.0 + 1.0
+    return host_trace_probe
+
+
+def _requests(program, since_seq):
+    return [r for r in T.compiles(since_seq) if r["program"] == program]
+
+
+def test_the_compile_record_says_compiled_fetched_or_uncached(
+        compile_cache):
+    x = np.arange(8, dtype=np.float32)
+    seq = T.compile_count()
+    with span("probe", step=5):
+        with span("probe.dispatch"):
+            _probe()(x).block_until_ready()
+    compiled, = _requests("jit_host_trace_probe", seq)
+    assert compiled["outcome"] == "compiled"
+    assert compiled["trace_s"] > 0 and compiled["lower_s"] > 0
+    assert compiled["backend_s"] > 0
+    assert compiled["fetch_s"] == 0.0 and compiled["saved_s"] == 0.0
+    assert (compiled["during"], compiled["step"]) == ("ds.probe.dispatch", 5)
+    assert compiled["when_ns"] < compiled["end_ns"] <= \
+        time.perf_counter_ns() + 1_000_000
+    # the same request as a span of the collector
+    last = [s for s in T.spans() if s[0] == "ds.compile"][-1]
+    assert last[3] == {"program": "jit_host_trace_probe",
+                       "outcome": "compiled"}
+    assert last[1:3] == (compiled["when_ns"], compiled["end_ns"])
+
+    jax.clear_caches()
+    seq = T.compile_count()
+    _probe()(x).block_until_ready()
+    fetched, = _requests("jit_host_trace_probe", seq)
+    assert fetched["outcome"] == "fetched"
+    assert fetched["fetch_s"] > 0
+    assert (fetched["during"], fetched["step"]) == (None, None)
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compile_cache.reset_cache()
+    jax.clear_caches()
+    seq = T.compile_count()
+    _probe()(x).block_until_ready()
+    uncached, = _requests("jit_host_trace_probe", seq)
+    assert uncached["outcome"] == "uncached"
+    assert uncached["backend_s"] > 0 and uncached["fetch_s"] == 0.0
+    assert "key" not in uncached
+    assert [r["seq"] for r in (compiled, fetched, uncached)] == sorted(
+        r["seq"] for r in (compiled, fetched, uncached))
+
+
+def test_the_record_starts_with_the_import_and_install_is_once():
+    first = T.compiles(-1)[0]
+    assert first["seq"] == 0 and first["outcome"] == "imported"
+    assert first["since_process_start_s"] is None or \
+        first["since_process_start_s"] > 0
+    from jax._src import monitoring
+    before = (len(monitoring.get_event_listeners()), T.compile_count())
+    T.install()
+    assert (len(monitoring.get_event_listeners()),
+            T.compile_count()) == before
+    assert T.compiles(-1)[0] is first
+
+
+def test_a_slow_request_gets_one_line_and_a_quick_one_none():
+    rec = {"program": "jit_loss_and_grads", "backend_s": 212.4,
+           "trace_s": 6.1, "lower_s": 9.8, "outcome": "compiled",
+           "fetch_s": 0.0, "saved_s": 0.0,
+           "during": "ds.forward.dispatch", "step": 1}
+    assert T.format_compile_line(rec) == (
+        "compiled jit_loss_and_grads in 212.4 s (persistent cache MISS; "
+        "traced 6.1 s, lowered 9.8 s) during ds.forward.dispatch, step 1")
+    rec.update(outcome="fetched", backend_s=3.2, fetch_s=3.1, saved_s=209.0,
+               during=None, step=None)
+    assert T.format_compile_line(rec) == (
+        "fetched jit_loss_and_grads in 3.2 s (persistent cache HIT, read "
+        "in 3.1 s, 209.0 s saved; traced 6.1 s, lowered 9.8 s)")
+
+
+def test_explain_compiles_yields_a_key_and_eight_component_hashes(
+        compile_cache):
+    log = logging.getLogger("jax._src.cache_key")
+    was = (log.level, log.propagate, list(log.handlers))
+    x = np.arange(8, dtype=np.float32)
+    seq = T.compile_count()
+    with T.explain_compiles():
+        _probe()(x).block_until_ready()
+    assert (log.level, log.propagate, list(log.handlers)) == was
+    explained, = _requests("jit_host_trace_probe", seq)
+    assert explained["key"].startswith("jit_host_trace_probe-")
+    assert len(explained["key"].rsplit("-", 1)[1]) == 64
+    assert tuple(explained["key_components"]) == T.KEY_COMPONENTS
+    assert all(len(h) == 64 for h in explained["key_components"].values())
+    # the same program again: the same key, whatever the outcome
+    jax.clear_caches()
+    seq = T.compile_count()
+    with T.explain_compiles():
+        _probe()(x).block_until_ready()
+    again, = _requests("jit_host_trace_probe", seq)
+    assert again["outcome"] == "fetched"
+    assert again["key"] == explained["key"]
+    assert again["key_components"] == explained["key_components"]
+    # outside the context the record carries none
+    jax.clear_caches()
+    seq = T.compile_count()
+    _probe()(x).block_until_ready()
+    assert "key" not in _requests("jit_host_trace_probe", seq)[0]
+
+
+# --------------------------------------------------------------------- #
+# the engine: the wait, the first launch, where set-up ends
+# --------------------------------------------------------------------- #
+def test_await_loss_appears_exactly_when_the_engine_waits():
+    engine = make_engine()
+    engine.LAUNCH_STALL_S = float("inf")   # a loaded machine decides nothing
+    train_steps(engine, n=2)
+    assert not engine._await_loss_before_launch
+    mark = time.perf_counter_ns()
+    train_steps(engine, n=2)
+    assert _since(mark, "ds.forward.await_loss") == []
+    assert len(_since(mark, "ds.forward.dispatch")) == 2
+    engine._await_loss_before_launch = True
+    mark = time.perf_counter_ns()
+    train_steps(engine, n=3)
+    waits = _since(mark, "ds.forward.await_loss")
+    assert [w[3] for w in waits] == [{"step": s, "micro": 0}
+                                     for s in (5, 6, 7)]
+    # before the launch, inside the call, outside the dispatch
+    for wait, call, dispatch in zip(waits, _since(mark, "ds.forward"),
+                                    _since(mark, "ds.forward.dispatch")):
+        assert call[1] <= wait[1] and wait[2] <= dispatch[1]
+        assert dispatch[2] <= call[2]
+
+
+def test_first_launches_have_their_span_and_steady_since_follows_them():
+    mark = time.perf_counter_ns()
+    engine = make_engine(gas=2)
+    initialize = _since(mark, "ds.initialize")[-1]
+    assert engine.trace_marks["initialize_ns"] == initialize[1:3]
+    assert T.newest_engine() is engine.trace_marks
+    leaves = [s[0] for s in _since(mark) if s[0].startswith(
+        "ds.initialize.")]
+    assert leaves == ["ds.initialize.config", "ds.initialize.mesh",
+                      "ds.initialize.config", "ds.initialize.params",
+                      "ds.initialize.optimizer", "ds.initialize.remat_plan",
+                      "ds.initialize.programs", "ds.initialize.monitor"]
+    assert engine.steady_since is None
+    train_steps(engine, n=1)
+    # the step that first launched the accumulate and apply programs
+    firsts = _since(mark, "ds.launch.first")
+    assert [f[3]["program"] for f in firsts] == [
+        "jit_loss_and_grads", "jit_accumulate", "jit_apply_step"]
+    assert engine.steady_since is None
+    before = time.perf_counter_ns()
+    train_steps(engine, n=1)
+    assert before < engine.steady_since <= time.perf_counter_ns()
+    assert engine.steady_since > firsts[-1][2]
+    mark2, since = time.perf_counter_ns(), engine.steady_since
+    train_steps(engine, n=2)
+    assert _since(mark2, "ds.launch.first") == []
+    assert engine.steady_since == since
+    # the compiles of the first launches name their span and step
+    during = {r["program"]: (r["during"], r["step"])
+              for r in T.compiles(0) if r["when_ns"] > mark}
+    assert during["jit_apply_step"] == ("ds.launch.first", 1)
+
+
+def test_a_stalled_launch_is_an_id_on_its_span_and_a_count(monkeypatch):
+    engine = make_engine()
+    train_steps(engine, n=2)
+    launch = engine._launch
+
+    def slow(fn, *args, **kwargs):
+        if fn is engine._grad_fn:
+            time.sleep(2 * engine.LAUNCH_STALL_S)
+        return launch(fn, *args, **kwargs)
+
+    monkeypatch.setattr(engine, "_launch", slow)
+    mark = time.perf_counter_ns()
+    engine.backward(engine.forward(*_batch()))
+    assert engine._stalled_launches == 1
+    dispatch, = _since(mark, "ds.forward.dispatch")
+    assert dispatch[3] == {"program": "jit_loss_and_grads", "stalled": 1}
+    engine.step()
+    assert engine._stalled_launches == 1   # the monitor's counters take it
+    assert engine._monitor_counters()[R.F_STALLED_LAUNCHES] == 1
+    assert engine._stalled_launches == 0
+
+
+def _batch(micro=8):
+    from .simple_model import random_dataloader
+    from .test_engine import HIDDEN
+    return next(iter(random_dataloader(HIDDEN, total_samples=micro,
+                                       batch_size=micro, seed=5)))
+
+
+# --------------------------------------------------------------------- #
+# the slow-step line
+# --------------------------------------------------------------------- #
+def test_a_sleep_inside_a_step_yields_one_line_under_the_right_span(
+        tmp_path, monkeypatch, caplog):
+    """Every step sleeps 30 ms inside the grad program's dispatch, so
+    that the host's own jitter stays far under twice the median; step 9
+    sleeps 400 ms there.  Warm-up (the compiling step, 100 times the
+    median) gets no line: the engine is not steady yet."""
+    from deepspeed_tpu.utils.logging import logger as ds_logger
+    engine = _monitored_engine(tmp_path, monitor={"writers": ["jsonl"],
+                                                  "write_interval": 4})
+    launch = engine._launch
+
+    def slow(fn, *args, **kwargs):
+        if fn is engine._grad_fn:
+            time.sleep(0.4 if engine.global_steps == 8 else 0.03)
+        return launch(fn, *args, **kwargs)
+
+    monkeypatch.setattr(engine, "_launch", slow)
+    ds_logger.addHandler(caplog.handler)  # the DS logger is non-propagating
+    try:
+        _run_steps(engine, 12)
+    finally:
+        ds_logger.removeHandler(caplog.handler)
+    engine.monitor.close()
+    lines = [r.getMessage() for r in caplog.records
+             if "slow step" in r.getMessage()]
+    assert len(lines) == 1, lines
+    assert "slow step 9:" in lines[0] and "ds.forward.dispatch" in lines[0]
+    recs = [json.loads(line) for line in open(engine.monitor.jsonl_path)]
+    slow_rec, = [r for r in recs if r[R.F_KIND] == R.KIND_SLOW_STEP]
+    assert slow_rec[R.F_STEP] == 9
+    assert 400 <= slow_rec["ms"] < 800
+    assert 30 <= slow_rec["median_ms"] < 100
+    assert slow_rec["spans_ms"]["ds.forward.dispatch"] >= 400
+    assert next(iter(slow_rec["spans_ms"])) == "ds.forward.dispatch"
+    assert 0 <= slow_rec["rest_ms"] < 100
+    assert slow_rec["gc_ms"] >= 0
+    assert slow_rec["involuntary_switches"] >= 0
+    assert slow_rec["major_faults"] >= 0
+    # the step records beside it: measured compiles, stalled launches
+    steps = [r for r in recs if r[R.F_KIND] == R.KIND_STEP]
+    assert [r[R.F_STEP] for r in steps] == list(range(1, 13))
+    assert steps[0][R.F_COMPILES] >= 2 and steps[0][R.F_RETRACES] is None
+    assert all(r[R.F_COMPILES] == 0 for r in steps[2:])
+    assert all(r[R.F_STALLED_LAUNCHES] == 1 for r in steps[1:])
+    # and the compile record rides the stream, the import's line first
+    compiles = [r for r in recs if r[R.F_KIND] == R.KIND_COMPILE]
+    assert compiles[0]["outcome"] == "imported"
+    assert "jit_apply_step" in {r["program"] for r in compiles}
+
+
+def test_the_monitor_spans_split_the_bookkeeping(tmp_path):
+    engine = _monitored_engine(tmp_path, monitor={"writers": ["jsonl"],
+                                                  "write_interval": 2})
+    _run_steps(engine, 1)
+    mark = time.perf_counter_ns()
+    _run_steps(engine, 4)
+    engine.monitor.close()
+    records = _since(mark, "ds.monitor.record")
+    flushes = _since(mark, "ds.monitor.flush")
+    assert [r[3]["step"] for r in records] == [2, 3, 4, 5]
+    assert [f[3] for f in flushes] == [{"window": 2}] * 2 + [{"window": 1}]
+    # a flush that a full window causes lies inside its record, which
+    # lies inside the step's bookkeeping
+    books = _since(mark, "ds.step.bookkeeping")
+    for rec, book in zip(records, books):
+        assert book[1] <= rec[1] and rec[2] <= book[2]
+    assert any(r[1] <= flushes[0][1] and flushes[0][2] <= r[2]
+               for r in records)
+
+
+def test_a_discarded_engine_with_telemetry_on_is_reclaimed(tmp_path):
+    """The monitor's exit hook holds it weakly: an engine dropped
+    unclosed (the benchmark's parity engine) leaves the device, and its
+    writer thread is closed with it; one closed by hand is as before."""
+    import gc
+    import weakref
+    engine = _monitored_engine(tmp_path, monitor={"writers": ["jsonl"]})
+    _run_steps(engine, 1)
+    alive = weakref.ref(engine)
+    thread = engine.monitor._thread
+    del engine
+    gc.collect()
+    assert alive() is None
+    assert thread._closed and not thread._thread.is_alive()
+    kept = _monitored_engine(tmp_path, monitor={"writers": ["jsonl"]})
+    _run_steps(kept, 2)
+    kept.monitor.close()
+    kept.monitor.close()
+    assert kept.monitor._thread._closed
+
+
+@pytest.mark.parametrize("at_exit", [True, False])
+def test_the_exit_hook_writes_the_last_window_and_prints_no_line(
+        tmp_path, caplog, at_exit):
+    """A job's last output stays its last line: the hook at interpreter
+    exit writes the partial window's records and logs none of their
+    lines; a ``close()`` by hand logs them as a full window does."""
+    import weakref
+    from deepspeed_tpu.monitor.monitor import _close_if_alive
+    from deepspeed_tpu.utils.logging import logger as ds_logger
+    engine = _monitored_engine(tmp_path, monitor={"writers": ["jsonl"],
+                                                  "write_interval": 2})
+    ds_logger.addHandler(caplog.handler)  # the DS logger is non-propagating
+    try:
+        _run_steps(engine, 3)
+        full = len(caplog.records)
+        if at_exit:
+            _close_if_alive(weakref.ref(engine.monitor))
+        else:
+            engine.monitor.close()
+    finally:
+        ds_logger.removeHandler(caplog.handler)
+    lines = [r.getMessage() for r in caplog.records]
+    assert sum("[monitor-reconcile] [1-2]" in line for line in lines[:full])
+    assert len(lines) - full == (0 if at_exit else 1), lines[full:]
+    recs = [json.loads(line) for line in
+            (tmp_path / "metrics.jsonl").read_text().splitlines()]
+    windows = [(r[R.R_WINDOW_START], r[R.R_WINDOW_END]) for r in recs
+               if r[R.F_KIND] == R.KIND_RECONCILE]
+    assert windows == [(1, 2), (3, 3)]
+
+
+def test_the_switch_to_waiting_starts_the_interval_history_over():
+    """Where the engine stops running a step ahead one host interval
+    holds two steps: the median it is held against starts over."""
+    engine = make_engine()
+    engine._step_intervals = [1, 2, 3, 4, 5]
+    engine._note_grad_launch(True, 1.0)
+    assert engine._step_intervals == [1, 2, 3, 4, 5]
+    engine._note_grad_launch(True, 1.0)
+    assert engine._await_loss_before_launch
+    assert engine._step_intervals == []
