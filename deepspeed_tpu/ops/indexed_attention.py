@@ -43,7 +43,13 @@ value and mask tiles arrive and the mask is unpacked once for them, and
 the heads go through the step's stages four abreast; the backward is ONE
 kernel that builds a tile once and makes dq, dk and dv from it) and
 ``dsa_align`` (value and the indexer's gradients in one pass: the loss is
-a scalar, so its backward rule only scales them).  Elsewhere blocked XLA
+a scalar, so its backward rule only scales them; a step is a tile and
+goes through its stages with the heads abreast, eight main heads' products,
+then their exponentials, then the sum in head order into ``pbar`` in VMEM;
+the indexer's ReLU(qI . kI) products once, left in VMEM by the scores'
+pass for the gradients'; that pass eight indexer heads abreast, and d kI
+gathered transposed, ``[Di, S]``, so that a head's q block is turned and
+not the tile).  Elsewhere blocked XLA
 forms of the same mathematics, a q block at a time under ``lax.map``; the
 tests hold the kernels to them.  ``ops/flash_attention.py`` knows nothing
 of this file and is not touched by it.
@@ -91,6 +97,32 @@ PACK = 32                 # queries a packed word
 # with the loop unrolled whole).  ``dsa_align`` (PR 50): 28.4 to 31.6 at
 # every block tried; ``dsa_select`` 10.2 to 10.5 at 512 to 2,048 columns a
 # step.
+# ``dsa_align``'s step at 256 x 512 (my chip runs, PR 57, the kernel alone
+# with the XLA around it, 20 calls, every form's four outputs the parent's
+# bit for bit but where said): 28.66 ms as PR 50 wrote it, the 32 main
+# heads one after another into a carry of 128 registers, the indexer's 16
+# unrolled head by head.  The main heads alone changed: 25.91 one at a
+# time into ``pbar`` in VMEM, 23.81 two abreast, 22.45 four (22.44 as ONE
+# product of four heads' rows), 21.71 eight (21.70 as one product), 21.41
+# sixteen.  The indexer's passes alone changed, on the parent's main loop:
+# the ReLU products kept in VMEM 28.56, the gradients' pass in stages two
+# and four abreast 29.54 and 29.71 (30.07 as a loop), the scores' pass in
+# stages 28.66: nothing.  On eight main heads abreast (21.71): the ReLU
+# products kept 21.63; d kI gathered transposed 20.79, and with the
+# products kept 19.89; then the gradients' pass as a LOOP over heads
+# abreast, two 18.88, four 18.11, eight 17.78 (unrolled in stages of two
+# and four 20.01 and 20.09; the scores' pass as a loop too 19.34; four
+# heads' d qI as one product 19.50; their d kI as one product, NOT
+# bit-equal, 20.90; sixteen main heads abreast 17.48).  **Shipped: 17.69**
+# (first call 3.9 s, the parent's 5.2), and with ``_abreast`` held to four
+# 18.75, to two 20.89, to one 25.09; of the shipped form's parts, each
+# taken back alone: d kI as the parent gathered it 22.31, the ReLU
+# products built twice 20.71, the gradients' pass unrolled 19.99.  By
+# parts (a part left out, timed only): the main heads 6.5 ms (5.8 at the
+# MXU's peak), the scores' pass 2.8 and the gradients' 4.8 (8.8 before),
+# near what their half-filled products (64 deep or 64 wide) cost the MXU,
+# the rest (the mask, the loss, dL / dI, the grid, 1.3 of XLA around the
+# kernel) 3.6.  In the cell the kernel's own line reads 16.42 (27.39).
 # The backward (my chip run, PR 51): 35.4 ms at 1,024 x 1,024, 37.0 at 512
 # x 1,024, 37.6 at 1,024 x 512, 45.5 at 512 x 512, 38.0 at 2,048 x 1,024,
 # 36.4 at 1,024 x 2,048 (the two kernels it replaced: 51.0).
@@ -101,6 +133,7 @@ _STATS_LANES = 8          # row statistics ride as [..., S, 8], lane 0 read
 _INT_MIN = -2 ** 31
 _MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
 _VMEM_LIMIT = 100 * 1024 * 1024
+_ALIGN_ABREAST = 8        # heads side by side in ``dsa_align``'s step, at most
 
 
 def _attn_bwd_vmem(seq, head_dim):
@@ -555,9 +588,10 @@ def _first_q(j, block_q, block_k):
     return (j * block_k) // block_q
 
 
-def _abreast(heads):
-    """How many of a step's heads go through its stages side by side."""
-    return next(n for n in (4, 2, 1) if heads % n == 0)
+def _abreast(heads, most=4):
+    """How many of a step's heads go through its stages side by side: the
+    widest of 8, 4, 2, 1 that divides them, ``most`` at most."""
+    return next(n for n in (8, 4, 2, 1) if n <= most and heads % n == 0)
 
 
 def _attn_fwd_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, lse_ref,
@@ -795,15 +829,17 @@ def indexed_attention_bwd_pallas(q, k, v, packed, out, lse, do, *, sm_scale,
 # dsa_align: the alignment term and the indexer's gradients in one pass
 # ---------------------------------------------------------------------- #
 def _align_kernel(qi_ref, ki_ref, w_ref, q_ref, k_ref, lse_ref, keep_ref,
-                  lsei_ref, loss_ref, dqi_ref, dki_ref, dw_ref, loss_scr,
-                  dw_scr, *, sm_scale, block_q, block_k, heads, idx_heads,
-                  group, scale, sub):
+                  lsei_ref, loss_ref, dqi_ref, dkit_ref, dw_ref, loss_scr,
+                  dw_scr, pbar_scr, relu_scr, *, sm_scale, block_q, block_k,
+                  heads, idx_heads, group, scale, sub):
     i, j = pl.program_id(1), pl.program_id(2)
     last = _last_k(i, block_q, block_k)
+    abreast = _abreast(heads, _ALIGN_ABREAST)
+    idx_abreast = _abreast(idx_heads, _ALIGN_ABREAST)
 
     @pl.when((i == 0) & (j == 0))
     def _():
-        dki_ref[...] = jnp.zeros_like(dki_ref)
+        dkit_ref[...] = jnp.zeros_like(dkit_ref)
 
     @pl.when(j == 0)
     def _():
@@ -814,31 +850,59 @@ def _align_kernel(qi_ref, ki_ref, w_ref, q_ref, k_ref, lse_ref, keep_ref,
     @pl.when(j <= last)
     def _():
         keep = _unpack_tile(keep_ref[0], sub)
+        pbar_scr[...] = jnp.zeros_like(pbar_scr)
 
-        def head(h, acc):
-            s = _nt(q_ref[0, h], k_ref[0, h // group]) * sm_scale
-            return acc + jnp.exp(s - lse_ref[0, h][:, :1])
+        def some(n, carry):
+            # stage by stage across the heads, as ``dsa_attn_fwd``'s step:
+            # every product, every exponential, then the sum IN HEAD ORDER
+            # (it is the sum head by head, bit for bit), held in VMEM and
+            # not as a carry of 128 registers
+            hs = [n * abreast + x for x in range(abreast)]
+            s = [_nt(q_ref[0, h], k_ref[0, h // group]) for h in hs]
+            p = [jnp.exp(x * sm_scale - lse_ref[0, h][:, :1])
+                 for x, h in zip(s, hs)]
+            pbar_scr[...] = functools.reduce(jnp.add, p, pbar_scr[...])
+            return carry
 
-        pbar = lax.fori_loop(0, heads, head,
-                             jnp.zeros((block_q, block_k), jnp.float32))
-        pbar = jnp.where(keep, pbar * (1.0 / heads), 0.0)
+        lax.fori_loop(0, heads // abreast, some, 0)
+        pbar = jnp.where(keep, pbar_scr[...] * (1.0 / heads), 0.0)
         k_idx = ki_ref[0]
-        scores = _scores_tile(lambda n: qi_ref[0, n],
-                              lambda n: w_ref[0, n][:, :1], idx_heads, k_idx)
+        # ``_scores_tile``, each head's ReLU(qI . kI) left in VMEM for the
+        # gradients below: the product is built once
+        scores = None
+        for n in range(idx_heads):
+            relu = jnp.maximum(_nt(qi_ref[0, n], k_idx), 0.0)
+            relu_scr[n] = relu
+            term = w_ref[0, n][:, :1] * relu
+            scores = term if scores is None else scores + term
         logp = scores - lsei_ref[0][:, :1]
         live = keep & (pbar > 0.0)
         loss_scr[...] += _fold_lanes(jnp.where(
             live, pbar * (jnp.log(jnp.where(live, pbar, 1.0)) - logp), 0.0))
         # dL / dI on the tile; nothing outside the keep-set
         g = (jnp.where(keep, jnp.exp(logp), 0.0) - pbar) * scale
-        for n in range(idx_heads):
-            q_n = qi_ref[0, n]
-            z = _nt(q_n, k_idx)
-            dw_scr[n] += _fold_lanes(g * jnp.maximum(z, 0.0))
-            gz = jnp.where(z > 0.0, g * w_ref[0, n][:, :1], 0.0).astype(
-                q_n.dtype)
-            dqi_ref[0, n] += _nn(gz, k_idx)
-            dki_ref[0, pl.ds(j * block_k, block_k), :] += _tn(gz, q_n)
+        cols = pl.ds(j * block_k, block_k)
+
+        def some_idx(m, carry):
+            # the indexer's heads likewise: the step's dI / dz tiles, then
+            # its products, each sum in head order.  d kI is gathered
+            # TRANSPOSED, [Di, S]: the head's q block is turned (64 wide)
+            # and not the tile, and 64 rows go through the MXU, not 512
+            ns = [m * idx_abreast + x for x in range(idx_abreast)]
+            relu = [relu_scr[n] for n in ns]
+            for n, r in zip(ns, relu):
+                dw_scr[n] += _fold_lanes(g * r)
+            gz = [jnp.where(r > 0.0, g * w_ref[0, n][:, :1], 0.0).astype(
+                k_idx.dtype) for n, r in zip(ns, relu)]
+            dq = [_nn(x, k_idx) for x in gz]
+            dk = [_tn(qi_ref[0, n], x) for n, x in zip(ns, gz)]
+            for n, x in zip(ns, dq):
+                dqi_ref[0, n] += x
+            dkit_ref[0, :, cols] = functools.reduce(
+                jnp.add, dk, dkit_ref[0, :, cols])
+            return carry
+
+        lax.fori_loop(0, idx_heads // idx_abreast, some_idx, 0)
 
     @pl.when(j == last)
     def _():
@@ -847,6 +911,42 @@ def _align_kernel(qi_ref, ki_ref, w_ref, q_ref, k_ref, lse_ref, keep_ref,
         for n in range(idx_heads):
             dw_ref[0, n] = _stats(
                 jnp.sum(dw_scr[n], axis=1, keepdims=True), block_q)
+
+
+def _align_vmem(seq, heads, kv_heads, head_dim, idx_heads, idx_dim, block_q,
+                block_k):
+    """The VMEM ``dsa_align`` asks for on two-byte operands, in bytes and
+    from above: what the call declares (every block twice, its last axis
+    whole lane tiles, the resident transposed d kI among them, and the
+    scratch: two row sums, ``pbar`` and every indexer head's ReLU tile) and
+    float32 ``[block_q, block_k]`` temporaries, four of the tile's own
+    (the mask, ``pbar``, the scores, dL / dI) and one for each main head
+    abreast or one and a half (the ReLU tile and, in two bytes, dI / dz)
+    for each of the indexer's, whichever is more.  54.4 MiB at the cell's
+    call (35.8 of blocks, 10.6 of scratch, 8 of temporaries), where the
+    v5e's compiler counts 48."""
+    def nbytes(*shape, size):
+        return math.prod(shape[:-1]) * -(-shape[-1] // 128) * 128 * size
+
+    blocks = (
+        nbytes(idx_heads, block_q, idx_dim, size=2)             # qI
+        + nbytes(block_k, idx_dim, size=2)                      # kI
+        + nbytes(idx_heads, block_q, _STATS_LANES, size=4)      # w
+        + nbytes(heads, block_q, head_dim, size=2)              # q
+        + nbytes(kv_heads, block_k, head_dim, size=2)           # k
+        + nbytes(heads, block_q, _STATS_LANES, size=4)          # lse
+        + nbytes(block_q // PACK, block_k, size=4)              # the mask
+        + 2 * nbytes(block_q, _STATS_LANES, size=4)             # lse_idx, loss
+        + nbytes(idx_heads, block_q, idx_dim, size=4)           # d qI
+        + nbytes(idx_dim, seq, size=4)                          # d kI^T
+        + nbytes(idx_heads, block_q, _STATS_LANES, size=4))     # d w
+    tile = nbytes(block_q, block_k, size=4)
+    # a row sum (a lane tile a row at most) and a tile: the loss's and
+    # ``pbar``, each indexer head's d w and ReLU
+    scratch = (1 + idx_heads) * (nbytes(block_q, 1, size=4) + tile)
+    return 2 * blocks + scratch + 4 * tile + max(
+        _abreast(heads, _ALIGN_ABREAST) * tile,
+        _abreast(idx_heads, _ALIGN_ABREAST) * tile * 3 // 2)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -872,7 +972,7 @@ def index_alignment_pallas(q_idx, k_idx, w, q, k, lse, packed, lse_idx, *,
         return pl.BlockSpec((1, heads_, block_q, width),
                             lambda b, i, j: (b, 0, i, 0))
 
-    loss, dqi, dki, dw = pl.pallas_call(
+    loss, dqi, dkit, dw = pl.pallas_call(
         functools.partial(
             _align_kernel, sm_scale=sm_scale, block_q=block_q,
             block_k=block_k, heads=heads, idx_heads=idx_heads,
@@ -893,22 +993,25 @@ def index_alignment_pallas(q_idx, k_idx, w, q, k, lse, packed, lse_idx, *,
             rows(_STATS_LANES)],
         out_specs=[
             rows(_STATS_LANES), rows(idx_dim, idx_heads),
-            pl.BlockSpec((1, seq, idx_dim), lambda b, i, j: (b, 0, 0)),
+            pl.BlockSpec((1, idx_dim, seq), lambda b, i, j: (b, 0, 0)),
             rows(_STATS_LANES, idx_heads)],
         out_shape=[
             jax.ShapeDtypeStruct((batch, seq, _STATS_LANES), jnp.float32),
             jax.ShapeDtypeStruct(q_idx.shape, jnp.float32),
-            jax.ShapeDtypeStruct(k_idx.shape, jnp.float32),
+            jax.ShapeDtypeStruct((batch, idx_dim, seq), jnp.float32),
             jax.ShapeDtypeStruct((batch, idx_heads, seq, _STATS_LANES),
                                  jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((block_q, lanes), jnp.float32),
-                        pltpu.VMEM((idx_heads, block_q, lanes), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, lanes), jnp.float32),
+            pltpu.VMEM((idx_heads, block_q, lanes), jnp.float32),
+            pltpu.VMEM((block_q, block_k), jnp.float32),
+            pltpu.VMEM((idx_heads, block_q, block_k), jnp.float32)],
         interpret=interpret, name="dsa_align",
         **_compiler_params(("arbitrary", "arbitrary", "arbitrary"),
                            interpret),
     )(q_idx, k_idx, _wide(w), q, k, _wide(lse), packed, _wide(lse_idx))
     return (jnp.sum(loss[..., 0]) / (batch * seq),
-            (dqi, dki, dw[..., 0]))
+            (dqi, jnp.swapaxes(dkit, 1, 2), dw[..., 0]))
 
 
 # ---------------------------------------------------------------------- #
