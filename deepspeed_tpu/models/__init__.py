@@ -12,8 +12,10 @@ from .gpt_moe import GPTMoEConfig, GPTMoEModel
 from .phi4flash import Phi4FlashConfig, Phi4FlashModel
 from .keye_vl2 import KeyeVL2Config, KeyeVL2Model
 from .granite_hybrid import GraniteHybridConfig, GraniteHybridModel
+from .xing4 import Xing4Config, Xing4Model
 
 __all__ = ["GPT2Config", "GPT2Model", "BertConfig", "BertModel",
            "GPTMoEConfig", "GPTMoEModel", "Phi4FlashConfig",
            "Phi4FlashModel", "KeyeVL2Config", "KeyeVL2Model",
-           "GraniteHybridConfig", "GraniteHybridModel"]
+           "GraniteHybridConfig", "GraniteHybridModel", "Xing4Config",
+           "Xing4Model"]

@@ -13,8 +13,16 @@ without bias.
               Rotary positions on the ``rope`` dimensions of every query
               head and on the ONE ``k_rope`` a position, which all heads
               share; ``k = [k_nope | k_rope]``, softmax(q k^T / sqrt(nope
-              + rope)) v, causal, through the flash kernels at a head of
-              ``nope + rope`` = ``v_head_dim`` (256 as published).
+              + rope)) v, causal, through the flash kernels at a
+              query/key head of ``nope + rope`` and a value head of
+              ``v_head_dim`` (256 and 256 as published here; 192 on 128
+              in models/xing4.py: the kernels take the two sizes apart).
+              ``rope_scaling`` of type ``yarn``: the rotated dimensions
+              turn at YaRN's blended frequencies (models/laguna.py
+              ``yarn_inv_freq``), cos and sin times ``mscale /
+              mscale_all_dim`` of YaRN's attention factor ``0.1 m
+              ln(factor) + 1``, and the softmax scale times the square
+              of that factor at ``mscale_all_dim``.
               Between the flat products and the kernels' head-major
               operands, where the shape is one ``latent_block`` takes
               (ops/latent_layout.py: heads of whole lane tiles whose
@@ -75,7 +83,7 @@ from ..ops.latent_layout import (heads_to_flat, latent_block, latent_heads,
                                  latent_tables, split_kv_columns)
 from ..ops.normalize import rms_norm
 from .laguna import (EMBEDDING_STD, ExpertStack, apply_rotary, gated_ffn,
-                     rotary_table)
+                     rotary_table, yarn_inv_freq)
 from .layer_stack import run_layer_stack
 
 IGNORE = -1          # the label of a position that has no target
@@ -95,6 +103,10 @@ class Glm4MoeLiteConfig:
     qk_rope_head_dim: int = 64
     v_head_dim: int = 256
     rope_theta: float = 1000000.0
+    # None, or the released ``rope_scaling`` of type "yarn": factor,
+    # original_max_position_embeddings, beta_fast, beta_slow, mscale,
+    # mscale_all_dim
+    rope_scaling: Optional[dict] = None
     rms_norm_eps: float = 1e-5
     first_k_dense_replace: int = 1
     n_routed_experts: int = 64
@@ -113,12 +125,10 @@ class Glm4MoeLiteConfig:
     activation_checkpointing: bool = False
 
     def __post_init__(self):
-        if self.qk_nope_head_dim + self.qk_rope_head_dim != self.v_head_dim:
+        if self.rope_scaling is not None and self.rope_scaling.get(
+                "type", self.rope_scaling.get("rope_type")) != "yarn":
             raise NotImplementedError(
-                "query/key heads of "
-                f"{self.qk_nope_head_dim + self.qk_rope_head_dim} and "
-                f"value heads of {self.v_head_dim}: the flash kernels take "
-                "one head size for q, k and v")
+                f"rope_scaling {self.rope_scaling}: none or yarn")
         if self.num_nextn_predict_layers not in (0, 1):
             raise NotImplementedError("one prediction module at most")
         if self.qk_rope_head_dim % 2:
@@ -134,6 +144,34 @@ class Glm4MoeLiteConfig:
     @property
     def head_dim(self):
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    def _yarn_factor(self, mscale):
+        """YaRN's attention factor ``0.1 mscale ln(factor) + 1``."""
+        factor = self.rope_scaling["factor"]
+        return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+    def rotary_frequencies(self):
+        """(the ``rope / 2`` frequencies, what cos and sin are times)."""
+        rope, scaling = self.qk_rope_head_dim, self.rope_scaling
+        if scaling is None:
+            return self.rope_theta ** (
+                -2.0 * jnp.arange(rope // 2, dtype=jnp.float32) / rope), 1.0
+        return (yarn_inv_freq(
+            rope, self.rope_theta, scaling["factor"],
+            scaling["original_max_position_embeddings"],
+            scaling["beta_fast"], scaling["beta_slow"]),
+            self._yarn_factor(scaling.get("mscale", 1))
+            / self._yarn_factor(scaling.get("mscale_all_dim", 0)))
+
+    @property
+    def softmax_scale(self):
+        """1 / sqrt(nope + rope), under YaRN times the square of its
+        attention factor at ``mscale_all_dim``."""
+        scale = 1.0 / math.sqrt(self.head_dim)
+        if self.rope_scaling is not None:
+            scale *= self._yarn_factor(
+                self.rope_scaling.get("mscale_all_dim", 0)) ** 2
+        return scale
 
     def groups(self):
         """Runs of like layers of the stack: [(name, sparse, first index,
@@ -153,6 +191,9 @@ class Glm4MoeLiteModel(ExpertStack):
     # the scalars of ``__call__``'s dict that the engine sums on the
     # device for whoever reads ``engine.model_counters()``
     aux_counters = (R.M_MAIN_LOSS, R.M_MTP_LOSS, R.M_LOAD_MAX_OVER_MEAN)
+    # even shares of the picks the experts' row buffers hold
+    # (``DroplessMoE.dispatch_headroom``; models/xing4.py takes two)
+    dispatch_headroom = 1.0
 
     def __init__(self, config: Glm4MoeLiteConfig):
         super().__init__(config, DroplessMoE(
@@ -166,7 +207,8 @@ class Glm4MoeLiteModel(ExpertStack):
             # on one rank of eight the held experts get no pick in some
             # steps and a stray one in others once the routers have
             # learnt the absent experts (PERF.md section 6, PR 42)
-            first_chunk_always=True))
+            first_chunk_always=True,
+            dispatch_headroom=self.dispatch_headroom))
 
     # -- parameters ---------------------------------------------------- #
     def _init_layer(self, rng, sparse):
@@ -295,9 +337,10 @@ class Glm4MoeLiteModel(ExpertStack):
                  else ("latent", "xla")),)
 
     def _heads(self, p, cq, ckv, k_rope, table, kernels):
-        """q, k, v ``[B, H, S, 256]`` from the two latents and the one
-        key a position; ``table`` (cos, sin) per lane of a head's last
-        tile where the ``kernels`` run, else ``rotary_table``'s."""
+        """q, k ``[B, H, S, nope + rope]`` and v ``[B, H, S, v_head_dim]``
+        from the two latents and the one key a position; ``table``
+        (cos, sin) per lane of a head's last tile where the ``kernels``
+        run, else ``rotary_table``'s."""
         cfg = self.config
         batch, seq, _ = cq.shape
         heads = cfg.num_attention_heads
@@ -347,7 +390,7 @@ class Glm4MoeLiteModel(ExpertStack):
             q, k, v = self._heads(p, cq, ckv, k_rope, table, kernels)
             with jax.named_scope("attn_core"):
                 a = flash_attention(q, k, v, causal=True,
-                                    sm_scale=1.0 / math.sqrt(cfg.head_dim))
+                                    sm_scale=cfg.softmax_scale)
             with jax.named_scope("attn_layout"):
                 if kernels:
                     a = heads_to_flat(a)
@@ -394,9 +437,7 @@ class Glm4MoeLiteModel(ExpertStack):
         then of the prediction module's block, each body ``(carry, xs) ->
         (carry, keep(routing) or None)``."""
         cfg = self.config
-        rope = cfg.qk_rope_head_dim
-        table = rotary_table(seq, cfg.rope_theta ** (
-            -2.0 * jnp.arange(rope // 2, dtype=jnp.float32) / rope))
+        table = rotary_table(seq, *cfg.rotary_frequencies())
         if self.latent_block(seq) is not None:
             table = latent_tables(*table)
         stacks = [(params[name], sparse, count)
@@ -414,10 +455,31 @@ class Glm4MoeLiteModel(ExpertStack):
                 p, forced_picks = xs
                 out, routing = self._layer(p, carry, sparse, table,
                                            forced_picks)
-                return out, keep(routing) if sparse else None
+                return out, self._kept(routing, keep)
 
             bodies.append((body, (stacked, forced), sparse, count))
         return bodies
+
+    # What a family with another residual path gives of its own
+    # (models/xing4.py: several streams, and each sublayer's mixes beside
+    # the routing): here one stream, and the routing alone.
+    def _carry_in(self, h):
+        """The stack's carry from the one stream ``h`` [B, S, hidden]."""
+        return h
+
+    def _carry_out(self, carry):
+        """The one stream [B, S, hidden] a head reads, from the carry."""
+        return carry
+
+    def _kept(self, routing, keep):
+        """What a body hands the scan of its layer's ``routing`` (None:
+        a dense layer's)."""
+        return None if routing is None else keep(routing)
+
+    def _gather(self, kept):
+        """The groups' stacked keeps as one, in layer order."""
+        return (jax.tree.map(lambda *a: jnp.concatenate(a), *kept)
+                if kept else None)
 
     def _head_loss(self, params, h, labels):
         cfg = self.config
@@ -435,7 +497,7 @@ class Glm4MoeLiteModel(ExpertStack):
         eps = cfg.rms_norm_eps
         wte = params["wte"].astype(cfg.dtype)
         with jax.named_scope("embed"):
-            h = wte[input_ids]
+            h = self._carry_in(wte[input_ids])
         seq = input_ids.shape[1]
         bodies = self._bodies(params, seq, picks, keep)
         wrap = self._layer_wrapper(bodies, h, {
@@ -446,8 +508,9 @@ class Glm4MoeLiteModel(ExpertStack):
             # a group of several layers is scanned: one traced body
             h, ys = run_layer_stack(wrap(body), h, xs, count > 1,
                                     with_ys=True)
-            if sparse:
+            if ys is not None:    # a dense layer of one stream keeps none
                 kept.append(ys)
+        h = self._carry_out(h)
 
         def shifted(by):
             ahead = jnp.roll(input_ids, -by, axis=1)
@@ -472,14 +535,14 @@ class Glm4MoeLiteModel(ExpertStack):
                 z = (rms_norm(ahead, p["enorm"], eps) @ proj[:hid]
                      + rms_norm(h, p["hnorm"], eps) @ proj[hid:])
                 body, xs, _, _ = module
-                z, ys = run_layer_stack(wrap(body), z, xs, False,
-                                        with_ys=True)
+                z, ys = run_layer_stack(wrap(body), self._carry_in(z), xs,
+                                        False, with_ys=True)
+                z = self._carry_out(z)
                 kept.append(ys)
                 with jax.named_scope("head"):
                     z = rms_norm(z, p["norm"], eps)
                 mtp = self._head_loss(params, z, shifted(2))
-        return (main, mtp), (jax.tree.map(
-            lambda *a: jnp.concatenate(a), *kept) if kept else None)
+        return (main, mtp), self._gather(kept)
 
     def _objective(self, params, input_ids, labels=None, picks=None):
         """(L, the counters of ``aux_counters``: L_main, L_mtp and the
@@ -490,6 +553,11 @@ class Glm4MoeLiteModel(ExpertStack):
         picks an expert, which the selection biases are moved by."""
         (main, mtp), stats = self._run(params, input_ids, picks,
                                        self.moe.stats, labels)
+        return self._counted(main, mtp, stats)
+
+    def _counted(self, main, mtp, stats):
+        """``_objective``'s pair from the two terms and the gates'
+        stacked RoutingStats (None: no gate)."""
         counters = {R.M_MAIN_LOSS: main, R.M_MTP_LOSS: mtp,
                     R.M_LOAD_MAX_OVER_MEAN: jnp.float32(0.0)}
         if stats is not None:

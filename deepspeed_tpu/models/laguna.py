@@ -138,6 +138,10 @@ class ExpertStack:
     casts_own_weights = ("the routed experts' grad program, which the v5e "
                          "compiler schedules worse on bf16 parameters")
 
+    # residual streams of the stack's carry: 1 is [batch, sequence,
+    # hidden]; more (models/xing4.py) [batch, streams, sequence, hidden]
+    carry_streams = 1
+
     def __init__(self, config, moe):
         self.config = config
         self.moe = moe
@@ -161,14 +165,15 @@ class ExpertStack:
         FFN's row buffers and goes to the budget's log line, or is logged
         here, once, where no budget will."""
         cfg = self.config
-        tokens = h.shape[0] * h.shape[1]
+        tokens = h.shape[0] * h.shape[-2]
         plan = {**plan, R.M_STACK_DISPATCH_ROWS: self.moe.capacity(tokens)}
         if cfg.activation_checkpointing:
             wrap = checkpoint_layers(
                 [group[:2] for group in groups],
                 self._remat_budget, h, cfg.vocab_size, plan,
                 extra_working_set=self.moe.working_set_bytes(
-                    tokens, h.dtype.itemsize))
+                    tokens, h.dtype.itemsize),
+                streams=self.carry_streams)
         else:
             def wrap(body):
                 return body

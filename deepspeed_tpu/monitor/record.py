@@ -159,6 +159,10 @@ M_STACK_INDEXER = "stack_indexer"
 # saves, the runs of like layers ("mamba x5, attention, mamba x4"),
 # "scanned" or "unrolled"]
 M_STACK_SSD = "stack_ssd"
+# (models/xing4.py) a residual path of several streams mixed by
+# hyper-connections (ops/hyper_connection.py): [streams, Sinkhorn rounds,
+# the clamp's two ends]
+M_STACK_STREAMS = "stack_streams"
 
 # ---- fleet field names (fleet.py / health.py payloads) --------------- #
 FL_WINDOW_START = "window_start_step"
@@ -239,6 +243,15 @@ M_EXIT_MASS = "exit_mass_"
 # pairs (min(t + 1, topk) summed over t, over S (S + 1) / 2)
 M_INDEX_LOSS = "index_loss"
 M_KEPT_SHARE = "kept_share"
+# (models/xing4.py) the hyper-connections' mixes over a step's tokens
+# and sublayers, averaged over micro-batches: the worst |row sum - 1|
+# and |column sum - 1| of H_res after its Sinkhorn rounds (columns are
+# normalised last), the means of H_pre (1 / streams at the start) and
+# of H_post (1 at the start)
+M_HC_ROW_ERR = "hc_res_row_err_max"
+M_HC_COL_ERR = "hc_res_col_err_max"
+M_HC_PRE_MEAN = "hc_pre_mean"
+M_HC_POST_MEAN = "hc_post_mean"
 
 # ---- reconciliation field names (reconcile.py payload) --------------- #
 R_WINDOW_START = "window_start_step"

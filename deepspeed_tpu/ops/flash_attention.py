@@ -9,6 +9,9 @@ runs in one Pallas kernel, streaming K/V blocks through VMEM with fp32
 accumulators (flash-attention style).  The MXU sees two big matmuls per block
 pair; HBM traffic is O(S*d) instead of O(S^2).
 
+q and k share a head size and v may have another: the output takes
+v's (``d_v`` in the two calls below).
+
 Backward is the FlashAttention-2 scheme: forward saves only the per-row
 logsumexp; ONE Pallas kernel (_fa_bwd_kernel, `flash_bwd_dkdv` in a
 trace) recomputes P block-wise, each tile once, and makes dk, dv and dq
@@ -835,6 +838,7 @@ def _flash_fwd_call(q, k, v, dropout_seed, *, causal, sm_scale, block_q,
     """flash_attention_pallas, traced once a process and shape."""
     batch, heads, q_len, d = _dims(q, layout)
     kv_heads, k_len = _dims(k, layout)[1:3]
+    d_v = _dims(v, layout)[3]   # the values' own head size (and out's)
     group = _kv_group(heads, kv_heads)
     _check_window(window, causal, dropout_rate)
     if layout == "bshd":
@@ -866,18 +870,19 @@ def _flash_fwd_call(q, k, v, dropout_seed, *, causal, sm_scale, block_q,
     scratch = [] if steps == 1 else [
         pltpu.VMEM((1, block_q), jnp.float32),   # running max
         pltpu.VMEM((1, block_q), jnp.float32),   # running sum
-        pltpu.VMEM((d, block_q), jnp.float32),   # output accumulator
+        pltpu.VMEM((d_v, block_q), jnp.float32),   # output accumulator
     ]
-    kv_spec = _tile_spec(block_k, d, "j", group,
-                         band.k_block if band else None)
+    k_spec, v_spec = (_tile_spec(block_k, width, "j", group,
+                                 band.k_block if band else None)
+                      for width in (d, d_v))
 
     out_specs = [
-        _tile_spec(block_q, d, "i"),
+        _tile_spec(block_q, d_v, "i"),
         pl.BlockSpec((1, 1, block_q, _STATS_LANES),
                      lambda b, h, i, j, *_: (b, h, i, 0)),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct(q.shape, q.dtype),
+        jax.ShapeDtypeStruct((batch, heads, q_len, d_v), q.dtype),
         jax.ShapeDtypeStruct((batch, heads, q_len, _STATS_LANES),
                              jnp.float32),
     ]
@@ -891,7 +896,7 @@ def _flash_fwd_call(q, k, v, dropout_seed, *, causal, sm_scale, block_q,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(batch, heads, nq, steps),
-            in_specs=[_tile_spec(block_q, d, "i"), kv_spec, kv_spec],
+            in_specs=[_tile_spec(block_q, d, "i"), k_spec, v_spec],
             out_specs=out_specs,
             scratch_shapes=scratch),
         out_shape=out_shape,
@@ -916,33 +921,38 @@ _VMEM_LIMIT = 100 * 1024 * 1024
 _BWD_TILE_TEMPS = 5
 
 
-def _bwd_vmem(span_rows, d, block_q, block_k, itemsize):
+def _bwd_vmem(span_rows, d, block_q, block_k, itemsize, d_v=None):
     """The VMEM `flash_bwd_dkdv` asks for, in bytes and from above: what
     grows with the sequence (dq of a span of q rows: float32 scratch and
     its output, double-buffered), a key block's share (dk and dv alike,
     and k and v double-buffered), a q block's (q and dO, and the two row
     statistics at a lane tile a row, double-buffered) and the step's
-    temporaries.  A head narrower than a lane tile still takes one.  On
+    temporaries.  A head narrower than a lane tile still takes one;
+    ``d_v`` is the values' head size where it is not ``d`` (dv, v and dO
+    are that wide, dq, dk, q and k ``d``).  On
     two-byte operands in blocks of 512 x 1024: 15.5 MiB at 1,024 x 64
     where the v5e's compiler counts 6.1, 22.5 at 8,192 x 128 (15.0), 34
     at 8,192 x 256 (28.3), 82 at 32,768 x 256 (my compiles for the v5e,
     PR 52)."""
     lanes = -(-d // _LANES) * _LANES
+    # dv, v and dO at the values' head size, where it is another
+    both = lanes + (lanes if d_v is None else -(-d_v // _LANES) * _LANES)
     resident = span_rows * lanes * (4 + 2 * itemsize)
-    keys = 2 * block_k * lanes * (4 + 2 * itemsize + 2 * itemsize)
-    rows = 2 * 2 * block_q * (lanes * itemsize + _LANES * 4)
+    keys = block_k * both * (4 + 2 * itemsize + 2 * itemsize)
+    rows = 2 * block_q * (both * itemsize + 2 * _LANES * 4)
     tile = _BWD_TILE_TEMPS * 4 * block_q * block_k
     return resident + keys + rows + tile
 
 
-def _bwd_spans(q_len, d, block_q, block_k, itemsize):
+def _bwd_spans(q_len, d, block_q, block_k, itemsize, d_v=None):
     """Spans of q rows the backward call walks: the fewest whole shares
     of the q blocks whose dq fits (_bwd_vmem under _VMEM_LIMIT).  One at
     every shape a cell or a test runs; from the shape alone."""
     nq = q_len // block_q
     for spans in range(1, nq):
         if nq % spans == 0 and _bwd_vmem(
-                q_len // spans, d, block_q, block_k, itemsize) <= _VMEM_LIMIT:
+                q_len // spans, d, block_q, block_k, itemsize,
+                d_v) <= _VMEM_LIMIT:
             return spans
     return nq
 
@@ -1090,6 +1100,7 @@ def _flash_bwd_call(q, k, v, out, lse, do, dropout_seed, *, causal,
     with the group's."""
     batch, heads, q_len, d = _dims(q, layout)
     kv_heads, k_len = _dims(k, layout)[1:3]
+    d_v = _dims(v, layout)[3]   # of v, out, dO and dv
     group = _kv_group(heads, kv_heads)
     _check_window(window, causal, dropout_rate)
     if layout == "bshd":
@@ -1125,7 +1136,7 @@ def _flash_bwd_call(q, k, v, out, lse, do, dropout_seed, *, causal,
                                              nq, nk)
     walk = ({} if band else
             _causal_walk(q_len, k_len, block_q, block_k, causal, True))
-    spans = _bwd_spans(q_len, d, block_q, block_k, q.dtype.itemsize)
+    spans = _bwd_spans(q_len, d, block_q, block_k, q.dtype.itemsize, d_v)
     span_rows = q_len // spans
     # inner steps: the q blocks of the band, or of the span
     steps = band.steps_q if band is not None and spans == 1 else nq // spans
@@ -1136,7 +1147,7 @@ def _flash_bwd_call(q, k, v, out, lse, do, dropout_seed, *, causal,
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary"),
             vmem_limit_bytes=max(_VMEM_DEFAULT, _bwd_vmem(
-                span_rows, d, block_q, block_k, q.dtype.itemsize)))
+                span_rows, d, block_q, block_k, q.dtype.itemsize, d_v)))
 
     # the index maps: grid dim 2 walks the key blocks (once a span), grid
     # dim 3 the inner steps; the trailing *_ absorbs the dropout seed's ref
@@ -1155,12 +1166,15 @@ def _flash_bwd_call(q, k, v, out, lse, do, dropout_seed, *, causal,
         return pl.BlockSpec((1, 1, block_q, width), lambda b, h, t, j, *_: (
             b, h, q_block(t, j), 0))
 
-    kv_spec = pl.BlockSpec((1, 1, block_k, d), lambda b, h, t, j, *_: (
-        b, h // group if group > 1 else h, key_block(t), 0))
+    def kv_spec(width):
+        return pl.BlockSpec((1, 1, block_k, width), lambda b, h, t, j, *_: (
+            b, h // group if group > 1 else h, key_block(t), 0))
+
     # one dk and dv a query head, and a span: a partial's batch index is
     # span * batch + b
-    part_spec = pl.BlockSpec((1, 1, block_k, d), lambda b, h, t, j, *_: (
-        span_of(t) * batch + b, h, key_block(t), 0))
+    def part_spec(width):
+        return pl.BlockSpec((1, 1, block_k, width), lambda b, h, t, j, *_: (
+            span_of(t) * batch + b, h, key_block(t), 0))
     # the resident output: written at the span's last step of the head
     dq_spec = pl.BlockSpec((1, 1, span_rows, d),
                            lambda b, h, t, j, *_: (b, h, span_of(t), 0))
@@ -1174,18 +1188,19 @@ def _flash_bwd_call(q, k, v, out, lse, do, dropout_seed, *, causal,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(batch, heads, spans * nk, steps),
-            in_specs=[q_spec(d), kv_spec, kv_spec, q_spec(d),
+            in_specs=[q_spec(d), kv_spec(d), kv_spec(d_v), q_spec(d_v),
                       q_spec(_STATS_LANES), q_spec(_STATS_LANES)],
-            out_specs=[dq_spec, part_spec, part_spec],
+            out_specs=[dq_spec, part_spec(d), part_spec(d_v)],
             scratch_shapes=[
                 pltpu.VMEM((span_rows, d), jnp.float32),
                 pltpu.VMEM((block_k, d), jnp.float32),
-                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, d_v), jnp.float32),
             ]),
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct((spans * batch, heads, k_len, d), k.dtype),
-            jax.ShapeDtypeStruct((spans * batch, heads, k_len, d), v.dtype),
+            jax.ShapeDtypeStruct((spans * batch, heads, k_len, d_v),
+                                 v.dtype),
         ],
         interpret=interpret,
         name=_kernel_name("flash_bwd_dkdv", band),
@@ -1193,8 +1208,8 @@ def _flash_bwd_call(q, k, v, out, lse, do, dropout_seed, *, causal,
     )(seed, q, k, v, do, lse, delta)
     if spans > 1 or group > 1:
         dk, dv = (t.astype(jnp.float32).reshape(
-            spans, batch, kv_heads, group, k_len, d).sum((0, 3)).astype(
-                t.dtype) for t in (dk, dv))
+            spans, batch, kv_heads, group, k_len, t.shape[-1]).sum(
+                (0, 3)).astype(t.dtype) for t in (dk, dv))
     if layout == "bshd":
         dq, dk, dv = _t_bhsd(dq), _t_bhsd(dk), _t_bhsd(dv)
     return dq, dk, dv
@@ -1352,6 +1367,12 @@ def flash_attention(q, k, v, causal: bool = False,
                     impl: str = "auto", dropout_rate: float = 0.0,
                     dropout_seed=None, window: Optional[int] = None):
     """Fused multi-head attention: q,k,v [B, H, S, D] -> [B, H, S, D].
+
+    v may have a head size of its own, [B, H, S, Dv]: the output (and
+    dO, dv) then has v's, the scores contract over q's and k's (latent
+    attention at 128 + 64 rotated on values of 128: models/xing4.py).
+    Both kernels take the two sizes in their block shapes and scratch,
+    and nothing is padded; at one size they lower as they always did.
 
     k and v may have fewer heads than q (grouped key/value heads: head j
     serves query heads [j * group, (j + 1) * group)), found by index map

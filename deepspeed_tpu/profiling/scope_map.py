@@ -111,7 +111,7 @@ import re
 import weakref
 
 SCOPES = ("embed", "attn", "ssm", "gmu", "mlp", "router", "dispatch",
-          "experts", "shared", "head", "layer")
+          "experts", "shared", "head", "layer", "hc")
 OTHER = "other"
 PHASES = ("forward", "recompute", "backward")
 CAST_SCOPE = "weight_cast"

@@ -50,6 +50,8 @@ from jax import lax
 from ...analysis.jaxpr_walk import sub_jaxprs
 from ...monitor import record as R
 from ...ops.flash_attention import RESIDUAL_NAME as FLASH_RESIDUALS_NAME
+from ...ops.hyper_connection import INPUT_NAME as HC_INPUT_NAME
+from ...ops.hyper_connection import MIX_NAME as HC_MIX_NAME
 from ...utils.logging import log_dist
 from ...parallel.mesh import MODEL_AXIS
 
@@ -163,19 +165,27 @@ def get_partition_policy():
 # 3. (ops/indexed_attention.py) the alignment term's value and its
 #    gradients, then the restricted attention's (out, lse): each spares
 #    the backward pass a second run of its kernel.
+# 4. (ops/hyper_connection.py) a hyper-connection's sublayer input ``u``
+#    = ``H_pre X``, one width a token: last, at the flash residuals'
+#    bytes it spares the recomputation pass one read of the streams.
 FFN_PRODUCT_NAME = "ffn_gate_up"
 RESIDUAL_ORDER = (FLASH_RESIDUALS_NAME, FFN_PRODUCT_NAME, "dsa_align",
-                  "dsa_residuals")
+                  "dsa_residuals", HC_INPUT_NAME)
 # Names kept wherever a layer offers them, with a budget, with none that
 # fits and with none at all (the CPU, a streamed ZeRO-3, a device that
 # reports no limit): a few integers a token, and what a recomputation
 # could not be trusted to find again (moe/dropless.py: a top-k recomputed
 # in other fusions may flip a near tie, and the backward pass must
 # differentiate the forward's picks; ops/indexed_attention.py: the packed
-# keep-set of a learned selection, a bit a pair, for the same reason).  A
+# keep-set of a learned selection, a bit a pair, for the same reason);
+# and what costs as little and spares a pass over the whole carry
+# (ops/hyper_connection.py: the sums of a sublayer's projection to its
+# mixes and the streams' mean square, 25 float32 a token at 4 streams,
+# without which the recomputation pass reads every stream twice more for
+# the norm and the projection).  A
 # body that offers none of them lowers to what ``jax.checkpoint(body)``
 # lowers to (tests/unit/test_remat_policy.py).
-ALWAYS_KEPT = ("routing_picks", "dsa_keep")
+ALWAYS_KEPT = ("routing_picks", "dsa_keep", HC_MIX_NAME)
 _KEEP_ALWAYS = functools.partial(
     jax.checkpoint,
     policy=jax.checkpoint_policies.save_only_these_names(*ALWAYS_KEPT))
@@ -197,7 +207,7 @@ MARGIN_BYTES = 420_000_000
 
 def working_set_bytes(tokens: int, width: int, num_layers: int,
                       head_width: int, itemsize: int,
-                      cast_bytes: int = 0) -> int:
+                      cast_bytes: int = 0, streams: int = 1) -> int:
     """Bytes a device holds in a step beside the engine's state and the
     saved residuals: ``cast_bytes`` (the compute-dtype copy of the
     weights where the grad program casts it; 0 where the engine keeps
@@ -209,9 +219,13 @@ def working_set_bytes(tokens: int, width: int, num_layers: int,
     larger than the measured ones is refused its residuals before it is
     refused its memory.  GPT-2 large at 4,096 tokens: 3,505,530,112, where
     the chip's programs reserved 2.4 to 2.9 GB beside their residuals and
-    0.42 GB was held beside them."""
+    0.42 GB was held beside them.  ``streams``: the residual streams a
+    carry holds (models/xing4.py), each ``width`` wide: the carries cost
+    that many widths a token, the layer's own pass LAYER_WIDTHS of one
+    (its sublayers run on one stream's width)."""
     return (cast_bytes + MARGIN_BYTES + tokens * (
-        (num_layers + LAYER_WIDTHS) * width * itemsize + 4 * head_width))
+        (num_layers * streams + LAYER_WIDTHS) * width * itemsize
+        + 4 * head_width))
 
 
 def _tiled_bytes(shape, dtype) -> int:
@@ -380,6 +394,11 @@ def stack_plan_line(plan: Dict[str, Any]) -> str:
         line += (f"; latent attention: queries through {q}, keys and "
                  f"values through {kv}, {heads} heads of {nope} + {rope} "
                  f"rotated (one rotated key a position) and values of {v}")
+    if R.M_STACK_STREAMS in plan:
+        streams, rounds, low, high = plan[R.M_STACK_STREAMS]
+        line += (f"; {streams} residual streams mixed by hyper-connections "
+                 f"({rounds} Sinkhorn rounds from logits clamped to "
+                 f"[{low:g}, {high:g}])")
     if R.M_STACK_MTP in plan:
         modules, weight = plan[R.M_STACK_MTP]
         line += (f"; {modules} multi-token-prediction module(s), loss "
@@ -410,7 +429,8 @@ def stack_plan_line(plan: Dict[str, Any]) -> str:
 def checkpoint_layers(groups, budget: Optional[RematBudget], carry,
                       head_width: int,
                       stack_plan: Optional[Dict[str, Any]] = None,
-                      extra_working_set: int = 0, passes: int = 1):
+                      extra_working_set: int = 0, passes: int = 1,
+                      streams: int = 1):
     """``checkpoint_layer`` for a model whose stack is several scanned
     groups of unlike layers: ``groups`` is a list of ``(body,
     stacked_xs)``, every body taking the same ``carry``; one budget is
@@ -444,9 +464,9 @@ def checkpoint_layers(groups, budget: Optional[RematBudget], carry,
     working_set = budget.working_set
     if working_set is None:
         working_set = working_set_bytes(
-            math.prod(carry.shape[:-1]) // budget.batch_shards,
+            math.prod(carry.shape[:-1]) // streams // budget.batch_shards,
             carry.shape[-1], num_layers, head_width, carry.dtype.itemsize,
-            budget.cast_bytes)
+            budget.cast_bytes, streams)
     working_set += extra_working_set
     kept = saved_residual_names(offered, 1, budget.bytes(working_set))
     by_name = tuple((name, offered[name]) for name in kept)
