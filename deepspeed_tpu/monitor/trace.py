@@ -147,6 +147,15 @@ def phase(of: str, name: Optional[str], **ids) -> None:
             return
 
 
+def mark(name: str, **ids) -> None:
+    """A point of the collector, ``(ds.<name>, now, now, ids)``: what
+    the program counted while it was traced (the streamed stack's
+    ``zero3.grad_wire``), under the span open at that moment.  No
+    profiler event: there is no stretch of time to show."""
+    now = time.perf_counter_ns()
+    _closed.append((SPAN_PREFIX + name, now, now, ids))
+
+
 def last_span() -> tuple:
     """The span that closed last."""
     return _closed[-1]

@@ -35,13 +35,17 @@ Two implementation layers:
      consumer/producer is an arbitrary model body rather than one GEMM
      we control: ``fcm_all_gather`` (drop-in for
      ``low_bandwidth_all_gather`` / ``_all_gather_f32grad``) and
-     ``fcm_reduce_scatter`` (drop-in for ``quantized_psum_scatter`` /
-     ``f32_psum_scatter``) realize the same per-tile schedule at program
-     granularity: W-1 independent quantize -> ppermute -> dequant tile
-     chains replace the monolithic collective, giving the scheduler
-     tile-level freedom and the Schedule Auditor a statically-checkable
-     property.  Enabled via ``zero_optimization.low_bandwidth.
-     fused_collective_matmul`` (docs/fused_collective_matmul.md).
+     ``fcm_reduce_scatter`` (drop-in for ``quantized_psum_scatter``)
+     realize the same per-tile schedule at program granularity: W-1
+     independent quantize -> ppermute -> dequant tile chains replace the
+     monolithic collective, giving the scheduler tile-level freedom and
+     the Schedule Auditor a statically-checkable property.  Enabled via
+     ``zero_optimization.low_bandwidth.fused_collective_matmul``
+     (docs/fused_collective_matmul.md).  The DENSE scatter
+     (``fcm_reduce_scatter(bits=0)``) is ``comm/low_bandwidth.
+     dense_psum_scatter``, which the streamed stack takes with or
+     without the knob: that transport, shifted permutes of whole chunks,
+     has run on the chip (four v5e chips, PERF.md section 6, PR 59).
 
 Every transport here traces under ``jax.named_scope(constants.FCM_SCOPE)``
 — the Schedule Auditor's overlap classifier (analysis/overlap.py) reads
@@ -62,11 +66,12 @@ Numerics contract (pinned by tests/unit/test_collective_matmul.py):
   - the error-feedback residual is computed from the same compensated
     quantization (``new_error = (x + error) - deq(quant(x + error))``).
 
-The qgz_bits=0 fallback reduces through the same per-tile table in fp32
-(promote half -> accumulate fp32 -> demote), which matches
-``f32_psum_scatter``'s accumulation DTYPE but fixes the accumulation
-ORDER (shard-index) where ``lax.psum_scatter`` leaves it to XLA — equal
-up to fp reassociation, exactly equal when qgZ is on.
+The qgz_bits=0 fallback (``dense_psum_scatter``) moves the chunks in
+the gradient's own dtype and accumulates what arrives in fp32 (widen ->
+accumulate fp32 -> narrow once), which matches ``f32_psum_scatter``'s
+accumulation DTYPE but fixes the accumulation ORDER (shard-index) where
+``lax.psum_scatter`` leaves it to XLA — equal up to fp reassociation,
+exactly equal when qgZ is on.
 """
 
 import functools
@@ -83,7 +88,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .. import constants as C
 from ..runtime.comm.low_bandwidth import (DEFAULT_BLOCK, blockwise_dequantize,
-                                          blockwise_quantize)
+                                          blockwise_quantize,
+                                          dense_psum_scatter)
 
 FCM_SCOPE = C.FCM_SCOPE
 
@@ -246,14 +252,12 @@ def _fcm_gather_impl(x, axes, dim, bits, block):
 
 
 def _fcm_scatter_one_axis(x, axis_name, dim, bits, block):
-    """One axis of the fused scatter: split into per-owner chunks,
-    quantize the compensated chunk table (per-chunk scales — identical
-    to the modular qgZ quantization), move each tile in a ring-scheduled
-    all-to-all round, dequantize the received source table and reduce in
-    shard-index order (``jnp.sum(axis=0)`` — the modular accumulation
-    contract, bitwise).  bits=0 moves native chunks promoted to fp32
-    (the ``f32_psum_scatter`` dtype contract with a FIXED shard-index
-    accumulation order)."""
+    """One axis of the fused QUANTIZED scatter: split into per-owner
+    chunks, quantize the compensated chunk table (per-chunk scales —
+    identical to the modular qgZ quantization), move each tile in a
+    ring-scheduled all-to-all round, dequantize the received source
+    table and reduce in shard-index order (``jnp.sum(axis=0)`` — the
+    modular accumulation contract, bitwise)."""
     world = lax.axis_size(axis_name)
     xt = jnp.moveaxis(x, dim, 0)
     m = xt.shape[0]
@@ -269,11 +273,17 @@ def _fcm_scatter_one_axis(x, axis_name, dim, bits, block):
 
 def fcm_reduce_scatter(x, axes, dim, bits: int = 0,
                        block: int = DEFAULT_BLOCK):
-    """Per-tile drop-in for ``quantized_psum_scatter`` (bits=4/8) and
-    ``f32_psum_scatter`` (bits=0): the backward GEMM's gradient leaves
-    as per-owner tiles on a ring-scheduled all-to-all instead of one
-    monolithic collective.  Multiple axes reduce sequentially in tuple
-    order, matching the modular path's staging."""
+    """Per-tile drop-in for ``quantized_psum_scatter`` (bits=4/8): the
+    backward GEMM's gradient leaves as per-owner tiles on a
+    ring-scheduled all-to-all instead of one monolithic collective.
+    Multiple axes reduce sequentially in tuple order, matching the
+    modular path's staging.  ``bits=0`` is ``dense_psum_scatter``, the
+    streamed stack's own dense transpose whether or not the knob is
+    set: the same shifted permutes, the chunks in the gradient's dtype
+    (one axis: bit for bit what the fp32 table of this function's
+    first form summed, since widening commutes with the wire)."""
+    if not bits:
+        return dense_psum_scatter(x, axes, dim)
     axes = _axes_tuple(axes)
     with _fcm_scope():
         for ax in axes:

@@ -28,10 +28,19 @@ with optional sub-blocks over the remaining flattened elements so a block
 never straddles a shard boundary along the gathered dimension (tiled
 all-gathers and chunked reduce-scatters stay self-describing).
 
-Wire-width note: these pay off when the gather/reduce crosses the SLOW
-mesh dimension (DCN between slices, or the long ICI axis); on a short
-intra-slice axis the dense fp32 collective is usually already overlapped
-behind compute (same honesty stance as comm/compressed.py).
+The dense transpose they fall back to is here too
+(``dense_psum_scatter``): a gradient reduce-scatter that leaves as
+shifted collective-permutes of whole chunks in the gradient's own dtype,
+summed in fp32 on arrival, because the TPU's compiler overlaps a permute
+with compute and a reduce-scatter with nothing.
+
+Wire-width note: qwZ/qgZ pay off when the gather/reduce crosses the SLOW
+mesh dimension (DCN between slices, or the long ICI axis).  On a short
+intra-slice axis measure first: the dense fp32 reduce-scatters of GPT-2
+XL on the four chips of a v5e host were NOT overlapped behind compute
+(95 of a step's 1,064 ms, ledger PR 58), and what cured that was the
+form of the transfer, not its width (PERF.md section 6, PR 59; same
+honesty stance as comm/compressed.py).
 """
 
 from functools import partial
@@ -43,6 +52,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ...constants import FCM_SCOPE
 from ...parallel.mesh import DATA_AXIS
 
 _QMAX = {8: 127, 4: 7}
@@ -179,9 +189,9 @@ def f32_psum_scatter(g, axes, dim):
     reduction and demotes after: cross-shard accumulation happens in fp32
     regardless of compute dtype, and the only reduction collective stays
     out of XLA-CPU's AllReducePromotion pass, which hard-aborts on
-    half-precision reduction collectives.  Shared transpose of both the
-    fp32 gather path (zero/stage3_streaming._all_gather_f32grad) and the
-    qgZ-off quantized gather below."""
+    half-precision reduction collectives.  The native form of
+    :func:`dense_psum_scatter`: what a leaf takes where the permutes are
+    not wanted (``permute_scatter_takes``)."""
     half = (jnp.issubdtype(g.dtype, jnp.floating) and
             jnp.dtype(g.dtype).itemsize < 4)
     if half:
@@ -189,6 +199,111 @@ def f32_psum_scatter(g, axes, dim):
                                  scatter_dimension=dim, tiled=True)
         return shard.astype(g.dtype)
     return lax.psum_scatter(g, axes, scatter_dimension=dim, tiled=True)
+
+
+# --------------------------------------------------------------------- #
+# the dense gradient reduce-scatter: shifted permutes of whole chunks
+# --------------------------------------------------------------------- #
+# A leaf costs W - 1 permutes an axis.  Past this width the native
+# collective's one instruction is kept: the TPU's compiler makes no
+# reduce-scatter asynchronous, but it does not issue 3 x W operations a
+# leaf either.  Measured on four chips only (PERF.md section 6, PR 59).
+PERMUTE_SCATTER_MAX_WORLD = 8
+
+
+def permute_scatter_takes(extent: int, world: int) -> bool:
+    """Whether a scattered dimension of ``extent`` over ``world`` shards
+    leaves as permutes: a width the op count allows and whole chunks."""
+    return 1 < world <= PERMUTE_SCATTER_MAX_WORLD and extent % world == 0
+
+
+def _permute_scatter_one_axis(g, axis_name, dim):
+    """One axis of :func:`dense_psum_scatter` as ``W - 1`` shifted
+    permutes.  Shard ``me`` keeps chunk ``me`` of ``dim`` and sends chunk
+    ``(me + t) % W`` to its owner under the shift-``t`` permutation, in
+    ``g``'s own dtype: only ORIGINAL values travel, and a half value
+    widened before or after the wire is the same fp32 number.  What
+    arrives is widened and summed in SOURCE-index order, which
+    ``lax.select_n`` on the shard's index recovers inside the adding
+    fusion (a roll of the stacked arrivals by a traced amount would copy
+    them all).  Returns the fp32 sum; the caller narrows once."""
+    world = lax.axis_size(axis_name)
+    me = lax.axis_index(axis_name)
+    chunk = g.shape[dim] // world
+    acc_dtype = jnp.promote_types(g.dtype, jnp.float32)
+
+    def owed(t):
+        return lax.dynamic_slice_in_dim(g, ((me + t) % world) * chunk,
+                                        chunk, axis=dim)
+
+    # arrived[t] left shard (me - t) % W
+    arrived = [owed(0)] + [
+        lax.ppermute(owed(t), axis_name,
+                     [(i, (i + t) % world) for i in range(world)])
+        for t in range(1, world)]
+    arrived = [a.astype(acc_dtype) for a in arrived]
+    total = None
+    for source in range(world):
+        term = lax.select_n((me - source) % world, *arrived)
+        total = term if total is None else total + term
+    return total
+
+
+def dense_psum_scatter(g, axes, dim):
+    """Drop-in for ``lax.psum_scatter(g, axes, scatter_dimension=dim,
+    tiled=True)`` with fp32 accumulation: the transpose of every dense
+    streamed gather (``stage3_streaming``'s ``scatter_grads`` and
+    ``_ag_bwd``, ``low_bandwidth_all_gather`` with qgZ off, and
+    ``fcm_reduce_scatter(bits=0)``, which IS this function).
+
+    The TPU's compiler runs a reduce-scatter synchronously, nothing
+    beside it; collective-permutes it starts early and finishes late,
+    compute between.  So each axis that ``permute_scatter_takes`` leaves
+    as shifted permutes of the owners' chunks
+    (``_permute_scatter_one_axis``), traced under
+    ``constants.FCM_SCOPE`` so that the Schedule Auditor prices them as
+    the hidden transport they are; any other axis keeps the native
+    collective on an fp32 wire.  Several axes reduce one after another
+    in tuple order (the joint tiled collective's axis-major chunks);
+    partial sums stay fp32 between them and the result is narrowed to
+    ``g``'s dtype ONCE."""
+    axes = (axes,) if isinstance(axes, str) else tuple(axes)
+    if not any(permute_scatter_takes(g.shape[dim], lax.axis_size(a))
+               for a in axes):
+        return f32_psum_scatter(g, axes, dim)
+    acc = g
+    for ax in axes:
+        if permute_scatter_takes(acc.shape[dim], lax.axis_size(ax)):
+            with jax.named_scope(FCM_SCOPE):
+                acc = _permute_scatter_one_axis(acc, ax, dim)
+        else:
+            acc = lax.psum_scatter(
+                acc.astype(jnp.promote_types(g.dtype, jnp.float32)), ax,
+                scatter_dimension=dim, tiled=True)
+    return acc.astype(g.dtype)
+
+
+def dense_scatter_wire(shape, dtype, dim, worlds):
+    """What :func:`dense_psum_scatter` puts on the wire for an array of
+    ``shape`` / ``dtype`` scattered along ``dim`` over axes of sizes
+    ``worlds``, from the shapes alone: ``(form, bytes)`` with ``form``
+    ``"permute"`` (every axis left as permutes) or ``"native"`` (some
+    axis kept the collective) and ``bytes`` what one shard sends."""
+    extent = shape[dim]
+    count = int(np.prod(shape))
+    itemsize = jnp.dtype(dtype).itemsize
+    wide = jnp.dtype(jnp.promote_types(dtype, jnp.float32)).itemsize
+    takes = [permute_scatter_takes(extent // int(np.prod(worlds[:i])), w)
+             for i, w in enumerate(worlds)]
+    if not any(takes):
+        world = int(np.prod(worlds))
+        return "native", count * wide * (world - 1) // world
+    sent = 0
+    for w, t in zip(worlds, takes):
+        sent += count * (itemsize if t else wide) * (w - 1) // w
+        count //= w
+        itemsize = wide  # partial sums travel from the second axis on
+    return ("permute" if all(takes) else "native"), sent
 
 
 # --------------------------------------------------------------------- #
@@ -232,7 +347,7 @@ def _lbag_bwd(axes, dim, qwz_bits, qgz_bits, block, _, g):
     if qgz_bits:
         return (quantized_psum_scatter(g, axes, dim, bits=qgz_bits,
                                        block=block),)
-    return (f32_psum_scatter(g, axes, dim),)
+    return (dense_psum_scatter(g, axes, dim),)
 
 
 low_bandwidth_all_gather.defvjp(_lbag_fwd, _lbag_bwd)

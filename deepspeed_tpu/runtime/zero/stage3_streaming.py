@@ -31,10 +31,18 @@ over the ZeRO ("data","expert") axes:
     needing a trace (the scan order IS the trace).  A bucket under one
     layer group (0) gathers each group at use instead;
   - the backward of a tiled all-gather over the ZeRO axes is a
-    psum-scatter — run in fp32 regardless of compute dtype
-    (_all_gather_f32grad): layer gradients leave the region already
-    reduce-scattered to their owner shard with fp32 accumulation
-    (stage3.py:1908 grad partitioning, tightened).
+    reduce-scatter with fp32 accumulation regardless of compute dtype
+    (stage3.py:1908 grad partitioning, tightened).  Its dense form
+    (``comm/low_bandwidth.dense_psum_scatter``) leaves as ``W - 1``
+    shifted collective-permutes of the owners' chunks IN THE GRADIENT'S
+    OWN DTYPE, widened and summed in source order on arrival: the TPU's
+    compiler runs a reduce-scatter synchronously with nothing beside it
+    (95 ms of GPT-2 XL's 1,064 ms step on four chips) and permutes
+    asynchronously.  Under the carried stream a group's stacks are
+    scattered at the top of the NEXT group's backward and land under it
+    (``_build_carried_stream``); gathering at use, the gather's own
+    transpose (``_ag_bwd``) sends a group's inside the scan's transposed
+    body.
 
 Tensor-parallel ("model") and any other non-ZeRO axes stay *automatic*
 (GSPMD) inside the region — explicit ZeRO streaming composes with
@@ -69,8 +77,10 @@ from jax.sharding import PartitionSpec
 
 from ...ops.collective_matmul import fcm_all_gather, fcm_reduce_scatter
 from ...parallel.mesh import MeshContext, ZERO_AXES
+from ...monitor import trace as host_trace
 from ...utils.logging import log_dist
-from ..comm.low_bandwidth import (f32_psum_scatter, largest_divisor_at_most,
+from ..comm.low_bandwidth import (dense_psum_scatter, dense_scatter_wire,
+                                  largest_divisor_at_most,
                                   low_bandwidth_all_gather,
                                   quantized_gather_saves_bytes,
                                   quantized_psum_scatter)
@@ -204,18 +214,21 @@ def _gather_dims(spec: PartitionSpec, manual: frozenset):
 
 @partial(jax.custom_vjp, nondiff_argnums=(1, 2))
 def _all_gather_f32grad(x, axes, dim):
-    """Tiled all-gather whose transpose reduce-scatters in float32.
+    """Tiled all-gather whose transpose accumulates in float32.
 
     Forward: identical to ``lax.all_gather(tiled=True)`` — shards move at
-    their native width (bf16 gathers cost bf16 bytes).  Backward: the layer
-    gradient is promoted to fp32 BEFORE the ``psum_scatter`` and demoted
-    back after, so the cross-shard gradient reduction accumulates in fp32
-    regardless of compute dtype (the reference reduces fp16 grads natively,
-    stage3.py:1908; fp32 accumulation strictly tightens that).  This also
-    keeps the manual region's only reduction collective out of XLA-CPU's
-    AllReducePromotion pass, which hard-aborts on half-precision reduction
-    collectives ('Invalid binary instruction opcode copy') — bf16 streaming
-    now runs identically on CPU and TPU."""
+    their native width (bf16 gathers cost bf16 bytes).  Backward:
+    ``dense_psum_scatter``: the owners' chunks of the layer gradient
+    travel as shifted permutes at the gradient's width and are summed in
+    fp32 on arrival (the native collective, where a leaf keeps it, is
+    promoted to fp32 BEFORE the ``psum_scatter`` and demoted after), so
+    the cross-shard gradient reduction accumulates in fp32 regardless of
+    compute dtype (the reference reduces fp16 grads natively,
+    stage3.py:1908; fp32 accumulation strictly tightens that).  Either
+    form keeps half-precision reduction collectives, on which XLA-CPU's
+    AllReducePromotion pass hard-aborts ('Invalid binary instruction
+    opcode copy'), out of the manual region — bf16 streaming runs
+    identically on CPU and TPU."""
     return lax.all_gather(x, axes, axis=dim, tiled=True)
 
 
@@ -224,7 +237,7 @@ def _ag_fwd(x, axes, dim):
 
 
 def _ag_bwd(axes, dim, _, g):
-    return (f32_psum_scatter(g, axes, dim),)
+    return (dense_psum_scatter(g, axes, dim),)
 
 
 _all_gather_f32grad.defvjp(_ag_fwd, _ag_bwd)
@@ -329,11 +342,25 @@ def _build_carried_stream(steps: int, g: int, gather_group, run_layer,
     time).  Peak gathered memory stays at ``2 x layers_per_step x
     params_per_layer``.
 
+    Where the gradients leave.  ``scatter_grads(g_full) -> g_shards`` is
+    the exact transpose of ``gather_group``'s wire (qwZ/qgZ aware) for a
+    group's ``[g, ...]`` stacks.  It is issued ONE GROUP LATE: group
+    ``i+1``'s stacks are scattered at the top of group ``i``'s backward
+    and what they bring is held by ``_finish_rows``' barrier after that
+    group's last layer, so a dense leaf's shifted permutes
+    (``dense_psum_scatter``) have sixteen layers of matmuls to travel
+    under.  Issued after its own group and held by nothing, every
+    group's scatter sinks to the end of the program, nothing beside it
+    (where the parent's synchronous reduce-scatters sat); spread over a
+    whole backward they stall the prefetch's all-gathers, which share
+    the links (PERF.md section 6, PR 59: four placements on the chip).
+    Group 0's own scatter is the tail: only the embeddings' gradients
+    are left to run beside it.
+
     ``steps`` must be >= 2 (a plan that prefetches guarantees it).
     ``gather_group(shards) -> full``, ``run_layer(act, layer_leaves,
     layer_extras) -> act`` for ONE of a group's ``g`` layers and
-    ``scatter_grads(g_full) -> g_shards`` (the exact transpose of
-    ``gather_group``'s wire, qwZ/qgZ aware) come from the enclosing
+    ``scatter_grads`` come from the enclosing
     :meth:`Zero3StreamContext.scan` trace.
     """
 
@@ -353,21 +380,22 @@ def _build_carried_stream(steps: int, g: int, gather_group, run_layer,
                        for leaf in leaves]
         return [buf.at[j].set(leaf) for buf, leaf in zip(stacked, leaves)]
 
-    def _finish_rows(g_c, stacked):
-        """This layer's rows are written before the next layer's backward
-        (which needs ``g_c``) starts.  Left free, XLA fuses each
-        weight-gradient matmul into its row update and sinks the chain
-        to the group's end, every layer's recomputed activations live
-        until then (+7 GB of temporaries for GPT-2 XL at g=16).  A
-        barrier gives all it holds one varying-axes type, so under
-        ``check_vma`` it holds the stacks of ``g_c``'s type only."""
+    def _finish_rows(g_c, held):
+        """What ``held`` lists is computed before the next layer's
+        backward (which needs ``g_c``) starts: this layer's rows, and
+        after a group's last layer the shards the group before it sent
+        for.  Left free, XLA fuses each weight-gradient matmul into its
+        row update and sinks the chain to the group's end, every layer's
+        recomputed activations live until then (+7 GB of temporaries
+        for GPT-2 XL at g=16).  A barrier gives all it holds one
+        varying-axes type, so under ``check_vma`` it holds the arrays of
+        ``g_c``'s type only."""
         vma = jax.typeof(jax.tree.leaves(g_c)[0]).vma
-        held = [jax.typeof(buf).vma == vma for buf in stacked]
+        same = [jax.typeof(buf).vma == vma for buf in held]
         g_c, out = lax.optimization_barrier(
-            (g_c, [buf for buf, h in zip(stacked, held) if h]))
+            (g_c, [buf for buf, h in zip(held, same) if h]))
         out = iter(out)
-        return g_c, [next(out) if h else buf
-                     for buf, h in zip(stacked, held)]
+        return g_c, [next(out) if h else buf for buf, h in zip(held, same)]
 
     def _run_group(c, full, extras_i):
         """A gathered group's ``g`` layers.  Returns the output carry and
@@ -418,11 +446,15 @@ def _build_carried_stream(steps: int, g: int, gather_group, run_layer,
             return [leaf for leaf, f in zip(jax.tree.leaves(g_ex), is_float)
                     if f]
 
-        def group_vjp(i, carry_in, full, g_c):
+        def group_vjp(i, carry_in, full, g_c, owed=None):
             """Group ``i``'s layers in reverse, each from its own saved
             carry ``carry_in(j)``; per-layer cotangents restacked to the
-            ``[g, ...]`` leaves ``scatter_grads`` (and the extras) take."""
+            ``[g, ...]`` leaves ``scatter_grads`` (and the extras) take.
+            ``owed``: the full-width stacks of the group whose backward
+            ran before this one's.  Their scatter is issued here and has
+            this group's whole backward to land under; returned last."""
             extras_i = _index_tree(extras_g, i)
+            flying = scatter_grads(owed) if owed is not None else []
             g_full = g_ex = None
             for j in reversed(range(g)):
                 _, vjp_fn = jax.vjp(run_layer, carry_in(j),
@@ -430,8 +462,10 @@ def _build_carried_stream(steps: int, g: int, gather_group, run_layer,
                 g_c, g_layer, g_ex_j = vjp_fn(g_c)
                 g_full = _set_row(g_full, g_layer, j)
                 g_ex = _set_row(g_ex, float_only(g_ex_j), j)
-                g_c, g_full = _finish_rows(g_c, g_full)
-            return g_c, g_full, g_ex
+                g_c, held = _finish_rows(
+                    g_c, g_full + (flying if j == 0 else []))
+                g_full, landed = held[:len(g_full)], held[len(g_full):]
+            return g_c, g_full, g_ex, landed
 
         def saved_carry(stack, *group):
             """``j -> `` layer ``j``'s carry out of a stack of them, one
@@ -444,26 +478,29 @@ def _build_carried_stream(steps: int, g: int, gather_group, run_layer,
         # double buffer)
         full_last = gather_group(_group_shards(params_g, steps - 1))
         full_prev = gather_group(_group_shards(params_g, steps - 2))
-        g_c, g_full, g_ex_last = group_vjp(
+        g_c, g_full, g_ex_last, _ = group_vjp(
             steps - 1, saved_carry(c_ins_last), full_last, g_out)
-        g_sh_last = scatter_grads(g_full)
 
         def bbody(carry, i):
-            g_c, cur = carry
+            g_c, cur, owed = carry
             nxt = gather_group(_group_shards(params_g, i - 1))
-            g_c, g_full, g_ex = group_vjp(i, saved_carry(c_ins, i), cur, g_c)
-            return (g_c, nxt), (scatter_grads(g_full), g_ex)
+            g_c, g_full, g_ex, landed = group_vjp(
+                i, saved_carry(c_ins, i), cur, g_c, owed)
+            # ys: group i+1's shards, landed under group i's backward
+            return (g_c, nxt, g_full), (landed, g_ex)
 
-        (g_c, cur0), (g_sh_mid, g_ex_mid) = lax.scan(
-            bbody, (g_c, full_prev), jnp.arange(1, steps - 1),
+        (g_c, cur0, g_full), (g_sh_upper, g_ex_mid) = lax.scan(
+            bbody, (g_c, full_prev, g_full), jnp.arange(1, steps - 1),
             reverse=True)
 
-        # group 0: consumes the last carried re-gather
-        g_c0, g_full, g_ex0 = group_vjp(0, saved_carry(c_ins, 0), cur0, g_c)
+        # group 0: consumes the last carried re-gather; group 1's shards
+        # land under it, and its own scatter is the tail
+        g_c0, g_full, g_ex0, g_sh1 = group_vjp(
+            0, saved_carry(c_ins, 0), cur0, g_c, g_full)
         g_sh0 = scatter_grads(g_full)
 
-        g_params = [jnp.concatenate([a[None], mid, b[None]], axis=0)
-                    for a, mid, b in zip(g_sh0, g_sh_mid, g_sh_last)]
+        g_params = [jnp.concatenate([a[None], b[None], upper], axis=0)
+                    for a, b, upper in zip(g_sh0, g_sh1, g_sh_upper)]
         out_ex, fi = [], 0
         for leaf, f in zip(ex_leaves, is_float):
             if f:
@@ -521,6 +558,8 @@ class Zero3StreamContext:
         # tracing, so the Schedule Auditor (analysis/auditor.py) can
         # name the streamed scan's structure in overlap findings
         self.last_plan: Optional[StreamPlan] = None
+        # the backward's wire as scan() last planned it (_grad_wire)
+        self.last_grad_wire: Optional[dict] = None
         if self.lbc is not None and self.lbc.hpz_group_size > 1:
             hpz = resolve_hpz_axes(self.axis_sizes,
                                    self.lbc.hpz_group_size)
@@ -555,9 +594,9 @@ class Zero3StreamContext:
         e.g. reused for inference), or the batch doesn't divide the ZeRO
         world (batch-1 decode).
 
-        Half precision streams on every backend: the region's only
-        reduction collective (the gather's transpose) runs in fp32 via
-        ``_all_gather_f32grad``, which sidesteps XLA-CPU's half-precision
+        Half precision streams on every backend: the gather's transpose
+        (``_all_gather_f32grad``) is permutes or an fp32 collective,
+        never a half-precision reduction, which sidesteps the XLA-CPU
         AllReducePromotion abort that used to force a GSPMD fallback
         here."""
         del params  # kept for call-site compatibility
@@ -604,9 +643,10 @@ class Zero3StreamContext:
         (bias gathered one layer at a time) would pay more in fp32
         block scales than it saves, so it degrades to 0 (dense) per
         direction.  The forward compares against the leaf's native
-        width; the backward against fp32, because that is what the
-        dense fallback's reduce-scatter moves for every float dtype
-        (f32_psum_scatter promotes half grads)."""
+        width; the backward against fp32, the native reduce-scatter's
+        wire for every float dtype (the dense fallback's permutes move a
+        half gradient at its own width, so for a half leaf this gate
+        lets qgZ in a little earlier than bytes alone would)."""
         lbc = self.lbc
         if lbc is None or not jnp.issubdtype(leaf.dtype, jnp.floating):
             return 0, 0
@@ -660,11 +700,54 @@ class Zero3StreamContext:
             _qwz, qgz = self._leaf_wire_bits(leaf, dim + 1)
             # the transpose wire depends only on qgz: both _lbag_bwd
             # (qwz path) and _ag_bwd (dense path) fall back to
-            # f32_psum_scatter when qgz == 0
+            # dense_psum_scatter when qgz == 0
             plan.append((dim + 1, tuple(axes), qgz))
             world = int(np.prod([self.param_axis_sizes[a] for a in axes]))
             shape[dim + 1] *= world
         return plan
+
+    def _leaf_wire_form(self, local_shape, dtype, plan_k):
+        """How one gathered leaf's gradient leaves, from shapes alone:
+        ``(form, {form: bytes one shard sends a group})`` with ``form``
+        the widest of its scattered dimensions' (``"quantized"``, then
+        ``"native"``, then ``"permute"``; ``dense_scatter_wire``), or
+        None for a leaf gathered over no axis, whose gradient is the
+        region boundary's psum."""
+        if not plan_k:
+            return None, {}
+        shape = list(local_shape)
+        worlds_of = [[self.param_axis_sizes[a] for a in axes]
+                     for _d, axes, _q in plan_k]
+        for (d, _axes, _q), worlds in zip(plan_k, worlds_of):
+            shape[d] *= int(np.prod(worlds))
+        sent = {}
+        for (d, _axes, qgz), worlds in zip(reversed(plan_k),
+                                           reversed(worlds_of)):
+            if qgz:
+                sent.setdefault("quantized", 0)
+            else:
+                form, nbytes = dense_scatter_wire(shape, dtype, d, worlds)
+                sent[form] = sent.get(form, 0) + nbytes
+            shape[d] //= int(np.prod(worlds))
+        return next(f for f in ("quantized", "native", "permute")
+                    if f in sent), sent
+
+    def _grad_wire(self, wires, steps):
+        """The counter of the backward's wire, over the leaves'
+        ``_leaf_wire_form``: how many of a group's gathered leaves leave
+        as shifted permutes, as the native reduce-scatter and quantized,
+        and the bytes one shard sends a step in the two dense forms."""
+        out = {"permuted_leaves": 0, "native_leaves": 0,
+               "quantized_leaves": 0, "permute_bytes_per_step": 0,
+               "native_bytes_per_step": 0}
+        for form, sent in wires:
+            if form is None:
+                continue
+            out[{"permute": "permuted_leaves", "native": "native_leaves",
+                 "quantized": "quantized_leaves"}[form]] += 1
+            for dense in ("permute", "native"):
+                out[f"{dense}_bytes_per_step"] += sent.get(dense, 0) * steps
+        return out
 
     # ------------------------------------------------------------------ #
     def scan(self, body, init_carry, stacked_params: Any, extra_xs: Any,
@@ -711,43 +794,6 @@ class Zero3StreamContext:
                         plan.forfeited)
             except Exception:  # pragma: no cover — partial install
                 pass
-        if not self._plan_logged:
-            lb = ""
-            if self.lbc is not None:
-                # key off the CONFIG, not param_manual == manual: a
-                # group size equal to the full ZeRO world is a
-                # configured (degenerate) hpZ, not "off"
-                hpz = (sorted(self.param_manual)
-                       if self.lbc.hpz_group_size > 1 else "off")
-                lb = (f", low_bandwidth: qwz={self.lbc.qwz_bits}b "
-                      f"qgz={self.lbc.qgz_bits}b hpz={hpz}"
-                      f"{' fcm' if self.fcm else ''}")
-            saved = ""
-            if plan.prefetch:
-                # the carried VJP's residuals: every layer's input carry
-                zero_world = int(np.prod(
-                    [self.axis_sizes[a] for a in self.manual]))
-                carry_bytes = sum(
-                    int(np.prod(leaf.shape)) * jnp.dtype(leaf.dtype).itemsize
-                    for leaf in jax.tree.leaves(init_carry)) // zero_world
-                saved = (f", backward saves {plan.num_layers} layer-input "
-                         f"carries ({plan.num_layers * carry_bytes:,} B "
-                         f"per shard)")
-            log_dist(
-                f"ZeRO-3 streaming: {plan.num_layers} layers in groups of "
-                f"{plan.layers_per_step}, prefetch={plan.prefetch}, "
-                f"live<= {plan.live_parameters:,} "
-                f"params (max_live={self.max_live_parameters:,}){saved}{lb}",
-                ranks=[0])
-            if plan.forfeited:
-                log_dist(
-                    f"ZeRO-3 streaming: prefetch FORFEITED — "
-                    f"{plan.forfeited}; falling back to serialized "
-                    f"at-use gathers ({plan.num_layers} layers in groups "
-                    f"of {plan.layers_per_step})",
-                    ranks=[0], level=logging.WARNING)
-            self._plan_logged = True
-
         mesh = self.ctx.mesh
         manual = self.manual
         g = plan.layers_per_step
@@ -774,7 +820,7 @@ class Zero3StreamContext:
         # over those axes at the shard_map transpose boundary.  Such
         # half-precision leaves are widened to fp32 at entry (cast back to
         # their dtype at use) so that psum accumulates in fp32 — matching
-        # _all_gather_f32grad's fp32 reduce-scatter for the gathered dims,
+        # _all_gather_f32grad's fp32 accumulation for the gathered dims,
         # and keeping every reduction collective the region emits out of
         # XLA-CPU's half-precision AllReducePromotion abort.  Without hpZ
         # the uncovered leaves are the ones too small to shard further, so
@@ -817,6 +863,77 @@ class Zero3StreamContext:
             init_carry)
         extras_specs = jax.tree.map(lambda _: PartitionSpec(), grouped_extras)
 
+        # The transpose of gather_group's wire, leaf by leaf, replaying
+        # the qwZ/qgZ decisions _gather_leaf makes from the LOCAL
+        # (in-region) shard shapes: what the carried backward applies by
+        # hand, and what the gather's own VJP does where groups are
+        # gathered at use.
+        def local_group_shape(k):
+            shape = [g] + list(p_leaves[k].shape[1:])
+            for d, axes in gathers[k]:
+                world = int(np.prod(
+                    [self.param_axis_sizes[a] for a in axes]))
+                shape[d + 1] //= world
+            return shape
+
+        local_shapes = [local_group_shape(k) for k in range(len(p_leaves))]
+        wire_dtypes = [jnp.float32 if w else dt
+                       for w, dt in zip(widen, leaf_dtypes)]
+        transpose_plans = [
+            self._leaf_transpose_plan(shape, dt, dims)
+            for shape, dt, dims in zip(local_shapes, wire_dtypes, gathers)]
+        wires = [self._leaf_wire_form(shape, dt, plan_k)
+                 for shape, dt, plan_k in zip(local_shapes, wire_dtypes,
+                                              transpose_plans)]
+        self.last_grad_wire = self._grad_wire(wires, steps)
+        host_trace.mark("zero3.grad_wire", **self.last_grad_wire)
+        if not self._plan_logged:
+            lb = ""
+            if self.lbc is not None:
+                # key off the CONFIG, not param_manual == manual: a
+                # group size equal to the full ZeRO world is a
+                # configured (degenerate) hpZ, not "off"
+                hpz = (sorted(self.param_manual)
+                       if self.lbc.hpz_group_size > 1 else "off")
+                lb = (f", low_bandwidth: qwz={self.lbc.qwz_bits}b "
+                      f"qgz={self.lbc.qgz_bits}b hpz={hpz}"
+                      f"{' fcm' if self.fcm else ''}")
+            saved = ""
+            if plan.prefetch:
+                # the carried VJP's residuals: every layer's input carry
+                zero_world = int(np.prod(
+                    [self.axis_sizes[a] for a in self.manual]))
+                carry_bytes = sum(
+                    int(np.prod(leaf.shape)) * jnp.dtype(leaf.dtype).itemsize
+                    for leaf in jax.tree.leaves(init_carry)) // zero_world
+                saved = (f", backward saves {plan.num_layers} layer-input "
+                         f"carries ({plan.num_layers * carry_bytes:,} B "
+                         f"per shard)")
+            wire = self.last_grad_wire
+            grads = (f", gradients leave a group as permutes for "
+                     f"{wire['permuted_leaves']} leaves "
+                     f"({wire['permute_bytes_per_step']:,} B sent per "
+                     f"shard and step) and as the native reduce-scatter "
+                     f"for {wire['native_leaves']} "
+                     f"({wire['native_bytes_per_step']:,} B)")
+            if wire["quantized_leaves"]:
+                grads += f", quantized for {wire['quantized_leaves']}"
+            log_dist(
+                f"ZeRO-3 streaming: {plan.num_layers} layers in groups of "
+                f"{plan.layers_per_step}, prefetch={plan.prefetch}, "
+                f"live<= {plan.live_parameters:,} "
+                f"params (max_live={self.max_live_parameters:,}){saved}"
+                f"{grads}{lb}",
+                ranks=[0])
+            if plan.forfeited:
+                log_dist(
+                    f"ZeRO-3 streaming: prefetch FORFEITED — "
+                    f"{plan.forfeited}; falling back to serialized "
+                    f"at-use gathers ({plan.num_layers} layers in groups "
+                    f"of {plan.layers_per_step})",
+                    ranks=[0], level=logging.WARNING)
+            self._plan_logged = True
+
         def gather_group(shards):
             """all-gather one layer group's param shards into full arrays.
             The +1 dim shift accounts for the group dimension.  Gathered
@@ -851,26 +968,8 @@ class Zero3StreamContext:
             # buffers never become scan residuals (the naive carried
             # structure would stack the full unsharded model; see the
             # builder's docstring), preserving StreamPlan.live_parameters'
-            # 2x bound.  The transpose schedule below replays the exact
-            # qwZ/qgZ wire decisions _gather_leaf makes, from the LOCAL
-            # (in-region) shard shapes.
+            # 2x bound.
             block = self.lbc.block_size if self.lbc is not None else 0
-
-            def local_group_shape(k):
-                shape = [g] + list(p_leaves[k].shape[1:])
-                for d, axes in gathers[k]:
-                    world = int(np.prod(
-                        [self.param_axis_sizes[a] for a in axes]))
-                    shape[d + 1] //= world
-                return shape
-
-            transpose_plans = [
-                self._leaf_transpose_plan(
-                    local_group_shape(k),
-                    jnp.float32 if widen[k] else leaf_dtypes[k],
-                    gathers[k])
-                for k in range(len(p_leaves))]
-
             fcm = self.fcm
 
             def scatter_grads(g_full):
@@ -879,7 +978,7 @@ class Zero3StreamContext:
                     if w:  # transpose of gather_group's cast-back to dt
                         gk = gk.astype(jnp.float32)
                     for d, axes, qgz in reversed(plan_k):
-                        if fcm and jnp.issubdtype(gk.dtype, jnp.floating):
+                        if qgz and fcm:
                             # per-tile ring scatter: the backward GEMM's
                             # epilogue wire, classified fused/hidden
                             gk = fcm_reduce_scatter(gk, axes, d,
@@ -888,8 +987,8 @@ class Zero3StreamContext:
                             gk = quantized_psum_scatter(gk, axes, d,
                                                         bits=qgz,
                                                         block=block)
-                        else:
-                            gk = f32_psum_scatter(gk, axes, d)
+                        else:  # with the knob or without: one dense path
+                            gk = dense_psum_scatter(gk, axes, d)
                     out.append(gk)
                 return out
 

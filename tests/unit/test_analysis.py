@@ -670,9 +670,14 @@ def test_zero3_streaming_carried_flips_overlap_gate_green():
     # the carried records carry real slack: a full group of compute sits
     # between issue and first consume
     assert all(r["slack_flops"] > 0 for r in hot_gathers)
-    # the backward re-fetch is carried too: hot-loop reduce_scatters
-    # (the re-gather sweep's grad transposes) escape via the carry/ys
-    assert report.overlap["n_carried"] > len(hot_gathers)
+    # the backward's gradient wire (the re-gather sweep's transposes) is
+    # no reduce_scatter any more: shifted permutes a layer, classified
+    # as the hidden transport they are
+    wire = [r for r in report.overlap["records"]
+            if r["prim"] in ("ppermute", "reduce_scatter", "psum_scatter")]
+    assert wire and all(r["prim"] == "ppermute" and r["fused"]
+                        and not r["serialized"] for r in wire)
+    assert report.overlap["n_fused"] == len(wire)
     serialized = json.loads(GOLDEN_STREAM_SERIALIZED.read_text())
     assert (report.overlap_efficiency
             > serialized["overlap"]["overlap_efficiency"])

@@ -235,8 +235,11 @@ def test_fcm_never_increases_wire_bytes():
         # lane must not exceed the modular twin's
         assert (rc.report.step_time["t_comm_exposed_s"]
                 <= twin.report.step_time["t_comm_exposed_s"] + 1e-12)
+        # (the modular twin's skinny leaves, dense under qgZ, leave as
+        # the same permutes: a sliver of the fused candidate's wire)
         assert rc.report.step_time["wire_bytes_fused"] > 0
-        assert twin.report.step_time["wire_bytes_fused"] == 0
+        assert (twin.report.step_time["wire_bytes_fused"]
+                < rc.report.step_time["wire_bytes_fused"] / 10)
         pairs += 1
     assert pairs >= 1
 

@@ -123,6 +123,41 @@ def test_fcm_reduce_scatter_matches_modular_bitwise():
     assert (np.asarray(of) == np.asarray(om)).all()
 
 
+def _parent_fcm_scatter_bits0(x, axis_name, dim):
+    """``fcm_reduce_scatter(bits=0)`` as the parent of PR 59 computed one
+    axis of it: the chunk table widened to fp32 BEFORE the wire, moved by
+    ``_scatter_tiles``, summed over the source index."""
+    world = lax.axis_size(axis_name)
+    xt = jnp.moveaxis(x, dim, 0)
+    chunks = xt.reshape((world, xt.shape[0] // world) + xt.shape[1:])
+    red, _ = cm._quantize_scatter_reduce(chunks, axis_name, 0, 16)
+    return jnp.moveaxis(red.astype(x.dtype), 0, dim)
+
+
+@pytest.mark.parametrize("dim", [0, 1])
+@pytest.mark.parametrize("world", [2, 4, 8])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+def test_fcm_reduce_scatter_bits0_is_the_dense_path_bitwise(dtype, world,
+                                                            dim):
+    """The knob's ``bits=0`` scatter and the default dense path are one
+    function, and its result is bit for bit what the parent's fp32 table
+    gave: widening commutes with the wire."""
+    mesh = _mesh(world)
+    x = (jax.random.normal(jax.random.PRNGKey(5), (world, 16, 24)) * 3.7
+         ).astype(dtype)
+
+    def on(fn):
+        return np.asarray(_sm(lambda a: fn(a[0])[None], mesh, P("data"),
+                              P("data"))(x).astype(jnp.float32))
+
+    now = on(lambda a: cm.fcm_reduce_scatter(a, ("data",), dim, bits=0))
+    dense = on(lambda a: lb.dense_psum_scatter(a, ("data",), dim))
+    parent = on(lambda a: _parent_fcm_scatter_bits0(a, "data", dim))
+    np.testing.assert_array_equal(now, dense)
+    np.testing.assert_array_equal(now, parent)
+
+
 def test_fcm_multi_axis_gather_matches_joint():
     """Nested per-axis rings reproduce the joint tiled all_gather's
     axis-major index order (the modular path gathers both axes in one

@@ -17,9 +17,11 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 import deepspeed_tpu as ds
 from deepspeed_tpu.runtime.comm.low_bandwidth import (
-    as_quantized_weight, blockwise_dequantize, blockwise_quantize,
-    collective_wire_bytes, init_error_feedback, low_bandwidth_all_gather,
-    pack_int4, qgz_reduce_scatter, qgz_reduce_scatter_inner,
+    PERMUTE_SCATTER_MAX_WORLD, as_quantized_weight, blockwise_dequantize,
+    blockwise_quantize, collective_wire_bytes, dense_psum_scatter,
+    dense_scatter_wire, f32_psum_scatter, init_error_feedback,
+    low_bandwidth_all_gather, pack_int4, permute_scatter_takes,
+    qgz_reduce_scatter, qgz_reduce_scatter_inner,
     quantized_gather_saves_bytes, quantized_psum_scatter, unpack_int4)
 
 
@@ -259,6 +261,170 @@ def test_qgz_stacked_wrapper_matches_inner():
 
 
 # --------------------------------------------------------------------- #
+# the dense gradient reduce-scatter: shifted permutes of whole chunks
+# --------------------------------------------------------------------- #
+def _stacked_scatter(fn, mesh, x, dim, check_vma=True):
+    """``fn(shard's array, axes, dim)`` on every shard of ``x [W, ...]``
+    (row i is shard i's full-width gradient); returns ``[W, chunk...]``."""
+    axes = tuple(mesh.axis_names)
+    run = jax.jit(jax.shard_map(
+        lambda a: fn(a[0], axes, dim)[None], mesh=mesh, in_specs=P(axes),
+        out_specs=P(axes), check_vma=check_vma))
+    return run(x)
+
+
+def _gradient_stack(world, dtype, seed=0):
+    """[W, 3, 16, 8] values a bf16 sum does not hold exactly."""
+    x = jax.random.normal(jax.random.PRNGKey(seed), (world, 3, 16, 8))
+    return (x * 3.7).astype(dtype)
+
+
+@pytest.mark.parametrize("dim", [1, 2])
+@pytest.mark.parametrize("world", [2, 4, 8])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "fp32"])
+def test_dense_scatter_is_the_source_order_sum_narrowed_once(dtype, world,
+                                                             dim):
+    """Bit for bit ``sum_s widen(chunk_s)`` in source-index order,
+    narrowed once: only original values travel, so the wire's dtype
+    changes nothing; and within one rounding of the native collective,
+    whose order is XLA's."""
+    mesh = _mesh((world,), ("data",))
+    x = _gradient_stack(world, dtype)
+    got = np.asarray(_stacked_scatter(dense_psum_scatter, mesh, x, dim)
+                     .astype(jnp.float32))
+    wide = np.asarray(x.astype(jnp.float32))
+    chunk = x.shape[dim + 1] // world
+    want = []
+    for owner in range(world):
+        cut = [slice(None)] * 3
+        cut[dim] = slice(owner * chunk, (owner + 1) * chunk)
+        total = wide[0][tuple(cut)]
+        for source in range(1, world):
+            total = total + wide[source][tuple(cut)]
+        want.append(np.asarray(
+            jnp.asarray(total).astype(dtype).astype(jnp.float32)))
+    np.testing.assert_array_equal(got, np.stack(want))
+
+    native = np.asarray(_stacked_scatter(f32_psum_scatter, mesh, x, dim)
+                        .astype(jnp.float32))
+    assert (np.abs(got - native) <= _one_rounding(native, wide, dtype)).all()
+
+
+def _one_rounding(native, wide, dtype):
+    """What two orders of one fp32 sum of ``wide [W, ...]`` may differ
+    by, plus one rounding to ``dtype`` of the result."""
+    order = wide.shape[0] * 2.0 ** -23 * np.abs(wide).sum(0).max()
+    return order + (np.abs(native) * 2.0 ** -7 if dtype == jnp.bfloat16
+                    else 0.0)
+
+
+def _wire_prims(fn, mesh, x, dim):
+    axes = tuple(mesh.axis_names)
+    jx = jax.make_jaxpr(jax.shard_map(
+        lambda a: fn(a[0], axes, dim)[None], mesh=mesh, in_specs=P(axes),
+        out_specs=P(axes), check_vma=False))(x)
+    from deepspeed_tpu.analysis.jaxpr_walk import iter_eqns
+    return [(c.eqn.primitive.name, c.eqn.invars[0].aval.dtype)
+            for c in iter_eqns(jx)
+            if c.eqn.primitive.name in ("ppermute", "psum_scatter",
+                                        "reduce_scatter", "all_to_all")]
+
+
+@pytest.mark.parametrize("world", [2, 4, 8])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "fp32"])
+def test_dense_scatter_leaves_as_permutes_in_its_own_dtype(dtype, world):
+    """``W - 1`` permutes a leaf, their operands in the gradient's dtype
+    (half the bytes of the fp32 collective for a bf16 gradient), and no
+    reduce-scatter; a dimension the shards do not divide keeps the native
+    collective on its fp32 wire."""
+    mesh = _mesh((world,), ("data",))
+    x = _gradient_stack(world, dtype)
+    prims = _wire_prims(dense_psum_scatter, mesh, x, 1)
+    assert prims == [("ppermute", jnp.dtype(dtype))] * (world - 1)
+    assert permute_scatter_takes(16, world)
+    assert not permute_scatter_takes(16, 1)
+    assert not permute_scatter_takes(16, 2 * PERMUTE_SCATTER_MAX_WORLD)
+    assert not permute_scatter_takes(15, world)
+    if world == 8:  # [.., 3, ..] / 8: the quantized forms refuse it too
+        with pytest.raises(Exception):
+            _wire_prims(dense_psum_scatter, mesh, x, 0)
+
+
+def test_dense_scatter_keeps_the_native_collective_past_the_width(
+        monkeypatch):
+    """Above ``PERMUTE_SCATTER_MAX_WORLD`` shards an axis is one native
+    reduce-scatter on an fp32 wire, as every leaf was."""
+    from deepspeed_tpu.runtime.comm import low_bandwidth as lb
+    monkeypatch.setattr(lb, "PERMUTE_SCATTER_MAX_WORLD", 2)
+    mesh = _mesh((4,), ("data",))
+    x = _gradient_stack(4, jnp.bfloat16)
+    assert _wire_prims(dense_psum_scatter, mesh, x, 1) == [
+        ("reduce_scatter", jnp.dtype(jnp.float32))]
+    got = _stacked_scatter(dense_psum_scatter, mesh, x, 1)
+    want = _stacked_scatter(f32_psum_scatter, mesh, x, 1)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    assert dense_scatter_wire((3, 16, 8), jnp.bfloat16, 1, [4]) == (
+        "native", 3 * 16 * 8 * 4 * 3 // 4)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "fp32"])
+def test_dense_scatter_over_two_axes_narrows_once(dtype):
+    """Several axes reduce one after another in tuple order, the joint
+    tiled collective's axis-major chunks; partial sums stay fp32 between
+    them (the second axis's wire is fp32) and the result is rounded
+    once, so it sits within one rounding of the joint fp32 collective."""
+    mesh = _mesh((2, 4), ("data", "expert"))
+    x = _gradient_stack(8, dtype, seed=3).reshape(2, 4, 3, 16, 8)
+    axes = ("data", "expert")
+
+    def run(fn):
+        return np.asarray(jax.jit(jax.shard_map(
+            lambda a: fn(a[0, 0], axes, 1)[None, None], mesh=mesh,
+            in_specs=P("data", "expert"), out_specs=P("data", "expert"),
+            check_vma=True))(x).astype(jnp.float32))
+
+    got, native = run(dense_psum_scatter), run(f32_psum_scatter)
+    wide = np.asarray(x.astype(jnp.float32)).reshape(8, 3, 16, 8)
+    assert (np.abs(got - native) <= _one_rounding(native, wide, dtype)).all()
+    jx = jax.make_jaxpr(jax.shard_map(
+        lambda a: dense_psum_scatter(a[0, 0], axes, 1)[None, None],
+        mesh=mesh, in_specs=P("data", "expert"),
+        out_specs=P("data", "expert"), check_vma=False))(x)
+    from deepspeed_tpu.analysis.jaxpr_walk import iter_eqns
+    wires = [c.eqn.invars[0].aval.dtype for c in iter_eqns(jx)
+             if c.eqn.primitive.name == "ppermute"]
+    assert wires == [jnp.dtype(dtype)] + [jnp.dtype(jnp.float32)] * 3
+    size = 3 * 16 * 8
+    item = jnp.dtype(dtype).itemsize
+    assert dense_scatter_wire((3, 16, 8), dtype, 1, [2, 4]) == (
+        "permute", size * item // 2 + (size // 2) * 4 * 3 // 4)
+
+
+def test_qwz_transpose_with_qgz_off_leaves_as_permutes():
+    """``low_bandwidth_all_gather``'s qgZ-off transpose is the same
+    dense scatter: permutes in the cotangent's dtype."""
+    mesh = _mesh((4,), ("data",))
+    x = jnp.ones((4, 8, 24), jnp.bfloat16)
+
+    def loss(a):
+        full = low_bandwidth_all_gather(a[0], ("data",), 0, 8, 0, 64)
+        return jnp.sum(full.astype(jnp.float32) ** 2)
+
+    jx = jax.make_jaxpr(jax.shard_map(
+        jax.grad(loss), mesh=mesh, in_specs=P("data"), out_specs=P("data"),
+        check_vma=False))(x)
+    from deepspeed_tpu.analysis.jaxpr_walk import iter_eqns
+    prims = [(c.eqn.primitive.name, c.eqn.invars[0].aval.dtype)
+             for c in iter_eqns(jx)
+             if c.eqn.primitive.name in ("ppermute", "psum_scatter")]
+    assert prims == [("ppermute", jnp.dtype(jnp.bfloat16))] * 3
+
+
+# --------------------------------------------------------------------- #
 # wire-byte accounting
 # --------------------------------------------------------------------- #
 def test_collective_wire_bytes_walker():
@@ -365,9 +531,14 @@ def test_e2e_quantized_parity_and_byte_reduction():
     # directions (scales and the all-to-all transport keep it under 4)
     bf = collective_wire_bytes(jx_f)
     bq = collective_wire_bytes(jx_q)
-    assert bf["gather_bytes"] > 0 and bf["reduce_bytes"] > 0
+    # (the dense gradients leave as shifted permutes of whole chunks,
+    # which the walker books under fcm_bytes: 7/8 of the array at W=8)
+    assert bf["gather_bytes"] > 0 and bf["reduce_bytes"] == 0
+    # leaves too skinny for qgZ to pay stay dense under it: permutes too
+    assert bf["fcm_bytes"] > 0 and bq["reduce_bytes"] > bq["fcm_bytes"] > 0
     assert bf["gather_bytes"] / bq["gather_bytes"] > 3.0, (bf, bq)
-    assert bf["reduce_bytes"] / bq["reduce_bytes"] > 3.0, (bf, bq)
+    assert bf["fcm_bytes"] / (bq["reduce_bytes"] + bq["fcm_bytes"]) > 3.0, (
+        bf, bq)
 
 
 def test_e2e_hpz_exact_parity_on_two_axis_mesh():

@@ -442,6 +442,188 @@ def test_carried_hpz_parity():
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
 
 
+# --------------------------------------------------------------------- #
+# the backward's wire: shifted permutes in the gradients' own dtype
+# --------------------------------------------------------------------- #
+def _grads_of(engine, model, ids):
+    return jax.tree.map(np.asarray, jax.jit(jax.grad(
+        lambda p: model.loss(p, None, ids)))(engine.params))
+
+
+@pytest.mark.parametrize("mode", ["carried", "off"])
+def test_two_group_gradients_match_the_unstreamed_engine(mode):
+    """A streamed two-group model's gradients, their reduce-scatters
+    gone as permutes, equal the non-streamed engine's: with prefetch
+    (``scatter_grads`` one group late, under the next group's backward)
+    and without (the gather's own transpose inside the scan)."""
+    ids = _tiny_ids()
+    engine, model = _tiny_engine({"stage": 0}, num_layers=4)
+    want = _grads_of(engine, model, ids)
+    engine, model = _tiny_engine(_group_cfg(mode, 2), num_layers=4)
+    stream = engine._zero3_stream
+    got = _grads_of(engine, model, ids)
+    assert stream.last_plan.prefetch == (mode == "carried")
+    assert stream.last_plan.num_layers // stream.last_plan.layers_per_step == 2
+    # ten of a layer's twelve leaves: the two column-parallel biases'
+    # one dimension is the tensor-parallel axis's, so ZeRO leaves them whole
+    assert stream.last_grad_wire["permuted_leaves"] == 10
+    assert stream.last_grad_wire["native_leaves"] == 0
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-6)
+    ds.reset_mesh_context()
+
+
+@pytest.mark.parametrize("mode,groups", [("carried", 3), ("off", 2)])
+def test_streamed_grad_program_permutes_where_it_reduce_scattered(mode,
+                                                                  groups):
+    """The grad jaxpr of the streamed stack holds no reduce-scatter: each
+    gathered leaf's transpose is ``W - 1`` permutes a group, operands in
+    the leaf's dtype (issued one group late under the carried stream,
+    inside the scan's transposed body where groups are gathered at
+    use)."""
+    from deepspeed_tpu.analysis.jaxpr_walk import iter_eqns
+    engine, model = _tiny_engine(_group_cfg(mode, 2), num_layers=2 * groups)
+    ids = _tiny_ids()
+    jx = jax.make_jaxpr(jax.grad(lambda p: model.loss(p, None, ids)))(
+        engine.params)
+    permutes = scatters = 0
+    for c in iter_eqns(jx):
+        name = c.eqn.primitive.name
+        if name == "ppermute":
+            permutes += c.mult
+            assert c.eqn.invars[0].aval.dtype == np.float32
+        scatters += name in ("psum_scatter", "reduce_scatter")
+    assert scatters == 0
+    leaves = engine._zero3_stream.last_grad_wire["permuted_leaves"]
+    assert leaves == 10
+    assert permutes == (8 - 1) * leaves * groups
+    ds.reset_mesh_context()
+
+
+def test_grad_wire_counter_names_permuted_and_native_leaves(monkeypatch):
+    """The plan line and the ``ds.*`` collector say how a group's
+    gradients leave: as permutes where the shards divide the gathered
+    dimension within ``PERMUTE_SCATTER_MAX_WORLD``, as the native
+    collective past it, and the bytes a shard sends a step."""
+    import jax.numpy as jnp
+    from deepspeed_tpu.monitor import trace as host_trace
+    from deepspeed_tpu.runtime.comm import low_bandwidth as lb
+    from deepspeed_tpu.runtime.zero import stage3_streaming as s3
+    from deepspeed_tpu.runtime.zero.stage3_streaming import Zero3StreamContext
+    logged = []
+    monkeypatch.setattr(
+        s3, "log_dist", lambda msg, *a, **k: logged.append(str(msg)))
+    ds.reset_mesh_context()
+    ds.initialize_mesh(data=-1)
+    ctx = ds.get_mesh_context()
+    x = jnp.ones((8, 16), jnp.bfloat16)
+    params = {"w": jnp.ones((4, 16, 16), jnp.bfloat16) * 0.1}
+
+    def loss(stream, p):
+        def body(c, xs):
+            return jnp.tanh(c @ xs[0]["w"]), None
+        return stream.scan(body, x, p, ()).astype(jnp.float32).sum()
+
+    def wire_of():
+        stream = Zero3StreamContext(ctx, 10 ** 9, 10 ** 9)
+        since = host_trace.last_span()[2]
+        jax.make_jaxpr(jax.grad(lambda p: loss(stream, p)))(params)
+        marks = [m for m in host_trace.spans(since - 1)
+                 if m[0] == "ds.zero3.grad_wire"]
+        return stream.last_grad_wire, marks[-1][3]
+
+    # [2 layers a group, 16, 16] bf16 over 8 shards, 2 groups a step:
+    # seven of eight chunks leave a shard in bf16
+    wire, mark = wire_of()
+    sent = 2 * 16 * 16 * 2 * 7 // 8 * 2
+    assert wire == mark == {
+        "permuted_leaves": 1, "native_leaves": 0, "quantized_leaves": 0,
+        "permute_bytes_per_step": sent, "native_bytes_per_step": 0}
+    assert (f"as permutes for 1 leaves ({sent:,} B sent per shard and "
+            "step) and as the native reduce-scatter for 0 (0 B)"
+            ) in logged[-1]
+
+    monkeypatch.setattr(lb, "PERMUTE_SCATTER_MAX_WORLD", 2)
+    wire, mark = wire_of()
+    assert wire == mark == {
+        "permuted_leaves": 0, "native_leaves": 1, "quantized_leaves": 0,
+        "permute_bytes_per_step": 0, "native_bytes_per_step": 2 * sent}
+    assert "as permutes for 0 leaves" in logged[-1]
+    ds.reset_mesh_context()
+
+
+@pytest.fixture(scope="module")
+def v5e_2x2():
+    """The four described chips of a v5e host (never while a module is
+    imported: one process loads the TPU's library, every xdist worker
+    imports this file), the persistent compile cache kept out of it."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # whatever the plugin raises where it cannot
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def test_streamed_grad_program_compiles_to_async_permutes_for_v5e(v5e_2x2):
+    """The chip's compiler, given the streamed grad program of a tiny
+    two-group GPT-2 over the four chips of ``v5e:2x2``, emits no
+    reduce-scatter and ``(W - 1) x leaves x groups`` asynchronous
+    collective-permutes whose operands are bf16.  A compile is not a
+    run: what the starts and dones have between them on the chip is the
+    benchmark's ``collective_exposed_ms``."""
+    import re
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from deepspeed_tpu.parallel.mesh import MeshContext, set_mesh_context
+    from deepspeed_tpu.runtime.zero.partition import ZeroPartitioner
+    from deepspeed_tpu.runtime.zero.stage3_streaming import Zero3StreamContext
+
+    ds.reset_mesh_context()
+    ctx = MeshContext.create(devices=v5e_2x2, data=4)
+    set_mesh_context(ctx)
+    cfg = GPT2Config(vocab_size=256, n_positions=128, hidden_size=128,
+                     num_layers=4, num_heads=2, bf16=True,
+                     embd_dropout=0.0, attn_dropout=0.0, hidden_dropout=0.0)
+    model = GPT2Model(cfg)
+    per_layer = 12 * 128 * 128 + 13 * 128
+    stream = Zero3StreamContext(ctx, 4 * per_layer, 4 * per_layer, 0)
+    model.install_zero3_streaming(stream)
+    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    shapes = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16), shapes)
+    part = ZeroPartitioner(ctx, 3)
+    params = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        shapes, part.param_shardings(shapes))
+    ids = jax.ShapeDtypeStruct((8, 128), jnp.int32,
+                               sharding=NamedSharding(ctx.mesh, P("data")))
+    try:
+        text = jax.jit(jax.grad(
+            lambda p, i: model.loss(p, None, i))).lower(
+                params, ids).compile().as_text()
+    finally:
+        ds.reset_mesh_context()
+    wire = stream.last_grad_wire
+    assert stream.last_plan.prefetch
+    assert stream.last_plan.num_layers // stream.last_plan.layers_per_step == 2
+    assert wire["native_leaves"] == 0 and wire["permuted_leaves"] == 10
+    assert " reduce-scatter(" not in text
+    assert "reduce-scatter-start(" not in text
+    starts = re.findall(r"= \(?(\w+)\[[^\n]*? collective-permute-start\(",
+                        text)
+    assert len(starts) == (4 - 1) * wire["permuted_leaves"] * 2
+    assert set(starts) == {"bf16"}
+    assert " collective-permute(" not in text  # none left synchronous
+
+
 def test_stream_context_low_bandwidth_wiring():
     """Zero3StreamContext consumes the ZeroLowBandwidthConfig: hpZ
     confines the param manual set (and spec sizes) to the resolved
