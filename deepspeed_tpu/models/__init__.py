@@ -13,9 +13,10 @@ from .phi4flash import Phi4FlashConfig, Phi4FlashModel
 from .keye_vl2 import KeyeVL2Config, KeyeVL2Model
 from .granite_hybrid import GraniteHybridConfig, GraniteHybridModel
 from .xing4 import Xing4Config, Xing4Model
+from .nemotron_h import NemotronHConfig, NemotronHModel
 
 __all__ = ["GPT2Config", "GPT2Model", "BertConfig", "BertModel",
            "GPTMoEConfig", "GPTMoEModel", "Phi4FlashConfig",
            "Phi4FlashModel", "KeyeVL2Config", "KeyeVL2Model",
            "GraniteHybridConfig", "GraniteHybridModel", "Xing4Config",
-           "Xing4Model"]
+           "Xing4Model", "NemotronHConfig", "NemotronHModel"]

@@ -183,7 +183,41 @@ class Glm4MoeLiteConfig:
         return [r for r in runs if r[3]]
 
 
-class Glm4MoeLiteModel(ExpertStack):
+class SelectionBiasUpdate:
+    """The leaves the optimizer does not own, for a model of sparse FFNs
+    built with ``selection_bias`` (this one, models/xing4.py,
+    models/nemotron_h.py): it has ``gates()``, ``init_params`` and
+    ``config.bias_update_rate``, and hands the engine one RoutingStats
+    entry a step with each gate's picks an expert (``layer_counts``)."""
+
+    def optimizer_exempt(self):
+        """(mask, update) for the engine: the selection biases take no
+        optimizer update; after each one ``b_e += gamma sign(mean(c) -
+        c_e)`` with ``c`` the gate's picks an expert over the step
+        (RoutingStats.layer_counts, summed over its micro-batches)."""
+        shapes = jax.eval_shape(self.init_params, jax.random.PRNGKey(0))
+        mask = jax.tree_util.tree_map_with_path(
+            lambda path, _: [getattr(k, "key", None) for k in path][-2:]
+            == ["moe", "bias"], shapes)
+        gamma = self.config.bias_update_rate
+
+        def update(leaves, stats):
+            counts = stats.layer_counts                       # [L, E]
+            move = gamma * jnp.sign(
+                jnp.mean(counts, axis=-1, keepdims=True) - counts)
+            at = 0
+            for keys, layers in self.gates():
+                moe = leaves
+                for key in keys:
+                    moe = moe[key]
+                moe["bias"] = moe["bias"] + move[at:at + layers]
+                at += layers
+            return leaves
+
+        return mask, update
+
+
+class Glm4MoeLiteModel(SelectionBiasUpdate, ExpertStack):
     """The decoder over stacked groups of like layers and its prediction
     module; trained through ``deepspeed_tpu.initialize`` like
     LagunaModel."""
@@ -281,7 +315,6 @@ class Glm4MoeLiteModel(ExpertStack):
         shapes = jax.eval_shape(self.init_params, jax.random.PRNGKey(0))
         return sum(math.prod(x.shape) for x in jax.tree.leaves(shapes))
 
-    # -- the leaves the optimizer does not own -------------------------- #
     def gates(self):
         """The paths of the sparse FFNs' parameters in gate order (the
         stack's sparse layers, then the prediction module's block):
@@ -292,32 +325,6 @@ class Glm4MoeLiteModel(ExpertStack):
         if cfg.num_nextn_predict_layers:
             paths.append(((MTP, "block", "moe"), 1))
         return paths
-
-    def optimizer_exempt(self):
-        """(mask, update) for the engine: the selection biases take no
-        optimizer update; after each one ``b_e += gamma sign(mean(c) -
-        c_e)`` with ``c`` the gate's picks an expert over the step
-        (RoutingStats.layer_counts, summed over its micro-batches)."""
-        shapes = jax.eval_shape(self.init_params, jax.random.PRNGKey(0))
-        mask = jax.tree_util.tree_map_with_path(
-            lambda path, _: [getattr(k, "key", None) for k in path][-2:]
-            == ["moe", "bias"], shapes)
-        gamma = self.config.bias_update_rate
-
-        def update(leaves, stats):
-            counts = stats.layer_counts                       # [L, E]
-            move = gamma * jnp.sign(
-                jnp.mean(counts, axis=-1, keepdims=True) - counts)
-            at = 0
-            for keys, layers in self.gates():
-                moe = leaves
-                for key in keys:
-                    moe = moe[key]
-                moe["bias"] = moe["bias"] + move[at:at + layers]
-                at += layers
-            return leaves
-
-        return mask, update
 
     # -- the layer ------------------------------------------------------ #
     def latent_block(self, seq):

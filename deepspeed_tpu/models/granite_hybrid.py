@@ -15,16 +15,10 @@ With ``r = residual_multiplier`` and ``N*`` an RMSNorm with its own gain:
              attention_multiplier q k^T) v``, causal, fewer key/value
              heads than query heads, no bias, NO rotation and no other
              positional operation (``position_embedding_type: nope``).
-  mamba      H heads of P channels, N states, one group of B and C:
-             ``[z, xBC] = u W_in``, ``dt = u W_dt`` (one published
-             ``in_proj`` [hidden, 2 H P + 2 N + H], kept here as two
-             leaves so that the 64 step columns leave their product in
-             float32); ``xBC = silu(conv(xBC) + b)``, depthwise, causal,
-             ``mamba_d_conv`` taps; ``[x, B, C] = xBC``; ``dt =
-             softplus(dt + dt_bias)``, ``A = -exp(A_log)``; the scan
-             ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t``, ``y_t =
-             S_t C_t + D x_t`` a head; ``y = N(y * silu(z))`` over all
-             H P channels; ``out = y W_out``.
+  mamba      models/mamba2.py's mixer (which has the equations): H
+             heads of P channels, N states, ``mamba_n_groups`` groups of
+             B and C (one as published), the gated norm over each
+             group's channels.
 
 TPU-native structure: ``layer_types`` is cut into runs of like layers
 (the cell's: mamba x5, attention, mamba x4), each run ONE stacked group
@@ -32,12 +26,11 @@ run by one body (models/layer_stack.py), and one recomputation budget is
 spent over all of them (``checkpoint_layers(groups, ...)``).  The scan is
 ``ops/ssd_scan.py``'s chunked matrix form at ``mamba_chunk_size``; the
 attention ``ops/flash_attention.py`` with ``sm_scale =
-attention_multiplier``; the FFN models/laguna.py's ``gated_ffn``; the
-head ``ops/fused_cross_entropy.py`` on the transposed table, the
-division by ``logits_scaling`` folded into the final norm's output (a
-power of two there, exact in any float).  Inside scope ``ssm`` the
-mixer names its parts (profiling/scope_map.py PARTS): ``ssm_in``,
-``ssm_conv``, ``ssm_scan``, ``ssm_gate``, ``ssm_out``.
+attention_multiplier`` (``position_free_attention``, which
+models/nemotron_h.py runs too); the FFN models/laguna.py's
+``gated_ffn``; the head ``ops/fused_cross_entropy.py`` on the transposed
+table, the division by ``logits_scaling`` folded into the final norm's
+output (a power of two there, exact in any float).
 """
 
 import math
@@ -49,7 +42,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..monitor import record as R
-from ..ops import ssd_scan as ssd
 from ..ops.flash_attention import flash_attention
 from ..ops.fused_cross_entropy import even_chunk, fused_linear_cross_entropy
 from ..ops.normalize import rms_norm
@@ -58,6 +50,7 @@ from ..runtime.activation_checkpointing.checkpointing import (
 from ..utils.logging import log_dist
 from .laguna import gated_ffn
 from .layer_stack import resolve_use_scan, run_layer_stack
+from .mamba2 import Mamba2Mixer, causal_conv, gated_rms_norm  # noqa: F401
 
 MAMBA, ATTENTION = "mamba", "attention"
 # the released stack: an attention layer at 5, 15, 25, 35 of 40
@@ -101,16 +94,21 @@ class GraniteHybridConfig:
                 f"each '{MAMBA}' or '{ATTENTION}': {self.layer_types}")
         if self.mamba_n_heads * self.mamba_d_head != \
                 self.mamba_expand * self.hidden_size:
-            raise ValueError("mamba_n_heads x mamba_d_head must be "
-                             "mamba_expand x hidden_size")
+            # this family's published in_proj is sized by the expansion;
+            # the mixer itself (models/mamba2.py) reads heads x head size
+            raise ValueError(
+                f"the mixer's inner width, mamba_n_heads x mamba_d_head = "
+                f"{self.mamba_n_heads * self.mamba_d_head}, is not "
+                f"mamba_expand x hidden_size = "
+                f"{self.mamba_expand * self.hidden_size}")
         if self.hidden_size % self.num_attention_heads or \
                 self.num_attention_heads % self.num_key_value_heads:
             raise ValueError("attention heads must divide the hidden size "
                              "and be a multiple of the key/value heads")
-        if self.mamba_n_groups != 1:
-            raise NotImplementedError(
-                f"mamba_n_groups {self.mamba_n_groups}: ops/ssd_scan.py "
-                "computes one group of B and C shared by every head")
+        if self.mamba_n_heads % self.mamba_n_groups:
+            raise ValueError(
+                f"mamba_n_heads {self.mamba_n_heads} do not divide into "
+                f"mamba_n_groups {self.mamba_n_groups}")
 
     @property
     def dtype(self):
@@ -121,13 +119,12 @@ class GraniteHybridConfig:
         return self.hidden_size // self.num_attention_heads
 
     @property
-    def d_inner(self) -> int:
-        return self.mamba_n_heads * self.mamba_d_head
-
-    @property
-    def conv_dim(self) -> int:
-        """Channels the conv runs over: x, B and C."""
-        return self.d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+    def mixer(self) -> Mamba2Mixer:
+        return Mamba2Mixer(
+            hidden_size=self.hidden_size, n_heads=self.mamba_n_heads,
+            d_head=self.mamba_d_head, d_state=self.mamba_d_state,
+            n_groups=self.mamba_n_groups, d_conv=self.mamba_d_conv,
+            chunk_size=self.mamba_chunk_size, eps=self.rms_norm_eps)
 
     def runs(self):
         """[(kind, first published index, count)] of the runs of like
@@ -141,24 +138,26 @@ class GraniteHybridConfig:
         return [tuple(run) for run in out]
 
 
-def causal_conv(x, w, b):
-    """Depthwise causal conv over positions: x [B, S, C], w [C, taps]
-    (tap j reads position t - (taps - 1) + j), b [C] -> silu(conv + b),
-    in float32, rounded once to x's dtype."""
-    taps, seq = w.shape[1], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    w = w.astype(jnp.float32)
-    out = sum(padded[:, j:j + seq].astype(jnp.float32) * w[:, j]
-              for j in range(taps))
-    return jax.nn.silu(out + b.astype(jnp.float32)).astype(x.dtype)
-
-
-def gated_rms_norm(y, z, gain, eps):
-    """``RMSNorm(y * silu(z)) * gain`` over the whole last dimension (one
-    group), float32 inside, in y's dtype."""
-    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
-    return (g * gain.astype(jnp.float32)).astype(y.dtype)
+def position_free_attention(p, u, heads, kv_heads, dim, sm_scale):
+    """Causal grouped-query attention with NO rotation and no other
+    positional operation: u [B, S, hidden], ``p["qkv_w"]`` [hidden,
+    (heads + 2 kv_heads) dim] (q, then k, then v), ``p["out_w"]``
+    [heads dim, hidden]; the parts named under scope ``attn``."""
+    batch, seq, _ = u.shape
+    with jax.named_scope("attn"):
+        with jax.named_scope("attn_qkv"):
+            q, k, v = jnp.split(
+                u @ p["qkv_w"], [heads * dim, (heads + kv_heads) * dim],
+                axis=-1)
+        with jax.named_scope("attn_layout"):
+            q, k, v = (t.reshape(batch, seq, -1, dim).transpose(
+                0, 2, 1, 3) for t in (q, k, v))
+        with jax.named_scope("attn_core"):
+            a = flash_attention(q, k, v, causal=True, sm_scale=sm_scale)
+        with jax.named_scope("attn_layout"):
+            a = a.transpose(0, 2, 1, 3).reshape(batch, seq, heads * dim)
+        with jax.named_scope("attn_out"):
+            return a @ p["out_w"]
 
 
 class GraniteHybridModel:
@@ -183,6 +182,7 @@ class GraniteHybridModel:
 
     def __init__(self, config: GraniteHybridConfig):
         self.config = config
+        self.mixer = config.mixer
         self._remat_budget = None
         self._stack_plan_logged = None
 
@@ -195,7 +195,6 @@ class GraniteHybridModel:
     def _init_layer(self, rng, kind):
         cfg = self.config
         hid, inter = cfg.hidden_size, cfg.shared_intermediate_size
-        heads, di = cfg.mamba_n_heads, cfg.d_inner
         keys = iter(jax.random.split(rng, 8))
 
         def normal(shape):
@@ -203,23 +202,7 @@ class GraniteHybridModel:
                 next(keys), shape, jnp.float32)
 
         if kind == MAMBA:
-            # dt bias: inverse softplus of steps log-uniform in [1e-3, 0.1]
-            dt = jnp.exp(jax.random.uniform(next(keys), (heads,), jnp.float32)
-                         * (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
-            mixer = {
-                "in_w": normal((hid, di + cfg.conv_dim)),      # z, xBC
-                "dt_w": normal((hid, heads)),
-                # torch's Conv1d default: uniform in +- 1/sqrt(taps)
-                "conv_w": jax.random.uniform(
-                    next(keys), (cfg.conv_dim, cfg.mamba_d_conv),
-                    jnp.float32, -1.0, 1.0) / math.sqrt(cfg.mamba_d_conv),
-                "conv_b": jnp.zeros((cfg.conv_dim,), jnp.float32),
-                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
-                "A_log": jnp.log(jax.random.uniform(
-                    next(keys), (heads,), jnp.float32, 1.0, 16.0)),
-                "D": jnp.ones((heads,), jnp.float32),
-                "norm_w": jnp.ones((di,), jnp.float32),
-                "out_w": normal((di, hid))}
+            mixer = self.mixer.init_params(keys, normal)
         else:
             kv = cfg.num_key_value_heads * cfg.head_dim
             mixer = {"qkv_w": normal((hid, hid + 2 * kv)),
@@ -262,48 +245,13 @@ class GraniteHybridModel:
 
     # -- the two mixers ------------------------------------------------- #
     def _mamba(self, p, u):
-        cfg = self.config
-        batch, seq, _ = u.shape
-        heads, dim, n = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
-        di, f32 = cfg.d_inner, jnp.float32
-        with jax.named_scope("ssm"):
-            with jax.named_scope("ssm_in"):
-                z, xbc = jnp.split(u @ p["in_w"], [di], axis=-1)
-                dt = jnp.dot(u, p["dt_w"], preferred_element_type=f32)
-            with jax.named_scope("ssm_conv"):
-                xbc = causal_conv(xbc, p["conv_w"], p["conv_b"])
-                x, b, c = jnp.split(xbc, [di, di + n], axis=-1)
-            with jax.named_scope("ssm_scan"):
-                y = ssd.ssd_scan(
-                    x.reshape(batch, seq, heads, dim),
-                    jax.nn.softplus(dt + p["dt_bias"].astype(f32)),
-                    -jnp.exp(p["A_log"].astype(f32)),
-                    b[:, :, None], c[:, :, None], p["D"].astype(f32),
-                    chunk=cfg.mamba_chunk_size).reshape(batch, seq, di)
-            with jax.named_scope("ssm_gate"):
-                y = gated_rms_norm(y, z, p["norm_w"], cfg.rms_norm_eps)
-            with jax.named_scope("ssm_out"):
-                return y @ p["out_w"]
+        return self.mixer.apply(p, u)
 
     def _attention(self, p, u):
         cfg = self.config
-        batch, seq, hid = u.shape
-        heads, kv_heads, dim = (cfg.num_attention_heads,
-                                cfg.num_key_value_heads, cfg.head_dim)
-        with jax.named_scope("attn"):
-            with jax.named_scope("attn_qkv"):
-                q, k, v = jnp.split(
-                    u @ p["qkv_w"], [hid, hid + kv_heads * dim], axis=-1)
-            with jax.named_scope("attn_layout"):
-                q, k, v = (t.reshape(batch, seq, -1, dim).transpose(
-                    0, 2, 1, 3) for t in (q, k, v))
-            with jax.named_scope("attn_core"):
-                a = flash_attention(q, k, v, causal=True,
-                                    sm_scale=cfg.attention_multiplier)
-            with jax.named_scope("attn_layout"):
-                a = a.transpose(0, 2, 1, 3).reshape(batch, seq, heads * dim)
-            with jax.named_scope("attn_out"):
-                return a @ p["out_w"]
+        return position_free_attention(
+            p, u, cfg.num_attention_heads, cfg.num_key_value_heads,
+            cfg.head_dim, cfg.attention_multiplier)
 
     def _layer(self, p, x, mixer):
         cfg = self.config
@@ -316,10 +264,7 @@ class GraniteHybridModel:
     def scan_form(self):
         """``kernel`` where ops/ssd_scan.py's Pallas kernels take this
         model's shapes on this backend, else ``xla``."""
-        cfg = self.config
-        return "kernel" if ssd.uses_kernels(
-            cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state,
-            cfg.mamba_chunk_size) else "xla"
+        return self.mixer.scan_form()
 
     def stack_plan(self, batch, seq):
         """The M_STACK_* fields of this stack on [batch, seq] tokens."""
@@ -330,12 +275,11 @@ class GraniteHybridModel:
                 (i, kind, 0) for i, kind in enumerate(cfg.layer_types)),
             R.M_STACK_SSD: (
                 self.scan_form(), cfg.mamba_chunk_size,
-                ssd.entry_state_bytes(
-                    batch, seq, cfg.mamba_n_heads, cfg.mamba_d_head,
-                    cfg.mamba_d_state, cfg.mamba_chunk_size),
+                self.mixer.entry_state_bytes(batch, seq),
                 ", ".join(kind + (f" x{count}" if count > 1 else "")
                           for kind, _, count in cfg.runs()),
-                "scanned" if use_scan else "unrolled")}
+                "scanned" if use_scan else "unrolled",
+                cfg.mamba_n_groups)}
 
     def hidden_states(self, params, input_ids):
         """input_ids [B, S] -> the hidden states before the final norm,

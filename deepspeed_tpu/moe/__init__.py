@@ -1,5 +1,5 @@
 from .dropless import DroplessMoE, Routing, route_topk
-from .experts import ExpertMLP, GatedExpertMLP
+from .experts import ExpertMLP, GatedExpertMLP, ReluSquaredExpertMLP
 from .layer import MoE
 from .sharded_moe import (MOELayer, RoutingStats, TopKGate,
                           collect_routing_stats, emit_routing_stats,

@@ -1,7 +1,10 @@
 """A mixture-of-experts layer that drops no token and pads to no
 capacity: k of E routing, rows sorted by expert, the experts' products as
 one grouped product over ragged counts (ops/grouped_matmul.py), a shared
-expert added once.
+expert added once.  Which expert the routed and the shared ones are is
+the builder's (``expert``: moe/experts.py ``GatedExpertMLP``, silu-gated
+with three matrices, unless told ``ReluSquaredExpertMLP``, not gated,
+with two); the routing, the dispatch and the walk do not look inside.
 
 The layer is told which experts it HOLDS, a contiguous range of the E
 the router scores.  It routes over all E, computes its own experts' part
@@ -292,7 +295,8 @@ class DroplessMoE:
                  experts_held: Optional[Tuple[int, int]] = None,
                  init_std: float = 0.02, selection_bias: bool = False,
                  first_chunk_always: bool = False,
-                 dispatch_headroom: float = 1.0):
+                 dispatch_headroom: float = 1.0,
+                 expert=GatedExpertMLP):
         first, count = experts_held or (0, num_experts)
         if not (0 <= first and count >= 1 and first + count <= num_experts):
             raise ValueError(
@@ -303,8 +307,9 @@ class DroplessMoE:
         self.k = k
         self.score, self.renormalize, self.scale = score, renormalize, scale
         self.experts_held = (first, count)
-        self.expert = GatedExpertMLP(hidden_size, expert_ff_size, init_std)
-        self.shared = (GatedExpertMLP(hidden_size, shared_ff_size, init_std)
+        # ``expert``: the class of the routed experts and of the shared one
+        self.expert = expert(hidden_size, expert_ff_size, init_std)
+        self.shared = (expert(hidden_size, shared_ff_size, init_std)
                        if shared_ff_size else None)
         self.init_std = init_std
         # a float32 leaf [E] beside the router's matrix, added to the
@@ -370,13 +375,15 @@ class DroplessMoE:
         """Bytes of the rows one layer holds at once, forward or backward:
         a chunk of ``capacity`` rows three times at the model's width
         (the gathered rows, their tokens' cotangents, the experts'
-        output) and three times at the experts' width (the first
-        product's result, which is twice as wide, and the gated rows
-        between the two products), and the float32 [tokens, d] sum the
-        chunks add into (tests/unit/test_dropless_chunks.py reads the
-        same off the backward walk's jaxpr)."""
-        return (self.capacity(tokens) * itemsize * 3 * (
-            self.hidden_size + self.expert.d_ff)
+        output) and, at the experts' width, the first product's result
+        (twice as wide under a gate, ``first_widths``) and the rows
+        between the two products, three times in all for a gated expert
+        and twice for one without a gate; and the float32 [tokens, d]
+        sum the chunks add into (tests/unit/test_dropless_chunks.py
+        reads the same off the backward walk's jaxpr)."""
+        return (self.capacity(tokens) * itemsize * (
+            3 * self.hidden_size
+            + (self.expert.first_widths + 1) * self.expert.d_ff)
             + tokens * self.hidden_size * 4)
 
     def route(self, params, x, picks=None) -> Routing:

@@ -6,6 +6,13 @@ them over the expert-data group only.  Under SPMD the stacked [E, ...] expert
 params carry a leading "expert" PartitionSpec instead (each expert-parallel
 shard holds E/ep_size experts), and the gradient reduction scope follows from
 the sharding — no tags needed.
+
+Three experts live here: ``ExpertMLP`` (GeLU with biases, the capacity
+path's default), and the dropless path's two without bias
+(moe/dropless.py takes either as ``expert=``): ``GatedExpertMLP``
+(``silu(gate) * up``, ``w1 [d, 2 ff]``) and ``ReluSquaredExpertMLP`` (no
+gate, ``relu(up)^2``, ``w1 [d, ff]``), both with ``apply`` for one expert
+and ``apply_grouped`` for a stack on rows sorted by expert.
 """
 
 import numpy as np
@@ -71,6 +78,9 @@ class GatedExpertMLP:
     stack of experts ``[G, ...]`` on rows sorted by expert, through the
     grouped product (ops/grouped_matmul.py): no slot buffer, no capacity."""
 
+    # the first product's columns over ``d_ff``: the gate's and the up's
+    first_widths = 2
+
     def __init__(self, d_model: int, d_ff: int, init_std: float = 0.02):
         self.d_model = d_model
         self.d_ff = d_ff
@@ -80,23 +90,37 @@ class GatedExpertMLP:
         k1, k2 = jax.random.split(rng)
         return {
             "w1": self.init_std * jax.random.normal(
-                k1, (self.d_model, 2 * self.d_ff), jnp.float32),
+                k1, (self.d_model, self.first_widths * self.d_ff),
+                jnp.float32),
             "w2": self.init_std * jax.random.normal(
                 k2, (self.d_ff, self.d_model), jnp.float32),
         }
 
     @staticmethod
-    def _gated(h):
+    def _act(h):
+        """What the second product reads of the first's result."""
         gate, up = jnp.split(h, 2, axis=-1)
         return up * jax.nn.silu(gate)
 
     def apply(self, params, x, rng=None):
         h = x @ params["w1"].astype(x.dtype)
-        return self._gated(h) @ params["w2"].astype(x.dtype)
+        return self._act(h) @ params["w2"].astype(x.dtype)
 
     def apply_grouped(self, params, rows, counts):
         """rows [R, d] sorted by expert, counts [G] rows an expert; zero
         past their sum."""
         from ..ops.grouped_matmul import gmm
         h = gmm(rows, params["w1"], counts)
-        return gmm(self._gated(h), params["w2"], counts)
+        return gmm(self._act(h), params["w2"], counts)
+
+
+class ReluSquaredExpertMLP(GatedExpertMLP):
+    """An expert WITHOUT a gate, ``(relu(x Wu))^2 Wd`` without bias: two
+    matrices, ``w1 [d, ff]`` and ``w2 [ff, d]`` (``mlp_hidden_act:
+    relu2``, models/nemotron_h.py); the rest is GatedExpertMLP's."""
+
+    first_widths = 1
+
+    @staticmethod
+    def _act(h):
+        return jnp.square(jax.nn.relu(h))

@@ -154,10 +154,11 @@ M_STACK_PASSES = "stack_passes"
 # [indexer heads, their size, keys kept a query, "kernels" or "xla"
 # (ops/indexed_attention.py: which form index, select, core and align run)]
 M_STACK_INDEXER = "stack_indexer"
-# (models/granite_hybrid.py) Mamba-2 mixers on ops/ssd_scan.py: ["kernel"
-# or "xla", positions a chunk, bytes of chunk-entry states a layer's scan
-# saves, the runs of like layers ("mamba x5, attention, mamba x4"),
-# "scanned" or "unrolled"]
+# (models/granite_hybrid.py, models/nemotron_h.py) Mamba-2 mixers on
+# ops/ssd_scan.py: ["kernel" or "xla", positions a chunk, bytes of
+# chunk-entry states a layer's scan saves, the runs of like layers ("mamba
+# x5, attention, mamba x4"; "M, E, M, E, M, *, E, M, E" where every layer
+# is one sublayer), "scanned" or "unrolled", groups of B and C]
 M_STACK_SSD = "stack_ssd"
 # (models/xing4.py) a residual path of several streams mixed by
 # hyper-connections (ops/hyper_connection.py): [streams, Sinkhorn rounds,

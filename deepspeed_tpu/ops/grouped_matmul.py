@@ -41,15 +41,37 @@ from .dispatch import pallas_available, pallas_interpret
 # so the MXU multiplies (1 + TILE_ROWS / r) times the rows it keeps: 1.5
 # at the 512 rows an expert of the benchmark's cell sees on average.
 TILE_ROWS = 256
-# Columns of the result a grid cell: with k <= 2,048 a bf16 weight block
-# is 2 MB, its double buffer 4.
+# Columns of the result a grid cell where n is whole lane tiles: a bf16
+# weight block of k x 512 is 2 MB at k = 2,048 and 2.75 at 2,688, its
+# double buffer twice that.  An n that is NOT whole lane tiles (1,856 =
+# 14.5 x 128) is one block, the whole of it: a block may be as wide as
+# its array whatever the width, no tile hangs over the edge, nothing is
+# padded or masked, and x is read once instead of once a column block
+# (my chip run, PR 60, PERF.md section 6).  Its double buffer has to fit
+# (``_whole_fits``): 20 MB at 2,688 x 1,856.
 TILE_COLS = 512
+_LANES = 128
+# what a width that is not whole lane tiles must still be a multiple of:
+# half a tile, the packing of a bf16 sublane pair
+_HALF = 64
 _VMEM_LIMIT = 64 * 1024 * 1024
 
 
+def _whole_fits(k, n):
+    """Whether a [k, n] bf16 weight block's double buffer leaves half of
+    the kernels' VMEM to the row tiles and the accumulator."""
+    return 2 * 2 * k * n <= _VMEM_LIMIT // 2
+
+
 def _use_pallas(rows, k, n):
+    """Whether the kernels take ``[rows, k] x [G, k, n]`` (and so its two
+    backward products, which swap k and n): whole lane tiles, or half
+    tiles (multiples of 64) taken as one block where that fits."""
+    def takes(width, other):
+        return width % _LANES == 0 or (
+            width % _HALF == 0 and _whole_fits(other, width))
     return ((pallas_available() or pallas_interpret())
-            and k % 128 == 0 and n % 128 == 0 and rows % 8 == 0)
+            and takes(k, n) and takes(n, k) and rows % 8 == 0)
 
 
 # ---------------------------------------------------------------------- #
@@ -191,9 +213,14 @@ def _tile_rows(rows):
 
 
 def _tile_cols(n):
+    """Columns a grid cell of an n-wide result: the largest multiple of
+    128 up to TILE_COLS that divides an n of whole lane tiles; any other
+    n whole (the module's text says why)."""
+    if n % _LANES:
+        return n
     cols = min(TILE_COLS, n)
     while n % cols:
-        cols -= 128
+        cols -= _LANES
     return cols
 
 
@@ -214,7 +241,7 @@ def _pallas_rows(x, w, counts, transposed):
         functools.partial(_rows_kernel, tile=tile, transposed=transposed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
-            grid=(n // cols, rows // tile + n_groups),
+            grid=(pl.cdiv(n, cols), rows // tile + n_groups),
             in_specs=[
                 pl.BlockSpec((tile, k),
                              lambda j, i, group, in_tile, *_: (in_tile[i], 0)),
@@ -243,7 +270,7 @@ def _pallas_weights(x, dy, counts):
         functools.partial(_weights_kernel, tile=tile),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
-            grid=(n // cols, rows // tile + n_groups),
+            grid=(pl.cdiv(n, cols), rows // tile + n_groups),
             in_specs=[
                 pl.BlockSpec((tile, k),
                              lambda j, i, group, in_tile, *_: (in_tile[i], 0)),

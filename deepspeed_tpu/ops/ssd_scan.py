@@ -25,7 +25,10 @@ chunk c's entry:
     G_{c+1} = exp(s_Q) G_c + sum_j exp(s_Q - s_j) xb_j (x) B_j     (state)
 
 Every exponent is <= 0, so nothing overflows whatever dt is.  ``C B^T``
-is one [Q, Q] product a chunk for all the heads.  The forward pass saves
+is one [Q, Q] product a chunk for all the heads of a GROUP: head h of H
+reads group ``h // (H / G)`` of the G that B and C come in (G = 1: one
+product a chunk for every head), and ``dB`` / ``dC`` are sums over a
+group's heads.  The forward pass saves
 the chunk-entry states ([S / Q, H, P, N] float32 a row: 33.6 MB at 4,096
 x 64 x 64 x 128, where every position's state would be 8.6 GB); the
 backward pass walks the chunks in reverse from them.  No
@@ -36,7 +39,7 @@ LEAVES chunk c (0 after the last) and ``e_i = exp(s_i)``, ``f_j =
 exp(s_Q - s_j)``:
 
     dxb_j = sum_i M_ij dy_i + f_j dG_c B_j
-    d(C B^T)_ij = sum_h exp(s_i - s_j) dy_i . xb_j                (i >= j)
+    d(C B^T)_ij = sum_h exp(s_i - s_j) dy_i . xb_j     (i >= j; h: the group's)
     dC_i = sum_j d(CB^T)_ij B_j + sum_h e_i G_c^T dy_i
     dB_j = sum_i d(CB^T)_ij C_i + sum_h f_j dG_c^T xb_j
     dG_{c-1} = exp(s_Q) dG_c + sum_i e_i dy_i (x) C_i
@@ -62,7 +65,11 @@ Two forms, one switch (the backend, as for the other ops: dispatch.py):
   ([S, H P], channels on the lanes); a grid cell is one chunk of one
   block of SSD_HEADS heads, the chunks walked in order (in reverse,
   backward) with the blocks' states ([H P, N] float32) carried in VMEM
-  scratch.  Heads of 64 lie two a lane tile: a head's [Q, Q] matrix
+  scratch.  A head block lies inside one group (``kernels_take``): it
+  reads its group's N columns of B and C, the group's first block builds
+  ``C B^T`` for the blocks after it and its last one turns the summed
+  ``d(C B^T)`` into the group's dB and dC (G = H / SSD_HEADS: a block IS
+  a group and does all three).  Heads of 64 lie two a lane tile: a head's [Q, Q] matrix
   multiplies the whole tile and the lanes of its own head are selected,
   which on a 128-wide MXU costs what the half tile would.  The products
   run on the MXU with bf16 operands and float32 accumulation: x, B, C and
@@ -77,13 +84,15 @@ Two forms, one switch (the backend, as for the other ops: dispatch.py):
   less than the bf16 of the projections around the scan does.
 - plain XLA elsewhere, and as the kernels' twin in tests: a lax.scan
   over the chunks, the same products as einsums in float32, the same
-  saved entries and the same backward formulas.
+  saved entries and the same backward formulas, mapped over the groups
+  where there are several.
 
-Refused at trace time, with a message: more than one group of B and C
-(G > 1) on either form.  A sequence that is no multiple of the chunk is
-padded with positions of dt = 0, which leave the state as it is.  Heads
-of another size than 64, a head count that is no multiple of SSD_HEADS
-or a state that is no multiple of 128 take the XLA form.
+A sequence that is no multiple of the chunk is padded with positions of
+dt = 0, which leave the state as it is.  Heads of another size than 64,
+a group of heads that is no multiple of SSD_HEADS (a head block would
+straddle two groups) or a state that is no multiple of 128 take the XLA
+form; a head count that is no multiple of the groups is refused at trace
+time.
 """
 
 import functools
@@ -109,16 +118,19 @@ def entry_state_bytes(batch, seq, heads, head_dim, states, chunk=CHUNK):
     return batch * -(-seq // chunk) * heads * head_dim * states * 4
 
 
-def kernels_take(heads, head_dim, states, chunk):
-    """Whether the Pallas kernels are written for these shapes."""
-    return (head_dim == _HEAD and heads % SSD_HEADS == 0
+def kernels_take(heads, head_dim, states, chunk, groups=1):
+    """Whether the Pallas kernels are written for these shapes: heads of
+    64 whose every block of SSD_HEADS lies inside one of the ``groups``
+    of B and C, ``states`` a group and ``chunk`` whole lane tiles."""
+    return (head_dim == _HEAD and heads % groups == 0
+            and (heads // groups) % SSD_HEADS == 0
             and states % _LANES == 0 and chunk % _LANES == 0)
 
 
-def uses_kernels(heads, head_dim, states, chunk):
+def uses_kernels(heads, head_dim, states, chunk, groups=1):
     """Whether a call of these shapes runs the Pallas kernels here."""
     return ((pallas_available() or pallas_interpret())
-            and kernels_take(heads, head_dim, states, chunk))
+            and kernels_take(heads, head_dim, states, chunk, groups))
 
 
 # ---------------------------------------------------------------------- #
@@ -192,6 +204,46 @@ def _xla_bwd(x, dt, s, b_mat, c_mat, entries, dy):
     return out
 
 
+def _heads_split(t, groups, axis=2):
+    """[.., H, ..] -> [.., G, H / G, ..] at ``axis``."""
+    return t.reshape(*t.shape[:axis], groups, -1, *t.shape[axis + 1:])
+
+
+def _heads_joined(t, axis=2):
+    """[.., G, H / G, ..] -> [.., H, ..] at ``axis``."""
+    return t.reshape(*t.shape[:axis], -1, *t.shape[axis + 2:])
+
+
+def _xla_fwd_groups(x, dt, s, b_mat, c_mat):
+    """``_xla_fwd`` for a batch row whose b_mat and c_mat come in groups,
+    [n_chunks, Q, G, N]: the one-group form mapped over the G groups of
+    H / G heads each.  One group: that form itself, the program of
+    before."""
+    groups = b_mat.shape[2]
+    if groups == 1:
+        return _xla_fwd(x, dt, s, b_mat[:, :, 0], c_mat[:, :, 0])
+    y, entries = jax.vmap(_xla_fwd, in_axes=2, out_axes=(2, 1))(
+        *(_heads_split(t, groups) for t in (x, dt, s)), b_mat, c_mat)
+    return _heads_joined(y), _heads_joined(entries, 1)
+
+
+def _xla_bwd_groups(x, dt, s, b_mat, c_mat, entries, dy):
+    """``_xla_bwd`` for a row of G groups (as ``_xla_fwd_groups``); dB and
+    dC [n_chunks, Q, G, N]."""
+    groups = b_mat.shape[2]
+    if groups == 1:
+        dxb, d_b, d_c, *rest = _xla_bwd(x, dt, s, b_mat[:, :, 0],
+                                        c_mat[:, :, 0], entries, dy)
+        return (dxb, d_b[:, :, None], d_c[:, :, None], *rest)
+    dxb, d_b, d_c, dg, first, second, third = jax.vmap(
+        _xla_bwd, in_axes=(2, 2, 2, 2, 2, 1, 2),
+        out_axes=(2, 2, 2, 1, 2, 2, 2))(
+        *(_heads_split(t, groups) for t in (x, dt, s)), b_mat, c_mat,
+        _heads_split(entries, groups, 1), _heads_split(dy, groups))
+    return (_heads_joined(dxb), d_b, d_c, _heads_joined(dg, 1),
+            *(_heads_joined(t) for t in (first, second, third)))
+
+
 # ---------------------------------------------------------------------- #
 # Pallas kernels
 # ---------------------------------------------------------------------- #
@@ -228,8 +280,15 @@ def _across(s_end, width):
     return jnp.exp(jnp.broadcast_to(s_end, (1, width)))
 
 
+def _in_group(grp, blocks, per_group):
+    """Where head block ``grp`` of ``blocks`` lies among the ``per_group``
+    blocks that read its group's B and C (one group: ``grp`` itself)."""
+    return grp if per_group == blocks else grp % per_group
+
+
 def _ssd_fwd_kernel(x_ref, col_ref, row_ref, b_ref, c_ref, d_ref,
-                    y_ref, entry_ref, g_scr, cb_scr, *, heads):
+                    y_ref, entry_ref, g_scr, cb_scr, *, heads, blocks,
+                    per_group):
     ci, grp = pl.program_id(1), pl.program_id(2)
     q = x_ref.shape[1]
     per = _LANES // _HEAD
@@ -241,7 +300,8 @@ def _ssd_fwd_kernel(x_ref, col_ref, row_ref, b_ref, c_ref, d_ref,
 
     b_mat, c_mat = b_ref[0], c_ref[0]                    # [Q, N] bf16
 
-    @pl.when(grp == 0)      # C B^T: once a chunk, for every head
+    # C B^T: once a chunk, for every head of the group
+    @pl.when(_in_group(grp, blocks, per_group) == 0)
     def _():
         cb_scr[...] = _nt(c_mat, b_mat)
 
@@ -283,8 +343,9 @@ def _ssd_fwd_kernel(x_ref, col_ref, row_ref, b_ref, c_ref, d_ref,
 
 def _ssd_bwd_kernel(x_ref, col_ref, row_ref, b_ref, c_ref, entry_ref, dy_ref,
                     dxb_ref, db_ref, dc_ref, dg_ref, dcol_ref, drow_ref,
-                    dg_scr, cbt_scr, dcbt_scr, *, heads, groups):
+                    dg_scr, cbt_scr, dcbt_scr, *, heads, blocks, per_group):
     step, grp = pl.program_id(1), pl.program_id(2)
+    in_group = _in_group(grp, blocks, per_group)
     q = x_ref.shape[1]
     per = _LANES // _HEAD
     bf16, f32 = jnp.bfloat16, jnp.float32
@@ -295,7 +356,7 @@ def _ssd_bwd_kernel(x_ref, col_ref, row_ref, b_ref, c_ref, entry_ref, dy_ref,
 
     b_mat, c_mat = b_ref[0], c_ref[0]                    # [Q, N] bf16
 
-    @pl.when(grp == 0)      # the transposes of C B^T and of its cotangent
+    @pl.when(in_group == 0)  # the transposes of C B^T and of its cotangent
     def _():
         cbt_scr[...] = _nt(b_mat, c_mat)                 # [j, i]
         dcbt_scr[...] = jnp.zeros(dcbt_scr.shape, f32)
@@ -369,24 +430,31 @@ def _ssd_bwd_kernel(x_ref, col_ref, row_ref, b_ref, c_ref, entry_ref, dy_ref,
     dc_ref[0] += d_c
     db_ref[0] += d_b
 
-    @pl.when(grp == groups - 1)     # every head's share of d(C B^T) is in
+    # every head of the group has put its share of d(C B^T) in
+    @pl.when(in_group == per_group - 1)
     def _():
         dcbt = dcbt_scr[...].astype(bf16)
         db_ref[0] += _nn(dcbt, c_mat)
         dc_ref[0] += _tn(dcbt, b_mat)
 
 
-def _specs(chunk, width, heads, states, reverse_of=None):
+def _specs(chunk, width, heads, states, blocks, per_group, reverse_of=None):
     """BlockSpecs of the kernels' operands on the grid (batch, chunk,
-    head block); ``reverse_of`` = n_chunks walks the chunks backwards."""
+    head block); ``reverse_of`` = n_chunks walks the chunks backwards.
+    B and C lie flat, [batch, S, G N]: head block j of ``blocks`` reads
+    the ``states`` columns of group ``j // per_group`` (one group, every
+    block in it: the first columns)."""
     def c(i):
         return i if reverse_of is None else reverse_of - 1 - i
+
+    def g(j):
+        return 0 if per_group == blocks else j // per_group
     seq = pl.BlockSpec((1, chunk, width), lambda b, i, j: (b, c(i), j))
     cols = pl.BlockSpec((1, 1, chunk, 2 * heads),
                         lambda b, i, j: (b, j, c(i), 0))
     rows = pl.BlockSpec((1, 1, heads, chunk),
                         lambda b, i, j: (b, j, 0, c(i)))
-    mat = pl.BlockSpec((1, chunk, states), lambda b, i, j: (b, c(i), 0))
+    mat = pl.BlockSpec((1, chunk, states), lambda b, i, j: (b, c(i), g(j)))
     vec = pl.BlockSpec((1, width), lambda b, i, j: (0, j))
     state = pl.BlockSpec((1, 1, width, states),
                          lambda b, i, j: (b, c(i), j, 0))
@@ -415,18 +483,20 @@ def _head_blocks(dt, s, heads):
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def _pallas_fwd(x, dt, s, b_mat, c_mat, d_vec, *, chunk, interpret):
     """x [batch, S, H, P] (S a multiple of chunk), dt and s [batch, S, H]
-    float32, b_mat and c_mat [batch, S, N], d_vec [H] -> (y, entries
+    float32, b_mat and c_mat [batch, S, G, N], d_vec [H] -> (y, entries
     [batch, S / chunk, H, P, N])."""
     batch, seq, num_heads, dim = x.shape
-    states = b_mat.shape[-1]
+    bc_groups, states = b_mat.shape[2:]
     width, heads = SSD_HEADS * dim, SSD_HEADS
     n_chunks, groups = seq // chunk, num_heads // heads
-    seq_s, cols_s, rows_s, mat, vec, state = _specs(chunk, width, heads,
-                                                    states)
+    per_group = groups // bc_groups     # head blocks a group of B and C
+    seq_s, cols_s, rows_s, mat, vec, state = _specs(
+        chunk, width, heads, states, groups, per_group)
     cols, rows = _head_blocks(dt, s, heads)
     bf16 = jnp.bfloat16
     y, entries = pl.pallas_call(
-        functools.partial(_ssd_fwd_kernel, heads=heads),
+        functools.partial(_ssd_fwd_kernel, heads=heads, blocks=groups,
+                          per_group=per_group),
         grid=(batch, n_chunks, groups),
         in_specs=[seq_s, cols_s, rows_s, mat, mat, vec],
         out_specs=[seq_s, state],
@@ -439,7 +509,8 @@ def _pallas_fwd(x, dt, s, b_mat, c_mat, d_vec, *, chunk, interpret):
         interpret=interpret, name="ssd_fwd",
         **_compiler_params(interpret),
     )(x.reshape(batch, seq, -1).astype(bf16), cols, rows,
-      b_mat.astype(bf16), c_mat.astype(bf16),
+      b_mat.reshape(batch, seq, -1).astype(bf16),
+      c_mat.reshape(batch, seq, -1).astype(bf16),
       jnp.repeat(d_vec.astype(jnp.float32), dim)[None])
     return (y.reshape(x.shape),
             entries.reshape(batch, n_chunks, num_heads, dim, states))
@@ -447,23 +518,26 @@ def _pallas_fwd(x, dt, s, b_mat, c_mat, d_vec, *, chunk, interpret):
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def _pallas_bwd(x, dt, s, b_mat, c_mat, entries, dy, *, chunk, interpret):
-    """(dxb [batch, S, H, P] bf16, dB, dC [batch, S, N] float32, the
+    """(dxb [batch, S, H, P] bf16, dB, dC [batch, S, G, N] float32, the
     cotangent of the state leaving each chunk, shaped as ``entries``,
     and the three parts of da, [batch, S, H] each: ``_xla_bwd``'s)."""
     batch, seq, num_heads, dim = x.shape
-    states = b_mat.shape[-1]
+    bc_groups, states = b_mat.shape[2:]
     width, heads = SSD_HEADS * dim, SSD_HEADS
     n_chunks, groups = seq // chunk, num_heads // heads
-    seq_s, cols_s, rows_s, mat, _, state = _specs(chunk, width, heads,
-                                                  states, n_chunks)
+    per_group = groups // bc_groups     # head blocks a group of B and C
+    seq_s, cols_s, rows_s, mat, _, state = _specs(
+        chunk, width, heads, states, groups, per_group, n_chunks)
     cols, rows = _head_blocks(dt, s, heads)
     bf16 = jnp.bfloat16
     flat = jax.ShapeDtypeStruct((batch, seq, num_heads * dim), bf16)
-    narrow = jax.ShapeDtypeStruct((batch, seq, states), jnp.float32)
+    narrow = jax.ShapeDtypeStruct((batch, seq, bc_groups * states),
+                                  jnp.float32)
     wide = jax.ShapeDtypeStruct(
         (batch, n_chunks, num_heads * dim, states), jnp.float32)
     dxb, d_b, d_c, dg, dcol, drow = pl.pallas_call(
-        functools.partial(_ssd_bwd_kernel, heads=heads, groups=groups),
+        functools.partial(_ssd_bwd_kernel, heads=heads, blocks=groups,
+                          per_group=per_group),
         grid=(batch, n_chunks, groups),
         in_specs=[seq_s, cols_s, rows_s, mat, mat, state, seq_s],
         out_specs=[seq_s, mat, mat, state, cols_s, rows_s],
@@ -476,14 +550,16 @@ def _pallas_bwd(x, dt, s, b_mat, c_mat, entries, dy, *, chunk, interpret):
         interpret=interpret, name="ssd_bwd",
         **_compiler_params(interpret),
     )(x.reshape(batch, seq, -1).astype(bf16), cols, rows,
-      b_mat.astype(bf16), c_mat.astype(bf16),
+      b_mat.reshape(batch, seq, -1).astype(bf16),
+      c_mat.reshape(batch, seq, -1).astype(bf16),
       entries.reshape(batch, n_chunks, num_heads * dim, states),
       dy.reshape(batch, seq, -1).astype(bf16))
     # back from the kernels' head blocks to [batch, S, H]
     dcol = dcol.transpose(0, 2, 1, 3).reshape(batch, seq, groups, 2, heads)
     second, third = (dcol[:, :, :, i].reshape(dt.shape) for i in (0, 1))
     first = drow.transpose(0, 3, 1, 2).reshape(dt.shape)
-    return (dxb.reshape(x.shape), d_b, d_c, dg.reshape(entries.shape),
+    return (dxb.reshape(x.shape), d_b.reshape(b_mat.shape),
+            d_c.reshape(b_mat.shape), dg.reshape(entries.shape),
             first, second, third)
 
 
@@ -510,6 +586,11 @@ def _running(dt, a_vec, chunk):
     return jnp.cumsum(a, axis=2).reshape(dt.shape)
 
 
+def _takes_kernels(x, b_mat, chunk):
+    return uses_kernels(x.shape[2], x.shape[3], b_mat.shape[3], chunk,
+                        b_mat.shape[2])
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def _scan(x, dt, a_vec, b_mat, c_mat, d_vec, chunk):
     return _scan_fwd(x, dt, a_vec, b_mat, c_mat, d_vec, chunk)[0]
@@ -520,12 +601,12 @@ def _scan_fwd(x, dt, a_vec, b_mat, c_mat, d_vec, chunk):
     xp, dtp, bp, cp = (_padded(t, chunk) for t in (
         x, dt.astype(f32), b_mat, c_mat))
     s = _running(dtp, a_vec.astype(f32), chunk)
-    if uses_kernels(x.shape[2], x.shape[3], b_mat.shape[-1], chunk):
+    if _takes_kernels(x, b_mat, chunk):
         y, entries = _pallas_fwd(xp, dtp, s, bp, cp, d_vec, chunk=chunk,
                                  interpret=pallas_interpret())
     else:
-        y, entries = jax.vmap(_xla_fwd)(*(_chunked(t.astype(f32), chunk)
-                                          for t in (xp, dtp, s, bp, cp)))
+        y, entries = jax.vmap(_xla_fwd_groups)(*(
+            _chunked(t.astype(f32), chunk) for t in (xp, dtp, s, bp, cp)))
         y = y.reshape(xp.shape) + d_vec.astype(f32)[:, None] * xp
     return y[:, :seq].astype(x.dtype), (x, dt, a_vec, b_mat, c_mat, d_vec,
                                         entries)
@@ -538,12 +619,12 @@ def _scan_bwd(chunk, res, dy):
         x, dt.astype(f32), b_mat, c_mat, dy))
     af, df = a_vec.astype(f32), d_vec.astype(f32)[:, None]
     s = _running(dtp, af, chunk)
-    if uses_kernels(x.shape[2], x.shape[3], b_mat.shape[-1], chunk):
+    if _takes_kernels(x, b_mat, chunk):
         dxb, d_b, d_c, dg, first, second, third = _pallas_bwd(
             xp, dtp, s, bp, cp, entries, dyp, chunk=chunk,
             interpret=pallas_interpret())
     else:
-        dxb, d_b, d_c, dg, first, second, third = jax.vmap(_xla_bwd)(
+        dxb, d_b, d_c, dg, first, second, third = jax.vmap(_xla_bwd_groups)(
             *(_chunked(t.astype(f32), chunk) for t in (xp, dtp, s, bp, cp)),
             entries, _chunked(dyp.astype(f32), chunk))
         dxb = dxb.reshape(xp.shape)
@@ -575,29 +656,28 @@ def ssd_scan(x, dt, a, b, c, d, chunk=CHUNK):
     """y [batch, S, H, P] of the recurrence in the module's text, in x's
     dtype; differentiable in all six operands.  x [batch, S, H, P], dt
     [batch, S, H] (positive), a [H] (negative), b and c [batch, S, G, N]
-    with G = 1, d [H].  Under a device mesh the kernels run in a region
-    manual over every axis, the batch split over the data axes."""
+    with H a multiple of G (head h reads group ``h // (H / G)``), d [H].
+    Under a device mesh the kernels run in a region manual over every
+    axis, the batch split over the data axes."""
     if x.ndim != 4 or dt.shape != x.shape[:3] or b.shape != c.shape \
             or b.ndim != 4 or b.shape[:2] != x.shape[:2]:
         raise ValueError(
             f"ssd_scan: x {x.shape} must be [batch, S, H, P], dt "
             f"{dt.shape} [batch, S, H], b {b.shape} and c {c.shape} "
             "[batch, S, G, N]")
-    if b.shape[2] != 1:
-        raise NotImplementedError(
-            f"ssd_scan: {b.shape[2]} groups of B and C; one group shared "
-            "by every head is what is written (a head would read its "
-            "group's C B^T)")
+    if x.shape[2] % b.shape[2]:
+        raise ValueError(
+            f"ssd_scan: {x.shape[2]} heads do not divide into the "
+            f"{b.shape[2]} groups of B and C")
     if chunk < 1:
         raise ValueError(f"ssd_scan: chunk {chunk}")
-    b_mat, c_mat = b[:, :, 0], c[:, :, 0]
-    if not uses_kernels(x.shape[2], x.shape[3], b.shape[-1], chunk):
-        return _scan(x, dt, a, b_mat, c_mat, d, chunk)
+    if not _takes_kernels(x, b, chunk):
+        return _scan(x, dt, a, b, c, d, chunk)
     rows = {0: BATCH_AXES}
 
-    def local(_, x, dt, a, b_mat, c_mat, d):
-        return _scan(x, dt, a, b_mat, c_mat, d, chunk)
+    def local(_, x, dt, a, b, c, d):
+        return _scan(x, dt, a, b, c, d, chunk)
 
     return manual_kernel_region(
-        local, (x, dt, a, b_mat, c_mat, d),
+        local, (x, dt, a, b, c, d),
         (rows, rows, None, rows, rows, None), rows)

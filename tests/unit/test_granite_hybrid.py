@@ -187,9 +187,9 @@ def test_the_stack_plan_and_its_line(sides):
     assert plan[R.M_STACK_LAYERS] == (
         (0, "mamba", 0), (1, "mamba", 0), (2, "attention", 0),
         (3, "mamba", 0))
-    form, chunk, entry_bytes, runs, mode = plan[R.M_STACK_SSD]
-    assert (form, chunk, runs, mode) == (
-        "xla", 16, "mamba x2, attention, mamba", "unrolled")
+    form, chunk, entry_bytes, runs, mode, groups = plan[R.M_STACK_SSD]
+    assert (form, chunk, runs, mode, groups) == (
+        "xla", 16, "mamba x2, attention, mamba", "unrolled", 1)
     # 2 rows x 3 chunks x 8 heads x 16 x 16 states, float32
     assert entry_bytes == 2 * 3 * 8 * 16 * 16 * 4
     line = stack_plan_line(plan)
@@ -201,8 +201,14 @@ def test_the_stack_plan_and_its_line(sides):
     cut = GraniteHybridConfig(num_hidden_layers=10, vocab_size=12544)
     assert cut.runs() == [("mamba", 0, 5), ("attention", 5, 1),
                           ("mamba", 6, 4)]
-    with pytest.raises(NotImplementedError, match="mamba_n_groups 8"):
-        GraniteHybridConfig(mamba_n_groups=8)
+    # several groups of B and C are the shared mixer's to run
+    # (models/mamba2.py); heads that do not divide into them are refused
+    assert GraniteHybridConfig(mamba_n_groups=8).mixer.conv_dim == (
+        4096 + 2 * 8 * 128)
+    with pytest.raises(ValueError, match="mamba_n_groups 3"):
+        GraniteHybridConfig(mamba_n_groups=3)
+    with pytest.raises(ValueError, match="inner width"):
+        GraniteHybridConfig(mamba_n_heads=32)
     with pytest.raises(ValueError, match="layer_types"):
         GraniteHybridConfig(num_hidden_layers=2,
                             layer_types=("mamba", "conv"))

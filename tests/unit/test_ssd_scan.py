@@ -21,14 +21,17 @@ NAMES = ("x", "dt", "a", "b", "c", "d")
 
 
 def recurrence(x, dt, a, b, c, d):
-    """The module's first two equations, one position at a time."""
+    """The module's first two equations, one position at a time; head h
+    of H reads group h // (H / G) of b and c [batch, S, G, N]."""
+    per = x.shape[2] // b.shape[2]
+
     def row(x, dt, b, c):
         def step(state, at):
             x_t, dt_t, b_t, c_t = at
+            b_t, c_t = (jnp.repeat(t, per, axis=0) for t in (b_t, c_t))
             state = (jnp.exp(dt_t * a)[:, None, None] * state
-                     + (dt_t[:, None] * x_t)[:, :, None]
-                     * b_t[0][None, None, :])
-            return state, (jnp.sum(state * c_t[0][None, None, :], axis=-1)
+                     + (dt_t[:, None] * x_t)[:, :, None] * b_t[:, None, :])
+            return state, (jnp.sum(state * c_t[:, None, :], axis=-1)
                            + d[:, None] * x_t)
         zero = jnp.zeros(x.shape[1:] + b.shape[-1:], jnp.float32)
         return jax.lax.scan(step, zero, (x, dt, b, c))[1]
@@ -36,15 +39,15 @@ def recurrence(x, dt, a, b, c, d):
 
 
 def operands(batch, seq, heads, dim, states, seed=0, dt_shift=-2.0,
-             dt_scale=1.0):
+             dt_scale=1.0, groups=1):
     k = jax.random.split(jax.random.PRNGKey(seed), 7)
     args = (jax.random.normal(k[0], (batch, seq, heads, dim)),
             dt_scale * jax.nn.softplus(
                 jax.random.normal(k[1], (batch, seq, heads)) + dt_shift),
             -jnp.exp(jax.random.uniform(k[2], (heads,), minval=0.0,
                                         maxval=2.7)),
-            0.3 * jax.random.normal(k[3], (batch, seq, 1, states)),
-            0.3 * jax.random.normal(k[4], (batch, seq, 1, states)),
+            0.3 * jax.random.normal(k[3], (batch, seq, groups, states)),
+            0.3 * jax.random.normal(k[4], (batch, seq, groups, states)),
             jax.random.normal(k[5], (heads,)))
     return args, jax.random.normal(k[6], (batch, seq, heads, dim))
 
@@ -126,9 +129,9 @@ def test_decays_near_one_and_near_zero(form, case, request):
 
 def test_what_is_refused_is_refused_with_a_message():
     (x, dt, a, b, c, d), _ = operands(1, 32, 4, 8, 16)
-    two = jnp.concatenate([b, b], axis=2)
-    with pytest.raises(NotImplementedError, match="2 groups"):
-        ssd.ssd_scan(x, dt, a, two, two, d, chunk=16)
+    three = jnp.concatenate([b, b, b], axis=2)
+    with pytest.raises(ValueError, match="3 groups"):
+        ssd.ssd_scan(x, dt, a, three, three, d, chunk=16)
     with pytest.raises(ValueError, match="must be"):
         ssd.ssd_scan(x.reshape(1, 32, 32), dt, a, b, c, d)
     with pytest.raises(ValueError, match="must be"):
@@ -139,6 +142,12 @@ def test_what_is_refused_is_refused_with_a_message():
     for shape in ((64, 32, 128, 256), (6, 64, 128, 256), (64, 64, 64, 256),
                   (64, 64, 128, 96)):
         assert not ssd.kernels_take(*shape), shape
+    # a block of eight heads lies inside one group of B and C, or the
+    # XLA form runs: 64 heads in 8 groups are a block each, in 16 half one
+    assert ssd.kernels_take(64, 64, 128, 128, groups=8)
+    assert ssd.kernels_take(64, 64, 128, 128, groups=4)
+    assert not ssd.kernels_take(64, 64, 128, 128, groups=16)
+    assert not ssd.kernels_take(64, 64, 128, 128, groups=3)
 
 
 @pytest.mark.parametrize("form", ["xla", "kernel"])
@@ -156,8 +165,8 @@ def test_what_the_forward_saves_is_the_chunk_entry_states(form, request):
     shapes = (jax.ShapeDtypeStruct((batch, seq, heads, dim), bf16),
               jax.ShapeDtypeStruct((batch, seq, heads), f32),
               jax.ShapeDtypeStruct((heads,), f32),
-              jax.ShapeDtypeStruct((batch, seq, states), bf16),
-              jax.ShapeDtypeStruct((batch, seq, states), bf16),
+              jax.ShapeDtypeStruct((batch, seq, 1, states), bf16),
+              jax.ShapeDtypeStruct((batch, seq, 1, states), bf16),
               jax.ShapeDtypeStruct((heads,), f32))
     y, saved = jax.eval_shape(
         lambda *a: ssd._scan_fwd(*a, chunk), *shapes)
@@ -213,7 +222,7 @@ def test_the_kernels_compile_for_v5e_at_the_cells_shapes(one_chip):
     bf16, f32 = jnp.bfloat16, jnp.float32
     x = on_chip((batch, seq, heads, dim), bf16)
     per_head = on_chip((batch, seq, heads), f32)
-    narrow = on_chip((batch, seq, states), bf16)
+    narrow = on_chip((batch, seq, 1, states), bf16)
     entries = on_chip((batch, seq // chunk, heads, dim, states), f32)
     for name, fn, args in (
             ("ssd_fwd", ssd._pallas_fwd.__wrapped__,
