@@ -52,11 +52,18 @@ when j < k, and in exp(s_Q) always.  Taken as ds_i first and summed from
 the chunk's end, the first term is a difference of a row sum and a
 column sum that all but cancel; as written no term cancels.)  Then
 ``ddt = x . dxb + A da``, ``dA = sum da dt``, ``dx = dt dxb + D dy``,
-``dD = sum dy . x``.  The products and the three sums that need a
-chunk's [Q, Q] matrices or its states are the kernels'; the sums over a
-head's channels that follow them (ddt, dA, dD, dx, the running sums of
-the second and third term) are a few elementwise passes over [S, H, P]
-and [S, H] that XLA fuses, shared by both forms.
+``dD = sum dy . x``.  The products, the sums that need a chunk's
+[Q, Q] matrices or its states and every sum over a head's channels are
+the kernels': ``ssd_bwd`` holds x, dy, dt and the float32 dxb of a
+chunk, G_c and dG_c, so it writes dx itself (rounded once), four rows a
+head and position (da's first term with ``exp(s_Q) <dG_c, G_c>`` added,
+the summands of its second and third, ``x . dxb``) and ``x . dy`` a
+channel summed over the chunk's positions; no dG_c and no float32 array
+of x's size reaches HBM.  What follows the kernel is [S, H] work (the
+running sums of the second and third term, ddt, dA) and dD's sum over
+chunks and channels, shared by both forms; the XLA form makes the
+channel sums as elementwise passes over [S, H, P] and ``<dG_c, G_c>``
+from the cotangents its scan returns.
 
 Two forms, one switch (the backend, as for the other ops: dispatch.py):
 
@@ -342,7 +349,7 @@ def _ssd_fwd_kernel(x_ref, col_ref, row_ref, b_ref, c_ref, d_ref,
 
 
 def _ssd_bwd_kernel(x_ref, col_ref, row_ref, b_ref, c_ref, entry_ref, dy_ref,
-                    dxb_ref, db_ref, dc_ref, dg_ref, dcol_ref, drow_ref,
+                    d_ref, dx_ref, db_ref, dc_ref, sums_ref, total_ref,
                     dg_scr, cbt_scr, dcbt_scr, *, heads, blocks, per_group):
     step, grp = pl.program_id(1), pl.program_id(2)
     in_group = _in_group(grp, blocks, per_group)
@@ -365,7 +372,6 @@ def _ssd_bwd_kernel(x_ref, col_ref, row_ref, b_ref, c_ref, entry_ref, dy_ref,
 
     g = entry_ref[0, 0]                                  # [heads P, N]
     dg = dg_scr[grp]
-    dg_ref[0, 0] = dg
     cols, rows = col_ref[0, 0], row_ref[0, 0]
     cbt = cbt_scr[...]
     lane = jax.lax.broadcasted_iota(jnp.int32, (q, _LANES), 1)
@@ -388,6 +394,7 @@ def _ssd_bwd_kernel(x_ref, col_ref, row_ref, b_ref, c_ref, entry_ref, dy_ref,
         dt = _by_head(lane, [cols[:, h:h + 1] for h in hs])
         s_col = [cols[:, heads + h:heads + h + 1] for h in hs]
         xb = x * dt
+        s_end = [rows[h:h + 1, q - 1:q] for h in hs]     # [1, 1] each
         diag = []
         for k, h in enumerate(hs):
             # decay^T [j, i] = exp(s_i - s_j) for i >= j
@@ -398,26 +405,38 @@ def _ssd_bwd_kernel(x_ref, col_ref, row_ref, b_ref, c_ref, entry_ref, dy_ref,
             dmt = _nt(jnp.where(own[k], xb, 0.0).astype(bf16), dy16)
             dcbt_scr[...] += decay * dmt
             # da's first term: sum over i >= k of W^T[j, i], then over
-            # the j before k
+            # the j before k; its last, exp(s_Q) <dG_c, G_c>, is the same
+            # at every position of the chunk
             reach = _nn((mt * dmt).astype(bf16), from_k)     # [j, k]
-            drow_ref[0, 0, h:h + 1, :] = jnp.sum(
-                jnp.where(before, reach, 0.0), axis=0, keepdims=True)
-        s_end = [rows[h:h + 1, q - 1:q] for h in hs]
+            lo = tile * _LANES + k * _HEAD
+            inner = jnp.sum(jnp.sum(dg[lo:lo + _HEAD] * g[lo:lo + _HEAD],
+                                    axis=0, keepdims=True),
+                            axis=1, keepdims=True)           # [1, 1]
+            sums_ref[0, 0, h:h + 1, :] = jnp.sum(
+                jnp.where(before, reach, 0.0), axis=0, keepdims=True) \
+                + _across(s_end[k], q) * inner
         e = _by_head(lane, [jnp.exp(sc) for sc in s_col])
         f = _by_head(lane, [jnp.exp(se - sc)
                                for se, sc in zip(s_end, s_col)])
         g16, dg16 = g[at].astype(bf16), dg[at].astype(bf16)
         from_state = f * _nt(b_mat, dg16)
         dxb = _by_head(lane, diag) + from_state
-        dxb_ref[0, :, at] = dxb.astype(dxb_ref.dtype)
+        dx_ref[0, :, at] = (dt * dxb + d_ref[:, at] * dy).astype(dx_ref.dtype)
         edy = e * dy
-        second = edy * _nt(c_mat, g16)
-        third = xb * from_state
-        for k, h in enumerate(hs):
-            dcol_ref[0, 0, :, h:h + 1] = jnp.sum(
-                jnp.where(own[k], second, 0.0), axis=1, keepdims=True)
-            dcol_ref[0, 0, :, heads + h:heads + h + 1] = jnp.sum(
-                jnp.where(own[k], third, 0.0), axis=1, keepdims=True)
+        # the sums over a head's channels a position, after da's first
+        # term: the summands of its second and third, then ddt's x . dxb.
+        # Transposed, a head's channels are 64 rows to add and a sum is a
+        # row of positions; as a sum over the lanes of a masked tile it
+        # cost ten times as much (PERF.md section 6, PR 61)
+        for i, t in enumerate((edy * _nt(c_mat, g16), xb * from_state,
+                               dxb * x)):
+            t = t.T                                      # [128, Q]
+            for k, h in enumerate(hs):
+                row = (i + 1) * heads + h
+                sums_ref[0, 0, row:row + 1, :] = jnp.sum(
+                    t[k * _HEAD:(k + 1) * _HEAD], axis=0, keepdims=True)
+        # dD's x . dy a channel, over the chunk's positions
+        total_ref[0, 0, :, at] = jnp.sum(dy * x, axis=0, keepdims=True)
         edy = edy.astype(bf16)
         d_c += _nn(edy, g16)
         d_b += _nn((f * xb).astype(bf16), dg16)
@@ -480,6 +499,11 @@ def _head_blocks(dt, s, heads):
     return cols.transpose(0, 2, 1, 3), rows.transpose(0, 2, 3, 1)
 
 
+def _spread(d_vec, dim):
+    """D [H] -> [1, H P] float32: a head's D at each of its channels."""
+    return jnp.repeat(d_vec.astype(jnp.float32), dim)[None]
+
+
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def _pallas_fwd(x, dt, s, b_mat, c_mat, d_vec, *, chunk, interpret):
     """x [batch, S, H, P] (S a multiple of chunk), dt and s [batch, S, H]
@@ -511,39 +535,49 @@ def _pallas_fwd(x, dt, s, b_mat, c_mat, d_vec, *, chunk, interpret):
     )(x.reshape(batch, seq, -1).astype(bf16), cols, rows,
       b_mat.reshape(batch, seq, -1).astype(bf16),
       c_mat.reshape(batch, seq, -1).astype(bf16),
-      jnp.repeat(d_vec.astype(jnp.float32), dim)[None])
+      _spread(d_vec, dim))
     return (y.reshape(x.shape),
             entries.reshape(batch, n_chunks, num_heads, dim, states))
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def _pallas_bwd(x, dt, s, b_mat, c_mat, entries, dy, *, chunk, interpret):
-    """(dxb [batch, S, H, P] bf16, dB, dC [batch, S, G, N] float32, the
-    cotangent of the state leaving each chunk, shaped as ``entries``,
-    and the three parts of da, [batch, S, H] each: ``_xla_bwd``'s)."""
+def _pallas_bwd(x, dt, s, b_mat, c_mat, entries, dy, d_vec, *, chunk,
+                interpret):
+    """The backward's products and every sum over a head's channels:
+    (dx [batch, S, H, P] bf16, finished: dt dxb + D dy from the float32
+    values, rounded once; dB, dC [batch, S, G, N] float32; four [batch,
+    S, H] float32 arrays: da's first term with exp(s_Q) <dG_c, G_c> of
+    the position's chunk added, the summands of its second and of its
+    third, and x . dxb; and dD [H] float32, x . dy summed over the
+    positions).  No cotangent of a state leaves the kernel."""
     batch, seq, num_heads, dim = x.shape
     bc_groups, states = b_mat.shape[2:]
     width, heads = SSD_HEADS * dim, SSD_HEADS
     n_chunks, groups = seq // chunk, num_heads // heads
     per_group = groups // bc_groups     # head blocks a group of B and C
-    seq_s, cols_s, rows_s, mat, _, state = _specs(
+    seq_s, cols_s, rows_s, mat, vec, state = _specs(
         chunk, width, heads, states, groups, per_group, n_chunks)
+    # four rows a head and position, one row a chunk and channel
+    sums_s = pl.BlockSpec((1, 1, 4 * heads, chunk),
+                          lambda b, i, j: (b, j, 0, n_chunks - 1 - i))
+    total_s = pl.BlockSpec((1, 1, 1, width),
+                           lambda b, i, j: (b, n_chunks - 1 - i, 0, j))
     cols, rows = _head_blocks(dt, s, heads)
     bf16 = jnp.bfloat16
     flat = jax.ShapeDtypeStruct((batch, seq, num_heads * dim), bf16)
     narrow = jax.ShapeDtypeStruct((batch, seq, bc_groups * states),
                                   jnp.float32)
-    wide = jax.ShapeDtypeStruct(
-        (batch, n_chunks, num_heads * dim, states), jnp.float32)
-    dxb, d_b, d_c, dg, dcol, drow = pl.pallas_call(
+    dx, d_b, d_c, sums, total = pl.pallas_call(
         functools.partial(_ssd_bwd_kernel, heads=heads, blocks=groups,
                           per_group=per_group),
         grid=(batch, n_chunks, groups),
-        in_specs=[seq_s, cols_s, rows_s, mat, mat, state, seq_s],
-        out_specs=[seq_s, mat, mat, state, cols_s, rows_s],
-        out_shape=[flat, narrow, narrow, wide,
-                   jax.ShapeDtypeStruct(cols.shape, jnp.float32),
-                   jax.ShapeDtypeStruct(rows.shape, jnp.float32)],
+        in_specs=[seq_s, cols_s, rows_s, mat, mat, state, seq_s, vec],
+        out_specs=[seq_s, mat, mat, sums_s, total_s],
+        out_shape=[flat, narrow, narrow,
+                   jax.ShapeDtypeStruct((batch, groups, 4 * heads, seq),
+                                        jnp.float32),
+                   jax.ShapeDtypeStruct((batch, n_chunks, 1, num_heads * dim),
+                                        jnp.float32)],
         scratch_shapes=[pltpu.VMEM((groups, width, states), jnp.float32),
                         pltpu.VMEM((chunk, chunk), jnp.float32),
                         pltpu.VMEM((chunk, chunk), jnp.float32)],
@@ -553,14 +587,12 @@ def _pallas_bwd(x, dt, s, b_mat, c_mat, entries, dy, *, chunk, interpret):
       b_mat.reshape(batch, seq, -1).astype(bf16),
       c_mat.reshape(batch, seq, -1).astype(bf16),
       entries.reshape(batch, n_chunks, num_heads * dim, states),
-      dy.reshape(batch, seq, -1).astype(bf16))
+      dy.reshape(batch, seq, -1).astype(bf16), _spread(d_vec, dim))
     # back from the kernels' head blocks to [batch, S, H]
-    dcol = dcol.transpose(0, 2, 1, 3).reshape(batch, seq, groups, 2, heads)
-    second, third = (dcol[:, :, :, i].reshape(dt.shape) for i in (0, 1))
-    first = drow.transpose(0, 3, 1, 2).reshape(dt.shape)
-    return (dxb.reshape(x.shape), d_b.reshape(b_mat.shape),
-            d_c.reshape(b_mat.shape), dg.reshape(entries.shape),
-            first, second, third)
+    sums = sums.reshape(batch, groups, 4, heads, seq).transpose(2, 0, 4, 1, 3)
+    return (dx.reshape(x.shape), d_b.reshape(b_mat.shape),
+            d_c.reshape(b_mat.shape), *sums.reshape(4, *dt.shape),
+            jnp.sum(total.reshape(-1, num_heads, dim), axis=(0, 2)))
 
 
 # ---------------------------------------------------------------------- #
@@ -617,36 +649,38 @@ def _scan_bwd(chunk, res, dy):
     seq, f32 = x.shape[1], jnp.float32
     xp, dtp, bp, cp, dyp = (_padded(t, chunk) for t in (
         x, dt.astype(f32), b_mat, c_mat, dy))
-    af, df = a_vec.astype(f32), d_vec.astype(f32)[:, None]
+    af = a_vec.astype(f32)
     s = _running(dtp, af, chunk)
     if _takes_kernels(x, b_mat, chunk):
-        dxb, d_b, d_c, dg, first, second, third = _pallas_bwd(
-            xp, dtp, s, bp, cp, entries, dyp, chunk=chunk,
+        dx, d_b, d_c, first, second, third, x_dxb, d_d = _pallas_bwd(
+            xp, dtp, s, bp, cp, entries, dyp, d_vec, chunk=chunk,
             interpret=pallas_interpret())
     else:
         dxb, d_b, d_c, dg, first, second, third = jax.vmap(_xla_bwd_groups)(
             *(_chunked(t.astype(f32), chunk) for t in (xp, dtp, s, bp, cp)),
             entries, _chunked(dyp.astype(f32), chunk))
-        dxb = dxb.reshape(xp.shape)
         d_b, d_c = (t.reshape(bp.shape) for t in (d_b, d_c))
-    # the sums over a head's channels that follow the products
-    xf, dyf, dxb = xp.astype(f32), dyp.astype(f32), dxb.astype(f32)
-    dx = dtp[..., None] * dxb + df * dyf
+        # the sums over a head's channels that follow the products
+        xf, dyf, dxb = xp.astype(f32), dyp.astype(f32), dxb.reshape(xp.shape)
+        dx = dtp[..., None] * dxb + d_vec.astype(f32)[:, None] * dyf
+        x_dxb = jnp.sum(dxb * xf, axis=-1)
+        d_d = jnp.sum(dyf * xf, axis=(0, 1, 3))
+        # da's last term, the same all through a chunk
+        through = (jnp.exp(_chunked(s, chunk)[:, :, -1])
+                   * jnp.einsum("bchpn,bchpn->bch", dg, entries))
+        first = first + through[:, :, None]
     first, second, third = (_chunked(t.reshape(dtp.shape), chunk)
                             for t in (first, second, third))
     # da: the second term from each position to its chunk's end, the
-    # third over the positions before it, the last the same all through
-    through = (jnp.exp(_chunked(s, chunk)[:, :, -1])
-               * jnp.einsum("bchpn,bchpn->bch", dg, entries))
+    # third over the positions before it
     d_a = (first + jnp.cumsum(second[:, :, ::-1], axis=2)[:, :, ::-1]
-           + jnp.cumsum(third, axis=2) - third
-           + through[:, :, None]).reshape(dtp.shape)
-    d_dt = jnp.sum(dxb * xf, axis=-1) + af * d_a
+           + jnp.cumsum(third, axis=2) - third).reshape(dtp.shape)
+    d_dt = x_dxb + af * d_a
     return (dx[:, :seq].astype(x.dtype), d_dt[:, :seq].astype(dt.dtype),
             jnp.sum(d_a * dtp, axis=(0, 1)).astype(a_vec.dtype),
             d_b[:, :seq].astype(b_mat.dtype),
             d_c[:, :seq].astype(c_mat.dtype),
-            jnp.sum(dyf * xf, axis=(0, 1, 3)).astype(d_vec.dtype))
+            d_d.astype(d_vec.dtype))
 
 
 _scan.defvjp(_scan_fwd, _scan_bwd)

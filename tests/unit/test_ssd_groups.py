@@ -104,8 +104,10 @@ def test_one_group_in_the_xla_form_is_the_one_group_functions():
 
 def test_one_groups_kernels_are_the_recorded_ones(interpreter):  # noqa: F811
     """The two Pallas calls of a one-group scan (sixteen heads: two head
-    blocks share C B^T and its cotangent), as text, against the record
-    read on the parent of PR 60: same bodies, same grid, same blocks."""
+    blocks share C B^T and its cotangent), as text, against the record:
+    ``ssd_fwd``'s read on the parent of PR 60, ``ssd_bwd``'s on PR 61,
+    which changed that kernel (the file says how): same bodies, same
+    grid, same blocks."""
     args, weight = operands(1, 256, 16, 64, 128, seed=3)
     jaxpr = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(
         ssd.ssd_scan(*a, chunk=128) * weight), argnums=range(6)))(*args)
@@ -140,11 +142,12 @@ def test_the_kernels_compile_for_v5e_at_a_cells_shapes(cell, one_chip):  # noqa:
     per_head = on_chip((batch, seq, heads), f32)
     narrow = on_chip((batch, seq, groups, states), bf16)
     entries = on_chip((batch, seq // chunk, heads, dim, states), f32)
+    d_vec = on_chip((heads,), f32)
     for name, fn, args in (
             ("ssd_fwd", ssd._pallas_fwd.__wrapped__,
-             (x, per_head, per_head, narrow, narrow, on_chip((heads,), f32))),
+             (x, per_head, per_head, narrow, narrow, d_vec)),
             ("ssd_bwd", ssd._pallas_bwd.__wrapped__,
-             (x, per_head, per_head, narrow, narrow, entries, x))):
+             (x, per_head, per_head, narrow, narrow, entries, x, d_vec))):
         text = jax.jit(lambda *a, fn=fn: fn(
             *a, chunk=chunk, interpret=False)).lower(*args).compile(
             ).as_text()

@@ -13,7 +13,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.analysis.jaxpr_walk import iter_eqns
+from deepspeed_tpu.analysis.jaxpr_walk import as_jaxpr, iter_eqns, sub_jaxprs
 from deepspeed_tpu.ops import ssd_scan as ssd
 from deepspeed_tpu.ops.dispatch import set_pallas_interpret
 
@@ -127,6 +127,146 @@ def test_decays_near_one_and_near_zero(form, case, request):
         compare(args, weight, 16, 2e-4)
 
 
+def both_forms(args, weight, chunk):
+    """(kernels', twin's, recurrence's) six gradients, the interpreter on
+    for the first alone."""
+    def grads(fn):
+        return jax.grad(lambda *a: jnp.sum(fn(*a) * weight),
+                        argnums=range(6))(*args)
+    set_pallas_interpret(True)
+    kernel = grads(lambda *a: ssd.ssd_scan(*a, chunk=chunk))
+    set_pallas_interpret(False)
+    twin = grads(lambda *a: ssd.ssd_scan(*a, chunk=chunk))
+    return kernel, twin, grads(recurrence)
+
+
+@pytest.mark.parametrize("groups,heads", [(1, 8), (8, 64)])
+@pytest.mark.parametrize("case", ["padded", "near_one", "near_zero"])
+def test_the_kernel_finishes_its_cotangents(case, groups, heads, interpreter):
+    """dx, the sums over a head's channels and <dG, G> come out of
+    ``ssd_bwd`` itself; the twin makes them in XLA by the module's
+    formulas.  Two routes to the same six cotangents, each against the
+    recurrence too, on a chunk and a bit (the second chunk padded) and on
+    two whole chunks at decays near 1 and near 0.  dt, a and d are the
+    ones whose route changed: they are held by name, at half the
+    kernels' limit."""
+    seq, shift, scale = {"padded": (168, -2.0, 1.0),
+                         "near_one": (256, -9.0, 1.0),
+                         "near_zero": (256, 3.0, 10.0)}[case]
+    args, weight = operands(1, seq, heads, 64, 128, seed=5,
+                            dt_shift=shift, dt_scale=scale, groups=groups)
+    assert ssd.uses_kernels(heads, 64, 128, 128, groups)
+    kernel, twin, want = both_forms(args, weight, 128)
+    seen = {}
+    for name, k, t, r in zip(NAMES, kernel, twin, want):
+        size = float(jnp.linalg.norm(r))
+        assert bool(jnp.isfinite(k).all()), name
+        assert float(jnp.linalg.norm(t - r)) / size < 2e-4, name
+        seen[name] = (float(jnp.linalg.norm(k - t)) / size,
+                      float(jnp.linalg.norm(k - r)) / size)
+        assert max(seen[name]) < 2e-2, (name, seen[name])
+    for name in ("dt", "a", "d"):
+        assert max(seen[name]) < 1e-2, (name, seen[name])
+
+
+@pytest.mark.parametrize("groups", [1, 2])
+def test_what_ssd_bwd_returns_is_the_twins_by_name(groups, interpreter):
+    """``_pallas_bwd``'s eight results against ``_xla_bwd_groups``' and
+    the formulas ``_scan_bwd`` puts after it: the finished dx, dB, dC,
+    da's first term WITH exp(s_Q) <dG_c, G_c>, the summands of its second
+    and third, x . dxb a head and position, and dD whole."""
+    chunk, heads = 128, 16
+    (x, dt, a, b, c, d), dy = operands(2, 2 * chunk, heads, 64, 128,
+                                       seed=4, groups=groups)
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    # what the kernels see: bf16 x, B, C and dy
+    x, b, c, dy = (t.astype(bf16).astype(f32) for t in (x, b, c, dy))
+    s = ssd._running(dt, a, chunk)
+    cut = [ssd._chunked(t, chunk) for t in (x, dt, s, b, c)]
+    entries = jax.vmap(ssd._xla_fwd_groups)(*cut)[1]
+    got = ssd._pallas_bwd(x, dt, s, b, c, entries, dy, d, chunk=chunk,
+                          interpret=True)
+    dxb, d_b, d_c, dg, first, second, third = jax.vmap(
+        ssd._xla_bwd_groups)(*cut, entries, ssd._chunked(dy, chunk))
+    dxb = dxb.reshape(x.shape)
+    through = (jnp.exp(cut[2][:, :, -1])
+               * jnp.einsum("bchpn,bchpn->bch", dg, entries))
+    want = (dt[..., None] * dxb + d[:, None] * dy, d_b.reshape(b.shape),
+            d_c.reshape(c.shape),
+            (first + through[:, :, None]).reshape(dt.shape),
+            second.reshape(dt.shape), third.reshape(dt.shape),
+            jnp.sum(dxb * x, axis=-1), jnp.sum(dy * x, axis=(0, 1, 3)))
+    assert got[0].dtype == bf16 and all(t.dtype == f32 for t in got[1:])
+    for name, g, w in zip(("dx", "dB", "dC", "first + through", "second",
+                           "third", "x . dxb", "dD"), got, want):
+        assert g.shape == w.shape, name
+        err = float(jnp.linalg.norm(g.astype(f32) - w) / jnp.linalg.norm(w))
+        assert err < (1e-5 if name == "dD" else 1e-2), (name, err)
+
+
+def leaf_eqns_outside_kernels(jaxpr):
+    """Every equation that holds no other, the bodies of the Pallas calls
+    left out; the calls themselves are yielded."""
+    for eqn in as_jaxpr(jaxpr).eqns:
+        inner = () if eqn.primitive.name == "pallas_call" else \
+            sub_jaxprs(eqn)
+        if inner:
+            for sub in inner:
+                yield from leaf_eqns_outside_kernels(sub.jaxpr)
+        else:
+            yield eqn
+
+
+@pytest.mark.parametrize("groups,chunk,states", [(1, 256, 128),
+                                                 (8, 128, 256)])
+def test_nothing_of_x_in_float32_nor_of_the_entries_is_made_outside(
+        groups, chunk, states, interpreter):
+    """The kernel path's backward, by its jaxpr at the model's dtypes
+    (bf16 x, B, C and dy; a sequence padded to its chunks; a state of
+    another size than the chunk, so that no two sizes meet): outside the
+    two Pallas calls no float32 array of x's size ([batch, S, H, P],
+    however shaped) is produced, and nothing of the saved entries' size
+    ([batch, S / Q, H P, N]) but their own reshapes on the way out of
+    ``ssd_fwd`` and into ``ssd_bwd``; ``ssd_bwd`` returns dx, dB, dC, four
+    rows a head and dD's summands a chunk and channel, and no state's
+    cotangent."""
+    batch, seq, heads, dim = 2, 3 * chunk - 56, 64, 64
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    args = [jnp.zeros(shape, dtype) for shape, dtype in (
+        ((batch, seq, heads, dim), bf16), ((batch, seq, heads), f32),
+        ((heads,), f32), ((batch, seq, groups, states), bf16),
+        ((batch, seq, groups, states), bf16), ((heads,), f32))]
+    jaxpr = jax.make_jaxpr(lambda *a: jax.vjp(
+        lambda *b: ssd.ssd_scan(*b, chunk=chunk), *a)[1](a[0]))(*args)
+    padded, n_chunks = 3 * chunk, 3
+    x_size = batch * padded * heads * dim
+    entries_size = batch * n_chunks * heads * dim * states
+    assert entries_size not in (x_size, batch * seq * heads * dim)
+    kernels, entry_reshapes = {}, 0
+    for eqn in leaf_eqns_outside_kernels(jaxpr):
+        name = eqn.primitive.name
+        if name == "pallas_call":
+            kernels[eqn.params["name"]] = [v.aval for v in eqn.outvars]
+            continue
+        for v in eqn.outvars:
+            size = getattr(v.aval, "size", 0)
+            if v.aval.dtype == f32:
+                assert size not in (x_size, batch * seq * heads * dim), eqn
+            if size == entries_size:
+                assert name == "reshape", eqn
+                entry_reshapes += 1
+    assert entry_reshapes <= 2
+    assert sorted(kernels) == ["ssd_bwd", "ssd_fwd"]
+    assert [a.size for a in kernels["ssd_fwd"]] == [x_size, entries_size]
+    blocks = heads // ssd.SSD_HEADS
+    assert [(a.shape, a.dtype) for a in kernels["ssd_bwd"]] == [
+        ((batch, padded, heads * dim), bf16),
+        ((batch, padded, groups * states), f32),
+        ((batch, padded, groups * states), f32),
+        ((batch, blocks, 4 * ssd.SSD_HEADS, padded), f32),
+        ((batch, n_chunks, 1, heads * dim), f32)]
+
+
 def test_what_is_refused_is_refused_with_a_message():
     (x, dt, a, b, c, d), _ = operands(1, 32, 4, 8, 16)
     three = jnp.concatenate([b, b, b], axis=2)
@@ -224,11 +364,12 @@ def test_the_kernels_compile_for_v5e_at_the_cells_shapes(one_chip):
     per_head = on_chip((batch, seq, heads), f32)
     narrow = on_chip((batch, seq, 1, states), bf16)
     entries = on_chip((batch, seq // chunk, heads, dim, states), f32)
+    d_vec = on_chip((heads,), f32)
     for name, fn, args in (
             ("ssd_fwd", ssd._pallas_fwd.__wrapped__,
-             (x, per_head, per_head, narrow, narrow, on_chip((heads,), f32))),
+             (x, per_head, per_head, narrow, narrow, d_vec)),
             ("ssd_bwd", ssd._pallas_bwd.__wrapped__,
-             (x, per_head, per_head, narrow, narrow, entries, x))):
+             (x, per_head, per_head, narrow, narrow, entries, x, d_vec))):
         text = jax.jit(lambda *a, fn=fn: fn(
             *a, chunk=chunk, interpret=False)).lower(*args).compile(
             ).as_text()
