@@ -66,10 +66,9 @@ def _expand_invars(arg_trees, donated_labels):
 
 
 def _engine_scan_info(engine) -> dict:
-    """Scan-structure provenance recorded at build time: the fused gas
-    scan (runtime/fused_step.py) and the streamed-ZeRO-3 layer plan
+    """Scan-structure provenance: the streamed-ZeRO-3 layer plan
     (runtime/zero/stage3_streaming.py, populated during tracing)."""
-    info = dict(getattr(engine, "_fused_scan_info", None) or {})
+    info = {}
     stream = getattr(engine, "_zero3_stream", None)
     plan = getattr(stream, "last_plan", None)
     if plan is not None:
@@ -121,23 +120,19 @@ def synthesize_sample_batch(engine) -> Optional[Tuple]:
     return (jax.ShapeDtypeStruct((batch, int(seq)), np.int32),)
 
 
-def _sharded_batch_structs(engine, sample_batch, stacked: bool):
-    """ShapeDtypeStructs carrying the shardings ``_shard_batch`` /
-    ``_shard_stacked_batch`` would place — the HLO audit must compile
-    the program TRAINING dispatches, and in/out shardings are part of
-    what the SPMD partitioner sees (an unsharded probe batch would
-    audit a different partitioning)."""
+def _sharded_batch_structs(engine, sample_batch):
+    """ShapeDtypeStructs carrying the shardings ``_shard_batch`` would
+    place — the HLO audit must compile the program TRAINING dispatches,
+    and in/out shardings are part of what the SPMD partitioner sees (an
+    unsharded probe batch would audit a different partitioning)."""
     import jax
     dp = engine.world_size
-    batch_dim = 1 if stacked else 0
-    data = (engine.mesh_ctx.sharding(
-        *([None] * batch_dim),
-        ("data", "expert")) if dp > 1 else engine.mesh_ctx.replicated())
+    data = (engine.mesh_ctx.sharding(("data", "expert")) if dp > 1
+            else engine.mesh_ctx.replicated())
     rep = engine.mesh_ctx.replicated()
 
     def place(s):
-        fits = (len(s.shape) > batch_dim
-                and s.shape[batch_dim] % dp == 0)
+        fits = len(s.shape) > 0 and s.shape[0] % dp == 0
         return jax.ShapeDtypeStruct(s.shape, s.dtype,
                                     sharding=data if fits else rep)
     return tuple(place(s) for s in sample_batch)
@@ -154,10 +149,10 @@ def _engine_spmd_waivers(engine, kind: str) -> Tuple[SpmdWaiver, ...]:
     pbytes = _tree_bytes(engine.params)
     slack = pbytes // 4 + (1 << 20)
     waivers = []
-    if kind in ("apply", "fused") and stage >= 1:
+    if kind == "apply" and stage >= 1:
         waivers.append(SpmdWaiver("zero_param_regather", pbytes + slack,
                                   ("all-gather",)))
-    if kind in ("grad", "fused") and stage >= 3:
+    if kind == "grad" and stage >= 3:
         waivers.append(SpmdWaiver("zero3_param_gather_at_use",
                                   2 * pbytes + slack, ("all-gather",)))
     return tuple(waivers)
@@ -189,53 +184,6 @@ def _onebit_engine_targets(engine, sample_batch) -> List[AuditTarget]:
                                        sharding=wire_sharding), wire_tmpl)
     targets: List[AuditTarget] = []
 
-    fused = progs.get("fused")
-    if fused is not None:
-        if sample_batch is None:
-            return targets
-        gas = engine.gradient_accumulation_steps()
-        stacked = tuple(
-            jax.ShapeDtypeStruct((gas,) + tuple(s.shape), s.dtype)
-            for s in sample_batch)
-        closed = jax.make_jaxpr(fused["raw"])(
-            engine.params, engine.opt_state, engine.scaler_state,
-            engine._fused_sent_state, wire_tmpl, engine._rng, stacked, {})
-        donated = fused["donate_argnums"]
-        args = [
-            ArgInfo("params", _tree_bytes(engine.params), 0 in donated,
-                    True),
-            ArgInfo("opt_state", _tree_bytes(engine.opt_state),
-                    1 in donated, True),
-            ArgInfo("scaler_state", _tree_bytes(engine.scaler_state),
-                    2 in donated, True),
-            ArgInfo("sentinel_state", _tree_bytes(engine._fused_sent_state),
-                    3 in donated, True),
-            ArgInfo("wire_error", _tree_bytes(wire_tmpl), 4 in donated,
-                    True),
-            ArgInfo("batch", _tree_bytes(stacked), False, False),
-        ]
-        arg_trees = (engine.params, engine.opt_state, engine.scaler_state,
-                     engine._fused_sent_state, wire_tmpl, engine._rng,
-                     stacked, {})
-        donated_invars, labels = _expand_invars(arg_trees, [
-            (0 in donated, "params"), (1 in donated, "opt_state"),
-            (2 in donated, "scaler_state"), (3 in donated,
-                                            "sentinel_state"),
-            (4 in donated, "wire_error"), (False, "rng"),
-            (False, "batch"), (False, "kwargs")])
-        sharded_stacked = _sharded_batch_structs(engine, stacked,
-                                                 stacked=True)
-        targets.append(AuditTarget(
-            "fused_step", closed, args,
-            donated_invars=donated_invars, invar_labels=labels,
-            scan_info=_engine_scan_info(engine),
-            lower=lambda: fused["fn"].lower(
-                engine.params, engine.opt_state, engine.scaler_state,
-                engine._fused_sent_state, wire_sharded, engine._rng,
-                sharded_stacked, {}).compile().as_text(),
-            spmd_waivers=_engine_spmd_waivers(engine, "fused")))
-        return targets
-
     if sample_batch is not None:
         closed = jax.make_jaxpr(
             lambda p, s, r, *b: progs["loss_and_grads"](p, s, r, *b))(
@@ -252,8 +200,7 @@ def _onebit_engine_targets(engine, sample_batch) -> List[AuditTarget]:
              list(sample_batch)),
             [(False, "params"), (False, "scaler_state"),
              (False, "rng"), (False, "batch")])
-        sharded_batch = _sharded_batch_structs(engine, sample_batch,
-                                               stacked=False)
+        sharded_batch = _sharded_batch_structs(engine, sample_batch)
         targets.append(AuditTarget(
             "grad_step", closed, args,
             donated_invars=donated_invars, invar_labels=labels,
@@ -311,11 +258,10 @@ def engine_targets(engine, sample_batch: Optional[Tuple] = None,
                    phase: Optional[str] = None) -> List[AuditTarget]:
     """Trace the engine's step program(s) abstractly.
 
-    Modular path: the grad program (dispatched gas times per step) and
-    the apply program.  Fused path: the single whole-step program.
-    Donation facts come from the argnum tuples the engine recorded next
-    to its jit calls (`_apply_donate_argnums` / `_fused_donate_argnums`)
-    so the audit reflects what is actually dispatched.
+    The grad program (dispatched gas times per step) and the apply
+    program.  Donation facts come from the argnum tuple the engine
+    recorded next to its jit call (`_apply_donate_argnums`) so the
+    audit reflects what is actually dispatched.
 
     ``phase`` selects which of an onebit engine's two step programs to
     trace ("warmup" / "compressed" — docs/onebit.md); None follows the
@@ -332,62 +278,6 @@ def engine_targets(engine, sample_batch: Optional[Tuple] = None,
             phase = getattr(engine, "_onebit_phase", "warmup")
         if phase == "compressed":
             return _onebit_engine_targets(engine, sample_batch)
-
-    fused_raw = getattr(engine, "_fused_step_raw", None)
-    fused_fn = engine._fused_step_fn
-    fused_donated = getattr(engine, "_fused_donate_argnums", (0, 1))
-    if (onebit is not None
-            and getattr(engine, "_onebit_phase", "warmup") == "compressed"
-            and engine._onebit_programs is not None):
-        # warmup-phase audit of an already-switched engine (checkpoint
-        # signature verify): the installed fused artifacts are phase-B,
-        # but the phase-A ones were stashed at the switch
-        fa = engine._onebit_programs.get("fused_phase_a")
-        if fa is not None:
-            fused_raw, fused_fn = fa["raw"], fa["fn"]
-            fused_donated = fa["donate_argnums"]
-    if engine._fused_step_fn is not None and fused_raw is not None:
-        if sample_batch is not None:
-            gas = engine.gradient_accumulation_steps()
-            stacked = tuple(
-                jax.ShapeDtypeStruct((gas,) + tuple(s.shape), s.dtype)
-                for s in sample_batch)
-            closed = jax.make_jaxpr(fused_raw)(
-                engine.params, engine.opt_state, engine.scaler_state,
-                engine._fused_sent_state, engine._rng, stacked, {})
-            donated = fused_donated
-            args = [
-                ArgInfo("params", _tree_bytes(engine.params),
-                        0 in donated, True),
-                ArgInfo("opt_state", _tree_bytes(engine.opt_state),
-                        1 in donated, True),
-                ArgInfo("scaler_state", _tree_bytes(engine.scaler_state),
-                        2 in donated, True),
-                ArgInfo("sentinel_state",
-                        _tree_bytes(engine._fused_sent_state),
-                        3 in donated, True),
-                ArgInfo("batch", _tree_bytes(stacked), False, False),
-            ]
-            arg_trees = (engine.params, engine.opt_state,
-                         engine.scaler_state, engine._fused_sent_state,
-                         engine._rng, stacked, {})
-            donated_invars, labels = _expand_invars(arg_trees, [
-                (0 in donated, "params"), (1 in donated, "opt_state"),
-                (2 in donated, "scaler_state"),
-                (3 in donated, "sentinel_state"),
-                (False, "rng"), (False, "batch"), (False, "kwargs")])
-            sharded_stacked = _sharded_batch_structs(engine, stacked,
-                                                     stacked=True)
-            targets.append(AuditTarget(
-                "fused_step", closed, args,
-                donated_invars=donated_invars, invar_labels=labels,
-                scan_info=_engine_scan_info(engine),
-                lower=lambda: fused_fn.lower(
-                    engine.params, engine.opt_state, engine.scaler_state,
-                    engine._fused_sent_state, engine._rng,
-                    sharded_stacked, {}).compile().as_text(),
-                spmd_waivers=_engine_spmd_waivers(engine, "fused")))
-        return targets
 
     if sample_batch is not None:
         closed = jax.make_jaxpr(
@@ -406,8 +296,7 @@ def engine_targets(engine, sample_batch: Optional[Tuple] = None,
             [(False, "params"), (False, "scaler_state"),
              (False, "rng"), (False, "batch")])
         # opt_state sits in HBM while the grad program runs
-        sharded_batch = _sharded_batch_structs(engine, sample_batch,
-                                               stacked=False)
+        sharded_batch = _sharded_batch_structs(engine, sample_batch)
         targets.append(AuditTarget(
             "grad_step", closed, args,
             donated_invars=donated_invars, invar_labels=labels,

@@ -2,7 +2,7 @@
 
 The audit report carries this lower bound next to the lockstep
 signature and wire bytes, and the monitor reconciles measured windows
-against it, so "the fused step should be ~X ms" is a number computed
+against it, so "the step should be ~X ms" is a number computed
 from the program, not a hope.  Three terms, each a genuine lower bound:
 
   compute   total jaxpr flops (profiling/flops_profiler walk, scan trip

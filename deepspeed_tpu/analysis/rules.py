@@ -43,8 +43,8 @@ class AuditTarget:
     # engine state resident during this program but not among its args
     # (the modular grad program runs while opt_state sits in HBM)
     resident_extra_bytes: int = 0
-    # scan-structure provenance the engine records at build time (gas
-    # scan length, streamed-ZeRO-3 plan) — named in overlap findings
+    # scan-structure provenance the engine records at build time (the
+    # streamed-ZeRO-3 plan) — named in overlap findings
     scan_info: dict = field(default_factory=dict)
     # HLO-level SPMD audit hooks (analysis/hlo_audit.py).  ``lower`` is
     # a zero-arg thunk returning the OPTIMIZED post-SPMD HLO text of
@@ -124,8 +124,8 @@ def donation_rule(target: AuditTarget, cfg) -> List[Finding]:
                          f"wasting ~{mb:.1f} MiB of HBM"),
                 target=target.label,
                 fix_hint=(f"add `{arg.label}`'s argnum to donate_argnums "
-                          "(the engine donates params/opt_state on both "
-                          "the modular apply and the fused step)")))
+                          "(the engine's apply program donates "
+                          "params/opt_state)")))
     return out
 
 

@@ -9,16 +9,14 @@ of a FIXED global batch (the batch is a hyperparameter, its split is a
 schedule choice), the ZeRO++ transport knobs qwZ/qgZ/hpZ
 (docs/low_bandwidth_collectives.md), per-tile fused collective-matmul
 transports (docs/fused_collective_matmul.md — candidate names carry an
-``fcm`` tag), fused vs modular step (docs/fused_step.md), and the
-offload tier with its prefetch/pipeline depths (docs/zero_infinity.md).
+``fcm`` tag), and the offload tier with its prefetch/pipeline depths
+(docs/zero_infinity.md).
 
 Enumeration is deterministic (nested loops in a documented order, names
 encode every knob) and GATED so the product only contains meaningful
 points: stage-3 streaming knobs collapse for stages 1/2, qwZ/hpZ only
 modulate streamed stage-3 gathers, qgZ needs a stage >= 2 grad
-reduce-scatter, the NVMe tier needs streamed stage 3, and the fused
-step is only enumerated where it would not silently fall back
-(offload-optimizer configs are host-interactive).  Structural
+reduce-scatter, and the NVMe tier needs streamed stage 3.  Structural
 infeasibilities — a global batch the data world cannot divide, an
 elasticity block that rejects the world size — are recorded as pruned
 candidates with reasons, never silently skipped.
@@ -102,7 +100,7 @@ def _deep_merge(dst: Dict[str, Any], overlay: Dict[str, Any]) -> None:
 
 
 def _candidate_name(stage, streamed, bucket, micro, gas, data,
-                    model, expert, qwz, qgz, hpz, fused, offload,
+                    model, expert, qwz, qgz, hpz, offload,
                     pdepth, odepth, multi_bucket, fcm=False,
                     onebit=False) -> str:
     bits = [f"z{stage}" + ("s" if streamed else "")]
@@ -120,7 +118,6 @@ def _candidate_name(stage, streamed, bucket, micro, gas, data,
         bits.append("fcm")
     if onebit:
         bits.append("1bit")
-    bits.append("fused" if fused else "mod")
     if offload == C.AUTOTUNING_OFFLOAD_TIER_NVME:
         # the depth axes only modulate the NVMe tier; the cpu tier has
         # no depth knob to encode
@@ -132,7 +129,7 @@ def _candidate_name(stage, streamed, bucket, micro, gas, data,
 
 def _build_config(base: Dict[str, Any], *, stage, streamed,
                   bucket, micro, gas, data, model, expert, qwz, qgz,
-                  hpz, fused, offload, pdepth, odepth,
+                  hpz, offload, pdepth, odepth,
                   fixed, fcm=False, onebit=False) -> Dict[str, Any]:
     raw = copy.deepcopy(base)
     # candidates are engine-ready engine configs: the search description
@@ -190,7 +187,6 @@ def _build_config(base: Dict[str, Any], *, stage, streamed,
             C.OFFLOAD_OPTIMIZER_DEVICE: C.OFFLOAD_NVME_DEVICE,
             C.OFFLOAD_OPTIMIZER_PIPELINE_DEPTH: odepth}
     raw[C.ZERO_OPTIMIZATION] = zo
-    raw[C.FUSED_STEP] = {C.FUSED_STEP_ENABLED: bool(fused)}
     if fixed:
         _deep_merge(raw, fixed)
     return raw
@@ -325,9 +321,6 @@ def enumerate_candidates(base: Dict[str, Any], tune_cfg,
                     odepths = (tune_cfg.opt_pipeline_depths
                                if offload == C.AUTOTUNING_OFFLOAD_TIER_NVME
                                else (None,))
-                    fuseds = (tune_cfg.fused
-                              if offload == C.AUTOTUNING_OFFLOAD_TIER_NONE
-                              else (False,))  # host-interactive fallback
                     # the 1-bit wire replaces the DATA-parallel grad
                     # allreduce of a resident stage <= 2 engine: ZeRO-3
                     # streaming has no whole-grad allreduce, offloaded
@@ -341,14 +334,12 @@ def enumerate_candidates(base: Dict[str, Any], tune_cfg,
                                    and model == 1 and expert == 1
                                    and not qgz)
                                else (False,))
-                    for pdepth, odepth, fused, onebit in \
-                            itertools.product(pdepths, odepths,
-                                              sorted(set(fuseds)),
-                                              onebits):
+                    for pdepth, odepth, onebit in itertools.product(
+                            pdepths, odepths, onebits):
                         space.n_enumerated += 1
                         name = _candidate_name(
                             stage, streamed, bucket, micro, gas,
-                            data, model, expert, qwz, qgz, hpz, fused,
+                            data, model, expert, qwz, qgz, hpz,
                             offload, pdepth, odepth, multi_bucket,
                             fcm=fcm, onebit=onebit)
                         cfg = _build_config(
@@ -356,7 +347,7 @@ def enumerate_candidates(base: Dict[str, Any], tune_cfg,
                             bucket=bucket, micro=micro,
                             gas=gas, data=data, model=model,
                             expert=expert, qwz=qwz, qgz=qgz, hpz=hpz,
-                            fused=fused, offload=offload, pdepth=pdepth,
+                            offload=offload, pdepth=pdepth,
                             odepth=odepth, fixed=tune_cfg.fixed,
                             fcm=fcm, onebit=onebit)
                         import json as _json
@@ -377,7 +368,6 @@ def enumerate_candidates(base: Dict[str, Any], tune_cfg,
                                 "hpz_group_size": hpz,
                                 "fused_collective_matmul": bool(fcm),
                                 "onebit": bool(onebit),
-                                "fused_step": bool(fused),
                                 "offload": offload,
                                 "nvme_prefetch_depth": pdepth,
                                 "opt_pipeline_depth": odepth,

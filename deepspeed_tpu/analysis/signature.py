@@ -73,8 +73,8 @@ def signature_of_sequence(seq: List[str]) -> str:
 
 
 def combine_signatures(sigs: List[str]) -> str:
-    """Per-engine signature over several traced programs (grad + apply,
-    or the fused whole-step): order-sensitive, like the dispatch order."""
+    """Per-engine signature over several traced programs (grad + apply):
+    order-sensitive, like the dispatch order."""
     h = hashlib.sha256()
     for s in sigs:
         h.update(s.encode())

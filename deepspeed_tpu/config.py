@@ -261,9 +261,17 @@ class ZeroLowBandwidthConfig:
 
 
 # Keys this package once read and no longer does: (section, key) -> what
-# to write instead.  Unknown keys are otherwise ignored silently, and an
-# ignored "stage3_prefetch_mode": "off" would turn prefetch ON.
+# to write instead ("" is the top level).  Unknown keys are otherwise
+# ignored silently, and an ignored "stage3_prefetch_mode": "off" would
+# turn prefetch ON.
+_ONE_STEP_LOOP = (
+    "train_batch runs the forward / backward / step loop, the one way to "
+    "take an optimizer step; delete the key, nothing need be written "
+    "instead")
 REMOVED_KEYS = {
+    ("", "fused_step"): _ONE_STEP_LOOP,
+    (C.AUTOTUNING, "fused"): (
+        f"every candidate takes the one step there is: {_ONE_STEP_LOOP}"),
     (C.ZERO_OPTIMIZATION, "stage3_prefetch_mode"): (
         f"the streamed ZeRO-3 scan prefetches whenever "
         f"{C.ZERO_OPTIMIZATION_PREFETCH_BUCKET_SIZE} covers a layer group "
@@ -281,7 +289,8 @@ def _refuse_removed_keys(section: str, d: Dict[str, Any]) -> None:
     for (where, key), instead in REMOVED_KEYS.items():
         if where == section and key in d:
             raise DeepSpeedConfigError(
-                f"{section}.{key} was removed: {instead}")
+                f"{'.'.join(filter(None, (section, key)))} was removed: "
+                f"{instead}")
 
 
 @dataclass
@@ -539,25 +548,6 @@ class TensorboardConfig:
                                       C.TENSORBOARD_JOB_NAME_DEFAULT),
             write_interval=None if interval is None else int(interval),
         )
-
-
-@dataclass
-class FusedStepConfig:
-    """Fused whole-step train program (docs/fused_step.md): gradient
-    accumulation as an in-program ``lax.scan`` + the optimizer apply in the
-    same compiled program — one XLA dispatch per optimizer step.  Off by
-    default; the engine falls back to the modular forward/backward/step
-    loop automatically whenever a host-interactive feature is active (the
-    fallback matrix is logged and exposed as ``engine.fused_step_reason``).
-    """
-    enabled: bool = C.FUSED_STEP_ENABLED_DEFAULT
-
-    @staticmethod
-    def from_dict(d: Optional[Dict[str, Any]]) -> "FusedStepConfig":
-        d = d or {}
-        return FusedStepConfig(
-            enabled=get_scalar_param(d, C.FUSED_STEP_ENABLED,
-                                     C.FUSED_STEP_ENABLED_DEFAULT))
 
 
 @dataclass
@@ -1036,7 +1026,6 @@ class AutotuningConfig:
     qwz_bits: tuple = C.AUTOTUNING_QWZ_BITS_DEFAULT
     qgz_bits: tuple = C.AUTOTUNING_QGZ_BITS_DEFAULT
     hpz_group_sizes: tuple = C.AUTOTUNING_HPZ_GROUP_SIZES_DEFAULT
-    fused: tuple = C.AUTOTUNING_FUSED_DEFAULT
     fused_collective_matmul: tuple = C.AUTOTUNING_FCM_DEFAULT
     onebit: tuple = C.AUTOTUNING_ONEBIT_DEFAULT
     offload: tuple = C.AUTOTUNING_OFFLOAD_TIERS_DEFAULT
@@ -1090,8 +1079,6 @@ class AutotuningConfig:
             hpz_group_sizes=_as_tuple(d.get(
                 C.AUTOTUNING_HPZ_GROUP_SIZES,
                 C.AUTOTUNING_HPZ_GROUP_SIZES_DEFAULT), int),
-            fused=_as_tuple(d.get(C.AUTOTUNING_FUSED,
-                                  C.AUTOTUNING_FUSED_DEFAULT), bool),
             fused_collective_matmul=_as_tuple(
                 d.get(C.AUTOTUNING_FCM, C.AUTOTUNING_FCM_DEFAULT), bool),
             onebit=_as_tuple(
@@ -1637,6 +1624,7 @@ class DeepSpeedConfig:
 
     # ------------------------------------------------------------------ #
     def _initialize_params(self, pd: Dict[str, Any]) -> None:
+        _refuse_removed_keys("", pd)
         self.train_batch_size = get_scalar_param(pd, C.TRAIN_BATCH_SIZE,
                                                  C.TRAIN_BATCH_SIZE_DEFAULT)
         self.train_micro_batch_size_per_gpu = get_scalar_param(
@@ -1699,8 +1687,6 @@ class DeepSpeedConfig:
             pd.get(C.FLOPS_PROFILER))
         self.tensorboard_config = TensorboardConfig.from_dict(
             pd.get(C.TENSORBOARD))
-        self.fused_step_config = FusedStepConfig.from_dict(
-            pd.get(C.FUSED_STEP))
         self.analysis_config = AnalysisConfig.from_dict(pd.get(C.ANALYSIS))
         self.autotuning_config = AutotuningConfig.from_dict(
             pd.get(C.AUTOTUNING))

@@ -130,23 +130,6 @@ MEMORY_BREAKDOWN = "memory_breakdown"
 MEMORY_BREAKDOWN_DEFAULT = False
 
 #############################################
-# Fused whole-step train program (TPU-native addition; docs/fused_step.md)
-#
-# One jitted program per optimizer step: gradient accumulation as a
-# lax.scan over a leading microbatch axis + the optimizer/loss-scale
-# update in the same program — 1 XLA dispatch instead of 2N+1, grad
-# buffers never leave the program, and XLA's latency-hiding scheduler
-# overlaps microbatch i's grad collective with microbatch i+1's compute.
-# Off by default; host-interactive features (offload optimizer,
-# eigenvalue/MoQ, sentinel rewind or grad-norm monitoring, PLD,
-# curriculum, custom grad programs) automatically fall back to the
-# modular forward/backward/step loop.
-#############################################
-FUSED_STEP = "fused_step"
-FUSED_STEP_ENABLED = "enabled"
-FUSED_STEP_ENABLED_DEFAULT = False
-
-#############################################
 # Program Auditor (TPU-native addition; docs/program_auditor.md)
 #
 # Static jaxpr lint of the traced train-step programs at engine init /
@@ -245,7 +228,7 @@ ANALYSIS_SPMD_MATCH_TOLERANCE_DEFAULT = 0.05
 #
 # Offline cost-model-driven search over the real config decision space
 # (mesh factorization, ZeRO stage/variant, gas/micro splits, qwZ/qgZ/
-# hpZ, fused vs modular, offload tier) — prune on hard constraints,
+# hpZ, offload tier) — prune on hard constraints,
 # trace survivors on a simulated mesh, rank by the static step-time
 # lower bound, emit the top-K as engine-ready configs.  The block only
 # configures `python -m deepspeed_tpu.analysis tune`; it never changes
@@ -285,8 +268,6 @@ AUTOTUNING_QGZ_BITS = "qgz_bits"
 AUTOTUNING_QGZ_BITS_DEFAULT = (0,)
 AUTOTUNING_HPZ_GROUP_SIZES = "hpz_group_sizes"
 AUTOTUNING_HPZ_GROUP_SIZES_DEFAULT = (0,)
-AUTOTUNING_FUSED = "fused"
-AUTOTUNING_FUSED_DEFAULT = (False,)
 AUTOTUNING_FCM = "fused_collective_matmul"
 AUTOTUNING_FCM_DEFAULT = (False,)
 AUTOTUNING_ONEBIT = "onebit"

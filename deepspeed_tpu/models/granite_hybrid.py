@@ -175,9 +175,6 @@ class GraniteHybridModel:
             "no pipeline module cuts a stack of unlike runs into stages "
             "yet, and a stage boundary inside a run would split its "
             "stacked group"),
-        "fused_step": (
-            "the fused one-dispatch step has not been run on a stack of "
-            "several scanned groups under one recomputation budget"),
     }
 
     def __init__(self, config: GraniteHybridConfig):

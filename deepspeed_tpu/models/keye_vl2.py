@@ -135,10 +135,6 @@ class KeyeVL2Model(ExpertStack):
             "the pipeline engine's stages return one loss, and the "
             "alignment terms of the layers on the earlier stages would "
             "have to travel with the activations"),
-        "fused_step": (
-            "the fused one-dispatch step returns the loss alone and would "
-            "drop the two loss terms and the selection's share "
-            "(aux_counters)"),
     }
 
     def __init__(self, config: KeyeVL2Config):

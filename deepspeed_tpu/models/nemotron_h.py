@@ -163,9 +163,6 @@ class NemotronHModel(SelectionBiasUpdate, ExpertStack):
             "no pipeline module cuts a pattern of unlike sublayers into "
             "stages yet, and the selection biases move from routing "
             "counts that would have to travel between the stages"),
-        "fused_step": (
-            "the fused one-dispatch step returns the loss alone and would "
-            "drop the routing counts the selection biases move from"),
     }
 
     def __init__(self, config: NemotronHConfig):

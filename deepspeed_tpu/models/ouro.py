@@ -137,9 +137,6 @@ class OuroModel:
             "the pipeline engine hands a micro-batch down the stages once, "
             "and every pass of the recurrence would have to cross all of "
             "them again"),
-        "fused_step": (
-            "the fused one-dispatch step returns the loss alone and would "
-            "drop the exit gate's counters (aux_counters)"),
     }
 
     def __init__(self, config: OuroConfig):

@@ -76,31 +76,6 @@ class DeepSpeedDataLoader:
             yield self.collate_fn(samples)
 
 
-def stack_microbatches(batches):
-    """Stack ``gas`` collated microbatches into one pytree whose leaves
-    carry a leading ``[gas, ...]`` axis — the scan axis of the fused
-    whole-step train program (runtime/fused_step.py).
-
-    Every microbatch must share one tree structure and per-leaf shape (the
-    loader contract already guarantees this under drop_last).  Leaves are
-    staged through numpy so a device-resident input is pulled back once
-    here rather than re-staged per microbatch inside the program.
-    """
-    import jax
-
-    if not batches:
-        raise ValueError("stack_microbatches needs at least one microbatch")
-    first = jax.tree.structure(batches[0])
-    for i, b in enumerate(batches[1:], start=1):
-        if jax.tree.structure(b) != first:
-            raise ValueError(
-                f"microbatch {i} has tree structure {jax.tree.structure(b)} "
-                f"!= microbatch 0's {first} — all gas microbatches must "
-                "collate identically")
-    return jax.tree.map(
-        lambda *leaves: np.stack([np.asarray(leaf) for leaf in leaves]), *batches)
-
-
 class RepeatingLoader:
     """Wraps an iterator to restart on StopIteration
     (reference: dataloader.py RepeatingLoader)."""

@@ -181,6 +181,15 @@ def resolve_mesh_ctx(config, mesh) -> MeshContext:
     return ctx
 
 
+# the keys a model's ``refuses`` may hold: the paths the engine asks it
+# about at construction, each with what in (ZeRO stage, mesh) asks for it
+REFUSABLE_PATHS = {
+    "zero3_streaming": lambda stage, mesh_ctx: stage >= 3,
+    "pipeline": lambda stage, mesh_ctx: (
+        mesh_ctx.pipe_parallel_world_size > 1),
+}
+
+
 class DeepSpeedEngine:
     """Config-driven training engine over a named-axis TPU mesh."""
 
@@ -242,13 +251,16 @@ class DeepSpeedEngine:
         stage = self.config.zero_optimization_stage
         # paths a model says it cannot run (a class attribute ``refuses``:
         # {path: the reason in one sentence}), refused before anything
-        # is built for them
-        for path, asked in (
-                ("zero3_streaming", stage >= 3),
-                ("pipeline", self.mesh_ctx.pipe_parallel_world_size > 1),
-                ("fused_step", self.config.fused_step_config.enabled)):
-            reason = getattr(model, "refuses", {}).get(path)
-            if asked and reason:
+        # is built for them; a key that is none of REFUSABLE_PATHS would
+        # refuse nothing, so it raises
+        refuses = getattr(model, "refuses", {})
+        unknown = sorted(set(refuses) - set(REFUSABLE_PATHS))
+        if unknown:
+            raise ValueError(
+                f"{type(model).__name__}.refuses names {unknown}, which the "
+                f"engine does not check: it knows {list(REFUSABLE_PATHS)}.")
+        for path, reason in refuses.items():
+            if reason and REFUSABLE_PATHS[path](stage, self.mesh_ctx):
                 raise NotImplementedError(
                     f"{type(model).__name__} under {path}: {reason}.")
         self.zero_partitioner = ZeroPartitioner(
@@ -464,47 +476,17 @@ class DeepSpeedEngine:
         # Scalars of the dict a model's apply returns beside its loss,
         # named in its ``aux_counters``: they leave the grad program with
         # the loss, are summed on the device over micro-batches and read
-        # by ``model_counters()`` alone.  The modular loop collects them.
+        # by ``model_counters()`` alone.
         self._aux_names = tuple(getattr(self.module, "aux_counters", ()))
         self._aux_acc = None
         self._build_functions()
 
-        # ---- fused whole-step program (off by default) --------------- #
-        # One dispatch per optimizer step: grad accumulation as a lax.scan
-        # + in-program apply (runtime/fused_step.py; docs/fused_step.md).
-        # Host-interactive features fall back to the modular loop — the
-        # reason is logged once and kept on `fused_step_reason`.
-        self._fused_step_fn = None
-        self._fused_sent_state = ()
-        self._fused_pending_flags = []
-        self.fused_step_reason = None
         # telemetry provenance: step programs launched since the last
         # optimizer step (_launch counts; _monitor_counters reads and
-        # clears).  Modular: gas grad programs + gas-1 accumulation adds
-        # + 1 apply; fused: 1.  The one-operation programs of the eager
-        # rng split (_next_rng) are no step programs and are not in it.
+        # clears): gas grad programs + gas-1 accumulation adds + 1 apply.
+        # The one-operation programs of the eager rng split (_next_rng)
+        # are no step programs and are not in it.
         self._launches = 0
-        if self.config.fused_step_config.enabled:
-            self._refuse_optimizer_exempt(
-                "fused_step (train_batch's one-dispatch step)")
-            from .fused_step import (build_fused_step, fused_fallback_reason,
-                                     sentinel_state_from_host)
-            reason = fused_fallback_reason(self)
-            if reason is not None:
-                self.fused_step_reason = reason
-                logger.warning(
-                    "fused_step: falling back to the modular forward/"
-                    f"backward/step loop — {reason}")
-            else:
-                if self.sentinel is not None:
-                    self._fused_sent_state = sentinel_state_from_host(
-                        self.sentinel, self.mesh_ctx)
-                self._fused_step_fn = build_fused_step(self)
-                log_dist(
-                    f"fused_step: 1 dispatch per optimizer step "
-                    f"(gas={self.gradient_accumulation_steps()}; modular "
-                    f"loop would issue "
-                    f"{2 * self.gradient_accumulation_steps()})", ranks=[0])
 
         # ---- data ---------------------------------------------------- #
         host_trace.phase("initialize", None)
@@ -896,9 +878,6 @@ class DeepSpeedEngine:
                  "apply program"),
                 (self.config.zero_config.low_bandwidth.onebit,
                  "the 1-bit tier's phase programs"),
-                (self.config.fused_step_config.enabled,
-                 "fused_step, where the cast and the update share one "
-                 "program"),
                 (self.config.sparse_gradients_enabled,
                  "the sparse_gradients shard_map region")):
             if refused:
@@ -1001,7 +980,6 @@ class DeepSpeedEngine:
         if self._onebit is not None:
             self._refuse_optimizer_exempt("the 1-bit compressed-phase step")
         if (custom_grad_program is not None or self._onebit is not None
-                or self.config.fused_step_config.enabled
                 or self.config.sparse_gradients_enabled):
             # these build or schedule a grad program of their own
             self._aux_names = ()
@@ -1037,10 +1015,10 @@ class DeepSpeedEngine:
         master_dtypes = jax.tree.map(lambda x: x.dtype, self._params)
 
         def loss_and_grads(params, scaler_state, rng, *args, **kwargs):
-            """``params``: the master, which is then cast here (the fused
-            step's scan body, the Program Auditor, the paths that keep no
-            copy), or the engine's ``_weights``, whose copies pass
-            through ``_cast_weights`` as they are."""
+            """``params``: the master, which is then cast here (the
+            Program Auditor, the paths that keep no copy), or the engine's
+            ``_weights``, whose copies pass through ``_cast_weights`` as
+            they are."""
             # inputs follow the compute dtype too — otherwise f32 activations
             # silently promote every matmul back to f32 and the MXU runs fp32
             args = _tree_cast(args, compute_dtype)
@@ -1195,8 +1173,8 @@ class DeepSpeedEngine:
                 return loss, grads
 
         replicated = self.mesh_ctx.replicated()
-        # the un-jitted body doubles as the fused whole-step program's scan
-        # body (runtime/fused_step.py) — one definition, two compilations
+        # the un-jitted body, which the Program Auditor traces abstractly
+        # (analysis/auditor.py)
         self._loss_and_grads = loss_and_grads
         grad_out_shardings = (replicated, self.grad_shardings)
         if moe_stats or exempt is not None:
@@ -1298,9 +1276,9 @@ class DeepSpeedEngine:
         # and the new one takes its buffers (or, under bf16 gradient
         # buffers, a gradient's: same shapes, same dtype).
         _install_donation_warning_filter()
-        # un-jitted apply body reused as the fused program's epilogue;
-        # the donate tuple is recorded for the Program Auditor's donation
-        # rule (analysis/auditor.py) so the audit reflects the dispatch
+        # the un-jitted apply body and its donate tuple are recorded for
+        # the Program Auditor's donation rule (analysis/auditor.py), so the
+        # audit reflects the dispatch
         self._apply_core = apply_core = apply_step
         self._apply_donate_argnums = (0, 1, 3)
         apply_shardings = (self.param_shardings, self.opt_shardings,
@@ -1448,17 +1426,12 @@ class DeepSpeedEngine:
             self._recompile_guard.note_planned()
         replicated = self.mesh_ctx.replicated()
         self.opt_state = jax.device_put(self.opt_state, replicated)
-        progs = self._onebit_get_programs()
+        self._onebit_get_programs()
         self._onebit_wire_error = jax.device_put(
             init_onebit_wire_error(self.params, ob["world"]),
             self.mesh_ctx.sharding(ob["axis"]))
         self._onebit_phase = "compressed"
         self._lockstep_sig_cache = None
-        if self._fused_step_fn is not None:
-            fb = progs["fused"]
-            self._fused_step_fn = fb["fn"]
-            self._fused_step_raw = fb["raw"]
-            self._fused_donate_argnums = fb["donate_argnums"]
         log_dist(
             f"onebit tier: entering compressed phase at applied step "
             f"{ob['freeze_step']} (planned retrace: {planned}) — dense "
@@ -1472,13 +1445,6 @@ class DeepSpeedEngine:
         self._onebit_phase = "warmup"
         self._onebit_wire_error = None
         self._lockstep_sig_cache = None
-        if self._fused_step_fn is not None and \
-                self._onebit_programs is not None:
-            fa = self._onebit_programs.get("fused_phase_a")
-            if fa is not None:
-                self._fused_step_fn = fa["fn"]
-                self._fused_step_raw = fa["raw"]
-                self._fused_donate_argnums = fa["donate_argnums"]
         log_dist("onebit tier: back to warmup phase (checkpoint load)",
                  ranks=[0])
 
@@ -1679,19 +1645,6 @@ class DeepSpeedEngine:
                 donate_argnums=apply_donate),
             "wire_sharding": stacked_sharding,
         }
-        if self._fused_step_fn is not None:
-            from .fused_step import build_fused_step
-            progs["fused_phase_a"] = {
-                "fn": self._fused_step_fn,
-                "raw": self._fused_step_raw,
-                "donate_argnums": self._fused_donate_argnums,
-            }
-            progs["fused"] = build_fused_step(self, onebit={
-                "loss_and_grads": loss_and_grads,
-                "apply_core": apply_core,
-                "world": W,
-                "wire_sharding": stacked_sharding,
-            })
         self._onebit_programs = progs
         return progs
 
@@ -1714,29 +1667,6 @@ class DeepSpeedEngine:
             x = jnp.asarray(x) if not isinstance(x, jax.Array) else x
             if getattr(x, "ndim", 0) >= 1 and x.shape[0] % dp == 0:
                 return jax.device_put(x, self.mesh_ctx.data_sharding())
-            return jax.device_put(x, self.mesh_ctx.replicated())
-        return jax.tree.map(place, tree)
-
-    def _shard_stacked_batch(self, tree):
-        """Placement for fused-step input: leaves carry a leading [gas]
-        microbatch (scan) axis, so the data-parallel batch dim is axis 1
-        (same decision rule as _shard_batch, shifted by one)."""
-        dp = self.world_size
-        multihost = jax.process_count() > 1
-        stacked_data = self.mesh_ctx.sharding(
-            None, (mesh_mod.DATA_AXIS, mesh_mod.EXPERT_AXIS))
-
-        def place(x):
-            if multihost:
-                x = np.asarray(x)
-                if x.ndim >= 2:
-                    return jax.make_array_from_process_local_data(
-                        stacked_data, x)
-                return jax.make_array_from_process_local_data(
-                    self.mesh_ctx.replicated(), x)
-            x = jnp.asarray(x) if not isinstance(x, jax.Array) else x
-            if getattr(x, "ndim", 0) >= 2 and x.shape[1] % dp == 0:
-                return jax.device_put(x, stacked_data)
             return jax.device_put(x, self.mesh_ctx.replicated())
         return jax.tree.map(place, tree)
 
@@ -1773,9 +1703,9 @@ class DeepSpeedEngine:
         """[(name, text)] of the step programs this engine has launched:
         ``name`` as the ``XLA Modules`` line of a device trace shows it
         (``jit_loss_and_grads``, ``jit_accumulate``, ``jit_apply_step``,
-        ``jit_fused_step``, before the fingerprint in parentheses) and
-        ``text()`` the optimized HLO, ``compiled.as_text()``, lowered
-        from the shapes of the first call.  Nothing is lowered until
+        before the fingerprint in parentheses) and ``text()`` the
+        optimized HLO, ``compiled.as_text()``, lowered from the shapes of
+        the first call.  Nothing is lowered until
         ``text`` is called; the compile cache serves it where one is on.
         profiling/scope_map.py turns the text into (scope, phase) per
         instruction."""
@@ -2164,8 +2094,7 @@ class DeepSpeedEngine:
                   "dtype": str(self.compute_dtype.__name__),
                   "gas": self.gradient_accumulation_steps(),
                   "micro_batch": self.train_micro_batch_size_per_gpu(),
-                  "world_size": self.world_size,
-                  "fused_step": self._fused_step_fn is not None})
+                  "world_size": self.world_size})
 
     def _monitor_boundary_reads(self) -> Dict[str, Any]:
         """Flush-boundary device reads, batched: one lr (may read an
@@ -2438,7 +2367,7 @@ class DeepSpeedEngine:
                 "each optimizer step from the step's routing counts); "
                 f"{path} does not thread those counts to the apply "
                 "program and would train the model with the leaves frozen. "
-                "Use the modular forward / backward / step loop.")
+                "Train it without that path.")
 
     def _exempt_note_stats(self, stats) -> None:
         """Sum one micro-batch's RoutingStats into the step's, for the
@@ -2864,22 +2793,13 @@ class DeepSpeedEngine:
     # ------------------------------------------------------------------ #
     def train_batch(self, data_iter=None):
         """Run gradient_accumulation_steps micro-steps + one optimizer step
-        (mirrors the reference PipelineEngine.train_batch API).
-
-        With ``fused_step.enabled`` (and no fallback feature active) the
-        whole batch is ONE compiled dispatch — scan-based accumulation plus
-        the in-program apply (runtime/fused_step.py); the returned loss is
-        a device scalar (mean over the gas microbatches) that the caller
-        may float() when it actually needs the value.  Otherwise the
-        modular forward/backward/step loop runs, fetching the losses once
-        at the end of the batch instead of once per microbatch."""
+        (mirrors the reference PipelineEngine.train_batch API): the
+        forward/backward/step loop, fetching the losses once at the end of
+        the batch instead of once per microbatch."""
         if data_iter is None:
             if self.training_dataloader is None:
                 raise ValueError("train_batch needs data_iter or training_data")
             data_iter = iter(self.training_dataloader)
-        if self._fused_step_fn is not None and self._is_train_mode:
-            with self._span("train_batch", step=self.global_steps + 1):
-                return self._fused_train_batch(data_iter)
         losses = []
         for _ in range(self.gradient_accumulation_steps()):
             batch = next(data_iter)
@@ -2892,146 +2812,6 @@ class DeepSpeedEngine:
         # one host fetch AFTER the whole window is dispatched (not one per
         # microbatch) so the queue stays deep across the accumulation loop
         return float(np.mean([np.asarray(loss) for loss in losses]))
-
-    def _fused_train_batch(self, data_iter):
-        """One fused dispatch: pull gas microbatches, stack them on a
-        leading scan axis, run the whole-step program, then do the same
-        host bookkeeping step() would — minus the per-microbatch fences."""
-        from .dataloader import stack_microbatches
-        if self._onebit is not None and self._onebit_phase == "warmup":
-            self._maybe_onebit_switch()
-        gas = self.gradient_accumulation_steps()
-        batches = []
-        for _ in range(gas):
-            b = next(data_iter)
-            batches.append(b if isinstance(b, tuple) else (b,))
-        self.tput_timer.start()
-        if self.monitor is not None:
-            self.monitor.mark_step_start()
-            self._monitor_note_batch(batches[0])
-        stacked = stack_microbatches(batches)
-        self._observe_retrace(stacked)
-        args = self._shard_stacked_batch(stacked)
-        rng = self._next_rng()
-        with self._span("train_batch.dispatch", step=self.global_steps + 1,
-                        program=_program_name(self._fused_step_fn)):
-            if self._onebit is not None and \
-                    self._onebit_phase == "compressed":
-                # compressed-phase fused program threads the wire-error
-                # state through as a donated carry (fused_step.py onebit
-                # build)
-                (self.params, self.opt_state, self.scaler_state,
-                 self._fused_sent_state, self._onebit_wire_error, loss,
-                 overflow, sent_flags) = self._launch(
-                    self._fused_step_fn, self.params, self.opt_state,
-                    self.scaler_state, self._fused_sent_state,
-                    self._onebit_wire_error, rng, args, {})
-                fused_out = None
-            else:
-                fused_out = self._launch(
-                    self._fused_step_fn, self.params, self.opt_state,
-                    self.scaler_state, self._fused_sent_state, rng, args, {})
-        if fused_out is None:
-            pass
-        elif self._moe_stats_enabled:
-            (self.params, self.opt_state, self.scaler_state,
-             self._fused_sent_state, loss, overflow, sent_flags,
-             moe_stats) = fused_out
-            self._moe_note_stats(moe_stats)
-            self._moe_stats_steps += 1
-        else:
-            (self.params, self.opt_state, self.scaler_state,
-             self._fused_sent_state, loss, overflow,
-             sent_flags) = fused_out
-        self._last_loss = loss
-        self._last_overflow = overflow
-        self.micro_steps += gas
-        self.global_steps += 1
-        self._note_step_interval()
-        # Mirror step()'s skip/scheduler chain exactly: a sentinel skip
-        # wins over the overflow branch (counted once), and the host
-        # scheduler never advances on a skipped step.  The skip_step
-        # policy's verdict is a per-step scalar fetch — like the modular
-        # path's per-step host observe, opting into monitoring opts into
-        # that read; policy "warn" stays fully async (verdicts drain at
-        # boundaries).
-        sentinel_skip = False
-        if self.sentinel is not None and self.sentinel.policy == "skip_step":
-            sentinel_skip = bool(sent_flags[0])
-        if sentinel_skip:
-            self.skipped_steps += 1
-            self.sentinel.record_skip()
-        elif self.scaler_cfg.dynamic:
-            # fp16 keeps its one scalar overflow fetch per optimizer step
-            # (exactly like the modular path — skipped_steps and the
-            # python-side scheduler must stay faithful); amortized over
-            # gas microbatches in one program it is the only read here
-            if bool(overflow):
-                self.skipped_steps += 1
-            elif self.lr_scheduler is not None:
-                self.lr_scheduler.step()
-        elif self.lr_scheduler is not None:
-            self.lr_scheduler.step()
-        if self.sentinel is not None:
-            self._fused_pending_flags.append(
-                (self.global_steps, loss, sent_flags))
-            if (self.global_steps % self.steps_per_print() == 0
-                    or len(self._fused_pending_flags) >= 32):
-                self._drain_fused_sentinel()
-            if self.sentinel.over_budget:
-                # a deferred (non-raising) drain — e.g. from a checkpoint
-                # save — may have exhausted the budget without aborting;
-                # stop at the next step boundary
-                self.sentinel.abort(self.global_steps,
-                                    float(self._last_loss))
-        self.tput_timer.stop(global_step=True)
-        if self.monitor is not None:
-            # no grad_norm here: the fused path's sentinel EWMA is
-            # device-resident (no host-side norm scalar exists without
-            # a per-step sync the fused design forbids), so the fleet
-            # grad-norm divergence lane is loss-only under fused_step —
-            # documented in docs/telemetry.md
-            self.monitor.end_step(self.global_steps, loss=loss,
-                                  tokens=self._monitor_tokens_per_step(),
-                                  counters=self._monitor_counters())
-        self._boundary_logging()
-        self._maybe_handle_preemption()
-        return loss
-
-    def _drain_fused_sentinel(self, raise_abort=True):
-        """Fold the fused program's per-step sentinel verdicts into the
-        host sentinel's counters/budget.  The flags are tiny device bools
-        already computed — draining at boundaries (or every 32 steps)
-        batches the syncs instead of fencing every step; the abort-budget
-        check consequently fires with up to that much latency
-        (docs/fused_step.md).  skipped_steps is NOT counted here — the
-        per-step chain in _fused_train_batch owns it, mirroring step().
-
-        raise_abort=False defers a budget-exhaustion abort to the next
-        step boundary: a drain running inside save_checkpoint (e.g. the
-        preemption emergency save) must never turn the save into a
-        SentinelAbort and lose the checkpoint."""
-        s = self.sentinel
-        pending, self._fused_pending_flags = self._fused_pending_flags, []
-        for step, loss, (flagged, nonfinite) in pending:
-            if not bool(flagged):
-                s.consecutive_anomalies = 0
-                continue
-            nf = bool(nonfinite)
-            loss_val = float(loss)
-            s.anomalies_seen += 1
-            s.last_reasons = [
-                f"loss is non-finite ({loss_val})" if nf else
-                f"loss {loss_val:.6g} exceeded k-sigma in-program "
-                f"(k={s.k_sigma})"]
-            if not (s.policy == "warn" and not nf):
-                s.consecutive_anomalies += 1
-            logger.warning(
-                f"sentinel(fused): anomaly at step {step} "
-                f"({s.consecutive_anomalies}/{s.anomaly_budget} "
-                f"consecutive): {s.last_reasons[0]}")
-            if s.over_budget and raise_abort:
-                s.abort(step, loss_val)
 
     # ------------------------------------------------------------------ #
     # memory estimate (reference: stage2.py:2141)
@@ -3181,14 +2961,6 @@ class DeepSpeedEngine:
             if sig:
                 client[reshard_mod.SIGNATURE_KEY] = sig
         if self.sentinel is not None:
-            if self._fused_step_fn is not None:
-                # fold the in-program loss EWMA + pending verdicts into the
-                # host sentinel so state_dict captures what the fused
-                # program learned; never abort from inside a save (the
-                # preemption emergency checkpoint must complete)
-                self._drain_fused_sentinel(raise_abort=False)
-                from .fused_step import sentinel_state_to_host
-                sentinel_state_to_host(self._fused_sent_state, self.sentinel)
             client["sentinel"] = self.sentinel.state_dict()
         if self.program_audit is not None or self._recompile_guard is not None:
             # audit counters ride client state like the sentinel counters:
@@ -3397,11 +3169,6 @@ class DeepSpeedEngine:
             self.skipped_steps = client.get("skipped_steps", 0)
             if self.sentinel is not None and client.get("sentinel"):
                 self.sentinel.load_state_dict(client["sentinel"])
-                if self._fused_step_fn is not None:
-                    from .fused_step import sentinel_state_from_host
-                    self._fused_pending_flags = []
-                    self._fused_sent_state = sentinel_state_from_host(
-                        self.sentinel, self.mesh_ctx)
             if self._recompile_guard is not None and client.get(
                     "program_audit"):
                 # the retrace tally keeps meaning "distinct shapes this

@@ -67,9 +67,9 @@ def update_loss_scale(cfg: LossScalerConfig, state: LossScaleState,
 
     Select form (jnp.where), not lax.cond: the transition is three scalar
     selects, and a cond would keep both branches' operands alive across the
-    branch boundary — inside the fused whole-step program that blocks XLA
-    from fusing the scaler update into the apply epilogue, the same
-    donation/aliasing argument as the engine's per-leaf overflow skip.
+    branch boundary — that blocks XLA from fusing the scaler update into
+    the apply program's epilogue, the same donation/aliasing argument as
+    the engine's per-leaf overflow skip.
     """
     if not cfg.dynamic:
         return state

@@ -48,18 +48,15 @@ Tensor-parallel ("model") and any other non-ZeRO axes stay *automatic*
 (GSPMD) inside the region — explicit ZeRO streaming composes with
 declarative TP.
 
-Scan-in-scan (fused whole-step program, runtime/fused_step.py): the fused
-train step wraps this layer scan in an OUTER ``lax.scan`` over the
-microbatch axis.  No special casing is needed here, but the composition
-leans on an invariant of this file: gathered layer groups are NEVER saved
-as residuals.  With prefetch that is structural — the hand-written
+Residuals: gathered layer groups are NEVER saved for the backward, or
+the saved stack would be the full unsharded model and max_live would
+bound nothing.  With prefetch that is structural — the hand-written
 VJP's residuals are each layer's input activation carry plus the
 sharded inputs, and the backward re-gathers (``_build_carried_stream``);
 gathering at use, the ``zero3_gathered`` checkpoint-name policy (see
 ``gather_group``) does the same job through the remat machinery.
-Without the invariant the fused program would save gas × (full unsharded
-model) and defeat max_live across microbatches, not just within one.
-Tested by test_fused_step.py::test_fused_zero3_streaming_parity.
+Tested by test_zero3_streaming.py::test_backward_regathers_instead_of_saving
+and ::test_carried_residuals_are_per_layer_carries.
 """
 
 import logging

@@ -4,7 +4,7 @@ One deliberately-broken fixture per lint rule — host callback in a scan
 body, undonated grad carry, divergent collective order, forced fp32
 upcast, wire-budget blowup, retrace storm — asserting rule id, severity,
 and provenance; plus clean-program zero-findings runs over the gpt2
-modular and fused train steps, the shared jaxpr-walk regression pins
+train step, the shared jaxpr-walk regression pins
 (remat2/shard_map/while-cond gaps, custom_vjp-bwd wire bytes), the
 golden lockstep signature, the CLI exit-code contract, and the
 checkpoint round-trip of the audit counters.
@@ -564,9 +564,9 @@ def test_step_time_model_fields_and_bound():
 
 
 # --------------------------------------------------------------------- #
-# clean programs: gpt2 modular + fused train steps audit to zero
+# clean programs: gpt2 train steps audit to zero
 # --------------------------------------------------------------------- #
-def _tiny_engine(extra_config=None, fused=False, bf16=False, gas=1,
+def _tiny_engine(extra_config=None, bf16=False, gas=1,
                  num_layers=2):
     from deepspeed_tpu.models import GPT2Config, GPT2Model
     ds.reset_mesh_context()
@@ -580,7 +580,6 @@ def _tiny_engine(extra_config=None, fused=False, bf16=False, gas=1,
         "gradient_accumulation_steps": gas,
         "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
         "zero_optimization": {"stage": 2},
-        "fused_step": {"enabled": fused},
         "analysis": {"mode": "warn"},
         "steps_per_print": 10 ** 9,
     }
@@ -593,21 +592,15 @@ def _tiny_engine(extra_config=None, fused=False, bf16=False, gas=1,
     return engine
 
 
-def test_clean_gpt2_modular_step_zero_findings():
-    engine = _tiny_engine()
+@pytest.mark.parametrize("bf16, gas", [(False, 1), (True, 2)],
+                         ids=["fp32-gas1", "bf16-gas2"])
+def test_clean_gpt2_modular_step_zero_findings(bf16, gas):
+    engine = _tiny_engine(bf16=bf16, gas=gas)
     report = engine.program_audit
     assert report is not None
     assert report.findings == [], [f.format() for f in report.findings]
     assert report.targets == ["grad_step", "apply_step"]
     assert report.signature is not None
-
-
-def test_clean_gpt2_fused_step_zero_findings():
-    engine = _tiny_engine(fused=True, bf16=True, gas=2)
-    assert engine._fused_step_fn is not None, engine.fused_step_reason
-    report = engine.program_audit
-    assert report.findings == [], [f.format() for f in report.findings]
-    assert report.targets == ["fused_step"]
 
 
 def test_zero3_streaming_gather_on_critical_path_pinned():

@@ -20,9 +20,8 @@ MIN_COLLECTED = 400
 
 def test_resilience_package_imports_cleanly():
     """Lazily-imported engine modules (resilience: only when the config
-    block is on; fused_step: only when fused_step.enabled) would not
-    surface a syntax/import error in most tests — and an ImportError in
-    their test modules would just shrink the suite under
+    block is on) would not surface a syntax/import error in most tests —
+    and an ImportError in their test modules would just shrink the suite under
     --continue-on-collection-errors.  Import each explicitly, in a
     subprocess, so it fails loudly."""
     mods = ("deepspeed_tpu.runtime.resilience",
@@ -41,7 +40,6 @@ def test_resilience_package_imports_cleanly():
             # jax-free and imported by controller-side scripts only
             "deepspeed_tpu.runtime.resilience.reshard",
             "deepspeed_tpu.runtime.resilience.supervisor",
-            "deepspeed_tpu.runtime.fused_step",
             # program auditor: lazily imported by the engine (only when
             # the analysis block is on) and by the CLI entry point
             "deepspeed_tpu.analysis",
@@ -123,26 +121,37 @@ def test_unit_suite_collects_cleanly():
         "did a module or parametrization silently vanish?")
 
 
-def test_fused_step_tests_run_in_fast_lane():
-    """Fast-lane marker audit: the fused-step regression surface (parity,
-    dispatch count, fallback matrix) must run in tier-1, i.e. survive the
-    `-m "not slow"` deselection — a conftest _SLOW_PREFIXES entry or a
-    stray marker would silently drop the whole module from the gate."""
+# the guards of the one step loop on the streamed ZeRO-3 scan, and the
+# loop at every ZeRO stage against the baseline
+FAST_LANE_GUARDS = (
+    "test_zero3_streaming.py::test_carried_mode_parity_fp32",
+    "test_zero3_streaming.py::test_carried_mode_parity_bf16",
+    "test_zero3_streaming.py::test_streaming_matches_baseline",
+    "test_zero3_streaming.py::test_carried_low_bandwidth_parity",
+    "test_zero3_streaming.py::test_carried_hpz_parity",
+    "test_zero3_streaming.py::test_zero3_bf16_streams_on_cpu",
+    "test_zero3_streaming.py::test_streaming_with_tensor_parallel",
+    "test_functionality_matrix.py::test_matrix_matches_baseline",
+)
+
+
+def test_step_loop_guards_run_in_fast_lane():
+    """Fast-lane marker audit: the parities of the streamed ZeRO-3 scan
+    against the unstreamed baseline and the functionality matrix are what
+    guards the forward / backward / step loop end to end, so they must run
+    in tier-1, i.e. survive the `-m "not slow"` deselection — a conftest
+    _SLOW_PREFIXES entry or a stray marker would silently drop them from
+    the gate."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
-        [sys.executable, "-m", "pytest", "tests/unit/test_fused_step.py",
+        [sys.executable, "-m", "pytest",
+         "tests/unit/test_zero3_streaming.py",
+         "tests/unit/test_functionality_matrix.py",
          "--collect-only", "-q", "-m", "not slow", "-p", "no:cacheprovider"],
         cwd=str(REPO), capture_output=True, text=True, timeout=300, env=env)
     assert out.returncode == 0, (
-        f"fused-step collection failed:\n{out.stdout[-1500:]}"
-        f"\n{out.stderr[-1500:]}")
-    m = re.search(r"(\d+) tests? collected", out.stdout)
-    assert m, f"no collection summary:\n{out.stdout[-1500:]}"
-    selected = int(m.group(1))
-    dm = re.search(r"(\d+) deselected", out.stdout)
-    deselected = int(dm.group(1)) if dm else 0
-    assert selected >= 15 and deselected == 0, (
-        f"fused-step fast lane shrank: {selected} selected, "
-        f"{deselected} deselected — the tier-1 gate no longer covers the "
-        "fused path")
+        f"collection failed:\n{out.stdout[-1500:]}\n{out.stderr[-1500:]}")
+    missing = [name for name in FAST_LANE_GUARDS if name not in out.stdout]
+    assert not missing, (
+        f"the tier-1 gate no longer runs {missing}: marked slow?")

@@ -202,19 +202,31 @@ def test_prng_impl_config_knob():
     assert cfg2.prng_impl == "rbg"
 
 
-@pytest.mark.parametrize("section,key", sorted(REMOVED_KEYS))
+# a removed key: (a value it once took, what its message has to name
+# besides its table entry)
+_REMOVED = {
+    "stage3_prefetch_mode": ("off", "stage3_prefetch_bucket_size"),
+    "prefetch_modes": (["off"], "stage3_prefetch_bucket_size"),
+    "fused_step": ({"enabled": False}, "forward / backward / step"),
+    "fused": ([False], "forward / backward / step")}
+
+
+@pytest.mark.parametrize(
+    "section,key", sorted(REMOVED_KEYS),
+    ids=["-".join(filter(None, k)) for k in sorted(REMOVED_KEYS)])
 def test_removed_keys_are_refused_and_name_their_replacement(section, key):
     """A key the package no longer reads is refused, not ignored: a
     silently ignored "stage3_prefetch_mode": "off" would turn the
     prefetch ON.  Every message names what to write instead."""
-    value = ["off"] if key.endswith("s") else "off"
+    value, instead = _REMOVED[key]
+    block = {section: {key: value}} if section else {key: value}
     with pytest.raises(DeepSpeedConfigError) as refused:
-        DeepSpeedConfig({"train_micro_batch_size_per_gpu": 1,
-                         section: {key: value}}, world_size=1)
+        DeepSpeedConfig({"train_micro_batch_size_per_gpu": 1, **block},
+                        world_size=1)
     message = str(refused.value)
-    assert f"{section}.{key}" in message
+    assert (f"{section}.{key}" if section else key) in message
     assert REMOVED_KEYS[(section, key)] in message
-    assert "stage3_prefetch_bucket_size" in message
+    assert instead in message
 
 
 def test_the_prefetch_structure_follows_the_bucket():

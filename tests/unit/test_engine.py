@@ -330,3 +330,119 @@ def test_save_fp16_model_export_bf16_offload(tmp_path):
                for leaf in jax.tree.leaves(engine.params)), \
         "offload engine should hold bf16 device params"
     _assert_fp16_export(engine, tmp_path)
+
+
+# --------------------------------------------------------------------- #
+# the step loop under accumulation: what it dispatches, what it reads
+# --------------------------------------------------------------------- #
+GAS = 4
+
+
+def _accum_stream(n_steps, gas=GAS, micro=8, seed=3):
+    """[(x, y)] covering n_steps optimizer steps."""
+    rng = np.random.RandomState(seed)
+    return [(rng.normal(0, 1, (micro, HIDDEN)).astype(np.float32),
+             rng.normal(0, 1, (micro,)).astype(np.float32))
+            for _ in range(n_steps * gas)]
+
+
+def _run_loop(engine, batches):
+    for x, y in batches:
+        engine.backward(engine.forward(x, y))
+        engine.step()
+
+
+class _CountCalls:
+    def __init__(self, fn):
+        self.fn = fn
+        self.calls = 0
+
+    def __call__(self, *args, **kwargs):
+        self.calls += 1
+        return self.fn(*args, **kwargs)
+
+
+def test_dispatch_count_of_the_step_loop_is_2n():
+    """The loop launches 2N compiled programs an optimizer step at gas=N:
+    N grad programs, N-1 accumulation adds — the first microbatch adopts
+    the grad buffer directly — and 1 apply.  Wrapping the engine's
+    compiled callables counts every dispatch the step loop can issue."""
+    steps = 3
+    engine = make_engine(gas=GAS)
+    counters = {}
+    for name in ("_grad_fn", "_acc_fn", "_apply_fn"):
+        counters[name] = _CountCalls(getattr(engine, name))
+        setattr(engine, name, counters[name])
+    _run_loop(engine, _accum_stream(steps))
+    assert counters["_grad_fn"].calls == steps * GAS
+    assert counters["_acc_fn"].calls == steps * (GAS - 1)
+    assert counters["_apply_fn"].calls == steps
+    total = sum(c.calls for c in counters.values())
+    assert total == steps * 2 * GAS                         # 2N per step
+
+
+class _RecordingWriter:
+    def __init__(self):
+        self.scalars = []
+
+    def add_scalar(self, tag, value, step):
+        self.scalars.append((tag, value, step))
+
+
+def test_summary_writer_and_lr_reads_only_at_boundaries():
+    """step() used to call float(self._last_loss) + get_lr() for the
+    writer on EVERY step, forcing a device sync each step; both must now
+    run only at steps_per_print / tensorboard.write_interval boundaries."""
+    engine = make_engine(gas=GAS, steps_per_print=3)
+    writer = _RecordingWriter()
+    engine._summary_writer = writer
+    engine._tb_write_interval = 3
+    lr_calls = []
+    orig_get_lr = engine.get_lr
+    engine.get_lr = lambda: (lr_calls.append(engine.global_steps)
+                             or orig_get_lr())
+    _run_loop(engine, _accum_stream(7, seed=31))
+    written_steps = sorted({s for (tag, _, s) in writer.scalars
+                            if tag == "Train/Samples/lr"})
+    assert written_steps == [3, 6]
+    assert sorted(set(lr_calls)) == [3, 6]
+
+
+def test_tb_write_interval_config():
+    engine = make_engine(gas=GAS, steps_per_print=100,
+                         tensorboard={"enabled": False, "write_interval": 7})
+    assert engine._tb_write_interval == 7
+    ds.reset_mesh_context()
+    engine = make_engine(gas=GAS, steps_per_print=100)
+    assert engine._tb_write_interval == 100
+
+
+# --------------------------------------------------------------------- #
+# the paths a model may refuse
+# --------------------------------------------------------------------- #
+@pytest.mark.parametrize("module, name", [
+    ("ouro", "OuroModel"), ("keye_vl2", "KeyeVL2Model"),
+    ("granite_hybrid", "GraniteHybridModel"),
+    ("nemotron_h", "NemotronHModel")])
+def test_every_key_of_refuses_is_a_path_the_engine_checks(module, name):
+    """A key that is none of the engine's paths would refuse nothing."""
+    import importlib
+    from deepspeed_tpu.runtime.engine import REFUSABLE_PATHS
+    refuses = getattr(importlib.import_module(
+        f"deepspeed_tpu.models.{module}"), name).refuses
+    assert refuses and set(refuses) <= set(REFUSABLE_PATHS)
+    assert all(isinstance(why, str) and why for why in refuses.values())
+
+
+def test_an_unknown_key_of_refuses_raises_at_construction():
+    class Model:
+        refuses = {"zero3_streaming": "it has not been run there",
+                   "fused_step": "a path that is gone"}
+
+        def __call__(self, params, rng, x, y):
+            return simple_model_apply(params, rng, x, y)
+
+    with pytest.raises(ValueError, match=r"\['fused_step'\].*knows "
+                       r"\['zero3_streaming', 'pipeline'\]"):
+        ds.initialize(model=Model(), config=base_engine_config(),
+                      model_parameters=simple_model_params(HIDDEN))

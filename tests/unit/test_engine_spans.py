@@ -22,9 +22,9 @@ FORWARD_CHILDREN = ("ds.forward.prepare", "ds.forward.shard_batch",
                     "ds.forward.rng", "ds.forward.dispatch")
 
 
-def _engine(tmp_path, gas, fused=False):
+def _engine(tmp_path, gas):
     """test_monitor.py's two-layer GPT-2 engine, Chrome export on."""
-    return _monitored_engine(tmp_path, gas=gas, fused=fused, monitor={
+    return _monitored_engine(tmp_path, gas=gas, monitor={
         "writers": ["jsonl"], "trace": True})
 
 
@@ -131,29 +131,6 @@ def test_modular_loop_spans(tmp_path, gas):
     stepped = [e["args"]["step"] for e in payload["traceEvents"]
                if e["name"] == "ds.step"]
     assert stepped == list(range(1, 2 + STEPS))
-
-
-def test_fused_path_spans(tmp_path):
-    engine = _engine(tmp_path, gas=2, fused=True)
-    assert engine._fused_step_fn is not None, engine.fused_step_reason
-    ids = np.random.RandomState(0).randint(0, 64, (2, 16)).astype(np.int32)
-
-    def loop(steps):
-        for _ in range(steps):
-            loss = engine.train_batch(iter([ids, ids]))
-        jax.block_until_ready(loss)
-
-    loop(1)
-    spans = _profiled(tmp_path, lambda: loop(STEPS))
-    engine.monitor.close()
-    assert [s[0] for s in spans] == ["ds.train_batch",
-                                     "ds.train_batch.dispatch",
-                                     "ds.monitor.record"] * STEPS
-    assert [s[3]["step"] for s in spans] == [
-        step for step in range(2, 2 + STEPS) for _ in range(3)]
-    assert {s[3]["program"] for s in spans[1::3]} == {"jit_fused_step"}
-    assert all(_inside(c, p) for p, c in zip(spans[::3], spans[1::3]))
-    assert all(_inside(c, p) for p, c in zip(spans[::3], spans[2::3]))
 
 
 def test_a_span_without_profiler_or_monitor_is_inert():

@@ -4,6 +4,8 @@ on the simulated 8-device mesh and assert they all compute the SAME
 optimization trajectory (ZeRO/TP/offload are memory/layout strategies, not
 math changes)."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -53,6 +55,12 @@ def _train(zero_stage: int, tp: int, offload: bool, steps: int = 3):
     return losses, final
 
 
+@functools.lru_cache(maxsize=None)
+def _baseline():
+    """The cell every other is compared with, trained once."""
+    return _train(0, 1, False)
+
+
 MATRIX = [
     (0, 1, False), (1, 1, False), (2, 1, False), (3, 1, False),
     (0, 2, False),  # pure TP vs TP=1 — validates TP is math-preserving
@@ -64,7 +72,7 @@ MATRIX = [
                          ids=[f"z{s}-tp{t}{'-off' if o else ''}"
                               for s, t, o in MATRIX])
 def test_matrix_matches_baseline(stage, tp, offload):
-    base_losses, base_params = _train(0, 1, False)
+    base_losses, base_params = _baseline()
     losses, params = _train(stage, tp, offload)
     np.testing.assert_allclose(losses, base_losses, rtol=2e-4,
                                err_msg=f"z{stage} tp{tp} off={offload}")

@@ -444,13 +444,6 @@ def test_the_shape_decides_the_latent_path_and_the_plan_says_which(
 # ---------------------------------------------------------------------- #
 # the engine paths that cannot thread the stats refuse the model
 # ---------------------------------------------------------------------- #
-def test_fused_step_refuses_a_model_with_exempt_leaves():
-    model = Glm4MoeLiteModel(_config())
-    with pytest.raises(NotImplementedError, match="fused_step"):
-        _engine(model, _params(model), fused_step={"enabled": True})
-    ds.reset_mesh_context()
-
-
 def test_a_custom_grad_program_refuses_a_model_with_exempt_leaves():
     from deepspeed_tpu.runtime.engine import DeepSpeedEngine
 

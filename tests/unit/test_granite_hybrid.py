@@ -243,13 +243,13 @@ def test_the_engine_trains_it_on_the_normal_path():
     ds.reset_mesh_context()
 
 
-@pytest.mark.parametrize("path, extra", [
-    ("zero3_streaming", {"zero_optimization": {"stage": 3}}),
-    ("fused_step", {"fused_step": {"enabled": True}})])
-def test_the_model_refuses_what_it_has_not_been_run_on(path, extra):
+@pytest.mark.parametrize("path, extra, axes", [
+    ("zero3_streaming", {"zero_optimization": {"stage": 3}}, {"data": 8}),
+    ("pipeline", {"train_batch_size": 4}, {"data": 4, "pipe": 2})])
+def test_the_model_refuses_what_it_has_not_been_run_on(path, extra, axes):
     model = _model()
     ds.reset_mesh_context()
-    mesh = ds.initialize_mesh(data=8)
+    mesh = ds.initialize_mesh(**axes)
     with pytest.raises(NotImplementedError, match=path):
         ds.initialize(model=model, mesh=mesh,
                       model_parameters=model.init_params(

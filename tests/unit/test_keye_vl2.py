@@ -21,10 +21,11 @@ def _config(**fields):
         index_topk=16, initializer_range=0.1, bf16=False, **fields)
 
 
-def _engine(config, **ds_fields):
+def _engine(config, pipe=1, **ds_fields):
     model = KeyeVL2Model(config)
     ds.reset_mesh_context()
-    mesh = ds.initialize_mesh(devices=jax.devices()[:1], data=1)
+    mesh = ds.initialize_mesh(devices=jax.devices()[:pipe], data=1,
+                              pipe=pipe)
     engine, _, _, _ = ds.initialize(
         model=model, mesh=mesh,
         model_parameters=model.init_params(jax.random.PRNGKey(0)),
@@ -59,7 +60,7 @@ def test_trains_through_initialize():
 
 @pytest.mark.parametrize("path, fields", [
     ("zero3_streaming", {"zero_optimization": {"stage": 3}}),
-    ("fused_step", {"fused_step": {"enabled": True}})])
+    ("pipeline", {"pipe": 2})])
 def test_refuses_the_paths_it_has_not_been_run_under(path, fields):
     with pytest.raises(NotImplementedError, match=path):
         _engine(_config(), **fields)

@@ -80,8 +80,9 @@ def test_gpt2_activation_checkpointing_same_loss():
         cfg = tiny_gpt2(activation_checkpointing=ckpt)
         model = GPT2Model(cfg)
         params = model.init_params(jax.random.PRNGKey(0))
-        grads = jax.grad(lambda p: model.loss(p, None, ids))(params)
-        losses[ckpt] = (float(model.loss(params, None, ids)),
+        loss, grads = jax.jit(jax.value_and_grad(
+            lambda p: model.loss(p, None, ids)))(params)
+        losses[ckpt] = (float(loss),
                         float(jnp.mean(jnp.abs(grads["wte"]))))
     assert np.allclose(losses[False], losses[True], rtol=1e-5)
 

@@ -5,8 +5,8 @@ accumulator reaches the JSONL stream as ``moe`` records with the
 ExpertPopularitySnapshot embedded (round-trip pinned on a rigged skewed
 router — the consumable contract ROADMAP item 6's NVMe expert streamer
 keys on), the host-sync audit regression (monitor.moe adds ZERO
-findings and leaves the lockstep signature + wire bytes bit-identical,
-modular and fused), the fused gas scan's in-program accumulation, the
+findings and leaves the lockstep signature + wire bytes bit-identical),
+the accumulation over a step's micro-batches on the device, the
 boundary-only fetch cadence, the monitor-on-vs-off wall tolerance on
 the MoE row, and the config/schema validation satellites.
 """
@@ -33,7 +33,7 @@ V, S, H = 128, 16, 32
 # --------------------------------------------------------------------- #
 # engine fixtures (tiny GPT-MoE on an expert=4 mesh)
 # --------------------------------------------------------------------- #
-def _moe_engine(tmp_path, monitor_moe=True, fused=False, gas=1,
+def _moe_engine(tmp_path, monitor_moe=True, gas=1,
                 num_layers=2, monitor=True):
     from deepspeed_tpu.models import GPTMoEConfig, GPTMoEModel
     ds.reset_mesh_context()
@@ -49,7 +49,6 @@ def _moe_engine(tmp_path, monitor_moe=True, fused=False, gas=1,
         "gradient_accumulation_steps": gas,
         "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
         "zero_optimization": {"stage": 2},
-        "fused_step": {"enabled": fused},
         "steps_per_print": 10 ** 9,
     }
     if monitor:
@@ -201,10 +200,9 @@ def test_moe_monitor_on_adds_zero_host_sync_findings(tmp_path):
     assert report.wire_bytes_per_step == plain_report.wire_bytes_per_step
 
 
-def test_moe_monitor_fused_audit_clean_and_gas_accumulates(tmp_path):
+def test_moe_monitor_train_batch_audit_clean_and_gas_accumulates(tmp_path):
     from deepspeed_tpu.analysis import RULE_HOST_SYNC, audit_engine
-    engine, cfg = _moe_engine(tmp_path, fused=True, gas=2)
-    assert engine._fused_step_fn is not None, engine.fused_step_reason
+    engine, cfg = _moe_engine(tmp_path, gas=2)
     ids = np.random.RandomState(0).randint(0, V, (8, S)).astype(np.int32)
 
     def it():
@@ -222,8 +220,8 @@ def test_moe_monitor_fused_audit_clean_and_gas_accumulates(tmp_path):
     assert len(moe) == 2
     n_moe_layers = sum(cfg.is_moe_layer(i) for i in range(cfg.num_layers))
     for m in moe:
-        # the gas scan summed IN-program: both microbatches' slots land
-        # in one per-step total (layers x tokens x k x gas)
+        # summed on the device over the step: both microbatches' slots
+        # land in one per-step total (layers x tokens x k x gas)
         assert m[R.M_TOKENS_PER_STEP] == n_moe_layers * 8 * S * 2 * 2
         assert m[R.M_STEPS] == 2
 

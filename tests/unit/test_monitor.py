@@ -38,8 +38,7 @@ from deepspeed_tpu.monitor.reconcile import (ATTR_COMM_EXPOSED,
 # --------------------------------------------------------------------- #
 # engine fixture (CPU gpt2 — the acceptance config)
 # --------------------------------------------------------------------- #
-def _engine(tmp_path, monitor=None, num_layers=2, gas=1, fused=False,
-            extra=None):
+def _engine(tmp_path, monitor=None, num_layers=2, gas=1, extra=None):
     from deepspeed_tpu.models import GPT2Config, GPT2Model
     ds.reset_mesh_context()
     cfg = GPT2Config(vocab_size=64, n_positions=16, hidden_size=32,
@@ -52,7 +51,6 @@ def _engine(tmp_path, monitor=None, num_layers=2, gas=1, fused=False,
         "gradient_accumulation_steps": gas,
         "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
         "zero_optimization": {"stage": 2},
-        "fused_step": {"enabled": fused},
         "steps_per_print": 10 ** 9,
     }
     if monitor is not None:
@@ -296,12 +294,11 @@ def test_monitor_on_adds_zero_host_sync_findings(tmp_path):
     assert report.wire_bytes_per_step == plain_report.wire_bytes_per_step
 
 
-def test_monitor_on_fused_step_audit_clean(tmp_path):
+def test_monitor_on_train_batch_audit_clean(tmp_path):
     from deepspeed_tpu.analysis import RULE_HOST_SYNC, audit_engine
-    engine = _engine(tmp_path, gas=2, fused=True,
+    engine = _engine(tmp_path, gas=2,
                      monitor={"writers": ["jsonl"], "trace": True},
                      extra={"bf16": {"enabled": True}})
-    assert engine._fused_step_fn is not None, engine.fused_step_reason
     rng = np.random.RandomState(0)
     ids = rng.randint(0, 64, size=(2, 16)).astype(np.int32)
 
@@ -318,7 +315,7 @@ def test_monitor_on_fused_step_audit_clean(tmp_path):
     recs = [json.loads(line) for line in open(engine.monitor.jsonl_path)]
     steps = [r for r in recs if r.get(R.F_KIND) == KIND_STEP]
     assert len(steps) == 3
-    assert all(r[R.F_DISPATCHES_PER_STEP] == 1 for r in steps)
+    assert all(r[R.F_DISPATCHES_PER_STEP] == 4 for r in steps)
 
 
 def test_dispatches_per_step_counts_the_launches_of_step_programs(tmp_path):

@@ -221,7 +221,6 @@ def test_three_steps_through_initialize_move_the_biases_and_the_loss(sides):
 
 @pytest.mark.parametrize("path,config,mesh", [
     ("zero3_streaming", {"zero_optimization": {"stage": 3}}, {}),
-    ("fused_step", {"fused_step": {"enabled": True}}, {}),
     ("pipeline", {}, {"pipe": 2}),
 ])
 def test_the_engine_refuses_what_the_model_has_not_run(path, config, mesh):

@@ -16,8 +16,7 @@ Covers the round-20 acceptance surface:
     reduction of the compressed program vs its dense twin;
   - e2e: 6-step parity across the switch, fp16 forced-overflow skip
     leaves params/momentum/wire-error untouched, checkpoint/resume on
-    both sides of freeze_step restores the phase as program identity,
-    fused-vs-modular parity through the switch;
+    both sides of freeze_step restores the phase as program identity;
   - config conflicts (config.py _validate_onebit).
 """
 
@@ -39,7 +38,7 @@ MICRO = 8
 # helpers
 # --------------------------------------------------------------------- #
 def make_engine(tier=True, optimizer="OneBitAdam", freeze=3, lr=1e-3,
-                stage=2, hidden=HIDDEN, gas=1, analysis=None, fused=False,
+                stage=2, hidden=HIDDEN, gas=1, analysis=None,
                 extra=None, opt_params=None):
     ds.reset_mesh_context()
     cfg = base_engine_config(micro_batch=MICRO, gas=gas)
@@ -56,8 +55,6 @@ def make_engine(tier=True, optimizer="OneBitAdam", freeze=3, lr=1e-3,
         cfg["zero_optimization"]["low_bandwidth"] = {"onebit": True}
     if analysis:
         cfg["analysis"] = analysis
-    if fused:
-        cfg["fused_step"] = {"enabled": True}
     if extra:
         cfg.update(extra)
     engine, _, _, _ = ds.initialize(model=simple_model_apply, config=cfg,
@@ -315,7 +312,7 @@ def test_compressed_wire_4x_reduction():
 
 
 # --------------------------------------------------------------------- #
-# e2e parity, overflow-skip, checkpoint, fused
+# e2e parity, overflow-skip, checkpoint
 # --------------------------------------------------------------------- #
 def test_e2e_six_step_parity():
     """6 steps across freeze=3: the warmup half is bitwise vs the
@@ -395,36 +392,6 @@ def test_checkpoint_across_freeze_boundary(tmp_path):
     run_steps(e, extra)
     run_steps(e3, extra)
     assert_tree_equal(e.params, e3.params)
-
-
-def test_fused_modular_parity_through_switch():
-    """The fused gas-scan step must track the modular loop through the
-    phase switch — same freeze boundary, same compressed numerics."""
-    gas = 2
-    rng = np.random.RandomState(19)
-    micro_batches = [(rng.normal(0, 1, (MICRO, HIDDEN)).astype(np.float32),
-                      rng.normal(0, 1, (MICRO,)).astype(np.float32))
-                     for _ in range(5 * gas)]
-
-    e_mod = make_engine(freeze=2, gas=gas)
-    it = iter(micro_batches)
-    for _ in range(5):
-        for _ in range(gas):
-            x, y = next(it)
-            loss = e_mod.forward(x, y)
-            e_mod.backward(loss)
-            e_mod.step()
-
-    e_fus = make_engine(freeze=2, gas=gas, fused=True)
-    assert e_fus._fused_step_fn is not None, e_fus.fused_step_reason
-    it = iter(micro_batches)
-    for _ in range(5):
-        e_fus.train_batch(it)
-
-    assert e_mod._onebit_phase == e_fus._onebit_phase == "compressed"
-    assert_tree_close(e_mod.params, e_fus.params, rtol=1e-5, atol=1e-6)
-    assert_tree_close(e_mod._onebit_wire_error, e_fus._onebit_wire_error,
-                      rtol=1e-5, atol=1e-6)
 
 
 # --------------------------------------------------------------------- #

@@ -486,7 +486,6 @@ def test_trains_through_initialize_and_reports_its_counters():
 
 @pytest.mark.parametrize("path,config,mesh", [
     ("zero3_streaming", {"zero_optimization": {"stage": 3}}, {}),
-    ("fused_step", {"fused_step": {"enabled": True}}, {}),
     ("pipeline", {}, {"pipe": 2}),
 ])
 def test_paths_the_model_refuses_by_name(path, config, mesh):

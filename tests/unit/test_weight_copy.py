@@ -324,9 +324,10 @@ def test_a_checkpoint_holds_the_master_alone(tmp_path):
 @pytest.mark.parametrize("config, kept", [
     ({"bf16": BF16_HALF_GRADS}, True),
     ({"bf16": BF16}, True),
-    ({"bf16": BF16, "fused_step": {"enabled": True}}, False),
+    ({"bf16": BF16, "sparse_gradients": True,
+      "zero_optimization": {"stage": 1}}, False),
     ({}, False),
-], ids=["half-grads", "bf16", "fused-step", "fp32"])
+], ids=["half-grads", "bf16", "sparse-gradients", "fp32"])
 def test_the_budget_is_the_parents_with_the_copy_among_the_state(
         monkeypatch, config, kept):
     limit = 16 * 10 ** 9
@@ -524,18 +525,10 @@ def _a_model_that_says_so():
     return engine, step
 
 
-def _fused_step():
-    engine, _ = _simple_path(fused_step={"enabled": True})
-    assert engine._fused_step_fn is not None
-    batch = _simple_batch(0)
-    return engine, lambda: engine.train_batch(iter([batch]))
-
-
 @pytest.mark.parametrize("build, why", [
     (_a_model_that_says_so, "routed experts"),
     (_pipeline, "pipeline engine"), (_sparse_gradients, "sparse_gradients"),
-    (_onebit, "1-bit"), (_offload, "offload"), (_fused_step, "fused_step"),
-    (_zero3_stream, "ZeRO-3")], ids=lambda x: getattr(x, "__name__", None))
+    (_onebit, "1-bit"), (_offload, "offload"), (_zero3_stream, "ZeRO-3")], ids=lambda x: getattr(x, "__name__", None))
 def test_a_path_with_programs_of_its_own_keeps_its_cast(build, why):
     engine, step = build()
     assert engine._weights is None and engine._copy_mask is None

@@ -7,6 +7,8 @@ PartitionedParameterCoordinator (gather-at-use, bounded live set, prefetch)
 with the non-streamed baseline across group sizes / prefetch / TP.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -167,6 +169,13 @@ def _train(zero_cfg: dict, tp: int = 1, steps: int = 3, num_layers: int = 4):
     return losses, final, stream
 
 
+@functools.lru_cache(maxsize=None)
+def _baseline():
+    """``_train`` without ZeRO, once for every test that compares with
+    it: (losses, final parameters)."""
+    return _train({"stage": 0})[:2]
+
+
 # one layer of the test model ~ 4*64*64 + 2*64*256 + 9*64 + 256 = 50k params
 LAYER_PARAMS = 4 * 64 * 64 + 2 * 64 * 256 + 9 * 64 + 256
 
@@ -183,7 +192,7 @@ LAYER_PARAMS = 4 * 64 * 64 + 2 * 64 * 256 + 9 * 64 + 256
      "stage3_prefetch_bucket_size": 0},
 ])
 def test_streaming_matches_baseline(stream_cfg):
-    base_losses, base_params, _ = _train({"stage": 0})
+    base_losses, base_params = _baseline()
     cfg = dict(stage=3, stage3_param_persistence_threshold=0, **stream_cfg)
     losses, params, stream = _train(cfg)
     assert stream is not None and stream.active
@@ -235,7 +244,7 @@ def test_backward_regathers_instead_of_saving():
 
 
 def test_streaming_with_tensor_parallel():
-    base_losses, base_params, _ = _train({"stage": 0})
+    base_losses, base_params = _baseline()
     losses, params, stream = _train(
         {"stage": 3, "stage3_param_persistence_threshold": 0,
          "stage3_max_live_parameters": LAYER_PARAMS,
