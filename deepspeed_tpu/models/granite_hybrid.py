@@ -276,7 +276,7 @@ class GraniteHybridModel:
                 ", ".join(kind + (f" x{count}" if count > 1 else "")
                           for kind, _, count in cfg.runs()),
                 "scanned" if use_scan else "unrolled",
-                cfg.mamba_n_groups)}
+                cfg.mamba_n_groups, self.mixer.conv_form(seq))}
 
     def hidden_states(self, params, input_ids):
         """input_ids [B, S] -> the hidden states before the final norm,

@@ -6,8 +6,9 @@ key says), N states, G groups of B and C that H / G heads each read.
     [z, xBC] = u W_in, dt = u W_dt   (one published ``in_proj`` [hidden,
         2 H P + 2 G N + H], kept as two leaves so that the H step
         columns leave their product in float32)
-    xBC = silu(conv(xBC) + b)        depthwise, causal, ``d_conv`` taps
-    [x, B, C] = xBC                  H P, G N and G N wide
+    [x, B, C] = silu(conv(xBC) + b)  depthwise, causal, ``d_conv`` taps
+        (ops/causal_conv.py: read where the projection wrote it, x, B
+        and C written H P, G N and G N wide where the scan reads them)
     dt = softplus(dt + dt_bias), A = -exp(A_log)
     S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t[g], y_t = S_t C_t[g]
         + D x_t                      a head, g its group (ops/ssd_scan.py)
@@ -26,19 +27,11 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from ..ops import causal_conv as conv
 from ..ops import ssd_scan as ssd
 
-
-def causal_conv(x, w, b):
-    """Depthwise causal conv over positions: x [B, S, C], w [C, taps]
-    (tap j reads position t - (taps - 1) + j), b [C] -> silu(conv + b),
-    in float32, rounded once to x's dtype."""
-    taps, seq = w.shape[1], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    w = w.astype(jnp.float32)
-    out = sum(padded[:, j:j + seq].astype(jnp.float32) * w[:, j]
-              for j in range(taps))
-    return jax.nn.silu(out + b.astype(jnp.float32)).astype(x.dtype)
+# the op's, importable from here (and from models/granite_hybrid.py)
+causal_conv = conv.causal_conv
 
 
 def gated_rms_norm(y, z, gain, eps, groups=1):
@@ -111,6 +104,18 @@ class Mamba2Mixer:
             self.n_heads, self.d_head, self.d_state, self.chunk_size,
             self.n_groups) else "xla"
 
+    def conv_parts(self):
+        """Widths of x, B and C along the conv's channels."""
+        bc = self.n_groups * self.d_state
+        return self.d_inner, bc, bc
+
+    def conv_form(self, seq) -> str:
+        """``kernel`` where ops/causal_conv.py's Pallas kernels take the
+        conv of ``seq`` positions on this backend, else ``xla``."""
+        return "kernel" if conv.uses_kernels(
+            seq, self.conv_dim, self.d_conv, self.d_inner,
+            self.conv_parts()) else "xla"
+
     def entry_state_bytes(self, batch, seq) -> int:
         """Bytes of chunk-entry states one mixer's scan saves."""
         return ssd.entry_state_bytes(batch, seq, self.n_heads, self.d_head,
@@ -123,11 +128,12 @@ class Mamba2Mixer:
         di, f32 = self.d_inner, jnp.float32
         with jax.named_scope("ssm"):
             with jax.named_scope("ssm_in"):
-                z, xbc = jnp.split(u @ p["in_w"], [di], axis=-1)
+                zxbc = u @ p["in_w"]
+                z = zxbc[..., :di]
                 dt = jnp.dot(u, p["dt_w"], preferred_element_type=f32)
             with jax.named_scope("ssm_conv"):
-                xbc = causal_conv(xbc, p["conv_w"], p["conv_b"])
-                x, b, c = jnp.split(xbc, [di, di + groups * n], axis=-1)
+                x, b, c = causal_conv(zxbc, p["conv_w"], p["conv_b"],
+                                      first=di, split=self.conv_parts())
             with jax.named_scope("ssm_scan"):
                 y = ssd.ssd_scan(
                     x.reshape(batch, seq, heads, self.d_head),

@@ -277,7 +277,8 @@ class NemotronHModel(SelectionBiasUpdate, ExpertStack):
             R.M_STACK_SSD: (
                 self.mixer.scan_form(), cfg.chunk_size,
                 self.mixer.entry_state_bytes(batch, seq),
-                ", ".join(pattern), "unrolled", cfg.n_groups)}
+                ", ".join(pattern), "unrolled", cfg.n_groups,
+                self.mixer.conv_form(seq))}
 
     def _run(self, params, input_ids, picks, keep):
         """The hidden states before the final norm and ``keep(routing)``

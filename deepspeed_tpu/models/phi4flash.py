@@ -43,6 +43,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..monitor import record as R
+from ..ops.causal_conv import causal_conv
 from ..ops.flash_attention import flash_attention
 from ..ops.fused_cross_entropy import fused_linear_cross_entropy
 from ..ops.normalize import fused_layer_norm
@@ -240,16 +241,11 @@ class Phi4FlashModel:
         cfg = self.config
         n, rank = cfg.ssm_state, cfg.dt_rank
         with jax.named_scope("ssm"):
-            xs, z = jnp.split(x @ p["in_w"], 2, axis=-1)
-            # causal depthwise conv: tap j of 4 reads position t - 3 + j
-            taps = cfg.ssm_conv
-            padded = jnp.pad(xs, ((0, 0), (taps - 1, 0), (0, 0))).astype(
-                jnp.float32)
-            seq = xs.shape[1]
-            conv_w = p["conv_w"].astype(jnp.float32)
-            xs = jax.nn.silu(sum(padded[:, j:j + seq] * conv_w[:, j]
-                           for j in range(taps))
-                       + p["conv_b"].astype(jnp.float32)).astype(x.dtype)
+            xz = x @ p["in_w"]
+            z = xz[..., xz.shape[-1] // 2:]
+            # causal depthwise conv, bias and silu over the first half,
+            # read where the projection wrote it (ops/causal_conv.py)
+            xs = causal_conv(xz, p["conv_w"], p["conv_b"])
             dbc = jnp.dot(xs, p["x_w"], preferred_element_type=jnp.float32)
             dt_low, b_mat, c_mat = jnp.split(dbc, [rank, rank + n], axis=-1)
             dt = jax.nn.softplus(

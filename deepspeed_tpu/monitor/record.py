@@ -158,7 +158,8 @@ M_STACK_INDEXER = "stack_indexer"
 # ops/ssd_scan.py: ["kernel" or "xla", positions a chunk, bytes of
 # chunk-entry states a layer's scan saves, the runs of like layers ("mamba
 # x5, attention, mamba x4"; "M, E, M, E, M, *, E, M, E" where every layer
-# is one sublayer), "scanned" or "unrolled", groups of B and C]
+# is one sublayer), "scanned" or "unrolled", groups of B and C, "kernel" or
+# "xla" again for the conv before the scan (ops/causal_conv.py)]
 M_STACK_SSD = "stack_ssd"
 # (models/xing4.py) a residual path of several streams mixed by
 # hyper-connections (ops/hyper_connection.py): [streams, Sinkhorn rounds,

@@ -419,11 +419,13 @@ def stack_plan_line(plan: Dict[str, Any]) -> str:
                 if block else "")
             for kind, path, *block in plan[R.M_STACK_ROTARY])
     if R.M_STACK_SSD in plan:
-        form, chunk, entry_bytes, runs, mode, groups = plan[R.M_STACK_SSD]
+        form, chunk, entry_bytes, runs, mode, groups, conv = plan[
+            R.M_STACK_SSD]
         line += (f"; runs of like layers: {runs}, {mode}; state-space "
                  f"duality scan: {form} in chunks of {chunk}"
                  + (f" on {groups} groups of B and C" if groups > 1 else "")
-                 + f", {entry_bytes:,} B of chunk-entry states a layer")
+                 + f", {entry_bytes:,} B of chunk-entry states a layer; "
+                 f"conv: {conv}")
     return line
 
 

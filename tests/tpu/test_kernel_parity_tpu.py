@@ -410,3 +410,25 @@ def test_latent_layout_kernels_equal_the_xla_lines_tpu(heads, monkeypatch):
     unit._same(flat, cts[2].transpose(0, 2, 1, 3).reshape(
         unit.BATCH, 1024, heads * unit.VDIM))
     unit._same(pull(flat)[0], cts[2])
+
+
+@pytest.mark.parametrize("cell", ["granite", "nemotron", "phi4"])
+def test_causal_conv_kernels_equal_the_xla_form_tpu(cell):
+    """ops/causal_conv.py on the chip at a cell's channel widths, two
+    blocks of 4,096 positions: x, B and C bit for bit the XLA form's (the
+    taps in its order, one rounding; the TPU contracts no multiply-add);
+    d xBC, rounded once where XLA's derivative rounds each tap's share,
+    to a bf16 value of it; the taps' and the bias' gradients, float32
+    sums in another order, to 1e-5."""
+    from tests.unit import test_causal_conv as unit
+    _, _, widths = unit.CELLS[cell]
+    args = unit.operands(1, 8192, widths, seed=3)
+    got = jax.jit(lambda *a: unit.through("kernel", a, widths))(*args)
+    want = jax.jit(lambda *a: unit.through("xla", a, widths))(*args)
+    np.testing.assert_array_equal(np.asarray(got[0], np.float32),
+                                  np.asarray(want[0], np.float32))
+    dx, dx_xla = (np.asarray(t[1], np.float32) for t in (got, want))
+    np.testing.assert_allclose(dx, dx_xla, rtol=2 ** -6,
+                               atol=float(np.abs(dx_xla).max()) * 2 ** -8)
+    for g, w in zip(got[2:], want[2:]):
+        assert unit.rel(g, w) < 1e-5

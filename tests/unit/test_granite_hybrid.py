@@ -181,15 +181,51 @@ def test_the_kernels_path_is_the_same_model():
             name
 
 
+@pytest.mark.parametrize("seq,form", [(64, "kernel"), (40, "xla")])
+def test_the_conv_kernels_are_the_same_model(seq, form, monkeypatch):
+    """ops/causal_conv.py's kernels through the interpreter (the scan
+    left on its XLA form): an inner width and B and C of one lane tile
+    each, a whole block of positions; 40 positions are none, and the
+    same switch then leaves the model on the XLA form.  The plan's line
+    names the form; loss and every gradient leaf against the model
+    without the switch."""
+    from deepspeed_tpu.ops import causal_conv
+    config = _file_config(mamba_d_state=128, num_hidden_layers=2,
+                          layer_types=["mamba", "attention"])
+    model = _model(config)
+    params = model.init_params(jax.random.PRNGKey(4))
+    ids = _ids(rows=2, seq=seq, seed=5)
+    value_and_grad = jax.value_and_grad(lambda p: model.loss(p, None, ids))
+    assert stack_plan_line(model.stack_plan(2, seq)).endswith("conv: xla")
+    monkeypatch.setattr(causal_conv, "pallas_interpret", lambda: True)
+    assert model.scan_form() == "xla"
+    assert stack_plan_line(model.stack_plan(2, seq)).endswith(
+        f"conv: {form}")
+    kernels = sorted({c.eqn.params["name"] for c in iter_eqns(
+        jax.make_jaxpr(value_and_grad)(params).jaxpr)
+        if c.eqn.primitive.name == "pallas_call"})
+    if form == "xla":
+        assert kernels == []        # the program without the switch
+        return
+    assert kernels == ["causal_conv_bwd", "causal_conv_fwd"]
+    got, grads = value_and_grad(params)
+    monkeypatch.undo()
+    want, want_grads = value_and_grad(params)
+    assert float(got) == pytest.approx(float(want), rel=2e-4)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        assert float(jnp.linalg.norm(a - b)) <= 1e-4 * float(
+            jnp.linalg.norm(b))
+
+
 def test_the_stack_plan_and_its_line(sides):
     model = sides["model"]
     plan = model.stack_plan(2, 40)
     assert plan[R.M_STACK_LAYERS] == (
         (0, "mamba", 0), (1, "mamba", 0), (2, "attention", 0),
         (3, "mamba", 0))
-    form, chunk, entry_bytes, runs, mode, groups = plan[R.M_STACK_SSD]
-    assert (form, chunk, runs, mode, groups) == (
-        "xla", 16, "mamba x2, attention, mamba", "unrolled", 1)
+    form, chunk, entry_bytes, runs, mode, groups, conv = plan[R.M_STACK_SSD]
+    assert (form, chunk, runs, mode, groups, conv) == (
+        "xla", 16, "mamba x2, attention, mamba", "unrolled", 1, "xla")
     # 2 rows x 3 chunks x 8 heads x 16 x 16 states, float32
     assert entry_bytes == 2 * 3 * 8 * 16 * 16 * 4
     line = stack_plan_line(plan)
@@ -197,7 +233,7 @@ def test_the_stack_plan_and_its_line(sides):
     assert line.endswith(
         "runs of like layers: mamba x2, attention, mamba, unrolled; "
         "state-space duality scan: xla in chunks of 16, 49,152 B of "
-        "chunk-entry states a layer")
+        "chunk-entry states a layer; conv: xla")
     cut = GraniteHybridConfig(num_hidden_layers=10, vocab_size=12544)
     assert cut.runs() == [("mamba", 0, 5), ("attention", 5, 1),
                           ("mamba", 6, 4)]

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu as ds
+from deepspeed_tpu.analysis.jaxpr_walk import iter_eqns
 from deepspeed_tpu.models import NemotronHConfig, NemotronHModel
 from deepspeed_tpu.monitor import record as R
 from deepspeed_tpu.runtime.activation_checkpointing.checkpointing import (
@@ -161,15 +162,16 @@ def test_the_stack_plan_and_its_line(sides):
     assert [kind for _, kind, _ in plan[R.M_STACK_LAYERS]] == [
         "mamba", "experts", "mamba", "experts", "mamba", "attention",
         "experts", "mamba", "experts"]
-    form, chunk, entry_bytes, runs, mode, groups = plan[R.M_STACK_SSD]
-    assert (form, chunk, runs, mode, groups) == (
-        "xla", 16, "M, E, M, E, M, *, E, M, E", "unrolled", 4)
+    form, chunk, entry_bytes, runs, mode, groups, conv = plan[R.M_STACK_SSD]
+    assert (form, chunk, runs, mode, groups, conv) == (
+        "xla", 16, "M, E, M, E, M, *, E, M, E", "unrolled", 4, "xla")
     assert entry_bytes == 2 * 3 * 8 * 8 * 16 * 4      # 2 rows x 3 chunks
     assert plan[R.M_STACK_EXPERTS_HELD] == (4, 4, 16)
     line = stack_plan_line(plan)
     assert "0:mamba, 1:experts, 2:mamba" in line
     assert "routed experts 4 to 7 of 16 held here" in line
     assert "on 4 groups of B and C" in line
+    assert line.endswith("chunk-entry states a layer; conv: xla")
     assert model.gates() == [((f"layers_{i:02d}", "moe"), 1)
                              for i in (1, 3, 6, 8)]
     published = NemotronHConfig()
@@ -180,6 +182,41 @@ def test_the_stack_plan_and_its_line(sides):
     assert published.mixer.d_inner == 4096 != 2 * published.hidden_size
     with pytest.raises(ValueError, match="hybrid_override_pattern"):
         _config(hybrid_override_pattern="MEX")
+
+
+@pytest.mark.parametrize("seq,form", [(64, "kernel"), (40, "xla")])
+def test_the_conv_kernels_are_the_same_model(seq, form, monkeypatch):
+    """ops/causal_conv.py's kernels through the interpreter (the scan
+    and the experts left on their XLA forms), the first three layers
+    (mixer, experts, mixer): an inner width of one lane tile, four groups
+    of 32 states another; a whole block of positions, or 40, which the
+    kernels refuse and the same switch leaves on the XLA form.  The
+    plan's line names the form; loss and every gradient leaf against the
+    model without the switch."""
+    from deepspeed_tpu.ops import causal_conv
+    model = NemotronHModel(_config(
+        num_hidden_layers=3, mamba_head_dim=16, ssm_state_size=32))
+    params = model.init_params(jax.random.PRNGKey(3))
+    ids = jax.random.randint(jax.random.PRNGKey(4), (2, seq), 0, 128)
+    value_and_grad = jax.value_and_grad(lambda p: model.loss(p, None, ids))
+    assert stack_plan_line(model.stack_plan(2, seq)).endswith("conv: xla")
+    monkeypatch.setattr(causal_conv, "pallas_interpret", lambda: True)
+    assert stack_plan_line(model.stack_plan(2, seq)).endswith(
+        f"conv: {form}")
+    kernels = sorted({c.eqn.params["name"] for c in iter_eqns(
+        jax.make_jaxpr(value_and_grad)(params).jaxpr)
+        if c.eqn.primitive.name == "pallas_call"})
+    if form == "xla":
+        assert kernels == []        # the program without the switch
+        return
+    assert kernels == ["causal_conv_bwd", "causal_conv_fwd"]
+    got, grads = value_and_grad(params)
+    monkeypatch.undo()
+    want, want_grads = value_and_grad(params)
+    assert float(got) == pytest.approx(float(want), rel=2e-4)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        assert float(jnp.linalg.norm(a - b)) <= 1e-4 * float(
+            jnp.linalg.norm(b))
 
 
 def _engine_config(**extra):

@@ -92,6 +92,35 @@ def test_mamba_mixer_alone(setup):
         family.reference_plan(model.config))["layers"][2]["mixer"], gb)
 
 
+@pytest.mark.parametrize("seq,kernels", [
+    (64, ["causal_conv_bwd", "causal_conv_fwd"]), (40, [])])
+def test_mamba_mixer_through_the_conv_kernels(setup, seq, kernels,
+                                              monkeypatch):
+    """The mixer's conv is ops/causal_conv.py's, over the first half of
+    the projection: through the kernels (the interpreter; 128 channels,
+    a whole block of positions) the mixer's outputs and gradients are
+    the XLA form's; 40 positions are no block, and the same switch leaves
+    the XLA form."""
+    from deepspeed_tpu.analysis.jaxpr_walk import iter_eqns
+    from deepspeed_tpu.ops import causal_conv
+    model, params, _, _, _, _ = setup
+    p = params["mid_mamba"]["mixer"]
+    ks = jax.random.split(jax.random.PRNGKey(11), 2)
+    x = jax.random.normal(ks[0], (2, seq, 64))
+    g = jax.random.normal(ks[1], (2, seq, 64))
+
+    def ours(p, x):
+        out, y = model._mamba(p, x)
+        return jnp.sum(out * g) + jnp.sum(jnp.sin(y))
+
+    want = _grads(ours, p, x)
+    monkeypatch.setattr(causal_conv, "pallas_interpret", lambda: True)
+    assert sorted({c.eqn.params["name"] for c in iter_eqns(
+        jax.make_jaxpr(jax.grad(ours, (0, 1)))(p, x).jaxpr)
+        if c.eqn.primitive.name == "pallas_call"}) == kernels
+    _close(_grads(ours, p, x), want)
+
+
 def test_gated_memory_unit_alone(setup):
     model, params, x, memory, _, g = setup
     ours_p = jax.tree.map(lambda a: a[0], params["cross"])["gmu"]["mixer"]

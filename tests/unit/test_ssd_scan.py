@@ -375,3 +375,14 @@ def test_the_kernels_compile_for_v5e_at_the_cells_shapes(one_chip):
             ).as_text()
         assert text.count('custom_call_target="tpu_custom_call"') == 1, name
         assert f"{name}/pallas_call" in text
+
+
+@pytest.mark.parametrize("cell", ["granite", "nemotron", "phi4"])
+def test_the_conv_kernels_compile_for_v5e_at_the_cells_shapes(
+        cell, one_chip, monkeypatch):
+    """ops/causal_conv.py's two passes before the scan, at `[1, 4096,
+    8448]`, `[2, 8192, 10240]` and phi4's `[1, 8192, 10240]`: each ONE
+    Mosaic call, nothing of the activations' size beside it."""
+    from tests.unit import test_causal_conv as conv
+    conv.both_passes_compile_as_one_kernel_each(cell, one_chip, monkeypatch)
+
