@@ -14,9 +14,10 @@ from .keye_vl2 import KeyeVL2Config, KeyeVL2Model
 from .granite_hybrid import GraniteHybridConfig, GraniteHybridModel
 from .xing4 import Xing4Config, Xing4Model
 from .nemotron_h import NemotronHConfig, NemotronHModel
+from .zaya import ZayaConfig, ZayaModel
 
 __all__ = ["GPT2Config", "GPT2Model", "BertConfig", "BertModel",
            "GPTMoEConfig", "GPTMoEModel", "Phi4FlashConfig",
            "Phi4FlashModel", "KeyeVL2Config", "KeyeVL2Model",
            "GraniteHybridConfig", "GraniteHybridModel", "Xing4Config",
-           "Xing4Model", "NemotronHConfig", "NemotronHModel"]
+           "Xing4Model", "NemotronHConfig", "NemotronHModel", "ZayaConfig", "ZayaModel"]

@@ -163,16 +163,19 @@ class ExpertStack:
         policy where ``config.activation_checkpointing`` says so, or
         nothing.  ``plan`` (the model's M_STACK_* fields) gets the sparse
         FFN's row buffers and goes to the budget's log line, or is logged
-        here, once, where no budget will."""
+        here, once, where no budget will.  ``h`` may be a tuple, the
+        stream first and then the carries of another width that go from
+        layer to layer with it (``checkpoint_layers`` has the rest)."""
         cfg = self.config
-        tokens = h.shape[0] * h.shape[-2]
+        stream = h[0] if isinstance(h, tuple) else h
+        tokens = stream.shape[0] * stream.shape[-2]
         plan = {**plan, R.M_STACK_DISPATCH_ROWS: self.moe.capacity(tokens)}
         if cfg.activation_checkpointing:
             wrap = checkpoint_layers(
                 [group[:2] for group in groups],
                 self._remat_budget, h, cfg.vocab_size, plan,
                 extra_working_set=self.moe.working_set_bytes(
-                    tokens, h.dtype.itemsize),
+                    tokens, stream.dtype.itemsize),
                 streams=self.carry_streams)
         else:
             def wrap(body):

@@ -296,7 +296,7 @@ class DroplessMoE:
                  init_std: float = 0.02, selection_bias: bool = False,
                  first_chunk_always: bool = False,
                  dispatch_headroom: float = 1.0,
-                 expert=GatedExpertMLP):
+                 expert=GatedExpertMLP, own_router: bool = True):
         first, count = experts_held or (0, num_experts)
         if not (0 <= first and count >= 1 and first + count <= num_experts):
             raise ValueError(
@@ -327,6 +327,10 @@ class DroplessMoE:
             raise ValueError("dispatch_headroom is at least one even "
                              f"share, got {dispatch_headroom}")
         self.dispatch_headroom = dispatch_headroom
+        # False: the layer has no router matrix of its own and is handed
+        # the logits of the caller's router module (``apply(logits=)``:
+        # models/zaya.py, an MLP with a state carried from layer to layer)
+        self.own_router = own_router
 
     def _check_mesh(self):
         ctx = mesh_mod.get_mesh_context(required=False)
@@ -343,10 +347,10 @@ class DroplessMoE:
         # expert e's weights depend on e alone, not on the range held
         keys = jax.vmap(lambda e: jax.random.fold_in(k_experts, e))(
             first + jnp.arange(count))
-        params = {
-            "router": self.init_std * jax.random.normal(
-                k_router, (self.hidden_size, self.num_experts), jnp.float32),
-            "experts": jax.vmap(self.expert.init_params)(keys)}
+        params = {"experts": jax.vmap(self.expert.init_params)(keys)}
+        if self.own_router:
+            params["router"] = self.init_std * jax.random.normal(
+                k_router, (self.hidden_size, self.num_experts), jnp.float32)
         if self.shared is not None:
             params["shared"] = self.shared.init_params(k_shared)
         if self.selection_bias:
@@ -386,12 +390,20 @@ class DroplessMoE:
             + (self.expert.first_widths + 1) * self.expert.d_ff)
             + tokens * self.hidden_size * 4)
 
-    def route(self, params, x, picks=None) -> Routing:
-        """x [T, d] -> the routing; the product in float32 whatever x is."""
+    def route(self, params, x, picks=None, logits=None) -> Routing:
+        """x [T, d] -> the routing; the product in float32 whatever x is.
+        ``logits`` (f32 [T, E]) are a caller's router's and take the
+        product's place: a layer built with ``own_router=False`` has no
+        matrix and takes nothing else."""
+        if (logits is None) == (not self.own_router):
+            raise ValueError(
+                "DroplessMoE: a layer with its own router takes no logits, "
+                "and one built with own_router=False needs its caller's")
         with jax.named_scope("router"):
-            logits = jnp.dot(x.astype(jnp.float32),
-                             params["router"].astype(jnp.float32),
-                             precision=jax.lax.Precision.HIGHEST)
+            if logits is None:
+                logits = jnp.dot(x.astype(jnp.float32),
+                                 params["router"].astype(jnp.float32),
+                                 precision=jax.lax.Precision.HIGHEST)
             return route_topk(logits, self.k, self.score, self.renormalize,
                               self.scale, picks, params.get("bias")
                               )._replace(inputs=x)
@@ -407,15 +419,18 @@ class DroplessMoE:
             held=self.experts_held, chunks=self.chunks_walked(
                 routing.counts[first:first + count], share.shape[0]))
 
-    def apply(self, params, x, picks=None):
+    def apply(self, params, x, picks=None, logits=None):
         """x [..., d] -> (y [..., d], Routing).  ``picks`` forces the
-        choice (a comparison with a reference on the same picks)."""
+        choice (a comparison with a reference on the same picks);
+        ``logits`` [..., E] are the caller's router's (``route``)."""
         self._check_mesh()
         shape = x.shape
         x = x.reshape(-1, shape[-1])
         tokens, k = x.shape[0], self.k
         first, count = self.experts_held
-        routing = self.route(params, x, picks)
+        if logits is not None:
+            logits = logits.reshape(-1, self.num_experts)
+        routing = self.route(params, x, picks, logits)
         with jax.named_scope("dispatch"):
             order, position = sort_by_expert(routing.picks, first, count)
         y = _routed_experts(

@@ -125,6 +125,10 @@ M_REMAT_WORKING_SET_BYTES = "remat_working_set_bytes"
 # times the whole stack runs on the same weights; on the record only where
 # it is not 1, and then M_REMAT_LAYERS and the bytes count applications
 M_REMAT_PASSES = "remat_passes"
+# bytes a layer of what the stack's carry holds beside its stream(s), a
+# device (models/zaya.py: the router's state); on the record only where
+# there is such a carry, and then M_REMAT_WORKING_SET_BYTES counts it
+M_REMAT_SIDE_CARRY_BYTES = "remat_side_carry_bytes"
 
 # ---- the plan of a stack of unlike layers, on the same meta record --- #
 # (models/phi4flash.py; checkpointing.checkpoint_layers carries it)
@@ -165,6 +169,11 @@ M_STACK_SSD = "stack_ssd"
 # hyper-connections (ops/hyper_connection.py): [streams, Sinkhorn rounds,
 # the clamp's two ends]
 M_STACK_STREAMS = "stack_streams"
+# (models/zaya.py) compressed convolutional attention and the router
+# that carries a state: [query heads, key/value heads, their size, taps
+# of the depthwise conv, taps of the conv within a head, the router
+# state's width]
+M_STACK_CCA = "stack_cca"
 
 # ---- fleet field names (fleet.py / health.py payloads) --------------- #
 FL_WINDOW_START = "window_start_step"
@@ -254,6 +263,13 @@ M_HC_ROW_ERR = "hc_res_row_err_max"
 M_HC_COL_ERR = "hc_res_col_err_max"
 M_HC_PRE_MEAN = "hc_pre_mean"
 M_HC_POST_MEAN = "hc_post_mean"
+# (models/zaya.py) averaged over micro-batches: the rms of the router's
+# carried state after the last layer; the mean of the key heads' learned
+# temperatures (1 at the start); the mean of the residual merges' scales
+# ``a`` and ``g`` (1 at the start)
+M_ROUTER_STATE_RMS = "router_state_rms"
+M_CCA_TAU_MEAN = "cca_tau_mean"
+M_RESIDUAL_SCALE_MEAN = "residual_scale_mean"
 
 # ---- reconciliation field names (reconcile.py payload) --------------- #
 R_WINDOW_START = "window_start_step"

@@ -53,6 +53,11 @@ its scope (``PARTS``: the name the program writes with
          the select); the exact top-k over every query's causal index
          scores; the alignment term with the indexer's gradients.  There
          ``core`` is the attention restricted to the selected keys.
+  mix    one more part of ``attn`` (models/zaya.py, ops/cca.py): what
+         compressed convolutional attention does between its projections
+         and the unit norm of its heads: the q-k mean, the two causal
+         convs over the sequence, the value shift.  There ``qk_norm`` is
+         the unit norm of every head and the keys' learned temperature.
   in, conv, scan, gate, out
          the parts of ``ssm`` that a Mamba-2 mixer names
          (models/granite_hybrid.py; the Mamba-1 mixer of
@@ -129,6 +134,7 @@ PARTS = {
     "attn_index": ("index", ("attn",)),
     "attn_select": ("select", ("attn",)),
     "attn_align": ("align", ("attn",)),
+    "attn_mix": ("mix", ("attn",)),
     "ssm_in": ("in", ("ssm",)),
     "ssm_conv": ("conv", ("ssm",)),
     "ssm_scan": ("scan", ("ssm",)),

@@ -294,7 +294,10 @@ class RematBudget:
             f"of {plan[R.M_REMAT_BUDGET_BYTES]:,} B (limit "
             f"{plan[R.M_REMAT_BYTES_LIMIT]:,} - state "
             f"{plan[R.M_REMAT_STATE_BYTES]:,} - working set "
-            f"{plan[R.M_REMAT_WORKING_SET_BYTES]:,})", ranks=[0])
+            f"{plan[R.M_REMAT_WORKING_SET_BYTES]:,}"
+            + (f", {plan[R.M_REMAT_SIDE_CARRY_BYTES]:,} B a layer of it a "
+               "side carry" if R.M_REMAT_SIDE_CARRY_BYTES in plan else "")
+            + ")", ranks=[0])
         if R.M_STACK_LAYERS in plan:
             log_dist(stack_plan_line(plan), ranks=[0])
 
@@ -399,6 +402,13 @@ def stack_plan_line(plan: Dict[str, Any]) -> str:
         line += (f"; {streams} residual streams mixed by hyper-connections "
                  f"({rounds} Sinkhorn rounds from logits clamped to "
                  f"[{low:g}, {high:g}])")
+    if R.M_STACK_CCA in plan:
+        heads, kv, size, taps0, taps1, wide = plan[R.M_STACK_CCA]
+        line += (f"; compressed convolutional attention: {heads} query "
+                 f"heads on {kv} key/value heads of {size}, mixed by a "
+                 f"depthwise conv of {taps0} taps and a conv within a head "
+                 f"of {taps1}; the router carries a state of {wide} from "
+                 "layer to layer")
     if R.M_STACK_MTP in plan:
         modules, weight = plan[R.M_STACK_MTP]
         line += (f"; {modules} multi-token-prediction module(s), loss "
@@ -447,7 +457,13 @@ def checkpoint_layers(groups, budget: Optional[RematBudget], carry,
     and kept bytes, the working set's carries and M_REMAT_LAYERS count
     applications, and M_REMAT_PASSES rides on the plan (1: today's plan,
     the field left out).  Returns the wrapper for every body
-    of the stack: what
+    of the stack.  ``carry`` may be a tuple: its first entry the stream(s)
+    and the others SIDE carries of widths of their own, handed from layer
+    to layer and kept a layer like the stream (models/zaya.py: the
+    router's state, float32 [tokens, 256]); they are charged to the
+    working set at their tiled bytes a layer, and
+    M_REMAT_SIDE_CARRY_BYTES rides on the plan (no side carry: today's
+    plan, the field left out).  What
     a wrapped body closes over (another layer's output that this one
     reads; the plan may be made on a stand-in of its shape) is an input
     of the checkpointed body: kept, never recomputed."""
@@ -455,6 +471,9 @@ def checkpoint_layers(groups, budget: Optional[RematBudget], carry,
         return _KEEP_ALWAYS
     shape = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), carry)
+    carry, *sides = carry if isinstance(carry, tuple) else (carry,)
+    side_bytes = sum(_tiled_bytes(a.shape, a.dtype)
+                     for a in sides) // budget.batch_shards
     steps = [passes * jax.tree.leaves(xs)[0].shape[0] for _, xs in groups]
     offers = [offered_residuals(
         body, shape, jax.tree.map(
@@ -470,7 +489,7 @@ def checkpoint_layers(groups, budget: Optional[RematBudget], carry,
             math.prod(carry.shape[:-1]) // streams // budget.batch_shards,
             carry.shape[-1], num_layers, head_width, carry.dtype.itemsize,
             budget.cast_bytes, streams)
-    working_set += extra_working_set
+    working_set += extra_working_set + num_layers * side_bytes
     kept = saved_residual_names(offered, 1, budget.bytes(working_set))
     by_name = tuple((name, offered[name]) for name in kept)
     budget.note_plan({
@@ -486,6 +505,7 @@ def checkpoint_layers(groups, budget: Optional[RematBudget], carry,
         R.M_REMAT_STATE_BYTES: budget.state_bytes,
         R.M_REMAT_WORKING_SET_BYTES: working_set,
         **({R.M_REMAT_PASSES: passes} if passes != 1 else {}),
+        **({R.M_REMAT_SIDE_CARRY_BYTES: side_bytes} if sides else {}),
         **(stack_plan or {})})
     if not kept:
         return _KEEP_ALWAYS
