@@ -13,7 +13,9 @@ key says), N states, G groups of B and C that H / G heads each read.
     S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t[g], y_t = S_t C_t[g]
         + D x_t                      a head, g its group (ops/ssd_scan.py)
     y = RMSNorm_G(y * silu(z)) * gain   the gate BEFORE the norm, the
-        mean square over each group's H P / G channels
+        mean square over each group's H P / G channels, a group a
+        static slice of lanes reduced on its own (never an axis: that
+        reshape is a relayout on the TPU), forward and backward
     out = y W_out
 
 Inside scope ``ssm`` the mixer names its parts (profiling/scope_map.py
@@ -21,6 +23,7 @@ PARTS): ``ssm_in``, ``ssm_conv``, ``ssm_scan``, ``ssm_gate``,
 ``ssm_out``.
 """
 
+import functools
 import math
 from dataclasses import dataclass
 
@@ -38,11 +41,75 @@ def gated_rms_norm(y, z, gain, eps, groups=1):
     """``RMSNorm(y * silu(z)) * gain``, the mean square taken over each of
     the ``groups`` equal parts of the last dimension (one: over all of
     it), float32 inside, in y's dtype."""
-    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
     if groups > 1:
-        g = g.reshape(*g.shape[:-1], groups, -1)
+        return _grouped_gated_norm(y, z, gain, eps, groups)
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
     g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
-    return (g.reshape(y.shape) * gain.astype(jnp.float32)).astype(y.dtype)
+    return (g * gain.astype(jnp.float32)).astype(y.dtype)
+
+
+def _group_slices(width, groups):
+    if width % groups:
+        raise ValueError(
+            f"{width} channels do not divide into {groups} groups")
+    return [slice(k, k + width // groups)
+            for k in range(0, width, width // groups)]
+
+
+def _gated(y, z):
+    """(y silu(z), silu(z), sigmoid(z)) in float32."""
+    z = z.astype(jnp.float32)
+    sig = jax.nn.sigmoid(z)
+    return y.astype(jnp.float32) * (z * sig), z * sig, sig
+
+
+def _rstd(g, eps):
+    return jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+
+
+# The grouped case never gives the group an axis of its own: on the TPU
+# the second-minor dimension of [.., groups, width] is the group, so that
+# reshape is a relayout and everything after it a float32 array of the
+# activations' size (768 MiB of them at [2, 8192, 4096] in 8 groups).  A
+# group is a static slice of lanes instead, reduced on its own and written
+# where it belongs, forward and backward; nothing is saved but the op's
+# inputs (as ops/normalize.py's LayerNorm and ops/causal_conv.py do).
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _grouped_gated_norm(y, z, gain, eps, groups):
+    return _grouped_fwd(y, z, gain, eps, groups)[0]
+
+
+def _grouped_fwd(y, z, gain, eps, groups):
+    outs = []
+    for s in _group_slices(y.shape[-1], groups):
+        g, _, _ = _gated(y[..., s], z[..., s])
+        outs.append((g * _rstd(g, eps) * gain[s].astype(jnp.float32)
+                     ).astype(y.dtype))
+    return jnp.concatenate(outs, axis=-1), (y, z, gain)
+
+
+def _grouped_bwd(eps, groups, res, dout):
+    """With g = y silu(z), r = rsqrt(mean_G(g^2) + eps), out = g r gain:
+    dg = r (gain dout) - g r^3 mean_G(g gain dout), dy = dg silu(z),
+    dz = dg y silu'(z), d gain = sum over the rows of dout g r."""
+    y, z, gain = res
+    dys, dzs, dgains = [], [], []
+    for s in _group_slices(y.shape[-1], groups):
+        g, act, sig = _gated(y[..., s], z[..., s])
+        r = _rstd(g, eps)
+        d = dout[..., s].astype(jnp.float32)
+        wd = d * gain[s].astype(jnp.float32)
+        dg = r * wd - g * (r * r * r * jnp.mean(g * wd, axis=-1,
+                                                keepdims=True))
+        dys.append((dg * act).astype(y.dtype))
+        dzs.append((dg * y[..., s].astype(jnp.float32)
+                    * (sig + act * (1 - sig))).astype(z.dtype))
+        dgains.append(jnp.sum((d * g * r).reshape(-1, d.shape[-1]), axis=0))
+    return (jnp.concatenate(dys, axis=-1), jnp.concatenate(dzs, axis=-1),
+            jnp.concatenate(dgains).astype(gain.dtype))
+
+
+_grouped_gated_norm.defvjp(_grouped_fwd, _grouped_bwd)
 
 
 @dataclass(frozen=True)
